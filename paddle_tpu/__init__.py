@@ -10,10 +10,6 @@ SPMD distributed training over TPU meshes.
 Reference capability map: see SURVEY.md at the repo root.
 """
 
-from paddle_tpu._platform import honor_jax_platforms_env as _honor_env
-
-_honor_env()    # JAX_PLATFORMS env beats any sitecustomize config pin
-
 from paddle_tpu.version import __version__
 
 from paddle_tpu.core import dtypes

@@ -19,10 +19,6 @@ import traceback
 import numpy as np
 
 
-from paddle_tpu._platform import \
-    honor_jax_platforms_env as _honor_jax_platforms_env
-
-
 _machines = {}
 _next_id = [1]
 _id_lock = threading.Lock()   # handle allocation under concurrent C threads
@@ -53,7 +49,6 @@ def _store_error(e):
 def create(config_path, params_path):
     """Build an inference machine; returns handle id (>0) or -1."""
     try:
-        _honor_jax_platforms_env()
         import jax.numpy as jnp
         from paddle_tpu.layers.graph import LayerOutput
         from paddle_tpu.trainer.checkpoint import load_merged
@@ -86,7 +81,6 @@ def create_exported(path):
     nor the merged params — the artifact is self-contained.  Returns
     handle id (>0) or -1."""
     try:
-        _honor_jax_platforms_env()
         from paddle_tpu.export import load_inference
         run_fn = load_inference(path)
         mid = _alloc_id()

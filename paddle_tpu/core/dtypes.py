@@ -37,11 +37,7 @@ def param_dtype():
 
 def _auto_compute_dtype():
     import jax
-    try:
-        platform = jax.default_backend()
-    except Exception:
-        platform = "cpu"
-    return jnp.bfloat16 if platform == "tpu" else jnp.float32
+    return jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32
 
 
 def compute_dtype():

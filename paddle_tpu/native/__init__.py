@@ -63,6 +63,18 @@ def is_available():
     return _load() is not None
 
 
+def feeder_status():
+    """Which sequence packer the DataFeeder runs, and why:
+    ``{"path": "native" | "python", "why": ...}``."""
+    if is_available():
+        return {"path": "native",
+                "why": f"{os.path.basename(_SO)} built from src/dataio.cpp "
+                       "(paddle_tpu/native/build.ensure)"}
+    from paddle_tpu.native import build as _build
+    return {"path": "python",
+            "why": _build.why_unavailable("dataio") or f"{_SO} is missing"}
+
+
 def pack_i32(seqs, max_len=None, pad=0):
     """seqs: list of 1-D int32 arrays -> (out [B, T] int32, lengths [B])."""
     lib = _load()

@@ -47,31 +47,54 @@ def build_capi(verbose=True):
     return out
 
 
+def _source_digest(src):
+    import hashlib
+    with open(src, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
+
+
 def ensure(which="dataio", verbose=False):
-    """Build `which` ('dataio' or 'capi') if its .so is missing or older
-    than its source.  Best-effort: returns the .so path on success, None
-    when the toolchain is unavailable or the build fails.  The binaries
-    are intentionally NOT committed — they are rebuilt on demand here.
+    """Build `which` ('dataio' or 'capi') unless its .so was built from
+    the source as it stands.  Freshness is the SOURCE'S DIGEST, recorded
+    beside the binary at build time — not mtimes, which a copied or
+    freshly checked-out tree does not preserve.  Best-effort: returns the
+    .so path on success, None when the toolchain is unavailable or the
+    build fails (``why_unavailable`` keeps the reason).  The binaries are
+    intentionally NOT committed — they are rebuilt on demand here.
     Disable with PADDLE_TPU_NO_NATIVE_BUILD=1 (e.g. images without g++)."""
+    name = {"dataio": "libpaddle_tpu_dataio.so",
+            "capi": "libpaddle_tpu_capi.so"}[which]
     if os.environ.get("PADDLE_TPU_NO_NATIVE_BUILD"):
+        _FAILED[which] = "PADDLE_TPU_NO_NATIVE_BUILD is set"
         return None
     if which in _FAILED:   # a persistent toolchain failure must not be
         return None        # re-paid per call (e.g. per feeder batch)
-    name = {"dataio": "libpaddle_tpu_dataio.so",
-            "capi": "libpaddle_tpu_capi.so"}[which]
     src = os.path.join(_DIR, "src", which + ".cpp")
     out = os.path.join(_DIR, name)
+    stamp = out + ".src.sha256"
     try:
-        if (os.path.exists(out)
-                and os.path.getmtime(out) >= os.path.getmtime(src)):
-            return out
-        return (build if which == "dataio" else build_capi)(verbose=verbose)
-    except Exception:   # noqa: BLE001 — missing g++/headers: fall back
-        _FAILED.add(which)
+        digest = _source_digest(src)
+        if os.path.exists(out) and os.path.exists(stamp):
+            with open(stamp) as f:
+                if f.read().strip() == digest:
+                    return out
+        path = (build if which == "dataio" else build_capi)(verbose=verbose)
+        with open(stamp, "w") as f:
+            f.write(digest + "\n")
+        return path
+    except (OSError, subprocess.CalledProcessError) as e:
+        # missing g++/headers: the Python paths keep working
+        _FAILED[which] = f"{type(e).__name__}: {e}"
         return None
 
 
-_FAILED = set()   # libs whose build failed this process; see ensure()
+_FAILED = {}   # lib -> why its build failed this process; see ensure()
+
+
+def why_unavailable(which="dataio"):
+    """Why ``ensure(which)`` returned None this process (None if it
+    never failed)."""
+    return _FAILED.get(which)
 
 
 def capi_header_dir():

@@ -25,11 +25,9 @@ from paddle_tpu.ops.pallas.flash_attention import flash_attention
 
 
 def use_pallas():
-    """True when the default backend compiles Pallas natively (TPU)."""
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    """True when the default backend compiles Pallas natively (TPU).  A
+    backend that fails to initialise raises here — it is not a CPU."""
+    return jax.default_backend() == "tpu"
 
 
 __all__ = ["flash_attention", "use_pallas"]

@@ -68,7 +68,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from paddle_tpu.ops.pallas.common import LANES as _LANES, lanes as _lanes
+from paddle_tpu.ops.pallas.common import (LANES as _LANES, lanes as _lanes,
+                                          vmem_budget_bytes)
 
 _NEG = -1e30
 
@@ -143,11 +144,25 @@ def _lane_tileable(n):
     return n <= _LANES or n % _LANES == 0
 
 
-def _pick_block_k(t, cap, interpret, quant=False):
+def _vmem_bytes_per_position(dkv, quant):
+    """VMEM one streamed KV position pins while a k-tile is resident:
+    the K and V rows (f32, or int8 when ``quant``), each double-buffered
+    by the Pallas pipeline; for int8 K/V also the two f32 images the body
+    widens a block into and the two f32 scale-sidecar rows (lane-padded,
+    double-buffered)."""
+    if not quant:
+        return 2 * 2 * dkv * 4
+    return 2 * 2 * dkv + 2 * dkv * 4 + 2 * 2 * _LANES * 4
+
+
+def _pick_block_k(t, cap, interpret, quant=False, dkv=None):
     """Largest k-tile <= cap dividing the slab length, compatible with
     the lane-replicated running-stat layout (<= LANES or a LANES
-    multiple).  Single-block (blk == t) when the whole stripe fits the
-    cap — the common serving shape, where the online softmax degenerates
+    multiple) and — given ``dkv`` — small enough that the streamed K/V
+    blocks fit ``common.vmem_budget_bytes()`` (at Dkv=2048 f32 the flag's
+    512 cap alone is 16 MiB of double-buffered blocks: the chip's whole
+    scoped VMEM).  Single-block (blk == t) when the whole stripe fits —
+    the common small-serving shape, where the online softmax degenerates
     to one plain masked softmax.  Compiled mode additionally wants
     8-sublane-divisible tiles — 32 for int8 K/V (``quant``; the s8 VMEM
     tile is (32, 128)), applied HERE so a 32-divisible tile is found
@@ -155,6 +170,9 @@ def _pick_block_k(t, cap, interpret, quant=False):
     rejected downstream; interpret mode takes any shape."""
     if t < 1:
         return None
+    if dkv is not None:
+        cap = min(cap, vmem_budget_bytes()
+                  // _vmem_bytes_per_position(dkv, quant))
     sublane = 32 if quant else 8
     b = min(t, cap)
     while b >= 1:
@@ -165,21 +183,35 @@ def _pick_block_k(t, cap, interpret, quant=False):
     return None
 
 
-def _mosaic_ok(blk, dkv, dh, interpret, quant=False):
-    """Tiling constraints.  The lane-replicated running stats require a
-    lane-tileable k-tile AND head dim in EVERY mode — ``_lanes`` can
-    only slice (n <= LANES) or tile (n % LANES == 0), so e.g. a paged
-    block_size of 136 must fall back to the reference path rather than
-    fail mid-trace.  Compiled mode additionally wants 8-divisible
-    sublane tiles and a lane-tileable Dkv; int8 K/V (``quant``) raises
-    the sublane requirement to 32 — the s8 VMEM tile is (32, 128)."""
-    if not (_lane_tileable(blk) and _lane_tileable(dh)):
-        return False
+def _tile_problem(blk, dkv, dh, interpret, quant=False):
+    """Why a k-tile of ``blk`` positions cannot run (None = it can).
+    The lane-replicated running stats require a lane-tileable k-tile AND
+    head dim in EVERY mode — ``_lanes`` can only slice (n <= LANES) or
+    tile (n % LANES == 0), so e.g. a paged block_size of 136 must fall
+    back to the reference path rather than fail mid-trace.  Compiled
+    mode additionally wants 8-divisible sublane tiles and a lane-tileable
+    Dkv; int8 K/V (``quant``) raises the sublane requirement to 32 — the
+    s8 VMEM tile is (32, 128) — and the tile's streamed blocks must fit
+    the VMEM budget."""
+    if not _lane_tileable(blk):
+        return f"k-tile {blk} is neither <= {_LANES} nor a multiple of it"
+    if not _lane_tileable(dh):
+        return f"head_dim {dh} is neither <= {_LANES} nor a multiple of it"
     if interpret:
-        return True
+        return None
     if quant and blk % 32:
-        return False
-    return blk % 8 == 0 and _lane_tileable(dkv)
+        return (f"int8 K/V needs a k-tile that is a multiple of 32 (the "
+                f"s8 VMEM tile is (32, {_LANES})), got {blk}")
+    if blk % 8:
+        return f"k-tile {blk} is not a multiple of 8 sublanes"
+    if not _lane_tileable(dkv):
+        return f"Dkv {dkv} is neither <= {_LANES} nor a multiple of it"
+    need = blk * _vmem_bytes_per_position(dkv, quant)
+    if need > vmem_budget_bytes():
+        return (f"k-tile {blk} x Dkv {dkv} streams {need} bytes of "
+                f"double-buffered K/V, over the {vmem_budget_bytes()}-byte "
+                f"VMEM budget")
+    return None
 
 
 # ------------------------------------------------------------ kernel body
@@ -411,7 +443,7 @@ def decode_attention_slab(q, k, v, positions, num_heads, *, block_k=None,
     t, dkv = k.shape[1], k.shape[2]
     split = _head_split(d, dkv, num_heads)
     blk = _pick_block_k(t, block_k or _block_k_cap(), interpret,
-                        quant=kscale is not None)
+                        quant=kscale is not None, dkv=dkv)
     if split is None or blk is None:
         raise ValueError(
             f"decode_attention_slab: unsupported shape q={q.shape} "
@@ -419,10 +451,9 @@ def decode_attention_slab(q, k, v, positions, num_heads, *, block_k=None,
     dh, hkv, _group = split
     quant = _check_scales("decode_attention_slab", kscale, vscale,
                           (s, t), hkv)
-    if not _mosaic_ok(blk, dkv, dh, interpret, quant=quant):
-        raise ValueError(
-            f"decode_attention_slab: untileable blk={blk} dkv={dkv} "
-            f"dh={dh} for the compiled backend")
+    problem = _tile_problem(blk, dkv, dh, interpret, quant=quant)
+    if problem:
+        raise ValueError(f"decode_attention_slab: {problem}")
     scale = 1.0 / math.sqrt(dh)
     kernel = functools.partial(_slab_kernel, blk=blk, num_heads=num_heads,
                                hkv=hkv, dh=dh, scale=scale)
@@ -490,10 +521,9 @@ def decode_attention_paged(q, k, v, positions, tables, num_heads, *,
     dh, hkv, _group = split
     quant = _check_scales("decode_attention_paged", kscale, vscale,
                           (k.shape[0], bs), hkv)
-    if not _mosaic_ok(bs, dkv, dh, interpret, quant=quant):
-        raise ValueError(
-            f"decode_attention_paged: untileable block_size={bs} "
-            f"dkv={dkv} dh={dh} for the compiled backend")
+    problem = _tile_problem(bs, dkv, dh, interpret, quant=quant)
+    if problem:
+        raise ValueError(f"decode_attention_paged: {problem}")
     scale = 1.0 / math.sqrt(dh)
     kernel = functools.partial(_paged_kernel, blk=bs,
                                num_heads=num_heads, hkv=hkv, dh=dh,
@@ -558,7 +588,7 @@ def decode_attention_slab_chunk(q, k, v, qpos, num_heads, *,
     t, dkv = k.shape[1], k.shape[2]
     split = _head_split(d, dkv, num_heads)
     blk = _pick_block_k(t, block_k or _block_k_cap(), interpret,
-                        quant=kscale is not None)
+                        quant=kscale is not None, dkv=dkv)
     if split is None or blk is None or not _chunk_ok(kk, num_heads,
                                                     interpret):
         raise ValueError(
@@ -567,10 +597,9 @@ def decode_attention_slab_chunk(q, k, v, qpos, num_heads, *,
     dh, hkv, _group = split
     quant = _check_scales("decode_attention_slab_chunk", kscale, vscale,
                           (s, t), hkv)
-    if not _mosaic_ok(blk, dkv, dh, interpret, quant=quant):
-        raise ValueError(
-            f"decode_attention_slab_chunk: untileable blk={blk} "
-            f"dkv={dkv} dh={dh} for the compiled backend")
+    problem = _tile_problem(blk, dkv, dh, interpret, quant=quant)
+    if problem:
+        raise ValueError(f"decode_attention_slab_chunk: {problem}")
     scale = 1.0 / math.sqrt(dh)
     kernel = functools.partial(_chunk_kernel, blk=blk, kk=kk,
                                num_heads=num_heads, hkv=hkv, dh=dh,
@@ -637,10 +666,9 @@ def decode_attention_paged_chunk(q, k, v, qpos, tables, num_heads, *,
     dh, hkv, _group = split
     quant = _check_scales("decode_attention_paged_chunk", kscale,
                           vscale, (k.shape[0], bs), hkv)
-    if not _mosaic_ok(bs, dkv, dh, interpret, quant=quant):
-        raise ValueError(
-            f"decode_attention_paged_chunk: untileable block_size={bs} "
-            f"dkv={dkv} dh={dh} for the compiled backend")
+    problem = _tile_problem(bs, dkv, dh, interpret, quant=quant)
+    if problem:
+        raise ValueError(f"decode_attention_paged_chunk: {problem}")
     scale = 1.0 / math.sqrt(dh)
     kernel = functools.partial(_paged_chunk_kernel, blk=bs, kk=kk,
                                num_heads=num_heads, hkv=hkv, dh=dh,
@@ -696,15 +724,17 @@ def _chunk_ok(kk, num_heads, interpret):
     return interpret or (kk * num_heads) % 8 == 0
 
 
-def covers(num_heads, d, dkv, blk_len, paged=False, chunk=1, quant=False,
-           shards=1):
-    """THE dispatch predicate (flag + shape support), shared by
-    ``maybe_slab``/``maybe_paged`` and by ``DecodeEngine.warmup``'s
-    resolved-path log — one definition, so the engine can never report
-    a path its compiled step didn't take.  ``blk_len``: the slab length
-    (slab) or the pool block size (paged).  ``chunk``: query lanes per
-    row (1 = plain decode; >1 = the chunked-prefill step).  ``quant``:
-    int8 K/V (tighter sublane tiling on the compiled backend).
+def decline_reason(num_heads, d, dkv, blk_len, paged=False, chunk=1,
+                   quant=False, shards=1):
+    """THE dispatch predicate (flag + shape support), as the reason the
+    fused kernel will NOT serve these shapes — None when it will.  Shared
+    by ``maybe_*`` (through ``covers``) and by ``DecodeEngine.warmup``'s
+    resolved-path log — one definition, so the engine can never report a
+    path its compiled step didn't take, and a reference path always has
+    a sentence saying why.  ``blk_len``: the slab length (slab) or the
+    pool block size (paged).  ``chunk``: query lanes per row (1 = plain
+    decode; >1 = the chunked-prefill step).  ``quant``: int8 K/V (tighter
+    sublane tiling on the compiled backend).
 
     ``shards``: a tensor-parallel mesh (docs/serving.md "Sharded
     decode") hands each chip the PER-CHIP stripe — ``num_heads/n``
@@ -713,27 +743,44 @@ def covers(num_heads, d, dkv, blk_len, paged=False, chunk=1, quant=False,
     the 4-head shard (lane-tiling of the narrower Dkv, the smaller
     ``chunk*H`` sublane dim).  The maybe_* call sites inside the
     shard_map see the local widths naturally; this localizes the
-    warm-up prediction to match, rejecting to the reference path
-    whenever any local width stops tiling."""
+    warm-up prediction to match."""
     if not decode_kernels_enabled():
-        return False
+        m = str(_mode()).lower()
+        if m == "auto":
+            return (f"pallas_decode=auto and the backend is "
+                    f"{jax.default_backend()!r}, not 'tpu'")
+        return f"pallas_decode={m}"
     shards = max(1, int(shards))
     if shards > 1:
         if num_heads % shards or d % shards or dkv % shards:
-            return False        # uneven stripes never reach the kernels
-        num_heads //= shards
-        d //= shards
-        dkv //= shards
+            return (f"heads {num_heads} / d {d} / Dkv {dkv} do not split "
+                    f"evenly over {shards} shards")
+        why = decline_reason(num_heads // shards, d // shards,
+                             dkv // shards, blk_len, paged=paged,
+                             chunk=chunk, quant=quant)
+        return why and f"per-chip stripe (1/{shards} of the heads): {why}"
     interpret = _interpret(None)
     split = _head_split(d, dkv, num_heads)
-    if split is None or not _chunk_ok(chunk, num_heads, interpret):
-        return False
+    if split is None:
+        return (f"d {d}, Dkv {dkv}, heads {num_heads} do not describe a "
+                "grouped-head layout")
+    if not _chunk_ok(chunk, num_heads, interpret):
+        return (f"chunk {chunk} x heads {num_heads} is not a multiple of 8 "
+                "sublanes")
     if paged:
-        return _mosaic_ok(blk_len, dkv, split[0], interpret, quant=quant)
-    blk = _pick_block_k(blk_len, _block_k_cap(), interpret,
-                        quant=quant)
-    return blk is not None and _mosaic_ok(blk, dkv, split[0], interpret,
-                                          quant=quant)
+        return _tile_problem(blk_len, dkv, split[0], interpret, quant=quant)
+    blk = _pick_block_k(blk_len, _block_k_cap(), interpret, quant=quant,
+                        dkv=dkv)
+    if blk is None:
+        return (f"no k-tile divides slab length {blk_len} under the "
+                "sublane, lane and VMEM-budget constraints")
+    return _tile_problem(blk, dkv, split[0], interpret, quant=quant)
+
+
+def covers(*args, **kw):
+    """True when the fused kernel serves these shapes
+    (``decline_reason`` is None)."""
+    return decline_reason(*args, **kw) is None
 
 
 def maybe_slab(q, k, v, positions, num_heads, kscale=None, vscale=None):

@@ -79,6 +79,28 @@ def prefill_flash_enabled():
     from paddle_tpu.ops import pallas as pk
     return pk.use_pallas()
 
+
+
+def prefill_decline_reason(tp, head_dim):
+    """Why ``lm_prefill``'s batched causal pass over a ``tp``-long prompt
+    bucket will NOT run the flash kernel (None = it will): the routing
+    flag, then ``flash_attention``'s own blocking — the same conditions
+    under which it falls back to the masked XLA path inside.  For the
+    engine's warm-up log: a reference path always has its sentence."""
+    if not prefill_flash_enabled():
+        m = str(_prefill_mode()).lower()
+        if m == "auto":
+            return (f"pallas_prefill=auto and the backend is "
+                    f"{jax.default_backend()!r}, not 'tpu'")
+        return f"pallas_prefill={m}"
+    if _pick_block(512, tp) is None:
+        return f"no 8-sublane block <= 512 tiles a prompt bucket of {tp}"
+    if not _tileable(head_dim):
+        return (f"head_dim {head_dim} is neither <= {_LANES} nor a "
+                "multiple of it")
+    return None
+
+
 # Per-row statistics (running max/sum, lse, delta) live lane-REPLICATED in
 # [rows, 128] tiles — the same layout
 # jax.experimental.pallas.ops.tpu.flash_attention uses; see pallas/common.py.
@@ -477,8 +499,8 @@ def _fwd_quant_kernel(q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
         # widen in registers: int8 block * per-(position, head) scale
         # column — the exact dequantize_heads product, so the kernel is
         # bit-identical to flash over the dequantized widened twin
-        k = k_ref[0].astype(jnp.float32) * ks_ref[0]   # [blk_k, dh]
-        v = v_ref[0].astype(jnp.float32) * vs_ref[0]
+        k = k_ref[0].astype(jnp.float32) * ks_ref[0, 0]   # [blk_k, dh]
+        v = v_ref[0].astype(jnp.float32) * vs_ref[0, 0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # [blk_q, blk_k]
@@ -551,6 +573,12 @@ def flash_attention_quant(q, k, v, kscale, vscale, num_heads, scale=None,
     kernel = functools.partial(_fwd_quant_kernel, blk_q=blk_q,
                                blk_k=blk_k, scale=scale, causal=causal)
     kv_map = lambda bb, hh, i, j: (bb, j, hh // group)
+    # the sidecars go in head-major [B, Hkv, Tk, 1]: a [blk_k, 1] column
+    # block of the cache layout [B, Tk, Hkv] has a last dim that is
+    # neither 128-divisible nor the array's, which Mosaic rejects
+    sc_map = lambda bb, hh, i, j: (bb, hh // group, j, 0)
+    kscale, vscale = (sc.transpose(0, 2, 1)[..., None]
+                      for sc in (kscale, vscale))
     o = pl.pallas_call(
         kernel,
         grid=(b, num_heads, tq // blk_q, tk // blk_k),
@@ -559,8 +587,8 @@ def flash_attention_quant(q, k, v, kscale, vscale, num_heads, scale=None,
                          lambda bb, hh, i, j: (bb, hh, i, 0)),
             pl.BlockSpec((1, blk_k, dh), kv_map),
             pl.BlockSpec((1, blk_k, dh), kv_map),
-            pl.BlockSpec((1, blk_k, 1), kv_map),
-            pl.BlockSpec((1, blk_k, 1), kv_map),
+            pl.BlockSpec((1, 1, blk_k, 1), sc_map),
+            pl.BlockSpec((1, 1, blk_k, 1), sc_map),
         ],
         out_specs=pl.BlockSpec((1, 1, blk_q, dh),
                                lambda bb, hh, i, j: (bb, hh, i, 0)),
@@ -582,20 +610,37 @@ def flash_attention_quant(q, k, v, kscale, vscale, num_heads, scale=None,
     return o
 
 
-def prefill_quant_covers(b, tq, tk, d, dkv, num_heads, interpret,
-                         block_q=512, block_k=512):
-    """True when flash_attention_quant's blocking covers the shape —
-    the dispatch predicate (decode_attention.covers's twin)."""
+def prefill_quant_decline_reason(tq, tk, d, dkv, num_heads, interpret=None,
+                                 block_q=512, block_k=512):
+    """Why flash_attention_quant's blocking does NOT cover the shape
+    (None = it does) — the dispatch predicate
+    (decode_attention.decline_reason's twin)."""
     from paddle_tpu.ops.pallas import decode_attention as _dk
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     hs = _dk._head_split(d, dkv, num_heads)
     if hs is None:
-        return False
+        return (f"d {d}, Dkv {dkv}, heads {num_heads} do not describe a "
+                "grouped-head layout")
     dh, _, _ = hs
-    if not _tileable(dh) or tq != tk:
-        return False
-    return (_pick_block(block_q, tq) is not None
-            and _pick_block(block_k, tk,
-                            sublane=8 if interpret else 32) is not None)
+    if not _tileable(dh):
+        return f"head_dim {dh} is neither <= {_LANES} nor a multiple of it"
+    if tq != tk:
+        return f"causal prefill needs Tq == Tk, got {tq} != {tk}"
+    if _pick_block(block_q, tq) is None:
+        return f"no q block <= {block_q} tiles Tq {tq}"
+    if _pick_block(block_k, tk, sublane=8 if interpret else 32) is None:
+        return (f"no k block <= {block_k} tiles Tk {tk} in "
+                f"{8 if interpret else 32}-sublane int8 tiles")
+    return None
+
+
+def prefill_quant_covers(b, tq, tk, d, dkv, num_heads, interpret,
+                         block_q=512, block_k=512):
+    """True when flash_attention_quant serves the shape."""
+    del b
+    return prefill_quant_decline_reason(tq, tk, d, dkv, num_heads,
+                                        interpret, block_q, block_k) is None
 
 
 def maybe_prefill_quant(q, k_set, v_set, sk, sv, num_heads):

@@ -17,6 +17,7 @@ through masked steps unchanged, so results match the reference's padding-free
 semantics exactly.
 """
 
+import contextlib
 from typing import NamedTuple
 
 import jax
@@ -127,16 +128,62 @@ def _fused_lstm_enabled():
 FUSED_DISPATCH_COUNT = 0
 
 
-def _fused_seq_apply(seq, xs, ms, reverse, kernel_fn):
+# The mesh axis a multi-device jit shards the batch over, as (mesh, axis) —
+# set by the trainer around its step's trace (``batch_sharded_over``).
+# GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+# automatically partitioned"), so under such a jit the fused kernels are
+# handed their batch shard through ``shard_map`` instead.  Read at TRACE
+# time, like FUSED_LSTM.
+_BATCH_MESH = None
+
+
+@contextlib.contextmanager
+def batch_sharded_over(mesh, axis):
+    """Trace the enclosed model code as data-parallel over ``axis`` of
+    ``mesh``: the fused RNN kernels run per batch shard under shard_map.
+    A no-op for ``mesh=None`` or an axis of size 1."""
+    global _BATCH_MESH
+    old = _BATCH_MESH
+    if mesh is not None and dict(mesh.shape).get(axis, 1) > 1:
+        _BATCH_MESH = (mesh, axis)
+    try:
+        yield
+    finally:
+        _BATCH_MESH = old
+
+
+def _local_batch(b):
+    """The batch ONE kernel instance sees: the per-shard batch under
+    ``batch_sharded_over`` (what the kernels' VMEM guards must judge)."""
+    if _BATCH_MESH is None:
+        return b
+    mesh, axis = _BATCH_MESH
+    n = mesh.shape[axis]
+    # an uneven batch cannot be sharded: 0 fails every supported() guard
+    return b // n if b % n == 0 else 0
+
+
+def _fused_seq_apply(seq, xs, ms, reverse, kernel_fn, weights):
     """Shared fused-kernel dispatch: reverse = forward kernel over
     time-flipped arrays, flipped back (valid because sequences are
     left-aligned; masked steps freeze the carry identically either way).
-    Returns (SequenceBatch, final-state) from kernel_fn(xs_tm, ms_tm)."""
+    Returns (SequenceBatch, final-state) from
+    ``kernel_fn(xs_tm, ms_tm, weights)``; ``weights`` (a tuple, None
+    entries allowed) is passed explicitly so that under
+    ``batch_sharded_over`` it enters the shard_map as a replicated operand
+    whose cotangent is summed over the batch shards."""
     global FUSED_DISPATCH_COUNT
     FUSED_DISPATCH_COUNT += 1
     xs_k = jnp.flip(xs, 0) if reverse else xs
     ms_k = jnp.flip(ms, 0) if reverse else ms
-    hs_tm, final = kernel_fn(xs_k, ms_k)
+    if _BATCH_MESH is not None:
+        from jax.sharding import PartitionSpec as P
+        mesh, axis = _BATCH_MESH
+        kernel_fn = jax.shard_map(
+            kernel_fn, mesh=mesh,
+            in_specs=(P(None, axis), P(None, axis), P()),
+            out_specs=(P(None, axis), P(axis)), check_vma=False)
+    hs_tm, final = kernel_fn(xs_k, ms_k, weights)
     if reverse:
         hs_tm = jnp.flip(hs_tm, 0)
     out = hs_tm.transpose(1, 0, 2) * seq.mask(hs_tm.dtype)[..., None]
@@ -177,21 +224,22 @@ def lstm(seq: SequenceBatch, w_r, bias=None, check_i=None, check_f=None,
         # the scan fallback down with it
         from paddle_tpu.ops.pallas import lstm as pl_lstm
         from paddle_tpu.ops.pallas import lstm_blocked as pl_lstm_blk
-        if pl_lstm.supported(b, d, act, gate_act, state_act, init_state):
+        b_k = _local_batch(b)
+        weights = (w_r, check_i, check_f, check_o)
+        if pl_lstm.supported(b_k, d, act, gate_act, state_act, init_state):
             sb, (fh, fc) = _fused_seq_apply(
                 seq, xs, ms, reverse,
-                lambda x, m: pl_lstm.lstm_fused(x, m, w_r, check_i,
-                                                check_f, check_o))
+                lambda x, m, w: pl_lstm.lstm_fused(x, m, *w), weights)
             return sb, LstmState(h=fh, c=fc)
         # over-VMEM hidden sizes: the gate-blocked forward keeps the carry
         # in VMEM and fuses the cell while streaming weight blocks (scan-
         # equivalent weight traffic; docs/kernels.md blocked-variant notes)
-        if pl_lstm_blk.supported(b, d, act, gate_act, state_act,
+        if pl_lstm_blk.supported(b_k, d, act, gate_act, state_act,
                                  init_state):
             sb, (fh, fc) = _fused_seq_apply(
                 seq, xs, ms, reverse,
-                lambda x, m: pl_lstm_blk.lstm_fused_blocked(
-                    x, m, w_r, check_i, check_f, check_o))
+                lambda x, m, w: pl_lstm_blk.lstm_fused_blocked(x, m, *w),
+                weights)
             return sb, LstmState(h=fh, c=fc)
 
     if init_state is None:
@@ -221,10 +269,11 @@ def gru(seq: SequenceBatch, w_gate, w_state, bias=None, reverse=False,
 
     if _fused_lstm_enabled():
         from paddle_tpu.ops.pallas import gru as pl_gru
-        if pl_gru.supported(b, d, act, gate_act, init_state):
+        if pl_gru.supported(_local_batch(b), d, act, gate_act, init_state):
             return _fused_seq_apply(
                 seq, xs, ms, reverse,
-                lambda x, m: pl_gru.gru_fused(x, m, w_gate, w_state))
+                lambda x, m, w: pl_gru.gru_fused(x, m, *w),
+                (w_gate, w_state))
 
     if init_state is None:
         init_state = jnp.zeros((b, d), x.dtype)
@@ -247,10 +296,10 @@ def simple_rnn(seq: SequenceBatch, w_r, bias=None, reverse=False, act="tanh",
 
     if _fused_lstm_enabled():
         from paddle_tpu.ops.pallas import simple_rnn as pl_rnn
-        if pl_rnn.supported(b, d, act, init_state):
+        if pl_rnn.supported(_local_batch(b), d, act, init_state):
             return _fused_seq_apply(
                 seq, xs, ms, reverse,
-                lambda x, m: pl_rnn.simple_rnn_fused(x, m, w_r))
+                lambda x, m, w: pl_rnn.simple_rnn_fused(x, m, *w), (w_r,))
 
     if init_state is None:
         init_state = jnp.zeros((b, d), x.dtype)

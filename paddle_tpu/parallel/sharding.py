@@ -27,30 +27,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from paddle_tpu.parallel.mesh import AXIS_DATA, AXIS_MODEL
 
-# version-compat shard_map: jax >= 0.5 promotes it to jax.shard_map, jax
-# 0.4.x keeps it in the experimental namespace.  Call sites here use the
-# NEW kwarg name (check_vma); whether the resolved function takes it is a
-# separate axis from where it lives (the promotion and the check_rep ->
-# check_vma rename were different releases), so translate by signature.
-try:
-    _shard_map_impl = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-try:
-    import inspect
-    _SM_TAKES_VMA = ("check_vma"
-                     in inspect.signature(_shard_map_impl).parameters)
-except (TypeError, ValueError):         # uninspectable wrapper: assume new
-    _SM_TAKES_VMA = True
-
-if _SM_TAKES_VMA:
-    shard_map = _shard_map_impl
-else:
-    def shard_map(f, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map_impl(f, **kwargs)
+shard_map = jax.shard_map
 
 
 def _path_str(path):
@@ -218,6 +195,20 @@ def lm_cache_specs(cache, axis=AXIS_MODEL):
     stripe of every slot row / pool block."""
     return jax.tree_util.tree_map(
         lambda l: P(*([None] * (np.ndim(l) - 1) + [axis])), cache)
+
+
+def new_lm_cache(build, mesh, axis=AXIS_MODEL):
+    """A fresh KV cache from ``build()`` (a zero-argument constructor such
+    as ``lambda: init_lm_cache_paged(...)``), every buffer born as its
+    per-chip head stripes: the constructor runs jitted with the
+    ``lm_cache_specs`` shardings as its out_shardings, so each chip only
+    ever allocates its own share.  Built whole and spread afterwards, a
+    pool sized to the per-chip budget of an n-chip mesh would first have
+    to fit — n times over — on the one chip it was created on."""
+    shardings = jax.tree_util.tree_map(
+        lambda spec: NamedSharding(mesh, spec),
+        lm_cache_specs(jax.eval_shape(build), axis))
+    return jax.jit(build, out_shardings=shardings)()
 
 
 def lm_shard_problems(params, num_heads, shards):

@@ -238,13 +238,24 @@ def widened_prefill_kv_instrs(hlo_text, b, tp, dkv):
     from paddle_tpu.perf import cost as _cost
     target = int(b) * int(tp) * int(dkv)
     shape_re = re.compile(r"^f32\[([0-9,]+)\]")
+    # the XLA of jax 0.9.0 prints operands by NAME only
+    # (``convert(%bitcast.57)``), so the operand's element type comes from
+    # the instruction that defines it
+    def_re = re.compile(r"^\s*(?:ROOT\s+)?(%[^\s=]+) = (\w+)\[")
+    operand_re = re.compile(r"convert\((%[^\s,)]+)\)")
+    lines = hlo_text.splitlines()
+    dtype_of = {m.group(1): m.group(2)
+                for m in map(def_re.match, lines) if m}
     hits = []
-    for line in hlo_text.splitlines():
+    for line in lines:
         m = _cost._INSTR_RE.match(line)
         if not m:
             continue
         rhs = m.group(1)
-        if _cost._op_of(rhs) != "convert" or "s8[" not in rhs:
+        if _cost._op_of(rhs) != "convert":
+            continue
+        om = operand_re.search(rhs)
+        if not om or dtype_of.get(om.group(1)) != "s8":
             continue
         sm = shape_re.match(rhs)
         if not sm:

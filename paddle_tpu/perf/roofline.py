@@ -16,9 +16,11 @@ f32 traffic unless the program itself casts; on TPU the auto bf16 policy
 roughly halves matmul operand bytes — the prediction is conservative for
 bandwidth-bound families.
 
-Spec sources: public TPU system spec sheets / the jax-ml scaling book;
-the v5e peak matches bench.py's `_PEAK_TFLOPS` table so measured MFU and
-predicted MFU share a denominator.
+``SPECS`` is the repository's ONE peaks table: every consumer — the
+analytic snapshot, bench.py's MFU denominator, the serving engine's
+restore/handoff routers — reads a chip's peaks from here, by the
+``device_kind`` JAX reports (``for_device_kind``).  A device that is not
+in the table is an error, never a default.
 """
 
 import dataclasses
@@ -27,6 +29,7 @@ import dataclasses
 @dataclasses.dataclass(frozen=True)
 class ChipSpec:
     name: str
+    device_kind: str        # jax.devices()[0].device_kind on this chip
     peak_flops: float       # dense bf16 FLOP/s (f32 for the cpu row)
     hbm_bytes_per_s: float  # HBM (DRAM for cpu) bandwidth, bytes/s
     # host<->device link (PCIe) bandwidth: the third roofline ceiling
@@ -43,15 +46,33 @@ class ChipSpec:
         return self.peak_flops / self.hbm_bytes_per_s
 
 
-# Keyed by the short names the snapshot JSON uses.  The cpu row is a
-# sanity anchor only (one NUMA node, AVX-512 class) — wall-clock on the
-# shared CI hosts is far noisier than the TPU rows.
+# Keyed by the short names the snapshot JSON uses; ``device_kind`` is
+# what the chip itself reports (read off libtpu 0.0.34's topology
+# descriptions; the v5e says "TPU v5 lite").  Peaks: Google Cloud TPU
+# documentation, system architecture pages "TPU v5e" (197 TFLOP/s bf16,
+# 819 GB/s HBM), "TPU v5p" (459 TFLOP/s, 2765 GB/s) and "TPU v4"
+# (275 TFLOP/s, 1228 GB/s).  The cpu row is a sanity anchor only (one
+# NUMA node, AVX-512 class) for the CPU test lane.
 SPECS = {
-    "v5e": ChipSpec("TPU v5e", 197e12, 819e9),
-    "v5p": ChipSpec("TPU v5p", 459e12, 2765e9),
-    "v4": ChipSpec("TPU v4", 275e12, 1228e9),
-    "cpu": ChipSpec("cpu (sanity anchor)", 1e11, 50e9),
+    "v5e": ChipSpec("TPU v5e", "TPU v5 lite", 197e12, 819e9),
+    "v5p": ChipSpec("TPU v5p", "TPU v5", 459e12, 2765e9),
+    "v4": ChipSpec("TPU v4", "TPU v4", 275e12, 1228e9),
+    "cpu": ChipSpec("cpu (sanity anchor)", "cpu", 1e11, 50e9),
 }
+
+
+def for_device_kind(device_kind):
+    """The peaks row of the chip that reports ``device_kind``.  Raises
+    ``KeyError`` naming the stranger: a number computed against an
+    assumed chip is worse than no number."""
+    for spec in SPECS.values():
+        if spec.device_kind == device_kind:
+            return spec
+    raise KeyError(
+        f"device_kind {device_kind!r} is not in the peaks table "
+        f"(perf/roofline.SPECS knows "
+        f"{sorted(s.device_kind for s in SPECS.values())}); add its row "
+        "with a source before computing against it")
 
 
 def predict(flops, bytes_accessed, spec):

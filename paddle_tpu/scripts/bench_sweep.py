@@ -4,9 +4,9 @@ The reference's benchmark table sweeps batch sizes per model
 (benchmark/README.md:33-120); the TPU equivalent sweeps into MXU-saturating
 batches (the round-2 verdict's scaling column: ResNet/GoogleNet at bs
 256-1024, transformer at >=32k tokens/batch).  Each combo runs as its own
-bench.py subprocess (fresh backend, own watchdog) and lands in
-bench_cache.json under model@bsN, so one healthy chip window fills the
-whole table and the round-end bench replays it from cache.
+bench.py subprocess, one after another (fresh backend, own watchdog): this
+parent never imports JAX, so the chip belongs to the one child that is
+running.
 
 Usage:
   python -m paddle_tpu.scripts.bench_sweep [--combos m:b,m:b,...]
@@ -19,49 +19,14 @@ plus the TPU scaling points.
 """
 
 import argparse
-import calendar
 import json
 import os
 import subprocess
 import sys
-import time
 
 _REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-
-def _fresh_live_row(model, batch, max_age_s, cache_path=None):
-    """Return the bench_cache.json row for this combo if it was measured
-    LIVE at the current code revision within max_age_s — i.e. re-running it
-    would spend healthy-window time reproducing a number we already have.
-    Conservative: any parse/import/revision mismatch means 'not fresh'."""
-    if max_age_s <= 0:
-        return None
-    if os.environ.get("BENCH_PLATFORM") == "cpu":
-        # a cpu sweep must never report the committed TPU rows as its own
-        return None
-    try:
-        if _REPO not in sys.path:
-            sys.path.insert(0, _REPO)
-        import bench
-        from paddle_tpu.utils.revision import code_revision
-        key = bench.cache_key_for(model, batch)
-        cache_path = cache_path or os.path.join(_REPO, "bench_cache.json")
-        with open(cache_path) as f:
-            row = json.load(f).get(key)
-        if not row or row.get("value") is None:
-            return None
-        if row.get("platform") == "cpu":
-            # a BENCH_CACHE_CPU row must not suppress the live TPU run
-            return None
-        rev = code_revision()
-        if "+" in rev or rev == "unknown" or row.get("revision") != rev:
-            return None
-        age = time.time() - calendar.timegm(
-            time.strptime(row["measured_at"], "%Y-%m-%dT%H:%M:%SZ"))
-        return row if 0 <= age <= max_age_s else None
-    except Exception:   # noqa: BLE001
-        return None
 
 DEFAULT_COMBOS = [
     # BASELINE.md reference points (bs 64 rows)
@@ -90,27 +55,6 @@ DEFAULT_COMBOS = [
     "seq2seq:64",
     "trainer_prefetch:64",                        # input-pipeline overlap
 ]
-
-
-def _chip_alive(timeout_s=90):
-    """Cheap liveness probe in a fresh subprocess: a 256x256 matmul that
-    must land on the TPU backend (jax's silent CPU fallback would read a
-    fast-failing wedge as alive — same assert as window_watch.sh).
-    Distinguishes 'this combo was slow/oversized' from 'the chip wedged
-    mid-window' after a *_timeout failure.  A cpu-forced sweep has no
-    chip to probe: vacuously alive."""
-    if os.environ.get("BENCH_PLATFORM") == "cpu":
-        return True
-    code = ("import jax, jax.numpy as jnp;"
-            "x = jnp.ones((256, 256));"
-            "assert float((x @ x).block_until_ready()[0, 0]) == 256.0;"
-            "assert jax.default_backend() == 'tpu'")
-    try:
-        return subprocess.run(
-            [sys.executable, "-c", code], timeout=timeout_s,
-            capture_output=True).returncode == 0
-    except subprocess.TimeoutExpired:
-        return False
 
 
 def run_combo(model, batch, steps, timeout):
@@ -143,7 +87,7 @@ def main(argv=None):
     ap.add_argument("--analytic", action="store_true",
                     help="run the chip-independent analytic snapshot "
                          "(paddle_tpu.perf.analytic, CPU backend) instead "
-                         "of live combos — the no-chip-window fallback")
+                         "of live combos")
     ap.add_argument("--analytic-out", default=None,
                     help="snapshot path for --analytic (default: "
                          "BENCH_ANALYTIC_r06.json at the repo root)")
@@ -155,13 +99,6 @@ def main(argv=None):
         from paddle_tpu.perf import analytic
         return analytic.main(["--out", args.analytic_out]
                              if args.analytic_out else [])
-
-    try:
-        skip_fresh_s = float(os.environ.get("BENCH_SWEEP_SKIP_FRESH_S", "0"))
-    except ValueError:
-        print("[sweep] bad BENCH_SWEEP_SKIP_FRESH_S (want seconds) — "
-              "skip-fresh disabled", file=sys.stderr)
-        skip_fresh_s = 0.0
 
     results = {}
     for combo in args.combos.split(","):
@@ -175,19 +112,6 @@ def main(argv=None):
             results[combo] = {"error": "bad_combo"}
             continue
         batch = int(batch)
-        # incremental across wedge-interrupted windows: a combo measured
-        # live at this exact revision recently enough doesn't get re-run
-        # (BENCH_SWEEP_SKIP_FRESH_S=0, the default, disables this)
-        fresh = _fresh_live_row(model, batch, skip_fresh_s)
-        if fresh is not None:
-            row = {k: fresh.get(k) for k in
-                   ("value", "unit", "vs_baseline", "mfu", "tokens_per_s")}
-            row.update(error=None, cached=True, skipped_fresh=True)
-            results[combo] = row
-            print(f"[sweep] {combo}: fresh at this revision "
-                  f"({fresh.get('measured_at')}) — skipping",
-                  file=sys.stderr, flush=True)
-            continue
         print(f"[sweep] {model} bs={batch} ...", file=sys.stderr, flush=True)
         try:
             r = run_combo(model, batch, args.steps, args.timeout)
@@ -195,58 +119,27 @@ def main(argv=None):
             r = {"error": "sweep_timeout"}
         row = {k: r.get(k) for k in
                ("value", "unit", "vs_baseline", "mfu",
-                "tokens_per_s", "error", "cached")}
-        # keep the diagnostics for failed runs — a crashed combo from a
-        # scarce healthy-chip window must stay debuggable.  A cached replay
-        # carries its live failure under live_error (bench.py _emit_failure)
-        if r.get("error") or r.get("live_error"):
-            for k in ("rc", "stderr", "phase", "detail", "live_error",
-                      "live_phase", "live_detail"):
+                "tokens_per_s", "error")}
+        # keep the diagnostics for failed runs — a crashed combo must stay
+        # debuggable from the sweep's one JSON line
+        if r.get("error"):
+            for k in ("rc", "stderr", "phase", "detail"):
                 if r.get(k) is not None:
                     row[k] = r[k]
         results[combo] = row
         print(f"[sweep] {combo}: {row}", file=sys.stderr, flush=True)
-        # only a true wedge signal stops the sweep; a combo-specific
-        # compile/steps/sweep timeout (e.g. an oversized batch) moves on so
-        # the remaining combos still use the healthy window.  Cached
-        # replays count: the chip is just as wedged, and each further combo
-        # would burn the full init-retry budget to replay its cache.
-        wedges = ("backend_unavailable_timeout", "backend_unavailable")
-        if r.get("error") in wedges or r.get("live_error") in wedges:
-            print(f"[sweep] backend wedged "
-                  f"({r.get('error') or r.get('live_error')}) — stopping "
+        # a backend that cannot come up fails every remaining combo the
+        # same way; a combo-specific compile/steps timeout (e.g. an
+        # oversized batch) moves on
+        if r.get("error") in ("backend_unavailable_timeout",
+                              "backend_unavailable"):
+            print(f"[sweep] backend unavailable ({r['error']}) — stopping "
                   "sweep", file=sys.stderr)
             break
-        # a wedge can also land AFTER backend init (the r4 window died in
-        # a build phase): any timeout failure triggers a cheap liveness
-        # probe, and a dead probe stops the sweep instead of burning every
-        # remaining combo's full deadline budget against a wedged chip
-        err = (r.get("error") or r.get("live_error") or "")
-        if err.endswith("_timeout") and not _chip_alive():
-            print(f"[sweep] liveness probe failed after {combo} ({err}) — "
-                  "chip wedged mid-window, stopping sweep", file=sys.stderr)
-            results[combo]["wedge_probe"] = "dead"
-            break
     print(json.dumps({"sweep": results}), flush=True)
-    # a cached replay over a live failure is NOT a measurement: rc 4
-    # (mirrors bench.py's PADDLE_TPU_BENCH_STRICT_RC contract) so
-    # healthy_window.sh's rc log cannot mistake a wedged-chip sweep for a
-    # live one
-    live_ok = sum(1 for r in results.values()
-                  if r.get("value") is not None and not r.get("error")
-                  and not r.get("live_error")
-                  and not r.get("skipped_fresh"))
-    replays = sum(1 for r in results.values() if r.get("live_error"))
-    skipped = sum(1 for r in results.values() if r.get("skipped_fresh"))
-    if live_ok:
-        return 0
-    if replays:
-        # a skipped-fresh prefix must not hide that THIS window wedged
-        return 4
-    if skipped and skipped == len(results):
-        # nothing to do: every combo already measured live at this revision
-        return 0
-    return 2
+    ok = sum(1 for r in results.values()
+             if r.get("value") is not None and not r.get("error"))
+    return 0 if ok else 2
 
 
 if __name__ == "__main__":

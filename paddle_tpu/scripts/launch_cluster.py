@@ -19,8 +19,11 @@ Requires passwordless ssh to each host and the repo available at the same
 path everywhere (reference conf.py HOSTS assumption).
 
 `--local N` fans out N ranks as plain subprocesses on THIS machine instead
-of ssh — the single-machine bring-up / debugging mode (and what the
-multi-process distributed test drives).
+of ssh — the CPU test mode (what the multi-process distributed test
+drives, under JAX_PLATFORMS=cpu).  Every rank inherits the same
+environment and so sees every chip: on a TPU host only one of them could
+take the chips and the rest would fail or hang.  One process drives all
+the chips of a host; use `--hosts` with one rank per host there.
 """
 
 import argparse
@@ -86,7 +89,9 @@ def main(argv=None):
     parser.add_argument("--hosts",
                         help="comma-separated host list; first = coordinator")
     parser.add_argument("--local", type=int, metavar="N",
-                        help="run N ranks as local subprocesses (no ssh)")
+                        help="run N ranks as local subprocesses (no ssh) — "
+                             "the CPU test mode: every rank sees every "
+                             "chip, so not for a TPU host")
     parser.add_argument("--port", type=int, default=8476)
     parser.add_argument("--workdir", default=os.getcwd())
     parser.add_argument("command", nargs=argparse.REMAINDER,

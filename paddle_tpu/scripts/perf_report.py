@@ -1,12 +1,5 @@
-"""Render docs/perf.md tables from bench_cache.json + analytic gates.
+"""Analytic snapshot tools (chip-independent: counts, not speeds).
 
-After a healthy-window sweep fills the cache, this prints the markdown
-tables the perf doc wants — BASELINE families vs the K40m reference,
-the TPU scaling column, the fused-vs-scan RNN kernel comparison, and the
-serving-decode row — each row carrying its measured_at timestamp so
-provenance survives the paste.
-
-Analytic mode (round-6, chip-independent):
   --analytic-diff OLD.json NEW.json   structural regression gate between
       two `bench.py --analytic` snapshots: exits non-zero when a family's
       bytes-accessed inflates, its FLOPs inflate, its HLO op mix shows a
@@ -14,155 +7,11 @@ Analytic mode (round-6, chip-independent):
       disappears.  Identical snapshots always pass.
   --analytic-table SNAP.json          render the per-family roofline
       markdown table for docs/perf.md "Analytic roofline".
-
-Usage:  python -m paddle_tpu.scripts.perf_report [--cache bench_cache.json]
 """
 
 import argparse
 import json
-import os
-import re
 
-_REPO = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-
-_FAMILY_ORDER = ["lstm256", "lstm", "lstm1280", "smallnet", "alexnet",
-                 "googlenet", "resnet50", "seq2seq", "transformer",
-                 "transformer_long", "transformer_decode",
-                 "transformer_serving"]
-
-
-def _fmt_mfu(e):
-    return f"{e['mfu'] * 100:.1f}%" if e.get("mfu") is not None else "—"
-
-
-def _fmt_speedup(e):
-    return f"{e['vs_baseline']}×" if e.get("vs_baseline") else "—"
-
-
-def _stamp(e):
-    return (e.get("measured_at") or "")[:16]
-
-
-def families_table(cache):
-    lines = ["| model | batch | ref K40m ms | TPU ms | speedup | MFU | "
-             "tokens/s | measured |",
-             "|---|---|---|---|---|---|---|---|"]
-    for name in _FAMILY_ORDER:
-        e = cache.get(name)
-        if not e or e.get("value") is None:
-            continue
-        m = re.search(r"bs=(\d+)", e.get("metric", ""))
-        batch = m.group(1) if m else "?"
-        # the K40m reference ms is recoverable from the cached speedup —
-        # one source of truth (bench.py's baselines), nothing re-typed here
-        ref = round(e["value"] * e["vs_baseline"], 1) \
-            if e.get("vs_baseline") else None
-        lines.append(
-            f"| {name} | {batch} | {ref if ref else 'n/a'} | "
-            f"{e['value']} | {_fmt_speedup(e)} | {_fmt_mfu(e)} | "
-            f"{e.get('tokens_per_s') or '—'} | {_stamp(e)} |")
-    return "\n".join(lines)
-
-
-def scaling_table(cache):
-    def key(k):
-        m = re.search(r"@bs(\d+)", k)
-        return (k.split("@")[0], int(m.group(1)) if m else 0)
-
-    rows = sorted((k for k in cache if "@bs" in k and "@scan" not in k
-                   and "@bfloat16" not in k and "@float32" not in k),
-                  key=key)
-    if not rows:
-        return "(no scaling rows cached yet)"
-    lines = ["| run | TPU ms | MFU | tokens/s | remat | measured |",
-             "|---|---|---|---|---|---|"]
-    for k in rows:
-        e = cache[k]
-        if e.get("value") is None:
-            continue
-        lines.append(f"| {k} | {e['value']} | {_fmt_mfu(e)} | "
-                     f"{e.get('tokens_per_s') or '—'} | "
-                     f"{'yes' if e.get('remat') else 'no'} | {_stamp(e)} |")
-    return "\n".join(lines)
-
-
-def _suffix_pairs(cache, suffix):
-    """[(base_key, base_row, variant_row)] for key+suffix variants whose
-    base row exists; both sides value-guarded."""
-    pairs = []
-    for k, e in cache.items():
-        if k.endswith(suffix) and e.get("value") is not None:
-            base = cache.get(k[:-len(suffix)])
-            if base and base.get("value") is not None:
-                pairs.append((k[:-len(suffix)], base, e))
-    return sorted(pairs)
-
-
-def bf16_table(cache):
-    """bf16 pairs (phase 2c rows cache under key@bfloat16).  The baseline
-    is an explicit @float32 row when one exists; otherwise the bare row,
-    which on TPU runs the AUTO policy (bf16 MXU inputs, f32 params/
-    activations) — labelled so the delta is not misread as f32-vs-bf16
-    compute when it is really the half-width HBM effect."""
-    pairs = []
-    for name, base, b in _suffix_pairs(cache, "@bfloat16"):
-        f32 = cache.get(name + "@float32")
-        if f32 and f32.get("value") is not None:
-            pairs.append((name, "f32", f32, b))
-        else:
-            pairs.append((name, "auto", base, b))
-    if not pairs:
-        return "(no bf16 pairs cached yet)"
-    lines = ["| run | baseline | baseline ms | bf16 ms | bf16 speedup | "
-             "bf16 MFU | measured |",
-             "|---|---|---|---|---|---|---|"]
-    for name, kind, base, b in pairs:
-        lines.append(
-            f"| {name} | {kind} | {base['value']} | {b['value']} | "
-            f"{base['value'] / b['value']:.2f}× | {_fmt_mfu(b)} | "
-            f"{_stamp(b)} |")
-    return "\n".join(lines)
-
-
-def kernel_table(cache):
-    pairs = [(name, base, scan)
-             for name, base, scan in _suffix_pairs(cache, "@scan")]
-    if not pairs:
-        return "(no fused-vs-scan pairs cached yet)"
-    lines = ["| model | fused ms | scan ms | kernel speedup | path | "
-             "measured |",
-             "|---|---|---|---|---|---|"]
-    for name, fused, scan in pairs:
-        # fused_rnn False on the "fused" row means the dispatcher actually
-        # ran the scan (fallback/guard) — flag it rather than implying a
-        # kernel win
-        path = "kernel" if fused.get("fused_rnn", True) else "scan (!)"
-        lines.append(
-            f"| {name} | {fused['value']} | {scan['value']} | "
-            f"{scan['value'] / fused['value']:.2f}× | {path} | "
-            f"{_stamp(fused)} |")
-    return "\n".join(lines)
-
-
-def int8_table(cache):
-    """Weight-only int8 serving column (phase 2d rows cache under
-    key@int8): the float-vs-int8 latency ratio isolates the weight-stream
-    HBM effect — the serving figure of merit docs/serving.md promises."""
-    pairs = _suffix_pairs(cache, "@int8")
-    if not pairs:
-        return "(no int8 pairs cached yet)"
-    lines = ["| model | float ms | int8 ms | int8 speedup | measured |",
-             "|---|---|---|---|---|"]
-    for name, base, q in pairs:
-        lines.append(
-            f"| {name} | {base['value']} | {q['value']} | "
-            f"{base['value'] / q['value']:.2f}× | {_stamp(q)} |")
-    return "\n".join(lines)
-
-
-# --------------------------------------------------------------------------
-# Analytic snapshots (bench.py --analytic): structural diff + doc table.
 # The gate's thresholds are deliberately loose enough to ride out XLA-
 # version churn in op counts and tight enough that a real de-fusion (a
 # matmul split into blocks, an elementwise chain falling out of its
@@ -289,11 +138,10 @@ def analytic_table(snap):
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--cache",
-                    default=os.path.join(_REPO, "bench_cache.json"))
-    ap.add_argument("--analytic-diff", nargs=2,
-                    metavar=("OLD", "NEW"), default=None)
-    ap.add_argument("--analytic-table", default=None, metavar="SNAP")
+    mode = ap.add_mutually_exclusive_group(required=True)
+    mode.add_argument("--analytic-diff", nargs=2,
+                      metavar=("OLD", "NEW"), default=None)
+    mode.add_argument("--analytic-table", default=None, metavar="SNAP")
     ap.add_argument("--bytes-tol", type=float, default=None)
     ap.add_argument("--flops-tol", type=float, default=None)
     args = ap.parse_args(argv)
@@ -316,22 +164,7 @@ def main(argv=None):
               f"{'ies' if len(old['families']) != 1 else 'y'} compared")
         return 0
 
-    if args.analytic_table:
-        print(analytic_table(_load_snapshot(args.analytic_table)))
-        return 0
-
-    with open(args.cache) as f:
-        cache = json.load(f)
-    print("## Benchmark families (vs BASELINE.md K40m)\n")
-    print(families_table(cache))
-    print("\n## TPU scaling column\n")
-    print(scaling_table(cache))
-    print("\n## Mixed-precision (bf16) column\n")
-    print(bf16_table(cache))
-    print("\n## Fused Pallas RNN kernels vs lax.scan\n")
-    print(kernel_table(cache))
-    print("\n## Weight-only int8 serving column\n")
-    print(int8_table(cache))
+    print(analytic_table(_load_snapshot(args.analytic_table)))
     return 0
 
 

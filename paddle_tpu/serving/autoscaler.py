@@ -475,9 +475,10 @@ def _smoke():
     code."""
     import http.client
     import numpy as _np
+    from paddle_tpu.serving.fleet import ReplicaSupervisor, pin_parent_to_cpu
+    pin_parent_to_cpu()     # the oracle below must not take a replica's chip
     import jax
     from paddle_tpu.models import transformer
-    from paddle_tpu.serving.fleet import ReplicaSupervisor
     from paddle_tpu.serving.router import Router
 
     errs = []

@@ -349,8 +349,8 @@ class DecodeEngine:
                 else None)
             # per-layer [num_blocks, block_size, Dkv] pools (block 0 is
             # the scratch block free slot rows point at)
-            self._cache = self._place_cache(
-                transformer.init_lm_cache_paged(
+            self._cache = self._new_cache(
+                lambda: transformer.init_lm_cache_paged(
                     params, num_blocks, self.block_size,
                     max_len=self.max_len, kv_dtype=kv_dtype,
                     num_heads=self.num_heads))
@@ -382,9 +382,10 @@ class DecodeEngine:
             self._cache_treedef = flat[1]
         else:
             # init_lm_cache validates max_len against the positional table
-            self._cache = self._place_cache(transformer.init_lm_cache(
-                params, self.num_slots, self.max_len, kv_dtype=kv_dtype,
-                num_heads=self.num_heads))
+            self._cache = self._new_cache(
+                lambda: transformer.init_lm_cache(
+                    params, self.num_slots, self.max_len,
+                    kv_dtype=kv_dtype, num_heads=self.num_heads))
         # prefill-compute ledger: real positions run through the prefill
         # ladder (the paged prefix cache's whole point is to NOT grow
         # this; bench.py serving_paged reads it for the elimination rate)
@@ -455,8 +456,10 @@ class DecodeEngine:
         self._prefill_engines = {}     # length bucket -> InferenceEngine
         self._step_traces = [0]
         # resolved at warm-up (the step's trace time): did the compiled
-        # step take the fused Pallas decode-attention path?
+        # step take the fused Pallas decode-attention path, and if not,
+        # the guard's reason (ops/pallas/decode_attention.decline_reason)
         self.decode_kernels = False
+        self.decode_decline_reason = None
 
         # all_lanes is a TRACE-TIME constant: a speculating engine's
         # step returns EVERY lane's argmax [S, K] (the verify surface —
@@ -549,20 +552,16 @@ class DecodeEngine:
 
     # --------------------------------------------------- sharded decode
 
-    def _place_cache(self, cache):
-        """Shard a fresh KV cache over the mesh: every buffer's trailing
-        (head-stripe) axis splits, so each chip holds its ``Hkv/n``
-        stripe of every slot row / pool block.  Identity when unsharded.
-        Used at construction AND by ``reset()`` — a recovery rebuild
-        must come back with the same placement or the warm step would
-        recompile."""
+    def _new_cache(self, build):
+        """A fresh KV cache from the zero-argument constructor ``build``.
+        Sharded engines get every buffer born as per-chip head stripes
+        (``parallel.sharding.new_lm_cache``: each chip holds its ``Hkv/n``
+        stripe of every slot row / pool block, and never more).  Used at
+        construction AND by ``reset()`` — a recovery rebuild must come
+        back with the same placement or the warm step would recompile."""
         if self._shard_axis is None:
-            return cache
-        from jax.sharding import NamedSharding
-        specs = self._psh.lm_cache_specs(cache, self._shard_axis)
-        return jax.tree_util.tree_map(
-            lambda l, s: jax.device_put(l, NamedSharding(self.mesh, s)),
-            cache, specs)
+            return build()
+        return self._psh.new_lm_cache(build, self.mesh, self._shard_axis)
 
     def _shard_body(self, fn, n_data):
         """Wrap a chunked step body in ``parallel.sharding.shard_map``
@@ -989,8 +988,8 @@ class DecodeEngine:
         chip spec matching this backend.  Returns ``(verdict,
         restore_ms, recompute_ms)`` — the ``serving_kv_spill`` bench
         gates both directions of this comparison."""
-        from paddle_tpu.perf import analytic
-        chip = "cpu" if jax.default_backend() == "cpu" else "v5e"
+        from paddle_tpu.perf import analytic, roofline
+        chip = roofline.for_device_kind(jax.devices()[0].device_kind)
         layers, dkv = self._kv_dims
         restore = analytic.predicted_restore_ms(
             covered, layers, dkv, self.num_heads, self.kv_dtype, chip)
@@ -1009,8 +1008,8 @@ class DecodeEngine:
         ``(verdict, handoff_ms, recompute_ms)`` — the ``serving_disagg``
         bench gates both directions of this comparison, exactly like
         ``serving_kv_spill`` gates the local pair."""
-        from paddle_tpu.perf import analytic
-        chip = "cpu" if jax.default_backend() == "cpu" else "v5e"
+        from paddle_tpu.perf import analytic, roofline
+        chip = roofline.for_device_kind(jax.devices()[0].device_kind)
         layers, dkv = self._kv_dims
         handoff = analytic.predicted_handoff_ms(
             covered, layers, dkv, self.num_heads, self.kv_dtype, chip)
@@ -1576,17 +1575,17 @@ class DecodeEngine:
                 # the blobs stay in the tier — recovery re-seats can
                 # restore-hit the same spilled prefixes
                 self._pending_restores.clear()
-                # _place_cache: a sharded engine's rebuilt pool must come
+                # _new_cache: a sharded engine's rebuilt pool must come
                 # back with the same mesh placement or the (still-cached)
                 # compiled step would see new shardings and recompile
-                self._cache = self._place_cache(
-                    self._transformer.init_lm_cache_paged(
+                self._cache = self._new_cache(
+                    lambda: self._transformer.init_lm_cache_paged(
                         self.params, old.pool.num_blocks, self.block_size,
                         max_len=self.max_len, kv_dtype=self.kv_dtype,
                         num_heads=self.num_heads))
             else:
-                self._cache = self._place_cache(
-                    self._transformer.init_lm_cache(
+                self._cache = self._new_cache(
+                    lambda: self._transformer.init_lm_cache(
                         self.params, self.num_slots, self.max_len,
                         kv_dtype=self.kv_dtype, num_heads=self.num_heads))
         self._tokens[:] = 0
@@ -1619,6 +1618,8 @@ class DecodeEngine:
             # step below is the entire serving hot path.
             for b in self.prefill_buckets:
                 self._prefill_engine(b).warmup()
+            if not self._warm:
+                self._log_prefill_paths()
         if self._warm:
             return
         # resolve the kernel path NOW — warm-up is the step's one trace,
@@ -1631,24 +1632,26 @@ class DecodeEngine:
             dkv = int(_w_shape(enc[0]["attn"]["wk"])[1])
             blk_len = (self.block_size if self.kv_layout == "paged"
                        else self.max_len)
-            # covers() sees the PER-CHIP stripe (shards=): a kernel that
-            # covers 8 KV heads may not cover the 4-head shard — the
-            # resolved path below is what the compiled step actually took
-            self.decode_kernels = _dk.covers(
+            # decline_reason() sees the PER-CHIP stripe (shards=): a
+            # kernel that covers 8 KV heads may not cover the 4-head shard
+            # — the resolved path below is what the compiled step actually
+            # took, and a reference path always carries its sentence
+            self.decode_decline_reason = _dk.decline_reason(
                 self.num_heads, d, dkv, blk_len,
                 paged=self.kv_layout == "paged",
                 chunk=self._kk or 1,
                 quant=self.kv_dtype == "int8",
                 shards=self.mesh_shards)
-            if self.mesh_shards > 1 and not self.decode_kernels \
-                    and _dk.covers(self.num_heads, d, dkv, blk_len,
-                                   paged=self.kv_layout == "paged",
-                                   chunk=self._kk or 1,
-                                   quant=self.kv_dtype == "int8"):
-                logger.info(
-                    "decode[%s]: fused kernel covers the FULL trunk but "
-                    "not the per-chip Hkv/%d head stripe -> xla-ref",
-                    self.name, self.mesh_shards)
+            self.decode_kernels = self.decode_decline_reason is None
+            if not self.decode_kernels and _dk.decode_kernels_enabled():
+                # kernels asked for, a shape guard said no: the reference
+                # path is never silent — it writes the score matrix (and,
+                # paged, gathers every chain) the fused kernels exist to
+                # avoid
+                logger.warning(
+                    "decode[%s]: fused decode kernel declined -> XLA "
+                    "reference path: %s", self.name,
+                    self.decode_decline_reason)
         self.metrics.set_prefill_chunk(self.prefill_chunk)
         self.metrics.set_kv_dtype(self.kv_dtype)
         self.metrics.set_speculate_k(self.speculate_k)
@@ -1704,7 +1707,8 @@ class DecodeEngine:
                 "speculate_k=%d, mesh_shards=%d)", self.name,
                 self.num_slots, self.max_len, self.kv_layout,
                 self.kv_dtype,
-                "fused-pallas" if self.decode_kernels else "xla-ref",
+                "fused-pallas" if self.decode_kernels
+                else f"xla-ref ({self.decode_decline_reason})",
                 self.prefill_chunk, self.prefill_chunk_budget or "inf",
                 self.speculate_k, self.mesh_shards)
             return
@@ -1752,8 +1756,36 @@ class DecodeEngine:
                     "decode kernels %s, prefill buckets %s)", self.name,
                     self.num_slots, self.max_len, self.kv_layout,
                     self.kv_dtype,
-                    "fused-pallas" if self.decode_kernels else "xla-ref",
+                    "fused-pallas" if self.decode_kernels
+                    else f"xla-ref ({self.decode_decline_reason})",
                     list(self.prefill_buckets))
+
+    def _log_prefill_paths(self):
+        """Once, at warm-up: which attention path each legacy prefill
+        bucket's compiled pass resolved to and why — a warning when the
+        kernel routing was on and a shape guard still declined (that
+        reference path costs the [Tp, Tp] score matrix)."""
+        import importlib
+        flash = importlib.import_module(
+            "paddle_tpu.ops.pallas.flash_attention")
+        enc = self.params.get("enc") or []
+        if not enc:
+            return
+        d = int(_w_shape(self.params["src_emb"])[1])
+        dkv = int(_w_shape(enc[0]["attn"]["wk"])[1])
+        quant = self.kv_dtype == "int8"
+        asked = (flash.prefill_quant_enabled() if quant
+                 else flash.prefill_flash_enabled())
+        for b in self.prefill_buckets:
+            if quant:
+                why = (flash.prefill_quant_decline_reason(
+                    b, b, d, dkv, self.num_heads) if asked
+                    else "pallas_prefill_quant routing is off")
+            else:
+                why = flash.prefill_decline_reason(b, d // self.num_heads)
+            log = logger.warning if why and asked else logger.info
+            log("decode[%s]: prefill bucket %d -> %s", self.name, b,
+                "flash-pallas" if why is None else f"xla-ref ({why})")
 
     def lower(self, what="step"):
         """``jax.stages.Lowered`` of the slab decode step (default) or of

@@ -42,6 +42,7 @@ import threading
 import time
 
 from paddle_tpu.resilience import faults
+from paddle_tpu.utils.error import ConfigError
 from paddle_tpu.utils.logging import logger
 
 # the default replica: the built-in tiny-LM generation server (bring-up/
@@ -63,6 +64,8 @@ class _Replica:
         self.log_path = log_path
         self.role = role                  # disaggregated serving role
         #                                   (prefill|decode|mixed|None)
+        self.chip = None                  # TPU chip index this replica
+        #                                   owns (None: CPU fleet)
         self.proc = None
         self.port = None                  # read lazily from port_file
         self.state = "stopped"
@@ -82,8 +85,49 @@ class _Replica:
                 if self.port is not None else None)
 
 
+def pin_parent_to_cpu():
+    """A supervising parent never initialises an accelerator backend: a
+    chip belongs to one process at a time, and the replicas need them
+    all.  Whatever the parent computes itself (a smoke's ``lm_generate``
+    oracle) runs on the CPU platform, in THIS process only —
+    ``os.environ`` is not touched, so the replicas' environment still
+    names the accelerator.  Call before the parent's first backend use."""
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+
+
+def _probe_devices(env):
+    """``(platform, count)`` of the devices a process started with ``env``
+    sees — asked of a short-lived child, because a parent that looked for
+    itself would be left holding the chips its replicas need."""
+    proc = subprocess.run(
+        [sys.executable, "-c",
+         "import jax; d = jax.devices(); print(d[0].platform, len(d))"],
+        env=env, capture_output=True, text=True, timeout=300)
+    if proc.returncode:
+        raise RuntimeError("device probe failed: "
+                           + proc.stderr.strip()[-500:])
+    platform, count = proc.stdout.split()[-2:]
+    return platform, int(count)
+
+
+def _chip_env(chip):
+    """The environment that shows a process exactly ONE of the host's TPU
+    chips (libtpu's own variables; a one-chip, one-process slice)."""
+    return {"TPU_VISIBLE_CHIPS": str(chip),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1"}
+
+
 class ReplicaSupervisor:
     """Spawn + supervise ``n_replicas`` serving subprocesses.
+
+    On a TPU host every replica is given its own chip through the
+    environment it is spawned with (``_chip_env``): two processes cannot
+    share one, and a replica that saw them all would take them all.
+    Asking for more replicas than the host has chips fails at
+    ``start()``/``add_replica()`` with that sentence.  A fleet whose
+    environment names the CPU platform shares the host as before.
 
     cmd: argv AFTER the interpreter (default: the built-in
     ``--demo-generate`` server) — ``--port 0 --port-file <path>`` is
@@ -140,13 +184,44 @@ class ReplicaSupervisor:
         #                                     scaled-in then scaled-out
         #                                     replica is a NEW identity
         self._monitor = None
+        self._chips = None          # chips to hand out; resolved at start()
+        #                             (0 = CPU fleet, nothing to assign)
 
     # ------------------------------------------------------------ lifecycle
+
+    def _resolve_chips(self):
+        """How many chips there are to hand out: 0 when the fleet's
+        environment names the CPU platform, else whatever a probe child
+        counts on the TPU."""
+        if self._chips is None:
+            first = self.env.get("JAX_PLATFORMS", "").split(",")[0].strip()
+            if first == "cpu":
+                self._chips = 0
+            else:
+                platform, count = _probe_devices(self.env)
+                self._chips = count if platform == "tpu" else 0
+        return self._chips
+
+    def _claim_chip(self, rep, n_wanted):
+        """Give ``rep`` the lowest chip no other replica owns (kept across
+        its restarts).  ``n_wanted``: the fleet size being asked for."""
+        chips = self._resolve_chips()
+        if not chips or rep.chip is not None:
+            return
+        if n_wanted > chips:
+            raise ConfigError(
+                f"{self.name}: {n_wanted} replicas asked for, but this "
+                f"host has {chips} chip(s) — each replica needs its own "
+                "chip")
+        taken = {r.chip for r in self.replicas.values()}
+        rep.chip = min(set(range(chips)) - taken)
 
     def start(self):
         """Spawn every replica and start the crash monitor (idempotent)."""
         with self._lock:
             self._stopping = False
+            for rep in self.replicas.values():
+                self._claim_chip(rep, len(self.replicas))
             for rep in self.replicas.values():
                 if rep.proc is None or rep.proc.poll() is not None:
                     if not rep.storm_tripped:
@@ -171,9 +246,11 @@ class ReplicaSupervisor:
             pass
         rep.port = None
         log = open(rep.log_path, "ab")
+        env = self.env if rep.chip is None \
+            else dict(self.env, **_chip_env(rep.chip))
         rep.proc = subprocess.Popen(
             rep.cmd + ["--port", "0", "--port-file", rep.port_file],
-            stdout=log, stderr=subprocess.STDOUT, env=self.env)
+            stdout=log, stderr=subprocess.STDOUT, env=env)
         log.close()                 # the child holds its own fd now
         rep.started_at = time.monotonic()
         rep.expected_exit = False
@@ -286,6 +363,7 @@ class ReplicaSupervisor:
                            + (["--role", role] if role else []), pf,
                            os.path.join(self.base_dir, f"{rid}.log"),
                            role=role)
+            self._claim_chip(rep, len(self.replicas) + 1)
             self._spawn(rep)        # raises on failure: register nothing
             self._next_idx = i + 1
             self.replicas[rid] = rep
@@ -467,6 +545,7 @@ class ReplicaSupervisor:
                 rep.rid: {
                     "state": rep.state,
                     "role": rep.role,
+                    "chip": rep.chip,
                     "port": rep.port,
                     "pid": (rep.proc.pid if rep.proc is not None
                             and rep.proc.poll() is None else None),

@@ -1458,12 +1458,17 @@ def _smoke():
     """Fleet self-test (healthy_window.sh phase 10): 2 tiny demo
     replicas on ephemeral ports behind the router, concurrent streaming
     /v1/generate clients, kill -9 one replica MID-STREAM — every stream
-    must finish bit-identical to the local ``lm_generate`` oracle, the
-    router must report the failover, and the supervisor must restart the
-    victim to readiness.  ONE JSON line; returns the exit code."""
+    must finish bit-identical to the answer the HEALTHY fleet gave to the
+    same prompt before the kill (same program, same inputs: failover
+    continuation is host logic, and its oracle is the fleet itself — a
+    float32 ``lm_generate`` in this parent would not round like replicas
+    on the chip, and a parent that touched JAX would hold a chip they
+    need).  The router must report the failover, and the supervisor must
+    restart the victim to readiness.  This parent never initialises a
+    JAX backend.
+    ONE JSON line; returns the exit code."""
+    import urllib.request
     import numpy as np
-    import jax
-    from paddle_tpu.models import transformer
     from paddle_tpu.serving.fleet import ReplicaSupervisor
 
     errs = []
@@ -1471,9 +1476,9 @@ def _smoke():
                      "router, kill -9 mid-stream)",
            "vs_baseline": None}
     n_clients, n_tokens, max_len = 6, 24, 64
-    # the replicas' demo LM (server.py _demo_gen_batcher) — recomputed
-    # here for the oracle; the injected decode-step hang paces tokens
-    # (~25ms each) so the kill reliably lands MID-stream
+    # the replicas' demo LM (server.py _demo_gen_batcher); the injected
+    # decode-step hang paces tokens (~25ms each) so the kill reliably
+    # lands MID-stream
     extra = ["--gen-slots", "4", "--gen-max-len", str(max_len),
              "--gen-prefill-buckets", "8,16",
              "--gen-max-tokens", str(n_tokens),
@@ -1500,16 +1505,15 @@ def _smoke():
         rng = np.random.RandomState(0)
         prompts = [rng.randint(1, 256, 3 + 2 * i).astype(np.int64)
                    for i in range(n_clients)]
-        params = transformer.init(jax.random.PRNGKey(0), src_vocab=256,
-                                  trg_vocab=1, d_model=32, num_heads=2,
-                                  dff=64, enc_layers=2, dec_layers=0,
-                                  max_len=max_len)
         oracle = []
         for p in prompts:
-            ids = np.asarray(transformer.lm_generate(
-                params, p[None], max_len=max_len, num_heads=2,
-                prompt_lengths=np.asarray([p.size])))
-            oracle.append(ids[0, p.size:p.size + n_tokens].tolist())
+            req = urllib.request.Request(
+                f"{base}/v1/generate",
+                json.dumps({"prompt": p.tolist(),
+                            "max_tokens": n_tokens}).encode(),
+                {"Content-Type": "application/json"})
+            with urllib.request.urlopen(req, timeout=120) as r:
+                oracle.append(json.loads(r.read())["tokens"])
 
         results = [None] * n_clients
         first_token = threading.Barrier(n_clients + 1, timeout=120)
@@ -1568,7 +1572,6 @@ def _smoke():
             and r["done"] and r["done"]["tokens"] == oracle[i]
             for i, r in enumerate(results))
         snap = router.metrics.snapshot()
-        import urllib.request
         with urllib.request.urlopen(f"{base}/metrics", timeout=30) as r:
             mtext = r.read().decode()
         out.update(
@@ -1587,6 +1590,7 @@ def _smoke():
         fsnap = sup.snapshot()
         out["restarted_ready"] = bool(restarted)
         out["victim_restarts"] = fsnap["r0"]["restarts_total"]
+        out["replica_chips"] = {rid: r["chip"] for rid, r in fsnap.items()}
         out["backoff_delays_s"] = fsnap["r0"]["backoff_delays_s"]
         checks = [
             ok == n_clients,
@@ -1627,9 +1631,10 @@ def _smoke_disagg():
     code."""
     import urllib.request
     import numpy as np
+    from paddle_tpu.serving.fleet import ReplicaSupervisor, pin_parent_to_cpu
+    pin_parent_to_cpu()     # the oracle below must not take a replica's chip
     import jax
     from paddle_tpu.models import transformer
-    from paddle_tpu.serving.fleet import ReplicaSupervisor
 
     errs = []
     out = {"metric": "disaggregated serving smoke (prefill/decode "

@@ -2061,6 +2061,8 @@ def main(argv=None):
     ap.add_argument("--obs-trace-ring", type=int,
                     default=FLAGS.obs_trace_ring)
     args = ap.parse_args(argv)
+    from paddle_tpu.utils.flags import set_compilation_cache_dir
+    set_compilation_cache_dir()
     # kernel selection is read at TRACE time — push the flags before any
     # engine is constructed
     FLAGS.pallas_decode = args.pallas_decode

@@ -124,9 +124,7 @@ class DraftTrunk:
         self._warm = False
         self._epoch = 0
         self._epoch_lock = threading.Lock()
-        self._cache = self._place_cache(
-            transformer.init_lm_cache(params, self.num_slots,
-                                      self.max_len))
+        self._cache = self._new_cache()
 
         axis = self._shard_axis
         heads = (self.num_heads // self.mesh_shards if axis is not None
@@ -173,16 +171,15 @@ class DraftTrunk:
         if warm:
             self.warmup()
 
-    def _place_cache(self, cache):
-        """Shard a fresh draft slab over the mesh (trailing head-stripe
-        axis, like the target's) — identity when unsharded."""
+    def _new_cache(self):
+        """A fresh draft slab; on a mesh, born as per-chip head stripes
+        like the target's (``parallel.sharding.new_lm_cache``)."""
+        def build():
+            return transformer.init_lm_cache(self.params, self.num_slots,
+                                             self.max_len)
         if self._shard_axis is None:
-            return cache
-        from jax.sharding import NamedSharding
-        specs = self._psh.lm_cache_specs(cache, self._shard_axis)
-        return jax.tree_util.tree_map(
-            lambda l, s: jax.device_put(l, NamedSharding(self.mesh, s)),
-            cache, specs)
+            return build()
+        return self._psh.new_lm_cache(build, self.mesh, self._shard_axis)
 
     @property
     def trace_count(self):
@@ -214,8 +211,7 @@ class DraftTrunk:
         in the engine and is re-seeded by the re-seat paths."""
         with self._epoch_lock:
             self._epoch += 1
-            self._cache = self._place_cache(transformer.init_lm_cache(
-                self.params, self.num_slots, self.max_len))
+            self._cache = self._new_cache()
 
     def warmup(self):
         """Trace the rollout exactly once at the live shapes.

@@ -34,9 +34,11 @@ from paddle_tpu.trainer.checkpoint import save_checkpoint, load_checkpoint
 from paddle_tpu.utils.error import ConfigError
 from paddle_tpu.utils.logging import logger
 from paddle_tpu.utils.stats import timer, global_stats
+from paddle_tpu.ops import rnn as _rnn_ops
 from paddle_tpu.parallel import (
     make_mesh, param_shardings, batch_shardings, replicated_shardings,
     shard_params)
+from paddle_tpu.parallel.mesh import AXIS_DATA
 
 
 
@@ -476,7 +478,10 @@ class SGD:
             # Python body runs only under tracing: this is the trace-count
             # hook precompile()'s no-retrace guarantee is asserted against
             self.trace_count += 1
-            return base_step(params, opt_state, state, feed, rng)
+            # a multi-device mesh: the fused RNN kernels take their batch
+            # shard through shard_map (GSPMD cannot partition them)
+            with _rnn_ops.batch_sharded_over(self.mesh, AXIS_DATA):
+                return base_step(params, opt_state, state, feed, rng)
 
         if self.mesh is None:
             self._step_fn = jax.jit(
@@ -514,6 +519,13 @@ class SGD:
         ss = replicated_shardings(self.model_state, self.mesh)
         fs = batch_shardings(feed_example, self.mesh)
         rs = replicated_shardings(jnp.zeros(2, jnp.uint32), self.mesh)
+        if not self._multiprocess:
+            # place the optimizer and model state where the step returns
+            # them: under jax 0.9 an array's mesh is part of its traced
+            # type, so fresh single-device state on the first call and
+            # mesh-placed state on the second would trace the step twice
+            self.opt_state = jax.device_put(self.opt_state, os_)
+            self.model_state = jax.device_put(self.model_state, ss)
         self._step_fn = jax.jit(
             step,
             in_shardings=(ps, os_, ss, fs, rs),
@@ -564,7 +576,7 @@ class SGD:
         ``train()``/``train_one_batch()`` — a subsequent pass over those
         buckets triggers no new traces (assert with ``trace_count``).
         Returns the number of NEW executables compiled.  Pair with the
-        ``jax_compilation_cache_dir`` flag (utils/flags.py) to persist
+        persistent compile cache (utils/flags.set_compilation_cache_dir) to keep
         the compilations across process restarts.
         """
         n_new = 0
@@ -1098,7 +1110,9 @@ class SGD:
             if self.compute_dtype is not None:
                 params = self._cast_compute(params)
                 feed = self._cast_compute(feed)
-            out = self.topology.apply(params, feed, mode="test", state=state)
+            with _rnn_ops.batch_sharded_over(self.mesh, AXIS_DATA):
+                out = self.topology.apply(params, feed, mode="test",
+                                          state=state)
             outs = out if isinstance(out, tuple) else (out,)
             cost_vals = outs[:len(self.costs)]
             # f32 reduction regardless of compute dtype (same rationale as

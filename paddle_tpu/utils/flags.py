@@ -11,13 +11,13 @@ look any flag up here and learn its fate.
 
 import argparse
 import dataclasses
+import os
 from typing import Optional
 
 
 @dataclasses.dataclass
 class Flags:
-    # ---- device / precision (reference: use_gpu, gpu_id, trainer_count)
-    use_tpu: bool = True            # use_gpu analog; False pins CPU
+    # ---- precision (the device is JAX's: JAX_PLATFORMS, not a flag)
     dtype: str = "float32"          # parameter dtype ("real" in the reference)
     compute_dtype: str = "bfloat16"  # matmul/conv compute dtype on TPU
     seed: int = 1                   # reference: --seed (0 = time-based)
@@ -76,11 +76,6 @@ class Flags:
     #                                 --prefetch (SGD.train(prefetch=N),
     #                                 data/prefetch.py device pipeline)
     prefetch_depth: int = 2
-    # opt-in persistent XLA compilation cache: compiled step executables
-    # (incl. SGD.precompile's per-bucket programs) are written here and
-    # reused across process restarts — the AOT warm-up then costs a disk
-    # read instead of a compile.  None = off (JAX default).
-    jax_compilation_cache_dir: Optional[str] = None
 
     # ---- serving runtime (serving/: dynamic batcher + HTTP front-end;
     # the reference served through C++ services over the C API with no
@@ -305,8 +300,6 @@ class Flags:
                           else self.compute_dtype)
         if self.debug_nans:
             jax.config.update("jax_debug_nans", True)
-        if self.jax_compilation_cache_dir:
-            set_compilation_cache_dir(self.jax_compilation_cache_dir)
         if self.resilience_fault_spec:
             from paddle_tpu.resilience import faults
             faults.install_spec(self.resilience_fault_spec)
@@ -316,17 +309,26 @@ class Flags:
                          capacity=self.obs_trace_ring)
 
 
-def set_compilation_cache_dir(path):
-    """Wire the opt-in persistent XLA compilation cache (docs/
-    input_pipeline.md).  min_compile_time is dropped to 0 so every bucket
-    executable persists, not just the slow ones — the whole point is a
-    cold process skipping ALL bucket compiles."""
+# the compile cache's place when the environment names none: one fixed,
+# git-ignored directory inside the checkout.  The path is part of the
+# cache key, so it is never built from a temporary name, a pid or the
+# time.
+_CACHE_DIR = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))), ".jax_cache")
+
+
+def set_compilation_cache_dir():
+    """THE one place the persistent XLA compilation cache is wired; the
+    trainer CLI, the serving CLI, bench.py and chip_smoke.py call it at
+    start.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it
+    itself and nothing is set in code; otherwise the cache lives at the
+    fixed in-checkout ``.jax_cache``.  Returns the directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
     import jax
-    jax.config.update("jax_compilation_cache_dir", str(path))
-    try:
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except AttributeError:      # older jax: the dir alone still works
-        pass
+    jax.config.update("jax_compilation_cache_dir", _CACHE_DIR)
+    return _CACHE_DIR
 
 
 # Per-flag documentation: {field name: (help, reference cmd_parameter
@@ -336,7 +338,6 @@ def set_compilation_cache_dir(path):
 # tests/test_flags_doc.py fails when a Flags field is added without a
 # row here or without regenerating the doc.
 FLAG_DOCS = {
-    "use_tpu": ("use the TPU backend; False pins CPU", "use_gpu"),
     "dtype": ("parameter dtype", 'real ("paddle float")'),
     "compute_dtype": ("matmul/conv compute dtype on TPU; auto = bf16 on "
                       "TPU, f32 on CPU", "—"),
@@ -401,9 +402,6 @@ FLAG_DOCS = {
                         "async_load_data (DoubleBuffer)"),
     "prefetch_depth": ("batches converted + H2D-transferred ahead on the "
                        "prefetch thread", "—"),
-    "jax_compilation_cache_dir": ("opt-in persistent XLA compile cache "
-                                  "(AOT bucket warm-up survives restarts)",
-                                  "—"),
     "serving_port": ("HTTP port for python -m paddle_tpu.serving", "—"),
     "serving_buckets": ("batch bucket ladder (comma ints) the serving "
                         "engine AOT-compiles", "—"),
@@ -701,7 +699,7 @@ def flags_table_md():
 # Reference flags with no runtime role here, and why — the lookup table for
 # migrating users (reference Flags.cpp names):
 SUBSUMED = {
-    "use_gpu": "use_tpu (XLA backend selection)",
+    "use_gpu": "the backend is JAX's choice (JAX_PLATFORMS); no flag",
     "gpu_id": "device choice is XLA's; use JAX_PLATFORMS / mesh flags",
     "trainer_count": "data_parallel mesh axis",
     "parallel_nn": "model_parallel mesh axis (sharding rules)",

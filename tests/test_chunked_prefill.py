@@ -99,11 +99,23 @@ def _drive(bat, cases, stagger_s=0.002):
 # ----------------------------------------------------- step-level units
 
 
-def test_chunk_step_matches_prefill_bit_identical(params):
+def test_chunk_step_matches_prefill(params):
     """Feeding a prompt through lm_decode_chunk_slots in K-token chunks
-    produces BIT-IDENTICAL K/V and last-position logits to the batched
+    produces the same K/V and last-position logits as the batched
     lm_prefill pass — the numerics fact the whole unified engine rests
-    on."""
+    on.
+
+    Bit for bit where the arithmetic is the same: layer 0's K/V are
+    projections of identical inputs.  Past the first attention the two
+    paths no longer round alike under the XLA of jax 0.9.0: the batched
+    pass softmaxes over Tp columns, the chunk step over max_len columns
+    whose masked tail contributes exp() == 0.0 exactly, and the CPU
+    backend sums rows of different widths in different tree orders
+    (measured: 1e-6 in layer-1 K/V, 1e-7 in the logits).  So deeper state
+    and the logits are held to float32 rounding, 1e-5 — a wrong position,
+    mask or lane lands at 1e-1.  The engine's own guarantee — streams
+    token-identical to lm_generate — is pinned by the engine tests
+    below."""
     rng = np.random.RandomState(0)
     prompt = _prompt(rng, 10)
     hidden, pc = transformer.lm_prefill(params, prompt[None], MAX_LEN,
@@ -124,12 +136,17 @@ def test_chunk_step_matches_prefill_bit_identical(params):
         out, cache = transformer.lm_decode_chunk_slots(
             params, toks, poss, lens, cache, HEADS)
         p += n
-    assert np.array_equal(np.asarray(out)[0], ref_logits[0])
+    np.testing.assert_allclose(np.asarray(out)[0], ref_logits[0],
+                               rtol=1e-5, atol=1e-5)
     for layer, (c, ref) in enumerate(zip(cache, pc)):
-        assert np.array_equal(np.asarray(c["k"])[0, :prompt.size],
-                              np.asarray(ref["k"])[0, :prompt.size]), layer
-        assert np.array_equal(np.asarray(c["v"])[0, :prompt.size],
-                              np.asarray(ref["v"])[0, :prompt.size]), layer
+        for kv in "kv":
+            got = np.asarray(c[kv])[0, :prompt.size]
+            want = np.asarray(ref[kv])[0, :prompt.size]
+            if layer == 0:
+                assert np.array_equal(got, want), kv
+            else:
+                np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5,
+                                           err_msg=f"layer {layer} {kv}")
 
 
 def test_chunk_step_len1_matches_tq1_step(params):

@@ -1,11 +1,10 @@
-"""CPU dry-run of the healthy-window playbook (VERDICT r5, Next round #1:
-"zero chip-window minutes debugging the harness").
+"""CPU dry-run of the chip playbook ("zero chip minutes debugging the
+harness").
 
 Executes `healthy_window.sh` end-to-end with HW_DRYRUN=1 — every phase
 runs its real command on the CPU backend at smoke scale — and asserts
 each phase left its artifact behind.  A path typo, env-plumbing break, or
-rc-logging bug in the playbook is caught here, not in a five-minute chip
-window.
+rc-logging bug in the playbook is caught here, not on the chip.
 
 Slow lane only (several minutes of real subprocess work): run with
 `pytest -m slow tests/test_healthy_window.py`.
@@ -29,8 +28,7 @@ def test_dryrun_executes_every_phase(tmp_path):
     env.update(HW_DRYRUN="1", JAX_PLATFORMS="cpu")
     # a dry run must be hermetic: no JAX persistent cache dir leaking in
     env.pop("BENCH_PROFILE_BASE", None)
-    committed = [os.path.join(_ROOT, p)
-                 for p in ("bench_cache.json", "BENCH_ANALYTIC_r06.json")]
+    committed = [os.path.join(_ROOT, "BENCH_ANALYTIC_r06.json")]
     mtimes_before = {p: os.path.getmtime(p) for p in committed
                      if os.path.exists(p)}
     proc = subprocess.run(
@@ -44,7 +42,7 @@ def test_dryrun_executes_every_phase(tmp_path):
                  "bench_scan_baselines.json", "bench_bf16.json",
                  "bench_int8.json", "diff_cpu.npz", "diff_tpu.npz",
                  "tpu_differential_pytest.log", "nmt_scale.json",
-                 "perf_report.md", "analytic.json",
+                 "analytic.json",
                  "analytic_snapshot.json", "serving_smoke.json",
                  "serving_gen_smoke.json", "chaos_smoke.json",
                  "fleet_smoke.json", "paged_smoke.json",
@@ -252,9 +250,8 @@ def test_dryrun_executes_every_phase(tmp_path):
     assert "errors" not in qpf, qpf
     assert "dryrun=1" in (art / "WINDOW_DONE").read_text()
 
-    # a dry run must never rewrite the committed perf artifacts (cpu rows
-    # would shadow real measurements) — guarded by BENCH_NO_CACHE and the
-    # dryrun-specific --out path above
+    # a dry run must never rewrite the committed analytic snapshot —
+    # guarded by the dryrun-specific --out path above
     for p, before in mtimes_before.items():
         assert os.path.getmtime(p) == before, (
             f"dry run rewrote committed perf artifact {p}")

@@ -57,7 +57,7 @@ def test_flags_surface_covers_reference_names():
     from paddle_tpu.utils import flags as F
     import dataclasses
     fields = {f.name for f in dataclasses.fields(F.Flags)}
-    renames = {"use_gpu": "use_tpu", "trainer_id": "process_id",
+    renames = {"trainer_id": "process_id",
                "num_gradient_servers": "num_processes",
                "trainer_count": "data_parallel"}
     reference_flags = [

@@ -115,3 +115,54 @@ def test_vmem_guard_routes_oversized_to_scan(monkeypatch):
     assert pl.supported(64, 1280, "tanh", "sigmoid", "tanh", None)
     monkeypatch.setenv("PADDLE_TPU_KERNEL_VMEM_MB", "1")
     assert not pl.supported(64, 512, "tanh", "sigmoid", "tanh", None)
+
+
+def test_fused_kernel_takes_its_batch_shard_under_a_data_mesh(np_rng):
+    """GSPMD cannot partition a Mosaic kernel (on the chip a batch-sharded
+    jit raises "Mosaic kernels cannot be automatically partitioned"), so
+    under ``rnn.batch_sharded_over`` the kernels run per batch shard in a
+    shard_map: forward and every gradient — the replicated weights'
+    included, summed over the shards — equal the single-device scan."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from paddle_tpu.parallel.mesh import AXIS_DATA, MeshConfig, make_mesh
+    mesh = make_mesh(MeshConfig(data=2), devices=jax.devices()[:2])
+    x = jnp.asarray(np_rng.randn(2 * B, T, 4 * D) * 0.3, jnp.float32)
+    lengths = jnp.asarray(np_rng.randint(1, T + 1, (2 * B,)), jnp.int32)
+    w_r = jnp.asarray(np_rng.randn(D, 4 * D) * 0.1, jnp.float32)
+    checks = [jnp.asarray(np_rng.randn(D) * 0.1, jnp.float32)
+              for _ in range(3)]
+
+    def loss(x, w_r, checks):
+        out, final = rnn.lstm(SequenceBatch(data=x, lengths=lengths), w_r,
+                              check_i=checks[0], check_f=checks[1],
+                              check_o=checks[2])
+        return jnp.sum(out.data ** 2) + jnp.sum(final.c ** 2)
+
+    grad = jax.value_and_grad(loss, argnums=(0, 1, 2))
+    prior = rnn.FUSED_LSTM
+    try:
+        rnn.FUSED_LSTM = "always"
+        before = rnn.FUSED_DISPATCH_COUNT
+
+        def sharded(x, w_r, checks):
+            with rnn.batch_sharded_over(mesh, AXIS_DATA):
+                return grad(x, w_r, checks)
+
+        got = jax.jit(sharded, in_shardings=(
+            NamedSharding(mesh, P(AXIS_DATA)), NamedSharding(mesh, P()),
+            NamedSharding(mesh, P())))(x, w_r, checks)
+        assert rnn.FUSED_DISPATCH_COUNT == before + 1
+        rnn.FUSED_LSTM = "0"
+        want = jax.jit(grad)(x, w_r, checks)
+    finally:
+        rnn.FUSED_LSTM = prior
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-4)
+    # the guard judges the PER-SHARD batch: 8 rows a shard is supported,
+    # a batch that does not split evenly is not
+    with rnn.batch_sharded_over(mesh, AXIS_DATA):
+        assert rnn._local_batch(2 * B) == B
+        assert rnn._local_batch(2 * B + 1) == 0
+    assert rnn._local_batch(2 * B) == 2 * B

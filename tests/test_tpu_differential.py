@@ -6,7 +6,7 @@ Two-process protocol (the suite pins jax to the virtual CPU mesh, and a
 platform cannot be re-pinned after backend init):
 
     python -m paddle_tpu.testing.tpu_diff cpu     /tmp/diff_cpu.npz
-    python -m paddle_tpu.testing.tpu_diff default /tmp/diff_tpu.npz  # on TPU
+    python -m paddle_tpu.testing.tpu_diff tpu     /tmp/diff_tpu.npz  # on the chip
     PADDLE_TPU_DIFF="/tmp/diff_cpu.npz:/tmp/diff_tpu.npz" pytest \
         tests/test_tpu_differential.py
 
