@@ -35,6 +35,13 @@ Core surface:
 * ``start_span`` / ``Span.end`` — the explicit pair for ASYNC seams
   (queue waits, slot lifetimes, futures) where begin and end live on
   different threads; these never touch the context variable.
+* ``phase(name, **attrs)`` — context manager for the PER-STEP host
+  phases of a hot loop (the generation loop, the trainer's batch loop):
+  always a ``jax.profiler.TraceAnnotation``, so a live profiler session
+  gets the phase in the host plane of its ``.xplane.pb``, on the
+  profiler's clock, next to the device operations — tracer on or off;
+  with the tracer on, also a record in a ring of its own (never the
+  request spans' ring).  Never per token or per slot.
 * ``extract(header)`` / ``inject(headers)`` — W3C-traceparent-style
   cross-process propagation (``00-<trace_id>-<span_id>-01``): the router
   injects on its upstream dispatches, the replica server extracts, and
@@ -217,6 +224,9 @@ class Tracer:
         self._lock = threading.Lock()
         self._done = collections.deque(maxlen=self.capacity)
         self._active = {}
+        # per-step loop phases (phase()): a ring of their own, so a hot
+        # loop's 400 phases a second never evict a request span
+        self._phases = collections.deque(maxlen=self.capacity)
         self.started_total = 0
         self.dropped_total = 0      # ring overwrites (oldest span lost)
 
@@ -240,6 +250,15 @@ class Tracer:
                 spans += [s.to_dict(self.process)
                           for s in self._active.values()]
         return spans
+
+    def phases(self):
+        """The held loop phases as dicts, oldest first."""
+        with self._lock:
+            rows = list(self._phases)
+        return [{"name": name, "process": self.process, "t_start": t0,
+                 "t_end": t1,
+                 "step": attrs.get("step", attrs.get("step_num")),
+                 "attrs": dict(attrs)} for name, t0, t1, attrs in rows]
 
     def slowest(self, n=5):
         """The worst recent requests by wall time and by TTFT:
@@ -364,6 +383,63 @@ def instant(name, ctx=None, **attrs):
     return s
 
 
+class _Phase:
+    """One host phase of one loop iteration (see ``phase``)."""
+
+    __slots__ = ("name", "attrs", "_ann", "_t0")
+
+    def __init__(self, name, attrs):
+        self.name, self.attrs, self._t0 = name, attrs, None
+        self._ann = _annotation(name, **attrs)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        if _tracer is not None:
+            self._t0 = time.time()
+        return self
+
+    def set(self, **attrs):
+        """Attrs known only when the phase's work is done (how many were
+        admitted, emitted, preempted)."""
+        self._ann.set_metadata(**attrs)
+        if self._t0 is not None:
+            self.attrs.update(attrs)
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        t = _tracer
+        if self._t0 is not None and t is not None:
+            with t._lock:
+                t._phases.append((self.name, self._t0, time.time(),
+                                  self.attrs))
+        return False
+
+
+# jax.profiler.TraceAnnotation, imported at the first phase(): a process
+# that only routes (router.py) imports this module and never jax
+_annotation = None
+
+
+def phase(name, **attrs):
+    """Context manager for a per-step host phase of a hot loop.
+
+    Always enters a ``jax.profiler.TraceAnnotation(name, **attrs)``: inert
+    (well under a microsecond) unless a profiler session is live, and then
+    the phase lies in the session's host plane on the profiler's clock,
+    with ``attrs`` as its stats — whether or not this module's tracer is
+    enabled.  With the tracer on, the phase (name, start, end, attrs) is
+    also kept in the tracer's phase ring (``/debug/traces`` ``"phases"``,
+    the ``loop`` track of ``chrome_trace()``); with it off, no lock, no
+    context variable and no ring is touched.  Phases of one iteration
+    share the attr ``step``, the ordinal of the device step they
+    surround."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation as _annotation
+    return _Phase(name, attrs)
+
+
 # ------------------------------------------------------------ propagation
 
 
@@ -417,7 +493,7 @@ def debug_payload(n_slowest=5):
     t = _tracer
     if t is None:
         return {"enabled": False, "process": None, "spans": [],
-                "slowest": {"wall": [], "ttft": []}}
+                "phases": [], "slowest": {"wall": [], "ttft": []}}
     return {
         "enabled": True,
         "process": t.process,
@@ -426,22 +502,36 @@ def debug_payload(n_slowest=5):
         "started_total": t.started_total,
         "dropped_total": t.dropped_total,
         "spans": t.snapshot(),
+        "phases": t.phases(),
         "slowest": t.slowest(n_slowest),
     }
 
 
-def chrome_trace(spans=None):
+def chrome_trace(spans=None, phases=None):
     """Span dicts -> a Chrome trace-event JSON object (the
     ``chrome://tracing`` / Perfetto format): one "X" complete event per
     span, "i" instants for span events, and metadata naming processes
     (router / each replica) and tracks (decode slots).  ``spans`` may be
     a MERGED list from several processes' ``/debug/traces`` — that is
-    the point: one file shows the whole fleet on one timeline."""
+    the point: one file shows the whole fleet on one timeline.
+    ``phases`` (the payload's ``"phases"``; this process's own when
+    ``spans`` is None too) lie on one ``loop`` track per process."""
     if spans is None:
         spans = snapshot()
+        if phases is None and _tracer is not None:
+            phases = _tracer.phases()
     pids = {}
     tid_names = {}          # (pid, tid) -> track name
     events = []
+    for ph in phases or ():
+        pid = pids.setdefault(ph.get("process") or "unknown", len(pids) + 1)
+        tid_names[(pid, 2)] = "loop"
+        events.append({
+            "name": ph["name"], "cat": "loop", "ph": "X",
+            "ts": round(ph["t_start"] * 1e6, 3),
+            "dur": round(max(0.0, ph["t_end"] - ph["t_start"]) * 1e6, 3),
+            "pid": pid, "tid": 2, "args": dict(ph.get("attrs", {})),
+        })
     for s in spans:
         proc = s.get("process") or "unknown"
         pid = pids.setdefault(proc, len(pids) + 1)
@@ -481,9 +571,10 @@ def chrome_trace(spans=None):
     return {"traceEvents": meta + events, "displayTimeUnit": "ms"}
 
 
-def dump_chrome_trace(path, spans=None):
-    """Write ``chrome_trace(spans)`` to ``path``; returns the object."""
-    obj = chrome_trace(spans)
+def dump_chrome_trace(path, spans=None, phases=None):
+    """Write ``chrome_trace(spans, phases)`` to ``path``; returns the
+    object."""
+    obj = chrome_trace(spans, phases)
     with open(path, "w") as f:
         json.dump(obj, f)
     return obj

@@ -483,7 +483,7 @@ def decode_attention_slab(q, k, v, positions, num_heads, *, block_k=None,
         ],
     )
     out = pl.pallas_call(
-        kernel, grid_spec=grid_spec,
+        kernel, grid_spec=grid_spec, name="decode_attn_slab",
         out_shape=jax.ShapeDtypeStruct((s, num_heads, dh), q.dtype),
         cost_estimate=kernel_cost(
             s, t, d, dkv, q.dtype.itemsize,
@@ -558,7 +558,7 @@ def decode_attention_paged(q, k, v, positions, tables, num_heads, *,
         ],
     )
     out = pl.pallas_call(
-        kernel, grid_spec=grid_spec,
+        kernel, grid_spec=grid_spec, name="decode_attn_paged",
         out_shape=jax.ShapeDtypeStruct((s, num_heads, dh), q.dtype),
         cost_estimate=kernel_cost(
             s, nb_row * bs, d, dkv, q.dtype.itemsize,
@@ -632,7 +632,7 @@ def decode_attention_slab_chunk(q, k, v, qpos, num_heads, *,
         ],
     )
     out = pl.pallas_call(
-        kernel, grid_spec=grid_spec,
+        kernel, grid_spec=grid_spec, name="decode_attn_slab_chunk",
         out_shape=jax.ShapeDtypeStruct((s, kk * num_heads, dh), q.dtype),
         cost_estimate=kernel_cost(
             s, t, d, dkv, q.dtype.itemsize, tq=kk,
@@ -701,7 +701,7 @@ def decode_attention_paged_chunk(q, k, v, qpos, tables, num_heads, *,
         ],
     )
     out = pl.pallas_call(
-        kernel, grid_spec=grid_spec,
+        kernel, grid_spec=grid_spec, name="decode_attn_paged_chunk",
         out_shape=jax.ShapeDtypeStruct((s, kk * num_heads, dh), q.dtype),
         cost_estimate=kernel_cost(
             s, nb_row * bs, d, dkv, q.dtype.itemsize, tq=kk,

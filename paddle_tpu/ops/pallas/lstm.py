@@ -176,6 +176,7 @@ def _fwd(xs, w_r, checks, mask, interpret, save_residuals):
 
     outs = pl.pallas_call(
         kernel,
+        name="lstm_fwd",
         grid=(nt,),
         in_specs=[
             pl.BlockSpec((1, b, g), lambda t: (t, 0, 0)),
@@ -208,6 +209,7 @@ def _bwd(interpret, res, g_out):
 
     dxs, dwr, dchk = pl.pallas_call(
         functools.partial(_bwd_kernel, d=d, nt=nt),
+        name="lstm_bwd",
         grid=(nt,),
         in_specs=[
             pl.BlockSpec((1, b, gcols), lambda j: (nt - 1 - j, 0, 0)),

@@ -143,6 +143,7 @@ def _fwd(xs4, w_r4, checks, mask, interpret, save_residuals):
 
     outs = pl.pallas_call(
         kernel,
+        name="lstm_blocked",
         grid=(nt, nblk),
         in_specs=[
             pl.BlockSpec((1, b, 4, _BLK), lambda t, j: (t, 0, 0, j)),

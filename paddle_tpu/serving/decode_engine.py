@@ -1449,33 +1449,40 @@ class DecodeEngine:
         watchdog-abandoned step that finishes late consumes its own
         (already orphaned) cache buffer and then discards itself,
         instead of poisoning the rebuilt slab."""
-        epoch = self._epoch
-        params, cache = self.params, self._cache
-        tokens, pos = self._tokens.copy(), self._pos.copy()
-        lens = self._len.copy() if self.prefill_chunk else None
-        # verify spans armed for THIS step (speculative mode); popped
-        # with the snapshot so an eviction racing the step can never
-        # resurrect a stale acceptance
-        spec_armed = {}
-        if self._draft is not None:
-            spec_armed, self._spec_armed = self._spec_armed, {}
-        # the fault point sits at the device-step boundary: a hang here
-        # models a wedged device step for the watchdog to catch
-        faults.hit("serving.decode_step")
-        t0 = time.perf_counter()
-        if self.prefill_chunk and self.kv_layout == "paged":
-            nxt, cache = self._jit_step(params, cache, tokens, pos, lens,
-                                        self._paged.tables.copy())
-        elif self.prefill_chunk:
-            nxt, cache = self._jit_step(params, cache, tokens, pos, lens)
-        elif self.kv_layout == "paged":
-            # block tables ride as DATA (snapshotted, like tokens/pos):
-            # table churn between steps never retraces
-            nxt, cache = self._jit_step(params, cache, tokens, pos,
-                                        self._paged.tables.copy())
-        else:
-            nxt, cache = self._jit_step(params, cache, tokens, pos)
-        nxt = np.asarray(nxt)
+        # the two host phases of a device step (obs/trace.py phase()), by
+        # the ordinal the step will be counted under: handing the step
+        # over, and waiting for its tokens.  A supervised step runs both
+        # on the watchdog's thread.
+        step = self.metrics.decode_steps_total
+        with obstrace.phase("engine.step.dispatch", step=step) as ph:
+            epoch = self._epoch
+            params, cache = self.params, self._cache
+            # the host arrays the call takes: snapshotted, so an eviction
+            # racing the step changes nothing it reads
+            tokens = self._tokens.copy()
+            host = [tokens, self._pos.copy()]
+            lens = self._len.copy() if self.prefill_chunk else None
+            if lens is not None:
+                host.append(lens)
+            if self.kv_layout == "paged":
+                # block tables ride as DATA (snapshotted, like
+                # tokens/pos): table churn between steps never retraces
+                host.append(self._paged.tables.copy())
+            # verify spans armed for THIS step (speculative mode); popped
+            # with the snapshot so an eviction racing the step can never
+            # resurrect a stale acceptance
+            spec_armed = {}
+            if self._draft is not None:
+                spec_armed, self._spec_armed = self._spec_armed, {}
+            # the fault point sits at the device-step boundary: a hang
+            # here models a wedged device step for the watchdog to catch
+            faults.hit("serving.decode_step")
+            t0 = time.perf_counter()
+            ph.set(host_args=len(host),
+                   host_arg_bytes=sum(a.nbytes for a in host))
+            nxt, cache = self._jit_step(params, cache, *host)
+        with obstrace.phase("engine.step.wait", step=step):
+            nxt = np.asarray(nxt)
         with self._epoch_lock:
             if epoch != self._epoch:
                 raise RuntimeError(
@@ -2526,7 +2533,7 @@ class GenerationBatcher:
         decode rows with chunking rows never retraces.  A slot that gets
         no lanes this step (budget spent) still advances one
         teacher-forced token through its lane 0, so feeding always makes
-        progress."""
+        progress.  Returns the lanes armed."""
         kk = self.engine.prefill_chunk
         budget = self.engine.prefill_chunk_budget
         used = 0
@@ -2542,6 +2549,7 @@ class GenerationBatcher:
             used += n
             req.slot_span.event("prefill_chunk", lanes=int(n),
                                 pos=int(self.engine._pos[slot]))
+        return used
 
     def _load_spec(self):
         """Speculative mode, strictly between steps (after
@@ -2713,28 +2721,72 @@ class GenerationBatcher:
                         "generation batcher closed without drain"))
                 self._preempted, self._waiting = [], collections.deque()
                 return
-            # host-tier restores land HERE — strictly between steps: the
-            # staged chunks write into their claimed blocks and the
-            # chain publishes into the prefix index, so a deferred
-            # request's next retry seats as an ordinary resident hit
-            self.engine.poll_restores()
-            # cross-replica exports land here too: same between-steps
-            # seam, same committed-cache safety as the restore commits
-            self._serve_exports()
-            self._admit_from_queue(block=not self._by_slot)
             if not self._by_slot:
-                if self._closed.is_set() and self._q.empty() \
-                        and not self._waiting and not self._preempted:
-                    return
-                if self._waiting:
-                    # every runnable request is deferred (restore in
-                    # flight / pool dry): wait a tick on the transfer
-                    # thread instead of hot-spinning the retry loop
-                    self.engine.poll_restores(timeout=0.005)
-                continue
-            sup = self.supervisor
+                # no slot is active: until a request seats, the loop
+                # waits for work (the blocking queue read, mostly)
+                with obstrace.phase("gen.loop.nowork"):
+                    self._admit(block=True)
+                if not self._by_slot:
+                    if self._closed.is_set() and self._q.empty() \
+                            and not self._waiting and not self._preempted:
+                        return
+                    if self._waiting:
+                        # every runnable request is deferred (restore in
+                        # flight / pool dry): wait a tick on the transfer
+                        # thread instead of hot-spinning the retry loop
+                        self.engine.poll_restores(timeout=0.005)
+                    continue
+            # the phases of one iteration (obs/trace.py phase()) share
+            # ``step``: the ordinal the device step they surround will be
+            # counted under
+            step = self.engine.metrics.decode_steps_total
+            with obstrace.phase("gen.loop.iter", step=step,
+                                active=len(self._by_slot)):
+                self._iterate(step)
+
+    def _admit(self, block):
+        """What lands strictly between steps, in order."""
+        # host-tier restores land HERE: the staged chunks write into
+        # their claimed blocks and the chain publishes into the prefix
+        # index, so a deferred request's next retry seats as an ordinary
+        # resident hit
+        self.engine.poll_restores()
+        # cross-replica exports land here too: same between-steps seam,
+        # same committed-cache safety as the restore commits
+        self._serve_exports()
+        self._admit_from_queue(block=block)
+
+    def _step_failed(self, e):
+        """A device operation of this iteration raised: isolate it to
+        the requests in flight; the loop keeps serving."""
+        sup = self.supervisor
+        if sup is None:
+            self._fail_all_inflight(e)
+            return
+        opened = sup.breaker.record_failure()
+        self._snap_breaker()
+        if opened:
+            logger.warning(
+                "%s: circuit breaker OPEN after %d consecutive "
+                "step failures; shedding new admissions for "
+                "%.1fs", self.name, sup.breaker.threshold,
+                sup.breaker.cooldown_s)
+        self._recover_inflight(e)
+
+    def _iterate(self, step):
+        """One iteration with a slot active: admit, prepare, step, emit
+        — each a phase on the profiler's clock and in the tracer's phase
+        ring, so a device gap names what the host did in it."""
+        with obstrace.phase("gen.loop.admit", step=step) as ph:
+            seated = len(self._by_slot)
+            self._admit(block=False)
+            ph.set(admitted=max(0, len(self._by_slot) - seated))
+        if not self._by_slot:
+            return                  # a failed admission cleared the slots
+        with obstrace.phase("gen.loop.prepare", step=step) as ph:
+            lanes = 0
             if self.engine.chunked:
-                self._load_chunks()
+                lanes = self._load_chunks()
                 if self.engine.speculating:
                     self._load_spec()
             try:
@@ -2743,94 +2795,99 @@ class GenerationBatcher:
                 # steps; pool exhaustion preempts the youngest slots —
                 # their requests re-seat via _reseat_preempted and their
                 # streams continue bit-identically
-                for slot in self.engine.prepare_step():
+                victims = self.engine.prepare_step()
+                for slot in victims:
                     req = self._by_slot.pop(slot)
                     req.slot = None
                     req.slot_span.event("preempted",
                                         reason="pool_exhausted")
                     self._preempted.append(req)
-                if not self._by_slot:
-                    continue        # everything was preempted
-                if sup is None:
-                    nxt = self.engine.step()
-                else:
-                    try:
-                        nxt = sup.run_step(self.engine)
-                    except WatchdogTimeout:
-                        self.metrics.observe_watchdog_trip()
-                        raise
-                    sup.breaker.record_success()
-                    self._snap_breaker()
-            except Exception as e:    # noqa: BLE001 — isolate to the
-                # requests in flight; the loop keeps serving
-                if sup is not None:
-                    opened = sup.breaker.record_failure()
-                    self._snap_breaker()
-                    if opened:
-                        logger.warning(
-                            "%s: circuit breaker OPEN after %d consecutive "
-                            "step failures; shedding new admissions for "
-                            "%.1fs", self.name, sup.breaker.threshold,
-                            sup.breaker.cooldown_s)
-                    self._recover_inflight(e)
-                else:
-                    self._fail_all_inflight(e)
+            except Exception as e:    # noqa: BLE001 — see _step_failed
+                self._step_failed(e)
+                return
+            ph.set(chunk_lanes=lanes, preempted=len(victims))
+        if not self._by_slot:
+            return                  # everything was preempted
+        sup = self.supervisor
+        try:
+            if sup is None:
+                nxt = self.engine.step()
+            else:
+                try:
+                    nxt = sup.run_step(self.engine)
+                except WatchdogTimeout:
+                    self.metrics.observe_watchdog_trip()
+                    raise
+                sup.breaker.record_success()
+                self._snap_breaker()
+        except Exception as e:    # noqa: BLE001 — see _step_failed
+            self._step_failed(e)
+            return
+        with obstrace.phase("gen.loop.emit", step=step) as ph:
+            seated = len(self._by_slot)
+            emitted = self.metrics.gen_tokens_total
+            self._emit(nxt)
+            ph.set(emitted=self.metrics.gen_tokens_total - emitted,
+                   finished=seated - len(self._by_slot))
+
+    def _emit(self, nxt):
+        """Deliver the step's token to every active slot, then advance
+        or finish it."""
+        for slot, req in list(self._by_slot.items()):
+            if req.future in self._abandoned:
+                # abandon() raced the seating window: the flag landed
+                # in the set after admission's check — honor it here
+                self._abandoned.discard(req.future)
+                req.abandoned = True
+            if req.abandoned:
+                self._finish(req, "abandoned")
                 continue
-            for slot, req in list(self._by_slot.items()):
-                if req.future in self._abandoned:
-                    # abandon() raced the seating window: the flag landed
-                    # in the set after admission's check — honor it here
-                    self._abandoned.discard(req.future)
-                    req.abandoned = True
-                if req.abandoned:
-                    self._finish(req, "abandoned")
+            # lanes this step processed for the slot (1 = plain
+            # decode; >1 = a prefill/replay chunk, chunked mode)
+            consumed = self.engine.chunk_len(slot)
+            if req.replay_feed:
+                if len(req.replay_feed) >= consumed:
+                    # teacher-forced feeding continues: this step's
+                    # emission re-derives an already-known token —
+                    # swallow it and feed the recorded stream, until
+                    # the slot reaches the end of its context
+                    self.engine.advance(
+                        slot, req.replay_feed[consumed - 1],
+                        consumed)
+                    del req.replay_feed[:consumed]
                     continue
-                # lanes this step processed for the slot (1 = plain
-                # decode; >1 = a prefill/replay chunk, chunked mode)
-                consumed = self.engine.chunk_len(slot)
-                if req.replay_feed:
-                    if len(req.replay_feed) >= consumed:
-                        # teacher-forced feeding continues: this step's
-                        # emission re-derives an already-known token —
-                        # swallow it and feed the recorded stream, until
-                        # the slot reaches the end of its context
-                        self.engine.advance(
-                            slot, req.replay_feed[consumed - 1],
-                            consumed)
-                        del req.replay_feed[:consumed]
-                        continue
-                    # the feed drained EXACTLY at this step's last lane:
-                    # its emission is the first real one — fall through
-                    del req.replay_feed[:]
-                if self.engine.speculating:
-                    run = self.engine.take_spec_result(slot)
-                    if run is not None:
-                        # a verify step: the whole accepted run emits in
-                        # one go (and does its own advance/finish)
-                        self._emit_spec_run(req, slot, run)
-                        continue
-                tok = int(nxt[slot])
-                first_emit = req.t_first is None
-                req.emit(tok, self.name)
-                if first_emit:
-                    # chunked admissions and continuations reach their
-                    # first token HERE (the fresh-prompt ladder path
-                    # records it at prefill instead)
-                    req.slot_span.event("first_token")
-                    self.metrics.observe_ttft(req.t_first - req.t_submit)
-                    if self.engine.chunked and req.replay_ctx is None:
-                        # the prompt's K/V is fully resident exactly
-                        # now: publish it to the paged prefix index
-                        # (no-op on slab), the chunked twin of the
-                        # ladder path's admit-time registration
-                        self.engine.register_context(slot, req.prompt)
-                self.metrics.observe_gen_tokens(1)
-                if req.eos_id is not None and tok == req.eos_id:
-                    self._finish(req, "eos")
-                elif len(req.tokens) >= req.max_tokens:
-                    self._finish(req, "length")
-                else:
-                    self.engine.advance(slot, tok, consumed)
+                # the feed drained EXACTLY at this step's last lane:
+                # its emission is the first real one — fall through
+                del req.replay_feed[:]
+            if self.engine.speculating:
+                run = self.engine.take_spec_result(slot)
+                if run is not None:
+                    # a verify step: the whole accepted run emits in
+                    # one go (and does its own advance/finish)
+                    self._emit_spec_run(req, slot, run)
+                    continue
+            tok = int(nxt[slot])
+            first_emit = req.t_first is None
+            req.emit(tok, self.name)
+            if first_emit:
+                # chunked admissions and continuations reach their
+                # first token HERE (the fresh-prompt ladder path
+                # records it at prefill instead)
+                req.slot_span.event("first_token")
+                self.metrics.observe_ttft(req.t_first - req.t_submit)
+                if self.engine.chunked and req.replay_ctx is None:
+                    # the prompt's K/V is fully resident exactly
+                    # now: publish it to the paged prefix index
+                    # (no-op on slab), the chunked twin of the
+                    # ladder path's admit-time registration
+                    self.engine.register_context(slot, req.prompt)
+            self.metrics.observe_gen_tokens(1)
+            if req.eos_id is not None and tok == req.eos_id:
+                self._finish(req, "eos")
+            elif len(req.tokens) >= req.max_tokens:
+                self._finish(req, "length")
+            else:
+                self.engine.advance(slot, tok, consumed)
 
     # ------------------------------------------------------------ shutdown
 

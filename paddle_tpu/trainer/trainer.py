@@ -862,110 +862,136 @@ class SGD:
                 batch_id = -1
                 try:
                     while True:
-                        # h2d_wait: host time blocked acquiring the next
-                        # device-ready feed — with prefetch this is the queue
-                        # wait (~0 when the pipeline keeps up), without it the
-                        # reader + feeder conversion run inline here
-                        t_in = time.perf_counter()
-                        try:
-                            item = next(feed_iter)
-                        except StopIteration:
-                            break
-                        if skip_left > 0:
-                            # already trained before the preemption: no
-                            # step, no rng split, no events — the
-                            # checkpointed rng/params sit exactly here
-                            skip_left -= 1
+                        # one step annotation a batch (obs/trace.py
+                        # phase(); the keys are the ones jax.profiler.
+                        # StepTraceAnnotation sets), the parent of the
+                        # feed, step and handler phases below
+                        with _obstrace.phase("trainer.iter", _r=1,
+                                             step_num=batch_id + 1):
+                            # h2d_wait: host time blocked acquiring the
+                            # next device-ready feed — with prefetch this
+                            # is the queue wait (~0 when the pipeline
+                            # keeps up), without it the reader + feeder
+                            # conversion run inline here
+                            t_in = time.perf_counter()
+                            with _obstrace.phase("trainer.feed",
+                                                 step=batch_id + 1):
+                                try:
+                                    item = next(feed_iter)
+                                except StopIteration:
+                                    break
+                                if skip_left > 0:
+                                    # already trained before the
+                                    # preemption: no step, no rng split,
+                                    # no events — the checkpointed
+                                    # rng/params sit exactly here
+                                    skip_left -= 1
+                                    batch_id += 1
+                                    continue
+                                feed = item if prefetcher is not None else \
+                                    convert(item)
+                            h2d_dt = time.perf_counter() - t_in
                             batch_id += 1
-                            continue
-                        feed = item if prefetcher is not None else \
-                            convert(item)
-                        h2d_dt = time.perf_counter() - t_in
-                        batch_id += 1
-                        event_handler(events.BeginIteration(pass_id, batch_id))
-                        self.rng, step_rng = jax.random.split(self.rng)
-                        if self._step_fn is None:
-                            self._build_step(feed)
-                        if prefetcher is None:
-                            # multi-process: the synchronous path's global-
-                            # array H2D assembly counts into h2d_wait too —
-                            # otherwise the prefetch 0-vs-N comparison the
-                            # column exists for is apples-to-oranges.
-                            # (Single-process this is a no-op; there the
-                            # sync path's transfer happens lazily inside
-                            # the jit call and lands in step time.)
+                            with _obstrace.phase("trainer.handler",
+                                                 step=batch_id):
+                                event_handler(events.BeginIteration(
+                                    pass_id, batch_id))
+                            self.rng, step_rng = jax.random.split(self.rng)
+                            if self._step_fn is None:
+                                self._build_step(feed)
+                            # the rest of the feed: the global arrays'
+                            # assembly (a no-op in one process)
                             t_g = time.perf_counter()
-                            feed, step_rng = self._globalize_step_inputs(
-                                feed, step_rng)
+                            with _obstrace.phase("trainer.feed",
+                                                 step=batch_id):
+                                if prefetcher is None:
+                                    # multi-process: the synchronous path's
+                                    # global-array H2D assembly counts into
+                                    # h2d_wait too — otherwise the prefetch
+                                    # 0-vs-N comparison the column exists
+                                    # for is apples-to-oranges.  (Single-
+                                    # process this is a no-op; there the
+                                    # sync path's transfer happens lazily
+                                    # inside the jit call and lands in step
+                                    # time.)
+                                    feed, step_rng = \
+                                        self._globalize_step_inputs(
+                                            feed, step_rng)
+                                else:
+                                    # feed was placed on the producer
+                                    # thread; rng assembly still runs here
+                                    # and counts like the synchronous
+                                    # path's (same per-step work on both
+                                    # sides of the 0-vs-N comparison)
+                                    step_rng = self._globalize_rng(step_rng)
                             h2d_dt += time.perf_counter() - t_g
-                        else:       # feed was placed on the producer thread;
-                            # rng assembly still runs here and counts like
-                            # the synchronous path's (same per-step work on
-                            # both sides of the 0-vs-N comparison)
-                            t_g = time.perf_counter()
-                            step_rng = self._globalize_rng(step_rng)
-                            h2d_dt += time.perf_counter() - t_g
-                        global_stats.get("h2d_wait").add(h2d_dt)
-                        h2d_window += h2d_dt
-                        # chaos hook (resilience/faults.py), host-side so
-                        # the compiled step is untouched; an injected
-                        # fault unwinds like any real step crash (the
-                        # finally blocks still close the prefetcher,
-                        # land pending saves, restore the handler)
-                        _faults.hit("trainer.step")
-                        step_fn = self._dispatch_step(feed)
-                        t_step = time.perf_counter()
-                        # tracing hook (obs/trace.py), host-side like the
-                        # chaos hook above: the span wraps the step
-                        # DISPATCH and carries this batch's input wait,
-                        # so a Chrome trace shows train steps next to
-                        # h2d stalls; strict no-op when tracing is off
-                        with _obstrace.span(
-                                "trainer.step", root=False,
-                                pass_id=pass_id, batch=batch_id,
-                                h2d_wait_ms=round(h2d_dt * 1e3, 3)), \
-                                timer("train_step"):
-                            (new_p, self.opt_state, self.model_state,
-                             cost, extras) = step_fn(
-                                self._step_params(), self.opt_state,
-                                self.model_state, feed, step_rng)
-                            self._absorb_step_params(new_p)
-                        # per-step distribution (BarrierStat skew-profiling role):
-                        # record this step's own delta, not the cumulative timer
-                        from paddle_tpu.utils.stats import step_histogram
-                        step_dt = time.perf_counter() - t_step
-                        step_histogram.add(step_dt)
-                        cost_sum = cost_sum + cost
-                        if self._multiprocess and len(skew_window) < 10000:
-                            # consumed by the PASS-END cross-rank report (a
-                            # collective can only live where every rank is
-                            # guaranteed to arrive); bounded like step_histogram
-                            skew_window.append(step_dt)
-                        n_batches += 1
-                        if log_period:      # only the log line consumes it;
-                            window.append(cost)  # log_period=0 must not pin
-                        if self.evaluators:      # a device scalar per batch
-                            update_evaluators(extras, feed)
-                        if log_period and (batch_id + 1) % log_period == 0:
-                            c = float(jnp.mean(jnp.stack(window)))
-                            window = []
-                            dt = (time.time() - t0) / log_period
-                            logger.info("Pass %d Batch %d Cost %.5f (%.1f ms/batch"
-                                        " h2d_wait=%.2fms)%s",
-                                        pass_id, batch_id + 1, c, dt * 1e3,
-                                        h2d_window / log_period * 1e3,
-                                        eval_log_suffix())
-                            h2d_window = 0.0
-                            t0 = time.time()
-                        if (show_parameter_stats_period
-                                and (batch_id + 1) % show_parameter_stats_period == 0):
-                            self.log_parameter_stats()
-                        event_handler(events.EndIteration(
-                            pass_id, batch_id, cost=cost,
-                            evaluator_results={f"extra_{i}": e
-                                               for i, e in enumerate(extras)}))
-                        if self._stop_signal is not None:
-                            break
+                            global_stats.get("h2d_wait").add(h2d_dt)
+                            h2d_window += h2d_dt
+                            # chaos hook (resilience/faults.py), host-side so
+                            # the compiled step is untouched; an injected
+                            # fault unwinds like any real step crash (the
+                            # finally blocks still close the prefetcher,
+                            # land pending saves, restore the handler)
+                            _faults.hit("trainer.step")
+                            step_fn = self._dispatch_step(feed)
+                            t_step = time.perf_counter()
+                            # tracing hook (obs/trace.py), host-side like the
+                            # chaos hook above: the phase wraps the step
+                            # DISPATCH (the call returns long before the
+                            # device is done) and carries this batch's
+                            # input wait, so a trace shows train steps
+                            # next to h2d stalls
+                            with _obstrace.phase(
+                                    "trainer.step", step=batch_id,
+                                    pass_id=pass_id, batch=batch_id,
+                                    h2d_wait_ms=round(h2d_dt * 1e3, 3)), \
+                                    timer("train_step"):
+                                (new_p, self.opt_state, self.model_state,
+                                 cost, extras) = step_fn(
+                                    self._step_params(), self.opt_state,
+                                    self.model_state, feed, step_rng)
+                                self._absorb_step_params(new_p)
+                            # per-step distribution (BarrierStat skew-
+                            # profiling role): record this step's own
+                            # delta, not the cumulative timer
+                            from paddle_tpu.utils.stats import step_histogram
+                            step_dt = time.perf_counter() - t_step
+                            step_histogram.add(step_dt)
+                            cost_sum = cost_sum + cost
+                            if self._multiprocess and len(skew_window) < 10000:
+                                # consumed by the PASS-END cross-rank
+                                # report (a collective can only live where
+                                # every rank is guaranteed to arrive);
+                                # bounded like step_histogram
+                                skew_window.append(step_dt)
+                            n_batches += 1
+                            if log_period:  # only the log line consumes it;
+                                window.append(cost)  # log_period=0 must not
+                            if self.evaluators:  # pin a device scalar a batch
+                                update_evaluators(extras, feed)
+                            if log_period and (batch_id + 1) % log_period == 0:
+                                c = float(jnp.mean(jnp.stack(window)))
+                                window = []
+                                dt = (time.time() - t0) / log_period
+                                logger.info("Pass %d Batch %d Cost %.5f (%.1f ms/batch"
+                                            " h2d_wait=%.2fms)%s",
+                                            pass_id, batch_id + 1, c, dt * 1e3,
+                                            h2d_window / log_period * 1e3,
+                                            eval_log_suffix())
+                                h2d_window = 0.0
+                                t0 = time.time()
+                            if (show_parameter_stats_period
+                                    and (batch_id + 1) % show_parameter_stats_period == 0):
+                                self.log_parameter_stats()
+                            with _obstrace.phase("trainer.handler",
+                                                 step=batch_id):
+                                event_handler(events.EndIteration(
+                                    pass_id, batch_id, cost=cost,
+                                    evaluator_results={
+                                        f"extra_{i}": e
+                                        for i, e in enumerate(extras)}))
+                            if self._stop_signal is not None:
+                                break
                 finally:
                     if prefetcher is not None:
                         prefetcher.close()

@@ -1,0 +1,287 @@
+"""obs.trace.phase(): the per-step host phases of the two hot loops
+(docs/observability.md).
+
+A phase is ALWAYS a ``jax.profiler.TraceAnnotation`` (so a live profiler
+session holds it on its own clock, tracer on or off) and, with the tracer
+on, a record in a ring of its own that never evicts a request span.  The
+generation loop and the trainer's batch loop are instrumented with it; the
+shapes they must keep are pinned here: per counted decode step one
+``admit -> prepare -> dispatch -> wait -> emit`` inside one
+``gen.loop.iter``; per batch ``trainer.feed``, ``trainer.step`` and
+``trainer.handler`` inside one ``trainer.iter`` — and not one new jit trace
+for any of it."""
+
+import glob
+import json
+import threading
+import urllib.request
+
+import numpy as np
+import pytest
+import jax
+
+from paddle_tpu.obs import trace
+from paddle_tpu.testing.trace import assert_no_retrace
+
+
+@pytest.fixture(autouse=True)
+def _clean_tracer():
+    trace.disable()
+    yield
+    trace.disable()
+
+
+class _CountingLock:
+    def __init__(self):
+        self._lock, self.entered = threading.Lock(), 0
+
+    def __enter__(self):
+        self.entered += 1
+        return self._lock.__enter__()
+
+    def __exit__(self, *exc):
+        return self._lock.__exit__(*exc)
+
+
+def test_phase_with_tracer_off_touches_no_ring_lock_or_context():
+    old = trace.enable(sample=1.0, capacity=8)
+    old._lock = _CountingLock()
+    trace.disable()
+    with trace.phase("gen.loop.iter", step=3, active=1) as ph:
+        assert trace.current() is None          # no context variable
+        assert ph.set(admitted=2) is ph         # chainable, inert
+    assert trace.get_tracer() is None
+    assert len(old._phases) == 0 and old._lock.entered == 0
+    assert trace.debug_payload()["phases"] == []
+    assert trace.snapshot() == []
+
+
+def test_phases_keep_a_ring_of_their_own():
+    t = trace.enable(sample=1.0, capacity=8, process="unit")
+    with trace.span("server.request", route="/v1/generate"):
+        pass
+    for i in range(50):                 # far more phases than capacity
+        with trace.phase("gen.loop.emit", step=i) as ph:
+            ph.set(emitted=i % 3)
+    # the request span survived, and is still the slowest root
+    assert [s["name"] for s in trace.snapshot()] == ["server.request"]
+    assert [r["name"] for r in trace.slowest()["wall"]] == ["server.request"]
+    assert t.dropped_total == 0
+    held = t.phases()
+    assert [p["step"] for p in held] == list(range(42, 50))   # bounded ring
+    last = held[-1]
+    assert last["name"] == "gen.loop.emit" and last["process"] == "unit"
+    assert last["attrs"] == {"step": 49, "emitted": 49 % 3}
+    assert last["t_start"] <= last["t_end"]
+
+
+def _host_events(trace_dir):
+    """{name: [stats dict]} of the written xplane's host planes."""
+    from jax.profiler import ProfileData
+    path, = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    out.setdefault(e.name, []).append(dict(e.stats))
+    return out
+
+
+def test_phase_reaches_the_profilers_host_plane_with_tracer_off(tmp_path):
+    assert not trace.enabled()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with trace.phase("gen.loop.admit", step=7) as ph:
+            ph.set(admitted=2)
+        with trace.phase("trainer.iter", _r=1, step_num=5):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    events = _host_events(tmp_path)
+    assert events["gen.loop.admit"] == [{"step": 7, "admitted": 2}]
+    # the keys jax.profiler.StepTraceAnnotation sets: a step event
+    assert events["trainer.iter"] == [{"_r": 1, "step_num": 5}]
+
+
+def test_chrome_trace_loop_track_and_debug_payload():
+    trace.enable(sample=1.0, capacity=64, process="replica:1")
+    with trace.span("server.request"):
+        with trace.phase("gen.loop.iter", step=0, active=1):
+            pass
+    payload = trace.debug_payload()
+    assert [p["name"] for p in payload["phases"]] == ["gen.loop.iter"]
+    json.loads(json.dumps(payload))
+    obj = trace.chrome_trace()
+    tracks = {e["args"]["name"] for e in obj["traceEvents"]
+              if e["ph"] == "M" and e["name"] == "thread_name"}
+    assert tracks == {"host", "loop"}
+    loop, = [e for e in obj["traceEvents"] if e.get("cat") == "loop"]
+    assert loop["name"] == "gen.loop.iter" and loop["ph"] == "X"
+    assert loop["args"] == {"step": 0, "active": 1}
+    # a merged fleet dump takes the payloads' phases; spans alone, as
+    # before, carry no loop track
+    merged = trace.chrome_trace(payload["spans"], payload["phases"])
+    assert sum(e.get("cat") == "loop" for e in merged["traceEvents"]) == 1
+    alone = trace.chrome_trace(payload["spans"])
+    assert not any(e.get("cat") == "loop" for e in alone["traceEvents"])
+
+
+# ------------------------------------------------------ the serving loop
+
+ITER_ORDER = ["gen.loop.iter", "gen.loop.admit", "gen.loop.prepare",
+              "engine.step.dispatch", "engine.step.wait", "gen.loop.emit"]
+
+
+def _lm_params():
+    from paddle_tpu.models import transformer
+    return transformer.init(jax.random.PRNGKey(0), src_vocab=64,
+                            trg_vocab=1, d_model=16, num_heads=2, dff=32,
+                            enc_layers=1, dec_layers=0, max_len=32)
+
+
+@pytest.mark.parametrize("engine_kw", [
+    dict(prefill_buckets=(4, 8)),
+    dict(kv_layout="paged", kv_block_size=8, prefill_chunk=4),
+], ids=["slab_ladder", "paged_chunked"])
+def test_generation_loop_one_phase_sequence_per_counted_step(engine_kw):
+    from paddle_tpu.serving.decode_engine import (DecodeEngine,
+                                                  GenerationBatcher)
+    engine = DecodeEngine(_lm_params(), num_heads=2, num_slots=2,
+                          max_len=32, name="obs_phase", **engine_kw)
+    trace.enable(sample=1.0, capacity=4096, process="unit")
+    gen = GenerationBatcher(engine, default_max_tokens=4)
+    try:
+        with assert_no_retrace(lambda: engine.step_trace_count,
+                               "decode with phases recorded"):
+            futs = [gen.submit(np.arange(1, 4 + 2 * i) % 60, max_tokens=4)
+                    for i in range(3)]
+            assert all(len(f.result(60)["tokens"]) == 4 for f in futs)
+    finally:
+        gen.close()
+    assert engine.step_trace_count == 1
+    steps = engine.metrics.decode_steps_total
+    assert steps > 0
+    phases = trace.get_tracer().phases()
+    by_step = {}
+    for p in phases:
+        if p["name"] != "gen.loop.nowork":
+            by_step.setdefault(p["step"], []).append(p)
+    assert sorted(by_step) == list(range(steps))
+    for step, rows in by_step.items():
+        rows.sort(key=lambda p: (p["t_start"], -p["t_end"]))
+        assert [p["name"] for p in rows] == ITER_ORDER, step
+        it = rows[0]
+        for a, b in zip(rows[1:], rows[2:]):
+            assert a["t_end"] <= b["t_start"]       # one after the other
+        assert it["t_start"] <= rows[1]["t_start"] \
+            and rows[-1]["t_end"] <= it["t_end"]    # all inside the iter
+    disp = next(p for p in phases if p["name"] == "engine.step.dispatch")
+    host_args = 2 + ("prefill_chunk" in engine_kw) \
+        + (engine_kw.get("kv_layout") == "paged")
+    assert disp["attrs"]["host_args"] == host_args
+    assert disp["attrs"]["host_arg_bytes"] > 0
+    emitted = sum(p["attrs"]["emitted"] for p in phases
+                  if p["name"] == "gen.loop.emit")
+    # the ladder path delivers each request's first token at admission
+    at_admission = 0 if "prefill_chunk" in engine_kw else 3
+    assert emitted == engine.metrics.gen_tokens_total - at_admission
+    assert sum(p["attrs"]["finished"] for p in phases
+               if p["name"] == "gen.loop.emit") == 3
+    # waiting for work lies outside every iteration
+    iters = [(p["t_start"], p["t_end"]) for p in phases
+             if p["name"] == "gen.loop.iter"]
+    for p in phases:
+        if p["name"] == "gen.loop.nowork":
+            assert not any(s < p["t_end"] and p["t_start"] < e
+                           for s, e in iters)
+    # the request spans are what they were: no phase among them
+    assert not {s["name"] for s in trace.snapshot()} & set(ITER_ORDER)
+
+
+def test_debug_traces_endpoint_carries_phases():
+    from paddle_tpu.serving.decode_engine import (DecodeEngine,
+                                                  GenerationBatcher)
+    from paddle_tpu.serving.server import make_server
+    engine = DecodeEngine(_lm_params(), num_heads=2, num_slots=2,
+                          max_len=32, prefill_buckets=(4, 8),
+                          name="obs_phase_http")
+    trace.enable(sample=1.0, capacity=256, process="unit")
+    gen = GenerationBatcher(engine, default_max_tokens=2)
+    httpd = make_server(None, port=0, gen_batcher=gen)
+    th = threading.Thread(target=httpd.serve_forever, daemon=True)
+    th.start()
+    try:
+        assert len(gen.generate(np.arange(1, 5), timeout=60)["tokens"]) == 2
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{httpd.port}/debug/traces",
+                timeout=30) as r:
+            payload = json.loads(r.read())
+    finally:
+        httpd.shutdown()
+        httpd.server_close()
+        th.join(30)
+        gen.close()
+    assert {"gen.loop.iter", "engine.step.dispatch"} \
+        <= {p["name"] for p in payload["phases"]}
+    assert "slot" in {s["name"] for s in payload["spans"]}
+
+
+# ------------------------------------------------------ the trainer loop
+
+
+def test_trainer_loop_phases_once_a_batch():
+    import paddle_tpu.layers as L
+    from paddle_tpu import optim
+    from paddle_tpu.data import dense_vector, integer_value
+    from paddle_tpu.layers.graph import reset_names
+    from paddle_tpu.trainer import SGD, events
+
+    reset_names()
+    x = L.data_layer("x", size=4)
+    lbl = L.data_layer("lbl", size=2)
+    out = L.fc_layer(x, size=2, act="softmax")
+    tr = SGD(cost=L.classification_cost(out, lbl),
+             update_equation=optim.Momentum(learning_rate=0.1))
+    rng = np.random.RandomState(0)
+    batches = [[(rng.rand(4).astype(np.float32), int(i % 2))
+                for i in range(8)] for _ in range(5)]
+    seen = []
+    feeding = {"x": dense_vector(4), "lbl": integer_value(2)}
+
+    def run():
+        tr.train(lambda: iter(batches), num_passes=1, feeding=feeding,
+                 event_handler=lambda e: seen.append(type(e)),
+                 log_period=0, buffered_batches=0)
+
+    run()                               # the step's one trace
+    trace.enable(sample=1.0, capacity=256, process="unit")
+    with assert_no_retrace(lambda: tr.trace_count,
+                           "train() with phases recorded"):
+        run()
+    assert seen.count(events.EndIteration) == 10
+    phases = trace.get_tracer().phases()
+    by_batch = {}
+    for p in phases:
+        by_batch.setdefault(p["step"], []).append(p)
+    # batch 5 is the read that found the reader empty: a feed, no step
+    assert sorted(by_batch) == list(range(6))
+    assert sorted(p["name"] for p in by_batch.pop(5)) \
+        == ["trainer.feed", "trainer.iter"]
+    for batch, rows in by_batch.items():
+        names = [p["name"] for p in sorted(
+            rows, key=lambda p: (p["t_start"], -p["t_end"]))]
+        # the feed is the reader and the conversion, then (after the
+        # BeginIteration handler) the global arrays' assembly
+        assert names == ["trainer.iter", "trainer.feed", "trainer.handler",
+                         "trainer.feed", "trainer.step",
+                         "trainer.handler"], batch
+        it = next(p for p in rows if p["name"] == "trainer.iter")
+        assert all(it["t_start"] <= p["t_start"] and p["t_end"] <= it["t_end"]
+                   for p in rows)
+        step, = [p for p in rows if p["name"] == "trainer.step"]
+        assert step["attrs"]["batch"] == batch
+        assert step["attrs"]["pass_id"] == 0
+        assert "h2d_wait_ms" in step["attrs"]
+    # a training step is no request: nothing of it among the spans
+    assert trace.snapshot() == []
