@@ -75,6 +75,22 @@ def feeder_status():
             "why": _build.why_unavailable("dataio") or f"{_SO} is missing"}
 
 
+def _ptr(a):
+    """Address of a contiguous array for a foreign call.  Not
+    ``a.ctypes.data_as(...)``: that goes through ``ctypes.cast``, which
+    leaves a pointer that refers to itself (bugs.python.org/issue12836) —
+    one piece of cyclic garbage a row, which only the collector frees and
+    whose full passes stall a training loop.  The caller keeps ``a`` alive
+    across the call."""
+    return ctypes.c_void_p(a.ctypes.data)
+
+
+def _row_ptrs(arrs):
+    """The rows' addresses as an array of pointers (a numpy one: a ctypes
+    array type is itself cyclic garbage once its last instance dies)."""
+    return np.array([a.ctypes.data for a in arrs], np.uintp)
+
+
 def pack_i32(seqs, max_len=None, pad=0):
     """seqs: list of 1-D int32 arrays -> (out [B, T] int32, lengths [B])."""
     lib = _load()
@@ -84,12 +100,9 @@ def pack_i32(seqs, max_len=None, pad=0):
     t = int(max_len or (lens.max() if b else 1))
     out = np.empty((b, t), np.int32)
     out_lens = np.empty((b,), np.int32)
-    ptrs = (ctypes.POINTER(ctypes.c_int32) * b)(
-        *[a.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)) for a in arrs])
-    rc = lib.pt_pack_i32(ptrs, lens.ctypes.data_as(
-        ctypes.POINTER(ctypes.c_int32)), b, t, pad,
-        out.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
-        out_lens.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)))
+    rows = _row_ptrs(arrs)
+    rc = lib.pt_pack_i32(_ptr(rows), _ptr(lens), b, t, pad,
+                         _ptr(out), _ptr(out_lens))
     if rc != 0:
         raise RuntimeError(f"pt_pack_i32 failed rc={rc}")
     return out, out_lens
@@ -105,12 +118,9 @@ def pack_f32(seqs, max_len=None):
     t = int(max_len or (lens.max() if b else 1))
     out = np.empty((b, t, dim), np.float32)
     out_lens = np.empty((b,), np.int32)
-    ptrs = (ctypes.POINTER(ctypes.c_float) * b)(
-        *[a.ctypes.data_as(ctypes.POINTER(ctypes.c_float)) for a in arrs])
-    rc = lib.pt_pack_f32(ptrs, lens.ctypes.data_as(
-        ctypes.POINTER(ctypes.c_int32)), b, t, dim,
-        out.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
-        out_lens.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)))
+    rows = _row_ptrs(arrs)
+    rc = lib.pt_pack_f32(_ptr(rows), _ptr(lens), b, t, dim,
+                         _ptr(out), _ptr(out_lens))
     if rc != 0:
         raise RuntimeError(f"pt_pack_f32 failed rc={rc}")
     return out, out_lens
@@ -124,12 +134,9 @@ def densify_sparse(rows, cols, vals, b, dim):
     vp = None
     if vals is not None:
         vals = np.ascontiguousarray(vals, np.float32)
-        vp = vals.ctypes.data_as(ctypes.POINTER(ctypes.c_float))
-    rc = lib.pt_densify_sparse(
-        rows.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
-        cols.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
-        vp, len(rows), b, dim,
-        out.ctypes.data_as(ctypes.POINTER(ctypes.c_float)))
+        vp = _ptr(vals)
+    rc = lib.pt_densify_sparse(_ptr(rows), _ptr(cols), vp, len(rows), b, dim,
+                               _ptr(out))
     if rc != 0:
         raise RuntimeError(f"pt_densify_sparse failed rc={rc}")
     return out

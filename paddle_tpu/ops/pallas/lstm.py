@@ -7,8 +7,17 @@ and the per-step gate tensor through HBM every timestep and re-fetches the
 recurrent weights.  Here the grid IS the time loop (TPU grids execute
 sequentially per core, the same property the flash-attention kernel uses):
 w_r and the peephole vectors stay resident in VMEM across all T steps,
-h/c live in VMEM scratch, and each step streams only its [B, 4D] gate
-input in and its [B, D] output out.
+h/c live in VMEM scratch, and each step streams only its [bt, 4D] gate
+input in and its [bt, D] output out.
+
+The batch is tiled: grid = (B // bt, T), time innermost, both axes
+sequential.  Rows of a batch never interact in the recurrence, so a tile
+is a whole LSTM over bt rows; w_r and the peepholes keep a constant block
+index and stay resident across every tile, the h/c carry restarts at the
+first step of each tile, and the backward's dW_r accumulator lives across
+ALL tiles (one [D, 4D] output, no per-tile partials in HBM).  bt is
+``batch_tile(B, D)``: the whole batch when it fits the VMEM budget (one
+tile), otherwise the largest multiple of 8 dividing B that does.
 
 Semantics match ops.rnn.lstm exactly (reference gate order
 [a, in_gate, forget_gate, out_gate], peepholes on i/f from c_prev and on o
@@ -28,7 +37,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from paddle_tpu.ops.pallas.common import LANES as _LANES, lanes as _lanes
+from paddle_tpu.ops.pallas.common import (
+    LANES as _LANES, lanes as _lanes, vmem_budget_bytes, vmem_limit_bytes)
 
 
 def _fwd_kernel(xs_ref, wr_ref, chk_ref, mask_ref,
@@ -37,7 +47,7 @@ def _fwd_kernel(xs_ref, wr_ref, chk_ref, mask_ref,
     """cs_ref/acts_ref are None in the lean (inference) variant — the
     residual tensors are ~5x the HBM traffic of the h output, so
     forward-only calls must not pay for them."""
-    t = pl.program_id(0)
+    t = pl.program_id(1)          # axis 0 walks the batch tiles
 
     @pl.when(t == 0)
     def _():
@@ -81,7 +91,8 @@ def _bwd_kernel(acts_ref, cs_ref, csp_ref, hsp_ref, wr_ref, chk_ref,
                 mask_ref, dh_out_ref, dcfin_ref,
                 dxs_ref, dwr_ref, dchk_ref,
                 dh_scr, dc_scr, dwr_scr, dchk_scr, *, d, nt):
-    j = pl.program_id(0)          # reversed: actual time t = nt - 1 - j
+    ib = pl.program_id(0)         # batch tile
+    j = pl.program_id(1)          # reversed: actual time t = nt - 1 - j
     t = nt - 1 - j
 
     @pl.when(j == 0)
@@ -90,8 +101,12 @@ def _bwd_kernel(acts_ref, cs_ref, csp_ref, hsp_ref, wr_ref, chk_ref,
         # final-cell cotangent enters the chain at the last (first-reversed)
         # step, exactly where the scan's carry cotangent starts
         dc_scr[:] = dcfin_ref[0].astype(jnp.float32)
-        dwr_scr[:] = jnp.zeros_like(dwr_scr)
         dchk_scr[:] = jnp.zeros_like(dchk_scr)
+
+    # dW_r sums over every row of the batch: one accumulator for all tiles
+    @pl.when((ib == 0) & (j == 0))
+    def _():
+        dwr_scr[:] = jnp.zeros_like(dwr_scr)
 
     a = acts_ref[0, :, 0:d]
     i = acts_ref[0, :, d:2 * d]
@@ -139,16 +154,29 @@ def _bwd_kernel(acts_ref, cs_ref, csp_ref, hsp_ref, wr_ref, chk_ref,
 
     @pl.when(j == nt - 1)
     def _():
-        dwr_ref[:] = dwr_scr[:]
         dchk_ref[:] = dchk_scr[:]
 
+    @pl.when((ib == pl.num_programs(0) - 1) & (j == nt - 1))
+    def _():
+        dwr_ref[:] = dwr_scr[:]
 
-def _fwd(xs, w_r, checks, mask, interpret, save_residuals):
+
+def _compiler_params(bt, d):
+    """grid = (batch tiles, time): the carry makes time sequential, the
+    shared dW_r accumulator makes the tiles sequential.  The scoped-VMEM
+    limit follows the plan ``batch_tile`` chose ``bt`` by: at d=512 even 64
+    rows are over Mosaic's default 16 MiB (docs/kernels.md, VMEM table)."""
+    return pltpu.CompilerParams(
+        dimension_semantics=("arbitrary", "arbitrary"),
+        vmem_limit_bytes=vmem_limit_bytes(vmem_bytes(bt, d)))
+
+
+def _fwd(xs, w_r, checks, mask, interpret, bt, save_residuals):
     nt, b, g = xs.shape
     d = g // 4
     out_specs = [
-        pl.BlockSpec((1, b, d), lambda t: (t, 0, 0)),      # hs
-        pl.BlockSpec((1, b, d), lambda t: (0, 0, 0)),      # c_final
+        pl.BlockSpec((1, bt, d), lambda ib, t: (t, ib, 0)),    # hs
+        pl.BlockSpec((1, bt, d), lambda ib, t: (0, ib, 0)),    # c_final
     ]
     out_shape = [
         jax.ShapeDtypeStruct((nt, b, d), xs.dtype),
@@ -156,8 +184,8 @@ def _fwd(xs, w_r, checks, mask, interpret, save_residuals):
     ]
     if save_residuals:
         out_specs += [
-            pl.BlockSpec((1, b, d), lambda t: (t, 0, 0)),  # cs
-            pl.BlockSpec((1, b, g), lambda t: (t, 0, 0)),  # acts
+            pl.BlockSpec((1, bt, d), lambda ib, t: (t, ib, 0)),    # cs
+            pl.BlockSpec((1, bt, g), lambda ib, t: (t, ib, 0)),    # acts
         ]
         out_shape += [
             jax.ShapeDtypeStruct((nt, b, d), jnp.float32),
@@ -177,19 +205,20 @@ def _fwd(xs, w_r, checks, mask, interpret, save_residuals):
     outs = pl.pallas_call(
         kernel,
         name="lstm_fwd",
-        grid=(nt,),
+        grid=(b // bt, nt),
         in_specs=[
-            pl.BlockSpec((1, b, g), lambda t: (t, 0, 0)),
-            pl.BlockSpec((d, g), lambda t: (0, 0)),
-            pl.BlockSpec((3, d), lambda t: (0, 0)),
-            pl.BlockSpec((1, b, _LANES), lambda t: (t, 0, 0)),
+            pl.BlockSpec((1, bt, g), lambda ib, t: (t, ib, 0)),
+            pl.BlockSpec((d, g), lambda ib, t: (0, 0)),
+            pl.BlockSpec((3, d), lambda ib, t: (0, 0)),
+            pl.BlockSpec((1, bt, _LANES), lambda ib, t: (t, ib, 0)),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((b, d), jnp.float32),
-            pltpu.VMEM((b, d), jnp.float32),
+            pltpu.VMEM((bt, d), jnp.float32),
+            pltpu.VMEM((bt, d), jnp.float32),
         ],
+        compiler_params=_compiler_params(bt, d),
         interpret=interpret,
     )(xs, w_r, checks, mask)
     if save_residuals:
@@ -199,7 +228,7 @@ def _fwd(xs, w_r, checks, mask, interpret, save_residuals):
     return hs, cfin, None, None
 
 
-def _bwd(interpret, res, g_out):
+def _bwd(interpret, bt, res, g_out):
     w_r, checks, mask, hs, cs, acts = res
     dh_out, dcfin = g_out
     xs_dtype = hs.dtype              # hs was emitted in xs.dtype
@@ -207,27 +236,31 @@ def _bwd(interpret, res, g_out):
     d = dd
     gcols = 4 * d
 
+    def now(ib, j):               # the step being differentiated
+        return (nt - 1 - j, ib, 0)
+
+    def prev(ib, j):              # its predecessor (zeroed in-kernel at t=0)
+        return (jnp.maximum(nt - 2 - j, 0), ib, 0)
+
     dxs, dwr, dchk = pl.pallas_call(
         functools.partial(_bwd_kernel, d=d, nt=nt),
         name="lstm_bwd",
-        grid=(nt,),
+        grid=(b // bt, nt),
         in_specs=[
-            pl.BlockSpec((1, b, gcols), lambda j: (nt - 1 - j, 0, 0)),
-            pl.BlockSpec((1, b, d), lambda j: (nt - 1 - j, 0, 0)),
-            pl.BlockSpec((1, b, d),
-                         lambda j: (jnp.maximum(nt - 2 - j, 0), 0, 0)),
-            pl.BlockSpec((1, b, d),
-                         lambda j: (jnp.maximum(nt - 2 - j, 0), 0, 0)),
-            pl.BlockSpec((d, gcols), lambda j: (0, 0)),
-            pl.BlockSpec((3, d), lambda j: (0, 0)),
-            pl.BlockSpec((1, b, _LANES), lambda j: (nt - 1 - j, 0, 0)),
-            pl.BlockSpec((1, b, d), lambda j: (nt - 1 - j, 0, 0)),
-            pl.BlockSpec((1, b, d), lambda j: (0, 0, 0)),
+            pl.BlockSpec((1, bt, gcols), now),                 # acts
+            pl.BlockSpec((1, bt, d), now),                     # cs
+            pl.BlockSpec((1, bt, d), prev),                    # c_{t-1}
+            pl.BlockSpec((1, bt, d), prev),                    # h_{t-1}
+            pl.BlockSpec((d, gcols), lambda ib, j: (0, 0)),
+            pl.BlockSpec((3, d), lambda ib, j: (0, 0)),
+            pl.BlockSpec((1, bt, _LANES), now),                # mask
+            pl.BlockSpec((1, bt, d), now),                     # dh_out
+            pl.BlockSpec((1, bt, d), lambda ib, j: (0, ib, 0)),    # dcfin
         ],
         out_specs=[
-            pl.BlockSpec((1, b, gcols), lambda j: (nt - 1 - j, 0, 0)),
-            pl.BlockSpec((d, gcols), lambda j: (0, 0)),
-            pl.BlockSpec((b, 3 * d), lambda j: (0, 0)),
+            pl.BlockSpec((1, bt, gcols), now),                 # dxs
+            pl.BlockSpec((d, gcols), lambda ib, j: (0, 0)),    # dW_r
+            pl.BlockSpec((bt, 3 * d), lambda ib, j: (ib, 0)),  # dchk rows
         ],
         out_shape=[
             jax.ShapeDtypeStruct((nt, b, gcols), xs_dtype),
@@ -235,11 +268,12 @@ def _bwd(interpret, res, g_out):
             jax.ShapeDtypeStruct((b, 3 * d), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((b, d), jnp.float32),
-            pltpu.VMEM((b, d), jnp.float32),
+            pltpu.VMEM((bt, d), jnp.float32),
+            pltpu.VMEM((bt, d), jnp.float32),
             pltpu.VMEM((d, gcols), jnp.float32),
-            pltpu.VMEM((b, 3 * d), jnp.float32),
+            pltpu.VMEM((bt, 3 * d), jnp.float32),
         ],
+        compiler_params=_compiler_params(bt, d),
         interpret=interpret,
     )(acts, cs, cs, hs, w_r, checks, mask, dh_out,
       dcfin.astype(jnp.float32))
@@ -248,15 +282,15 @@ def _bwd(interpret, res, g_out):
     return dxs, dwr.astype(w_r.dtype), dchecks, None
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _fused(xs, w_r, checks, mask, interpret):
-    hs, cfin, _, _ = _fwd(xs, w_r, checks, mask, interpret,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _fused(xs, w_r, checks, mask, interpret, bt):
+    hs, cfin, _, _ = _fwd(xs, w_r, checks, mask, interpret, bt,
                           save_residuals=False)
     return hs, cfin
 
 
-def _fused_fwd_rule(xs, w_r, checks, mask, interpret):
-    hs, cfin, cs, acts = _fwd(xs, w_r, checks, mask, interpret,
+def _fused_fwd_rule(xs, w_r, checks, mask, interpret, bt):
+    hs, cfin, cs, acts = _fwd(xs, w_r, checks, mask, interpret, bt,
                               save_residuals=True)
     return (hs, cfin), (w_r, checks, mask, hs, cs, acts)
 
@@ -264,27 +298,44 @@ def _fused_fwd_rule(xs, w_r, checks, mask, interpret):
 _fused.defvjp(_fused_fwd_rule, _bwd)
 
 
-def vmem_bytes(b, d):
+def vmem_bytes(bt, d):
     """Planning estimate of the BACKWARD kernel's VMEM footprint (the
-    larger pass): resident w_r + dW_r accumulator (4dd each, f32) +
-    dh/dc/dchk scratch + one set of streamed per-step blocks (acts, cs,
-    csp, hsp, dh_out, dxs, mask, dcfin).  docs/kernels.md carries the
-    audit table derived from this."""
-    resident = 8 * d * d + 3 * d + 5 * b * d        # weights+accum+scratch
-    streamed = 13 * b * d + _LANES * b
-    return 4 * (resident + streamed)
+    larger pass) at a batch tile of ``bt`` rows, as Mosaic allocates it:
+    w_r, the dW_r accumulator and dW_r's output block (4dd each, f32; a
+    block whose index never moves gets ONE buffer), the peepholes, and per
+    row the dh/dc/dchk scratch (5d) plus TWO buffers — the pipeline's —
+    of every streamed block: acts and dxs (4d each), cs, csp, hsp, dh_out,
+    dcfin (d each), dchk (3d), the mask (128).  Against the v5e compiler's
+    own count the plan is 2-7% over at d = 128..512 and within 3% either
+    way at d = 1024..1536 (docs/kernels.md carries the table);
+    ``vmem_limit_bytes`` adds the margin."""
+    resident = 12 * d * d + 6 * d
+    per_row = 5 * d + 2 * (16 * d + _LANES)
+    return 4 * (resident + bt * per_row)
+
+
+def batch_tile(b, d):
+    """Rows per batch tile: the largest multiple of 8 that divides ``b``
+    and whose ``vmem_bytes`` fits the budget — ``b`` itself (one tile)
+    whenever the whole batch fits; 0 when no such tile exists (the weights
+    alone are over the budget, or no multiple of 8 divides ``b``)."""
+    budget = vmem_budget_bytes(scoped_limit_raised=True)
+    for bt in range(b - b % 8, 0, -8):
+        if b % bt == 0 and vmem_bytes(bt, d) <= budget:
+            return bt
+    return 0
 
 
 def supported(b, d, act, gate_act, state_act, init_state):
     """Kernel path preconditions; callers fall back to the scan otherwise.
     reverse is handled by the caller's time-flip (see rnn._fused_seq_apply).
-    The VMEM guard keeps e.g. d=1280 (w_r alone = 26 MB f32) off the
-    kernel path — it cannot be weight-resident on a ~16 MB core."""
-    from paddle_tpu.ops.pallas.common import vmem_budget_bytes
+    The VMEM guard keeps weights that cannot be resident off the kernel
+    path (d=1280: w_r, its gradient's accumulator and output block are
+    79 MB f32 — over a 16 MiB core, inside a v5e's 128 MiB); a batch too
+    large for one block is tiled, not declined."""
     return (act == "tanh" and gate_act == "sigmoid" and state_act == "tanh"
             and init_state is None
-            and b % 8 == 0 and d % _LANES == 0
-            and vmem_bytes(b, d) <= vmem_budget_bytes())
+            and d % _LANES == 0 and batch_tile(b, d) > 0)
 
 
 def lstm_fused(xs_tm, mask_tm, w_r, check_i, check_f, check_o,
@@ -298,10 +349,12 @@ def lstm_fused(xs_tm, mask_tm, w_r, check_i, check_f, check_o,
         interpret = jax.default_backend() != "tpu"
     nt, b, g = xs_tm.shape
     d = g // 4
+    bt = batch_tile(b, d)
+    assert bt, f"lstm_fused: no batch tile for b={b}, d={d} (supported())"
     checks = jnp.stack([
         jnp.zeros((d,), jnp.float32) if v is None else v.astype(jnp.float32)
         for v in (check_i, check_f, check_o)])
     mask_r = jnp.broadcast_to(
         mask_tm.astype(jnp.float32)[:, :, None], (nt, b, _LANES))
-    hs, cfin = _fused(xs_tm, w_r, checks, mask_r, interpret)
+    hs, cfin = _fused(xs_tm, w_r, checks, mask_r, interpret, bt)
     return hs, (hs[-1], cfin[0].astype(hs.dtype))

@@ -12,9 +12,12 @@ least one ran and none was interpreted — and on CPU interpret mode
 Each case is built at one of two widths: ``SMALL`` (seconds on CPU) and
 ``SERVING`` (the widths chip_smoke.py serves and trains at: d_model 2048
 as 16 heads of 128, slab length 2048, pool block 16, chunk 8; LSTM h=512
-B=64 T=100; blocked LSTM h=1280).  A case whose kernel's own guard
-declines the shape reports the guard's reason instead of running — it
-never silently takes a reference path.
+B=64 T=100, again at the benchmark's batch of 1,024, and at h=1280 B=256
+x 25 — the widest row of the reference's own LSTM table
+(benchmark/README.md: hidden 256/512/1280 x batch 64/128/256), which a
+v5e core holds only as two batch tiles of 128; blocked LSTM h=1280).  A case whose
+kernel's own guard declines the shape reports the guard's reason instead
+of running — it never silently takes a reference path.
 
 The oracle side always traces under ``f32_reference()`` (float32 compute
 policy + ``jax.default_matmul_precision("highest")``): on the MXU a
@@ -50,6 +53,10 @@ class Widths:
     rnn_batch: int
     rnn_len: int
     rnn_hidden: int
+    lstm_cell_batch: int    # the benchmark cell's batch, at rnn_len
+    lstm_tiled_batch: int   # over one batch tile on the chip at
+    lstm_tiled_hidden: int  # ... this width; the plan nears the budget
+    lstm_tiled_len: int     # (short: keeps the case's arrays small)
     blocked_hidden: int     # lstm_blocked (the over-VMEM variant)
     blocked_len: int        # odd: exercises the t-parity pad
 
@@ -57,7 +64,8 @@ class Widths:
 SMALL = Widths(heads=8, kv_heads=2, head_dim=128, slots=8, slab_len=256,
                block_size=32, blocks_per_row=4, chunk=8, flash_batch=2,
                flash_len=512, flash_block=256, rnn_batch=8, rnn_len=12,
-               rnn_hidden=128, blocked_hidden=256, blocked_len=9)
+               rnn_hidden=128, lstm_cell_batch=16, lstm_tiled_batch=24,
+               lstm_tiled_hidden=128, lstm_tiled_len=5, blocked_hidden=256, blocked_len=9)
 
 # chip_smoke.py's leg-2 trunk and leg-3 network; the serving CLI's own
 # defaults for block size and prefill chunk (utils/flags.py)
@@ -65,7 +73,8 @@ SERVING = Widths(heads=16, kv_heads=16, head_dim=128, slots=8,
                  slab_len=2048, block_size=16, blocks_per_row=128, chunk=8,
                  flash_batch=2, flash_len=2048, flash_block=512,
                  rnn_batch=64, rnn_len=100, rnn_hidden=512,
-                 blocked_hidden=1280, blocked_len=25)
+                 lstm_cell_batch=1024, lstm_tiled_batch=256,
+                 lstm_tiled_hidden=1280, lstm_tiled_len=25, blocked_hidden=1280, blocked_len=25)
 
 
 class Case(NamedTuple):
@@ -73,6 +82,7 @@ class Case(NamedTuple):
     oracle: Callable    # pure-XLA reference, same signature
     args: tuple
     err: Callable       # (got, want) -> float
+    facts: dict = {}    # what the case knows of its path; joins its row
 
 
 class Declined(NamedTuple):
@@ -174,13 +184,15 @@ def _tree_rel_err(got, want):
 
 # ------------------------------------------------------------------ RNN
 
-def _rnn_case(kind, w):
+def _rnn_case(kind, w, batch=None, length=None, hidden=None):
     """Fused-vs-scan equality (fwd + full BPTT grads) through the public
     rnn.{lstm,gru,simple_rnn} dispatch.  The dispatch mode is read at
     TRACE time, so each side sets it inside its own traced body."""
     from paddle_tpu.ops import rnn
 
-    b, t, d = w.rnn_batch, w.rnn_len, w.rnn_hidden
+    b, t, d = (batch or w.rnn_batch, length or w.rnn_len,
+               hidden or w.rnn_hidden)
+    facts = {}
     gates = {"lstm": 4, "gru": 3, "simple_rnn": 1}[kind]
     rng = np.random.RandomState(7)
     data = jnp.asarray(rng.randn(b, t, gates * d) * 0.3, jnp.float32)
@@ -189,6 +201,9 @@ def _rnn_case(kind, w):
     scale = 1.0 / np.sqrt(d)
 
     if kind == "lstm":
+        from paddle_tpu.ops.pallas import lstm as pl_lstm
+        # the dispatcher's own rule, so the row says how the batch was cut
+        facts = {"batch": b, "batch_tile": pl_lstm.batch_tile(b, d)}
         wr = jnp.asarray(rng.randn(d, 4 * d) * scale, jnp.float32)
         checks = [jnp.asarray(rng.randn(d) * 0.1, jnp.float32)
                   for _ in range(3)]
@@ -225,7 +240,7 @@ def _rnn_case(kind, w):
         with _fused_mode("0"):
             return vg(data, wr)
 
-    return Case(fn, oracle, (data, wr), _tree_rel_err)
+    return Case(fn, oracle, (data, wr), _tree_rel_err, facts)
 
 
 def _lstm_blocked_case(w):
@@ -452,6 +467,11 @@ def _decode(paged, chunk, quant, seed):
 
 CASES = {
     "lstm_fused": lambda w: _rnn_case("lstm", w),
+    "lstm_fused_cell_batch": lambda w: _rnn_case(
+        "lstm", w, batch=w.lstm_cell_batch),
+    "lstm_fused_tiled": lambda w: _rnn_case(
+        "lstm", w, batch=w.lstm_tiled_batch, length=w.lstm_tiled_len,
+        hidden=w.lstm_tiled_hidden),
     "lstm_blocked": _lstm_blocked_case,
     "gru_fused": lambda w: _rnn_case("gru", w),
     "simple_rnn_fused": lambda w: _rnn_case("simple_rnn", w),
@@ -518,4 +538,5 @@ def run_case(name, widths=SMALL, expect_compiled=False):
     assert err == err and err <= tol, \
         f"{name}: max err {err:.3e} > tol {tol} ({why})"
     return {"ok": True, "max_err": err, "tol": tol, "why": why,
-            "pallas_calls": len(seen), "interpreted": sum(seen)}
+            "pallas_calls": len(seen), "interpreted": sum(seen),
+            **case.facts}

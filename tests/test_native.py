@@ -128,6 +128,28 @@ def test_densify_sparse():
 
 
 @needs_lib
+@pytest.mark.parametrize("call", [
+    lambda: native.pack_i32([np.arange(5, dtype=np.int32)] * 64),
+    lambda: native.pack_f32([np.ones((3, 2), np.float32)] * 64),
+    lambda: native.densify_sparse([0, 1], [1, 0], [0.5, 2.0], 2, 2),
+], ids=["pack_i32", "pack_f32", "densify_sparse"])
+def test_foreign_calls_leave_nothing_to_the_collector(call):
+    """A pointer made by ``ndarray.ctypes.data_as`` refers to itself
+    (ctypes.cast, bugs.python.org/issue12836): one per row was the training
+    loop's cyclic garbage, and its full collections stalled the step."""
+    import gc
+    call()
+    gc.collect()
+    gc.disable()
+    try:
+        for _ in range(3):
+            call()
+        assert gc.collect() == 0
+    finally:
+        gc.enable()
+
+
+@needs_lib
 def test_record_roundtrip():
     p = os.path.join(tempfile.mkdtemp(), "x.ptrc")
     payloads = [struct.pack("<3i", i, i * 2, i * 3) for i in range(20)]

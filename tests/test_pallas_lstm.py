@@ -117,6 +117,180 @@ def test_vmem_guard_routes_oversized_to_scan(monkeypatch):
     assert not pl.supported(64, 512, "tanh", "sigmoid", "tanh", None)
 
 
+# ------------------------------------------------------------ batch tiles
+
+TILED_B = 32            # tiles of 32, 16 and 8 rows: one, two, four
+
+
+def _force_tile(monkeypatch, b, d, bt):
+    """Make ``batch_tile(b, d)`` come out as ``bt`` the way a small core
+    would: through the budget the guard already reads."""
+    from paddle_tpu.ops.pallas import lstm as pl
+    monkeypatch.setenv("PADDLE_TPU_KERNEL_VMEM_MB",
+                       repr((pl.vmem_bytes(bt, d) + 512) / 2 ** 20))
+    assert pl.batch_tile(b, d) == bt
+
+
+@pytest.fixture
+def pallas_grids(monkeypatch):
+    """The ``(name, grid)`` of every pallas_call traced in the test."""
+    from jax.experimental import pallas
+    seen, real = [], pallas.pallas_call
+
+    def spy(*a, **kw):
+        seen.append((kw.get("name"), tuple(kw["grid"])))
+        return real(*a, **kw)
+
+    monkeypatch.setattr(pallas, "pallas_call", spy)
+    return seen
+
+
+@pytest.mark.parametrize("tiles", [1, 2, 4])
+@pytest.mark.parametrize("reverse", [False, True], ids=["fwd", "rev"])
+@pytest.mark.parametrize("peephole", [True, False], ids=["peep", "nopeep"])
+def test_tiled_matches_scan(np_rng, monkeypatch, pallas_grids, tiles,
+                            reverse, peephole):
+    """One, two and four batch tiles: outputs, final state and EVERY
+    gradient equal the scan's on ragged rows, with a cotangent on the
+    final cell (``dcfin`` enters each tile's chain at its own last step)
+    and ``dW_r`` / ``dchecks`` summed across the tiles."""
+    _force_tile(monkeypatch, TILED_B, D, TILED_B // tiles)
+    x = jnp.asarray(np_rng.randn(TILED_B, T, 4 * D) * 0.3, jnp.float32)
+    lengths = jnp.asarray(np_rng.randint(1, T + 1, (TILED_B,)), jnp.int32)
+    _, w_r, checks, bias = _mk(np_rng)
+    probe = jnp.asarray(np_rng.randn(TILED_B, T, D), jnp.float32)
+    probe_c = jnp.asarray(np_rng.randn(TILED_B, D), jnp.float32)
+
+    def loss(fused, x, w_r, checks, bias):
+        prior = rnn.FUSED_LSTM
+        rnn.FUSED_LSTM = "always" if fused else "0"
+        try:
+            ci, cf, co = checks if peephole else (None, None, None)
+            out, final = rnn.lstm(SequenceBatch(data=x, lengths=lengths),
+                                  w_r, bias=bias, check_i=ci, check_f=cf,
+                                  check_o=co, reverse=reverse)
+        finally:
+            rnn.FUSED_LSTM = prior
+        return (jnp.sum(out.data * probe) + jnp.sum(final.c * probe_c)
+                + jnp.sum(final.h))
+
+    args = (x, w_r, checks, bias)
+    got = jax.value_and_grad(lambda *a: loss(True, *a),
+                             argnums=(0, 1, 2, 3))(*args)
+    assert pallas_grids == [("lstm_fwd", (tiles, T)),
+                            ("lstm_bwd", (tiles, T))]
+    want = jax.value_and_grad(lambda *a: loss(False, *a),
+                              argnums=(0, 1, 2, 3))(*args)
+    labels = ["loss", "dx", "dw_r", "dci", "dcf", "dco", "dbias"]
+    for la, g, w in zip(labels, jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=2e-4, atol=2e-5, err_msg=la)
+
+
+V5E_BUDGET_MB = "112"       # 7/8 of the v5e core's 128 MiB
+
+
+@pytest.mark.parametrize("budget_mb,b,d,want", [
+    (V5E_BUDGET_MB, 1024, 512, 1024),   # the benchmark's cell: one tile
+    (V5E_BUDGET_MB, 4096, 512, 1024),
+    (V5E_BUDGET_MB, 2048, 1024, 256),
+    (V5E_BUDGET_MB, 64, 1280, 64),      # resident on a 128 MiB core
+    ("24", 1024, 512, 128),
+    ("14", 1024, 512, 16),              # a 16 MiB core: 13.2 MB at 16 rows
+    ("14", 64, 1280, 0),                # ... cannot hold d=1280's weights
+    ("14", 168, 512, 24),
+    ("14", 1000, 512, 8),
+    ("14", 1024, 128, 512),
+], ids=lambda v: str(v))
+def test_batch_tile_rule(monkeypatch, budget_mb, b, d, want):
+    """The largest multiple of 8 that divides the batch and fits."""
+    from paddle_tpu.ops.pallas import lstm as pl
+    monkeypatch.setenv("PADDLE_TPU_KERNEL_VMEM_MB", budget_mb)
+    bt = pl.batch_tile(b, d)
+    assert bt == want
+    if bt:
+        assert b % bt == 0 and bt % 8 == 0
+        assert pl.vmem_bytes(bt, d) <= float(budget_mb) * 2 ** 20
+    assert pl.supported(b, d, "tanh", "sigmoid", "tanh", None) == (bt > 0)
+
+
+@pytest.mark.parametrize("d", [128, 256, 384, 512, 640])
+def test_batches_that_dispatched_before_are_one_tile_on_the_v5e(
+        monkeypatch, d):
+    """Every (b, d) the whole-batch guard admitted (its own estimate
+    against 14 MiB) is ONE tile under the v5e's budget: the grid is
+    (1, T), the program the kernel always was."""
+    from paddle_tpu.ops.pallas import lstm as pl
+    monkeypatch.setenv("PADDLE_TPU_KERNEL_VMEM_MB", V5E_BUDGET_MB)
+
+    def admitted_before(b):
+        return 4 * (8 * d * d + 3 * d + 18 * b * d + 128 * b) <= 14 * 2 ** 20
+
+    bs = [b for b in range(8, 4096, 8) if admitted_before(b)]
+    assert bs, "the old guard admitted no batch at this width"
+    assert [pl.batch_tile(b, d) for b in bs] == bs
+
+
+@pytest.mark.parametrize("b", [0, 4, 12, 100, 1001])
+def test_no_tile_when_no_multiple_of_8_divides_the_batch(monkeypatch, b):
+    from paddle_tpu.ops.pallas import lstm as pl
+    monkeypatch.setenv("PADDLE_TPU_KERNEL_VMEM_MB", V5E_BUDGET_MB)
+    assert pl.batch_tile(b, 128) == 0
+    assert not pl.supported(b, 128, "tanh", "sigmoid", "tanh", None)
+
+
+@pytest.mark.parametrize("what", ["activation", "init_state", "width"])
+def test_shapes_declined_for_other_reasons_still_scan(monkeypatch, what):
+    from paddle_tpu.ops.pallas import lstm as pl
+    monkeypatch.setenv("PADDLE_TPU_KERNEL_VMEM_MB", V5E_BUDGET_MB)
+    args = {"activation": (64, 128, "relu", "sigmoid", "tanh", None),
+            "init_state": (64, 128, "tanh", "sigmoid", "tanh", object()),
+            "width": (64, 192, "tanh", "sigmoid", "tanh", None)}[what]
+    assert not pl.supported(*args)
+
+
+def test_budget_follows_the_core_only_for_a_kernel_that_sets_its_limit(
+        monkeypatch):
+    """``vmem_budget_bytes()`` stays 14 MiB for the kernels that live
+    under Mosaic's default scoped limit; the LSTM, which hands Mosaic its
+    own limit, plans against 7/8 of the core's physical VMEM — the same
+    14 MiB where the device is no TPU."""
+    from jax.sharding import AbstractDevice, AbstractMesh, use_abstract_mesh
+    from paddle_tpu.ops.pallas import common, lstm as pl
+    monkeypatch.delenv("PADDLE_TPU_KERNEL_VMEM_MB", raising=False)
+    mib = 2 ** 20
+    assert common.vmem_budget_bytes() == 14 * mib
+    assert common.vmem_budget_bytes(scoped_limit_raised=True) == 14 * mib
+    assert pl.batch_tile(1024, 512) == 16
+    # a chip-free compile names its chip the way JAX itself reads it
+    v5e = AbstractMesh((), (), abstract_device=AbstractDevice(
+        device_kind="TPU v5 lite", num_cores=1))
+    with use_abstract_mesh(v5e):
+        assert common.vmem_budget_bytes() == 14 * mib
+        assert common.vmem_budget_bytes(scoped_limit_raised=True) == 112 * mib
+        assert pl.batch_tile(1024, 512) == 1024
+        assert pl.batch_tile(256, 1280) == 128
+    assert pl.batch_tile(1024, 512) == 16
+
+
+@pytest.mark.parametrize("bt,d,in_context_mib", [
+    (1024, 512, 82.12), (1352, 512, 111.56), (256, 1024, 78.08),
+    (64, 1280, 84.22), (128, 1280, 100.58)])
+def test_limit_handed_to_mosaic_covers_its_own_count(bt, d, in_context_mib):
+    """The smallest ``vmem_limit_bytes`` under which fwd+bwd through
+    ``rnn.lstm`` compiled for the v5e (bisected chip-free, T=25, PR 26): at
+    d=1280 it is OVER the plan, so the limit is the plan plus a sixteenth —
+    inside the core's 128 MiB for any plan the budget admits."""
+    from paddle_tpu.ops.pallas import common, lstm as pl
+    mib = 2 ** 20
+    plan = pl.vmem_bytes(bt, d)
+    assert plan <= 112 * mib
+    assert in_context_mib * mib * 1.02 <= common.vmem_limit_bytes(plan) \
+        <= 119 * mib
+    assert common.vmem_limit_bytes(pl.vmem_bytes(8, 128)) == 16 * mib
+
+
 def test_fused_kernel_takes_its_batch_shard_under_a_data_mesh(np_rng):
     """GSPMD cannot partition a Mosaic kernel (on the chip a batch-sharded
     jit raises "Mosaic kernels cannot be automatically partitioned"), so
