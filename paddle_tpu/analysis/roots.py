@@ -123,6 +123,12 @@ JIT_ROOTS = {r.name: r for r in [
          note="unified chunked-prefill step, paged layout (all_lanes is "
               "the spec-verify projection switch, shard_axis the "
               "tensor-parallel mesh axis — both trace-time only)"),
+    Root("hybrid_decode_chunk",
+         "paddle_tpu.models.hybrid_lm:decode_chunk",
+         static_args=("cfg", "with_routes"),
+         note="the hybrid trunk's chunked paged step (KDA state and MLA "
+              "latents; DecodeEngine(model=...) reaches it through "
+              "hybrid_lm.Served, which the call graph cannot follow)"),
     # ---- engine-side jitted closures (serving/): the slot-step wrapper
     # plus the admission/write/fork device ops around it
     Root("decode_engine_step",
@@ -163,6 +169,10 @@ JIT_ROOTS = {r.name: r for r in [
          "decode_attention_paged_chunk",
          static_args=("num_heads", "interpret"),
          note="Tq=chunk paged kernel (unified chunked prefill)"),
+    Root("kda_chunk",
+         "paddle_tpu.ops.pallas.kda:kda_chunk",
+         static_args=("hp", "interpret"),
+         note="gated delta rule with the state in VMEM (hybrid trunk)"),
     Root("flash_attention",
          "paddle_tpu.ops.pallas.flash_attention:flash_attention",
          static_args=("scale", "causal", "block_q", "block_k",

@@ -1,0 +1,299 @@
+"""A served decoder-only trunk built from a configuration: RMSNorm, no
+biases, an untied head, a per-layer attention kind (``"kda"``: the gated
+delta rule with per-slot state, ops/kda.py; ``"mla"``: latent attention over
+the paged pool, ops/mla.py) and a per-layer FFN kind (``"dense"``: a gated
+SiLU FFN; ``"moe"``: a sigmoid-routed expert layer that holds its share of
+the experts plus a shared expert, ops/moe.py).
+
+    x += Attn_l(RMSNorm(x));  x += FFN_l(RMSNorm(x));  logits = RMSNorm(x_L) W_head
+
+``DecodeEngine(params, model=Served(cfg))`` serves it through the one
+chunked paged step (docs/serving.md "Models that hold state").  The cache
+has two kinds of leaf, which ``cache_kinds`` declares: the MLA layers'
+latent pools are block-addressed like K/V; a KDA layer's recurrent state
+and convolution tail are slot-addressed, zeroed as data inside the step when
+a row starts at position 0, and left alone by lanes past a row's length.
+
+The residual stream, the norms, the router and the recurrence are float32;
+matrix products follow ``ops/linear.matmul``."""
+
+import dataclasses
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.models.transformer import _chunk_lanes
+from paddle_tpu.ops import kda, linear, mla, moe
+from paddle_tpu.serving.kv_pool import BLOCK_LEAF, SLOT_LEAF
+
+
+@dataclasses.dataclass(frozen=True)
+class Config:
+    vocab_size: int
+    hidden_size: int
+    layers: tuple               # ((attention kind, ffn kind), ...)
+    rms_norm_eps: float
+    # kda
+    kda_heads: int
+    kda_head_dim: int
+    conv_kernel: int
+    kda_gate_rank: int
+    # mla
+    mla_heads: int
+    qk_nope: int
+    qk_rope: int
+    v_head_dim: int
+    kv_rank: int
+    # ffn
+    dense_width: int
+    expert_width: int
+    router_width: int           # experts the router scores (all of them)
+    held: tuple                 # (first, count) of the experts held here
+    top_k: int
+    routed_scale: float
+    shared_experts: int
+
+    @property
+    def latent_width(self):
+        return self.kv_rank + self.qk_rope
+
+    @property
+    def kda_width(self):
+        return self.kda_heads * self.kda_head_dim
+
+
+def config_from_hf(c):
+    """``Config`` from a published ``config.json`` of the kimi_linear
+    family (a dict), plus the groups a cut adds: ``assumed.kda_gate_rank``
+    and ``expert_parallel`` (``num_experts`` are then the experts held of
+    ``num_experts_published``, by rank ``rank``)."""
+    la = c["linear_attn_config"]
+    full = set(la["full_attn_layers"])
+    ep = c.get("expert_parallel") or {}
+    count = c["num_experts"]
+    return Config(
+        vocab_size=c["vocab_size"], hidden_size=c["hidden_size"],
+        layers=tuple(("mla" if l in full else "kda",
+                      "dense" if l <= c["first_k_dense_replace"] else "moe")
+                     for l in range(1, c["num_hidden_layers"] + 1)),
+        rms_norm_eps=c["rms_norm_eps"],
+        kda_heads=la["num_heads"], kda_head_dim=la["head_dim"],
+        conv_kernel=la["short_conv_kernel_size"],
+        kda_gate_rank=(c.get("assumed") or {}).get("kda_gate_rank",
+                                                   la["head_dim"]),
+        mla_heads=c["num_attention_heads"], qk_nope=c["qk_nope_head_dim"],
+        qk_rope=c["qk_rope_head_dim"], v_head_dim=c["v_head_dim"],
+        kv_rank=c["kv_lora_rank"], dense_width=c["intermediate_size"],
+        expert_width=c["moe_intermediate_size"],
+        router_width=ep.get("num_experts_published", count),
+        held=(ep.get("rank", 0) * count, count),
+        top_k=c["num_experts_per_token"],
+        routed_scale=c["routed_scaling_factor"],
+        shared_experts=c["num_shared_experts"])
+
+
+# ------------------------------------------------------------ parameters
+
+def _normal(key, shape, std, dtype):
+    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+
+
+def _init_attn(key, cfg, kind, dtype):
+    d = cfg.hidden_size
+    ks = jax.random.split(key, 12)
+    lin = lambda k, i, o: _normal(k, (i, o), i ** -0.5, dtype)
+    if kind == "mla":
+        return {
+            "wq": lin(ks[0], d, cfg.mla_heads * (cfg.qk_nope + cfg.qk_rope)),
+            "wkva": lin(ks[1], d, cfg.latent_width),
+            "kv_norm": jnp.ones((cfg.kv_rank,), jnp.float32),
+            "wkvb": lin(ks[2], cfg.kv_rank,
+                        cfg.mla_heads * (cfg.qk_nope + cfg.v_head_dim)),
+            "wo": lin(ks[3], cfg.mla_heads * cfg.v_head_dim, d)}
+    w, r = cfg.kda_width, cfg.kda_gate_rank
+    # flash-linear-attention's own start: A in [1, 16], dt in [1e-3, 1e-1]
+    dt = jnp.exp(jax.random.uniform(ks[4], (w,), jnp.float32,
+                                    jnp.log(1e-3), jnp.log(1e-1)))
+    return {
+        "wqkv": lin(ks[0], d, 3 * w),
+        "conv": _normal(ks[1], (cfg.conv_kernel, 3 * w),
+                        cfg.conv_kernel ** -0.5, jnp.float32),
+        "wf1": lin(ks[2], d, r), "wf2": lin(ks[3], r, w),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "a_log": jnp.log(jax.random.uniform(ks[5], (cfg.kda_heads,),
+                                            jnp.float32, 1.0, 16.0)),
+        "wb": lin(ks[6], d, cfg.kda_heads),
+        "wg1": lin(ks[7], d, r), "wg2": lin(ks[8], r, w),
+        "o_norm": jnp.ones((cfg.kda_head_dim,), jnp.float32),
+        "wo": lin(ks[9], w, d)}
+
+
+def _init_ffn(key, cfg, kind, dtype):
+    d = cfg.hidden_size
+    ks = jax.random.split(key, 7)
+
+    def gated(k3, width, lead=()):
+        return {"wg": _normal(k3[0], lead + (d, width), d ** -0.5, dtype),
+                "wu": _normal(k3[1], lead + (d, width), d ** -0.5, dtype),
+                "wd": _normal(k3[2], lead + (width, d), width ** -0.5,
+                              dtype)}
+    if kind == "dense":
+        return gated(ks[:3], cfg.dense_width)
+    out = gated(ks[:3], cfg.expert_width, (cfg.held[1],))
+    out["router"] = _normal(ks[3], (d, cfg.router_width), d ** -0.5,
+                            jnp.float32)
+    out["router_bias"] = jnp.zeros((cfg.router_width,), jnp.float32)
+    out["shared"] = gated(jax.random.split(ks[4], 3),
+                          cfg.expert_width * cfg.shared_experts)
+    return out
+
+
+def _init_layer(key, cfg, kinds, dtype):
+    ka, kf = jax.random.split(key)
+    d = cfg.hidden_size
+    return {"norm1": jnp.ones((d,), jnp.float32),
+            "norm2": jnp.ones((d,), jnp.float32),
+            "attn": _init_attn(ka, cfg, kinds[0], dtype),
+            "ffn": _init_ffn(kf, cfg, kinds[1], dtype)}
+
+
+def init(key, cfg, dtype=jnp.float32, emb_std=0.02):
+    """Seeded parameters: N(0, 1/fan_in) projections, N(0, emb_std)
+    embeddings, gains at 1, the router bias at 0.  ``dtype`` is that of the
+    matrices; gains, biases, the convolution and the router stay float32.
+    Made one layer a jitted call, so that a model that nearly fills the
+    device is never beside a second copy of itself."""
+    keys = jax.random.split(key, len(cfg.layers) + 2)
+    d, v = cfg.hidden_size, cfg.vocab_size
+    layer = lambda kinds: jax.jit(functools.partial(
+        _init_layer, cfg=cfg, kinds=kinds, dtype=dtype))
+    by_kind = {kinds: layer(kinds) for kinds in set(cfg.layers)}
+    table = jax.jit(lambda k, shape, std: _normal(k, shape, std, dtype),
+                    static_argnums=(1, 2))
+    return {"emb": table(keys[0], (v, d), emb_std),
+            "head": table(keys[1], (d, v), d ** -0.5),
+            "norm_f": jnp.ones((d,), jnp.float32),
+            "layers": [by_kind[kinds](k)
+                       for kinds, k in zip(cfg.layers, keys[2:])]}
+
+
+# ----------------------------------------------------------------- cache
+
+def init_cache(cfg, slots, blocks, block, latent_dtype=jnp.float32):
+    """One entry a layer.  KDA: ``{"state" [slots, H, dk, dv] float32,
+    "conv" [slots, W-1, 3*H*dk] float32}``, owned by the slot; MLA:
+    ``{"latent" [blocks, block, pool_width(rank + rope)]}``, addressed
+    through the block tables (block 0 is the scratch block free rows point at)."""
+    h, dk = cfg.kda_heads, cfg.kda_head_dim
+    return [{"state": jnp.zeros((slots, h, dk, dk), jnp.float32),
+             "conv": jnp.zeros((slots, cfg.conv_kernel - 1, 3 * h * dk),
+                               jnp.float32)} if kind == "kda"
+            else {"latent": jnp.zeros(
+                (blocks, block, mla.pool_width(cfg.latent_width)),
+                latent_dtype)}
+            for kind, _ffn in cfg.layers]
+
+
+def cache_kinds(cfg):
+    """The cache's tree with ``kv_pool.SLOT_LEAF`` / ``BLOCK_LEAF`` in
+    place of each buffer."""
+    return [{"state": SLOT_LEAF, "conv": SLOT_LEAF} if kind == "kda"
+            else {"latent": BLOCK_LEAF} for kind, _ffn in cfg.layers]
+
+
+# ------------------------------------------------------------------ step
+
+def decode_chunk(params, cfg, tokens, positions, lengths, cache, tables,
+                 with_routes=False):
+    """``lm_decode_chunk_paged``'s lane semantics: tokens ``[S, K]``,
+    positions ``[S]`` (lane 0's), lengths ``[S]`` in ``[1, K]``; row r
+    advances ``lengths[r]`` positions.  -> (logits ``[S, V]`` at each
+    row's last fed lane, new cache), and with ``with_routes`` the chosen
+    experts ``[S, K, top_k]`` of every expert layer, in layer order.  A row
+    at position 0 starts from zero state; lengths and positions are data."""
+    s, kk = tokens.shape
+    # lanes past a row's length clamp to its last live lane
+    li, qpos = _chunk_lanes(positions, lengths, kk)
+    live = jnp.arange(kk)[None, :] < lengths[:, None]
+    eps = cfg.rms_norm_eps
+    x = params["emb"][tokens].astype(jnp.float32)
+    new_cache, routes = [], []
+    for lp, c, (attn_kind, ffn_kind) in zip(params["layers"], cache,
+                                            cfg.layers):
+        h = kda.rms_norm(x, lp["norm1"], eps)
+        if attn_kind == "kda":
+            y, state, tail = kda.kda_chunk(
+                lp["attn"], h, c["state"], c["conv"], positions, lengths,
+                num_heads=cfg.kda_heads, head_dim=cfg.kda_head_dim, eps=eps)
+            new_cache.append({"state": state, "conv": tail})
+        else:
+            y, pool = mla.mla_chunk(
+                lp["attn"], h, c["latent"], li, qpos, tables,
+                num_heads=cfg.mla_heads, nope=cfg.qk_nope, rope=cfg.qk_rope,
+                v_dim=cfg.v_head_dim, rank=cfg.kv_rank, eps=eps)
+            new_cache.append({"latent": pool})
+        x = x + y
+        h = kda.rms_norm(x, lp["norm2"], eps)
+        f = lp["ffn"]
+        if ffn_kind == "dense":
+            x = x + moe.gated_ffn(h, f["wg"], f["wu"], f["wd"])
+            continue
+        flat = h.reshape(s * kk, -1)
+        idx, weights = moe.sigmoid_router(flat, f["router"],
+                                          f["router_bias"], cfg.top_k,
+                                          cfg.routed_scale)
+        y = moe.routed_experts(flat, idx, weights, f, cfg.held,
+                               valid=live.reshape(-1))
+        sh = f["shared"]
+        y = y + moe.gated_ffn(flat, sh["wg"], sh["wu"], sh["wd"])
+        x = x + y.reshape(s, kk, -1)
+        routes.append(idx.reshape(s, kk, -1))
+    last = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)[:, 0]
+    logits = linear.matmul(kda.rms_norm(last, params["norm_f"], eps),
+                           params["head"])
+    if with_routes:
+        return logits, new_cache, routes
+    return logits, new_cache
+
+
+# ------------------------------------------------------------ the engine
+
+class Served:
+    """What ``DecodeEngine(params, model=...)`` asks of a model: the
+    vocabulary, a cache and the kinds of its leaves, the chunk step (with
+    what it reports of itself), and which kernels the step will take."""
+
+    def __init__(self, cfg, latent_dtype="float32"):
+        self.cfg = cfg
+        self.latent_dtype = jnp.dtype(latent_dtype)
+        self.vocab_size = cfg.vocab_size
+
+    def init_cache(self, slots, blocks, block):
+        return init_cache(self.cfg, slots, blocks, block, self.latent_dtype)
+
+    def cache_kinds(self):
+        return cache_kinds(self.cfg)
+
+    def decode_chunk(self, params, tokens, positions, lengths, cache,
+                     tables):
+        """-> (logits, new cache, what the step reports of itself: the
+        chosen experts ``[expert layers, S, K, top_k]`` int32, which the
+        engine keeps on the device unread, ``DecodeEngine.step_aux``)."""
+        logits, cache, routes = decode_chunk(
+            params, self.cfg, tokens, positions, lengths, cache, tables,
+            with_routes=True)
+        aux = jnp.stack(routes) if routes else jnp.zeros((0,), jnp.int32)
+        return logits, cache, aux
+
+    def kernel_report(self, kk):
+        """{"kda_kernels": bool, "kda_decline_reason": str | None} for a
+        step of ``kk`` lanes, from the kernel's own predicate."""
+        from paddle_tpu.ops.pallas import kda as kernel
+        if not any(kind == "kda" for kind, _f in self.cfg.layers):
+            return {"kda_kernels": False,
+                    "kda_decline_reason": "the model has no KDA layer"}
+        why = kernel.decline_reason(kk, self.cfg.kda_heads,
+                                    self.cfg.kda_head_dim,
+                                    self.cfg.kda_head_dim)
+        return {"kda_kernels": why is None, "kda_decline_reason": why}

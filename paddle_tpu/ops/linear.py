@@ -22,6 +22,14 @@ def matmul(x, w):
                       preferred_element_type=acc)
 
 
+def einsum(spec, x, w):
+    """``matmul``'s policy for a two-operand einsum."""
+    cd = dtypes.compute_dtype()
+    return jnp.einsum(spec, x.astype(cd), w.astype(cd),
+                      preferred_element_type=jnp.promote_types(
+                          cd, jnp.float32))
+
+
 def fc(x, w, b=None, act=None):
     """y = act(x @ w + b).  x: [..., in], w: [in, out], b: [out]."""
     y = matmul(x, w)

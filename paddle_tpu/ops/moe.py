@@ -8,6 +8,12 @@ inserts the psum that combines partial expert outputs — expert parallelism
 derived from shardings, no hand-written all-to-all.  Gating is dense
 top-k with renormalization (Switch/GShard style): no dynamic shapes, no
 scatter — everything stays MXU-friendly einsums under jit.
+
+That all-experts einsum (``moe_ffn``) serves the 2017 trunk and is the
+tests' oracle.  The SERVED expert layer is below it (``sigmoid_router``,
+``routed_experts``): tokens sorted by expert, grouped products over the
+experts this holder was told it holds, the rest of the experts' part left
+to whoever holds them (models/hybrid_lm.py; docs/serving.md).
 """
 
 import jax
@@ -94,3 +100,73 @@ def expert_shardings(mesh, axis="expert"):
         "w1": NamedSharding(mesh, P(axis, None, None)),
         "w2": NamedSharding(mesh, P(axis, None, None)),
     }
+
+
+# ------------------------------------------------- routed, held experts
+
+def sigmoid_router(x, w, bias, top_k, scale):
+    """A sigmoid router with a selection bias and a scale, in float32:
+    ``s = sigmoid(x W_r)``; the ``top_k`` experts are the largest of
+    ``s + bias`` (the bias chooses, it does not weigh); the weights are
+    ``scale * s_e / sum of the chosen s``.  x ``[N, D]``, w ``[D, E]``,
+    bias ``[E]`` -> (idx ``[N, top_k]`` int32, weights ``[N, top_k]``).
+    The product runs at ``highest`` precision: on a TPU a float32 product
+    is otherwise one bfloat16 pass, and the choice is discontinuous."""
+    s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
+                               w.astype(jnp.float32),
+                               precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    chosen = jnp.take_along_axis(s, idx, axis=-1)
+    return idx.astype(jnp.int32), \
+        scale * chosen / chosen.sum(-1, keepdims=True)
+
+
+def routed_experts(x, idx, weights, params, held, valid=None):
+    """The part of a routed expert layer that THIS holder's experts give:
+    expert parallelism as one chip sees it, without the exchange.
+
+    x ``[N, D]``; idx/weights ``[N, k]`` over ALL experts (the router keeps
+    its published width); params ``{"wg", "wu" [C, D, F], "wd" [C, F, D]}``
+    hold the ``C`` experts ``first .. first + C - 1`` (``held = (first,
+    C)``); valid ``[N]`` marks real tokens (padding lanes are routed
+    nowhere).  Returns ``sum over held chosen e of w_e E_e(x)``, ``[N, D]``
+    float32, ``E(x) = (SiLU(x W_gate) * x W_up) W_down``.
+
+    The ``N * k`` (token, expert) pairs are sorted by expert; pairs of
+    experts held elsewhere, and of padding, sort last and are never
+    computed.  The three grouped products are ``jax.lax.ragged_dot`` (on a
+    TPU: XLA's Mosaic grouped matmul, ``ragged-dot`` in the trace), which
+    reads only the experts that have rows."""
+    from paddle_tpu.core import dtypes
+    first, count = held
+    n, k = idx.shape
+    cd = dtypes.compute_dtype()
+    local = idx - first
+    mine = (local >= 0) & (local < count)
+    if valid is not None:
+        mine &= valid[:, None]
+    key = jnp.where(mine, local, count).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.bincount(key, length=count + 1)[:count].astype(jnp.int32)
+    rows = x.astype(cd)[order // k]
+    dot = lambda a, w: jax.lax.ragged_dot(
+        a.astype(cd), w.astype(cd), sizes,
+        preferred_element_type=jnp.float32)
+    y = dot(jax.nn.silu(dot(rows, params["wg"])) * dot(rows, params["wu"]),
+            params["wd"])
+    # rows past the last group belong to no expert here: whatever the
+    # grouped product left in them is dropped, not scaled
+    w_sorted = weights.reshape(-1)[order]
+    y = jnp.where((jnp.arange(n * k) < sizes.sum())[:, None],
+                  y * w_sorted[:, None], 0.0)
+    back = jnp.zeros((n * k,), jnp.int32).at[order].set(
+        jnp.arange(n * k, dtype=jnp.int32))
+    return y[back].reshape(n, k, -1).sum(1)
+
+
+def gated_ffn(x, wg, wu, wd):
+    """``(SiLU(x W_gate) * x W_up) W_down`` under ``linear.matmul``'s
+    policy: the dense FFN and the shared expert."""
+    from paddle_tpu.ops import linear
+    return linear.matmul(
+        jax.nn.silu(linear.matmul(x, wg)) * linear.matmul(x, wu), wd)

@@ -114,6 +114,16 @@ def decode_kernels_enabled():
     return pk.use_pallas()
 
 
+def flag_decline_reason():
+    """Why ``decode_kernels_enabled()`` is False, as a sentence (shared
+    with ops/pallas/kda.py, which the same flag gates)."""
+    m = str(_mode()).lower()
+    if m == "auto":
+        return (f"pallas_decode=auto and the backend is "
+                f"{jax.default_backend()!r}, not 'tpu'")
+    return f"pallas_decode={m}"
+
+
 def _block_k_cap():
     from paddle_tpu.utils.flags import FLAGS
     return int(getattr(FLAGS, "pallas_decode_block_k", 512))
@@ -745,11 +755,7 @@ def decline_reason(num_heads, d, dkv, blk_len, paged=False, chunk=1,
     shard_map see the local widths naturally; this localizes the
     warm-up prediction to match."""
     if not decode_kernels_enabled():
-        m = str(_mode()).lower()
-        if m == "auto":
-            return (f"pallas_decode=auto and the backend is "
-                    f"{jax.default_backend()!r}, not 'tpu'")
-        return f"pallas_decode={m}"
+        return flag_decline_reason()
     shards = max(1, int(shards))
     if shards > 1:
         if num_heads % shards or d % shards or dkv % shards:

@@ -86,11 +86,12 @@ from paddle_tpu.serving.batcher import (BatchExecutionError,
 from paddle_tpu.serving.engine import InferenceEngine, InvalidRequestError
 from paddle_tpu.quant.kv import KV_DTYPES
 from paddle_tpu.quant.weights import weight_shape as _w_shape
-from paddle_tpu.serving.kv_pool import (HostTier,
+from paddle_tpu.serving.kv_pool import (BLOCK_LEAF, SLOT_LEAF, HostTier,
                                         InsufficientBlocksError,
                                         PagedKVState,
                                         RestorePendingError,
-                                        WireFormatError,
+                                        WireFormatError, leaf_bytes,
+                                        map_block_leaves,
                                         peek_chain_header,
                                         restore_chain, serialize_chain,
                                         slab_equivalent_blocks)
@@ -165,9 +166,23 @@ class DecodeEngine:
                  kv_layout="slab", kv_block_size=16, kv_num_blocks=0,
                  prefix_cache=True, prefill_chunk=0,
                  prefill_chunk_budget=0, kv_dtype="float32",
-                 speculate_k=0, draft=None, mesh=None, kv_host_bytes=0):
+                 speculate_k=0, draft=None, mesh=None, kv_host_bytes=0,
+                 model=None):
         from paddle_tpu.models import transformer
         self._transformer = transformer
+        # model=None: the transformer trunk (every default below).  A
+        # model object (models/hybrid_lm.Served) brings its own chunk step
+        # and cache, whose leaves it declares block- or slot-addressed
+        # (kv_pool.BLOCK_LEAF / SLOT_LEAF; docs/serving.md "Models that
+        # hold state")
+        self._model = model
+        self._leaf_kinds = None
+        if model is not None:
+            self._check_model_config(
+                kv_layout=kv_layout, prefill_chunk=prefill_chunk,
+                prefix_cache=prefix_cache, speculate_k=speculate_k,
+                kv_host_bytes=kv_host_bytes, mesh=mesh, kv_dtype=kv_dtype)
+            self._leaf_kinds = model.cache_kinds()
         if params.get("dec"):
             raise ConfigError(
                 "DecodeEngine serves the decoder-only LM trunk "
@@ -349,11 +364,7 @@ class DecodeEngine:
                 else None)
             # per-layer [num_blocks, block_size, Dkv] pools (block 0 is
             # the scratch block free slot rows point at)
-            self._cache = self._new_cache(
-                lambda: transformer.init_lm_cache_paged(
-                    params, num_blocks, self.block_size,
-                    max_len=self.max_len, kv_dtype=kv_dtype,
-                    num_heads=self.num_heads))
+            self._cache = self._new_cache(self._build_paged_cache)
             # host-tier restore bookkeeping (``_pending_restores``: one
             # in-flight marker per prefix key -> (epoch at submit,
             # t_submit) — poll_restores drops a job whose epoch went
@@ -362,12 +373,13 @@ class DecodeEngine:
             # trunks; the param count/bytes feed the restore-vs-
             # recompute model.
             enc = params.get("enc") or []
-            d = int(_w_shape(params["src_emb"])[1])
-            dkv = int(_w_shape(enc[0]["attn"]["wk"])[1]) if enc else 0
-            self._kv_dims = (len(enc), dkv)
-            self._trunk_sig = (f"L{len(enc)}.d{d}.dkv{dkv}"
-                               f".h{self.num_heads}.{kv_dtype}"
-                               f".b{self.block_size}")
+            if model is None:
+                d = int(_w_shape(params["src_emb"])[1])
+                dkv = int(_w_shape(enc[0]["attn"]["wk"])[1]) if enc else 0
+                self._kv_dims = (len(enc), dkv)
+                self._trunk_sig = (f"L{len(enc)}.d{d}.dkv{dkv}"
+                                   f".h{self.num_heads}.{kv_dtype}"
+                                   f".b{self.block_size}")
             leaves = jax.tree_util.tree_leaves(params)
             self._param_count = sum(int(l.size) for l in leaves)
             self._param_bytes = sum(
@@ -460,6 +472,14 @@ class DecodeEngine:
         # the guard's reason (ops/pallas/decode_attention.decline_reason)
         self.decode_kernels = False
         self.decode_decline_reason = None
+        # the same for a model's recurrent kernel (ops/pallas/kda.py):
+        # False and None where the model has no such layer
+        self.kda_kernels = False
+        self.kda_decline_reason = None
+        # what a model's last step reported of itself (hybrid_lm: the
+        # chosen experts), left on the device; None for the trunk
+        self.step_aux = None
+        self._step_log = None      # a list while record_steps() is on
 
         # all_lanes is a TRACE-TIME constant: a speculating engine's
         # step returns EVERY lane's argmax [S, K] (the verify surface —
@@ -472,7 +492,14 @@ class DecodeEngine:
         axis = self._shard_axis
         heads = (self.num_heads // self.mesh_shards if axis is not None
                  else self.num_heads)
-        if self.prefill_chunk and self.kv_layout == "paged":
+        if model is not None:
+            def _step_fn(p, cache, tokens, pos, lens, tables):
+                self._step_traces[0] += 1  # runs only under tracing
+                logits, cache, aux = model.decode_chunk(
+                    p, tokens, pos, lens, cache, tables)
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                return (nxt, aux), cache
+        elif self.prefill_chunk and self.kv_layout == "paged":
             def _model(p, cache, tokens, pos, lens, tables):
                 logits, cache = transformer.lm_decode_chunk_paged(
                     p, tokens, pos, lens, cache, tables, heads,
@@ -528,16 +555,20 @@ class DecodeEngine:
         # (slab layout only — paged admission goes through _jit_write)
         self._jit_admit = jax.jit(_admit_fn, donate_argnums=(0,))
 
+        # block writes and copies touch the block-addressed leaves alone
+        # (every leaf of the transformer trunk's cache)
+        kinds = self._leaf_kinds
+
         def _write_fn(cache, chunk, bid):
             self._write_traces[0] += 1
-            return jax.tree_util.tree_map(
+            return map_block_leaves(
                 lambda c, ch: c.at[bid].set(ch.astype(c.dtype)),
-                cache, chunk)
+                cache, kinds, chunk)
 
         def _copy_fn(cache, src, dst):
             self._copy_traces[0] += 1
-            return jax.tree_util.tree_map(
-                lambda c: c.at[dst].set(c[src]), cache)
+            return map_block_leaves(
+                lambda c: c.at[dst].set(c[src]), cache, kinds)
 
         # paged device ops: ONE fixed [block_size, Dkv] write shape
         # regardless of prompt bucket (one trace total), and the
@@ -549,6 +580,75 @@ class DecodeEngine:
         self._warm = False
         if warm:
             self.warmup()
+
+    # ------------------------------------------------ models with state
+
+    @staticmethod
+    def _check_model_config(*, kv_layout, prefill_chunk, prefix_cache,
+                            speculate_k, kv_host_bytes, mesh, kv_dtype):
+        """What ``model=`` cannot be combined with yet.  A slot of such a
+        model owns state that no block table addresses; each feature below
+        needs a way to SNAPSHOT that state at a position (ROADMAP D6), and
+        until one exists the engine refuses rather than serve tokens
+        computed from the wrong state."""
+        lacks = "needs a state snapshot kind the cache does not have yet"
+        if kv_layout != "paged":
+            raise ConfigError(
+                "model= serves through the paged layout only: the slab "
+                "layout keeps one row of positions a slot and has no place "
+                "for state that positions do not address")
+        if not prefill_chunk:
+            raise ConfigError(
+                "model= serves through the one chunked step "
+                "(prefill_chunk > 0): the legacy prefill ladder runs the "
+                "transformer trunk's own forward pass")
+        if prefix_cache:
+            raise ConfigError(
+                "prefix_cache with model=: a shared prefix's blocks hold "
+                "its latents but not the recurrent state at its end; "
+                f"sharing {lacks} (pass prefix_cache=False)")
+        if speculate_k:
+            raise ConfigError(
+                "speculate_k with model=: rejected draft lanes would have "
+                f"advanced the recurrent state; rollback {lacks}")
+        if kv_host_bytes:
+            raise ConfigError(
+                "kv_host_bytes with model=: a spilled chain would come "
+                f"back without its recurrent state; spill {lacks}")
+        if mesh is not None:
+            raise ConfigError(
+                "mesh with model=: the sharding rules cover the "
+                "transformer trunk's K/V stripes only; slot-addressed "
+                "state has no placement rule yet")
+        if kv_dtype != "float32":
+            raise ConfigError(
+                "kv_dtype with model=: the model chooses its pool's dtype "
+                "(hybrid_lm.Served(latent_dtype=...))")
+
+    def record_steps(self, on=True):
+        """While on, ``step()`` keeps what each step fed and what the model
+        reported of it (``recorded_steps()``): the host arrays it
+        snapshotted anyway and the unread device report.  For a check that
+        must know what the SERVER's step chose (the benchmark hands the
+        routed experts of streamed tokens to its reference); off by
+        default, and nothing is kept then."""
+        self._step_log = [] if on else None
+
+    def recorded_steps(self):
+        """[(tokens [S, K], positions [S], lengths [S], report)] of the
+        steps since ``record_steps()``, oldest first."""
+        return list(self._step_log or ())
+
+    def _build_paged_cache(self):
+        """A fresh, zeroed paged cache: the model's own, or the
+        transformer trunk's K/V pools."""
+        blocks = self._paged.pool.num_blocks
+        if self._model is not None:
+            return self._model.init_cache(self.num_slots, blocks,
+                                          self.block_size)
+        return self._transformer.init_lm_cache_paged(
+            self.params, blocks, self.block_size, max_len=self.max_len,
+            kv_dtype=self.kv_dtype, num_heads=self.num_heads)
 
     # --------------------------------------------------- sharded decode
 
@@ -679,6 +779,14 @@ class DecodeEngine:
             self._tokens[slot] = token
         self._pos[slot] = pos
 
+    def _set_cache_gauges(self):
+        """The bytes a model's two kinds of cache leaf hold (0 and 0 for
+        the transformer trunk, whose pool ``kv_blocks_*`` describe)."""
+        if self._model is not None:
+            self._metrics.set_state_cache_bytes(
+                leaf_bytes(self._cache, self._leaf_kinds, SLOT_LEAF),
+                leaf_bytes(self._cache, self._leaf_kinds, BLOCK_LEAF))
+
     @property
     def free_slots(self):
         return len(self._free)
@@ -711,6 +819,7 @@ class DecodeEngine:
         # batch/latency stats on an orphaned object; the chunk-size
         # gauge is config, so the fresh object inherits it immediately
         self._metrics = m
+        self._set_cache_gauges()
         m.set_prefill_chunk(self.prefill_chunk)
         m.set_kv_dtype(self.kv_dtype)
         m.set_speculate_k(self.speculate_k)
@@ -815,6 +924,9 @@ class DecodeEngine:
                 raise
         self._arm(slot, full[0], 0)
         self._draft_seed(slot, full[:1])
+        if self._model is not None:
+            # the step zeroes the slot's state itself, on seeing position 0
+            self.metrics.observe_state_reset()
         return slot, [int(t) for t in full[1:]]
 
     def load_chunk(self, slot, toks):
@@ -1481,6 +1593,8 @@ class DecodeEngine:
             ph.set(host_args=len(host),
                    host_arg_bytes=sum(a.nbytes for a in host))
             nxt, cache = self._jit_step(params, cache, *host)
+            if self._model is not None:
+                nxt, self.step_aux = nxt
         with obstrace.phase("engine.step.wait", step=step):
             nxt = np.asarray(nxt)
         with self._epoch_lock:
@@ -1489,6 +1603,8 @@ class DecodeEngine:
                     f"{self.name}: engine was reset mid-step; stale step "
                     "result discarded")
             self._cache = cache
+        if self._step_log is not None:
+            self._step_log.append((tokens, host[1], lens, self.step_aux))
         # teacher-forced lanes this step fed beyond the per-slot token
         # (the chunked-prefill occupancy surface)
         chunk_lanes = int(lens.sum() - self.num_slots) if lens is not None \
@@ -1585,11 +1701,7 @@ class DecodeEngine:
                 # _new_cache: a sharded engine's rebuilt pool must come
                 # back with the same mesh placement or the (still-cached)
                 # compiled step would see new shardings and recompile
-                self._cache = self._new_cache(
-                    lambda: self._transformer.init_lm_cache_paged(
-                        self.params, old.pool.num_blocks, self.block_size,
-                        max_len=self.max_len, kv_dtype=self.kv_dtype,
-                        num_heads=self.num_heads))
+                self._cache = self._new_cache(self._build_paged_cache)
             else:
                 self._cache = self._new_cache(
                     lambda: self._transformer.init_lm_cache(
@@ -1659,10 +1771,20 @@ class DecodeEngine:
                     "decode[%s]: fused decode kernel declined -> XLA "
                     "reference path: %s", self.name,
                     self.decode_decline_reason)
+        if self._model is not None:
+            report = self._model.kernel_report(self._kk)
+            self.kda_kernels = report["kda_kernels"]
+            self.kda_decline_reason = report["kda_decline_reason"]
+            if not self.kda_kernels:
+                logger.warning(
+                    "decode[%s]: kda_chunk kernel declined -> XLA scan "
+                    "(every lane rewrites the state): %s", self.name,
+                    self.kda_decline_reason)
         self.metrics.set_prefill_chunk(self.prefill_chunk)
         self.metrics.set_kv_dtype(self.kv_dtype)
         self.metrics.set_speculate_k(self.speculate_k)
         self.metrics.set_mesh_shards(self.mesh_shards)
+        self._set_cache_gauges()
         if self._draft is not None:
             # the draft rollout is its own ONE warm-up trace
             self._draft.warmup()
@@ -1714,8 +1836,9 @@ class DecodeEngine:
                 "speculate_k=%d, mesh_shards=%d)", self.name,
                 self.num_slots, self.max_len, self.kv_layout,
                 self.kv_dtype,
-                "fused-pallas" if self.decode_kernels
-                else f"xla-ref ({self.decode_decline_reason})",
+                "fused-pallas" if self.decode_kernels or self.kda_kernels
+                else "xla-ref (%s)" % (self.kda_decline_reason
+                                       or self.decode_decline_reason),
                 self.prefill_chunk, self.prefill_chunk_budget or "inf",
                 self.speculate_k, self.mesh_shards)
             return
@@ -1838,7 +1961,8 @@ class DecodeEngine:
         if not np.issubdtype(ids.dtype, np.integer):
             raise InvalidRequestError(
                 f"{name} must be integer token ids, got {ids.dtype}")
-        vocab = _w_shape(self.params["src_emb"])[0]
+        vocab = (self._model.vocab_size if self._model is not None
+                 else _w_shape(self.params["src_emb"])[0])
         if int(ids.min()) < 0 or int(ids.max()) >= vocab:
             raise InvalidRequestError(
                 f"{name} ids must be in [0, {vocab}); got "

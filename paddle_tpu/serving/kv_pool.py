@@ -78,6 +78,39 @@ WIRE_VERSION = 1
 MAX_CHAIN_BLOB_BYTES = 1 << 30
 
 
+# The two kinds of leaf a cache pytree may hold (the first step of ROADMAP
+# D6; docs/serving.md "Models that hold state").  A model served through
+# ``DecodeEngine(model=...)`` declares one of them for every leaf, as a
+# pytree of these strings shaped like its cache.
+BLOCK_LEAF = "block"    # [num_blocks, block_size, ...]: a position lives
+#                         where the slot's block table says (K/V, latents)
+SLOT_LEAF = "slot"      # [num_slots, ...]: owned whole by the slot, not
+#                         addressed by position (recurrent state)
+
+
+def map_block_leaves(fn, cache, kinds, *rest):
+    """``tree_map(fn, cache, *rest)`` over the block-addressed leaves
+    alone; slot-addressed leaves come back as they are (block writes and
+    copies have nothing to say about them).  ``kinds=None``: every leaf is
+    block-addressed (the transformer trunk's K/V pools).  ``rest`` trees
+    are shaped like ``cache``; their slot-addressed entries are ignored."""
+    import jax
+    if kinds is None:
+        return jax.tree_util.tree_map(fn, cache, *rest)
+    return jax.tree_util.tree_map(
+        lambda kind, leaf, *r: fn(leaf, *r) if kind == BLOCK_LEAF else leaf,
+        kinds, cache, *rest)
+
+
+def leaf_bytes(cache, kinds, kind):
+    """Bytes of the cache leaves that ``kinds`` declares of ``kind``."""
+    import jax
+    return sum(int(l.size) * l.dtype.itemsize
+               for l, k in zip(jax.tree_util.tree_leaves(cache),
+                               jax.tree_util.tree_leaves(kinds))
+               if k == kind)
+
+
 class WireFormatError(ValueError):
     """A chain blob violates the ``serialize_chain`` wire format
     (truncated, oversized, inconsistent manifest, foreign trunk).

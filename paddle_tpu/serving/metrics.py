@@ -121,6 +121,11 @@ class ServingMetrics:
         #                                  resident prefix blocks
         self.prefix_cache_misses = 0     # fresh admissions that prefilled
         self.cow_forks = 0               # copy-on-write block forks
+        # ---- models that hold state (DecodeEngine(model=...)): what the
+        # two kinds of cache leaf hold, and how often a slot started over
+        self.recurrent_state_bytes = 0   # gauge: slot-addressed leaves
+        self.latent_pool_bytes = 0       # gauge: the model's block pools
+        self.state_resets_total = 0      # slots seated at position 0
         # ---- hierarchical KV host tier (decode_engine.py kv_host_bytes
         # over serving/kv_pool.HostTier): evicted prefix chains spill to
         # host RAM and restore over the host link instead of recomputing
@@ -259,6 +264,19 @@ class ServingMetrics:
     def observe_cow_fork(self, n=1):
         with self._lock:
             self.cow_forks += int(n)
+
+    def set_state_cache_bytes(self, slot_bytes, block_bytes):
+        """Gauges: bytes of a served model's slot-addressed state and of
+        its block-addressed pools (both 0 for the transformer trunk)."""
+        with self._lock:
+            self.recurrent_state_bytes = int(slot_bytes)
+            self.latent_pool_bytes = int(block_bytes)
+
+    def observe_state_reset(self, n=1):
+        """A slot of a state-holding model was seated at position 0: the
+        next step zeroes its state."""
+        with self._lock:
+            self.state_resets_total += int(n)
 
     def set_kv_pool(self, free, total):
         """Snapshot the block pool's free/allocatable gauges."""
@@ -442,6 +460,9 @@ class ServingMetrics:
                 "prefix_cache_hits_total": self.prefix_cache_hits,
                 "prefix_cache_misses_total": self.prefix_cache_misses,
                 "cow_forks_total": self.cow_forks,
+                "recurrent_state_bytes": self.recurrent_state_bytes,
+                "latent_pool_bytes": self.latent_pool_bytes,
+                "state_resets_total": self.state_resets_total,
                 "kv_spill_blocks_total": self.kv_spill_blocks_total,
                 "kv_restore_hits_total": self.kv_restore_hits_total,
                 "kv_restore_bytes_total": self.kv_restore_bytes_total,
@@ -561,6 +582,9 @@ class ServingMetrics:
                  "fresh admissions that re-prefilled (paged KV cache)"),
                 ("cow_forks_total", self.cow_forks,
                  "copy-on-write KV block forks (paged KV cache)"),
+                ("state_resets_total", self.state_resets_total,
+                 "slots of a state-holding model seated at position 0 "
+                 "(the step zeroes their recurrent state)"),
                 ("kv_spill_blocks_total", self.kv_spill_blocks_total,
                  "KV blocks serialized to the host tier at prefix "
                  "eviction (hierarchical KV)"),
@@ -604,6 +628,8 @@ class ServingMetrics:
             chunk_size = self.prefill_chunk_size
             spec_k = self.speculate_k
             mesh_shards = self.mesh_shards
+            state_bytes = self.recurrent_state_bytes
+            latent_bytes = self.latent_pool_bytes
         for metric, value, help_ in gen_counters:
             emit(metric, value, help_, mtype="counter")
         emit("prefill_chunk_size", chunk_size,
@@ -631,6 +657,12 @@ class ServingMetrics:
         emit("kv_block_utilization",
              f"{((kv_total - kv_free) / kv_total if kv_total else 0.0):.6f}",
              "fraction of the paged KV pool in use")
+        emit("recurrent_state_bytes", state_bytes,
+             "bytes of slot-addressed recurrent state a served model "
+             "holds (0 = the transformer trunk)")
+        emit("latent_pool_bytes", latent_bytes,
+             "bytes of a served model's block-addressed pools (latent "
+             "attention; 0 = the transformer trunk)")
         emit("kv_cache_int8", int(kv_int8),
              "1 when the KV cache stores int8 + per-head scale sidecars "
              "(quantized serving; docs/serving.md)")

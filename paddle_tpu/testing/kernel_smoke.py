@@ -59,6 +59,9 @@ class Widths:
     lstm_tiled_len: int     # (short: keeps the case's arrays small)
     blocked_hidden: int     # lstm_blocked (the over-VMEM variant)
     blocked_len: int        # odd: exercises the t-parity pad
+    kda_slots: int = 4      # kda_chunk: rows, heads of head_dim x head_dim
+    kda_heads: int = 8      # state, lanes a row
+    kda_chunk: int = 16
 
 
 SMALL = Widths(heads=8, kv_heads=2, head_dim=128, slots=8, slab_len=256,
@@ -74,7 +77,9 @@ SERVING = Widths(heads=16, kv_heads=16, head_dim=128, slots=8,
                  flash_batch=2, flash_len=2048, flash_block=512,
                  rnn_batch=64, rnn_len=100, rnn_hidden=512,
                  lstm_cell_batch=1024, lstm_tiled_batch=256,
-                 lstm_tiled_hidden=1280, lstm_tiled_len=25, blocked_hidden=1280, blocked_len=25)
+                 lstm_tiled_hidden=1280, lstm_tiled_len=25, blocked_hidden=1280, blocked_len=25,
+                 # the kimilinear_reason cell's step: 32 slots of 32 heads
+                 kda_slots=32, kda_heads=32, kda_chunk=16)
 
 
 class Case(NamedTuple):
@@ -83,6 +88,8 @@ class Case(NamedTuple):
     args: tuple
     err: Callable       # (got, want) -> float
     facts: dict = {}    # what the case knows of its path; joins its row
+    tol: tuple = None   # (tolerance, why) of a case that computes in one
+    #                     precision however it runs; else by what ran
 
 
 class Declined(NamedTuple):
@@ -460,6 +467,44 @@ def _decode_case(w, *, paged, chunk, quant, seed):
                 lambda got, want: _max_err(got[live], want[live]))
 
 
+# --------------------------------------------------------- delta rule
+
+_WHY_KDA = ("kda_chunk is float32 VPU arithmetic whether Mosaic compiles it "
+            "or the interpreter runs it (no MXU pass): summation order is "
+            "all that differs from the float32 scan, and a bfloat16 state "
+            "or operand is 2^-9 * max|S| ~ 1e-2 off and fails")
+
+
+def _kda_case(w):
+    """``kda_chunk`` against the scan (ops/kda.recurrence_scan): unit q and
+    k, decays in (0.3, 1), states N(0, 0.5^2); row 0 is a decode row (one
+    lane), row 1 fills every lane, row 2 is fresh (its stale state must
+    not leak), the rest draw their lane counts."""
+    from paddle_tpu.ops import kda
+    from paddle_tpu.ops.pallas import kda as kernel
+    s, kk, h, d = w.kda_slots, w.kda_chunk, w.kda_heads, w.head_dim
+    why = kernel.shape_problem(kk, h, d, d,
+                               interpret=jax.default_backend() != "tpu")
+    if why:
+        return Declined(why)
+    ks = jax.random.split(jax.random.PRNGKey(110), 7)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(ks[0], (s, kk, h, d))) * d ** -0.5
+    k = unit(jax.random.normal(ks[1], (s, kk, h, d)))
+    v = 0.5 * jax.random.normal(ks[2], (s, kk, h, d))
+    a = jax.random.uniform(ks[3], (s, kk, h, d), minval=0.3, maxval=1.0)
+    beta = jax.random.uniform(ks[4], (s, kk, h))
+    state = 0.5 * jax.random.normal(ks[5], (s, h, d, d))
+    lengths = jax.random.randint(ks[6], (s,), 1, kk + 1) \
+        .at[0].set(1).at[1].set(kk)
+    fresh = jnp.zeros((s,), bool).at[2].set(True)
+    return Case(fn=kernel.kda_chunk, oracle=kda.recurrence_scan,
+                args=(q, k, v, a, beta, state, lengths, fresh),
+                err=_tree_rel_err,
+                facts={"state_bytes": int(state.size) * 4},
+                tol=(_TOL_INTERPRETED, _WHY_KDA))
+
+
 def _decode(paged, chunk, quant, seed):
     return lambda w: _decode_case(w, paged=paged, chunk=chunk, quant=quant,
                                   seed=seed)
@@ -486,6 +531,7 @@ CASES = {
     "decode_attention_paged_int8": _decode(True, False, True, 70),
     "decode_attention_slab_chunk_int8": _decode(False, True, True, 80),
     "decode_attention_paged_chunk_int8": _decode(True, True, True, 90),
+    "kda_chunk": _kda_case,
 }
 
 
@@ -529,8 +575,9 @@ def run_case(name, widths=SMALL, expect_compiled=False):
     if expect_compiled:
         assert not any(seen), \
             f"{name}: {sum(seen)}/{len(seen)} pallas_calls interpreted"
-    tol, why = ((_TOL_INTERPRETED, _WHY_INTERPRETED) if all(seen)
-                else (_TOL_COMPILED, _WHY_COMPILED))
+    tol, why = case.tol or (
+        (_TOL_INTERPRETED, _WHY_INTERPRETED) if all(seen)
+        else (_TOL_COMPILED, _WHY_COMPILED))
     with f32_reference():
         want = jax.jit(case.oracle)(*case.args)
         jax.block_until_ready(want)

@@ -275,7 +275,9 @@ def test_router_readiness_gating_and_least_loaded():
                     hedge_ms=0)
     httpd = router.start(port=0)
     try:
-        assert _wait(router.ready, 10)
+        # both ready replicas polled (the router is ready after the first)
+        assert _wait(lambda: all(router.replica_states()[r]["ready"]
+                                 for r in ("r1", "r2")), 10)
         for _ in range(4):
             st, out = _post(httpd.port, "/v1/infer", {"feed": {}})
             assert st == 200 and "outputs" in out

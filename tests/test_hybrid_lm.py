@@ -1,0 +1,319 @@
+"""The hybrid served trunk (models/hybrid_lm.py: KDA + MLA + held experts) at
+tiny widths on the CPU: the served path against the plain reference
+(benchmark/reference/kimi_linear.py), the share of the experts against the
+uncut layer, and what a slot's state is owed (zero at position 0, nothing
+from lanes past its length)."""
+
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from benchmark.drivers import serve_hybrid  # noqa: E402
+from benchmark.reference import kimi_linear as reference  # noqa: E402
+from paddle_tpu.models import hybrid_lm  # noqa: E402
+from paddle_tpu.ops import kda, moe  # noqa: E402
+from paddle_tpu.ops.pallas import kda as kda_kernel  # noqa: E402
+from paddle_tpu.serving.decode_engine import (DecodeEngine,  # noqa: E402
+                                              GenerationBatcher)
+from paddle_tpu.utils.error import ConfigError  # noqa: E402
+
+BLOCK = 4
+
+
+def tiny(**over):
+    """hidden 64, layers KDA KDA KDA MLA, 16 experts top-4 (all held unless
+    ``expert_parallel`` says otherwise), as a published config.json."""
+    with open(os.path.join(ROOT, "benchmark", "testdata", "configs",
+                           "tiny-hybrid.json")) as f:
+        hf = json.load(f)
+    hf.pop("expert_parallel")
+    hf.update(num_experts=16, vocab_size=97)
+    hf["serving"] = dict(hf["serving"], kv_block_size=BLOCK)
+    hf.update(over)
+    return hf
+
+
+@pytest.fixture(scope="module")
+def hf():
+    return tiny()
+
+
+@pytest.fixture(scope="module")
+def params(hf):
+    return serve_hybrid.make_params(hf, 11)
+
+
+def run_chunks(hf, params, seqs, kk, cache=None, start=None):
+    """Feed ``seqs`` through ``decode_chunk`` ``kk`` lanes at a time (row i
+    one lane fewer, so that rows never move in step) -> (logits at every
+    row's last position, cache)."""
+    cfg = hybrid_lm.config_from_hf(hf)
+    n = len(seqs)
+    nb_row = -(-max(map(len, seqs)) // BLOCK) + 1
+    tables = jnp.asarray(np.arange(1, n * nb_row + 1, dtype=np.int32)
+                         .reshape(n, nb_row))
+    if cache is None:
+        cache = hybrid_lm.init_cache(cfg, n, n * nb_row + 1, BLOCK)
+    step = jax.jit(lambda p, c, t, pos, l: hybrid_lm.decode_chunk(
+        p, cfg, t, pos, l, c, tables))
+    cursor = list(start or [0] * n)
+    last = [None] * n
+    while any(c < len(s) for c, s in zip(cursor, seqs)):
+        tok = np.zeros((n, kk), np.int32)
+        pos, lens = np.zeros(n, np.int32), np.ones(n, np.int32)
+        fed = [0] * n
+        for i, s in enumerate(seqs):
+            if cursor[i] >= len(s):     # done: idle past the end
+                tok[i, 0], pos[i] = s[-1], len(s)
+                continue
+            piece = s[cursor[i]:cursor[i] + max(1, kk - i % 2)]
+            tok[i, :len(piece)], pos[i], lens[i] = piece, cursor[i], \
+                len(piece)
+            fed[i] = len(piece)
+        logits, new = step(params, cache, tok, pos, lens)
+        # a row that has nothing to feed must idle on SOME lane (lengths
+        # are at least 1); the test puts its slot-addressed state back,
+        # as the engine never idles a seated slot
+        idle = jnp.asarray([f == 0 for f in fed])
+        cache = jax.tree_util.tree_map(
+            lambda kind, old, now: jnp.where(
+                idle.reshape((n,) + (1,) * (now.ndim - 1)), old, now)
+            if kind == "slot" else now,
+            hybrid_lm.cache_kinds(cfg), cache, new)
+        for i in range(n):
+            cursor[i] += fed[i]
+            if fed[i] and cursor[i] == len(seqs[i]):
+                last[i] = np.asarray(logits[i])
+    return last, cache
+
+
+def prompts(lengths, seed=0, vocab=97):
+    rng = np.random.RandomState(seed)
+    return [rng.randint(1, vocab, n).tolist() for n in lengths]
+
+
+@pytest.mark.parametrize("kk", [1, 5, 8])
+def test_served_path_matches_reference(hf, params, kk):
+    """Prefill in chunks of K, then decode, through the state and the
+    latent pool: logits against the reference's full forward."""
+    seqs = prompts([21, 37])
+    last, cache = run_chunks(hf, params, seqs, kk)
+    ids = np.zeros((2, 40), np.int32)
+    for step in range(3):           # the prompt's end, then two decode steps
+        for i, s in enumerate(seqs):
+            ids[i, :len(s)] = s
+        want, _ = reference.logits(serve_hybrid.reference_params(params, hf),
+                                   jnp.asarray(ids), hf)
+        for i, s in enumerate(seqs):
+            np.testing.assert_allclose(last[i], np.asarray(want)[i,
+                                                                 len(s) - 1],
+                                       atol=2e-4)
+        grown = [s + [int(l.argmax())] for s, l in zip(seqs, last)]
+        last, cache = run_chunks(hf, params, grown, 1, cache,
+                                 [len(s) for s in seqs])
+        seqs = grown
+
+
+@pytest.mark.parametrize("kk", [3, 8])
+def test_chunked_prefill_equals_one_pass(hf, params, kk):
+    seqs = prompts([16, 16], seed=3)
+    one, _ = run_chunks(hf, params, seqs, 17)
+    many, _ = run_chunks(hf, params, seqs, kk)
+    for a, b in zip(one, many):
+        np.testing.assert_allclose(a, b, atol=2e-4)
+
+
+@pytest.mark.parametrize("shares", [2, 4])
+def test_shares_add_up_to_the_uncut_layer(hf, params, shares):
+    """The routed parts that all the shares give, plus the shared expert
+    counted once, are the uncut reference layer."""
+    mc = hybrid_lm.config_from_hf(hf)
+    layer = next(lp for lp, (_a, f) in zip(params["layers"], mc.layers)
+                 if f == "moe")["ffn"]
+    x = jax.random.normal(jax.random.PRNGKey(5), (24, mc.hidden_size))
+    idx, w = moe.sigmoid_router(x, layer["router"], layer["router_bias"],
+                                mc.top_k, mc.routed_scale)
+    count = mc.router_width // shares
+    total = moe.gated_ffn(x, *(layer["shared"][k] for k in ("wg", "wu",
+                                                            "wd")))
+    for rank in range(shares):
+        held = {k: layer[k][rank * count:(rank + 1) * count]
+                for k in ("wg", "wu", "wd")}
+        total = total + moe.routed_experts(x, idx, w, held,
+                                           (rank * count, count))
+    ref_layer = {"router": layer["router"],
+                 "router_bias": layer["router_bias"],
+                 "shared": layer["shared"],
+                 "experts": {k: layer[k] for k in ("wg", "wu", "wd")}}
+    with jax.default_matmul_precision("highest"):
+        want, _ = reference.moe(x, ref_layer, hf)
+    np.testing.assert_allclose(total, want, atol=1e-4)
+
+
+@pytest.mark.parametrize("valid", [None, "half"])
+def test_routed_layer_matches_all_experts_einsum(hf, params, valid):
+    """Sorted by expert and grouped, against every expert on every token
+    weighed by dense gates (the old ``moe_ffn`` formulation)."""
+    mc = hybrid_lm.config_from_hf(hf)
+    layer = params["layers"][1]["ffn"]
+    x = jax.random.normal(jax.random.PRNGKey(6), (20, mc.hidden_size))
+    idx, w = moe.sigmoid_router(x, layer["router"], layer["router_bias"],
+                                mc.top_k, mc.routed_scale)
+    mask = None if valid is None else jnp.arange(20) % 2 == 0
+    got = moe.routed_experts(x, idx, w, layer, mc.held, valid=mask)
+    gates = (jax.nn.one_hot(idx, mc.router_width) * w[..., None]).sum(1)
+    h = jax.nn.silu(jnp.einsum("nd,edf->nef", x, layer["wg"])) \
+        * jnp.einsum("nd,edf->nef", x, layer["wu"])
+    want = jnp.einsum("nef,efd,ne->nd", h, layer["wd"], gates)
+    if mask is not None:
+        want = jnp.where(mask[:, None], want, 0.0)
+    np.testing.assert_allclose(got, want, atol=1e-4)
+
+
+@pytest.mark.parametrize("first", ["fresh", "stale"])
+def test_reseated_slot_starts_from_zero_state(hf, params, first):
+    """A row whose chunk starts at position 0 computes what a fresh cache
+    computes, whatever the previous occupant left in the slot."""
+    seqs = prompts([13, 9], seed=8)
+    cache = None
+    if first == "stale":
+        _, cache = run_chunks(hf, params, prompts([19, 23], seed=9), 8)
+        assert all(float(jnp.abs(c["state"]).max()) > 0
+                   for c in cache if "state" in c)
+    got, _ = run_chunks(hf, params, seqs, 8, cache)
+    want, _ = run_chunks(hf, params, seqs, 8)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("garbage", [1, 50])
+def test_lanes_past_lengths_change_nothing(hf, params, garbage):
+    """What sits in the lanes at or past a row's ``lengths`` reaches neither
+    the logits nor the state, the convolution tail or the latent pool."""
+    cfg = hybrid_lm.config_from_hf(hf)
+    tables = jnp.asarray(np.arange(1, 9, dtype=np.int32).reshape(2, 4))
+    cache = hybrid_lm.init_cache(cfg, 2, 9, BLOCK)
+    tok = np.asarray(prompts([8, 8], seed=4), np.int32)
+    pos, lens = np.zeros(2, np.int32), np.asarray([3, 5], np.int32)
+    step = jax.jit(lambda t: hybrid_lm.decode_chunk(params, cfg, t, pos,
+                                                    lens, cache, tables))
+    base_logits, base_cache = step(tok)
+    other = tok.copy()
+    other[0, 3:], other[1, 5:] = garbage, garbage
+    logits, new_cache = step(other)
+    np.testing.assert_array_equal(base_logits, logits)
+    for a, b in zip(jax.tree_util.tree_leaves(base_cache),
+                    jax.tree_util.tree_leaves(new_cache)):
+        np.testing.assert_array_equal(a, b)
+    # and a state leaf moved at all only for rows that had lanes
+    assert float(jnp.abs(new_cache[0]["state"]).max()) > 0
+
+
+@pytest.mark.parametrize("s,kk,heads,dk,hp", [(3, 8, 4, 16, 2),
+                                              (2, 16, 8, 8, 8),
+                                              (5, 4, 2, 32, 1)])
+def test_kda_kernel_interpreted_matches_scan(s, kk, heads, dk, hp):
+    ks = jax.random.split(jax.random.PRNGKey(s), 8)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q, k = (unit(jax.random.normal(ks[i], (s, kk, heads, dk)))
+            for i in (0, 1))
+    v = jax.random.normal(ks[2], (s, kk, heads, dk))
+    a = jax.random.uniform(ks[3], (s, kk, heads, dk), minval=0.3)
+    beta = jax.random.uniform(ks[4], (s, kk, heads))
+    state = jax.random.normal(ks[5], (s, heads, dk, dk))
+    lengths = jax.random.randint(ks[6], (s,), 1, kk + 1).at[0].set(1)
+    fresh = jnp.zeros((s,), bool).at[1].set(True)
+    args = (q, k, v, a, beta, state, lengths, fresh)
+    o, st = kda_kernel.kda_chunk(*args, hp=hp, interpret=True)
+    o_want, st_want = kda.recurrence_scan(*args)
+    np.testing.assert_allclose(o, o_want, atol=1e-5)
+    np.testing.assert_allclose(st, st_want, atol=1e-5)
+    # a decode row moved its state by one lane only; a fresh row forgot
+    assert float(jnp.abs(o[0, 1:]).max()) == 0.0
+    assert kda_kernel.heads_per_program(16, 32) == 8
+
+
+def test_kda_kernel_guard_names_its_reason():
+    assert "pallas_decode" in kda_kernel.decline_reason(16, 32, 128, 128)
+    assert kda_kernel.shape_problem(16, 32, 128, 128) is None
+    assert "lanes" in kda_kernel.shape_problem(12, 32, 128, 128)
+    assert "heads" in kda_kernel.shape_problem(16, 6, 128, 128, hp=4)
+
+
+REFUSED = {
+    "prefix_cache": dict(prefix_cache=True),
+    "speculate_k": dict(speculate_k=2, draft=object()),
+    "kv_host_bytes": dict(kv_host_bytes=1 << 20),
+    "mesh": dict(mesh=object()),
+    "slab": dict(kv_layout="slab"),
+    "ladder": dict(prefill_chunk=0),
+}
+
+
+@pytest.mark.parametrize("what", sorted(REFUSED))
+def test_state_holding_model_refuses(hf, params, what):
+    """Each names what is missing instead of serving wrong tokens."""
+    kw = dict(num_slots=2, max_len=32, kv_layout="paged",
+              kv_block_size=BLOCK, prefix_cache=False, prefill_chunk=4,
+              warm=False)
+    kw.update(REFUSED[what])
+    model = hybrid_lm.Served(hybrid_lm.config_from_hf(hf))
+    with pytest.raises(ConfigError) as e:
+        DecodeEngine(params, model=model, **kw)
+    needle = {"slab": "paged layout", "ladder": "chunked step",
+              "mesh": "placement rule"}.get(what, "state snapshot")
+    assert needle in str(e.value)
+
+
+def test_engine_serves_the_hybrid_trunk(hf, params):
+    """Through DecodeEngine -> GenerationBatcher with more requests than
+    slots: every stream is the reference's greedy continuation, the step
+    traced once, slots reseated, the gauges and the counter set."""
+    model = hybrid_lm.Served(hybrid_lm.config_from_hf(hf))
+    engine = DecodeEngine(params, model=model, num_slots=2, max_len=64,
+                          kv_layout="paged", kv_block_size=BLOCK,
+                          prefix_cache=False, prefill_chunk=8, name="hy")
+    assert engine.kda_kernels is False
+    assert "pallas_decode" in engine.kda_decline_reason
+    reqs = prompts([21, 5, 30, 11], seed=2)
+    engine.record_steps(True)
+    with GenerationBatcher(engine, default_max_tokens=4) as gen:
+        outs = [f.result(120) for f in
+                [gen.submit(p, max_tokens=4) for p in reqs]]
+    ref_params = serve_hybrid.reference_params(params, hf)
+    for prompt, out in zip(reqs, outs):
+        seq = list(prompt)
+        for tok in out["tokens"]:
+            want, _ = reference.logits(ref_params,
+                                       jnp.asarray([seq], jnp.int32), hf)
+            row = np.asarray(want)[0, -1]
+            assert row.max() - row[tok] < 1e-4
+            seq.append(tok)
+    # what the server's own steps chose, for a check to hand its reference
+    steps = engine.recorded_steps()
+    assert len(steps) == engine.metrics.decode_steps_total
+    tokens, pos, lens, chosen = steps[0]
+    assert tokens.shape == (2, 8) and pos.shape == lens.shape == (2,)
+    assert chosen.shape == (3, 2, 8, 4)         # expert layers, S, K, top_k
+    engine.record_steps(False)
+    assert engine.recorded_steps() == []
+    m = engine.metrics
+    assert engine.step_trace_count == 1
+    assert m.state_resets_total == 4
+    cache = model.init_cache(2, engine._paged.pool.num_blocks, BLOCK)
+    assert m.recurrent_state_bytes == sum(
+        c[k].size * 4 for c in cache for k in c if k != "latent")
+    assert m.latent_pool_bytes == sum(c["latent"].size * 4 for c in cache
+                                      if "latent" in c)
+    text = m.render_prometheus()
+    for name in ("recurrent_state_bytes", "latent_pool_bytes",
+                 "state_resets_total"):
+        assert name in text
