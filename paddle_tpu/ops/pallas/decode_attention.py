@@ -1,10 +1,10 @@
 """Fused decode-attention kernels (Pallas TPU): read the KV cache ONCE
 per step.
 
-The serving decode hot path is memory-bound on every analytic family
-(BENCH_ANALYTIC_r06.json), so bytes — not FLOPs — set the step time.
-The reference XLA paths in ``models/transformer`` pay for the KV cache
-more than once per layer per step:
+A serving step moves every live row's K/V once a layer, and its time is
+those bytes (docs/kernels.md "Decode-attention kernels"; PERF.md section
+5 has the measured ``opt1.3b_chat`` step).  The reference XLA paths in
+``models/transformer`` pay for the cache more than once:
 
 * slab (``_cached_self_attn_slots``): ``repeat_kv_heads`` widens the
   grouped K/V to full head width and the dense attention materializes
@@ -15,17 +15,19 @@ more than once per layer per step:
   ``[S, T, Dkv]`` HBM buffer — a second full read AND a full write of
   the logical cache — before the same widened-score dance.
 
-The two kernels here delete all of that traffic.  Per row the K/V
-stripe streams HBM -> VMEM exactly once; the masked online softmax
-(flash-style running max/sum, the ``flash_attention.py`` recipe) and
-the grouped-KV -> full-head expansion happen in VMEM/registers; neither
-the score matrix nor a second KV copy ever exists in HBM.
+The FOUR kernels here — (slab | paged) x (Tq=1 | Tq=chunk) — delete that
+traffic.  Per row the K/V stripe streams HBM -> VMEM exactly once; the
+masked online softmax (flash-style running max/sum, the
+``flash_attention.py`` recipe) and the grouped-KV -> full-head expansion
+happen in VMEM/registers; neither the score matrix nor a second KV copy
+ever exists in HBM.
 
-* ``decode_attention_slab``: grid ``(S, T/blk)`` with the kv dimension
-  innermost; per-row ``positions`` ride as SCALAR-PREFETCH data
-  (``pltpu.PrefetchScalarGridSpec``) so the k-block index map CLAMPS at
-  the row's position — blocks past a row's live prefix map to the same
-  block id, which the Pallas pipeline recognizes and never re-fetches.
+* ``decode_attention_slab`` / ``_slab_chunk``: grid ``(S, T/blk)`` with
+  the kv dimension innermost; per-row ``positions`` ride as
+  SCALAR-PREFETCH data (``pltpu.PrefetchScalarGridSpec``) so the k-block
+  index map CLAMPS at the row's position — blocks past a row's live
+  prefix map to the same block id, which the Pallas pipeline recognizes
+  and never re-fetches.
 
 * ``decode_attention_paged``: the per-slot block TABLE is the second
   scalar-prefetch operand and the kernel walks it directly — the
@@ -33,6 +35,19 @@ the score matrix nor a second KV copy ever exists in HBM.
   row reads ONLY the physical blocks it owns (clamped at its position,
   like the slab) and the chain gather disappears from the HLO entirely
   (perf/analytic.py's fusion-proof gate pins exactly that).
+
+* ``decode_attention_paged_chunk``, the one attention of the chunked
+  serving step (``K`` token lanes a row): a TILE of ``G`` table entries
+  (``paged_chunk_tile``: a lane row of positions, cut to the table and
+  the VMEM budget) is one step of a loop over the row's LIVE tiles
+  inside a grid of ``(S,)``; the pools stay in HBM and the row's blocks
+  are copied into a double-buffered ``[2, G*bs, Dkv]`` scratch by hand
+  (``_paged_tile_kernel``).  A table of 128 entries of 16 positions cost
+  1,024 grid steps a call, five in six of them dead at the serving
+  contexts; tiled, the ``opt1.3b_chat`` call went from 0.27 to 0.08 ms
+  (docs/kernels.md has the table).  ``G = 1`` — int8 K/V in a block
+  under an s8 tile, shapes whose panels are not whole lane rows — is the
+  block-a-grid-step kernel the other three still are.
 
 Masking matches ``_attend`` exactly: cols > positions[r] sit at -1e30,
 whose exp is 0.0 — cache width beyond a row's position never perturbs
@@ -49,14 +64,15 @@ multiply per KV-head group panel) — int8 is what streams from HBM and
 the widened K/V never exists in any memory.  ``kernel_cost`` declares
 the honest int8 byte counts (1-byte elements + the f32 sidecar).
 
-Dispatch: callers go through ``maybe_slab`` / ``maybe_paged``, which
-return None (caller falls back to the reference XLA path) unless the
-``pallas_decode`` flag enables the kernels — ``auto`` follows
-``use_pallas()`` (TPU only; the CPU tier-1 default stays the reference
-path, preserving the greedy bit-identity discipline), ``always`` forces
-them anywhere (interpret mode off-TPU — the CPU test/smoke mode), ``off``
-disables.  The flag is read at TRACE time: set it before constructing
-the engine/jitting the step.
+Dispatch: callers go through ``maybe_slab`` / ``maybe_paged`` /
+``maybe_slab_chunk`` / ``maybe_paged_chunk``, which return None (caller
+falls back to the reference XLA path) unless the ``pallas_decode`` flag
+enables the kernels — ``auto`` follows ``use_pallas()`` (TPU only; the
+CPU tier-1 default stays the reference path, preserving the greedy
+bit-identity discipline), ``always`` forces them anywhere (interpret
+mode off-TPU — the CPU test/smoke mode), ``off`` disables.  The flag is
+read at TRACE time: set it before constructing the engine/jitting the
+step.
 """
 
 import contextlib
@@ -222,6 +238,71 @@ def _tile_problem(blk, dkv, dh, interpret, quant=False):
                 f"double-buffered K/V, over the {vmem_budget_bytes()}-byte "
                 f"VMEM budget")
     return None
+
+
+def _panel_heads(hkv, dh):
+    """KV heads a panel of the tiled paged kernel holds: as many as fit
+    one row of LANES (2 heads of 64), so K and V are read in whole lane
+    tiles; 1 where a head fills the row."""
+    return max(p for p in range(1, hkv + 1)
+               if hkv % p == 0 and (p == 1 or p * dh <= _LANES))
+
+
+def paged_chunk_tile(num_heads, d, dkv, bs, nb_row, chunk, quant=False,
+                     interpret=None):
+    """Table entries G a tile of the paged Tq=chunk kernel covers (G x bs
+    positions), from what the call sees; 1 = the block-a-grid-step kernel.
+    One lane row of scores (LANES positions) is the aim — past it a tile
+    only adds masked tail to a row's last one — cut to the table, to the
+    ``pallas_decode_block_k`` cap and to the K/V tiles the VMEM budget
+    holds double-buffered.  int8 K/V keeps G = 1 (a block of 16 is half
+    an s8 tile); so does a compiled shape whose panels (``_panel_heads``)
+    are not whole lane rows, or whose panel rows are not whole sublanes."""
+    interpret = _interpret(interpret)
+    split = _head_split(d, dkv, num_heads)
+    if quant or split is None:
+        return 1
+    dh, hkv, group = split
+    if not interpret:
+        heads = _panel_heads(hkv, dh)
+        if (heads * group * chunk) % 8 or (
+                (heads * dh) % _LANES and heads != hkv):
+            return 1
+    cap = min(_LANES, _block_k_cap(), vmem_budget_bytes()
+              // _vmem_bytes_per_position(dkv, False))
+    g = max(1, min(nb_row, cap // bs))
+    while g > 1 and _tile_problem(g * bs, dkv, dh, interpret):
+        g -= 1
+    return g
+
+
+def _to_panels(q, hkv, group, dh):
+    """q [S, K, D] -> [S, panels, heads*group*K, heads*dh]: panel j holds
+    ``heads`` kv heads; row (u, gq, i) is lane i of query head (j*heads +
+    u)*group + gq, its dh values in head u's lanes and exact zeros in the
+    panel's other heads' (block-diagonal: one product with the panel's K
+    lanes scores every head, a zero adds nothing)."""
+    s, kk, _d = q.shape
+    heads = _panel_heads(hkv, dh)
+    x = q.reshape(s, kk, hkv // heads, heads, group, dh)
+    x = x.transpose(0, 2, 3, 4, 1, 5).reshape(
+        s, hkv // heads, heads, group * kk, dh)
+    if heads > 1:
+        eye = jnp.eye(heads, dtype=q.dtype)
+        x = x[:, :, :, :, None, :] * eye[None, None, :, None, :, None]
+    return x.reshape(s, hkv // heads, heads * group * kk, heads * dh)
+
+
+def _from_panels(o, kk, hkv, group, dh):
+    """``_to_panels``'s inverse on the kernel's output: each head's own
+    lanes of its rows (the others hold its weights over a neighbour's
+    values) -> [S, K, D]."""
+    s = o.shape[0]
+    heads = _panel_heads(hkv, dh)
+    x = o.reshape(s, hkv // heads, heads, group * kk, heads, dh)
+    x = jnp.stack([x[:, :, u, :, u] for u in range(heads)], axis=2)
+    x = x.reshape(s, hkv // heads, heads, group, kk, dh)
+    return x.transpose(0, 4, 1, 2, 3, 5).reshape(s, kk, hkv * group * dh)
 
 
 # ------------------------------------------------------------ kernel body
@@ -418,6 +499,100 @@ def _chunk_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, blk, kk,
 def _paged_chunk_kernel(pos_ref, tbl_ref, *args, **kw):
     del tbl_ref
     _chunk_kernel(pos_ref, *args, **kw)
+
+
+def _paged_tile_kernel(pos_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf,
+                       vbuf, sem, first_slot, m_scr, l_scr, acc_scr, *, bs,
+                       g, kk, scale):
+    """Tq=chunk paged body with a TILE of ``g`` table entries: one grid
+    step is one ROW, and the row's live tiles are a loop inside it.
+
+    The pools stay in HBM (``pl.ANY``).  Tile t of a row is its table
+    entries ``[t*g, (t+1)*g)``: each LIVE entry's block is copied into its
+    ``bs`` rows of ``kbuf/vbuf[slot]`` ([2, g*bs, Dkv], double-buffered by
+    hand) — an entry past the row's furthest lane is neither copied nor
+    addressed, its rows keep finite data of an earlier tile (``vbuf`` is
+    zeroed once a call: a masked score is an exact 0.0, and 0.0 x NaN is
+    not) under columns the mask removes.  While tile t is consumed tile
+    t+1 is in flight, and during a row's LAST tile the next row's first:
+    ``first_slot`` (SMEM) carries the buffer parity from row to row.
+
+    q arrives PANEL-major (``_to_panels``): panel j is the ``wp`` lanes of
+    K/V that hold its kv heads, and its query rows — every lane of every
+    head of the panel, block-diagonal over the panel's heads — meet the
+    tile in ONE ``[mp, wp] x [g*bs, wp]`` product, so K and V are read in
+    whole lane tiles and a prefilling row's lanes share each product.
+    The online softmax is ``_accumulate``'s, on ``[mp, g*bs]`` scores."""
+    r = pl.program_id(0)
+    tile = g * bs
+    n_p, mp, wp = acc_scr.shape
+    last = pos_ref[r, kk - 1]
+    n_tiles = last // tile + 1
+
+    def copies(row, t, slot, op):
+        live = pos_ref[row, kk - 1] // bs + 1
+        for i in range(g):
+            @pl.when(t * g + i < live)
+            def _():
+                bid = tbl_ref[row, t * g + i]
+                for hbm, buf in ((k_hbm, kbuf), (v_hbm, vbuf)):
+                    cp = pltpu.make_async_copy(
+                        hbm.at[bid], buf.at[slot, pl.ds(i * bs, bs)],
+                        sem.at[slot])
+                    getattr(cp, op)()
+
+    @pl.when(r == 0)
+    def _():
+        vbuf[...] = jnp.zeros_like(vbuf)
+        first_slot[0] = 0
+        copies(0, 0, 0, "start")
+
+    first = first_slot[0]
+    _init_row(m_scr, l_scr, acc_scr)
+    # row (u, gq, i) of a panel is lane i of one head: its own position
+    lane = jax.lax.broadcasted_iota(jnp.int32, (mp, tile), 0) % kk
+    lim = jnp.full((mp, tile), pos_ref[r, 0], jnp.int32)
+    for i in range(1, kk):
+        lim = jnp.where(lane >= i, pos_ref[r, i], lim)
+    col = jax.lax.broadcasted_iota(jnp.int32, (mp, tile), 1)
+
+    def body(t, carry):
+        slot = (first + t) % 2
+
+        @pl.when(t + 1 < n_tiles)
+        def _():
+            copies(r, t + 1, 1 - slot, "start")
+
+        @pl.when(jnp.logical_and(t + 1 == n_tiles,
+                                 r + 1 < pl.num_programs(0)))
+        def _():
+            copies(r + 1, 0, 1 - slot, "start")
+
+        copies(r, t, slot, "wait")
+        seen = col + t * tile <= lim
+        for j in range(n_p):
+            cols = slice(j * wp, (j + 1) * wp)
+            s = jax.lax.dot_general(
+                q_ref[0, j].astype(jnp.float32), kbuf[slot, :, cols],
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale    # [mp, tile]
+            s = jnp.where(seen, s, _NEG)
+            m_prev, l_prev = m_scr[j], l_scr[j]                # [mp, LANES]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - _lanes(m_new, tile))
+            alpha = jnp.exp(m_prev - m_new)
+            m_scr[j] = m_new
+            l_scr[j] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc_scr[j] = acc_scr[j] * _lanes(alpha, wp) + jax.lax.dot_general(
+                p, vbuf[slot, :, cols], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)            # [mp, wp]
+        return carry
+
+    jax.lax.fori_loop(0, n_tiles, body, 0)
+    first_slot[0] = (first + n_tiles) % 2
+    l = jnp.maximum(l_scr[...], 1e-30)
+    for j in range(n_p):
+        o_ref[0, j] = (acc_scr[j] / _lanes(l[j], wp)).astype(o_ref.dtype)
 
 
 # ------------------------------------------------------------ public API
@@ -679,6 +854,11 @@ def decode_attention_paged_chunk(q, k, v, qpos, tables, num_heads, *,
     problem = _tile_problem(bs, dkv, dh, interpret, quant=quant)
     if problem:
         raise ValueError(f"decode_attention_paged_chunk: {problem}")
+    g = paged_chunk_tile(num_heads, d, dkv, bs, nb_row, kk, quant=quant,
+                         interpret=interpret)
+    if g > 1:
+        return _paged_chunk_tiled(q, k, v, qpos, tables, g=g,
+                                  num_heads=num_heads, interpret=interpret)
     scale = 1.0 / math.sqrt(dh)
     kernel = functools.partial(_paged_chunk_kernel, blk=bs, kk=kk,
                                num_heads=num_heads, hkv=hkv, dh=dh,
@@ -721,6 +901,48 @@ def decode_attention_paged_chunk(q, k, v, qpos, tables, num_heads, *,
     )(jnp.asarray(qpos, jnp.int32),
       jnp.asarray(tables, jnp.int32), *operands)
     return out.reshape(s, kk, d)
+
+
+@functools.partial(jax.jit, static_argnames=("g", "num_heads", "interpret"))
+def _paged_chunk_tiled(q, k, v, qpos, tables, *, g, num_heads, interpret):
+    """``decode_attention_paged_chunk`` at G > 1 (``_paged_tile_kernel``):
+    grid (S,), q and the output panel-major, the pools left in HBM.
+    Jitted so that the layers of a step, which call it with the same
+    shapes, share ONE trace of the kernel and one Mosaic lowering (the
+    step is traced a layer at a time; XLA inlines the calls)."""
+    s, kk, d = q.shape
+    bs, dkv = k.shape[1], k.shape[2]
+    dh, hkv, group = _head_split(d, dkv, num_heads)
+    qp = _to_panels(q, hkv, group, dh)
+    _s, n_p, mp, wp = qp.shape
+    row = pl.BlockSpec((1, n_p, mp, wp), lambda r, pos, tbl: (r, 0, 0, 0))
+    pool = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(s,),
+        in_specs=[row, pool, pool],
+        out_specs=row,
+        scratch_shapes=[
+            pltpu.VMEM((2, g * bs, dkv), k.dtype),
+            pltpu.VMEM((2, g * bs, dkv), v.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((n_p, mp, _LANES), jnp.float32),
+            pltpu.VMEM((n_p, mp, _LANES), jnp.float32),
+            pltpu.VMEM((n_p, mp, wp), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_paged_tile_kernel, bs=bs, g=g, kk=kk,
+                          scale=1.0 / math.sqrt(dh)),
+        grid_spec=grid_spec, name="decode_attn_paged_chunk",
+        out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
+        cost_estimate=kernel_cost(s, tables.shape[1] * bs, d, dkv,
+                                  q.dtype.itemsize, tq=kk,
+                                  kv_itemsize=k.dtype.itemsize),
+        interpret=interpret,
+    )(jnp.asarray(qpos, jnp.int32), jnp.asarray(tables, jnp.int32), qp, k, v)
+    return _from_panels(out, kk, hkv, group, dh)
 
 
 # ------------------------------------------------------------ dispatch
@@ -781,6 +1003,25 @@ def decline_reason(num_heads, d, dkv, blk_len, paged=False, chunk=1,
         return (f"no k-tile divides slab length {blk_len} under the "
                 "sublane, lane and VMEM-budget constraints")
     return _tile_problem(blk, dkv, split[0], interpret, quant=quant)
+
+
+def tile_positions(num_heads, d, dkv, blk_len, nb_row=1, paged=False,
+                   chunk=1, quant=False, shards=1):
+    """K/V positions ONE step of the kernel covers for shapes
+    ``decline_reason`` accepts (same arguments; ``nb_row``: the table's
+    entries a row), judged like it on the per-chip stripe: the slab
+    kernels' k-tile, a pool block at Tq=1, G blocks at Tq=chunk
+    (``paged_chunk_tile``).  ``DecodeEngine.warmup`` logs it beside the
+    resolved path, so a run's output says which tile served it."""
+    shards = max(1, int(shards))
+    num_heads, d, dkv = num_heads // shards, d // shards, dkv // shards
+    if not paged:
+        return _pick_block_k(blk_len, _block_k_cap(), _interpret(None),
+                             quant=quant, dkv=dkv)
+    if chunk == 1:
+        return blk_len
+    return blk_len * paged_chunk_tile(num_heads, d, dkv, blk_len, nb_row,
+                                      chunk, quant=quant)
 
 
 def covers(*args, **kw):

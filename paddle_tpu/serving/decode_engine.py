@@ -472,6 +472,9 @@ class DecodeEngine:
         # the guard's reason (ops/pallas/decode_attention.decline_reason)
         self.decode_kernels = False
         self.decode_decline_reason = None
+        # K/V positions a step of the fused kernel covers
+        # (decode_attention.tile_positions); None on the reference path
+        self.decode_tile = None
         # the same for a model's recurrent kernel (ops/pallas/kda.py):
         # False and None where the model has no such layer
         self.kda_kernels = False
@@ -1749,19 +1752,23 @@ class DecodeEngine:
         if enc:
             d = int(_w_shape(self.params["src_emb"])[1])
             dkv = int(_w_shape(enc[0]["attn"]["wk"])[1])
-            blk_len = (self.block_size if self.kv_layout == "paged"
-                       else self.max_len)
+            paged = self.kv_layout == "paged"
+            blk_len = self.block_size if paged else self.max_len
             # decline_reason() sees the PER-CHIP stripe (shards=): a
             # kernel that covers 8 KV heads may not cover the 4-head shard
             # — the resolved path below is what the compiled step actually
             # took, and a reference path always carries its sentence
+            call = dict(paged=paged, chunk=self._kk or 1,
+                        quant=self.kv_dtype == "int8",
+                        shards=self.mesh_shards)
             self.decode_decline_reason = _dk.decline_reason(
-                self.num_heads, d, dkv, blk_len,
-                paged=self.kv_layout == "paged",
-                chunk=self._kk or 1,
-                quant=self.kv_dtype == "int8",
-                shards=self.mesh_shards)
+                self.num_heads, d, dkv, blk_len, **call)
             self.decode_kernels = self.decode_decline_reason is None
+            if self.decode_kernels:
+                self.decode_tile = _dk.tile_positions(
+                    self.num_heads, d, dkv, blk_len,
+                    nb_row=self._paged.tables.shape[1] if paged else 1,
+                    **call)
             if not self.decode_kernels and _dk.decode_kernels_enabled():
                 # kernels asked for, a shape guard said no: the reference
                 # path is never silent — it writes the score matrix (and,
@@ -1835,10 +1842,7 @@ class DecodeEngine:
                 "kernels %s, chunked prefill K=%d budget=%s, "
                 "speculate_k=%d, mesh_shards=%d)", self.name,
                 self.num_slots, self.max_len, self.kv_layout,
-                self.kv_dtype,
-                "fused-pallas" if self.decode_kernels or self.kda_kernels
-                else "xla-ref (%s)" % (self.kda_decline_reason
-                                       or self.decode_decline_reason),
+                self.kv_dtype, self._kernel_path(),
                 self.prefill_chunk, self.prefill_chunk_budget or "inf",
                 self.speculate_k, self.mesh_shards)
             return
@@ -1885,10 +1889,17 @@ class DecodeEngine:
         logger.info("decode[%s]: warm (%d slots, max_len %d, kv %s/%s, "
                     "decode kernels %s, prefill buckets %s)", self.name,
                     self.num_slots, self.max_len, self.kv_layout,
-                    self.kv_dtype,
-                    "fused-pallas" if self.decode_kernels
-                    else f"xla-ref ({self.decode_decline_reason})",
+                    self.kv_dtype, self._kernel_path(),
                     list(self.prefill_buckets))
+
+    def _kernel_path(self):
+        """The warm line's account of the path the compiled step took."""
+        if self.decode_kernels:
+            return f"fused-pallas, {self.decode_tile} positions a step"
+        if self.kda_kernels:
+            return "fused-pallas"
+        return "xla-ref (%s)" % (self.kda_decline_reason
+                                 or self.decode_decline_reason)
 
     def _log_prefill_paths(self):
         """Once, at warm-up: which attention path each legacy prefill

@@ -62,6 +62,14 @@ class Widths:
     kda_slots: int = 4      # kda_chunk: rows, heads of head_dim x head_dim
     kda_heads: int = 8      # state, lanes a row
     kda_chunk: int = 16
+    # the opt1.3b_chat cell's own paged chunk call: MHA heads of cell_head_dim
+    # over a pool of block 16, rows at contexts drawn from cell_contexts
+    # (lo, hi) around cell_mean_context
+    cell_heads: int = 4
+    cell_head_dim: int = 64
+    cell_blocks_per_row: int = 16
+    cell_contexts: tuple = (10, 200)
+    cell_mean_context: int = 86
 
 
 SMALL = Widths(heads=8, kv_heads=2, head_dim=128, slots=8, slab_len=256,
@@ -79,7 +87,10 @@ SERVING = Widths(heads=16, kv_heads=16, head_dim=128, slots=8,
                  lstm_cell_batch=1024, lstm_tiled_batch=256,
                  lstm_tiled_hidden=1280, lstm_tiled_len=25, blocked_hidden=1280, blocked_len=25,
                  # the kimilinear_reason cell's step: 32 slots of 32 heads
-                 kda_slots=32, kda_heads=32, kda_chunk=16)
+                 kda_slots=32, kda_heads=32, kda_chunk=16,
+                 # benchmark/configs/lm-opt-1.3b.json x chat_open.json
+                 cell_heads=32, cell_head_dim=64, cell_blocks_per_row=128,
+                 cell_contexts=(100, 700), cell_mean_context=330)
 
 
 class Case(NamedTuple):
@@ -391,7 +402,16 @@ def _chunk_lanes_ref(positions, lengths, kk):
     return (positions[:, None] + li).astype(np.int32)
 
 
-def _decode_case(w, *, paged, chunk, quant, seed):
+def _cell(w):
+    """``w`` with the opt1.3b_chat cell's paged chunk call in the decode
+    fields: MHA, block 16, the cell's table length."""
+    return dataclasses.replace(
+        w, heads=w.cell_heads, kv_heads=w.cell_heads,
+        head_dim=w.cell_head_dim, block_size=16,
+        blocks_per_row=w.cell_blocks_per_row)
+
+
+def _decode_case(w, *, paged, chunk, quant, seed, contexts=None):
     """One of the eight fused decode-attention kernels (slab | paged) x
     (Tq=1 | Tq=chunk) x (f32 | int8 K/V) vs the masked-XLA oracle
     (models/transformer._attend over the gathered, dequantized rows) —
@@ -399,7 +419,8 @@ def _decode_case(w, *, paged, chunk, quant, seed):
     rows (1 live lane) and chunking rows (all lanes) in the chunk cases;
     the oracle compares LIVE lanes only: dead tail lanes repeat the last
     live qpos and their output is unspecified (the decode-row fast path
-    skips them; nothing downstream reads a dead lane)."""
+    skips them; nothing downstream reads a dead lane).  ``contexts``
+    (lo, hi): every row's position drawn from that range, none pinned."""
     from paddle_tpu.models import transformer
     from paddle_tpu.ops.pallas import decode_attention as dk
     from paddle_tpu.quant import kv as kvq
@@ -417,12 +438,15 @@ def _decode_case(w, *, paged, chunk, quant, seed):
 
     rng = np.random.RandomState(seed)
     q = jnp.asarray(rng.randn(s, kk, d) * 0.5, jnp.float32)
-    pos = rng.randint(0, t - kk, s).astype(np.int32)
     lens = rng.randint(1, kk + 1, s).astype(np.int32)
     lens[0], lens[-1] = 1, kk           # pin both extremes
-    pos[1] = t - kk                     # one row reaches the last block
-    pos[2] = 0                          # one row attends <= kk positions:
-    #                                     nothing averages its rounding away
+    if contexts is not None:
+        pos = rng.randint(contexts[0], contexts[1] + 1, s).astype(np.int32)
+    else:
+        pos = rng.randint(0, t - kk, s).astype(np.int32)
+        pos[1] = t - kk                 # one row reaches the last block
+        pos[2] = 0                      # one row attends <= kk positions:
+        #                                 nothing averages its rounding away
     qpos = _chunk_lanes_ref(pos, lens, kk)
     kv_shape = ((s * nb_row + 1, bs, dkv) if paged else (s, t, dkv))
     if quant:
@@ -465,6 +489,56 @@ def _decode_case(w, *, paged, chunk, quant, seed):
 
     return Case(fn, oracle, (q, k, v, ks, vs),
                 lambda got, want: _max_err(got[live], want[live]))
+
+
+def time_paged_chunk_cell(w, calls=24, reps=20):
+    """Wall ms a call of ``decode_attention_paged_chunk`` ALONE at the
+    opt1.3b_chat cell's shape: every row at position 0 (all but a row's
+    first tile dead), at the cell's mean context, and at the table's last
+    position (none dead), decode rows (one live lane) and prefill rows
+    (every lane) apart; then the cell's own mix, contexts drawn from
+    ``cell_contexts`` with one row in eight prefilling.  ``calls`` kernels
+    chained in one program, as the step chains its layers; a time only on
+    the chip."""
+    import time
+    from paddle_tpu.ops.pallas import decode_attention as dk
+    c = _cell(w)
+    h, dh, s, kk = c.heads, c.head_dim, c.slots, c.chunk
+    bs, nb_row = c.block_size, c.blocks_per_row
+    d, t = h * dh, nb_row * bs
+    rng = np.random.RandomState(5)
+    q = jnp.asarray(rng.randn(s, kk, d) * 0.5, jnp.float32)
+    k, v = (jnp.asarray(rng.randn(s * nb_row + 1, bs, d) * 0.5, jnp.float32)
+            for _ in range(2))
+    lo, hi = w.cell_contexts
+    one, full = np.ones(s, np.int64), np.full(s, kk)
+    mix = one.copy()
+    mix[-1] = kk
+    settings = {"cell_mix": (rng.randint(lo, hi + 1, s), mix)}
+    for last in (0, w.cell_mean_context, t - 1):
+        settings[f"decode_at_{last}"] = (np.full(s, last), one)
+        last = max(last, kk - 1)
+        settings[f"prefill_at_{last}"] = (np.full(s, last), full)
+
+    @jax.jit        # positions and tables are data: one program for all
+    def chain(q, k, v, qpos, tables):
+        for _ in range(calls):
+            q = dk.decode_attention_paged_chunk(q, k, v, qpos, tables, h)
+        return q
+
+    out = {}
+    for name, (last, lens) in settings.items():
+        args = (q, k, v,
+                jnp.asarray(_chunk_lanes_ref(last - lens + 1, lens, kk)),
+                jnp.asarray(build_private_tables(last, nb_row, bs,
+                                                 k.shape[0])))
+        jax.block_until_ready(chain(*args))
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            got = chain(*args)
+        jax.block_until_ready(got)
+        out[name] = round((time.perf_counter() - t0) * 1e3 / (reps * calls), 4)
+    return out
 
 
 # --------------------------------------------------------- delta rule
@@ -531,6 +605,9 @@ CASES = {
     "decode_attention_paged_int8": _decode(True, False, True, 70),
     "decode_attention_slab_chunk_int8": _decode(False, True, True, 80),
     "decode_attention_paged_chunk_int8": _decode(True, True, True, 90),
+    "decode_attention_paged_chunk_cell": lambda w: _decode_case(
+        _cell(w), paged=True, chunk=True, quant=False, seed=100,
+        contexts=w.cell_contexts),
     "kda_chunk": _kda_case,
 }
 

@@ -249,6 +249,205 @@ def test_scratch_block_rows_never_attended():
     assert np.all(np.isfinite(np.asarray(poisoned)))
 
 
+# ------------------------------------------------ the paged chunk tile
+
+KK = 8          # lanes a row, the serving default's prefill chunk
+
+
+def _chunk_setup(rng, last, lens, h, hkv, dh, bs, nb_row):
+    """q, pool, per-lane positions and private chains for rows whose
+    furthest lane sits at ``last`` with ``lens`` live lanes (dead lanes
+    repeat the last live one, the engine's ``_chunk_lanes``)."""
+    from paddle_tpu.testing.kernel_smoke import (_chunk_lanes_ref,
+                                                 build_private_tables)
+    last, lens = np.asarray(last, np.int32), np.asarray(lens, np.int32)
+    s, nb = last.size, last.size * nb_row + 1
+    qpos = _chunk_lanes_ref(last - lens + 1, lens, KK)
+    q = rng.randn(s, KK, h * dh).astype(np.float32)
+    kp = rng.randn(nb, bs, hkv * dh).astype(np.float32)
+    vp = rng.randn(nb, bs, hkv * dh).astype(np.float32)
+    tables = build_private_tables(last, nb_row, bs, nb)
+    live = np.arange(KK)[None, :] < lens[:, None]
+    return q, kp, vp, qpos, tables, live
+
+
+def _ref_paged_chunk(q, kp, vp, qpos, tables, num_heads):
+    s, dkv = q.shape[0], kp.shape[-1]
+    t = tables.shape[1] * kp.shape[1]
+    pm = np.arange(t)[None, None, :] <= qpos[:, :, None]
+    return np.asarray(transformer._attend(
+        jnp.asarray(q), jnp.asarray(kp[tables].reshape(s, -1, dkv)),
+        jnp.asarray(vp[tables].reshape(s, -1, dkv)), num_heads,
+        jnp.asarray(pm)))
+
+
+def _paged_chunk(q, kp, vp, qpos, tables, h):
+    with dk.forced_mode("always"):
+        out = dk.maybe_paged_chunk(*map(jnp.asarray, (q, kp, vp, qpos,
+                                                      tables)), h)
+    assert out is not None
+    return np.asarray(out)
+
+
+# (heads, kv heads, head dim): OPT's layout (two heads of 64 a panel),
+# grouped K/V in one panel, a head that fills the lane row by itself
+LAYOUTS = {"mha_dh64": (4, 4, 64), "gqa_dh16": (4, 2, 16),
+           "mqa_dh128": (2, 1, 128)}
+BS_T, NB_ROW_T = 16, 20     # a table of 320 positions: two tiles and a half
+
+
+@pytest.mark.parametrize("lanes", ["decode", "prefill", "mixed"])
+@pytest.mark.parametrize("where", ["first_position", "tile_last",
+                                   "next_tile_first", "table_last",
+                                   "under_one_tile"])
+def test_paged_chunk_tile_matches_chain_gather(where, lanes):
+    """The tiled kernel (G table entries a step of its loop) against
+    ``_attend`` over the chain gather, for a row that ends on each seam
+    of the tiling — beside rows elsewhere, decode rows (one live lane)
+    and prefilling rows (every lane) mixed; G does not divide the table."""
+    h, hkv, dh = LAYOUTS["mha_dh64"]
+    g = dk.paged_chunk_tile(h, h * dh, hkv * dh, BS_T, NB_ROW_T, KK)
+    tile = g * BS_T
+    assert g > 1 and NB_ROW_T % g, g
+    rng = np.random.RandomState(sum(map(ord, where + lanes)))
+    last = [{"first_position": 0, "tile_last": tile - 1,
+             "next_tile_first": tile, "table_last": NB_ROW_T * BS_T - 1,
+             "under_one_tile": 2 * BS_T + 3}[where],
+            int(rng.randint(KK, tile)), int(rng.randint(tile, 2 * tile)),
+            int(rng.randint(2 * tile, NB_ROW_T * BS_T))]
+    lens = {"decode": [1] * 4, "prefill": [KK] * 4,
+            "mixed": [1, KK, 3, 1]}[lanes]
+    lens = [min(n, p + 1) for n, p in zip(lens, last)]
+    q, kp, vp, qpos, tables, live = _chunk_setup(rng, last, lens, h, hkv,
+                                                 dh, BS_T, NB_ROW_T)
+    got = _paged_chunk(q, kp, vp, qpos, tables, h)
+    want = _ref_paged_chunk(q, kp, vp, qpos, tables, h)
+    np.testing.assert_allclose(got[live], want[live], rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+@pytest.mark.parametrize("nb_row", [16, 20, 3])
+def test_paged_chunk_tile_layouts_and_table_lengths(layout, nb_row):
+    """Every panel layout, over a table that G divides, one that it does
+    not, and one shorter than a lane row of positions (G = the table)."""
+    h, hkv, dh = LAYOUTS[layout]
+    g = dk.paged_chunk_tile(h, h * dh, hkv * dh, BS_T, nb_row, KK)
+    assert g == min(nb_row, 128 // BS_T)
+    rng = np.random.RandomState(nb_row + len(layout))
+    t = nb_row * BS_T
+    last = [t - 1, int(rng.randint(0, t)), 0, int(rng.randint(KK, t))]
+    q, kp, vp, qpos, tables, live = _chunk_setup(
+        rng, last, [KK, 1, 1, 5], h, hkv, dh, BS_T, nb_row)
+    got = _paged_chunk(q, kp, vp, qpos, tables, h)
+    want = _ref_paged_chunk(q, kp, vp, qpos, tables, h)
+    np.testing.assert_allclose(got[live], want[live], rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("lanes", ["decode", "prefill"])
+def test_paged_chunk_tile_never_reads_a_block_it_does_not_own(lanes):
+    """NaN in every block no row owns — scratch block 0, which the dead
+    entries of each table name, and the pool's spare blocks — and in the
+    owned last block past each row's position nothing but finite stale
+    data: the output is bit-identical to the clean pool's and finite.
+    Dead entries INSIDE a live tile are neither copied nor addressed."""
+    h, hkv, dh = LAYOUTS["mha_dh64"]
+    rng = np.random.RandomState(len(lanes))
+    last = [0, 2 * BS_T + 3, 128, NB_ROW_T * BS_T - BS_T - 1]
+    lens = [1] * 4 if lanes == "decode" else [1, KK, KK, KK]
+    q, kp, vp, qpos, tables, live = _chunk_setup(rng, last, lens, h, hkv,
+                                                 dh, BS_T, NB_ROW_T)
+    clean = _paged_chunk(q, kp, vp, qpos, tables, h)
+    owned = np.unique(tables[tables > 0])
+    unowned = np.setdiff1d(np.arange(kp.shape[0]), owned)
+    assert 0 in unowned and unowned.size > NB_ROW_T
+    kp, vp = kp.copy(), vp.copy()
+    kp[unowned] = np.nan
+    vp[unowned] = np.nan
+    poisoned = _paged_chunk(q, kp, vp, qpos, tables, h)
+    np.testing.assert_array_equal(poisoned[live], clean[live])
+    assert np.all(np.isfinite(poisoned))
+
+
+def test_paged_chunk_tile_rule(monkeypatch):
+    """G follows from what the call sees (block size, Dkv, table length,
+    the VMEM budget, the block_k cap), and falls to 1 — the block-a-step
+    kernel — where no larger tile runs."""
+    tile = dk.paged_chunk_tile
+    # interpret mode (this backend): a lane row of positions, cut to the table
+    assert tile(32, 2048, 2048, 16, 128, 8) == 8
+    assert tile(32, 2048, 2048, 16, 5, 8) == 5
+    assert tile(32, 2048, 2048, 32, 64, 8) == 4
+    assert tile(32, 2048, 2048, 128, 16, 8) == 1     # a block is a tile
+    assert tile(32, 2048, 2048, 16, 128, 8, quant=True) == 1
+    monkeypatch.setattr(dk, "_interpret", lambda i: False)
+    # compiled: OPT-1.3B's call, 128 positions = 4 MiB of the budget
+    assert tile(32, 2048, 2048, 16, 128, 8) == 8
+    assert 128 * dk._vmem_bytes_per_position(2048, False) == 4 << 20
+    # the VMEM budget cuts a wide Dkv's tile below the lane row
+    wide = dk.vmem_budget_bytes() // dk._vmem_bytes_per_position(
+        16384, False)
+    assert 16 < wide < 128
+    assert tile(128, 16384, 16384, 16, 128, 8) == wide // 16
+    # panel rows that are not whole sublanes (2 heads x 1 x 3 lanes), and
+    # panels that are not whole lane rows: G = 1
+    assert tile(32, 2048, 2048, 16, 128, 3) == 1
+    assert tile(3, 96, 96, 16, 128, 8) == 8      # one panel holds all 3
+    assert tile(8, 384, 384, 16, 128, 8) == 1    # 2 heads of 48: 96 lanes
+    # the flag's cap on positions a step still binds
+    monkeypatch.setattr(dk, "_block_k_cap", lambda: 64)
+    assert tile(32, 2048, 2048, 16, 128, 8) == 4
+
+
+def test_tile_positions_names_each_kernels_step(slab_engine, paged_engine):
+    """What ``DecodeEngine.warmup`` logs beside the resolved path: the
+    K/V positions one step of the serving kernel covers — the slab
+    kernels' k-tile, a pool block at Tq=1, G blocks at Tq=chunk — on the
+    per-chip stripe, like ``decline_reason``."""
+    assert dk.tile_positions(2, 32, 32, 48) == 48
+    assert dk.tile_positions(2, 32, 32, 1024) == 512     # the flag's cap
+    assert dk.tile_positions(2, 32, 32, 8, nb_row=6, paged=True) == 8
+    assert dk.tile_positions(32, 2048, 2048, 16, nb_row=128, paged=True,
+                             chunk=8) == 128
+    assert dk.tile_positions(32, 2048, 2048, 16, nb_row=128, paged=True,
+                             chunk=8, shards=2) == 128
+    assert dk.tile_positions(32, 2048, 2048, 16, nb_row=128, paged=True,
+                             chunk=8, quant=True) == 16
+    assert slab_engine.decode_tile == MAX_LEN
+    assert paged_engine.decode_tile == BS
+    assert paged_engine._kernel_path() == \
+        f"fused-pallas, {BS} positions a step"
+
+
+@pytest.mark.parametrize("g", [1, 8])
+def test_covers_agrees_with_the_paged_chunk_call(monkeypatch, g):
+    """``decline_reason`` / ``covers`` stay THE predicate whichever tile
+    serves: what they cover the call runs (tiled or block-a-step), what
+    they decline ``maybe_paged_chunk`` hands back as None."""
+    if g == 1:
+        monkeypatch.setattr(dk, "paged_chunk_tile", lambda *a, **k: 1)
+    calls = []
+    real = dk._paged_chunk_tiled
+    monkeypatch.setattr(dk, "_paged_chunk_tiled",
+                        lambda *a, **k: calls.append(k["g"]) or real(*a, **k))
+    h, hkv, dh = LAYOUTS["gqa_dh16"]
+    rng = np.random.RandomState(g)
+    q, kp, vp, qpos, tables, live = _chunk_setup(
+        rng, [5, 200, 319], [1, KK, 4], h, hkv, dh, BS_T, NB_ROW_T)
+    with dk.forced_mode("always"):
+        assert dk.covers(h, h * dh, hkv * dh, BS_T, paged=True, chunk=KK)
+    got = _paged_chunk(q, kp, vp, qpos, tables, h)
+    assert calls == ([] if g == 1 else [8])
+    want = _ref_paged_chunk(q, kp, vp, qpos, tables, h)
+    np.testing.assert_allclose(got[live], want[live], rtol=1e-5, atol=1e-5)
+    with dk.forced_mode("always"):
+        # a block of 136 positions: declined before any tile is chosen
+        assert not dk.covers(h, h * dh, hkv * dh, 136, paged=True, chunk=KK)
+        big = jnp.zeros((4, 136, hkv * dh), jnp.float32)
+        assert dk.maybe_paged_chunk(
+            jnp.asarray(q), big, big, jnp.asarray(qpos),
+            jnp.zeros((3, 2), jnp.int32), h) is None
+
+
 def test_dispatch_gating():
     """auto on CPU -> reference path (None); off -> None even when
     forced upstream; always -> kernel output; bad mode -> error."""
@@ -379,6 +578,43 @@ def test_paged_engine_greedy_bit_identical_under_churn(params,
     snap = eng.metrics.snapshot()
     assert snap["prefix_cache_hits_total"] >= 1
     assert snap["cow_forks_total"] >= 1
+    eng._paged.check()
+
+
+def test_chunked_engine_streams_cross_the_tile_seam():
+    """The tiled kernel compiled into the chunked paged step: prompts
+    that end before, on and past the first tile's last position (127),
+    decode rows beside prefilling rows under slot reuse — every greedy
+    stream token-identical to ``lm_generate``, one trace."""
+    max_len, bs, kk = 160, 8, 4
+    p = transformer.init(jax.random.PRNGKey(3), src_vocab=VOCAB,
+                         trg_vocab=1, d_model=D_MODEL, num_heads=HEADS,
+                         dff=64, enc_layers=LAYERS, dec_layers=0,
+                         max_len=max_len)
+    assert dk.paged_chunk_tile(HEADS, D_MODEL, D_MODEL, bs, max_len // bs,
+                               kk) * bs == 128
+    with dk.forced_mode("always"):
+        eng = DecodeEngine(p, num_heads=HEADS, num_slots=3,
+                           max_len=max_len, name="kern_tile",
+                           kv_layout="paged", kv_block_size=bs,
+                           prefill_chunk=kk)
+    assert eng.decode_kernels
+    assert eng._kernel_path() == "fused-pallas, 128 positions a step"
+    rng = np.random.RandomState(15)
+    cases = [(_prompt(rng, n), m) for n, m in
+             [(120, 12), (5, 6), (127, 4), (128, 3), (30, 9), (141, 8)]]
+    with assert_no_retrace(lambda: eng.step_trace_count,
+                           "tiled chunk churn"):
+        bat = GenerationBatcher(eng, default_max_tokens=8)
+        results, excs = _drive(bat, cases)
+        bat.close()
+    assert all(e is None for e in excs), excs
+    for (prompt, n), res in zip(cases, results):
+        ids = np.asarray(transformer.lm_generate(
+            p, prompt[None], max_len=max_len, num_heads=HEADS,
+            prompt_lengths=np.asarray([prompt.size])))
+        assert res["tokens"] == ids[0, prompt.size:prompt.size + n].tolist(), \
+            f"prompt len {prompt.size}, n {n}"
     eng._paged.check()
 
 
