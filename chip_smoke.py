@@ -358,8 +358,9 @@ def _first_token_logits(params, cfg, rehearsal):
              "pallas_calls": len(seen), "interpreted": sum(seen)}
     ok = np.isfinite(first).all() and err <= tol
     if not rehearsal:
-        # auto on a TPU must have taken the kernel in every layer
-        ok = ok and len(seen) == cfg["layers"] and not any(seen)
+        # auto on a TPU must have taken the kernel, compiled (the layers
+        # share ONE trace of the tiled kernel, so one call is recorded)
+        ok = ok and bool(seen) and not any(seen)
     return bool(ok), facts
 
 

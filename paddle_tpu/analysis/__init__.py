@@ -29,8 +29,7 @@ REVERSE against a seeded-violation fixture (`analysis/fixtures/`,
 pinned by tests/test_analysis.py) — the analytic-gate discipline.
 
 Modules:
-  roots.py      the jitted-root registry (shared with perf/analytic.py's
-                FAMILIES — the drift test keeps them joined)
+  roots.py      the jitted-root registry (every jitted step's entry point)
   callgraph.py  AST project index + best-effort call/name resolution
   purity.py     jit-purity pass
   retrace.py    retrace-hazard pass (taint from the roots' data args)

@@ -3,7 +3,7 @@ families (demo/mnist, image_classification, seqToseq, sentiment,
 recommendation, benchmark/rnn) plus the Transformer stretch config.
 
 The DSL-based demo scripts (v1-config parity) live in /demo; these modules
-are the fast path used by bench.py and __graft_entry__.py.
+are the fast path used by benchmark/ and __graft_entry__.py.
 """
 
 from paddle_tpu.models import alexnet

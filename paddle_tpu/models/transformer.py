@@ -837,196 +837,12 @@ def _shard_gather_att(att, shard_axis):
     return jax.lax.all_gather(att, shard_axis, axis=-1, tiled=True)
 
 
-def _cached_self_attn_slots(blk, x, c, positions, pos_mask, num_heads,
-                            rope_pos=None, shard_axis=None):
-    """``_cached_self_attn`` with a PER-ROW position vector: row r writes
-    its K/V at its own ``positions[r]`` (scatter instead of a shared
-    dynamic slice) and attends under its own mask row.  Row r's compute is
-    exactly ``_cached_self_attn``'s at t=positions[r] — every matmul here
-    is batched over the leading axis ([S, 1, D] @ [D, H]), so a row's
-    numerics do not depend on what the other slots are doing.  The
-    continuous-batching decode slab (serving/decode_engine.py) runs on
-    this.
-
-    shard_axis: set inside the serving shard_map — blk's wq/wk/wv are
-    local head stripes, c local KV stripes, num_heads the LOCAL count;
-    everything below computes the stripe exactly as the single chip
-    computes those heads, and ``_shard_gather_att`` reassembles before
-    the replicated wo."""
-    h = _ln(blk["ln1"], x)
-    k_new = linear.matmul(h, blk["attn"]["wk"])
-    q = linear.matmul(h, blk["attn"]["wq"])
-    if rope_pos is not None:
-        dh = q.shape[-1] // num_heads
-        k_new = _rope_flat(k_new, rope_pos, dh)
-        q = _rope_flat(q, rope_pos, dh)
-    v_new = linear.matmul(h, blk["attn"]["wv"])
-    rows = jnp.arange(positions.shape[0])
-    # quantize-on-write for an int8 cache (scales None on the f32 path)
-    k_set, v_set, sk, sv = _kv_writes(c, k_new[:, 0], v_new[:, 0])
-    upd = lambda buf, val: buf.at[rows, positions].set(val)
-    nc, ks, vs = _kv_commit(c, upd, k_set, v_set, sk, sv)
-    k, v = nc["k"], nc["v"]
-    # fused Pallas decode kernel (ops/pallas/decode_attention.py): the
-    # row's stripe streams HBM->VMEM once, no score matrix, grouped KV
-    # expanded in registers (int8: + scale sidecars dequantized there
-    # too).  None -> the reference XLA path (the CPU tier-1 default;
-    # pallas_decode flag gates — see maybe_slab), which widens the
-    # stripe via _kv_view — same math as the kernel's register dequant.
-    from paddle_tpu.ops.pallas import decode_attention as _decode_kernels
-    att = _decode_kernels.maybe_slab(q[:, 0], k, v, positions, num_heads,
-                                     kscale=ks, vscale=vs)
-    if att is None:
-        att = _attend(q, _kv_view(k, ks), _kv_view(v, vs), num_heads,
-                      pos_mask)
-    else:
-        att = att[:, None]
-    att = _shard_gather_att(att, shard_axis)
-    return x + linear.matmul(att, blk["attn"]["wo"]), nc
-
-
-def lm_decode_step_slots(params, prev_ids, positions, cache, num_heads=8,
-                         moe_top_k=2, pos_type="learned",
-                         shard_axis=None):
-    """One incremental decode position for EVERY row of a slot slab, each
-    row at its OWN position — the continuous-batching twin of
-    ``lm_decode_step`` (which advances the whole batch at one shared t).
-
-    prev_ids [S], positions [S] int32; cache: per-enc-layer K/V
-    [S, max_len, Dkv] (``init_lm_cache``) -> (logits [S, V], new cache).
-    Row r computes exactly ``lm_decode_step``'s result at t=positions[r]:
-    the position row is gathered instead of sliced, the K/V write is a
-    per-row scatter, and the attention mask is per-row ``<= positions[r]``
-    — same values, same masked-softmax width (masked logits sit at -1e30,
-    whose exp is exactly 0.0, so cache width beyond a row's position never
-    perturbs its numerics).  tests/test_decode_engine.py pins the
-    per-request bit-identity against ``lm_generate``.
-
-    shard_axis (trace-time): the tensor-parallel serving path — params/
-    cache are local stripes and num_heads the LOCAL head count (src_emb
-    shards its VOCAB axis, so the embedded x keeps the full width d and
-    the sqrt(d) scale is untouched).  The draft trunk's rollout runs
-    through here inside its own shard_map."""
-    params = _maybe_dequant(params)
-    s = prev_ids.shape[0]
-    max_len = cache[0]["k"].shape[1]
-    x = _lm_embed(params, prev_ids, shard_axis)[:, None]
-    x = x * math.sqrt(x.shape[-1])
-    if pos_type == "learned":
-        x = x + params["pos"][positions][:, None]
-    rope_pos = positions[:, None] if pos_type == "rope" else None
-    pos_mask = jnp.arange(max_len)[None, :] <= positions[:, None]
-    pos_mask = jnp.broadcast_to(pos_mask, (s, max_len))
-    new_cache = []
-    for blk, c in zip(params["enc"], cache):
-        x, nc = _cached_self_attn_slots(blk, x, c, positions, pos_mask,
-                                        num_heads, rope_pos, shard_axis)
-        x = x + _block_ffn(blk, _ln(blk["ln2"], x), moe_top_k)[0]
-        new_cache.append(nc)
-    return _lm_project(params, x, shard_axis)[:, 0], new_cache
-
-
-def _cached_self_attn_paged(blk, x, c, positions, tables, pos_mask,
-                            num_heads, rope_pos=None):
-    """``_cached_self_attn_slots`` over a PAGED KV pool: the cache is a
-    shared pool of fixed-size blocks ``[num_blocks, block_size, Dkv]``
-    and each row's K/V live wherever its block table says (``tables``
-    [S, blocks_per_row] int32 of physical block ids).  Row r writes its
-    new K/V into block ``tables[r, p // bs]`` at offset ``p % bs`` (host
-    scheduling guarantees writer exclusivity: a block being written has
-    pool refcount 1 — the copy-on-write fork in serving/kv_pool.py; free
-    rows all target the reserved scratch block 0, whose contents are
-    never attended) and attends over the GATHER of its own chain —
-    ``pool[tables[r]]`` flattened back to a contiguous [S, T, Dkv] view.
-    The gathered values at positions <= positions[r] are exactly what
-    the slab holds at those logical positions, and masked positions
-    contribute exp(-1e30) = 0.0, so row r's numerics are bit-identical
-    to ``_cached_self_attn_slots`` — shared physical blocks and all."""
-    s = positions.shape[0]
-    block_size = c["k"].shape[1]
-    h = _ln(blk["ln1"], x)
-    k_new = linear.matmul(h, blk["attn"]["wk"])
-    q = linear.matmul(h, blk["attn"]["wq"])
-    if rope_pos is not None:
-        dh = q.shape[-1] // num_heads
-        k_new = _rope_flat(k_new, rope_pos, dh)
-        q = _rope_flat(q, rope_pos, dh)
-    v_new = linear.matmul(h, blk["attn"]["wv"])
-    rows = jnp.arange(s)
-    bids = tables[rows, positions // block_size]
-    offs = positions % block_size
-    # quantize-on-write for an int8 pool (scales None on the f32 path)
-    k_set, v_set, sk, sv = _kv_writes(c, k_new[:, 0], v_new[:, 0])
-    upd = lambda buf, val: buf.at[bids, offs].set(val)
-    nc, ks, vs = _kv_commit(c, upd, k_set, v_set, sk, sv)
-    k, v = nc["k"], nc["v"]
-    # fused Pallas paged kernel (ops/pallas/decode_attention.py): the
-    # block table rides as scalar-prefetch data and the kernel walks
-    # each row's chain in place — no [S, T, Dkv] gathered copy, no
-    # score matrix (perf/analytic.py's fusion-proof gate pins the
-    # gather's absence; int8 sidecar blocks ride the same walk).
-    # None -> the reference chain-gather path.
-    from paddle_tpu.ops.pallas import decode_attention as _decode_kernels
-    att = _decode_kernels.maybe_paged(q[:, 0], k, v, positions, tables,
-                                      num_heads, kscale=ks, vscale=vs)
-    if att is not None:
-        att = att[:, None]
-    else:
-        # chain gather: [S, blocks_per_row, bs, Dkv] -> [S, T, Dkv]
-        # where T = blocks_per_row * bs covers every position a row can
-        # hold (int8: the gathered chain widens via its gathered scales)
-        k_rows = _kv_view(k[tables],
-                          None if ks is None else ks[tables]) \
-            .reshape(s, -1, k.shape[-1])
-        v_rows = _kv_view(v[tables],
-                          None if vs is None else vs[tables]) \
-            .reshape(s, -1, v.shape[-1])
-        att = _attend(q, k_rows, v_rows, num_heads, pos_mask)
-    return x + linear.matmul(att, blk["attn"]["wo"]), nc
-
-
-def lm_decode_step_paged(params, prev_ids, positions, cache, tables,
-                         num_heads=8, moe_top_k=2, pos_type="learned"):
-    """One incremental decode position for every row of a PAGED slot
-    slab — the block-pool twin of ``lm_decode_step_slots``.
-
-    prev_ids [S], positions [S] int32; cache: per-enc-layer K/V pools
-    ``[num_blocks, block_size, Dkv]`` (``init_lm_cache_paged``); tables:
-    [S, blocks_per_row] int32 physical block ids (block 0 = the reserved
-    scratch block free rows point at) -> (logits [S, V], new cache).
-    Row r computes exactly ``lm_decode_step_slots``'s result at
-    t=positions[r]: same gathered K/V values at every unmasked position,
-    same masked-softmax width semantics (-1e30 logits exp to exactly
-    0.0).  The block table is DATA, not shape: admission, eviction and
-    copy-on-write forks churn ``tables`` between steps without ever
-    retracing (tests/test_kv_pool.py pins 1 warm-up trace, 0 after)."""
-    params = _maybe_dequant(params)
-    s = prev_ids.shape[0]
-    block_size = cache[0]["k"].shape[1]
-    t_span = tables.shape[1] * block_size
-    x = emb_ops.embedding_lookup(params["src_emb"], prev_ids)[:, None]
-    x = x * math.sqrt(x.shape[-1])
-    if pos_type == "learned":
-        x = x + params["pos"][positions][:, None]
-    rope_pos = positions[:, None] if pos_type == "rope" else None
-    pos_mask = jnp.arange(t_span)[None, :] <= positions[:, None]
-    pos_mask = jnp.broadcast_to(pos_mask, (s, t_span))
-    new_cache = []
-    for blk, c in zip(params["enc"], cache):
-        x, nc = _cached_self_attn_paged(blk, x, c, positions, tables,
-                                        pos_mask, num_heads, rope_pos)
-        x = x + _block_ffn(blk, _ln(blk["ln2"], x), moe_top_k)[0]
-        new_cache.append(nc)
-    return _lm_project(params, x)[:, 0], new_cache
-
-
 # ------------------------------------------------ chunked decode steps
 #
-# The unified chunked-prefill serving step (serving/decode_engine.py
-# prefill_chunk > 0; docs/serving.md "Chunked prefill"): ONE jitted step
-# advances a MIX of decode rows (1 token) and prompt-ingesting rows (up
-# to K tokens — Sarathi-style chunked prefill on the Orca-style slot
-# scheduler).  Row r feeds tokens[r, :lengths[r]] at positions
+# The serving step (serving/decode_engine.py; docs/serving.md "Chunked
+# prefill"): ONE jitted step advances a MIX of decode rows (1 token) and
+# prompt-ingesting rows (up to K tokens — Sarathi-style chunked prefill
+# on the Orca-style slot scheduler).  Row r feeds tokens[r, :lengths[r]] at positions
 # positions[r] .. positions[r]+lengths[r]-1; lane i attends causally
 # within the chunk AND over the row's live prefix (cols <= its own
 # position), and the returned logits are each row's LAST fed lane —
@@ -1048,14 +864,21 @@ def _chunk_lanes(positions, lengths, kk):
 
 def _cached_self_attn_chunk(blk, x, c, li, qpos, pos_mask, num_heads,
                             rope_pos=None, shard_axis=None):
-    """``_cached_self_attn_slots`` at Tq=K: row r writes lane i's K/V at
-    its own ``qpos[r, i]`` and lane i attends under its own mask row
-    (cols <= qpos[r, i] — causal within the chunk, clamped at the live
-    prefix).  Writes happen BEFORE the attention, so within-chunk
-    causality falls out of the ordinary masked cache read.  Lane
-    numerics are position-local (batched matmuls over the flattened
-    [S*K] leading axis), so each lane computes exactly what the Tq=1
-    step computes at that position."""
+    """``_cached_self_attn`` with PER-ROW, PER-LANE positions over a slot
+    slab: row r scatter-writes lane i's K/V at its own ``qpos[r, i]``
+    and lane i attends under its own mask row (cols <= qpos[r, i] —
+    causal within the chunk, clamped at the live prefix).  Writes happen
+    BEFORE the attention, so within-chunk causality falls out of the
+    ordinary masked cache read.  Lane numerics are position-local
+    (batched matmuls over the flattened [S*K] leading axis), so each
+    lane computes exactly ``_cached_self_attn``'s result at
+    t=qpos[r, i], whatever the other slots and lanes are doing.
+
+    shard_axis: set inside the serving shard_map — blk's wq/wk/wv are
+    local head stripes, c local KV stripes, num_heads the LOCAL count;
+    everything below computes the stripe exactly as the single chip
+    computes those heads, and ``_shard_gather_att`` reassembles before
+    the replicated wo."""
     s, kk, _d = x.shape
     h = _ln(blk["ln1"], x)
     k_new = linear.matmul(h, blk["attn"]["wk"])
@@ -1077,9 +900,13 @@ def _cached_self_attn_chunk(blk, x, c, li, qpos, pos_mask, num_heads,
     upd = lambda buf, val: buf.at[rows, qpos].set(val)
     nc, ks, vs = _kv_commit(c, upd, k_set, v_set, sk, sv)
     k, v = nc["k"], nc["v"]
-    # fused Tq=chunk Pallas kernel (ops/pallas/decode_attention.py):
-    # each row's stripe streams HBM->VMEM once and every lane consumes
-    # it in VMEM — no [S, K, T] score matrix.  None -> reference path.
+    # fused Pallas kernel (ops/pallas/decode_attention.py): each row's
+    # stripe streams HBM->VMEM once and every lane consumes it in VMEM —
+    # no [S, K, T] score matrix, grouped KV expanded in registers (int8:
+    # scale sidecars dequantized there too).  None -> the reference XLA
+    # path (the CPU tier-1 default; the pallas_decode flag gates — see
+    # maybe_slab_chunk), which widens the stripe via _kv_view — same
+    # math as the kernel's register dequant.
     from paddle_tpu.ops.pallas import decode_attention as _decode_kernels
     att = _decode_kernels.maybe_slab_chunk(q, k, v, qpos, num_heads,
                                            kscale=ks, vscale=vs)
@@ -1093,17 +920,26 @@ def _cached_self_attn_chunk(blk, x, c, li, qpos, pos_mask, num_heads,
 def lm_decode_chunk_slots(params, tokens, positions, lengths, cache,
                           num_heads=8, moe_top_k=2, pos_type="learned",
                           all_lanes=False, shard_axis=None):
-    """The Tq=chunk generalization of ``lm_decode_step_slots``: every
-    row advances ``lengths[r]`` (1..K) positions in ONE step.
+    """The slot-slab serving step: every row of the slab advances
+    ``lengths[r]`` (1..K) positions in ONE step, each row at its OWN
+    position — the continuous-batching twin of ``lm_decode_step`` (which
+    advances the whole batch by one token at one shared t).
 
     tokens [S, K] int32 (row r's lanes < lengths[r] are fed; the rest
     are ignored — callers pad with anything in-vocab), positions [S]
-    (lane 0's position), lengths [S] in [1, K]; cache as
-    ``init_lm_cache`` -> (logits [S, V] at each row's LAST fed lane,
-    new cache).  A row with lengths[r]=1 computes exactly
-    ``lm_decode_step_slots``'s result; a row chunking through its prompt
-    computes exactly what sequential steps would — tokens and lengths
-    are DATA, so mixing decode and prefill rows never retraces.
+    (lane 0's position), lengths [S] in [1, K]; cache: per-enc-layer K/V
+    [S, max_len, Dkv] (``init_lm_cache``) -> (logits [S, V] at each
+    row's LAST fed lane, new cache).  Lane i of row r computes exactly
+    ``lm_decode_step``'s result at t=positions[r]+i: the position row is
+    gathered instead of sliced, the K/V write is a per-row scatter, and
+    the attention mask is per-lane ``<= qpos`` — same values, same
+    masked-softmax width (masked logits sit at -1e30, whose exp is
+    exactly 0.0, so cache width beyond a lane's position never perturbs
+    its numerics).  A row with lengths[r]=1 is one plain decode step; a
+    row chunking through its prompt computes exactly what sequential
+    steps would — tokens and lengths are DATA, so mixing decode and
+    prefill rows never retraces.  tests/test_decode_engine.py pins the
+    per-request bit-identity against ``lm_generate``.
 
     all_lanes=True (a TRACE-TIME constant, like num_heads) projects
     EVERY lane instead of only the last fed one -> logits [S, K, V]:
@@ -1115,8 +951,11 @@ def lm_decode_chunk_slots(params, tokens, positions, lengths, cache,
     shard_axis (trace-time): the tensor-parallel serving path
     (docs/serving.md "Sharded decode") — inside the engine's shard_map
     params/cache are local head/vocab stripes and num_heads the LOCAL
-    count; the two all-gather seams (attention output, logits) plus the
-    embedding psum reassemble bit-identically to the single chip."""
+    count (src_emb shards its VOCAB axis, so the embedded x keeps the
+    full width d and the sqrt(d) scale is untouched); the two all-gather
+    seams (attention output, logits) plus the embedding psum reassemble
+    bit-identically to the single chip.  The draft trunk's rollout runs
+    through here inside its own shard_map."""
     params = _maybe_dequant(params)
     s, kk = tokens.shape
     max_len = cache[0]["k"].shape[1]
@@ -1142,11 +981,20 @@ def lm_decode_chunk_slots(params, tokens, positions, lengths, cache,
 def _cached_self_attn_chunk_paged(blk, x, c, li, qpos, tables, pos_mask,
                                   num_heads, rope_pos=None,
                                   shard_axis=None):
-    """``_cached_self_attn_chunk`` over the paged block pool: lane i of
-    row r scatter-writes into ``pool[tables[r, qpos//bs], qpos % bs]``
-    (host scheduling provisions exclusive blocks for the WHOLE span
-    before the step — ``PagedKVState.write_plan_span``) and attends over
-    the gather of its own chain."""
+    """``_cached_self_attn_chunk`` over a PAGED KV pool: the cache is a
+    shared pool of fixed-size blocks ``[num_blocks, block_size, Dkv]``
+    and each row's K/V live wherever its block table says (``tables``
+    [S, blocks_per_row] int32 of physical block ids).  Lane i of row r
+    scatter-writes into ``pool[tables[r, qpos // bs], qpos % bs]`` (host
+    scheduling guarantees writer exclusivity for the WHOLE span before
+    the step: a block being written has pool refcount 1 — the
+    copy-on-write fork in serving/kv_pool.py; free rows all target the
+    reserved scratch block 0, whose contents are never attended) and
+    attends over its own chain.  The chain's values at positions <=
+    qpos[r, i] are exactly what the slab holds at those logical
+    positions, and masked positions contribute exp(-1e30) = 0.0, so the
+    lane's numerics are bit-identical to ``_cached_self_attn_chunk`` —
+    shared physical blocks and all."""
     s = qpos.shape[0]
     block_size = c["k"].shape[1]
     h = _ln(blk["ln1"], x)
@@ -1166,11 +1014,20 @@ def _cached_self_attn_chunk_paged(blk, x, c, li, qpos, tables, pos_mask,
     upd = lambda buf, val: buf.at[bids, offs].set(val)
     nc, ks, vs = _kv_commit(c, upd, k_set, v_set, sk, sv)
     k, v = nc["k"], nc["v"]
+    # fused Pallas paged kernel (ops/pallas/decode_attention.py): the
+    # block table rides as scalar-prefetch data and the kernel walks
+    # each row's chain in place — no [S, T, Dkv] gathered copy, no
+    # score matrix (perf/analytic.py's fusion-proof gate pins the
+    # gather's absence; int8 sidecar blocks ride the same walk).
+    # None -> the reference chain-gather path.
     from paddle_tpu.ops.pallas import decode_attention as _decode_kernels
     att = _decode_kernels.maybe_paged_chunk(q, k, v, qpos, tables,
                                             num_heads, kscale=ks,
                                             vscale=vs)
     if att is None:
+        # chain gather: [S, blocks_per_row, bs, Dkv] -> [S, T, Dkv]
+        # where T = blocks_per_row * bs covers every position a row can
+        # hold (int8: the gathered chain widens via its gathered scales)
         k_rows = _kv_view(k[tables],
                           None if ks is None else ks[tables]) \
             .reshape(s, -1, k.shape[-1])
@@ -1186,12 +1043,17 @@ def lm_decode_chunk_paged(params, tokens, positions, lengths, cache,
                           tables, num_heads=8, moe_top_k=2,
                           pos_type="learned", all_lanes=False,
                           shard_axis=None):
-    """The Tq=chunk generalization of ``lm_decode_step_paged`` — the
-    paged twin of ``lm_decode_chunk_slots`` (same lane semantics, block
-    tables as DATA; ``all_lanes`` the same trace-time verify switch;
-    ``shard_axis`` the same tensor-parallel switch — each chip walks
-    the SAME replicated block tables over its local Hkv/n stripe of
-    every pool block)."""
+    """The paged twin of ``lm_decode_chunk_slots``: same lane semantics
+    over K/V block pools ``[num_blocks, block_size, Dkv]``
+    (``init_lm_cache_paged``) addressed through ``tables`` [S,
+    blocks_per_row] int32 physical block ids (block 0 = the reserved
+    scratch block free rows point at).  The block table is DATA, not
+    shape: admission, eviction and copy-on-write forks churn ``tables``
+    between steps without ever retracing (tests/test_kv_pool.py pins 1
+    warm-up trace, 0 after).  ``all_lanes`` is the same trace-time
+    verify switch; ``shard_axis`` the same tensor-parallel switch — each
+    chip walks the SAME replicated block tables over its local Hkv/n
+    stripe of every pool block."""
     params = _maybe_dequant(params)
     s, kk = tokens.shape
     block_size = cache[0]["k"].shape[1]
@@ -1263,7 +1125,7 @@ def _kv_layer_buffers(params, lead_shape, kv_dtype, num_heads):
 
 def init_lm_cache_paged(params, num_blocks, block_size, max_len=None,
                         kv_dtype=None, num_heads=None):
-    """K/V block pools for ``lm_decode_step_paged``: per enc layer
+    """K/V block pools for ``lm_decode_chunk_paged``: per enc layer
     ``{"k","v"}`` of ``[num_blocks, block_size, Dkv]`` — the paged twin
     of ``init_lm_cache`` (same per-block KV width inference, so GQA
     trunks get proportionally smaller blocks).  Block 0 is reserved as

@@ -1,4 +1,4 @@
-"""Trace smoke CLI — healthy_window.sh phase 12.
+"""Trace smoke CLI: cross-process request tracing over a real fleet.
 
     python -m paddle_tpu.obs --smoke [--chrome-out PATH]
 
@@ -65,7 +65,6 @@ def _smoke(chrome_out=None):
     # the injected decode-step hang paces tokens (~25ms each) so the
     # kill reliably lands MID-stream, exactly like the fleet smoke
     extra = ["--gen-slots", "4", "--gen-max-len", "64",
-             "--gen-prefill-buckets", "8,16",
              "--gen-max-tokens", str(n_tokens),
              "--obs-trace", "1",
              "--fault-spec",

@@ -14,8 +14,7 @@ processes, exported as Chrome trace-event JSON.
 Discipline (shared with resilience/faults.py):
 
 * strictly HOST-side — no hook ever sits inside a jit-traced body, so an
-  enabled tracer changes no XLA program (``bench.py --analytic-diff``
-  stays clean by construction) and can never cause a retrace;
+  enabled tracer changes no XLA program and can never cause a retrace;
 * near-zero cost when disabled (the default): every hook is one global
   read plus an ``is None`` test returning the ``NULL`` span singleton —
   no allocation, no lock, no contextvar touch;

@@ -6,57 +6,56 @@ those bytes (docs/kernels.md "Decode-attention kernels"; PERF.md section
 5 has the measured ``opt1.3b_chat`` step).  The reference XLA paths in
 ``models/transformer`` pay for the cache more than once:
 
-* slab (``_cached_self_attn_slots``): ``repeat_kv_heads`` widens the
+* slab (``_cached_self_attn_chunk``): ``repeat_kv_heads`` widens the
   grouped K/V to full head width and the dense attention materializes
-  the ``[S, H, T]`` score matrix in HBM before the softmax reads it
+  the ``[S, K, H, T]`` score matrix in HBM before the softmax reads it
   back;
-* paged (``_cached_self_attn_paged``): the per-row chain gather
+* paged (``_cached_self_attn_chunk_paged``): the per-row chain gather
   ``pool[tables]`` copies every row's blocks into a contiguous
   ``[S, T, Dkv]`` HBM buffer — a second full read AND a full write of
   the logical cache — before the same widened-score dance.
 
-The FOUR kernels here — (slab | paged) x (Tq=1 | Tq=chunk) — delete that
-traffic.  Per row the K/V stripe streams HBM -> VMEM exactly once; the
-masked online softmax (flash-style running max/sum, the
+The TWO kernels here — slab and paged, each over the step's ``K`` token
+lanes a row (``K = 1`` is a one-lane call of the same kernel) — delete
+that traffic.  Per row the K/V stripe streams HBM -> VMEM exactly once;
+the masked online softmax (flash-style running max/sum, the
 ``flash_attention.py`` recipe) and the grouped-KV -> full-head expansion
 happen in VMEM/registers; neither the score matrix nor a second KV copy
 ever exists in HBM.
 
-* ``decode_attention_slab`` / ``_slab_chunk``: grid ``(S, T/blk)`` with
-  the kv dimension innermost; per-row ``positions`` ride as
-  SCALAR-PREFETCH data (``pltpu.PrefetchScalarGridSpec``) so the k-block
-  index map CLAMPS at the row's position — blocks past a row's live
-  prefix map to the same block id, which the Pallas pipeline recognizes
-  and never re-fetches.
+* ``decode_attention_slab_chunk``: grid ``(S, T/blk)`` with the kv
+  dimension innermost; per-lane ``qpos`` ride as SCALAR-PREFETCH data
+  (``pltpu.PrefetchScalarGridSpec``) so the k-block index map CLAMPS at
+  the row's furthest lane — blocks past a row's live prefix map to the
+  same block id, which the Pallas pipeline recognizes and never
+  re-fetches.
 
-* ``decode_attention_paged``: the per-slot block TABLE is the second
-  scalar-prefetch operand and the kernel walks it directly — the
-  ``[1, block_size, Dkv]`` k/v specs index ``pool[tables[r, j]]``, so a
-  row reads ONLY the physical blocks it owns (clamped at its position,
-  like the slab) and the chain gather disappears from the HLO entirely
-  (perf/analytic.py's fusion-proof gate pins exactly that).
+* ``decode_attention_paged_chunk``, the one attention of the paged
+  serving step: the per-slot block TABLE is the second scalar-prefetch
+  operand and the kernel walks it directly, so a row reads ONLY the
+  physical blocks it owns and the chain gather disappears from the HLO
+  entirely (perf/analytic.py's fusion-proof gate pins exactly that).  A
+  TILE of ``G`` table entries (``paged_chunk_tile``: a lane row of
+  positions, cut to the table and the VMEM budget) is one step of a
+  loop over the row's LIVE tiles inside a grid of ``(S,)``; the pools
+  stay in HBM and the row's blocks are copied into a double-buffered
+  ``[2, G*bs, Dkv]`` scratch by hand (``_paged_tile_kernel``).  A table
+  of 128 entries of 16 positions cost 1,024 grid steps a call, five in
+  six of them dead at the serving contexts; tiled, the ``opt1.3b_chat``
+  call went from 0.27 to 0.08 ms (docs/kernels.md has the table).
+  ``G = 1`` — int8 K/V in a block under an s8 tile, shapes whose panels
+  are not whole lane rows — is the block-a-grid-step form, grid ``(S,
+  blocks_per_row)`` with ``[1, block_size, Dkv]`` k/v specs indexing
+  ``pool[tables[r, j]]``, which the slab kernel also is.
 
-* ``decode_attention_paged_chunk``, the one attention of the chunked
-  serving step (``K`` token lanes a row): a TILE of ``G`` table entries
-  (``paged_chunk_tile``: a lane row of positions, cut to the table and
-  the VMEM budget) is one step of a loop over the row's LIVE tiles
-  inside a grid of ``(S,)``; the pools stay in HBM and the row's blocks
-  are copied into a double-buffered ``[2, G*bs, Dkv]`` scratch by hand
-  (``_paged_tile_kernel``).  A table of 128 entries of 16 positions cost
-  1,024 grid steps a call, five in six of them dead at the serving
-  contexts; tiled, the ``opt1.3b_chat`` call went from 0.27 to 0.08 ms
-  (docs/kernels.md has the table).  ``G = 1`` — int8 K/V in a block
-  under an s8 tile, shapes whose panels are not whole lane rows — is the
-  block-a-grid-step kernel the other three still are.
-
-Masking matches ``_attend`` exactly: cols > positions[r] sit at -1e30,
-whose exp is 0.0 — cache width beyond a row's position never perturbs
+Masking matches ``_attend`` exactly: cols > qpos[r, i] sit at -1e30,
+whose exp is 0.0 — cache width beyond a lane's position never perturbs
 its numerics, so greedy streams through the kernels stay token-for-token
 identical to ``lm_generate`` (tests/test_pallas_decode.py pins it across
 admission/eviction/CoW churn and supervisor recovery).
 
-INT8 K/V (quant/kv.py; docs/serving.md "Quantized serving"): every
-kernel takes optional ``kscale``/``vscale`` per-(position, head) f32
+INT8 K/V (quant/kv.py; docs/serving.md "Quantized serving"): both
+kernels take optional ``kscale``/``vscale`` per-(position, head) f32
 sidecars marking a quantized cache.  The sidecar blocks ride the SAME
 clamped/table-walked DMA stream as the int8 K/V blocks, and the
 widening happens in REGISTERS inside ``_accumulate`` (one broadcast
@@ -64,15 +63,14 @@ multiply per KV-head group panel) — int8 is what streams from HBM and
 the widened K/V never exists in any memory.  ``kernel_cost`` declares
 the honest int8 byte counts (1-byte elements + the f32 sidecar).
 
-Dispatch: callers go through ``maybe_slab`` / ``maybe_paged`` /
-``maybe_slab_chunk`` / ``maybe_paged_chunk``, which return None (caller
-falls back to the reference XLA path) unless the ``pallas_decode`` flag
-enables the kernels — ``auto`` follows ``use_pallas()`` (TPU only; the
-CPU tier-1 default stays the reference path, preserving the greedy
-bit-identity discipline), ``always`` forces them anywhere (interpret
-mode off-TPU — the CPU test/smoke mode), ``off`` disables.  The flag is
-read at TRACE time: set it before constructing the engine/jitting the
-step.
+Dispatch: callers go through ``maybe_slab_chunk`` /
+``maybe_paged_chunk``, which return None (caller falls back to the
+reference XLA path) unless the ``pallas_decode`` flag enables the
+kernels — ``auto`` follows ``use_pallas()`` (TPU only; the CPU tier-1
+default stays the reference path, preserving the greedy bit-identity
+discipline), ``always`` forces them anywhere (interpret mode off-TPU —
+the CPU test/smoke mode), ``off`` disables.  The flag is read at TRACE
+time: set it before constructing the engine/jitting the step.
 """
 
 import contextlib
@@ -89,7 +87,7 @@ from paddle_tpu.ops.pallas.common import (LANES as _LANES, lanes as _lanes,
 
 _NEG = -1e30
 
-# test/bench override for the pallas_decode flag: None = read FLAGS
+# test override for the pallas_decode flag: None = read FLAGS
 # (utils/flags.py), else one of "auto" | "always" | "off" — same values
 # the flag takes.  The FUSED_LSTM pattern (ops/rnn.py).
 MODE = None
@@ -105,8 +103,8 @@ def _mode():
 @contextlib.contextmanager
 def forced_mode(mode):
     """Temporarily force the kernel dispatch mode ("always" | "off" |
-    "auto") — tests and the A/B bench.  The mode is read at TRACE time,
-    so wrap the jit/lower call, not just the execution."""
+    "auto") — for tests.  The mode is read at TRACE time, so wrap the
+    jit/lower call, not just the execution."""
     global MODE
     old = MODE
     MODE = mode
@@ -250,7 +248,7 @@ def _panel_heads(hkv, dh):
 
 def paged_chunk_tile(num_heads, d, dkv, bs, nb_row, chunk, quant=False,
                      interpret=None):
-    """Table entries G a tile of the paged Tq=chunk kernel covers (G x bs
+    """Table entries G a tile of the paged kernel covers (G x bs
     positions), from what the call sees; 1 = the block-a-grid-step kernel.
     One lane row of scores (LANES positions) is the aim — past it a tile
     only adds masked tail to a row's last one — cut to the table, to the
@@ -317,7 +315,7 @@ def _accumulate(q, kb, vb, col0, blk, pos, m_scr, l_scr, acc_scr, *,
     Grouped KV expands in REGISTERS: each kv head's [dh]-slice meets its
     query group's rows — no widened K/V ever exists in memory.  ``sl``
     selects this lane's running-stat rows inside scratch shaped
-    [K*H, ...] (the Tq=chunk kernels; Tq=1 passes the whole scratch).
+    [K*H, ...].
     A block entirely past ``pos`` is a BIT-EXACT no-op: every score
     masks to -1e30, so p underflows to exactly 0.0 and alpha is exactly
     1.0 — the chunk kernels rely on this for their shorter lanes.
@@ -394,51 +392,10 @@ def _finalize(o_ref, l_scr, acc_scr, dh):
     o_ref[0] = (acc_scr[:] / _lanes(l, dh)).astype(o_ref.dtype)
 
 
-def _slab_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, blk, num_heads,
-                 hkv, dh, scale):
-    # int8 K/V adds two scale-sidecar operands between v and the output
-    # (quantized dispatch appends their BlockSpecs); the f32 layout is
-    # unchanged
-    if len(rest) == 6:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        ks_ref = vs_ref = None
-        o_ref, m_scr, l_scr, acc_scr = rest
-    r = pl.program_id(0)
-    j = pl.program_id(1)
-    pos = pos_ref[r]
-
-    @pl.when(j == 0)
-    def _():
-        _init_row(m_scr, l_scr, acc_scr)
-
-    @pl.when(j * blk <= pos)
-    def _():
-        _accumulate(q_ref[0].astype(jnp.float32),
-                    k_ref[0].astype(jnp.float32),
-                    v_ref[0].astype(jnp.float32),
-                    j * blk, blk, pos, m_scr, l_scr, acc_scr,
-                    num_heads=num_heads, hkv=hkv, dh=dh, scale=scale,
-                    ks=None if ks_ref is None else ks_ref[0],
-                    vs=None if vs_ref is None else vs_ref[0])
-
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _():
-        _finalize(o_ref, l_scr, acc_scr, dh)
-
-
-def _paged_kernel(pos_ref, tbl_ref, *args, **kw):
-    """Same body as the slab kernel — the block table shapes the DMA
-    stream through the index maps, not the compute; ``tbl_ref`` is
-    consumed entirely by the BlockSpecs."""
-    del tbl_ref
-    _slab_kernel(pos_ref, *args, **kw)
-
-
 def _chunk_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, blk, kk,
                   num_heads, hkv, dh, scale):
-    """Tq=chunk body: ``kk`` query lanes per row share each streamed K/V
-    block.  pos_ref [S, K] carries every lane's own position (the
+    """The block-a-grid-step body: ``kk`` query lanes per row share each
+    streamed K/V block.  pos_ref [S, K] carries every lane's own position (the
     engine's clamped ``qpos`` — non-decreasing per row, inactive lanes
     repeat the last active lane's), so lane i's mask is causal within
     the chunk AND clamped at the row's live prefix.  Lane stats live in
@@ -446,6 +403,9 @@ def _chunk_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, blk, kk,
     from HBM exactly once per row — the chunk consumes it in VMEM (and
     for int8 K/V every lane shares the same in-register dequant panels:
     the scale sidecars ride the same block stream)."""
+    # int8 K/V adds two scale-sidecar operands between v and the output
+    # (quantized dispatch appends their BlockSpecs); the f32 layout is
+    # unchanged
     if len(rest) == 6:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -497,6 +457,9 @@ def _chunk_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, blk, kk,
 
 
 def _paged_chunk_kernel(pos_ref, tbl_ref, *args, **kw):
+    """Same body as the slab kernel — the block table shapes the DMA
+    stream through the index maps, not the compute; ``tbl_ref`` is
+    consumed entirely by the BlockSpecs."""
     del tbl_ref
     _chunk_kernel(pos_ref, *args, **kw)
 
@@ -504,7 +467,7 @@ def _paged_chunk_kernel(pos_ref, tbl_ref, *args, **kw):
 def _paged_tile_kernel(pos_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf,
                        vbuf, sem, first_slot, m_scr, l_scr, acc_scr, *, bs,
                        g, kk, scale):
-    """Tq=chunk paged body with a TILE of ``g`` table entries: one grid
+    """Paged body with a TILE of ``g`` table entries: one grid
     step is one ROW, and the row's live tiles are a loop inside it.
 
     The pools stay in HBM (``pl.ANY``).  Tile t of a row is its table
@@ -612,154 +575,12 @@ def _check_scales(name, kscale, vscale, lead_shape, hkv):
     return True
 
 
-def decode_attention_slab(q, k, v, positions, num_heads, *, block_k=None,
-                          interpret=None, kscale=None, vscale=None):
-    """Fused slab decode attention: q [S, D], k/v [S, T, Dkv] (the
-    already-updated cache), positions [S] int32 -> [S, D].  Row r
-    attends its own stripe at cols <= positions[r]; the stripe is read
-    from HBM exactly once and no score matrix is ever materialized.
-    kscale/vscale [S, T, Hkv] f32 mark an INT8 cache (quant/kv.py): the
-    kernel DMAs the int8 stripe + its scale sidecar and widens in
-    registers inside the accumulator — the widened K/V never exists in
-    any memory.  Raises ValueError on shapes the kernel doesn't cover —
-    callers use ``maybe_slab``."""
-    interpret = _interpret(interpret)
-    s, d = q.shape
-    t, dkv = k.shape[1], k.shape[2]
-    split = _head_split(d, dkv, num_heads)
-    blk = _pick_block_k(t, block_k or _block_k_cap(), interpret,
-                        quant=kscale is not None, dkv=dkv)
-    if split is None or blk is None:
-        raise ValueError(
-            f"decode_attention_slab: unsupported shape q={q.shape} "
-            f"k={k.shape} heads={num_heads}")
-    dh, hkv, _group = split
-    quant = _check_scales("decode_attention_slab", kscale, vscale,
-                          (s, t), hkv)
-    problem = _tile_problem(blk, dkv, dh, interpret, quant=quant)
-    if problem:
-        raise ValueError(f"decode_attention_slab: {problem}")
-    scale = 1.0 / math.sqrt(dh)
-    kernel = functools.partial(_slab_kernel, blk=blk, num_heads=num_heads,
-                               hkv=hkv, dh=dh, scale=scale)
-    # clamp at the row's live prefix: blocks past positions[r] re-map
-    # to the last needed block — same index, no re-fetch
-    kv_map = lambda r, j, pos: (r, jnp.minimum(j, pos[r] // blk), 0)
-    in_specs = [
-        pl.BlockSpec((1, num_heads, dh), lambda r, j, pos: (r, 0, 0)),
-        pl.BlockSpec((1, blk, dkv), kv_map),
-        pl.BlockSpec((1, blk, dkv), kv_map),
-    ]
-    operands = [q.reshape(s, num_heads, dh), k, v]
-    if quant:
-        in_specs += [pl.BlockSpec((1, blk, hkv), kv_map),
-                     pl.BlockSpec((1, blk, hkv), kv_map)]
-        operands += [kscale, vscale]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(s, t // blk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, num_heads, dh),
-                               lambda r, j, pos: (r, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((num_heads, _LANES), jnp.float32),
-            pltpu.VMEM((num_heads, _LANES), jnp.float32),
-            pltpu.VMEM((num_heads, dh), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel, grid_spec=grid_spec, name="decode_attn_slab",
-        out_shape=jax.ShapeDtypeStruct((s, num_heads, dh), q.dtype),
-        cost_estimate=kernel_cost(
-            s, t, d, dkv, q.dtype.itemsize,
-            kv_itemsize=k.dtype.itemsize,
-            scale_hkv=hkv if quant else 0),
-        interpret=interpret,
-    )(jnp.asarray(positions, jnp.int32), *operands)
-    return out.reshape(s, d)
-
-
-def decode_attention_paged(q, k, v, positions, tables, num_heads, *,
-                           interpret=None, kscale=None, vscale=None):
-    """Fused paged decode attention: q [S, D], k/v [num_blocks,
-    block_size, Dkv] (the shared block POOL, already scatter-updated),
-    positions [S] int32, tables [S, blocks_per_row] int32 -> [S, D].
-
-    The block table is the kernel's second scalar-prefetch operand: the
-    k/v index maps read ``tables[r, j]`` directly, so row r's DMA stream
-    is exactly the physical blocks it owns (clamped at its position) —
-    the ``pool[tables]`` chain gather and its [S, T, Dkv] HBM buffer
-    are gone, not fused.  kscale/vscale [num_blocks, block_size, Hkv]
-    f32 mark an INT8 pool (quant/kv.py): the sidecar blocks ride the
-    SAME table-walked stream and the widening happens in registers.
-    Raises ValueError on shapes the kernel doesn't cover — callers use
-    ``maybe_paged``."""
-    interpret = _interpret(interpret)
-    s, d = q.shape
-    bs, dkv = k.shape[1], k.shape[2]
-    nb_row = tables.shape[1]
-    split = _head_split(d, dkv, num_heads)
-    if split is None:
-        raise ValueError(
-            f"decode_attention_paged: unsupported shape q={q.shape} "
-            f"pool={k.shape} heads={num_heads}")
-    dh, hkv, _group = split
-    quant = _check_scales("decode_attention_paged", kscale, vscale,
-                          (k.shape[0], bs), hkv)
-    problem = _tile_problem(bs, dkv, dh, interpret, quant=quant)
-    if problem:
-        raise ValueError(f"decode_attention_paged: {problem}")
-    scale = 1.0 / math.sqrt(dh)
-    kernel = functools.partial(_paged_kernel, blk=bs,
-                               num_heads=num_heads, hkv=hkv, dh=dh,
-                               scale=scale)
-
-    def _kv_map(r, j, pos, tbl):
-        # walk the row's chain, clamped at its live prefix: entries past
-        # positions[r] (scratch/stale ids) are never even addressed
-        return (tbl[r, jnp.minimum(j, pos[r] // bs)], 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, num_heads, dh),
-                     lambda r, j, pos, tbl: (r, 0, 0)),
-        pl.BlockSpec((1, bs, dkv), _kv_map),
-        pl.BlockSpec((1, bs, dkv), _kv_map),
-    ]
-    operands = [q.reshape(s, num_heads, dh), k, v]
-    if quant:
-        in_specs += [pl.BlockSpec((1, bs, hkv), _kv_map),
-                     pl.BlockSpec((1, bs, hkv), _kv_map)]
-        operands += [kscale, vscale]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(s, nb_row),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, num_heads, dh),
-                               lambda r, j, pos, tbl: (r, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((num_heads, _LANES), jnp.float32),
-            pltpu.VMEM((num_heads, _LANES), jnp.float32),
-            pltpu.VMEM((num_heads, dh), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel, grid_spec=grid_spec, name="decode_attn_paged",
-        out_shape=jax.ShapeDtypeStruct((s, num_heads, dh), q.dtype),
-        cost_estimate=kernel_cost(
-            s, nb_row * bs, d, dkv, q.dtype.itemsize,
-            kv_itemsize=k.dtype.itemsize,
-            scale_hkv=hkv if quant else 0),
-        interpret=interpret,
-    )(jnp.asarray(positions, jnp.int32),
-      jnp.asarray(tables, jnp.int32), *operands)
-    return out.reshape(s, d)
-
-
 def decode_attention_slab_chunk(q, k, v, qpos, num_heads, *,
                                 block_k=None, interpret=None,
                                 kscale=None, vscale=None):
-    """Fused Tq=chunk slab decode attention (the unified chunked-prefill
-    step): q [S, K, D], k/v [S, T, Dkv] (the already-updated cache),
+    """Fused slab decode attention (the serving step's, K lanes a row;
+    K = 1 is a one-lane call): q [S, K, D], k/v [S, T, Dkv] (the
+    already-updated cache),
     qpos [S, K] int32 per-LANE positions (non-decreasing per row; the
     engine clamps inactive lanes to the last active one) -> [S, K, D].
     Lane (r, i) attends row r's stripe at cols <= qpos[r, i]; the
@@ -831,14 +652,20 @@ def decode_attention_slab_chunk(q, k, v, qpos, num_heads, *,
 def decode_attention_paged_chunk(q, k, v, qpos, tables, num_heads, *,
                                  interpret=None, kscale=None,
                                  vscale=None):
-    """Fused Tq=chunk PAGED decode attention: q [S, K, D], k/v
-    [num_blocks, block_size, Dkv] (the shared pool, already
-    scatter-updated for the whole chunk span), qpos [S, K], tables
-    [S, blocks_per_row] int32 -> [S, K, D].  The block table stays the
-    second scalar-prefetch operand: a row's DMA stream is exactly the
-    physical blocks it owns, clamped at its furthest lane.  kscale/
-    vscale [num_blocks, block_size, Hkv] f32 mark an INT8 pool —
-    sidecar blocks ride the same stream, dequant in registers."""
+    """Fused PAGED decode attention: q [S, K, D], k/v [num_blocks,
+    block_size, Dkv] (the shared block POOL, already scatter-updated for
+    the whole chunk span), qpos [S, K], tables [S, blocks_per_row] int32
+    -> [S, K, D].
+
+    The block table is the kernel's second scalar-prefetch operand: the
+    k/v index maps read ``tables[r, j]`` directly, so row r's DMA stream
+    is exactly the physical blocks it owns (clamped at its furthest
+    lane) — the ``pool[tables]`` chain gather and its [S, T, Dkv] HBM
+    buffer are gone, not fused.  kscale/vscale [num_blocks, block_size,
+    Hkv] f32 mark an INT8 pool (quant/kv.py): the sidecar blocks ride
+    the SAME table-walked stream and the widening happens in registers.
+    Raises ValueError on shapes the kernel doesn't cover — callers use
+    ``maybe_paged_chunk``."""
     interpret = _interpret(interpret)
     s, kk, d = q.shape
     bs, dkv = k.shape[1], k.shape[2]
@@ -865,6 +692,8 @@ def decode_attention_paged_chunk(q, k, v, qpos, tables, num_heads, *,
                                scale=scale)
 
     def _kv_map(r, j, pos, tbl):
+        # walk the row's chain, clamped at its live prefix: entries past
+        # the furthest lane (scratch/stale ids) are never even addressed
         return (tbl[r, jnp.minimum(j, pos[r, kk - 1] // bs)], 0, 0)
 
     in_specs = [
@@ -964,9 +793,9 @@ def decline_reason(num_heads, d, dkv, blk_len, paged=False, chunk=1,
     resolved-path log — one definition, so the engine can never report a
     path its compiled step didn't take, and a reference path always has
     a sentence saying why.  ``blk_len``: the slab length (slab) or the
-    pool block size (paged).  ``chunk``: query lanes per row (1 = plain
-    decode; >1 = the chunked-prefill step).  ``quant``: int8 K/V (tighter
-    sublane tiling on the compiled backend).
+    pool block size (paged).  ``chunk``: query lanes per row (the step's
+    K; 1 = a one-lane call).  ``quant``: int8 K/V (tighter sublane tiling
+    on the compiled backend).
 
     ``shards``: a tensor-parallel mesh (docs/serving.md "Sharded
     decode") hands each chip the PER-CHIP stripe — ``num_heads/n``
@@ -1010,16 +839,13 @@ def tile_positions(num_heads, d, dkv, blk_len, nb_row=1, paged=False,
     """K/V positions ONE step of the kernel covers for shapes
     ``decline_reason`` accepts (same arguments; ``nb_row``: the table's
     entries a row), judged like it on the per-chip stripe: the slab
-    kernels' k-tile, a pool block at Tq=1, G blocks at Tq=chunk
-    (``paged_chunk_tile``).  ``DecodeEngine.warmup`` logs it beside the
+    kernel's k-tile, or G pool blocks (``paged_chunk_tile``).  ``DecodeEngine.warmup`` logs it beside the
     resolved path, so a run's output says which tile served it."""
     shards = max(1, int(shards))
     num_heads, d, dkv = num_heads // shards, d // shards, dkv // shards
     if not paged:
         return _pick_block_k(blk_len, _block_k_cap(), _interpret(None),
                              quant=quant, dkv=dkv)
-    if chunk == 1:
-        return blk_len
     return blk_len * paged_chunk_tile(num_heads, d, dkv, blk_len, nb_row,
                                       chunk, quant=quant)
 
@@ -1030,32 +856,9 @@ def covers(*args, **kw):
     return decline_reason(*args, **kw) is None
 
 
-def maybe_slab(q, k, v, positions, num_heads, kscale=None, vscale=None):
-    """Kernel output [S, D] when the fused slab kernel is enabled and
-    covers these shapes; None -> caller takes the reference XLA path."""
-    if not covers(num_heads, q.shape[1], k.shape[2], k.shape[1],
-                  paged=False, quant=kscale is not None):
-        return None
-    return decode_attention_slab(q, k, v, positions, num_heads,
-                                 interpret=_interpret(None),
-                                 kscale=kscale, vscale=vscale)
-
-
-def maybe_paged(q, k, v, positions, tables, num_heads, kscale=None,
-                vscale=None):
-    """Kernel output [S, D] when the fused paged kernel is enabled and
-    covers these shapes; None -> caller takes the chain-gather path."""
-    if not covers(num_heads, q.shape[1], k.shape[2], k.shape[1],
-                  paged=True, quant=kscale is not None):
-        return None
-    return decode_attention_paged(q, k, v, positions, tables, num_heads,
-                                  interpret=_interpret(None),
-                                  kscale=kscale, vscale=vscale)
-
-
 def maybe_slab_chunk(q, k, v, qpos, num_heads, kscale=None, vscale=None):
-    """Kernel output [S, K, D] when the fused Tq=chunk slab kernel is
-    enabled and covers these shapes; None -> the reference XLA path."""
+    """Kernel output [S, K, D] when the fused slab kernel is enabled
+    and covers these shapes; None -> the reference XLA path."""
     if not covers(num_heads, q.shape[2], k.shape[2], k.shape[1],
                   paged=False, chunk=q.shape[1],
                   quant=kscale is not None):
@@ -1067,8 +870,8 @@ def maybe_slab_chunk(q, k, v, qpos, num_heads, kscale=None, vscale=None):
 
 def maybe_paged_chunk(q, k, v, qpos, tables, num_heads, kscale=None,
                       vscale=None):
-    """Kernel output [S, K, D] when the fused Tq=chunk paged kernel is
-    enabled and covers these shapes; None -> the chain-gather path."""
+    """Kernel output [S, K, D] when the fused paged kernel is enabled
+    and covers these shapes; None -> the chain-gather path."""
     if not covers(num_heads, q.shape[2], k.shape[2], k.shape[1],
                   paged=True, chunk=q.shape[1],
                   quant=kscale is not None):

@@ -122,9 +122,10 @@ def _fused_lstm_enabled():
 
 
 #: incremented on every fused-kernel dispatch (trace time).  Observers
-#: (bench.py) snapshot it around a compile to learn whether the fused path
-#: was ACTUALLY taken for a given model/shape — the one source of truth,
-#: instead of re-deriving supported()'s decision externally.
+#: (benchmark/drivers/train.py, chip_smoke.py) snapshot it around a
+#: compile to learn whether the fused path was ACTUALLY taken for a given
+#: model/shape — the one source of truth, instead of re-deriving
+#: supported()'s decision externally.
 FUSED_DISPATCH_COUNT = 0
 
 

@@ -1,53 +1,21 @@
-"""Analytic bench runner: cost + roofline snapshot for every bench family.
+"""Structure gates and first-principles byte/time models.
 
-For each family in bench.py the factory's AOT hook (`extras["lower"]`,
-a zero-arg callable returning the jitted step's `jax.stages.Lowered`) is
-compiled on the CURRENT backend — the CPU backend when no TPU answers —
-and fed through `perf.cost.extract` and `perf.roofline.predict`.  The
-result is one JSON snapshot (`BENCH_ANALYTIC_r06.json`) holding, per
-family: XLA-model FLOPs, bytes accessed, arithmetic intensity, the HLO
-op histogram / fusion count, and the v5e-roofline predicted step time,
-predicted MFU and named bottleneck.  No program is ever executed, so a
-wedged chip cannot block the snapshot ("no chip window -> partial
-evidence").
+Two kinds of chip-independent statement about a serving program, neither
+of which executes anything:
 
-`scripts/perf_report.py --analytic-diff old.json new.json` diffs two
-snapshots structurally and exits non-zero when a change de-fuses a step
-or inflates bytes-accessed beyond threshold (see `analytic_diff` there).
-
-Usage:
-  python bench.py --analytic [--families a,b] [--out PATH]
-  python -m paddle_tpu.perf.analytic [...]
-  python -m paddle_tpu.scripts.bench_sweep --analytic   (same snapshot)
+- Structure gates over lowered/compiled HLO text (``assert_decode_fused``,
+  ``assert_prefill_flash``, ``assert_kv_quantized``,
+  ``assert_prefill_kv_quantized``, ``assert_weights_quantized`` and the
+  ``*_instrs`` detectors behind them): does the program materialize the
+  buffer a fused kernel exists to avoid?  The tests run them both ways
+  (the reference path must trip them, the kernel path must not).
+- ``predicted_*``: bytes and milliseconds from shapes and spec-sheet
+  peaks (``perf.roofline``).  ``DecodeEngine`` routes host-tier restores
+  and cross-replica handoffs by them; they are counts, not measurements
+  (a device time comes only from a chip run — ``benchmark/run.py``).
 """
 
-import argparse
-import gc
-import json
-import os
-import sys
-import time
-
-from paddle_tpu.perf import cost, roofline
-
-_REPO = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-
-DEFAULT_OUT = os.path.join(_REPO, "BENCH_ANALYTIC_r06.json")
-
-# The family registry moved to paddle_tpu/analysis/roots.py — ONE list
-# shared with the static invariant analyzer, so a new bench family
-# cannot add a jitted step the analyzer doesn't see (FAMILY_ROOTS maps
-# every family to the jit roots its extras["lower"] traces; the drift
-# test in tests/test_analysis.py keeps registry and code joined).  The
-# name stays importable from here for every existing consumer
-# (scripts/perf_report.py, tests/test_perf_analytic.py).
-from paddle_tpu.analysis.roots import FAMILIES  # noqa: E402,F401
-
-
-def _log(msg):
-    print(f"[analytic] {msg}", file=sys.stderr, flush=True)
-
+from paddle_tpu.perf import roofline
 
 # ----------------------------------------------------- fusion-proof gate
 
@@ -406,116 +374,6 @@ def predicted_prefill_bytes(params, b, tp, num_heads,
     return qw.param_bytes(params) + kv_stream + kv_write + acts + io
 
 
-def predicted_spec_bytes_per_token(layers, d, dff, vocab, s, t_span,
-                                   num_heads, draft_layers, k,
-                                   acceptance, dkv=None):
-    """First-principles HBM traffic per EMITTED token, speculative vs
-    plain decode — the serving_speculative bytes model (docs/serving.md
-    "Speculative decoding").  Returns ``(spec, nonspec)`` byte totals.
-
-    The target's verify step streams each row's K/V stripe ONCE no
-    matter how many query lanes ride it (the Tq=chunk kernels —
-    ``kernel_cost(tq=k+1)`` differs from ``tq=1`` only by the extra
-    q/o lanes and the all-lanes vocab projection), so verifying k
-    drafts costs nearly the same bytes as decoding one token.  The
-    draft rollout is the price: k sequential passes, each streaming
-    the draft's weights and its own K/V.  With expected emitted tokens
-    ``E = sum(a^i, i=0..k) = (1 - a^(k+1)) / (1 - a)`` per verify
-    step, spec wins iff ``(target_step + k * draft_pass) / E <
-    target_step`` — a cheap-enough draft and a real acceptance rate,
-    which is why the adversarial direction (a = 0, E = 1) must predict
-    a REGRESSION: the model is gated in both directions by the
-    serving_speculative postcheck."""
-    from paddle_tpu.ops.pallas.decode_attention import kernel_cost
-    dkv = d if dkv is None else dkv
-
-    def weight_bytes(n_layers, with_embed=True):
-        trunk = n_layers * (4 * d * d + 2 * d * dff + 9 * d) * 4
-        emb = (2 * vocab * d + t_span * d + 2 * d) * 4 if with_embed \
-            else 0
-        return trunk + emb
-
-    def step_bytes(n_layers, tq, vocab_lanes):
-        attn = n_layers * kernel_cost(s, t_span, d, dkv,
-                                      tq=tq).bytes_accessed
-        kv_write = n_layers * 2 * s * tq * dkv * 4
-        acts = n_layers * 2 * s * tq * d * 4
-        io = s * tq * 4 + s * vocab_lanes * vocab * 4
-        return weight_bytes(n_layers) + attn + kv_write + acts + io
-
-    a = min(max(float(acceptance), 0.0), 1.0 - 1e-9)
-    emitted = (1.0 - a ** (k + 1)) / (1.0 - a)
-    verify = step_bytes(layers, k + 1, k + 1)
-    draft = k * step_bytes(draft_layers, 1, 1)
-    nonspec = step_bytes(layers, 1, 1)
-    return (verify + draft) / emitted, float(nonspec)
-
-
-def predicted_sharded_step_bytes(layers, d, dff, vocab, s, t_span,
-                                 num_heads, shards, dkv=None,
-                                 kv_dtype="float32",
-                                 weight_dtype="float32", chunk=1,
-                                 replicate_weights=False):
-    """First-principles PER-CHIP HBM traffic of one tensor-parallel
-    chunked decode step — the serving_sharded bytes model
-    (docs/serving.md "Sharded decode").  Returns a breakdown dict:
-    ``total`` (per-chip bytes), ``weights``, ``kv``, ``acts_io``, and
-    ``collective`` (the wire bytes of the gather seams).
-
-    The sharding policy is ``parallel.sharding.lm_decode_param_specs``'s,
-    priced term by term: wq/wk/wv shard their out-feature axis and
-    src_emb its vocab axis (each chip streams 1/n of those weights);
-    the K/V pool shards its trailing Dkv axis (1/n of the read/write
-    stream per chip).  Everything bit-exactness forces to stay
-    REPLICATED — wo, the FFN, LNs/biases, the positional table — is
-    streamed in full on every chip: the model never pretends the whole
-    step scales 1/n.  The collective term prices the seams honestly as
-    ring traffic (in + out ~= 2 * (n-1)/n * payload per chip): one
-    attention-output all-gather of [s, chunk, d] per layer, one logits
-    all-gather of [s, vocab], one embedding psum of [s, chunk, d].
-
-    ``replicate_weights=True`` is the adversarial twin: same mesh, same
-    collectives, but every weight streamed in full on every chip — the
-    serving_sharded postcheck requires THAT prediction to FAIL the
-    reduction gate (weight replication must never look like a win), and
-    ``shards=1`` collapses to the single-chip step (no collectives) the
-    sharded prediction is gated against in the other direction."""
-    n = max(1, int(shards))
-    dkv = d if dkv is None else dkv
-    hkv = dkv // (d // num_heads)
-    wsz = 1 if weight_dtype == "int8" else 4
-    # int8 weights carry a per-out-channel f32 scale; the scale shards
-    # with its weight's out axis (the emb scale [1, d] is replicated)
-    ssz = 4 if weight_dtype == "int8" else 0
-    w_shard = layers * ((d * d + 2 * d * dkv) * wsz
-                        + (d + 2 * dkv) * ssz) \
-        + vocab * d * wsz + vocab * 0 * ssz
-    w_repl = layers * ((d * d + 2 * d * dff) * wsz
-                       + (d + 2 * dff) * ssz + 9 * d * 4) \
-        + t_span * d * 4 + 2 * d * 4 + d * ssz
-    if replicate_weights or n == 1:
-        weights = w_shard + w_repl
-    else:
-        weights = w_shard / n + w_repl
-    kv_isz = 1 if kv_dtype == "int8" else 4
-    sidecar = 2 * s * t_span * hkv * 4 if kv_dtype == "int8" else 0
-    kv_read = layers * (2 * s * t_span * dkv * kv_isz + sidecar)
-    kv_write = layers * s * chunk * (2 * dkv * kv_isz
-                                     + (2 * hkv * 4 if kv_isz == 1
-                                        else 0))
-    kv = (kv_read + kv_write) / n      # the pool ALWAYS shards its Dkv
-    acts = layers * 2 * s * chunk * d * 4
-    io = s * chunk * 4 + s * vocab * 4
-    ring = 2.0 * (n - 1) / n if n > 1 else 0.0
-    collective = ring * (layers * s * chunk * d * 4      # att gathers
-                         + s * vocab * 4                 # logits gather
-                         + s * chunk * d * 4)            # embed psum
-    total = weights + kv + acts + io + collective
-    return {"total": float(total), "weights": float(weights),
-            "kv": float(kv), "acts_io": float(acts + io),
-            "collective": float(collective)}
-
-
 # ------------------------------------------------ hierarchical-KV model
 
 # Scheduling cycles a host-tier restore spends off the device: the
@@ -603,217 +461,3 @@ def predicted_recompute_ms(covered, param_count, param_bytes,
     r = roofline.predict(2.0 * float(covered) * float(param_count),
                          float(steps) * float(param_bytes), chip)
     return steps * STEP_DISPATCH_MS + r["predicted_ms"]
-
-
-def _import_bench():
-    if _REPO not in sys.path:
-        sys.path.insert(0, _REPO)
-    import bench
-    return bench
-
-
-# Families whose capture needs a multi-device host platform (the
-# sharded-serving mesh).  XLA's CPU device count is fixed at backend
-# init, and forcing it for the WHOLE snapshot perturbs every
-# single-device family's HLO (the CPU backend re-partitions its thread
-# pool per device — alexnet grows `call` ops under a 2-device flag), so
-# when THIS process lacks the devices these families are captured in a
-# subprocess that sets the flag for itself alone.
-MESH_FAMILIES = {"serving_sharded": 2}
-
-
-def _capture_subprocess(name, model, batch, devices):
-    """Run one family's capture under a forced ``devices``-way host
-    platform in a child ``bench.py --analytic`` process and return its
-    row (an error row on any child failure — same isolation contract
-    as ``capture``)."""
-    import subprocess
-    import tempfile
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                        f" --xla_force_host_platform_device_count="
-                        f"{devices}").strip()
-    fd, out = tempfile.mkstemp(suffix=".json")
-    os.close(fd)
-    try:
-        proc = subprocess.run(
-            [sys.executable, os.path.join(_REPO, "bench.py"),
-             "--analytic", "--families", name, "--out", out],
-            env=env, capture_output=True, text=True, timeout=1800)
-        with open(out) as f:
-            snap = json.load(f)
-        return snap["families"][name]
-    except Exception as e:   # noqa: BLE001 — per-family isolation
-        tail = ""
-        try:
-            tail = proc.stderr[-300:]
-        except Exception:    # noqa: BLE001
-            pass
-        return {"model": model, "batch": batch,
-                "error": f"mesh-capture subprocess failed: "
-                         f"{type(e).__name__}: {e} {tail}"[:500]}
-    finally:
-        if os.path.exists(out):
-            os.unlink(out)
-
-
-def capture(name, model, batch=None, chips=("v5e", "v5p")):
-    """Build one bench family, AOT-compile its step, extract cost +
-    roofline rows.  Returns the snapshot row (with an "error" key instead
-    of numbers if the family fails — partial evidence beats none)."""
-    bench = _import_bench()
-    factory, default_batch = bench._BENCHES[model]
-    batch = int(batch if batch is not None else default_batch)
-    t0 = time.perf_counter()
-    # tell build-time-measuring factories (trainer_prefetch) that only the
-    # AOT hook will be consumed — nothing may execute during the snapshot
-    prev = os.environ.get("BENCH_ANALYTIC_BUILD")
-    os.environ["BENCH_ANALYTIC_BUILD"] = "1"
-    try:
-        built = factory(batch)
-        run, model_flops, _baseline, metric = built[:4]
-        extras = built[4] if len(built) > 4 else {}
-        lower = extras.get("lower")
-        if lower is None:
-            raise RuntimeError(f"bench family {model!r} exposes no "
-                               "extras['lower'] AOT hook")
-        compiled = lower().compile()
-        # inside the isolation net: cost_analysis()/as_text() raise
-        # Unimplemented on some backend/jax combinations (the documented
-        # BENCH_PLATFORM override), and one family's extraction failure
-        # must degrade to an error row, not kill the snapshot
-        row = cost.extract(compiled)
-        # structural acceptance gate hook: a family may ship a
-        # postcheck(compiled) -> dict that ASSERTS on the compiled
-        # program (e.g. serving_decode_fused's fusion proof) and
-        # returns extra row fields; a failed assertion degrades this
-        # family to an error row like any other capture failure
-        postcheck = extras.get("postcheck")
-        if postcheck is not None:
-            row.update(postcheck(compiled))
-    except Exception as e:    # noqa: BLE001 — per-family isolation
-        return {"model": model, "batch": batch,
-                "error": f"{type(e).__name__}: {e}"[:500]}
-    finally:
-        if prev is None:
-            os.environ.pop("BENCH_ANALYTIC_BUILD", None)
-        else:
-            os.environ["BENCH_ANALYTIC_BUILD"] = prev
-    row.update(model=model, batch=batch, metric=metric,
-               compile_s=round(time.perf_counter() - t0, 1))
-    # bench.py's hand-derived FLOPs model, normalized to the same scope
-    # as the lowered program (one step); trainer_prefetch's model covers
-    # a whole pass, the serving families' covers the whole request
-    # stream/burst — the lowered program there is one batch, so scopes
-    # differ and the cross-check is omitted for them.
-    bps = extras.get("batches_per_step")
-    if model in ("transformer_serving", "serving", "serving_generate",
-                 "serving_fleet", "serving_paged",
-                 "serving_decode_fused", "serving_autoscale",
-                 "serving_chunked_prefill", "serving_quant",
-                 "serving_quant_prefill",
-                 "serving_speculative", "serving_sharded",
-                 "serving_kv_spill", "serving_disagg"):
-        # the lowered program is one batch/slab step while the bench FLOPs
-        # model covers the whole stream/burst — scopes differ, no cross-check
-        row["bench_model_flops"] = None
-    else:
-        row["bench_model_flops"] = model_flops / (bps or 1)
-    row["roofline"] = {c: roofline.predict(row["flops"],
-                                           row["bytes_accessed"], c)
-                       for c in chips}
-    head = row["roofline"][chips[0]]
-    row["predicted_ms"] = head["predicted_ms"]
-    row["predicted_mfu"] = head["predicted_mfu"]
-    row["bottleneck"] = head["bottleneck"]
-    return row
-
-
-def snapshot(families=None, chips=("v5e", "v5p")):
-    """Full snapshot dict for the given family names (default: all)."""
-    import jax
-    sel = [f for f in FAMILIES if families is None or f[0] in families]
-    unknown = set(families or ()) - {f[0] for f in sel}
-    if unknown:
-        raise SystemExit(f"unknown analytic families: {sorted(unknown)} "
-                         f"(known: {[f[0] for f in FAMILIES]})")
-    rows = {}
-    for name, model, batch in sel:
-        _log(f"{name} (model={model} batch={batch or 'default'}) ...")
-        need = MESH_FAMILIES.get(name, 0)
-        if need and len(jax.devices()) < need:
-            _log(f"{name}: needs a {need}-device mesh, forcing it in a "
-                 "subprocess (this process stays single-device)")
-            rows[name] = _capture_subprocess(name, model, batch, need)
-        else:
-            rows[name] = capture(name, model, batch, chips=chips)
-        if "error" in rows[name]:
-            _log(f"{name}: FAILED {rows[name]['error']}")
-        else:
-            _log(f"{name}: {rows[name]['flops'] / 1e9:.1f} GFLOP, "
-                 f"{rows[name]['bytes_accessed'] / 1e6:.0f} MB, "
-                 f"predicted {rows[name]['predicted_ms']:.2f} ms "
-                 f"({rows[name]['bottleneck']}-bound, "
-                 f"MFU<={rows[name]['predicted_mfu'] * 100:.0f}%)")
-        gc.collect()
-    try:
-        from paddle_tpu.utils.revision import code_revision
-        rev = code_revision()
-    except Exception:   # noqa: BLE001
-        rev = "unknown"
-    return {
-        "schema": 1,
-        "kind": "paddle_tpu analytic perf snapshot",
-        "generated_at": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
-        "revision": rev,
-        "backend": jax.default_backend(),
-        "jax_version": jax.__version__,
-        "roofline_chips": list(chips),
-        "families": rows,
-    }
-
-
-def write(path, snap):
-    tmp = path + ".tmp"
-    with open(tmp, "w") as f:
-        json.dump(snap, f, indent=1, sort_keys=True)
-        f.write("\n")
-    os.replace(tmp, path)
-    return path
-
-
-def main(argv=None):
-    ap = argparse.ArgumentParser(
-        description="chip-independent analytic perf snapshot")
-    ap.add_argument("--analytic", action="store_true",
-                    help="accepted for bench.py passthrough; implied")
-    ap.add_argument("--families", default=None,
-                    help="comma-separated subset (default: all)")
-    ap.add_argument("--out", default=os.environ.get("BENCH_ANALYTIC_OUT",
-                                                    DEFAULT_OUT))
-    args = ap.parse_args(argv)
-
-    # the snapshot is defined on the CPU backend (works every round); an
-    # explicit BENCH_PLATFORM still overrides for A/B-ing backends
-    platform = os.environ.get("BENCH_PLATFORM", "cpu")
-    os.environ["JAX_PLATFORMS"] = platform
-    import jax
-    jax.config.update("jax_platforms", platform)
-
-    fams = ([f.strip() for f in args.families.split(",") if f.strip()]
-            if args.families else None)
-    snap = snapshot(families=fams)
-    write(args.out, snap)
-    errors = sorted(n for n, r in snap["families"].items() if "error" in r)
-    out = {"metric": "analytic perf snapshot (roofline v5e)",
-           "value": len(snap["families"]) - len(errors),
-           "unit": f"families_ok/{len(snap['families'])}",
-           "vs_baseline": None, "out": args.out, "backend": snap["backend"]}
-    if errors:
-        out["errors"] = errors
-    print(json.dumps(out), flush=True)
-    return 2 if errors else 0
-
-
-if __name__ == "__main__":
-    sys.exit(main())

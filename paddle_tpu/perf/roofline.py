@@ -16,9 +16,9 @@ f32 traffic unless the program itself casts; on TPU the auto bf16 policy
 roughly halves matmul operand bytes — the prediction is conservative for
 bandwidth-bound families.
 
-``SPECS`` is the repository's ONE peaks table: every consumer — the
-analytic snapshot, bench.py's MFU denominator, the serving engine's
-restore/handoff routers — reads a chip's peaks from here, by the
+``SPECS`` is the program's peaks table: every consumer in the package —
+the serving engine's restore/handoff routers, through
+``perf/analytic.predicted_*`` — reads a chip's peaks from here, by the
 ``device_kind`` JAX reports (``for_device_kind``).  A device that is not
 in the table is an error, never a default.
 """

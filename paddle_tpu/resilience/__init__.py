@@ -10,7 +10,7 @@
                    streams bit-identical across a mid-stream rebuild),
                    circuit breaker (fast 503 + Retry-After), bounded
                    retry with backoff+jitter for transient submits
-    __main__.py    chaos smoke CLI (healthy_window.sh phase 9): serving
+    __main__.py    chaos smoke CLI: serving
                    under an injected decode fault + kill-9 trainer
                    resume, one JSON line
 

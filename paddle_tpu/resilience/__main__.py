@@ -1,4 +1,4 @@
-"""Chaos smoke CLI — healthy_window.sh phase 9.
+"""Chaos smoke CLI: serving and training under injected faults.
 
     python -m paddle_tpu.resilience --smoke
 
@@ -53,7 +53,7 @@ def _chaos_serving(errs):
                               dff=64, enc_layers=2, dec_layers=0,
                               max_len=48)
     engine = DecodeEngine(params, num_heads=2, num_slots=4, max_len=48,
-                          prefill_buckets=(8, 16), name="chaos_lm")
+                          name="chaos_lm")
     sup = Supervisor(step_deadline_s=2.0, breaker_threshold=5)
     gen = GenerationBatcher(engine, default_max_tokens=8, supervisor=sup)
     httpd = make_server(None, port=0, gen_batcher=gen)

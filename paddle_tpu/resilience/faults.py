@@ -15,8 +15,7 @@ With no plan installed (the default, and the only state production ever
 runs in) ``hit()`` is a function call plus one ``is None`` test — it
 cannot retrace, allocate, or touch a lock.  The hooks live strictly in
 HOST code (never inside a jit-traced body), so an installed plan changes
-no XLA program either: ``bench.py --analytic-diff`` stays clean by
-construction.
+no XLA program either.
 
 A ``FaultPlan`` is a set of per-point rules, each fully deterministic:
 
@@ -58,7 +57,6 @@ from paddle_tpu.utils.error import ConfigError
 # fire).
 FAULT_POINTS = (
     "serving.engine.execute",      # InferenceEngine._infer_bucketed
-    "serving.prefill",             # DecodeEngine.prefill
     "serving.decode_step",         # DecodeEngine.step (host wrapper)
     "batcher.submit",              # Batcher.submit / GenerationBatcher.submit
     "data.prefetch.h2d",           # ShardedPrefetcher producer placement

@@ -7,7 +7,7 @@ victims' work on the floor and keeps admitting traffic into a possibly
 sick engine.  This module adds the supervision layer:
 
 * ``Supervisor.run_step(engine)`` — a per-step WATCHDOG: when
-  ``step_deadline_s`` is set, the slab decode step runs on a sacrificial
+  ``step_deadline_s`` is set, the decode step runs on a sacrificial
   thread and a step that neither returns nor raises within the deadline
   trips ``WatchdogTimeout``.  The hung thread cannot be killed (Python),
   but the engine's epoch guard (``DecodeEngine.reset`` bumps an epoch;
@@ -15,10 +15,9 @@ sick engine.  This module adds the supervision layer:
   can never poison the rebuilt slab.
 
 * ``Supervisor.reprefill(engine, items)`` — SLOT RECOVERY: interrupted
-  requests are reconstructed by re-prefilling the longest ladder-covered
-  prefix of ``prompt + tokens-so-far`` (same-bucket victims as ONE
-  engine batch) and teacher-force-replaying the remainder through the
-  shared slab step — byte-for-byte the state each slot held before the
+  requests are reconstructed by re-seating them and teacher-force-
+  replaying ``prompt + tokens-so-far`` through the shared step, K lanes
+  at a time — byte-for-byte the state each slot held before the
   failure, so a recovered greedy stream stays bit-identical to
   ``lm_generate`` even across a mid-stream engine rebuild.  Recovery
   runs entirely over warm executables: zero new traces beyond the
@@ -159,8 +158,8 @@ class CircuitBreaker:
                 return True, None
             now = self._clock()
             # half-open: one probe per cooldown window.  A probe that
-            # never resolves through a step (e.g. it finished at
-            # prefill) must not wedge admissions forever — after a
+            # never resolves through a step (e.g. it was abandoned
+            # before it reached a slot) must not wedge admissions forever — after a
             # further cooldown a fresh probe is handed out.
             if st == "half_open" and (
                     not self._probe_out
@@ -201,11 +200,11 @@ def retry_transient(fn, budget=3, base_delay_s=0.01, max_delay_s=0.5,
 class Supervisor:
     """Per-engine supervision policy for a ``GenerationBatcher``.
 
-    step_deadline_s: watchdog deadline for one slab step (None = off,
+    step_deadline_s: watchdog deadline for one decode step (None = off,
     the step runs inline with zero overhead).  breaker_threshold /
     breaker_cooldown_s: circuit-breaker tuning (docs/serving.md §6).
     max_request_recoveries: how many times ONE request may be re-
-    prefilled before it is failed (bounds the work a permanently
+    seated before it is failed (bounds the work a permanently
     poisoned step can burn).
     """
 
@@ -281,17 +280,12 @@ class Supervisor:
         engine.  ``items`` is a list of ``(prompt, tokens)``; for each,
         the lost cache held K/V for ``full[0:R]`` with the last delivered
         token armed at position R, where ``full = prompt + tokens`` and
-        ``R = len(full) - 1``.  Rebuild in two warm-executable legs:
-
-        1. re-PREFILL the longest prefix the ladder covers (all of
-           ``full[:R]`` when R fits; the ladder-top prefix otherwise) —
-           same-bucket victims prefill as ONE engine batch, so a full
-           slab recovers in a handful of prefill executions, not one
-           per slot — and seat each in a fresh slot;
-        2. teacher-force-REPLAY the remainder through the shared slab
-           step: each replay step feeds the RECORDED stream and its
-           re-derived emission is swallowed by the batcher (the
-           ``replay_feed`` returned here), never re-delivered.
+        ``R = len(full) - 1``.  Rebuild over the warm step: seat each
+        in a fresh slot and teacher-force-REPLAY ``full`` through the
+        shared step, up to K lanes per step (docs/serving.md "Chunked
+        prefill"): each replay step feeds the RECORDED stream and its
+        re-derived emission is swallowed by the batcher (the
+        ``replay_feed`` returned here), never re-delivered.
 
         Greedy decode is deterministic, so after the replay drains each
         slot is byte-for-byte its pre-failure state and the stream
@@ -301,15 +295,9 @@ class Supervisor:
         victim's failure never blocks the others).
 
         The mechanics live in ``DecodeEngine.seat_prefilled`` — the ONE
-        seat-prefix helper this path shares with the batcher's
-        continuation-``replay`` leg, paged prefix-cache admission, and
-        pool-pressure re-seating (serving/kv_pool.py).  On a CHUNKED
-        engine (prefill_chunk > 0) recovery rides chunks: leg 1's
-        ladder re-prefill disappears and the whole context returns as
-        the feed, drained up to K lanes per step through the one
-        unified executable (docs/serving.md "Chunked prefill") — K×
-        fewer recovery steps than per-token teacher-forcing, still
-        bit-identical, still zero new traces."""
+        seat-prefix helper this path shares with fresh admission, the
+        batcher's continuation-``replay`` leg and pool-pressure
+        re-seating (serving/kv_pool.py).  Zero new traces."""
         import numpy as np
         with obstrace.span("supervisor.reprefill", root=False,
                            n=len(items)):

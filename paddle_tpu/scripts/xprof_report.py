@@ -3,9 +3,8 @@
 The MFU tuning loop needs to know WHERE a step's time goes before a chip
 window opens (round-3 verdict: pre-stage the analysis so the window is
 measure-only).  This reads the chrome-trace half of a profile directory
-written by `jax.profiler.start_trace` (bench.py's BENCH_PROFILE_DIR /
-bench_sweep's BENCH_PROFILE_BASE) — stdlib-only, no tensorboard needed —
-and reports, per device track:
+written by `jax.profiler.start_trace` — stdlib-only, no tensorboard
+needed — and reports, per device track:
 
   - busy vs idle time over the traced span (MXU starvation shows as idle)
   - time by category: matmul/conv (MXU), fusion (VPU/elementwise),
@@ -16,9 +15,9 @@ and reports, per device track:
 
 Usage:
   python -m paddle_tpu.scripts.xprof_report PROFILE_DIR [--top N] [--json]
-PROFILE_DIR may be a bench profile dir (contains plugins/profile/<run>/),
-a run dir itself, or a BENCH_PROFILE_BASE parent of per-combo dirs —
-every run found is reported.
+PROFILE_DIR may be a profile dir (contains plugins/profile/<run>/), a
+run dir itself, or a parent of several profile dirs — every run found is
+reported.
 """
 
 import argparse

@@ -44,7 +44,7 @@ deterministic.
 CLI (``python -m paddle_tpu.serving.autoscaler``):
   --min-replicas/--max-replicas --target-ttft-ms ...   run a managed
       fleet + router + autoscaler (the production shape)
-  --smoke   self-test (healthy_window.sh phase 14): 1 replica + a
+  --smoke   self-test: 1 replica + a
       seeded load spike → scale-out to 2 and p99 TTFT back under
       target, spike ends → rolling scale-in, ZERO failed requests;
       ONE JSON line, exit code.
@@ -463,7 +463,7 @@ class Autoscaler:
 
 
 def _smoke():
-    """Autoscale self-test (healthy_window.sh phase 14): ONE tiny demo
+    """Autoscale self-test: ONE tiny demo
     replica behind the router + autoscaler (min 1, max 2); a seeded load
     spike of concurrent paced streams breaches the TTFT target → the
     loop scales out to 2 and spawn-to-readiness completes; with both
@@ -494,7 +494,6 @@ def _smoke():
     # p99 lands well above target*(1+hysteresis) while a 2-client
     # steady drive on the scaled fleet stays far below target
     extra = ["--gen-slots", str(slots), "--gen-max-len", str(max_len),
-             "--gen-prefill-buckets", "8,16",
              "--gen-max-tokens", str(n_tokens),
              "--fault-spec",
              "serving.decode_step:every=1,action=hang,hang_s=0.03"]

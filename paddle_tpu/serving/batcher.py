@@ -147,8 +147,8 @@ class Batcher:
         # start the queue-wait span before the enqueue (the worker may
         # pull the request the instant it lands); the rejection paths
         # below end it so a refused submit leaks nothing
-        # root=False: driven without an HTTP request span (bench drives,
-        # embedded use) this must not mint a "request" for slowest()
+        # root=False: driven without an HTTP request span (benchmark
+        # drives, embedded use) this must not mint a "request" for slowest()
         req.queue_span = obstrace.start_span("batcher.queue_wait",
                                              root=False)
         with self._admit_lock:

@@ -4,55 +4,42 @@
 end: a single long request holds the whole batch hostage, finished rows
 keep burning decode steps until the slowest row is done, and new
 requests wait for the entire batch to drain.  This module is the serving
-answer (Orca-style iteration-level scheduling over a vLLM-style slot
-slab):
+answer (Orca-style iteration-level scheduling, Sarathi-style chunked
+prefill, over a vLLM-style cache):
 
-* ``DecodeEngine`` — a fixed-shape KV-cache SLAB ``[num_slots, max_len,
-  Dkv]`` per layer (``init_lm_cache`` machinery) plus per-slot position
-  counters.  ONE jitted decode step (``lm_decode_step_slots``) advances
-  every slot by one token; each row runs at its own position, so slots
-  hold unrelated requests at unrelated depths.  Admission and eviction
-  happen BETWEEN steps, entirely on the host: a freed slot's cache row is
-  overwritten wholesale at the next admission, so scheduling never
-  touches compiled code and the step traces exactly once at warm-up and
-  never again (``expect_traces`` discipline, shared with
-  ``InferenceEngine.warmup`` and ``SGD.precompile``).
+* ``DecodeEngine`` — ``num_slots`` slots, each holding one request at
+  its own depth, and ONE jitted step (``lm_decode_chunk_slots`` /
+  ``lm_decode_chunk_paged``, or a model's own ``decode_chunk``) that
+  advances every slot by up to ``prefill_chunk`` = K token lanes: a
+  decoding slot feeds 1 lane, a slot still ingesting its prompt feeds
+  up to K prompt tokens (re-derived emissions swallowed until the last
+  chunk, whose output is the first real token).  Tokens, positions AND
+  per-slot lane counts are data, so the chunk budget tunes without
+  retracing; there is no separate prefill program, no admission write
+  and no prompt cap below ``max_len`` — one executable is the whole
+  serving hot path.  Admission and eviction happen BETWEEN steps,
+  entirely on the host, so scheduling never touches compiled code and
+  the step traces exactly once at warm-up and never again
+  (``expect_traces`` discipline, shared with ``InferenceEngine.warmup``
+  and ``SGD.precompile``).  Every greedy stream is bit-identical to
+  running the request alone through ``lm_generate`` (the parity tests
+  pin this token for token).
 
-* ``DecodeEngine(kv_layout="paged")`` — the same engine over a PAGED
-  KV cache (docs/serving.md §5): per layer a block POOL ``[num_blocks,
-  block_size, Dkv]`` plus per-slot block tables, managed by the
-  host-side allocator in ``serving/kv_pool.py`` (free list, per-block
-  refcounts, copy-on-write forks, prefix index).  Memory is committed
-  per BLOCK as a stream actually grows instead of ``max_len`` up front,
-  so mixed-length traffic packs by actual length, and requests sharing
-  a prompt prefix map their leading blocks to the SAME physical blocks
-  (admission takes references instead of re-prefilling — the vLLM/
-  PagedAttention memory tier over the Orca scheduler above).  Still ONE
-  jitted step (``lm_decode_step_paged``): the block table is data, not
+* Two cache layouts.  ``kv_layout="slab"``: a fixed-shape KV-cache SLAB
+  ``[num_slots, max_len, Dkv]`` per layer (``init_lm_cache``); a freed
+  slot's row is simply overwritten by its next occupant.
+  ``kv_layout="paged"`` (docs/serving.md §5): per layer a block POOL
+  ``[num_blocks, block_size, Dkv]`` plus per-slot block tables, managed
+  by the host-side allocator in ``serving/kv_pool.py`` (free list,
+  per-block refcounts, copy-on-write forks, prefix index).  Memory is
+  committed per BLOCK as a stream actually grows instead of ``max_len``
+  up front, so mixed-length traffic packs by actual length, and requests
+  sharing a prompt prefix map their leading blocks to the SAME physical
+  blocks (admission takes references instead of re-ingesting — the
+  vLLM/PagedAttention memory tier).  The block table is data, not
   shape, so admission/eviction/fork churn never retraces, and greedy
   streams stay bit-identical to the slab and to ``lm_generate``
-  (tests/test_kv_pool.py).  The slab stays the default layout.
-
-* ``DecodeEngine(prefill_chunk=K)`` — UNIFIED CHUNKED PREFILL (the
-  serving CLI default; docs/serving.md "Chunked prefill"): prompt
-  ingestion folds into the one jitted step itself
-  (``lm_decode_chunk_slots``/``_paged`` — Sarathi-style chunked
-  prefill on the Orca scheduler).  Each step advances a MIX of decode
-  rows (1 token) and admitting rows (up to K prompt tokens, re-derived
-  emissions swallowed until the last chunk, whose output is the first
-  real token).  Tokens, positions AND per-slot lane counts are data,
-  so the chunk budget tunes without retracing; there is no admission
-  write, no prefill ladder, and no prompt cap below ``max_len`` —
-  ONE executable is the whole serving hot path.
-
-* Legacy mode (``prefill_chunk=0``): prefill rides the bucketed
-  ``InferenceEngine`` ladder — one engine per prompt-LENGTH bucket
-  (each with its own batch-bucket ladder), whose forward is
-  ``lm_prefill`` + the last-real-position logits — the exact
-  composition ``lm_generate`` uses, so a request's greedy stream is
-  bit-identical to running it alone (the parity tests pin this token
-  for token).  Prompt compile cost is paid once per (length bucket,
-  batch bucket), never per request.
+  (tests/test_kv_pool.py).
 
 * ``GenerationBatcher`` — the request front: bounded queue, per-request
   deadlines (``DeadlineExceededError`` while queued), admission control
@@ -83,7 +70,7 @@ from paddle_tpu.resilience.supervisor import (BreakerOpenError,
 from paddle_tpu.serving.batcher import (BatchExecutionError,
                                         DeadlineExceededError,
                                         OverloadedError, ShutdownError)
-from paddle_tpu.serving.engine import InferenceEngine, InvalidRequestError
+from paddle_tpu.serving.engine import InvalidRequestError
 from paddle_tpu.quant.kv import KV_DTYPES
 from paddle_tpu.quant.weights import weight_shape as _w_shape
 from paddle_tpu.serving.kv_pool import (BLOCK_LEAF, SLOT_LEAF, HostTier,
@@ -100,36 +87,19 @@ from paddle_tpu.testing.trace import expect_traces
 from paddle_tpu.utils.error import ConfigError
 from paddle_tpu.utils.logging import logger
 
-DEFAULT_PREFILL_BUCKETS = (32, 64)
-
-
-def _block_chunk(row, j, block_size):
-    """Block ``j`` of a prefill cache row ``[bucket, Dkv]`` as an exact
-    ``[block_size, Dkv]`` chunk (zero-padded past the bucket — those
-    positions are masked until the decode step overwrites them)."""
-    piece = np.asarray(row)[j * block_size:(j + 1) * block_size]
-    if piece.shape[0] == block_size:
-        return piece
-    pad = np.zeros((block_size - piece.shape[0],) + piece.shape[1:],
-                   piece.dtype)
-    return np.concatenate([piece, pad], axis=0)
-
-
 class DecodeEngine:
     """Slot-based continuous-batching decoder over a decoder-only LM trunk
     (``models/transformer`` params with ``dec_layers=0``).
 
-    params: the trunk pytree; num_slots: concurrent requests the slab
-    holds; max_len: slab length — every request must satisfy
-    ``len(prompt) + max_tokens <= max_len``; prefill_buckets: prompt-
-    length ladder (prompts pad up to the nearest bucket; the top bucket
-    caps prompt length); prefill_batch_buckets: the batch ladder each
-    prefill engine compiles; eos_id: default stop token (None = run to
-    max_tokens; per-request override at submit).
+    params: the trunk pytree; num_slots: concurrent requests the engine
+    holds; max_len: positions a slot can hold — every request must
+    satisfy ``len(prompt) + max_tokens <= max_len``; eos_id: default
+    stop token (None = run to max_tokens; per-request override at
+    submit).
 
-    prefill_chunk: 0 (legacy ladder prefill) or K > 0 — unified chunked
-    prefill: prompts ingest through the one decode step as up-to-K-token
-    chunks (``[S, K]`` token lanes; docs/serving.md "Chunked prefill").
+    prefill_chunk: K >= 1, the token lanes of the one step — prompts
+    ingest through it as up-to-K-token chunks (``[S, K]`` token lanes;
+    docs/serving.md "Chunked prefill"), a decoding slot feeds one.
     prefill_chunk_budget: max teacher-forced lanes one step may feed
     across all slots (0 = unbounded) — pure data, bounds per-step
     prefill work and hence TPOT jitter.
@@ -152,19 +122,19 @@ class DecodeEngine:
     the same byte budget.  Composable with quantized weights
     (quant/weights.quantize_lm — just pass the quantized params tree).
 
-    Slot lifecycle (docs/serving.md §4): FREE -> (prefill) -> ACTIVE
-    -> one emitted token per ``step()`` -> EVICTED (eos | length |
-    error | shutdown | pool_exhausted) -> FREE.  All bookkeeping is
-    host-side numpy; the device only ever sees the fixed-shape slab/pool
-    step and the fixed-shape admission writes.
+    Slot lifecycle (docs/serving.md §4): FREE -> SEATED (context fed K
+    lanes a step, emissions swallowed) -> ACTIVE -> one emitted token
+    per ``step()`` -> EVICTED (eos | length | error | shutdown |
+    pool_exhausted) -> FREE.  All bookkeeping is host-side numpy; the
+    device only ever sees the fixed-shape step (and, paged, the
+    fixed-shape block fork and restore write).
     """
 
     def __init__(self, params, *, num_heads=8, num_slots=8, max_len=256,
-                 prefill_buckets=DEFAULT_PREFILL_BUCKETS,
-                 prefill_batch_buckets=(1, 4), eos_id=None, moe_top_k=2,
-                 pos_type="learned", metrics=None, name="lm", warm=True,
+                 eos_id=None, moe_top_k=2, pos_type="learned",
+                 metrics=None, name="lm", warm=True,
                  kv_layout="slab", kv_block_size=16, kv_num_blocks=0,
-                 prefix_cache=True, prefill_chunk=0,
+                 prefix_cache=True, prefill_chunk=8,
                  prefill_chunk_budget=0, kv_dtype="float32",
                  speculate_k=0, draft=None, mesh=None, kv_host_bytes=0,
                  model=None):
@@ -179,9 +149,9 @@ class DecodeEngine:
         self._leaf_kinds = None
         if model is not None:
             self._check_model_config(
-                kv_layout=kv_layout, prefill_chunk=prefill_chunk,
-                prefix_cache=prefix_cache, speculate_k=speculate_k,
-                kv_host_bytes=kv_host_bytes, mesh=mesh, kv_dtype=kv_dtype)
+                kv_layout=kv_layout, prefix_cache=prefix_cache,
+                speculate_k=speculate_k, kv_host_bytes=kv_host_bytes,
+                mesh=mesh, kv_dtype=kv_dtype)
             self._leaf_kinds = model.cache_kinds()
         if params.get("dec"):
             raise ConfigError(
@@ -197,21 +167,21 @@ class DecodeEngine:
         self.pos_type = pos_type
         self.name = name
         self._metrics = metrics or ServingMetrics()
-        # unified chunked prefill (docs/serving.md "Chunked prefill"):
-        # prefill_chunk = K > 0 folds prompt ingestion into the ONE
-        # jitted decode step — each step advances a mix of decode rows
-        # (1 token) and admitting rows (up to K prompt tokens, logits
-        # discarded until the last chunk).  The separate prefill
-        # InferenceEngine ladder below is the opt-in LEGACY mode
-        # (prefill_chunk=0).  prefill_chunk_budget: max teacher-forced
-        # lanes per step across all slots (0 = unbounded) — data, not
-        # shape, so tuning it never retraces.
+        # chunked prefill (docs/serving.md "Chunked prefill"): prompt
+        # ingestion rides the ONE jitted step — each step advances a mix
+        # of decode rows (1 token) and admitting rows (up to K prompt
+        # tokens, logits discarded until the last chunk).
+        # prefill_chunk_budget: max teacher-forced lanes per step across
+        # all slots (0 = unbounded) — data, not shape, so tuning it
+        # never retraces.
         self.prefill_chunk = int(prefill_chunk or 0)
         self.prefill_chunk_budget = int(prefill_chunk_budget or 0)
-        if self.prefill_chunk < 0 or self.prefill_chunk > self.max_len:
+        if not 1 <= self.prefill_chunk <= self.max_len:
             raise ConfigError(
                 f"prefill_chunk={prefill_chunk} must be in "
-                f"[0, max_len={self.max_len}]")
+                f"[1, max_len={self.max_len}] (0 used to select the "
+                "bucketed prefill ladder, which is gone: prompts ingest "
+                "through the one chunk step)")
         # speculative decoding (serving/speculative.py; docs/serving.md
         # "Speculative decoding"): a draft trunk proposes up to
         # speculate_k tokens per slot, the ONE chunked step scores them
@@ -226,11 +196,6 @@ class DecodeEngine:
             raise ConfigError(
                 f"speculate_k={speculate_k} must be in "
                 f"[0, max_len={self.max_len})")
-        if self.speculate_k and not self.prefill_chunk:
-            raise ConfigError(
-                "speculate_k needs the unified chunked step "
-                "(prefill_chunk > 0): the verify step IS the chunk "
-                "step scoring draft lanes")
         if draft is not None and not self.speculate_k:
             raise ConfigError("a draft trunk without speculate_k > 0 "
                               "would never run")
@@ -242,19 +207,7 @@ class DecodeEngine:
         # token-lane width: the chunk step's K dimension must hold the
         # larger of a prefill chunk and a full verify span (the
         # committed token + speculate_k draft lanes)
-        self._kk = (max(self.prefill_chunk, self.speculate_k + 1)
-                    if self.prefill_chunk else 0)
-        self.prefill_buckets = tuple(sorted(set(int(b)
-                                                for b in prefill_buckets)))
-        if not self.prefill_buckets or self.prefill_buckets[0] < 1:
-            raise ConfigError(f"bad prefill ladder {prefill_buckets!r}")
-        if not self.prefill_chunk \
-                and self.prefill_buckets[-1] >= self.max_len:
-            # chunked mode never builds the ladder, so its shape cannot
-            # invalidate a chunked engine
-            raise ConfigError(
-                f"prefill bucket top {self.prefill_buckets[-1]} leaves no "
-                f"room to generate within max_len={self.max_len}")
+        self._kk = max(self.prefill_chunk, self.speculate_k + 1)
         if self.num_slots < 1:
             raise ConfigError("num_slots must be >= 1")
         if kv_layout not in ("slab", "paged"):
@@ -279,7 +232,7 @@ class DecodeEngine:
         # 0-retrace discipline is untouched.
         self.kv_dtype = kv_dtype
         # tensor-parallel sharded decode (docs/serving.md "Sharded
-        # decode"): mesh=... runs the ONE chunked step under
+        # decode"): mesh=... runs the ONE step under
         # parallel.sharding.shard_map with head-sharded attention, a
         # head-sharded KV pool (each chip holds its Hkv/n stripe of
         # every slot row / pool block — tables/allocator/prefix-index/
@@ -299,11 +252,6 @@ class DecodeEngine:
                     "DecodeEngine(mesh=...) needs a mesh with a "
                     f"'{AXIS_MODEL}' axis "
                     "(parallel.sharding.decode_mesh builds one)")
-            if not self.prefill_chunk:
-                raise ConfigError(
-                    "sharded decode runs on the unified chunked step: "
-                    "set prefill_chunk > 0 (the legacy prefill ladder "
-                    "is single-chip only)")
             probs = _psh.lm_shard_problems(params, self.num_heads,
                                            int(mesh.shape[AXIS_MODEL]))
             if probs:
@@ -315,7 +263,7 @@ class DecodeEngine:
             self.mesh_shards = int(mesh.shape[AXIS_MODEL])
             # place the params ONCE: wq/wk/wv + src_emb (and their int8
             # payload/scale leaves) as stripes, everything else
-            # replicated — admission/step/reset all reuse this placement
+            # replicated — step and reset both reuse this placement
             pspecs = _psh.lm_decode_param_specs(params, AXIS_MODEL)
             params = jax.tree_util.tree_map(
                 lambda l, s: jax.device_put(l, NamedSharding(mesh, s)),
@@ -398,23 +346,17 @@ class DecodeEngine:
                 lambda: transformer.init_lm_cache(
                     params, self.num_slots, self.max_len,
                     kv_dtype=kv_dtype, num_heads=self.num_heads))
-        # prefill-compute ledger: real positions run through the prefill
-        # ladder (the paged prefix cache's whole point is to NOT grow
-        # this; bench.py serving_paged reads it for the elimination rate)
+        # prefill-compute ledger: context positions seated for ingestion
+        # through the step (the paged prefix cache's whole point is to
+        # NOT grow this: a resident prefix seats by reference)
         self.prefill_positions_total = 0
-        # host-side slot state: token(s) fed at the NEXT step and the
-        # position lane 0 sits at; free slots idle at (0, 0) — their
-        # compute is discarded and their cache row is overwritten at
-        # admission.  Chunked mode widens the token row to K lanes and
-        # adds the per-slot lane count (_len — per-slot variable
-        # advance, the generalized position counter).
-        if self.prefill_chunk:
-            self._tokens = np.zeros((self.num_slots, self._kk),
-                                    np.int32)
-            self._len = np.ones((self.num_slots,), np.int32)
-        else:
-            self._tokens = np.zeros((self.num_slots,), np.int32)
-            self._len = None
+        # host-side slot state: the K token lanes fed at the NEXT step,
+        # how many of them are live (_len — the per-slot variable
+        # advance) and the position lane 0 sits at; free slots idle at
+        # (0, 0, 1 lane) — their compute is discarded and their cache
+        # row is overwritten by the next occupant
+        self._tokens = np.zeros((self.num_slots, self._kk), np.int32)
+        self._len = np.ones((self.num_slots,), np.int32)
         # draft-side host bookkeeping (speculative mode).  Invariant per
         # active slot: _d_pos + len(_d_feed) == _pos + 1 — every
         # committed token (and nothing else) either sits in the draft
@@ -464,8 +406,6 @@ class DecodeEngine:
         # could pass the check and then overwrite the fresh slab.
         self._epoch = 0
         self._epoch_lock = threading.Lock()
-        self._prefill_batch_buckets = tuple(prefill_batch_buckets)
-        self._prefill_engines = {}     # length bucket -> InferenceEngine
         self._step_traces = [0]
         # resolved at warm-up (the step's trace time): did the compiled
         # step take the fused Pallas decode-attention path, and if not,
@@ -502,7 +442,7 @@ class DecodeEngine:
                     p, tokens, pos, lens, cache, tables)
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 return (nxt, aux), cache
-        elif self.prefill_chunk and self.kv_layout == "paged":
+        elif self.kv_layout == "paged":
             def _model(p, cache, tokens, pos, lens, tables):
                 logits, cache = transformer.lm_decode_chunk_paged(
                     p, tokens, pos, lens, cache, tables, heads,
@@ -514,7 +454,7 @@ class DecodeEngine:
             def _step_fn(p, cache, tokens, pos, lens, tables):
                 self._step_traces[0] += 1  # runs only under tracing
                 return body(p, cache, tokens, pos, lens, tables)
-        elif self.prefill_chunk:
+        else:
             def _model(p, cache, tokens, pos, lens):
                 logits, cache = transformer.lm_decode_chunk_slots(
                     p, tokens, pos, lens, cache, heads,
@@ -526,37 +466,10 @@ class DecodeEngine:
             def _step_fn(p, cache, tokens, pos, lens):
                 self._step_traces[0] += 1  # runs only under tracing
                 return body(p, cache, tokens, pos, lens)
-        elif self.kv_layout == "paged":
-            def _step_fn(p, cache, tokens, pos, tables):
-                self._step_traces[0] += 1  # runs only under tracing
-                logits, cache = transformer.lm_decode_step_paged(
-                    p, tokens, pos, cache, tables, self.num_heads,
-                    self.moe_top_k, self.pos_type)
-                return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
-        else:
-            def _step_fn(p, cache, tokens, pos):
-                self._step_traces[0] += 1  # runs only under tracing
-                logits, cache = transformer.lm_decode_step_slots(
-                    p, tokens, pos, cache, self.num_heads, self.moe_top_k,
-                    self.pos_type)
-                return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
-
-        # donate the cache: the step rewrites one position per row, the
+        # donate the cache: the step rewrites a few positions per row, the
         # rest is carried through — without donation every step would copy
         # the whole slab/pool
         self._jit_step = jax.jit(_step_fn, donate_argnums=(1,))
-
-        def _admit_fn(cache, row, slot):
-            self._admit_traces[0] += 1
-            return jax.tree_util.tree_map(
-                lambda s, r: jax.lax.dynamic_update_slice(
-                    s, r[None].astype(s.dtype), (slot, 0, 0)), cache, row)
-
-        self._admit_traces = [0]
-        # jax.jit compiles one executable per distinct row prefix length
-        # (= prefill bucket); warm-up pays each bucket's trace up front.
-        # (slab layout only — paged admission goes through _jit_write)
-        self._jit_admit = jax.jit(_admit_fn, donate_argnums=(0,))
 
         # block writes and copies touch the block-addressed leaves alone
         # (every leaf of the transformer trunk's cache)
@@ -573,9 +486,9 @@ class DecodeEngine:
             return map_block_leaves(
                 lambda c: c.at[dst].set(c[src]), cache, kinds)
 
-        # paged device ops: ONE fixed [block_size, Dkv] write shape
-        # regardless of prompt bucket (one trace total), and the
-        # copy-on-write block fork
+        # paged device ops: ONE fixed [block_size, Dkv] write shape (a
+        # host-tier restore landing a block) and the copy-on-write
+        # block fork
         self._write_traces = [0]
         self._copy_traces = [0]
         self._jit_write = jax.jit(_write_fn, donate_argnums=(0,))
@@ -587,8 +500,8 @@ class DecodeEngine:
     # ------------------------------------------------ models with state
 
     @staticmethod
-    def _check_model_config(*, kv_layout, prefill_chunk, prefix_cache,
-                            speculate_k, kv_host_bytes, mesh, kv_dtype):
+    def _check_model_config(*, kv_layout, prefix_cache, speculate_k,
+                            kv_host_bytes, mesh, kv_dtype):
         """What ``model=`` cannot be combined with yet.  A slot of such a
         model owns state that no block table addresses; each feature below
         needs a way to SNAPSHOT that state at a position (ROADMAP D6), and
@@ -600,11 +513,6 @@ class DecodeEngine:
                 "model= serves through the paged layout only: the slab "
                 "layout keeps one row of positions a slot and has no place "
                 "for state that positions do not address")
-        if not prefill_chunk:
-            raise ConfigError(
-                "model= serves through the one chunked step "
-                "(prefill_chunk > 0): the legacy prefill ladder runs the "
-                "transformer trunk's own forward pass")
         if prefix_cache:
             raise ConfigError(
                 "prefix_cache with model=: a shared prefix's blocks hold "
@@ -667,7 +575,7 @@ class DecodeEngine:
         return self._psh.new_lm_cache(build, self.mesh, self._shard_axis)
 
     def _shard_body(self, fn, n_data):
-        """Wrap a chunked step body in ``parallel.sharding.shard_map``
+        """Wrap a step body in ``parallel.sharding.shard_map``
         over the engine's mesh (identity when unsharded).  in_specs:
         the param-stripe tree, the cache-stripe tree, then ``n_data``
         replicated host operands (tokens/pos/lens[/tables]).  The
@@ -686,100 +594,14 @@ class DecodeEngine:
             in_specs=(pspecs, cspecs) + (_P(),) * n_data,
             out_specs=(_P(), cspecs), check_vma=False)
 
-    # ------------------------------------------------------------ prefill
-
-    def prefill_bucket_for(self, n):
-        """Smallest prompt-length bucket >= n, or None beyond the top."""
-        for b in self.prefill_buckets:
-            if b >= n:
-                return b
-        return None
-
-    def _prefill_engine(self, bucket):
-        eng = self._prefill_engines.get(bucket)
-        if eng is not None:
-            return eng
-        params, transformer = self.params, self._transformer
-        trace_box = [0]
-
-        def fwd(feed):
-            trace_box[0] += 1
-            # cache at BUCKET length, not slab length: the admission
-            # write only needs the prompt prefix, so the device<->host
-            # round-trip per admission moves bucket-sized rows instead
-            # of max_len-sized ones.  kv_dtype threads through: an int8
-            # engine's prefill returns int8 rows + scale sidecars, so
-            # the admission write's tree_map dtypes line up
-            hidden, cache = transformer.lm_prefill(
-                params, feed["prompt"], bucket, self.num_heads,
-                self.moe_top_k, self.pos_type,
-                kv_dtype=None if self.kv_dtype == "float32"
-                else self.kv_dtype)
-            # the request's FIRST token comes from its last real
-            # position's hidden state — gather BEFORE the d_model x vocab
-            # projection, exactly like lm_generate
-            h_last = jnp.take_along_axis(
-                hidden, (feed["length"] - 1)[:, None, None], axis=1)
-            logits0 = transformer._lm_project(params, h_last)[:, 0]
-            return {"first_logits": logits0, "cache": cache}
-
-        spec = {"prompt": jax.ShapeDtypeStruct((1, bucket), np.int32),
-                "length": jax.ShapeDtypeStruct((1,), np.int32)}
-        eng = InferenceEngine(jitted=jax.jit(fwd), feed_spec=spec,
-                              buckets=self._prefill_batch_buckets,
-                              warm=False, name=f"{self.name}.prefill{bucket}",
-                              metrics=self.metrics, trace_box=trace_box)
-        self._prefill_engines[bucket] = eng
-        return eng
-
-    def prefill(self, prompts, lengths):
-        """Run prompts through the length-bucketed prefill ladder.
-
-        prompts: [n, L] int32 (rows padded to a common L <= the ladder
-        top; pad value is irrelevant — causal attention plus the decode
-        loop's own K/V rewrites keep it out of every real position);
-        lengths: [n] real lengths.  Returns (first_tokens [n] np.int32,
-        cache_rows: list of n per-layer {"k","v"} host-numpy rows
-        [bucket, Dkv] — BUCKET-length prefixes, which is all admission
-        writes into the slab; see ``admit``).
-        """
-        faults.hit("serving.prefill")
-        prompts = np.asarray(prompts, np.int32)
-        lengths = np.asarray(lengths, np.int32)
-        n, t = prompts.shape
-        bucket = self.prefill_bucket_for(t)
-        if bucket is None:
-            raise InvalidRequestError(
-                f"prompt length {t} exceeds the prefill ladder top "
-                f"{self.prefill_buckets[-1]}")
-        if t < bucket:
-            prompts = np.concatenate(
-                [prompts, np.zeros((n, bucket - t), np.int32)], axis=1)
-        self.prefill_positions_total += int(lengths.sum())
-        out = self._prefill_engine(bucket).infer(
-            {"prompt": prompts, "length": lengths})
-        first = np.argmax(out["first_logits"], axis=-1).astype(np.int32)
-        rows = [jax.tree_util.tree_map(lambda l, i=i: l[i], out["cache"])
-                for i in range(n)]
-        return first, rows
-
     # ------------------------------------------------------------ slots
 
-    @property
-    def chunked(self):
-        """True when prompt ingestion rides the unified chunked step
-        (``prefill_chunk > 0``) instead of the legacy prefill ladder."""
-        return self.prefill_chunk > 0
-
     def _arm(self, slot, token, pos):
-        """Point a slot at (token, position) for the next step — the one
-        place the two token-state layouts ([S] vs [S, K]) meet."""
-        if self.prefill_chunk:
-            self._tokens[slot, :] = 0
-            self._tokens[slot, 0] = token
-            self._len[slot] = 1
-        else:
-            self._tokens[slot] = token
+        """Point a slot at (token, position), one lane, for the next
+        step."""
+        self._tokens[slot, :] = 0
+        self._tokens[slot, 0] = token
+        self._len[slot] = 1
         self._pos[slot] = pos
 
     def _set_cache_gauges(self):
@@ -800,15 +622,15 @@ class DecodeEngine:
 
     @property
     def step_trace_count(self):
-        """Traces of the slab decode step (the no-retrace discipline:
+        """Traces of the decode step (the no-retrace discipline:
         exactly 1 after warm-up, flat across admission/eviction churn).
         ``lower()`` is an offline tool and re-stages (+1)."""
         return self._step_traces[0]
 
     @property
     def ready(self):
-        """Readiness (/readyz): the slab step, admission write, and
-        prefill ladder are all warm."""
+        """Readiness (/readyz): the step (and the paged block ops) are
+        warm."""
         return self._warm
 
     @property
@@ -817,74 +639,24 @@ class DecodeEngine:
 
     @metrics.setter
     def metrics(self, m):
-        # rewire the cached prefill engines too, so a metrics swap (the
-        # bench's per-drive reset) never strands the prefill plane's
-        # batch/latency stats on an orphaned object; the chunk-size
-        # gauge is config, so the fresh object inherits it immediately
+        # the config gauges are the engine's, not the object's: a fresh
+        # metrics object inherits them immediately
         self._metrics = m
         self._set_cache_gauges()
         m.set_prefill_chunk(self.prefill_chunk)
         m.set_kv_dtype(self.kv_dtype)
         m.set_speculate_k(self.speculate_k)
         m.set_mesh_shards(self.mesh_shards)
-        for eng in self._prefill_engines.values():
-            eng.metrics = m
-
-    def admit(self, first_token, cache_row, length, tokens=None):
-        """Seat one prefilled request and arm the slot at (first_token,
-        position=length).  Returns the slot id; raises if no slot is
-        free (callers check ``free_slots`` — the batcher never
-        over-admits).
-
-        Slab: write the bucket-length cache rows into positions
-        [0, bucket) of a free slot's slab row.  The row tail past the
-        bucket keeps whatever the previous occupant left there — safe by
-        the same argument that covers prompt padding: position p is
-        scatter-overwritten by the decode step in the same step that
-        first unmasks it.
-
-        Paged: claim ``ceil(length / block_size)`` private blocks, chop
-        the prefill rows into block-sized chunks and write each into its
-        block (ONE compiled write shape — no per-bucket executables),
-        then, when ``tokens`` (the real prefix ids) are given and the
-        prefix cache is on, publish the full-block prefixes so later
-        requests admit by reference.  Raises ``InsufficientBlocksError``
-        (nothing claimed) when the pool is dry — the batcher defers the
-        request instead of failing it."""
-        if not self._free:
-            raise RuntimeError(f"{self.name}: no free decode slot")
-        if self.kv_layout == "paged":
-            slot = self._free.pop()
-            try:
-                chain = self._paged.seat_fresh(slot, int(length))
-            except InsufficientBlocksError:
-                self._free.append(slot)
-                raise
-            bs = self.block_size
-            for j, bid in enumerate(chain):
-                chunk = jax.tree_util.tree_map(
-                    lambda l, j=j: _block_chunk(l, j, bs), cache_row)
-                self._cache = self._jit_write(self._cache, chunk,
-                                              np.int32(bid))
-            if tokens is not None:
-                self._paged.register_prefix(
-                    np.asarray(tokens)[:int(length)], slot)
-        else:
-            slot = self._free.pop()
-            self._cache = self._jit_admit(self._cache, cache_row,
-                                          np.int32(slot))
-        self._arm(slot, first_token, length)
-        return slot
 
     def seat_cached(self, full, covered, chain):
         """Seat one request whose leading ``covered`` positions are
         already RESIDENT in ``chain`` (a prefix-cache hit, paged layout
         only): take shared references on the physical blocks — no
-        prefill, no copy — arm the slot at ``pre = min(covered,
+        recompute, no copy — arm the slot at ``pre = min(covered,
         len(full) - 1)`` with ``full[pre]``, and return ``(slot,
         replay_feed)`` where replay_feed is the teacher-forced remainder
         ``full[pre+1:]`` (its re-derived emissions are swallowed by the
-        batcher, so the stream is bit-identical to a fresh prefill).
+        batcher, so the stream is bit-identical to a fresh ingestion).
         The slot's first write lands either in a fresh block (divergent
         suffix) or inside the last shared block — which ``prepare_step``
         then copy-on-write forks before the step touches it."""
@@ -906,15 +678,15 @@ class DecodeEngine:
         return slot, [int(t) for t in full[pre + 1:]]
 
     def seat_chunked(self, full):
-        """Seat one request for CHUNKED ingestion (prefill_chunk > 0):
-        arm a free slot at (``full[0]``, position 0) and return
-        ``(slot, feed)`` where ``feed = full[1:]`` is what the batcher
-        chunk-loads through the unified step (its re-derived emissions
-        swallowed until the last token is fed — whose step output IS the
-        first real emission).  No prefill ladder, no bulk admission
-        write: the slab layout touches no device state at all, and the
-        paged layout seats an EMPTY chain that ``prepare_step`` grows
-        block by block as the span advances."""
+        """Seat one request whose whole context must be ingested: arm a
+        free slot at (``full[0]``, position 0) and return ``(slot,
+        feed)`` where ``feed = full[1:]`` is what the batcher
+        chunk-loads through the step (its re-derived emissions swallowed
+        until the last token is fed — whose step output IS the first
+        real emission).  No device write at admission: the slab layout
+        touches no device state at all, and the paged layout seats an
+        EMPTY chain that ``prepare_step`` grows block by block as the
+        span advances."""
         if not self._free:
             raise RuntimeError(f"{self.name}: no free decode slot")
         full = np.asarray(full, np.int32)
@@ -935,11 +707,11 @@ class DecodeEngine:
     def load_chunk(self, slot, toks):
         """Arm lanes 1..n of ``slot`` for the NEXT step: after the
         slot's current token, feed ``toks`` (the next teacher-forced
-        prompt/replay tokens) in the same step.  Chunked mode only;
-        called by the batcher strictly BETWEEN steps — lane counts are
-        data, so loading never retraces."""
+        prompt/replay tokens) in the same step.  Called by the batcher
+        strictly BETWEEN steps — lane counts are data, so loading never
+        retraces."""
         n = len(toks)
-        if not self.prefill_chunk or n >= self.prefill_chunk:
+        if n >= self.prefill_chunk:
             raise RuntimeError(
                 f"{self.name}: load_chunk({n}) needs prefill_chunk > "
                 f"{n} (engine has {self.prefill_chunk})")
@@ -950,7 +722,7 @@ class DecodeEngine:
     def chunk_len(self, slot):
         """Lanes the next/current step feeds for ``slot`` (1 = plain
         decode)."""
-        return int(self._len[slot]) if self.prefill_chunk else 1
+        return int(self._len[slot])
 
     @property
     def speculating(self):
@@ -1046,8 +818,7 @@ class DecodeEngine:
 
     def register_context(self, slot, tokens):
         """Publish a fully-ingested context's prompt prefix into the
-        paged prefix index (chunked admission's twin of the ``admit``
-        registration; no-op on slab / with the cache off)."""
+        paged prefix index (no-op on slab / with the cache off)."""
         if self.kv_layout == "paged":
             self._paged.register_prefix(np.asarray(tokens, np.int32),
                                         slot)
@@ -1101,18 +872,15 @@ class DecodeEngine:
         wall cost of streaming ``covered`` spilled positions back over
         the host link vs re-running them through chunked prefill, at the
         chip spec matching this backend.  Returns ``(verdict,
-        restore_ms, recompute_ms)`` — the ``serving_kv_spill`` bench
-        gates both directions of this comparison."""
+        restore_ms, recompute_ms)``."""
         from paddle_tpu.perf import analytic, roofline
         chip = roofline.for_device_kind(jax.devices()[0].device_kind)
         layers, dkv = self._kv_dims
         restore = analytic.predicted_restore_ms(
             covered, layers, dkv, self.num_heads, self.kv_dtype, chip)
-        # the legacy ladder re-prefills in ONE dispatch — model it as a
-        # single whole-prefix chunk step
-        k = self.prefill_chunk if self.prefill_chunk else int(covered) + 1
         recompute = analytic.predicted_recompute_ms(
-            covered, self._param_count, self._param_bytes, k, chip)
+            covered, self._param_count, self._param_bytes,
+            self.prefill_chunk, chip)
         return restore < recompute, restore, recompute
 
     def _handoff_predicted_faster(self, covered):
@@ -1120,17 +888,15 @@ class DecodeEngine:
         wall cost of pulling ``covered`` positions' K/V from a peer
         replica over the network AND restoring them over the host link,
         vs re-running them through chunked prefill here.  Returns
-        ``(verdict, handoff_ms, recompute_ms)`` — the ``serving_disagg``
-        bench gates both directions of this comparison, exactly like
-        ``serving_kv_spill`` gates the local pair."""
+        ``(verdict, handoff_ms, recompute_ms)``."""
         from paddle_tpu.perf import analytic, roofline
         chip = roofline.for_device_kind(jax.devices()[0].device_kind)
         layers, dkv = self._kv_dims
         handoff = analytic.predicted_handoff_ms(
             covered, layers, dkv, self.num_heads, self.kv_dtype, chip)
-        k = self.prefill_chunk if self.prefill_chunk else int(covered) + 1
         recompute = analytic.predicted_recompute_ms(
-            covered, self._param_count, self._param_bytes, k, chip)
+            covered, self._param_count, self._param_bytes,
+            self.prefill_chunk, chip)
         return handoff < recompute, handoff, recompute
 
     def export_chain(self, tokens):
@@ -1215,8 +981,7 @@ class DecodeEngine:
             return None
         full = np.asarray(full, np.int32)
         key, covered, blob = tier.lookup(full, self.block_size)
-        if key is None \
-                or not self.cached_seat_worthwhile(covered, full.size):
+        if key is None:
             return None
         if key in self._pending_restores:
             return RestorePendingError(
@@ -1321,113 +1086,34 @@ class DecodeEngine:
         return landed
 
     def seat_prefilled(self, fulls):
-        """THE seat-prefix helper (one definition, four callers:
-        ``Supervisor.reprefill`` slot recovery, the batcher's
-        continuation-``replay`` leg, paged prefix-cache admission, and
-        pool-pressure re-seating).  For each 1-D ``full`` context array,
-        reconstruct a slot holding K/V for its prefix with the following
-        token armed, WITHOUT re-emitting anything:
+        """THE seat-prefix helper (one definition, four callers: fresh
+        admission, ``Supervisor.reprefill`` slot recovery, the batcher's
+        continuation-``replay`` leg, and pool-pressure re-seating).  For
+        each 1-D ``full`` context array, seat a slot that will hold K/V
+        for the context with the following token armed, WITHOUT
+        emitting anything on the way:
 
         1. paged + prefix cache: a resident chain seats by REFERENCE
-           (``seat_cached`` — zero prefill compute);
-        2. otherwise re-PREFILL the longest ladder-covered prefix
-           ``full[:min(len(full) - 1, ladder_top)]`` — same-bucket items
-           as ONE engine batch — and seat it (``admit``).
+           (``seat_cached`` — zero recompute for the covered positions;
+           any resident coverage strictly shrinks the feed);
+        2. a prefix spilled to the host tier defers behind its async
+           restore when the analytic model says that beats recompute;
+        3. otherwise ``seat_chunked``: the whole context is the feed.
 
         Either way the remainder returns as the teacher-forced
-        ``replay_feed`` the batcher drains through the shared step with
-        re-derived emissions swallowed; greedy decode being
-        deterministic, the slot ends byte-for-byte at its target state.
-        Returns a list aligned with ``fulls``: ``(slot, replay_feed)``
-        per seated item, or the exception that failed it
+        ``replay_feed`` the batcher drains K lanes per step through the
+        one step, with re-derived emissions swallowed; greedy decode
+        being deterministic, the slot ends byte-for-byte at its target
+        state.  Returns a list aligned with ``fulls``: ``(slot,
+        replay_feed)`` per seated item, or the exception that failed it
         (``InsufficientBlocksError`` means "defer and retry", not
-        "fail").
-
-        CHUNKED mode (prefill_chunk > 0) replaces leg 2 entirely: there
-        is no ladder, so the whole uncovered context returns as the
-        feed and the batcher drains it K lanes per step through the ONE
-        unified executable — supervisor recovery and continuation
-        replay ride chunks instead of one teacher-forced token per
-        step."""
-        if self.prefill_chunk:
-            return self._seat_prefilled_chunked(fulls)
-        top = self.prefill_buckets[-1]
-        results = [None] * len(fulls)
-        prep = []
-        for i, full in enumerate(fulls):
-            full = np.asarray(full, np.int32)
-            if self.kv_layout == "paged":
-                covered, chain = self._paged.lookup_prefix(full)
-                if covered and self.cached_seat_worthwhile(covered,
-                                                           full.size):
-                    try:
-                        results[i] = self.seat_cached(full, covered, chain)
-                    except Exception as e:    # noqa: BLE001 — isolate
-                        results[i] = e        # to this item
-                    continue
-                # resident miss: a spilled twin may be one host-link
-                # stream away — defer behind the async restore when the
-                # analytic model says that beats re-prefilling
-                pending = self._maybe_begin_restore(full)
-                if pending is not None:
-                    results[i] = pending
-                    continue
-            pre = min(full.size - 1, top)
-            if self.kv_layout == "paged" and not self.can_admit(pre + 1):
-                # pool-dry fast path: admit() below would raise this
-                # AFTER the prefill ran; gate here so every defer-and-
-                # retry cycle costs zero device work while the pool
-                # stays dry (admit stays the authoritative backstop)
-                results[i] = InsufficientBlocksError(
-                    f"pool cannot hold {pre + 1} positions yet")
-                continue
-            prep.append((i, full, pre))
-        groups = {}
-        for item in prep:
-            groups.setdefault(self.prefill_bucket_for(item[2]),
-                              []).append(item)
-        for bucket, items in sorted(groups.items()):
-            prompts = np.zeros((len(items), bucket), np.int32)
-            lengths = np.zeros((len(items),), np.int32)
-            for j, (_i, full, pre) in enumerate(items):
-                prompts[j, :pre] = full[:pre]
-                lengths[j] = pre
-            try:
-                # reconstruction prefill (recovery / continuation /
-                # pool re-seat): one standalone span per bucket batch
-                with obstrace.span("gen.prefill", root=False,
-                                   bucket=int(bucket), n=len(items)):
-                    _first, rows = self.prefill(prompts, lengths)
-            except Exception as e:      # noqa: BLE001 — crosses to the
-                for i, _full, _pre in items:    # caller per item
-                    results[i] = e
-                continue
-            for j, (i, full, pre) in enumerate(items):
-                try:
-                    # arm with the recorded stream's next token (inside
-                    # the prompt the model's own prediction is
-                    # irrelevant; past it, identical)
-                    slot = self.admit(np.int32(full[pre]), rows[j],
-                                      np.int32(pre), tokens=full[:pre])
-                except Exception as e:  # noqa: BLE001
-                    results[i] = e
-                    continue
-                results[i] = (slot, [int(t) for t in full[pre + 1:]])
-        return results
-
-    def _seat_prefilled_chunked(self, fulls):
-        """``seat_prefilled`` for the unified chunked engine: resident
-        prefixes still seat by REFERENCE (paged prefix cache); every
-        other context seats via ``seat_chunked`` with the WHOLE context
-        as the feed.  Same per-item isolation / defer-and-retry
-        contract."""
+        "fail")."""
         results = [None] * len(fulls)
         for i, full in enumerate(fulls):
             full = np.asarray(full, np.int32)
             if self.kv_layout == "paged":
                 covered, chain = self._paged.lookup_prefix(full)
-                if covered and self.cached_seat_worthwhile(covered,
-                                                           full.size):
+                if covered:
                     try:
                         results[i] = self.seat_cached(full, covered,
                                                       chain)
@@ -1457,19 +1143,6 @@ class DecodeEngine:
             except Exception as e:  # noqa: BLE001 — per-item isolation
                 results[i] = e
         return results
-
-    def cached_seat_worthwhile(self, covered, size):
-        """Seat through the prefix cache only when the resident coverage
-        saves at least half the ladder-covered prefill: the uncovered
-        remainder teacher-forces ONE DECODE STEP PER TOKEN, so a short
-        shared preamble on a long prompt would cost more steps (and
-        worse TTFT) than the single whole-prompt prefill it avoids —
-        route those as ordinary misses instead.  CHUNKED mode has no
-        ladder and the remainder rides K-lane chunks, so ANY resident
-        coverage strictly shrinks the feed: always worthwhile."""
-        if self.prefill_chunk:
-            return covered > 0
-        return covered * 2 >= min(int(size) - 1, self.prefill_buckets[-1])
 
     def prefix_lookup(self, prompt):
         """``(covered_positions, chain)`` of the longest cached block-
@@ -1507,11 +1180,11 @@ class DecodeEngine:
             if slot in free_set or slot in victims:
                 continue
             pos = int(self._pos[slot])
-            # chunked mode writes a SPAN this step (lane 0 .. lane
-            # _len-1): provision every touched block, in order, each
-            # CoW executed immediately so a mid-span exhaustion can
-            # never orphan a planned fork
-            n = int(self._len[slot]) if self.prefill_chunk else 1
+            # the slot writes a SPAN this step (lane 0 .. lane _len-1):
+            # provision every touched block, in order, each CoW executed
+            # immediately so a mid-span exhaustion can never orphan a
+            # planned fork
+            n = int(self._len[slot])
             for j in range(pos // bs, (pos + n - 1) // bs + 1):
                 p = pos if j == pos // bs else j * bs
                 while True:
@@ -1542,7 +1215,8 @@ class DecodeEngine:
 
     def evict(self, slot, reason):
         """Free a slot (between steps).  Slab: the cache row is left
-        as-is — the next admission overwrites it wholesale.  Paged: the
+        as-is — the next occupant overwrites each position in the step
+        that first unmasks it.  Paged: the
         slot's block references release (shared blocks stay resident for
         their other sharers / the prefix index)."""
         if self.kv_layout == "paged":
@@ -1553,11 +1227,12 @@ class DecodeEngine:
         self.metrics.evict_slot(reason)
 
     def step(self):
-        """Advance EVERY slot one position; returns the next token per
-        slot ([num_slots] np.int32).  Free slots compute too (fixed-shape
-        slab — that is the cost model) but their output is garbage the
-        caller ignores and their cache rows are overwritten at admission.
-        Callers then bump their active slots via ``advance``.
+        """Advance EVERY slot by its armed lanes; returns the next token
+        per slot ([num_slots] np.int32: the pick after each slot's last
+        fed lane).  Free slots compute too (fixed shape — that is the
+        cost model) but their output is garbage the caller ignores and
+        their cache rows are overwritten by the next occupant.  Callers
+        then bump their active slots via ``advance``.
 
         Epoch-guarded: inputs are snapshotted up front and the result is
         only committed if no ``reset()`` happened meanwhile — so a
@@ -1575,10 +1250,8 @@ class DecodeEngine:
             # the host arrays the call takes: snapshotted, so an eviction
             # racing the step changes nothing it reads
             tokens = self._tokens.copy()
-            host = [tokens, self._pos.copy()]
-            lens = self._len.copy() if self.prefill_chunk else None
-            if lens is not None:
-                host.append(lens)
+            lens = self._len.copy()
+            host = [tokens, self._pos.copy(), lens]
             if self.kv_layout == "paged":
                 # block tables ride as DATA (snapshotted, like
                 # tokens/pos): table churn between steps never retraces
@@ -1610,8 +1283,7 @@ class DecodeEngine:
             self._step_log.append((tokens, host[1], lens, self.step_aux))
         # teacher-forced lanes this step fed beyond the per-slot token
         # (the chunked-prefill occupancy surface)
-        chunk_lanes = int(lens.sum() - self.num_slots) if lens is not None \
-            else 0
+        chunk_lanes = int(lens.sum() - self.num_slots)
         kw = {}
         if self._draft is not None:
             # speculating step output is EVERY lane's argmax [S, K]:
@@ -1653,8 +1325,8 @@ class DecodeEngine:
     def advance(self, slot, token, consumed=1):
         """Record the token fed at the next step for ``slot``, advanced
         past the ``consumed`` lanes the last step processed (1 = plain
-        decode; a chunked step advances by its lane count — the
-        per-slot variable advance)."""
+        decode; a chunk advances by its lane count — the per-slot
+        variable advance)."""
         if self._draft is not None:
             # every committed token re-feeds the draft cache (matched
             # drafts rewrite identical K/V; a mismatch feeds the
@@ -1663,11 +1335,8 @@ class DecodeEngine:
             self._d_feed[slot].extend(
                 [int(t) for t in self._tokens[slot, 1:consumed]]
                 + [int(token)])
-        if self.prefill_chunk:
-            self._tokens[slot, 0] = token
-            self._len[slot] = 1
-        else:
-            self._tokens[slot] = token
+        self._tokens[slot, 0] = token
+        self._len[slot] = 1
         self._pos[slot] += consumed
         if self._draft is not None and self._paged is not None:
             # paged rollback (kv_pool.truncate): release blocks the
@@ -1678,7 +1347,7 @@ class DecodeEngine:
     def reset(self):
         """Drop all slot state and re-zero the cache slab (the batch-
         failure isolation path: a failed step must not leak a poisoned
-        slab into the next batch).  The compiled step/admit/prefill
+        slab into the next batch).  The compiled step and block-op
         executables stay jit-cached — a rebuild costs zero new traces —
         and the epoch bump orphans any still-running stale step."""
         with self._epoch_lock:
@@ -1687,7 +1356,7 @@ class DecodeEngine:
                 # fresh pool + allocator + (empty) prefix index: the
                 # blocks' contents are gone, so every cached chain is
                 # invalid — recovery re-seats through seat_prefilled,
-                # which misses and re-prefills.  REPLACE the state (a
+                # which misses and re-ingests.  REPLACE the state (a
                 # watchdog-abandoned stale step may still be reading the
                 # old tables array).
                 old = self._paged
@@ -1712,8 +1381,7 @@ class DecodeEngine:
                         kv_dtype=self.kv_dtype, num_heads=self.num_heads))
         self._tokens[:] = 0
         self._pos[:] = 0
-        if self.prefill_chunk:
-            self._len[:] = 1
+        self._len[:] = 1
         self._free = list(range(self.num_slots))[::-1]
         if self._draft is not None:
             # BOTH caches rebuild: recovery re-seats every stream and
@@ -1728,20 +1396,12 @@ class DecodeEngine:
     # ------------------------------------------------------------ warm-up
 
     def warmup(self):
-        """Compile + execute the slab step, the admission write, and every
-        prefill ladder engine before traffic, asserting the trace
+        """Compile + execute the step (and, paged, the block fork and
+        the restore write) before traffic, asserting the trace
         discipline: the step's Python body traces exactly ONCE here and
         never again in steady state (admission/eviction are host-side, so
         churn cannot retrace by construction — the churn test pins it).
-        Idempotent: a second call only warms prefill buckets added since."""
-        if not self.prefill_chunk:
-            # the legacy ladder: one engine per prompt-length bucket.
-            # The chunked engine has NO prefill plane to warm — the one
-            # step below is the entire serving hot path.
-            for b in self.prefill_buckets:
-                self._prefill_engine(b).warmup()
-            if not self._warm:
-                self._log_prefill_paths()
+        Idempotent."""
         if self._warm:
             return
         # resolve the kernel path NOW — warm-up is the step's one trace,
@@ -1758,7 +1418,7 @@ class DecodeEngine:
             # kernel that covers 8 KV heads may not cover the 4-head shard
             # — the resolved path below is what the compiled step actually
             # took, and a reference path always carries its sentence
-            call = dict(paged=paged, chunk=self._kk or 1,
+            call = dict(paged=paged, chunk=self._kk,
                         quant=self.kv_dtype == "int8",
                         shards=self.mesh_shards)
             self.decode_decline_reason = _dk.decline_reason(
@@ -1795,102 +1455,54 @@ class DecodeEngine:
         if self._draft is not None:
             # the draft rollout is its own ONE warm-up trace
             self._draft.warmup()
-        if self.prefill_chunk:
-            if self.kv_layout == "paged":
-                if self._host_tier is not None:
-                    # host-tier restores land through the block write;
-                    # warm it HERE so the first restore commits with
-                    # zero new compiles (chunked ingestion itself never
-                    # uses it — prompt writes ride the step)
-                    chunk = jax.tree_util.tree_map(
-                        lambda l: np.zeros(l.shape[1:], l.dtype),
-                        self._cache)
-                    with expect_traces(lambda: self._write_traces[0], 1,
-                                       f"decode[{self.name}]: "
-                                       "block-write warm-up"):
-                        self._cache = self._jit_write(self._cache, chunk,
-                                                      np.int32(0))
-                # the CoW fork is the only other device op the chunked
-                # paged engine uses (block writes ride the step itself)
-                with expect_traces(lambda: self._copy_traces[0], 1,
-                                   f"decode[{self.name}]: block-fork "
-                                   "warm-up"):
-                    self._cache = self._jit_copy(self._cache, np.int32(0),
-                                                 np.int32(0))
-                with expect_traces(
-                        lambda: self.step_trace_count, 1,
-                        f"decode[{self.name}]: chunked paged step "
-                        "warm-up",
-                        hint="the chunked step is not shape-stable"):
-                    nxt, self._cache = self._jit_step(
-                        self.params, self._cache, self._tokens,
-                        self._pos, self._len, self._paged.tables.copy())
-                    jax.block_until_ready(nxt)
-            else:
-                with expect_traces(
-                        lambda: self.step_trace_count, 1,
-                        f"decode[{self.name}]: chunked slab step "
-                        "warm-up",
-                        hint="the chunked step is not shape-stable"):
-                    nxt, self._cache = self._jit_step(
-                        self.params, self._cache, self._tokens,
-                        self._pos, self._len)
-                    jax.block_until_ready(nxt)
-            self._warm = True
-            logger.info(
-                "decode[%s]: warm (%d slots, max_len %d, kv %s/%s, decode "
-                "kernels %s, chunked prefill K=%d budget=%s, "
-                "speculate_k=%d, mesh_shards=%d)", self.name,
-                self.num_slots, self.max_len, self.kv_layout,
-                self.kv_dtype, self._kernel_path(),
-                self.prefill_chunk, self.prefill_chunk_budget or "inf",
-                self.speculate_k, self.mesh_shards)
-            return
         if self.kv_layout == "paged":
-            # ONE block-write shape and ONE fork shape serve every
-            # bucket/admission/CoW — both warmed (and executed) against
-            # the scratch block, whose contents are never attended
-            chunk = jax.tree_util.tree_map(
-                lambda l: np.zeros(l.shape[1:], l.dtype), self._cache)
-            with expect_traces(lambda: self._write_traces[0], 1,
-                               f"decode[{self.name}]: block-write "
-                               "warm-up"):
-                self._cache = self._jit_write(self._cache, chunk,
-                                              np.int32(0))
+            if self._host_tier is not None:
+                # host-tier restores land through the block write;
+                # warm it HERE so the first restore commits with zero
+                # new compiles (ingestion itself never uses it — prompt
+                # writes ride the step)
+                chunk = jax.tree_util.tree_map(
+                    lambda l: np.zeros(l.shape[1:], l.dtype),
+                    self._cache)
+                with expect_traces(lambda: self._write_traces[0], 1,
+                                   f"decode[{self.name}]: "
+                                   "block-write warm-up"):
+                    self._cache = self._jit_write(self._cache, chunk,
+                                                  np.int32(0))
+            # the CoW fork is the only other device op the paged engine
+            # uses; warmed (and executed) against the scratch block,
+            # whose contents are never attended
             with expect_traces(lambda: self._copy_traces[0], 1,
                                f"decode[{self.name}]: block-fork "
                                "warm-up"):
                 self._cache = self._jit_copy(self._cache, np.int32(0),
                                              np.int32(0))
-            with expect_traces(lambda: self.step_trace_count, 1,
-                               f"decode[{self.name}]: paged step warm-up",
-                               hint="the decode step is not shape-stable"):
+            with expect_traces(
+                    lambda: self.step_trace_count, 1,
+                    f"decode[{self.name}]: paged step warm-up",
+                    hint="the decode step is not shape-stable"):
                 nxt, self._cache = self._jit_step(
-                    self.params, self._cache, self._tokens, self._pos,
-                    self._paged.tables.copy())
+                    self.params, self._cache, self._tokens,
+                    self._pos, self._len, self._paged.tables.copy())
                 jax.block_until_ready(nxt)
         else:
-            for b in self.prefill_buckets:
-                zero_row = jax.tree_util.tree_map(
-                    lambda l: np.zeros((b,) + l.shape[2:], l.dtype),
-                    self._cache)
-                with expect_traces(lambda: self._admit_traces[0], 1,
-                                   f"decode[{self.name}]: bucket-{b} "
-                                   "admission warm-up"):
-                    self._cache = self._jit_admit(self._cache, zero_row,
-                                                  np.int32(0))
-            with expect_traces(lambda: self.step_trace_count, 1,
-                               f"decode[{self.name}]: slab step warm-up",
-                               hint="the decode step is not shape-stable"):
+            with expect_traces(
+                    lambda: self.step_trace_count, 1,
+                    f"decode[{self.name}]: slab step warm-up",
+                    hint="the decode step is not shape-stable"):
                 nxt, self._cache = self._jit_step(
-                    self.params, self._cache, self._tokens, self._pos)
+                    self.params, self._cache, self._tokens,
+                    self._pos, self._len)
                 jax.block_until_ready(nxt)
         self._warm = True
-        logger.info("decode[%s]: warm (%d slots, max_len %d, kv %s/%s, "
-                    "decode kernels %s, prefill buckets %s)", self.name,
-                    self.num_slots, self.max_len, self.kv_layout,
-                    self.kv_dtype, self._kernel_path(),
-                    list(self.prefill_buckets))
+        logger.info(
+            "decode[%s]: warm (%d slots, max_len %d, kv %s/%s, decode "
+            "kernels %s, chunked prefill K=%d budget=%s, "
+            "speculate_k=%d, mesh_shards=%d)", self.name,
+            self.num_slots, self.max_len, self.kv_layout,
+            self.kv_dtype, self._kernel_path(),
+            self.prefill_chunk, self.prefill_chunk_budget or "inf",
+            self.speculate_k, self.mesh_shards)
 
     def _kernel_path(self):
         """The warm line's account of the path the compiled step took."""
@@ -1901,63 +1513,26 @@ class DecodeEngine:
         return "xla-ref (%s)" % (self.kda_decline_reason
                                  or self.decode_decline_reason)
 
-    def _log_prefill_paths(self):
-        """Once, at warm-up: which attention path each legacy prefill
-        bucket's compiled pass resolved to and why — a warning when the
-        kernel routing was on and a shape guard still declined (that
-        reference path costs the [Tp, Tp] score matrix)."""
-        import importlib
-        flash = importlib.import_module(
-            "paddle_tpu.ops.pallas.flash_attention")
-        enc = self.params.get("enc") or []
-        if not enc:
-            return
-        d = int(_w_shape(self.params["src_emb"])[1])
-        dkv = int(_w_shape(enc[0]["attn"]["wk"])[1])
-        quant = self.kv_dtype == "int8"
-        asked = (flash.prefill_quant_enabled() if quant
-                 else flash.prefill_flash_enabled())
-        for b in self.prefill_buckets:
-            if quant:
-                why = (flash.prefill_quant_decline_reason(
-                    b, b, d, dkv, self.num_heads) if asked
-                    else "pallas_prefill_quant routing is off")
-            else:
-                why = flash.prefill_decline_reason(b, d // self.num_heads)
-            log = logger.warning if why and asked else logger.info
-            log("decode[%s]: prefill bucket %d -> %s", self.name, b,
-                "flash-pallas" if why is None else f"xla-ref ({why})")
-
     def lower(self, what="step"):
-        """``jax.stages.Lowered`` of the slab decode step (default) or of
-        one prefill bucket (``what=<bucket int>``) — the ``extras
-        ["lower"]`` analytic hook (perf/analytic.py).  ``what="draft"``
-        lowers the attached draft trunk's rollout instead.  Offline
-        tool: it re-stages the function (one extra trace), like
+        """``jax.stages.Lowered`` of the decode step (the structure
+        gates of perf/analytic.py read its HLO); ``what="draft"`` lowers
+        the attached draft trunk's rollout instead.  Offline tool: it
+        re-stages the function (one extra trace), like
         ``InferenceEngine.lower``."""
         if what == "draft":
             if self._draft is None:
                 raise ConfigError(
                     f"{self.name}: no draft trunk (speculate_k=0)")
             return self._draft.lower()
-        if what == "step":
-            if self.prefill_chunk and self.kv_layout == "paged":
-                return self._jit_step.lower(self.params, self._cache,
-                                            self._tokens, self._pos,
-                                            self._len,
-                                            self._paged.tables)
-            if self.prefill_chunk:
-                return self._jit_step.lower(self.params, self._cache,
-                                            self._tokens, self._pos,
-                                            self._len)
-            if self.kv_layout == "paged":
-                return self._jit_step.lower(self.params, self._cache,
-                                            self._tokens, self._pos,
-                                            self._paged.tables)
+        if what != "step":
+            raise ConfigError(
+                f"{self.name}: lower({what!r}) (takes 'step' | 'draft')")
+        if self.kv_layout == "paged":
             return self._jit_step.lower(self.params, self._cache,
-                                        self._tokens, self._pos)
-        return self._prefill_engine(int(what)).lower(
-            self._prefill_batch_buckets[-1])
+                                        self._tokens, self._pos,
+                                        self._len, self._paged.tables)
+        return self._jit_step.lower(self.params, self._cache,
+                                    self._tokens, self._pos, self._len)
 
     # ------------------------------------------------------------ validate
 
@@ -1993,15 +1568,9 @@ class DecodeEngine:
         return max_tokens
 
     def validate_request(self, prompt, max_tokens):
-        """Admission-control checks, raised BEFORE the queue.  The
-        chunked engine has no ladder, so only ``max_len`` caps the
-        prompt (chunks bound per-STEP work instead)."""
+        """Admission-control checks, raised BEFORE the queue.  Only
+        ``max_len`` caps the prompt (chunks bound per-STEP work)."""
         prompt = self._validate_ids("prompt", prompt)
-        if not self.prefill_chunk \
-                and prompt.size > self.prefill_buckets[-1]:
-            raise InvalidRequestError(
-                f"prompt length {prompt.size} exceeds the prefill ladder "
-                f"top {self.prefill_buckets[-1]}")
         max_tokens = self._parse_max_tokens(max_tokens)
         if prompt.size + max_tokens > self.max_len:
             raise InvalidRequestError(
@@ -2028,12 +1597,10 @@ class DecodeEngine:
         tokens were already delivered to the caller by a previous serving
         of this stream (a router failing over off a dead replica —
         docs/serving.md §7) and must be teacher-forced, never re-emitted.
-        Unlike a fresh prompt, the combined context may exceed the
-        prefill ladder top — seating re-prefills the longest
-        ladder-covered prefix and replays the remainder through the slab
-        step (the exact ``Supervisor.reprefill`` contract), so only the
-        slab length bounds it: ``len(prompt) + len(replay) + max_tokens
-        <= max_len``."""
+        Seating feeds the whole combined context through the step (the
+        exact ``Supervisor.reprefill`` contract), so like a fresh prompt
+        only the slot length bounds it: ``len(prompt) + len(replay) +
+        max_tokens <= max_len``."""
         prompt = self._validate_ids("prompt", prompt)
         replay = self._validate_ids("replay", replay)
         max_tokens = self._parse_max_tokens(max_tokens)
@@ -2071,8 +1638,9 @@ class _GenRequest:
         self.started = False      # future marked running (a request can
         #                           re-enter admission — pool-deferred —
         #                           but the transition fires once)
-        self.replay_feed = []     # recovery replay: recorded tokens still
-        #                           to teacher-force through the slab step
+        self.replay_feed = []     # context (prompt, continuation, recovery
+        #                           replay) still to teacher-force through
+        #                           the step
         self.replay_ctx = replay_ctx   # continuation context: tokens a
         #                                previous serving of this stream
         #                                already delivered (never re-emitted)
@@ -2134,30 +1702,20 @@ class GenerationBatcher:
     twin of ``Batcher``: bounded queue, futures, deadlines, drain; plus
     streaming (per-token callbacks) and slot scheduling.
 
-    ONE worker thread runs the loop: admit queued requests into free
-    slots (prefilling same-bucket prompts together through the ladder),
-    run one slab step, deliver each active slot's token, evict finished
-    slots.  Admission happens strictly BETWEEN steps, so the compiled
-    step never sees a shape change.
-
-    admission="continuous" (the point of this module) refills freed slots
-    from the queue between ANY two steps.  admission="gang" only admits
-    into an EMPTY slab and runs that gang to completion — the sequential
-    whole-batch policy ``lm_generate`` imposes (finished rows burn steps
-    until the slowest row is done; arrivals wait for the drain).  Same
-    compiled step, same prefill ladder, so ``bench.py serving_generate``'s
-    continuous-vs-sequential comparison isolates exactly the scheduling
-    policy.
+    ONE worker thread runs the loop: seat queued requests into free
+    slots, load each feeding slot's next chunk, run one step, deliver
+    each active slot's token, evict finished slots.  Freed slots refill
+    from the queue between ANY two steps; admission happens strictly
+    BETWEEN steps, so the compiled step never sees a shape change.
     """
 
     def __init__(self, engine, queue_size=256, default_deadline_ms=None,
-                 default_max_tokens=64, admission="continuous", name=None,
-                 supervisor=None):
+                 default_max_tokens=64, name=None, supervisor=None):
         self.engine = engine
         self.metrics = engine.metrics
         # resilience.Supervisor (None = PR-5 semantics: a step failure
         # fails the in-flight batch).  With one attached: step failures
-        # and watchdog trips REBUILD the slab and re-prefill every
+        # and watchdog trips REBUILD the cache and re-seat every
         # in-flight request (streams continue bit-identically), and the
         # circuit breaker sheds admissions after repeated failures.
         self.supervisor = supervisor
@@ -2166,10 +1724,6 @@ class GenerationBatcher:
         self.default_max_tokens = int(default_max_tokens)
         if int(queue_size) < 1:
             raise ValueError("queue_size must be >= 1")
-        if admission not in ("continuous", "gang"):
-            raise ValueError(f"admission={admission!r} (supported: "
-                             "'continuous', 'gang')")
-        self._gang = admission == "gang"
         self._q = queue.Queue(maxsize=int(queue_size))
         # cross-replica KV exports (serving/transfer.py): HTTP handlers
         # queue (tokens, result_box, done_event) here and the worker
@@ -2182,8 +1736,8 @@ class GenerationBatcher:
         self._drain = True
         self._admit_lock = threading.Lock()
         self._by_slot = {}          # slot -> _GenRequest
-        self._abandoned = set()     # futures flagged mid-prefill (before
-        #                             their request reached a slot)
+        self._abandoned = set()     # futures flagged in the seating window
+        #                             (before their request reached a slot)
         # paged-layout overflow lanes (both worker-thread-only):
         # _waiting: popped requests the pool cannot seat yet (retried
         # ahead of the queue); _preempted: requests whose slot was
@@ -2205,7 +1759,7 @@ class GenerationBatcher:
         ``{"tokens": [ids...], "finish_reason": "eos"|"length",
         "ttft_ms": float}``.
 
-        prompt: 1-D int token ids (<= the prefill ladder top);
+        prompt: 1-D int token ids;
         max_tokens: emission cap (default: the batcher default), with
         ``len(prompt) + max_tokens <= engine.max_len``; eos_id: stop
         token override (None = the engine default); on_token: optional
@@ -2214,15 +1768,13 @@ class GenerationBatcher:
 
         replay: mid-stream CONTINUATION — tokens a previous serving of
         this stream already delivered (a router failing over off a dead
-        replica, docs/serving.md §7).  Seating re-prefills the longest
-        ladder-covered prefix of ``prompt + replay`` and teacher-forces
-        the remainder through the slab step with re-derived emissions
-        swallowed (``Supervisor.reprefill`` semantics), so the result's
-        ``tokens`` are ONLY the new emissions and — greedy decode being
+        replica, docs/serving.md §7).  Seating teacher-forces ``prompt +
+        replay`` through the step with re-derived emissions swallowed
+        (``Supervisor.reprefill`` semantics), so the result's ``tokens``
+        are ONLY the new emissions and — greedy decode being
         deterministic — the concatenated stream is bit-identical to the
         uninterrupted one.  ``max_tokens`` counts new emissions;
-        ``len(prompt) + len(replay) + max_tokens <= engine.max_len``
-        (the ladder top does NOT cap the combined context).
+        ``len(prompt) + len(replay) + max_tokens <= engine.max_len``.
 
         Raises synchronously: ``InvalidRequestError``,
         ``OverloadedError`` (queue full), ``ShutdownError`` (draining),
@@ -2346,7 +1898,7 @@ class GenerationBatcher:
             if req.future is future:
                 req.abandoned = True
                 return
-        # running but not slotted: it is inside the prefill window —
+        # running but not slotted: it is inside the seating window —
         # admission checks this set before seating it
         self._abandoned.add(future)
 
@@ -2374,15 +1926,14 @@ class GenerationBatcher:
 
     def _resolve(self, req, reason):
         """Resolve a finished request's future — the ONE place the
-        response shape is built (slotted finishes and prefill-time
-        finishes — max_tokens==1 / immediate eos / abandoned — all land
-        here)."""
+        response shape is built (slotted finishes and requests
+        abandoned before they reached a slot all land here)."""
         self._abandoned.discard(req.future)     # a late abandon() of a
         #                                         finished future is inert
         ttft = (req.t_first - req.t_submit) if req.t_first else 0.0
         # the slot-lifetime span ends with the request, carrying the
         # eviction reason next to TTFT (NULL no-op for requests that
-        # finished at prefill and never held a slot)
+        # never held a slot)
         req.slot_span.end(reason=reason, tokens=len(req.tokens),
                           ttft_ms=round(ttft * 1e3, 3))
         self.metrics.observe_response(time.perf_counter() - req.t_submit)
@@ -2396,28 +1947,25 @@ class GenerationBatcher:
             pass
 
     def _flag_abandoned(self, req):
-        """Fold a mid-prefill ``abandon()`` into the request's flag."""
+        """Fold a seating-window ``abandon()`` into the request's
+        flag."""
         if req.future in self._abandoned:
             self._abandoned.discard(req.future)
             req.abandoned = True
         return req.abandoned
 
     def _admit_from_queue(self, block):
-        """Fill free slots from the queue; same-length-bucket prompts
-        prefill as ONE engine batch.  Runs strictly between steps.
+        """Fill free slots from the queue.  Runs strictly between steps.
 
-        Fresh prompts prefill WHOLE and their first emission is
-        delivered at admission.  Everything that must be RECONSTRUCTED
-        instead — continuations (``replay_ctx``), fresh prompts whose
-        prefix is resident in the paged prefix cache, and pool-preempted
-        requests — seats through ``engine.seat_prefilled`` (the one
-        seat-prefix helper, shared with ``Supervisor.reprefill``):
-        teacher-forced remainder, re-derived emissions swallowed, so
-        every stream is bit-identical to an uninterrupted one.  On the
-        paged layout, requests the pool cannot hold yet are DEFERRED
-        (``_waiting`` / ``_preempted``), never failed."""
-        if self._gang and self._by_slot:
-            return          # whole-batch policy: drain before refilling
+        Every picked request — fresh prompt, continuation
+        (``replay_ctx``), prefix-cache hit — seats through
+        ``engine.seat_prefilled`` (the one seat-prefix helper, shared
+        with ``Supervisor.reprefill``) and its context drains through
+        the step as K-lane chunks: teacher-forced, re-derived emissions
+        swallowed, first emission at the last chunk, so every stream is
+        bit-identical to an uninterrupted one.  On the paged layout,
+        requests the pool cannot hold yet are DEFERRED (``_waiting`` /
+        ``_preempted``), never failed."""
         self._reseat_preempted()
         block = block and not self._preempted
         picked = []
@@ -2444,16 +1992,12 @@ class GenerationBatcher:
             covered = 0
             if kv_budget is not None and req.replay_ctx is None:
                 covered = self.engine.prefix_lookup(req.prompt)[0]
-                if not self.engine.cached_seat_worthwhile(
-                        covered, req.prompt.size):
-                    covered = 0    # short preamble: route (and budget)
-                    #                it as an ordinary whole-prompt miss
                 if not covered:
                     # paged fresh miss: it will claim private blocks for
                     # its whole prompt — defer it while the pool (free
                     # blocks minus what this admission round already
-                    # earmarked) cannot hold them, instead of prefilling
-                    # just to fail
+                    # earmarked) cannot hold them, instead of seating it
+                    # just to preempt it
                     need = self.engine._paged.blocks_for(
                         req.prompt.size + 1)
                     if need > kv_budget[0] \
@@ -2462,113 +2006,20 @@ class GenerationBatcher:
                         stashed.append(req)
                         continue
                     kv_budget[0] -= need
-            req.admit_covered = covered
-            picked.append(req)
-        self._waiting.extend(stashed)
-        if not picked:
-            return
-        # route: fresh misses prefill whole (emit at admission); fresh
-        # prefix-cache hits and continuations reconstruct via
-        # seat_prefilled (nothing re-emitted).  The CHUNKED engine has
-        # no prefill plane at all: EVERY request seats through
-        # seat_prefilled and its context drains through the unified
-        # step as K-lane chunks (first emission at the last chunk).
-        fresh, recon = [], []
-        for req in picked:
-            if self.engine.chunked:
-                if self.engine.kv_layout == "paged" \
-                        and req.replay_ctx is None \
-                        and not req.prefix_counted:
-                    req.prefix_counted = True
-                    self.metrics.observe_prefix_cache(
-                        hit=req.admit_covered > 0)
-                recon.append(req)
-                continue
-            if req.replay_ctx is not None:
-                recon.append(req)
-                continue
-            if self.engine.kv_layout == "paged":
-                # the budget-gate loop above already did this request's
-                # prefix lookup this pass; seat_prefilled re-looks-up at
-                # seating time (the pool may shift as items seat), so
-                # that one stays the authoritative reference-taker
-                covered = req.admit_covered
                 if not req.prefix_counted:
                     req.prefix_counted = True
                     self.metrics.observe_prefix_cache(hit=covered > 0)
-                if covered:
-                    recon.append(req)
-                    continue
-            fresh.append(req)
-        self._seat_reconstructed(recon)
-        groups = {}
-        for req in fresh:
-            b = self.engine.prefill_bucket_for(req.prompt.size)
-            groups.setdefault(b, []).append(req)
-        for bucket, reqs in sorted(groups.items()):
-            prompts = np.zeros((len(reqs), bucket), np.int32)
-            lengths = np.zeros((len(reqs),), np.int32)
-            for i, req in enumerate(reqs):
-                prompts[i, :req.prompt.size] = req.prompt
-                lengths[i] = req.prompt.size
-            try:
-                # one span per admission prefill batch, parented to the
-                # FIRST rider's trace (a batch serves several requests;
-                # co-riders see the bucket on their slot span instead)
-                with obstrace.span("gen.prefill", ctx=reqs[0].trace_ctx,
-                                   root=False, bucket=int(bucket),
-                                   n=len(reqs)):
-                    first, rows = self.engine.prefill(prompts, lengths)
-            except Exception as e:    # noqa: BLE001 — isolate to THIS group
-                logger.warning("%s: prefill of %d failed: %s: %s",
-                               self.name, len(reqs), type(e).__name__, e)
-                self.metrics.observe_error(len(reqs))
-                for req in reqs:
-                    req.fail(BatchExecutionError(
-                        f"prefill failed: {type(e).__name__}: {e}"))
-                continue
-            for i, req in enumerate(reqs):
-                self._flag_abandoned(req)
-                req.emit(first[i], self.name)
-                self.metrics.observe_ttft(req.t_first - req.t_submit)
-                self.metrics.observe_gen_tokens(1)
-                if req.abandoned:
-                    self._resolve(req, "abandoned")     # never seated, so
-                    #                                     no slot eviction
-                elif req.eos_id is not None \
-                        and int(first[i]) == req.eos_id:
-                    self._resolve(req, "eos")
-                elif req.max_tokens == 1:
-                    self._resolve(req, "length")
-                else:
-                    try:
-                        req.slot = self.engine.admit(first[i], rows[i],
-                                                     lengths[i],
-                                                     tokens=req.prompt)
-                    except InsufficientBlocksError:
-                        # the pool budget raced CoW growth: the token is
-                        # already delivered, so the request continues as
-                        # a preemption (re-seat + teacher-forced replay)
-                        self._preempted.append(req)
-                        continue
-                    except Exception as e:    # noqa: BLE001 — the slot
-                        # write is a device op like step/prefill; a
-                        # failure may have consumed the donated cache, so
-                        # fail everything in flight (incl. this group's
-                        # rest) and reset; later groups get fresh state
-                        self._fail_all_inflight(
-                            e, extra=[req] + reqs[i + 1:])
-                        break
-                    self._by_slot[req.slot] = req
-                    req.slot_span = obstrace.start_span(
-                        "slot", ctx=req.trace_ctx, root=False,
-                        slot=int(req.slot), mode="prefill",
-                        bucket=int(bucket))
+            # the lookup above is this pass's routing; seat_prefilled
+            # re-looks-up at seating time (the pool may shift as items
+            # seat), so that one stays the authoritative reference-taker
+            req.admit_covered = covered
+            picked.append(req)
+        self._waiting.extend(stashed)
+        self._seat_reconstructed(picked)
 
     def _seat_reconstructed(self, reqs):
-        """Seat requests whose context must be rebuilt without
-        re-emitting (continuations + paged prefix-cache hits) through
-        ``engine.seat_prefilled``; pool-dry items defer to ``_waiting``."""
+        """Seat picked requests through ``engine.seat_prefilled``;
+        pool-dry items defer to ``_waiting``."""
         live = []
         for req in reqs:
             if self._flag_abandoned(req):
@@ -2592,21 +2043,18 @@ class GenerationBatcher:
                 self._by_slot[req.slot] = req
                 if req.replay_ctx is not None:
                     mode = "continuation"
-                elif not self.engine.chunked or req.admit_covered:
+                elif req.admit_covered:
                     mode = "prefix_hit"
                 else:
-                    mode = "prefill"        # fresh chunked admission
+                    mode = "prefill"        # fresh admission
                 req.slot_span = obstrace.start_span(
                     "slot", ctx=req.trace_ctx, root=False,
                     slot=int(req.slot), mode=mode,
-                    chunked=self.engine.chunked,
                     teacher_forced=len(req.replay_feed))
         if hard is not None:
-            # the failed seat was a device op (prefill / admit /
-            # seat_cached) that may have consumed the donated cache —
-            # fail everything in flight and reset, exactly like the
-            # fresh-admission path, instead of stepping a possibly-
-            # deleted buffer
+            # a seat that failed for anything but pool space left the
+            # engine's slot state in doubt — fail everything in flight
+            # and reset instead of stepping it
             self._fail_all_inflight(hard)
 
     def _reseat_preempted(self):
@@ -2661,7 +2109,7 @@ class GenerationBatcher:
             self._fail_all_inflight(hard)
 
     def _load_chunks(self):
-        """Chunked mode, strictly between steps: arm each feeding slot's
+        """Strictly between steps: arm each feeding slot's
         next up-to-(K-1)-token chunk (prompt ingestion, continuation
         replay, recovery replay — one mechanism), bounded by the
         engine's per-step chunk budget.  Lane counts are DATA: mixing
@@ -2745,13 +2193,12 @@ class GenerationBatcher:
 
     def _recover_inflight(self, e):
         """The supervised step failed (error or watchdog trip): rebuild
-        the slab from the AOT cache (``reset()`` — the compiled step is
-        jit-cached, so the rebuild costs ZERO new traces) and re-prefill
-        every in-flight request from prompt + tokens-generated-so-far,
-        continuing each greedy stream bit-identically
-        (``Supervisor.reprefill``).  A request whose replay outgrew the
-        prefill ladder or whose recovery budget ran out fails with the
-        cause; everything else keeps streaming."""
+        the cache (``reset()`` — the compiled step is jit-cached, so the
+        rebuild costs ZERO new traces) and re-seat every in-flight
+        request from prompt + tokens-generated-so-far, continuing each
+        greedy stream bit-identically (``Supervisor.reprefill``).  A
+        request whose recovery budget ran out fails with the cause;
+        everything else keeps streaming."""
         sup = self.supervisor
         victims = list(self._by_slot.values())
         self._by_slot.clear()
@@ -2789,8 +2236,8 @@ class GenerationBatcher:
         if not recoverable:
             recover_sp.end(recovered=0)
             return
-        # same-bucket victims re-prefill as ONE engine batch; each
-        # result is (slot, replay_feed) or the exception for that victim
+        # each result is (slot, replay_feed) or the exception for that
+        # victim
         try:
             outcomes = sup.reprefill(self.engine,
                                      [(req.context, req.tokens)
@@ -2919,11 +2366,9 @@ class GenerationBatcher:
         if not self._by_slot:
             return                  # a failed admission cleared the slots
         with obstrace.phase("gen.loop.prepare", step=step) as ph:
-            lanes = 0
-            if self.engine.chunked:
-                lanes = self._load_chunks()
-                if self.engine.speculating:
-                    self._load_spec()
+            lanes = self._load_chunks()
+            if self.engine.speculating:
+                self._load_spec()
             try:
                 # paged layout: provision every active slot's write block
                 # (chain growth + copy-on-write forks) strictly BETWEEN
@@ -2978,7 +2423,7 @@ class GenerationBatcher:
                 self._finish(req, "abandoned")
                 continue
             # lanes this step processed for the slot (1 = plain
-            # decode; >1 = a prefill/replay chunk, chunked mode)
+            # decode; >1 = a prefill/replay chunk)
             consumed = self.engine.chunk_len(slot)
             if req.replay_feed:
                 if len(req.replay_feed) >= consumed:
@@ -3005,16 +2450,12 @@ class GenerationBatcher:
             first_emit = req.t_first is None
             req.emit(tok, self.name)
             if first_emit:
-                # chunked admissions and continuations reach their
-                # first token HERE (the fresh-prompt ladder path
-                # records it at prefill instead)
                 req.slot_span.event("first_token")
                 self.metrics.observe_ttft(req.t_first - req.t_submit)
-                if self.engine.chunked and req.replay_ctx is None:
+                if req.replay_ctx is None:
                     # the prompt's K/V is fully resident exactly
                     # now: publish it to the paged prefix index
-                    # (no-op on slab), the chunked twin of the
-                    # ladder path's admit-time registration
+                    # (no-op on slab)
                     self.engine.register_context(slot, req.prompt)
             self.metrics.observe_gen_tokens(1)
             if req.eos_id is not None and tok == req.eos_id:

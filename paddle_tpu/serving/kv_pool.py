@@ -35,7 +35,7 @@ This module is the host half of the paged answer (docs/serving.md §5):
   referenced.
 
 Everything here is host-side numpy/bookkeeping between steps; the one
-jitted step (``transformer.lm_decode_step_paged``) only ever sees
+jitted step (``transformer.lm_decode_chunk_paged``) only ever sees
 fixed-shape pools and tables.  ``check()`` verifies the refcount ledger
 (no leak, no double-free) — the chaos tests run it after every fault
 matrix pass.
@@ -664,8 +664,8 @@ class PagedKVState:
 
     def seat_fresh(self, slot, n_positions):
         """Claim private blocks covering ``[0, n_positions)`` for a
-        just-prefilled admission; returns the chain (the engine writes
-        the prefill rows into them).  All-or-nothing: on exhaustion
+        fresh admission (0: an empty chain ``write_plan`` grows as the
+        span advances); returns the chain.  All-or-nothing: on exhaustion
         nothing is claimed and ``InsufficientBlocksError`` raises (the
         batcher defers the request)."""
         need = self.blocks_for(n_positions)

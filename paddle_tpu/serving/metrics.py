@@ -11,8 +11,8 @@ timer for the per-batch engine time so ``print_all_stats()`` shows serving
 next to training).
 
 ``render_prometheus()`` is the text format served at ``/metrics``;
-``snapshot()`` is the same data as a dict (the bench family and the smoke
-JSON consume it).
+``snapshot()`` is the same data as a dict (the benchmark's drivers and
+the smoke JSON consume it).
 """
 
 import threading
@@ -420,7 +420,7 @@ class ServingMetrics:
         return total
 
     def snapshot(self):
-        """All metrics as one dict (bench family / smoke JSON surface)."""
+        """All metrics as one dict (benchmark / smoke JSON surface)."""
         lat = self.latency.percentiles(_QUANTILES)
         bt = self.batch_time.percentiles(_QUANTILES)
         ttft = self.ttft.percentiles(_QUANTILES)

@@ -63,7 +63,6 @@ CLI (``python -m paddle_tpu.serving.router``):
                                    generate, kill -9 one mid-stream,
                                    assert bit-identical completion +
                                    /metrics evidence; ONE JSON line
-                                   (healthy_window.sh phase 10)
   --smoke-disagg                   disaggregated-serving self-test:
                                    1 prefill + 1 decode replica,
                                    concurrent streams handed off at the
@@ -72,7 +71,7 @@ CLI (``python -m paddle_tpu.serving.router``):
                                    short prompt, kill -9 of the prefill
                                    replica falls back to recompute —
                                    every stream bit-identical; ONE JSON
-                                   line (healthy_window.sh phase 21)
+                                   line
 """
 
 import argparse
@@ -1455,7 +1454,7 @@ class RouterHandler(BaseHTTPRequestHandler):
 
 
 def _smoke():
-    """Fleet self-test (healthy_window.sh phase 10): 2 tiny demo
+    """Fleet self-test: 2 tiny demo
     replicas on ephemeral ports behind the router, concurrent streaming
     /v1/generate clients, kill -9 one replica MID-STREAM — every stream
     must finish bit-identical to the answer the HEALTHY fleet gave to the
@@ -1480,7 +1479,6 @@ def _smoke():
     # decode-step hang paces tokens (~25ms each) so the kill reliably
     # lands MID-stream
     extra = ["--gen-slots", "4", "--gen-max-len", str(max_len),
-             "--gen-prefill-buckets", "8,16",
              "--gen-max-tokens", str(n_tokens),
              "--fault-spec",
              "serving.decode_step:every=1,action=hang,hang_s=0.025"]
@@ -1616,7 +1614,7 @@ def _smoke():
 
 
 def _smoke_disagg():
-    """Disaggregated-serving self-test (healthy_window.sh phase 21):
+    """Disaggregated-serving self-test:
     ONE prefill-role + ONE decode-role replica behind the router,
     concurrent streaming clients handed off mid-flight — each new
     prompt prefills on r0, crosses the socket transport at the first
@@ -1648,7 +1646,6 @@ def _smoke_disagg():
     # analytic fallback and still stream bit-identically.
     lengths = [32, 40, 16, 32]
     extra = ["--gen-slots", "4", "--gen-max-len", str(max_len),
-             "--gen-prefill-buckets", "8,16",
              "--gen-max-tokens", str(n_tokens),
              "--prefill-chunk", str(bs),
              "--kv-layout", "paged", "--kv-block-size", str(bs),

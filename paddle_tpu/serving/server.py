@@ -48,15 +48,14 @@ CLI (``python -m paddle_tpu.serving``):
   --demo-generate                  built-in tiny LM trunk behind the
                                    continuous-batching /v1/generate
   --buckets 1,4,16 --port N --max-delay-ms --queue-size --deadline-ms
-  --gen-slots --gen-max-len --gen-prefill-buckets --gen-max-tokens
+  --gen-slots --gen-max-len --gen-max-tokens
   --smoke                          self-test: ephemeral port, concurrent
                                    requests, /metrics sanity, ONE JSON
-                                   line, exit code (healthy_window.sh's
-                                   serving phase)
+                                   line, exit code
   --smoke-generate                 generation self-test: concurrent
                                    staggered /v1/generate requests,
                                    streaming, EOS early-finish, ONE JSON
-                                   line (healthy_window.sh phase 8)
+                                   line
   --kv-layout slab|paged           decode KV-cache layout (paged = block
                                    pool + prefix sharing, kv_pool.py)
   --kv-block-size --kv-num-blocks --kv-prefix-cache
@@ -69,13 +68,11 @@ CLI (``python -m paddle_tpu.serving``):
                                    prompt clients, prefix hits + CoW
                                    fork, streams bit-identical to the
                                    slab twin, ONE JSON line
-                                   (healthy_window.sh phase 11)
   --smoke-spill                    hierarchical-KV self-test: churn
                                    evicts the shared chain, the
                                    returning prefix restore-hits with
                                    zero chunk lanes, bit-identical to
                                    the tier-less twin, ONE JSON line
-                                   (healthy_window.sh phase 20)
   --role prefill|decode|mixed      disaggregated-serving role advertised
                                    on /metrics (serving_role{role=...}):
                                    the router prefers prefill replicas
@@ -85,18 +82,10 @@ CLI (``python -m paddle_tpu.serving``):
                                    /v1/kv/export (serving/transfer.py;
                                    docs/serving.md "Disaggregated
                                    serving"); mixed (default) = both
-  --prefill-chunk K                unified chunked prefill (the
-                                   default): prompt ingestion rides the
-                                   ONE decode step as K-token chunks;
-                                   0 = the legacy prefill ladder
-                                   (docs/serving.md "Chunked prefill")
-  --smoke-chunked                  chunked-prefill self-test: a long
-                                   prompt admitted MID-DECODE chunks
-                                   through the step while in-flight
-                                   streams keep emitting, all streams
-                                   bit-identical to the ladder twin,
-                                   ONE JSON line (healthy_window.sh
-                                   phase 15)
+  --prefill-chunk K                token lanes of the one decode step:
+                                   prompt ingestion rides it as K-token
+                                   chunks (docs/serving.md "Chunked
+                                   prefill")
   --kv-dtype float32|int8          quantized KV cache (int8 + per-head
                                    scale sidecars; paged auto-sizing
                                    doubles the block count at equal
@@ -109,13 +98,13 @@ CLI (``python -m paddle_tpu.serving``):
                                    budget vs the fp32 twin, int8+weights
                                    exact vs the quantized oracle,
                                    kv_blocks_total doubled, ONE JSON
-                                   line (healthy_window.sh phase 16)
+                                   line
   --smoke-quant-prefill            end-to-end low-precision self-test:
                                    int8 flash prefill within the logit
                                    budget vs the fp32 twin, int8 cache
                                    bit-exact vs sequential steps, int8
                                    trainer 3-step loss parity, ONE JSON
-                                   line (healthy_window.sh phase 22)
+                                   line
   --speculate-k K                  speculative decoding: a truncated-
                                    trunk draft proposes K tokens per
                                    slot, the one chunked step scores
@@ -129,7 +118,6 @@ CLI (``python -m paddle_tpu.serving``):
                                    engine vs a non-spec twin, streams
                                    bit-identical, acceptance evidence
                                    in /metrics, ONE JSON line
-                                   (healthy_window.sh phase 18)
   --mesh-shards N                  tensor-parallel sharded decode: the
                                    one chunked step runs under an
                                    N-chip model-axis mesh (head-striped
@@ -142,8 +130,7 @@ CLI (``python -m paddle_tpu.serving``):
                                    staggered concurrent streams
                                    bit-identical to the single-chip
                                    twin, mesh evidence in /metrics, ONE
-                                   JSON line (healthy_window.sh
-                                   phase 19)
+                                   JSON line
 
 The JSON front-end serves plain-array feed slots (dense/index vectors);
 structured SequenceBatch slots are an in-process engine feature.
@@ -289,8 +276,7 @@ class ServingHandler(BaseHTTPRequestHandler):
                 "status": "ok",
                 "draining": draining,
                 "model": engine.name,
-                "buckets": list(getattr(engine, "buckets", None)
-                                or getattr(engine, "prefill_buckets", ())),
+                "buckets": list(getattr(engine, "buckets", ())),
                 "queue_depth": batcher.metrics.queue_depth(),
             })
         elif self.path == "/readyz":
@@ -705,7 +691,7 @@ def _demo_engine(buckets, warm=True):
 def _demo_gen_batcher(args, tiny=False, metrics=None):
     """Built-in tiny decoder-only LM trunk behind the continuous-batching
     decode engine — /v1/generate bring-up and smoke without a trained
-    model.  ``tiny=True`` shrinks slab + ladder to smoke scale so the
+    model.  ``tiny=True`` shrinks the slots to smoke scale so the
     self-test warms in seconds.  ``metrics``: share the inference
     batcher's ServingMetrics on a combined server, so /metrics reports
     BOTH planes from the one object the handler renders."""
@@ -713,11 +699,10 @@ def _demo_gen_batcher(args, tiny=False, metrics=None):
     from paddle_tpu.serving.decode_engine import (DecodeEngine,
                                                   GenerationBatcher)
     if tiny:
-        slots, max_len, buckets = 4, 48, (8, 16)
+        slots, max_len = 4, 48
     else:
         slots = args.gen_slots
         max_len = args.gen_max_len
-        buckets = tuple(int(b) for b in args.gen_prefill_buckets.split(","))
     params = transformer.init(jax.random.PRNGKey(0), src_vocab=256,
                               trg_vocab=1, d_model=32, num_heads=2,
                               dff=64, enc_layers=2, dec_layers=0,
@@ -744,14 +729,13 @@ def _demo_gen_batcher(args, tiny=False, metrics=None):
         from paddle_tpu.parallel import sharding as _psh
         mesh = _psh.decode_mesh(mesh_shards)
     engine = DecodeEngine(params, num_heads=2, num_slots=slots,
-                          max_len=max_len, prefill_buckets=buckets,
-                          name="demo_lm", metrics=metrics, mesh=mesh,
+                          max_len=max_len, name="demo_lm", metrics=metrics, mesh=mesh,
                           kv_layout=args.kv_layout,
                           kv_block_size=args.kv_block_size,
                           kv_num_blocks=args.kv_num_blocks,
                           prefix_cache=args.kv_prefix_cache,
                           kv_dtype=getattr(args, "kv_dtype", "float32"),
-                          prefill_chunk=getattr(args, "prefill_chunk", 0),
+                          prefill_chunk=args.prefill_chunk,
                           prefill_chunk_budget=getattr(
                               args, "prefill_chunk_budget", 0),
                           speculate_k=speculate_k, draft=draft,
@@ -797,7 +781,7 @@ def _zeros_row_json(engine, fill=0.5):
 def _smoke(batcher, n_requests=8):
     """Self-contained serving smoke: ephemeral port, n concurrent HTTP
     requests, a malformed request, /healthz + /metrics sanity.  Prints ONE
-    JSON line; returns the process exit code (healthy_window.sh phase)."""
+    JSON line; returns the process exit code."""
     import urllib.error
     import urllib.request
 
@@ -876,7 +860,7 @@ def _smoke(batcher, n_requests=8):
 
 
 def _smoke_generate(gen, n_requests=6):
-    """Generation-serving self-test (healthy_window.sh phase 8): ephemeral
+    """Generation-serving self-test: ephemeral
     port, concurrent STAGGERED /v1/generate requests with mixed prompt
     lengths and max_tokens (so admissions land mid-decode and slots churn),
     one streaming request, and an EOS early-finish probe (greedy decode is
@@ -985,8 +969,7 @@ def _smoke_generate(gen, n_requests=6):
 
 
 def _smoke_paged(args):
-    """Paged-KV-cache self-test (healthy_window.sh phase 11; docs/
-    serving.md §5): serve the demo LM with ``kv_layout="paged"`` on an
+    """Paged-KV-cache self-test (docs/serving.md §5): serve the demo LM with ``kv_layout="paged"`` on an
     ephemeral port and drive the prefix-sharing scenario — one client
     establishes a long system-prompt context (prefix-cache miss, chains
     registered), then two clients sharing that system prompt (one the
@@ -1013,8 +996,7 @@ def _smoke_paged(args):
     base = f"http://127.0.0.1:{httpd.port}"
     bs = gen.engine.block_size
     rng = np.random.RandomState(0)
-    # system prompt spanning one full block + a partial tail; questions
-    # keep the total inside the tiny prefill ladder (top bucket 16)
+    # system prompt spanning one full block + a partial tail
     sys_prompt = rng.randint(1, 256, bs + bs // 2).tolist()
     qa = rng.randint(1, 256, 4).tolist()
     qb = rng.randint(1, 256, 4).tolist()
@@ -1119,9 +1101,8 @@ def _smoke_paged(args):
 
 
 def _smoke_spill(args):
-    """Hierarchical-KV self-test (healthy_window.sh phase 20; docs/
-    serving.md "Hierarchical KV"): serve the demo LM with a tiny paged
-    pool plus a host-RAM spill tier on an ephemeral port.  A leader
+    """Hierarchical-KV self-test (docs/serving.md "Hierarchical KV"):
+    serve the demo LM with a tiny paged pool plus a host-RAM spill tier on an ephemeral port.  A leader
     establishes a long block-aligned system-prompt context, churn
     traffic forces the pool to evict (and therefore spill) that chain,
     and then the leader's prompt RETURNS: the engine must restore-hit
@@ -1234,8 +1215,8 @@ def _smoke_spill(args):
 
 
 def _smoke_decode_fused(args):
-    """Fused decode-kernel self-test (healthy_window.sh phase 13;
-    docs/perf.md "Fused decode kernels"): the demo generation drive with
+    """Fused decode-kernel self-test (docs/perf.md "Fused decode
+    kernels"): the demo generation drive with
     ``pallas_decode=always`` — the Pallas decode-attention kernels
     compiled INTO the slab and paged steps (interpret mode on CPU, the
     real Mosaic kernels on TPU) — against a reference-path twin engine
@@ -1307,97 +1288,9 @@ def _smoke_decode_fused(args):
     return 0 if ok_layouts == 2 else 2
 
 
-def _smoke_chunked(args):
-    """Chunked-prefill self-test (healthy_window.sh phase 15; docs/
-    serving.md "Chunked prefill"): the demo LM with prompt ingestion
-    folded into the unified decode step.  A short stream is put
-    mid-decode, then a LONG prompt (the legacy ladder's whole top
-    bucket) is admitted: its ingestion must ride the step as chunks
-    (``prefill_chunks_total``), the in-flight stream must KEEP EMITTING
-    between the newcomer's submit and its first token (the TPOT-
-    bounding property the legacy ladder lacks — its monolithic prefill
-    stalls every in-flight row), and every stream must come back
-    bit-identical to the same prompts served through a legacy-ladder
-    twin engine (one compiled trunk, two ingestion modes, same greedy
-    tokens).  Prints ONE JSON line; returns the process exit code."""
-    import copy
-
-    chunk_args = copy.copy(args)
-    chunk_args.prefill_chunk = min(4, args.prefill_chunk or 4) or 4
-    gen = _demo_gen_batcher(chunk_args, tiny=True)
-    ladder_args = copy.copy(args)
-    ladder_args.prefill_chunk = 0
-    ladder = _demo_gen_batcher(ladder_args, tiny=True)
-    kk = gen.engine.prefill_chunk
-    rng = np.random.RandomState(0)
-    short = rng.randint(1, 256, 4).astype(np.int64)
-    long_p = rng.randint(1, 256, 16).astype(np.int64)  # tiny ladder top
-    n_short, n_long = 40, 6
-    errs = []
-    a_tokens = []               # appended on the worker thread, so the
-    #                             counts below are step-ordered, not
-    #                             wall-clock-dependent
-    a_count_at_b = [None]
-    out = {"metric": "chunked-prefill smoke (unified step vs legacy "
-                     "ladder twin)", "vs_baseline": None,
-           "prefill_chunk": kk}
-    try:
-        fut_a = gen.submit(short, max_tokens=n_short,
-                           on_token=lambda _t:
-                           a_tokens.append(time.perf_counter()))
-        deadline = time.perf_counter() + 60
-        while not a_tokens and time.perf_counter() < deadline:
-            time.sleep(0.002)       # put A provably mid-decode
-        a_count_submit = len(a_tokens)
-        fut_b = gen.submit(long_p, max_tokens=n_long,
-                           on_token=lambda _t, s=a_count_at_b:
-                           s.__setitem__(0, s[0] if s[0] is not None
-                                         else len(a_tokens)))
-        res_b = fut_b.result(120)
-        res_a = fut_a.result(120)
-        # decode tokens A emitted between B's submit and B's first token
-        # — every one delivered WHILE B's prompt was chunking through
-        # the shared step (both counters advance on the worker thread)
-        interleaved = max(0, (a_count_at_b[0] or 0) - a_count_submit)
-        ref_a = ladder.submit(short, max_tokens=n_short).result(120)
-        ref_b = ladder.submit(long_p, max_tokens=n_long).result(120)
-        bit_identical = (res_a["tokens"] == ref_a["tokens"]
-                         and res_b["tokens"] == ref_b["tokens"])
-        requests_ok = 2
-    except Exception as e:      # noqa: BLE001 — a probe failure must
-        # become a failed flag in the ONE JSON line, not a traceback
-        errs.append(f"{type(e).__name__}: {e}")
-        requests_ok, interleaved, bit_identical = 0, 0, False
-    snap = gen.metrics.snapshot()
-    min_chunks = -(-int(long_p.size - 1) // max(1, kk - 1))
-    out.update({
-        "value": requests_ok, "unit": "requests_ok/2",
-        "bit_identical": bool(bit_identical),
-        # decode tokens the in-flight stream received while the long
-        # prompt was being ingested — the ladder's monolithic prefill
-        # yields 0 here by construction
-        "interleaved_tokens": int(interleaved),
-        "prefill_chunks_total": snap["prefill_chunks_total"],
-        "prefill_chunk_lanes_total": snap["prefill_chunk_lanes_total"],
-        "mean_prefill_chunk_occupancy":
-            snap["mean_prefill_chunk_occupancy"],
-        "tpot_jitter_p99_p50": snap["tpot_jitter_p99_p50"],
-        "ttft_long_ms": snap["ttft_ms"]["p99"],
-    })
-    if errs:
-        out["errors"] = errs[:5]
-    gen.close()
-    ladder.close()
-    print(json.dumps(out), flush=True)
-    passed = (requests_ok == 2 and bit_identical and interleaved >= 1
-              and snap["prefill_chunks_total"] >= min_chunks)
-    return 0 if passed else 2
-
-
 def _smoke_quant(args):
-    """Quantized-serving self-test (healthy_window.sh phase 16; docs/
-    serving.md "Quantized serving"): the demo LM behind an INT8-KV
-    paged engine (kv_num_blocks auto-DOUBLED at the slab-equivalent
+    """Quantized-serving self-test (docs/serving.md "Quantized
+    serving"): the demo LM behind an INT8-KV paged engine (kv_num_blocks auto-DOUBLED at the slab-equivalent
     byte budget) serving HTTP /v1/generate, its streams compared
     against a fp32-twin engine under the COMMITTED quality budget
     (quant/kv.py: every stream's common prefix >= GREEDY_PREFIX_MIN_FULL
@@ -1525,9 +1418,8 @@ def _smoke_quant(args):
 
 
 def _smoke_quant_prefill(args):
-    """End-to-end low-precision self-test (healthy_window.sh phase 22;
-    docs/perf.md "Int8 flash prefill" / "Int8 weight-streaming
-    trainer").  Serving half: the demo trunk's batched causal prefill
+    """End-to-end low-precision self-test (docs/perf.md "Int8 flash
+    prefill" / "Int8 weight-streaming trainer").  Serving half: the demo trunk's batched causal prefill
     with ``kv_dtype="int8"`` THROUGH the int8 flash kernel
     (``pallas_prefill_quant=always`` — interpret mode off-TPU), its
     logits bounded against the fp32 prefill twin by the COMMITTED
@@ -1650,8 +1542,8 @@ def _smoke_quant_prefill(args):
 
 
 def _smoke_speculative(args):
-    """Speculative-decoding self-test (healthy_window.sh phase 18;
-    docs/serving.md "Speculative decoding"): the demo LM behind a
+    """Speculative-decoding self-test (docs/serving.md "Speculative
+    decoding"): the demo LM behind a
     speculating engine (1-layer draft riding the chunked step) serving
     concurrent staggered clients, every stream compared byte-for-byte
     against a NON-speculating twin of the same trunk — the draft may
@@ -1735,8 +1627,8 @@ def _smoke_speculative(args):
 
 
 def _smoke_sharded(args):
-    """Tensor-parallel sharded-decode self-test (healthy_window.sh
-    phase 19; docs/serving.md "Sharded decode"): the demo LM's one
+    """Tensor-parallel sharded-decode self-test (docs/serving.md
+    "Sharded decode"): the demo LM's one
     chunked step under an n=2 model-axis mesh serving concurrent
     staggered clients, every stream compared byte-for-byte against the
     single-chip twin — sharding may only ever change WHERE bytes live,
@@ -1867,8 +1759,6 @@ def main(argv=None):
     ap.add_argument("--gen-slots", type=int, default=FLAGS.serving_gen_slots)
     ap.add_argument("--gen-max-len", type=int,
                     default=FLAGS.serving_gen_max_len)
-    ap.add_argument("--gen-prefill-buckets",
-                    default=FLAGS.serving_gen_prefill_buckets)
     ap.add_argument("--gen-max-tokens", type=int,
                     default=FLAGS.serving_gen_max_tokens)
     # ---- paged KV cache (serving/kv_pool.py; docs/serving.md §5) ----
@@ -1923,13 +1813,12 @@ def main(argv=None):
                          "step: auto (TPU only) | always (interpret "
                          "off-TPU) | off — docs/perf.md 'Fused decode "
                          "kernels'")
-    # ---- unified chunked prefill (docs/serving.md "Chunked prefill") --
+    # ---- chunked prefill (docs/serving.md "Chunked prefill") ---------
     ap.add_argument("--prefill-chunk", type=int,
                     default=FLAGS.serving_prefill_chunk,
-                    help="fold prompt ingestion into the one decode "
-                         "step as up-to-K-token chunks per slot per "
-                         "step (the default serving mode); 0 = the "
-                         "legacy per-bucket prefill ladder")
+                    help="token lanes K of the one decode step: prompt "
+                         "ingestion rides it as up-to-K-token chunks "
+                         "per slot per step (>= 1)")
     ap.add_argument("--prefill-chunk-budget", type=int,
                     default=FLAGS.serving_prefill_chunk_budget,
                     help="max teacher-forced chunk lanes per step "
@@ -1953,13 +1842,13 @@ def main(argv=None):
                     default=FLAGS.serving_mesh_shards,
                     help="run the one chunked step under an N-chip "
                          "model-axis mesh (heads/KV/vocab striped, "
-                         "streams bit-identical to single-chip; "
-                         "requires --prefill-chunk > 0); 0/1 = "
+                         "streams bit-identical to single-chip); 0/1 = "
                          "single-chip")
     ap.add_argument("--pallas-prefill", default=FLAGS.pallas_prefill,
-                    help="route the legacy ladder's lm_prefill causal "
-                         "pass through the flash kernel (no [Tp, Tp] "
-                         "scores): auto (TPU only) | always | off")
+                    help="route lm_prefill's causal pass (lm_generate; "
+                         "the served step never runs it) through the "
+                         "flash kernel (no [Tp, Tp] scores): auto (TPU "
+                         "only) | always | off")
     ap.add_argument("--pallas-prefill-quant",
                     default=FLAGS.pallas_prefill_quant,
                     help="int8-cache prefill through the int8 flash "
@@ -2005,12 +1894,6 @@ def main(argv=None):
                          "(slab + paged), streams bit-identical to a "
                          "reference-path twin, 0 retraces; one JSON "
                          "line, exit")
-    ap.add_argument("--smoke-chunked", action="store_true",
-                    help="chunked-prefill self-test: a long prompt "
-                         "admitted MID-DECODE must chunk through the "
-                         "unified step while in-flight streams keep "
-                         "emitting, every stream bit-identical to the "
-                         "legacy-ladder twin; one JSON line, exit")
     ap.add_argument("--smoke-quant", action="store_true",
                     help="quantized-serving self-test: int8-KV paged "
                          "engine vs a fp32 twin within the committed "
@@ -2091,8 +1974,6 @@ def main(argv=None):
         return _smoke_spill(args)
     if args.smoke_decode_fused:
         return _smoke_decode_fused(args)
-    if args.smoke_chunked:
-        return _smoke_chunked(args)
     if args.smoke_quant:
         return _smoke_quant(args)
     if args.smoke_quant_prefill:

@@ -12,8 +12,8 @@ target itself would have emitted greedily, so streams stay token-
 identical to ``lm_generate`` no matter how good or bad the draft is.
 
 Trace discipline matches the target engine: ONE jitted rollout function
-(chunk-ingest the committed tokens, then k-1 static-unrolled single-
-position steps), warmed exactly once; k is a constructor constant and
+(chunk-ingest the committed tokens, then k-1 static-unrolled one-lane
+steps), warmed exactly once; k is a constructor constant and
 per-slot feed lengths/positions are data, so acceptance churn never
 retraces.  The draft cache is epoch-guarded like the target's
 (``reset()`` bumps the epoch; an in-flight rollout's cache commit is
@@ -140,11 +140,12 @@ class DraftTrunk:
             # keeps the scatter in-bounds for rows parked at the cache
             # edge (their junk write is re-fed before anything attends)
             base = positions + lengths
+            one = jnp.ones_like(lengths)
             for i in range(self.k - 1):
                 qp = jnp.minimum(base + i, self.max_len - 1)
-                logits, cache = transformer.lm_decode_step_slots(
-                    p, nxt, qp, cache, heads, self.moe_top_k,
-                    self.pos_type, shard_axis=axis)
+                logits, cache = transformer.lm_decode_chunk_slots(
+                    p, nxt[:, None], qp, one, cache, heads,
+                    self.moe_top_k, self.pos_type, shard_axis=axis)
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 drafts.append(nxt)
             return jnp.stack(drafts, axis=1), cache
@@ -229,8 +230,8 @@ class DraftTrunk:
         self.reset()
 
     def lower(self):
-        """Lowered (unspecialized-to-device-data) rollout for the
-        analytic bench's compiled-HLO inspection."""
+        """Lowered (unspecialized-to-device-data) rollout, for
+        compiled-HLO inspection."""
         tokens, positions, lengths = self._dummy_feed()
         return self._jit.lower(self.params, self._cache,
                                jnp.asarray(tokens), jnp.asarray(positions),

@@ -46,7 +46,7 @@ class Widths:
     slab_len: int       # slab layout: cache length T
     block_size: int     # paged layout: positions per pool block
     blocks_per_row: int
-    chunk: int          # query lanes per row in the Tq=chunk kernels
+    chunk: int          # query lanes per row in the chunk cases
     flash_batch: int
     flash_len: int
     flash_block: int
@@ -382,8 +382,7 @@ def build_private_tables(positions, nb_row, block_size, num_blocks):
     ``pos // block_size + 1`` distinct block ids from 1..num_blocks-1,
     unowned table slots stay 0 (the reserved scratch block) — the layout
     serving/kv_pool.py's allocator produces.  One definition for the
-    smoke cases here, bench.py's serving_decode_fused inputs, and
-    tests/test_pallas_decode.py."""
+    smoke cases here and tests/test_pallas_decode.py."""
     tables = np.zeros((len(positions), nb_row), np.int32)
     nxt = 1
     for r, p in enumerate(positions):
@@ -412,8 +411,8 @@ def _cell(w):
 
 
 def _decode_case(w, *, paged, chunk, quant, seed, contexts=None):
-    """One of the eight fused decode-attention kernels (slab | paged) x
-    (Tq=1 | Tq=chunk) x (f32 | int8 K/V) vs the masked-XLA oracle
+    """One of the eight fused decode-attention calls (slab | paged) x
+    (one lane | ``w.chunk`` lanes) x (f32 | int8 K/V) vs the masked-XLA oracle
     (models/transformer._attend over the gathered, dequantized rows) —
     forward only (the decode hot path has no backward).  Mixed decode
     rows (1 live lane) and chunking rows (all lanes) in the chunk cases;
@@ -463,20 +462,14 @@ def _decode_case(w, *, paged, chunk, quant, seed, contexts=None):
 
     def fn(q, k, v, ks, vs):
         with dk.forced_mode("always"):
-            if chunk and paged:
+            if paged:
                 out = dk.maybe_paged_chunk(q, k, v, qpos, tables, h,
                                            kscale=ks, vscale=vs)
-            elif chunk:
+            else:
                 out = dk.maybe_slab_chunk(q, k, v, qpos, h, kscale=ks,
                                           vscale=vs)
-            elif paged:
-                out = dk.maybe_paged(q[:, 0], k, v, qpos[:, 0], tables, h,
-                                     kscale=ks, vscale=vs)
-            else:
-                out = dk.maybe_slab(q[:, 0], k, v, qpos[:, 0], h,
-                                    kscale=ks, vscale=vs)
         assert out is not None, "kernel declined a shape its guard covers"
-        return out if chunk else out[:, None]
+        return out
 
     def oracle(q, k, v, ks, vs):
         if quant:
@@ -612,17 +605,14 @@ CASES = {
 }
 
 
-def run_all(widths=SMALL, expect_compiled=False, before_case=None):
+def run_all(widths=SMALL, expect_compiled=False):
     """Every case through ``run_case``; one broken kernel must not hide the
     verdict on the others, so a failure becomes ``{"ok": False, "error"}``
-    in its row.  ``before_case(name)`` is called ahead of each (bench.py
-    arms its watchdog there).  Returns ``(all_ok, {name: row})``; every row
-    carries its wall ``secs``."""
+    in its row.  Returns ``(all_ok, {name: row})``; every row carries its
+    wall ``secs``."""
     import time
     results = {}
     for name in CASES:
-        if before_case is not None:
-            before_case(name)
         t0 = time.perf_counter()
         try:
             row = run_case(name, widths, expect_compiled)

@@ -90,7 +90,6 @@ class Flags:
     # continuous batching over a fixed KV-cache slab; docs/serving.md §4)
     serving_gen_slots: int = 8          # concurrent decode slots
     serving_gen_max_len: int = 256      # KV slab length (prompt + output)
-    serving_gen_prefill_buckets: str = "32,64"  # prompt-length ladder
     serving_gen_max_tokens: int = 64    # default per-request emission cap
     # ---- paged KV cache (serving/kv_pool.py: block-pool allocator +
     # copy-on-write prefix sharing; docs/serving.md §5)
@@ -135,13 +134,10 @@ class Flags:
     #                                     step (trainer quant_weights
     #                                     mode: f32 masters optimizer-
     #                                     side, requantize after update)
-    # ---- unified chunked prefill (decode_engine.py prefill_chunk:
-    # prompt ingestion folded into the ONE jitted decode step as K-lane
-    # chunks; docs/serving.md "Chunked prefill").  The serving CLI
-    # defaults to chunked; 0 demotes to the legacy per-bucket prefill
-    # ladder.
-    serving_prefill_chunk: int = 8      # lanes per chunked-prefill step
-    #                                     (K; 0 = legacy ladder prefill)
+    # ---- chunked prefill (decode_engine.py prefill_chunk: prompt
+    # ingestion rides the ONE jitted decode step as K-lane chunks;
+    # docs/serving.md "Chunked prefill")
+    serving_prefill_chunk: int = 8      # token lanes K of the step
     serving_prefill_chunk_budget: int = 0  # max teacher-forced lanes per
     #                                        step across all slots
     #                                        (0 = unbounded); data, not
@@ -319,7 +315,7 @@ _CACHE_DIR = os.path.join(os.path.dirname(os.path.dirname(
 
 def set_compilation_cache_dir():
     """THE one place the persistent XLA compilation cache is wired; the
-    trainer CLI, the serving CLI, bench.py and chip_smoke.py call it at
+    trainer CLI, the serving CLI and chip_smoke.py call it at
     start.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it
     itself and nothing is set in code; otherwise the cache lives at the
     fixed in-checkout ``.jax_cache``.  Returns the directory in use."""
@@ -417,10 +413,6 @@ FLAG_DOCS = {
                           "slab (concurrent generations)", "—"),
     "serving_gen_max_len": ("KV-cache slab length; every request needs "
                             "prompt + max_tokens <= this", "—"),
-    "serving_gen_prefill_buckets": ("prompt-length ladder (comma ints) "
-                                    "the prefill engines AOT-compile; "
-                                    "the top bucket caps prompt length",
-                                    "—"),
     "serving_gen_max_tokens": ("default per-request emission cap for "
                                "/v1/generate", "—"),
     "serving_kv_layout": ("decode KV-cache layout: slab (max_len "
@@ -485,12 +477,11 @@ FLAG_DOCS = {
                     "optimizer side and re-quantize after each update.  "
                     "Checkpoints carry both trees and resume "
                     "bit-identically", "—"),
-    "serving_prefill_chunk": ("unified chunked prefill: prompt "
-                              "ingestion rides the ONE jitted decode "
-                              "step as up-to-K-token chunks per slot "
-                              "per step (first token at the last "
-                              "chunk); 0 = the legacy per-bucket "
-                              "prefill InferenceEngine ladder", "—"),
+    "serving_prefill_chunk": ("token lanes K of the ONE jitted decode "
+                              "step: prompt ingestion rides it as "
+                              "up-to-K-token chunks per slot per step "
+                              "(first token at the last chunk); "
+                              ">= 1", "—"),
     "serving_prefill_chunk_budget": ("max teacher-forced chunk lanes "
                                      "one step may feed across all "
                                      "slots (bounds per-step prefill "
@@ -504,8 +495,7 @@ FLAG_DOCS = {
                             "nets 1 + accepted tokens, streams stay "
                             "token-identical to lm_generate (the "
                             "acceptance rule keeps exactly the greedy "
-                            "prefix).  0 = off; requires "
-                            "serving_prefill_chunk > 0", "—"),
+                            "prefix).  0 = off", "—"),
     "serving_mesh_shards": ("tensor-parallel sharded decode: run the "
                             "ONE chunked serving step under an N-chip "
                             "model-axis mesh (decode_mesh) — attention "
@@ -516,8 +506,7 @@ FLAG_DOCS = {
                             "attention-output all-gather, the logits "
                             "all-gather, and the embedding psum.  "
                             "Streams stay BIT-IDENTICAL to the "
-                            "single-chip engine; requires "
-                            "serving_prefill_chunk > 0 and N dividing "
+                            "single-chip engine; requires N dividing "
                             "heads/Hkv/vocab.  0/1 = single-chip", "—"),
     "serving_draft_layers": ("trunk depth of the draft model derived "
                              "from the target (speculative.make_draft: "

@@ -4,8 +4,8 @@ Every rule is proven IN REVERSE against the seeded-violation fixtures
 (analysis/fixtures/) — the analytic-gate discipline: a detector that
 never fires is no detector — plus clean controls, the committed-tree
 rc-0 acceptance gate, baseline round-trip, JSON schema, and the
-FAMILIES/JIT_ROOTS drift test that keeps perf/analytic.py and the
-analyzer agreeing on what a "jitted step" is.
+JIT_ROOTS drift test (every registered root still names a real function
+and real parameters).
 
 The retrace rules also get a RUNTIME confirmation: the statically
 flagged fixture shape really retraces per value under jit, its
@@ -26,10 +26,8 @@ import pytest
 
 from paddle_tpu.analysis import baseline as baseline_mod
 from paddle_tpu.analysis import callgraph, locks, purity, retrace
-from paddle_tpu.analysis import roots as roots_mod
 from paddle_tpu.analysis.__main__ import main as analysis_main
-from paddle_tpu.analysis.roots import (FAMILIES, FAMILY_ROOTS, JIT_ROOTS,
-                                       Root, TRACE_TIME_FLAGS, all_roots)
+from paddle_tpu.analysis.roots import Root, TRACE_TIME_FLAGS, all_roots
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -189,10 +187,8 @@ def test_locks_real_scan_set_is_not_polluted_by_fixtures(project):
 # ------------------------------------------- the gate on the real tree
 
 @pytest.mark.slow       # whole-tree parse x all three passes: the
-#                         heavy run rides the slow lane (the fast lane
-#                         is budget-saturated per PR 14's host note);
-#                         healthy_window phase 17 + the subprocess CLI
-#                         test below gate the same thing
+#                         heavy run rides the slow lane; the subprocess
+#                         CLI test below gates the same thing
 def test_clean_tree_exits_zero():
     """Acceptance: `python -m paddle_tpu.analysis --check all` exits 0
     on HEAD — every finding fixed or baselined with a reason."""
@@ -276,19 +272,10 @@ def test_committed_baseline_loads_and_is_justified():
 
 # ------------------------------------------------------- registry drift
 
-def test_every_family_maps_to_known_roots(project):
-    """A new bench family cannot add a jitted step the analyzer doesn't
-    see: FAMILIES and FAMILY_ROOTS must cover each other exactly, every
-    mapped root must exist, and every root ref must resolve in the AST
-    index with its static_args naming real parameters."""
-    names = {n for n, _m, _b in FAMILIES}
-    assert names == set(FAMILY_ROOTS), (
-        "FAMILIES vs FAMILY_ROOTS drift — map the new family in "
-        "paddle_tpu/analysis/roots.py")
-    for fam, rs in FAMILY_ROOTS.items():
-        assert rs, f"{fam}: empty root mapping"
-        for r in rs:
-            assert r in JIT_ROOTS, f"{fam} names unknown root {r}"
+def test_every_root_resolves_with_real_static_args(project):
+    """A rename cannot drop a jitted step out of the analysis: every
+    root ref must resolve in the AST index with its static_args naming
+    real parameters."""
     for root in all_roots():
         infos = project.function(root.ref)
         assert infos, f"root {root.name}: {root.ref} not found in AST"
@@ -297,11 +284,6 @@ def test_every_family_maps_to_known_roots(project):
         assert not missing, (
             f"root {root.name}: static_args {sorted(missing)} are not "
             f"parameters of {root.ref} (has {sorted(params)})")
-
-
-def test_analytic_families_is_the_shared_registry():
-    from paddle_tpu.perf import analytic
-    assert analytic.FAMILIES is roots_mod.FAMILIES
 
 
 def test_trace_time_flags_are_real_flags():

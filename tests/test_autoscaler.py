@@ -876,7 +876,7 @@ def lm_replica():
                               dff=64, enc_layers=2, dec_layers=0,
                               max_len=48)
     engine = DecodeEngine(params, num_heads=2, num_slots=4, max_len=48,
-                          prefill_buckets=(8, 16), name="autoscale_lm")
+                          name="autoscale_lm")
     gen = GenerationBatcher(engine)
     httpd = make_server(None, port=0, gen_batcher=gen)
     threading.Thread(target=httpd.serve_forever, daemon=True).start()

@@ -1,6 +1,6 @@
-"""Unified chunked-prefill serving (DecodeEngine prefill_chunk > 0).
+"""Chunked-prefill serving (DecodeEngine's one step, prefill_chunk = K).
 
-Prompt ingestion folded into the ONE jitted decode step: each step
+Prompt ingestion rides the ONE jitted decode step: each step
 advances a mix of decode rows (1 token) and admitting rows (up to K
 prompt tokens, re-derived emissions swallowed until the last chunk).
 The correctness bar is the slab engine's own: every greedy stream —
@@ -30,7 +30,7 @@ from paddle_tpu.testing import assert_no_retrace
 from paddle_tpu.utils.error import ConfigError
 
 VOCAB, D_MODEL, LAYERS, HEADS = 64, 32, 2, 2
-MAX_LEN, SLOTS, BUCKETS, BS, K = 48, 4, (8, 16), 8, 4
+MAX_LEN, SLOTS, BS, K = 48, 4, 8, 4
 
 
 @pytest.fixture(autouse=True)
@@ -57,7 +57,6 @@ def rope_params():
 
 def _engine(params, **kw):
     kw.setdefault("prefill_chunk", K)
-    kw.setdefault("prefill_buckets", BUCKETS)
     return DecodeEngine(params, num_heads=HEADS, num_slots=SLOTS,
                         max_len=MAX_LEN, **kw)
 
@@ -149,34 +148,6 @@ def test_chunk_step_matches_prefill(params):
                                            err_msg=f"layer {layer} {kv}")
 
 
-def test_chunk_step_len1_matches_tq1_step(params):
-    """Every row at lengths=1 computes what the Tq=1 slot step computes
-    — same greedy tokens, logits equal to float rounding (XLA may tile
-    the [S, K, D] matmuls differently from [S, 1, D], so the last ULP
-    can move; the ENGINE is self-consistent because it always runs the
-    one chunk-shaped step, and the drive tests below pin stream-level
-    bit-identity against lm_generate)."""
-    rng = np.random.RandomState(1)
-    cache = transformer.init_lm_cache(params, SLOTS, MAX_LEN)
-    toks = rng.randint(1, VOCAB, SLOTS).astype(np.int32)
-    pos = rng.randint(0, 8, SLOTS).astype(np.int32)
-    l1, c1 = transformer.lm_decode_step_slots(params, toks, pos, cache,
-                                              HEADS)
-    tk = np.zeros((SLOTS, K), np.int32)
-    tk[:, 0] = toks
-    l2, c2 = transformer.lm_decode_chunk_slots(
-        params, tk, pos, np.ones((SLOTS,), np.int32), cache, HEADS)
-    np.testing.assert_allclose(np.asarray(l1), np.asarray(l2),
-                               rtol=1e-6, atol=1e-7)
-    assert np.array_equal(np.argmax(np.asarray(l1), -1),
-                          np.argmax(np.asarray(l2), -1))
-    rows = np.arange(SLOTS)
-    for a, b in zip(c1, c2):
-        np.testing.assert_allclose(np.asarray(a["k"])[rows, pos],
-                                   np.asarray(b["k"])[rows, pos],
-                                   rtol=2e-5, atol=1e-6)
-
-
 # --------------------------------------------------------- engine parity
 
 
@@ -184,14 +155,14 @@ def test_chunk_step_len1_matches_tq1_step(params):
 def test_chunked_staggered_admissions_bit_identical(params):
     """The acceptance drive: more requests than slots, mixed prompt
     lengths (including chunk-boundary sizes 1 / K-1 / K / K+1 / 2K and
-    prompts BEYOND the legacy ladder top) and mixed max_tokens,
+    prompts of several chunks) and mixed max_tokens,
     staggered so admissions land mid-decode — every stream equals the
     single-request oracle exactly."""
     eng = _engine(params, name="cp_slab")
     eng.metrics = ServingMetrics()
     bat = GenerationBatcher(eng, default_max_tokens=8)
     rng = np.random.RandomState(2)
-    sizes = [1, K - 1, K, K + 1, 2 * K, 25, 30]     # 25/30 > ladder 16
+    sizes = [1, K - 1, K, K + 1, 2 * K, 25, 30]
     cases = [(_prompt(rng, s), int(rng.randint(2, 10))) for s in sizes]
     cases += [(_prompt(rng), int(rng.randint(2, 10))) for _ in range(5)]
     results, excs = _drive(bat, cases)
@@ -206,8 +177,6 @@ def test_chunked_staggered_admissions_bit_identical(params):
     assert snap["prefill_chunk_lanes_total"] > 0
     assert snap["prefill_chunk_size"] == K
     assert eng.free_slots == SLOTS
-    # the legacy ladder was never touched: no prefill engines exist
-    assert not eng._prefill_engines
 
 
 def test_chunked_eos_and_single_token(params):
@@ -249,7 +218,7 @@ def test_chunked_rope_trunk_bit_identical(rope_params):
 def test_chunked_continuation_replay_bit_identical(params):
     """PR-7 continuations ride chunks: a stream interrupted after k
     delivered tokens finishes emitting ONLY the remainder, bit-identical
-    — including contexts longer than the legacy ladder top."""
+    — including contexts of many chunks."""
     eng = _engine(params, name="cp_cont")
     bat = GenerationBatcher(eng)
     rng = np.random.RandomState(5)
@@ -311,9 +280,9 @@ def test_chunked_paged_prefix_cow_pressure_bit_identical(params):
 
 
 def test_one_warmup_trace_zero_retraces_under_chunk_churn(params):
-    """ONE step trace at warm-up (the chunked engine compiles no
-    admission write and no prefill ladder at all; paged adds only the
-    block-fork executable), then ZERO traces across admission churn,
+    """ONE step trace at warm-up (the engine compiles no admission
+    write and no prefill program; paged adds only the block-fork
+    executable), then ZERO traces across admission churn,
     varying chunk lane counts, budget throttling, prefix hits, CoW
     forks and pool preemption — lane counts are data, not shape."""
     for layout, extra in (("slab", {}),
@@ -400,8 +369,8 @@ def test_chunked_with_fused_kernels_token_identical(params):
 def test_supervisor_recovery_rides_chunks_bit_identical(params):
     """PR-6 chaos on the chunked engine: an injected decode-step fault
     rebuilds the pool and re-seats every in-flight stream through
-    CHUNKED seating (whole contexts as K-lane feeds — no ladder, no
-    per-token-only replay) — all streams bit-identical, zero extra
+    CHUNKED seating (whole contexts as K-lane feeds, not one token a
+    step) — all streams bit-identical, zero extra
     traces, ledger balanced."""
     eng = _engine(params, name="cp_chaos", kv_layout="paged",
                   kv_block_size=BS)
@@ -423,7 +392,6 @@ def test_supervisor_recovery_rides_chunks_bit_identical(params):
     snap = eng.metrics.snapshot()
     assert snap["evictions"]["recovered"] >= 1
     assert snap["slot_reprefills_total"] >= 1
-    assert not eng._prefill_engines       # recovery never built a ladder
     eng._paged.check()
 
 
@@ -432,9 +400,9 @@ def test_supervisor_recovery_rides_chunks_bit_identical(params):
 
 def test_chunked_validation_and_config(params):
     eng = _engine(params, name="cp_val", warm=False)
-    # no ladder cap: a prompt beyond the bucket top is FINE now...
+    # a prompt of many chunks is fine...
     eng.validate_request(np.arange(1, 31, dtype=np.int32), 8)
-    # ...but max_len still bounds prompt + emission
+    # ...and max_len bounds prompt + emission
     with pytest.raises(InvalidRequestError, match="max_len"):
         eng.validate_request(np.arange(1, 41, dtype=np.int32), 10)
     with pytest.raises(ConfigError, match="prefill_chunk"):
@@ -442,11 +410,6 @@ def test_chunked_validation_and_config(params):
     with pytest.raises(ConfigError, match="prefill_chunk"):
         _engine(params, name="cp_bad2", prefill_chunk=MAX_LEN + 1,
                 warm=False)
-    # chunked mode ignores the ladder-top-vs-max_len constraint the
-    # legacy mode enforces (it never builds the ladder)
-    DecodeEngine(params, num_heads=HEADS, num_slots=SLOTS, max_len=24,
-                 prefill_buckets=(8, 32), prefill_chunk=K, warm=False,
-                 name="cp_nobucket")
 
 
 # ----------------------------------------------------------- metrics

@@ -1,8 +1,8 @@
 """Continuous-batching generation serving (serving/decode_engine.py).
 
 The correctness bar mirrors test_serving.py's: a request served through
-the full stack — queue, prefill ladder, slot admission, the shared slab
-step, eviction — must return EXACTLY the tokens the single-request
+the full stack — queue, slot admission, chunked ingestion through the
+shared step, eviction — must return EXACTLY the tokens the single-request
 oracle (``models/transformer.lm_generate``, greedy) produces for that
 prompt.  Every linear layer in the decode path is batched over the
 leading slot axis, so a row's numerics do not depend on what the other
@@ -10,7 +10,7 @@ slots hold; greedy outputs are therefore bit-identical token for token,
 across staggered admissions, mixed prompt lengths, and slot reuse after
 eviction.
 
-Trace discipline: the slab step traces exactly ONCE at warm-up and never
+Trace discipline: the step traces exactly ONCE at warm-up and never
 again across admission/eviction churn (the shared
 ``paddle_tpu.testing.trace`` assertion, same as ``InferenceEngine`` and
 ``SGD.precompile``).
@@ -18,7 +18,9 @@ again across admission/eviction churn (the shared
 Fault injection covers the GenerationBatcher's admission-control paths
 (invalid prompt before the queue, overload, deadline), batch-failure
 isolation (a step failure fails only the in-flight requests; the engine
-resets and keeps serving), and both drain semantics.
+resets and keeps serving), and both drain semantics.  The lifecycle
+tests share one module-scoped engine a layout: they run on the slab and
+on the paged pool, the engine every benchmark cell serves.
 """
 
 import json
@@ -32,15 +34,23 @@ import pytest
 import jax
 
 from paddle_tpu.models import transformer
+from paddle_tpu.resilience import faults
 from paddle_tpu.serving import (BatchExecutionError, DeadlineExceededError,
                                 GenerationBatcher, InvalidRequestError,
                                 OverloadedError, ServingMetrics,
                                 ShutdownError, make_server)
 from paddle_tpu.serving.decode_engine import DecodeEngine
 from paddle_tpu.testing import assert_no_retrace
+from paddle_tpu.utils.error import ConfigError
 
 VOCAB, D_MODEL, LAYERS, HEADS = 64, 32, 2, 2
-MAX_LEN, SLOTS, BUCKETS = 48, 4, (8, 16)
+MAX_LEN, SLOTS, PROMPT_TOP, BS = 48, 4, 16, 8
+
+
+@pytest.fixture(autouse=True)
+def _no_leaked_fault_plan():
+    yield
+    faults.clear()
 
 
 @pytest.fixture(scope="module")
@@ -51,28 +61,30 @@ def params():
                             max_len=MAX_LEN)
 
 
-@pytest.fixture(scope="module")
-def engine(params):
+@pytest.fixture(scope="module", params=["slab", "paged"])
+def engine(params, request):
     return DecodeEngine(params, num_heads=HEADS, num_slots=SLOTS,
-                        max_len=MAX_LEN, prefill_buckets=BUCKETS,
-                        name="test_lm")
+                        max_len=MAX_LEN, kv_layout=request.param,
+                        kv_block_size=BS, name=f"test_lm_{request.param}")
 
 
 def _prompt(rng, n=None):
-    return rng.randint(1, VOCAB, n or rng.randint(3, BUCKETS[-1] + 1)
+    return rng.randint(1, VOCAB, n or rng.randint(3, PROMPT_TOP + 1)
                        ).astype(np.int32)
 
 
-def _oracle(params, engine, prompt, n_tokens, eos_id=None):
-    """Single-request greedy lm_generate, run at the SAME prefill bucket
-    and cache width the engine used (pad value is irrelevant — proven by
-    lm_generate's own ragged-prompt contract)."""
-    bucket = engine.prefill_bucket_for(prompt.size)
-    padded = np.zeros((1, bucket), np.int32)
+def _oracle(params, prompt, n_tokens, eos_id=None, pos_type="learned"):
+    """Single-request greedy lm_generate at the engine's cache width.
+    The prompt is padded to a multiple of PROMPT_TOP so the oracle
+    compiles a shape or two, not one per length (the pad value is
+    irrelevant — lm_generate's own ragged-prompt contract)."""
+    width = -(-prompt.size // PROMPT_TOP) * PROMPT_TOP
+    padded = np.zeros((1, width), np.int32)
     padded[0, :prompt.size] = prompt
     ids = np.asarray(transformer.lm_generate(
-        params, padded, max_len=engine.max_len, num_heads=HEADS,
-        eos_id=eos_id, prompt_lengths=np.asarray([prompt.size])))
+        params, padded, max_len=MAX_LEN, num_heads=HEADS,
+        eos_id=eos_id, prompt_lengths=np.asarray([prompt.size]),
+        pos_type=pos_type))
     return ids[0, prompt.size:prompt.size + n_tokens].tolist()
 
 
@@ -81,7 +93,7 @@ def _oracle(params, engine, prompt, n_tokens, eos_id=None):
 
 def test_staggered_admissions_bit_identical_to_lm_generate(params, engine):
     """The acceptance drive: more requests than slots, mixed prompt
-    lengths (both ladder buckets), mixed max_tokens, submitted in
+    lengths (under one chunk and several), mixed max_tokens, submitted in
     staggered waves so admissions land mid-decode and every slot is
     reused after eviction — each request's greedy tokens must equal the
     single-request oracle exactly."""
@@ -100,7 +112,7 @@ def test_staggered_admissions_bit_identical_to_lm_generate(params, engine):
     for (prompt, n), res in zip(cases, results):
         assert res["finish_reason"] == "length"
         assert len(res["tokens"]) == n
-        assert res["tokens"] == _oracle(params, engine, prompt, n), \
+        assert res["tokens"] == _oracle(params, prompt, n), \
             f"prompt len {prompt.size}, n {n}"
     # 12 requests over 4 slots: every slot was reused after eviction
     snap = engine.metrics.snapshot()
@@ -113,7 +125,7 @@ def test_staggered_admissions_bit_identical_to_lm_generate(params, engine):
 
 def test_rope_trunk_bit_identical_to_lm_generate():
     """The per-row rope path (positions[:, None] through _rope_flat into
-    rope()'s [B, T] branch) is the subtlest slab-step code: pin the same
+    rope()'s [B, T] branch) is the subtlest step code: pin the same
     bit-identity guarantee on a rope trunk (no learned table at all)."""
     rope_params = transformer.init(jax.random.PRNGKey(1), src_vocab=VOCAB,
                                    trg_vocab=1, d_model=D_MODEL,
@@ -121,8 +133,7 @@ def test_rope_trunk_bit_identical_to_lm_generate():
                                    enc_layers=LAYERS, dec_layers=0,
                                    max_len=MAX_LEN, pos_type="rope")
     eng = DecodeEngine(rope_params, num_heads=HEADS, num_slots=SLOTS,
-                       max_len=MAX_LEN, prefill_buckets=BUCKETS,
-                       pos_type="rope", name="rope_lm")
+                       max_len=MAX_LEN, pos_type="rope", name="rope_lm")
     bat = GenerationBatcher(eng)
     rng = np.random.RandomState(10)
     cases = [(_prompt(rng), int(rng.randint(2, 9))) for _ in range(6)]
@@ -130,14 +141,8 @@ def test_rope_trunk_bit_identical_to_lm_generate():
     results = [f.result(120) for f in futs]
     bat.close()
     for (prompt, n), res in zip(cases, results):
-        bucket = eng.prefill_bucket_for(prompt.size)
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :prompt.size] = prompt
-        ids = np.asarray(transformer.lm_generate(
-            rope_params, padded, max_len=eng.max_len, num_heads=HEADS,
-            prompt_lengths=np.asarray([prompt.size]), pos_type="rope"))
-        assert res["tokens"] == \
-            ids[0, prompt.size:prompt.size + n].tolist()
+        assert res["tokens"] == _oracle(rope_params, prompt, n,
+                                        pos_type="rope")
 
 
 def test_eos_early_finish_matches_oracle(params, engine):
@@ -155,7 +160,7 @@ def test_eos_early_finish_matches_oracle(params, engine):
     assert res["tokens"][-1] == eos
     k = free.index(eos) + 1             # first occurrence stops the run
     assert res["tokens"] == free[:k]
-    assert res["tokens"] == _oracle(params, engine, prompt, k, eos_id=eos)
+    assert res["tokens"] == _oracle(params, prompt, k, eos_id=eos)
 
 
 def test_streaming_on_token_callback(params, engine):
@@ -180,27 +185,46 @@ def test_streaming_on_token_callback(params, engine):
 
 
 def test_one_warmup_trace_zero_retraces_across_churn(params):
-    """The trace-count discipline, end to end: warm-up traces the slab
-    step exactly once; an admission/eviction churn run (staggered
-    requests, slot reuse, mixed buckets) retraces NOTHING — scheduling is
+    """The trace-count discipline, end to end: warm-up traces the step
+    exactly once; an admission/eviction churn run (staggered requests,
+    slot reuse, mixed prompt lengths) retraces NOTHING — scheduling is
     host-side by construction."""
     eng = DecodeEngine(params, num_heads=HEADS, num_slots=SLOTS,
-                       max_len=MAX_LEN, prefill_buckets=BUCKETS,
-                       name="trace_lm")
+                       max_len=MAX_LEN, name="trace_lm")
     assert eng.step_trace_count == 1           # exactly one warm-up trace
     rng = np.random.RandomState(4)
     with assert_no_retrace(lambda: eng.step_trace_count,
-                           "decode churn over the warm slab step"):
+                           "decode churn over the warm step"):
         bat = GenerationBatcher(eng, default_max_tokens=6)
         futs = [bat.submit(_prompt(rng), max_tokens=int(rng.randint(2, 9)))
                 for _ in range(10)]
         for f in futs:
             f.result(120)
         bat.close()
-    # prefill ladder discipline: one trace per (length bucket, batch
-    # bucket) executable, all paid at warm-up
-    for b, peng in eng._prefill_engines.items():
-        assert peng.trace_count == len(peng.buckets), (b, peng.trace_count)
+
+
+def test_default_engine_is_chunked_and_the_ladder_is_gone():
+    """``DecodeEngine(params)`` with no keywords is the engine the CLI
+    builds (slab, 8 lanes a step); asking for the ladder is an error that
+    says where it went."""
+    trunk = transformer.init(jax.random.PRNGKey(2), src_vocab=VOCAB,
+                             trg_vocab=1, d_model=D_MODEL, num_heads=8,
+                             dff=64, enc_layers=1, dec_layers=0,
+                             max_len=256)
+    eng = DecodeEngine(trunk)
+    assert (eng.prefill_chunk, eng.kv_layout) == (8, "slab")
+    assert eng.step_trace_count == 1 and eng.ready
+    prompt = np.arange(1, 21, dtype=np.int32)       # three chunks
+    bat = GenerationBatcher(eng)
+    got = bat.submit(prompt, max_tokens=4).result(60)["tokens"]
+    bat.close()
+    want = np.asarray(transformer.lm_generate(trunk, prompt[None],
+                                              max_len=256))
+    assert got == want[0, 20:24].tolist()
+    with pytest.raises(ConfigError, match="ladder, which is gone"):
+        DecodeEngine(trunk, prefill_chunk=0, warm=False)
+    with pytest.raises(ConfigError, match="prefill_chunk"):
+        DecodeEngine(trunk, prefill_chunk=257, warm=False)
 
 
 # ------------------------------------------------------------ admission
@@ -212,7 +236,6 @@ def test_validate_request_rejects_before_queue(engine):
     for bad, kw in [
         (np.zeros((2, 3), np.int32), {}),            # 2-D
         (np.zeros((0,), np.int32), {}),              # empty
-        (np.zeros((BUCKETS[-1] + 1,), np.int32), {}),  # past the ladder
         (np.zeros((3,), np.float32), {}),            # not ids
         (np.full((3,), VOCAB, np.int32), {}),        # out of vocab
         (ok, {"max_tokens": 0}),                     # no emission budget
@@ -225,8 +248,23 @@ def test_validate_request_rejects_before_queue(engine):
     bat.close()
 
 
+def test_prompt_bounded_by_max_len_alone(params, engine):
+    """Only ``max_len`` caps a prompt: one of several chunks, longer than
+    any prefill bucket the engine used to have, is served — and the
+    first length that cannot emit a token inside ``max_len`` is refused
+    before the queue."""
+    bat = GenerationBatcher(engine)
+    prompt = _prompt(np.random.RandomState(14), MAX_LEN - 3)
+    res = bat.submit(prompt, max_tokens=3).result(120)
+    assert res["tokens"] == _oracle(params, prompt, 3)
+    with pytest.raises(InvalidRequestError, match="max_len"):
+        bat.submit(_prompt(np.random.RandomState(15), MAX_LEN),
+                   max_tokens=1)
+    bat.close()
+
+
 def _stall_engine(engine, stall_s):
-    """Make each slab step slow — deterministic queue buildup."""
+    """Make each step slow — deterministic queue buildup."""
     orig = engine.step
 
     def slow():
@@ -286,7 +324,7 @@ def test_step_failure_isolated_and_engine_recovers(params, engine):
         victim.result(60)
     engine.step = orig
     res = bat.submit(prompt, max_tokens=6).result(60)
-    assert res["tokens"] == _oracle(params, engine, prompt, 6)
+    assert res["tokens"] == _oracle(params, prompt, 6)
     snap = engine.metrics.snapshot()
     assert snap["evictions"]["error"] >= 1
     assert snap["errors_total"] >= 1
@@ -294,23 +332,32 @@ def test_step_failure_isolated_and_engine_recovers(params, engine):
     bat.close()
 
 
-def test_prefill_failure_isolated(engine):
+def test_step_fault_mid_ingestion_isolated(params, engine):
+    """A device-step fault that hits while a prompt is still being
+    ingested (its second chunk of five) fails that step's requests alone
+    — nothing was emitted yet — and the engine recovers: the request
+    queued behind it is served with unchanged numerics."""
     engine.metrics = ServingMetrics()
     bat = GenerationBatcher(engine)
-    orig = engine.prefill
-
-    def boom(prompts, lengths):
-        raise RuntimeError("injected prefill failure")
-    engine.prefill = boom
-    try:
-        f = bat.submit(np.arange(1, 5, dtype=np.int32), max_tokens=3)
-        with pytest.raises(BatchExecutionError):
-            f.result(60)
-    finally:
-        engine.prefill = orig
-    ok = bat.submit(np.arange(1, 5, dtype=np.int32), max_tokens=3)
-    assert len(ok.result(60)["tokens"]) == 3
+    rng = np.random.RandomState(16)
+    long_prompt, prompt = _prompt(rng, 35), _prompt(rng, 5)
+    seen = []
+    faults.install_spec("serving.decode_step:at=2")
+    victim = bat.submit(long_prompt, max_tokens=3, on_token=seen.append)
+    with pytest.raises(BatchExecutionError, match="InjectedFault"):
+        victim.result(60)
+    assert faults.fired_counts() == {"serving.decode_step": 1}
+    assert seen == []                   # failed before its first token
+    snap = engine.metrics.snapshot()
+    assert 0 < snap["prefill_chunk_lanes_total"] < long_prompt.size - 1
+    assert snap["errors_total"] == 1
+    res = bat.submit(prompt, max_tokens=6).result(60)
+    assert res["tokens"] == _oracle(params, prompt, 6)
+    # and the victim's prompt itself is servable on the rebuilt cache
+    res = bat.submit(long_prompt, max_tokens=3).result(60)
+    assert res["tokens"] == _oracle(params, long_prompt, 3)
     bat.close()
+    assert engine.free_slots == SLOTS
 
 
 def test_abandon_reclaims_slot_midflight(engine):
@@ -360,24 +407,25 @@ def test_drain_finishes_queued_and_inflight(engine):
 
 
 @pytest.mark.parametrize("drain", [True, False])
-def test_close_during_inflight_prefill_resolves_submitter(engine, drain):
-    """The batcher-close-during-in-flight-prefill race: close() while a
-    prefill future is outstanding must RESOLVE the submitter (result on
-    drain=True, ShutdownError on drain=False) — never strand it.  The
-    worker is provably inside the prefill when close() lands."""
-    orig = engine.prefill
+def test_close_during_seating_resolves_submitter(engine, drain):
+    """The batcher-close-during-admission race: close() while a request
+    is inside the seating window (popped from the queue, not yet in a
+    slot) must RESOLVE the submitter (result on drain=True, ShutdownError
+    on drain=False) — never strand it.  The worker is provably inside
+    the seat when close() lands."""
+    orig = engine.seat_prefilled
     inside = threading.Event()
 
-    def slow(prompts, lengths):
+    def slow(fulls):
         inside.set()
         time.sleep(0.3)
-        return orig(prompts, lengths)
-    engine.prefill = slow
+        return orig(fulls)
+    engine.seat_prefilled = slow
     try:
         bat = GenerationBatcher(engine, default_max_tokens=4)
         rng = np.random.RandomState(13)
         fut = bat.submit(rng.randint(1, VOCAB, 4).astype(np.int32))
-        assert inside.wait(10)          # worker is mid-prefill NOW
+        assert inside.wait(10)          # worker is mid-seat NOW
         closer = threading.Thread(target=bat.close,
                                   kwargs={"drain": drain})
         closer.start()
@@ -387,10 +435,10 @@ def test_close_during_inflight_prefill_resolves_submitter(engine, drain):
             with pytest.raises((ShutdownError, BatchExecutionError)):
                 fut.result(30)          # resolved, not stranded
         closer.join(30)
-        assert not closer.is_alive(), "close() wedged on the prefill"
+        assert not closer.is_alive(), "close() wedged on the seat"
         assert engine.free_slots == SLOTS
     finally:
-        engine.prefill = orig
+        engine.seat_prefilled = orig
 
 
 def test_close_without_drain_fails_inflight_and_queued(engine):
@@ -438,8 +486,8 @@ def test_http_generate_plain_stream_and_faults(params, engine):
         status, raw = post({"prompt": prompt, "max_tokens": 6})
         plain = json.loads(raw)
         assert status == 200 and plain["finish_reason"] == "length"
-        assert plain["tokens"] == _oracle(params, engine,
-                                          np.asarray(prompt, np.int32), 6)
+        assert plain["tokens"] == _oracle(
+            params, np.asarray(prompt, np.int32), 6)
         assert plain["ttft_ms"] >= 0
 
         _, raw = post({"prompt": prompt, "max_tokens": 6, "stream": True})
@@ -483,35 +531,9 @@ def test_http_generate_plain_stream_and_faults(params, engine):
 
 
 @pytest.mark.slow
-def test_generation_load_sweep_continuous_beats_whole_batch():
-    """The bench acceptance property, asserted: under the serving-shaped
-    short/long mix at 8 closed-loop clients, continuous batching
-    out-throughputs the sequential whole-batch policy (same compiled
-    step, same prefill ladder) with a lower p99 TTFT, and really packs
-    the slab (occupancy > 1)."""
-    import importlib
-    import os as _os
-    import sys as _sys
-    _sys.path.insert(0, _os.path.dirname(_os.path.dirname(
-        _os.path.abspath(__file__))))
-    bench = importlib.import_module("bench")
-    built = bench.bench_serving_generate(slots=8, n_requests=48)
-    extras = built[4]
-    assert extras["mean_slot_occupancy"] > 1.0, extras
-    # the committed bench shows ~2.6x; assert with slack for loaded CI
-    assert extras["continuous_tokens_per_s"] \
-        > 1.5 * extras["gang_tokens_per_s"], extras
-    assert extras["continuous_ttft_p99_ms"] \
-        < extras["gang_ttft_p99_ms"], extras
-    # the analytic hook lowers without executing
-    assert extras["lower"]() is not None
-
-
-@pytest.mark.slow
 def test_generation_smoke_subprocess():
-    """`python -m paddle_tpu.serving --smoke-generate` — the
-    healthy_window.sh phase-8 command — passes end to end in a fresh
-    process."""
+    """`python -m paddle_tpu.serving --smoke-generate` passes end to end
+    in a fresh process."""
     import os
     import subprocess
     import sys

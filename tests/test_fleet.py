@@ -37,13 +37,12 @@ from paddle_tpu.serving import (DecodeEngine, GenerationBatcher,
                                 ReplicaSupervisor, Router, ServingMetrics,
                                 make_server)
 
-VOCAB, HEADS, MAX_LEN, SLOTS, BUCKETS = 64, 2, 48, 4, (8, 16)
+VOCAB, HEADS, MAX_LEN, SLOTS = 64, 2, 48, 4
 
 # the fleet replicas' demo-LM scale (server.py _demo_gen_batcher with the
 # flags below); the decode-step hang paces tokens so kills land MID-stream
 FLEET_VOCAB, FLEET_MAX_LEN, FLEET_TOKENS = 256, 64, 20
 FLEET_ARGS = ["--gen-slots", "4", "--gen-max-len", str(FLEET_MAX_LEN),
-              "--gen-prefill-buckets", "8,16",
               "--gen-max-tokens", str(FLEET_TOKENS),
               "--fault-spec",
               "serving.decode_step:every=1,action=hang,hang_s=0.02"]
@@ -67,8 +66,7 @@ def params():
 @pytest.fixture(scope="module")
 def engine(params):
     return DecodeEngine(params, num_heads=HEADS, num_slots=SLOTS,
-                        max_len=MAX_LEN, prefill_buckets=BUCKETS,
-                        name="fleet_lm")
+                        max_len=MAX_LEN, name="fleet_lm")
 
 
 @pytest.fixture(scope="module")
@@ -135,8 +133,7 @@ def test_replay_submit_bit_identical(engine):
     """The contract the router's failover rides on: submitting prompt +
     already-delivered replay tokens continues the greedy stream
     bit-identically, emitting only NEW tokens — including when the
-    combined context outgrows the prefill ladder top (re-prefill the
-    clamped prefix + teacher-forced replay)."""
+    combined context takes several chunks to ingest."""
     engine.metrics = ServingMetrics()
     bat = GenerationBatcher(engine)
     rng = np.random.RandomState(3)
@@ -148,7 +145,6 @@ def test_replay_submit_bit_identical(engine):
                                                         np.int32),
                               max_tokens=total - cut).result(120)
             assert cont["tokens"] == full[cut:], (size, cut)
-            # the (16, 1) case: context 17 > ladder top 16 — clamped
         with pytest.raises(Exception, match="replay"):
             bat.submit(np.asarray([1, 2], np.int32), replay=np.asarray(
                 [], np.int32), max_tokens=2).result(5)

@@ -254,7 +254,6 @@ REFUSED = {
     "kv_host_bytes": dict(kv_host_bytes=1 << 20),
     "mesh": dict(mesh=object()),
     "slab": dict(kv_layout="slab"),
-    "ladder": dict(prefill_chunk=0),
 }
 
 
@@ -268,7 +267,7 @@ def test_state_holding_model_refuses(hf, params, what):
     model = hybrid_lm.Served(hybrid_lm.config_from_hf(hf))
     with pytest.raises(ConfigError) as e:
         DecodeEngine(params, model=model, **kw)
-    needle = {"slab": "paged layout", "ladder": "chunked step",
+    needle = {"slab": "paged layout",
               "mesh": "placement rule"}.get(what, "state snapshot")
     assert needle in str(e.value)
 

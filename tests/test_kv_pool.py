@@ -1,13 +1,13 @@
 """Paged KV cache (serving/kv_pool.py + DecodeEngine kv_layout="paged").
 
 The correctness bar is the slab's own: every greedy stream served
-through the paged layout — block-pool admission, prefix-cache seating,
+through the paged layout — chunked ingestion, prefix-cache seating,
 copy-on-write forks, pool-pressure preemption and re-seat, supervisor
 recovery, continuation replay — must be BIT-IDENTICAL to the
 single-request oracle (``models/transformer.lm_generate``) and hence to
 the slab layout.  Trace discipline: ONE warm-up trace for the paged
-step (plus one block-write and one block-fork executable), ZERO traces
-across any block-table churn — the table is data, not shape.
+step (plus one block-fork executable), ZERO traces across any
+block-table churn — the table is data, not shape.
 
 The allocator's refcount ledger (``PagedKVState.check``: every block's
 refcount equals its slot-chain + prefix-index references; the free list
@@ -35,7 +35,7 @@ from paddle_tpu.testing import assert_no_retrace
 from paddle_tpu.utils.error import ConfigError
 
 VOCAB, D_MODEL, LAYERS, HEADS = 64, 32, 2, 2
-MAX_LEN, SLOTS, BUCKETS, BS = 48, 4, (8, 16), 8
+MAX_LEN, SLOTS, PROMPT_TOP, BS = 48, 4, 16, 8
 
 
 @pytest.fixture(autouse=True)
@@ -57,24 +57,24 @@ def engine(params):
     """Auto-sized pool (the slab-equivalent byte budget), prefix cache
     on — the default paged configuration."""
     return DecodeEngine(params, num_heads=HEADS, num_slots=SLOTS,
-                        max_len=MAX_LEN, prefill_buckets=BUCKETS,
-                        name="paged_lm", kv_layout="paged",
+                        max_len=MAX_LEN, name="paged_lm", kv_layout="paged",
                         kv_block_size=BS)
 
 
 def _prompt(rng, n=None):
-    return rng.randint(1, VOCAB, n or rng.randint(3, BUCKETS[-1] + 1)
+    return rng.randint(1, VOCAB, n or rng.randint(3, PROMPT_TOP + 1)
                        ).astype(np.int32)
 
 
-def _oracle(params, engine, prompt, n_tokens, eos_id=None):
-    """Single-request greedy lm_generate at the engine's prefill bucket
-    (same composition the slab parity tests pin)."""
-    bucket = engine.prefill_bucket_for(prompt.size)
-    padded = np.zeros((1, bucket), np.int32)
+def _oracle(params, prompt, n_tokens, eos_id=None):
+    """Single-request greedy lm_generate at the engine's cache width
+    (the prompt padded to a multiple of PROMPT_TOP: a shape or two to
+    compile, and lm_generate ignores the pad)."""
+    width = -(-prompt.size // PROMPT_TOP) * PROMPT_TOP
+    padded = np.zeros((1, width), np.int32)
     padded[0, :prompt.size] = prompt
     ids = np.asarray(transformer.lm_generate(
-        params, padded, max_len=engine.max_len, num_heads=HEADS,
+        params, padded, max_len=MAX_LEN, num_heads=HEADS,
         eos_id=eos_id, prompt_lengths=np.asarray([prompt.size])))
     return ids[0, prompt.size:prompt.size + n_tokens].tolist()
 
@@ -218,7 +218,7 @@ def test_paged_staggered_admissions_bit_identical_to_lm_generate(
     assert all(e is None for e in excs), excs
     for (prompt, n), res in zip(cases, results):
         assert res["finish_reason"] == "length"
-        assert res["tokens"] == _oracle(params, engine, prompt, n), \
+        assert res["tokens"] == _oracle(params, prompt, n), \
             f"prompt len {prompt.size}, n {n}"
     snap = engine.metrics.snapshot()
     assert snap["evictions"]["length"] == 12
@@ -230,8 +230,9 @@ def test_prefix_cache_hit_and_cow_fork_bit_identical(params, engine):
     """Prefix sharing end to end: a leader registers a 1.5-block system
     prompt; an EXACT duplicate then seats inside the shared tail block
     (copy-on-write fork on its first write) and a divergent prompt
-    seats on the shared aligned block — both by reference, neither
-    re-prefilled, all three streams bit-identical to the oracle."""
+    seats on the shared aligned block — both by reference, only the
+    uncovered suffix ingested, all three streams bit-identical to the
+    oracle."""
     engine.metrics = ServingMetrics()
     rng = np.random.RandomState(2)
     sys_prompt = _prompt(rng, BS + BS // 2)
@@ -244,13 +245,16 @@ def test_prefix_cache_hit_and_cow_fork_bit_identical(params, engine):
     div = bat.submit(divergent, max_tokens=6).result(60)
     bat.close()
     assert lead["tokens"] == dup["tokens"] \
-        == _oracle(params, engine, sys_prompt, 6)
-    assert div["tokens"] == _oracle(params, engine, divergent, 6)
+        == _oracle(params, sys_prompt, 6)
+    assert div["tokens"] == _oracle(params, divergent, 6)
     snap = engine.metrics.snapshot()
     assert snap["prefix_cache_hits_total"] == 2
     assert snap["cow_forks_total"] >= 1
-    # the hits never touched the prefill ladder
-    assert engine.prefill_positions_total - pre0 == prefilled_lead
+    # the hits ingested their uncovered suffix alone: nothing for the
+    # duplicate, the 4 divergent tokens for the other
+    assert prefilled_lead == sys_prompt.size
+    assert engine.prefill_positions_total - pre0 \
+        == prefilled_lead + divergent.size - BS
     _audit(engine)
 
 
@@ -258,8 +262,7 @@ def test_paged_equals_slab_layout_token_for_token(params, engine):
     """The two memory layouts are one compiled trunk: the same prompts
     through a slab engine produce byte-identical streams."""
     slab = DecodeEngine(params, num_heads=HEADS, num_slots=SLOTS,
-                        max_len=MAX_LEN, prefill_buckets=BUCKETS,
-                        name="slab_twin")
+                        max_len=MAX_LEN, name="slab_twin")
     rng = np.random.RandomState(3)
     cases = [(_prompt(rng), 7) for _ in range(6)]
     engine.metrics = ServingMetrics()
@@ -277,9 +280,9 @@ def test_paged_equals_slab_layout_token_for_token(params, engine):
 def test_prefix_cache_off_still_bit_identical(params):
     """kv_layout="paged" with prefix_cache=False: pure block packing,
     no sharing — parity and the ledger still hold, and duplicates
-    re-prefill (zero hits by construction)."""
+    re-ingest (zero hits by construction)."""
     eng = DecodeEngine(params, num_heads=HEADS, num_slots=SLOTS,
-                       max_len=MAX_LEN, prefill_buckets=BUCKETS,
+                       max_len=MAX_LEN,
                        name="paged_nocache", kv_layout="paged",
                        kv_block_size=BS, prefix_cache=False)
     eng.metrics = ServingMetrics()
@@ -289,7 +292,7 @@ def test_prefix_cache_off_still_bit_identical(params):
     a = bat.submit(p, max_tokens=5).result(60)
     b = bat.submit(p, max_tokens=5).result(60)
     bat.close()
-    assert a["tokens"] == b["tokens"] == _oracle(params, eng, p, 5)
+    assert a["tokens"] == b["tokens"] == _oracle(params, p, 5)
     snap = eng.metrics.snapshot()
     assert snap["prefix_cache_hits_total"] == 0
     assert eng._paged.pool.num_used == 0
@@ -315,8 +318,7 @@ def test_pool_pressure_preemption_recovers_bit_identical(params):
     serialize the clients — requests finished before pressure ever
     built, and the preemption asserts below flaked."""
     eng = DecodeEngine(params, num_heads=HEADS, num_slots=SLOTS,
-                       max_len=MAX_LEN, prefill_buckets=BUCKETS,
-                       name="paged_tight", kv_layout="paged",
+                       max_len=MAX_LEN, name="paged_tight", kv_layout="paged",
                        kv_block_size=BS, kv_num_blocks=10)
     eng.metrics = ServingMetrics()
     bat = GenerationBatcher(eng, default_max_tokens=8)
@@ -326,12 +328,12 @@ def test_pool_pressure_preemption_recovers_bit_identical(params):
     # allocatable-block budget's requests seat concurrently and their
     # growth to 12 wanted blocks guarantees mid-decode preemption —
     # regardless of how fast the worker runs relative to this thread
-    cases = [(_prompt(rng, BUCKETS[-1]), 16) for _ in range(6)]
+    cases = [(_prompt(rng, PROMPT_TOP), 16) for _ in range(6)]
     futs = [bat.submit(p, max_tokens=n) for p, n in cases]
     results = [f.result(300) for f in futs]
     bat.close()
     for (prompt, n), res in zip(cases, results):
-        assert res["tokens"] == _oracle(params, eng, prompt, n)
+        assert res["tokens"] == _oracle(params, prompt, n)
     snap = eng.metrics.snapshot()
     assert snap["evictions"]["pool_exhausted"] >= 1, snap
     assert snap["slot_reprefills_total"] >= 1, snap
@@ -344,8 +346,7 @@ def test_request_that_cannot_fit_pool_rejected_up_front(params):
     submit (the preemption path could never make room), while the same
     request fits the auto-sized pool."""
     eng = DecodeEngine(params, num_heads=HEADS, num_slots=SLOTS,
-                       max_len=MAX_LEN, prefill_buckets=BUCKETS,
-                       name="paged_small", kv_layout="paged",
+                       max_len=MAX_LEN, name="paged_small", kv_layout="paged",
                        kv_block_size=BS, kv_num_blocks=3)
     bat = GenerationBatcher(eng)
     with pytest.raises(InvalidRequestError, match="KV blocks"):
@@ -357,26 +358,31 @@ def test_request_that_cannot_fit_pool_rejected_up_front(params):
 
 
 def test_one_warmup_trace_zero_retraces_under_block_churn(params):
-    """Warm-up traces the paged step exactly once (plus ONE block-write
-    and ONE block-fork executable — no per-bucket admission ladder);
-    then a churn run covering admission, prefix-cache seating, CoW
-    forks, pool-pressure preemption and re-seat retraces NOTHING: the
-    block table is data, not shape."""
+    """Warm-up traces the paged step exactly once (plus ONE block-fork
+    executable; prompt writes ride the step, and the block write only
+    exists for host-tier restores); then a churn run covering admission,
+    prefix-cache seating, CoW forks, pool-pressure preemption and
+    re-seat retraces NOTHING: the block table is data, not shape."""
     eng = DecodeEngine(params, num_heads=HEADS, num_slots=SLOTS,
-                       max_len=MAX_LEN, prefill_buckets=BUCKETS,
-                       name="paged_trace", kv_layout="paged",
+                       max_len=MAX_LEN, name="paged_trace",
+                       kv_layout="paged",
                        kv_block_size=BS, kv_num_blocks=12)
     assert eng.step_trace_count == 1
-    assert eng._write_traces[0] == 1 and eng._copy_traces[0] == 1
+    assert (eng._write_traces[0], eng._copy_traces[0]) == (0, 1)
     rng = np.random.RandomState(6)
     shared = _prompt(rng, BS + 2)
     with assert_no_retrace(lambda: eng.step_trace_count
                            + eng._write_traces[0] + eng._copy_traces[0],
                            "paged block churn (admit/CoW/preempt)"):
         bat = GenerationBatcher(eng, default_max_tokens=10)
-        cases = [(shared, 10), (shared, 10)]    # prefix hit + CoW fork
-        cases += [(_prompt(rng, BUCKETS[-1]), 12) for _ in range(4)]
-        results, excs = _drive(bat, cases)
+        # the leader alone first (a chain is published at its prompt's
+        # first token), then its duplicate — prefix hit + CoW fork —
+        # among the churners
+        cases = [(shared, 10)]
+        cases += [(_prompt(rng, PROMPT_TOP), 12) for _ in range(4)]
+        results, excs = _drive(bat, [(shared, 10)])
+        more, more_excs = _drive(bat, cases)
+        results, excs = results + more, excs + more_excs
         bat.close()
     assert all(e is None for e in excs), excs
     snap = eng.metrics.snapshot()
@@ -396,7 +402,7 @@ def test_supervisor_recovery_on_paged_engine_bit_identical(params, engine):
     engine.metrics = ServingMetrics()
     rng = np.random.RandomState(7)
     cases = [(_prompt(rng), 4 + (i % 5)) for i in range(8)]
-    ref = [_oracle(params, engine, p, n) for p, n in cases]
+    ref = [_oracle(params, p, n) for p, n in cases]
     sup = Supervisor(breaker_threshold=10)
     bat = GenerationBatcher(engine, supervisor=sup)
     faults.install_spec("serving.decode_step:at=6")
@@ -419,14 +425,14 @@ def test_continuation_replay_on_paged_engine_bit_identical(params, engine):
     paged layout: a stream interrupted after k delivered tokens finishes
     through a paged engine emitting ONLY the remaining tokens, and the
     concatenation equals the uninterrupted oracle — including when the
-    replay context is longer than the prefill ladder top."""
+    replay context takes several chunks to ingest."""
     engine.metrics = ServingMetrics()
     rng = np.random.RandomState(8)
     bat = GenerationBatcher(engine)
-    for plen, n, k in ((6, 10, 3), (BUCKETS[-1], 12, 7),
-                       (BUCKETS[-1], 24, 14)):   # 16+14 > ladder top
+    for plen, n, k in ((6, 10, 3), (PROMPT_TOP, 12, 7),
+                       (PROMPT_TOP, 24, 14)):   # 30 tokens: 4 chunks
         prompt = _prompt(rng, plen)
-        full = _oracle(params, engine, prompt, n)
+        full = _oracle(params, prompt, n)
         res = bat.submit(prompt, replay=np.asarray(full[:k], np.int32),
                          max_tokens=n - k).result(60)
         assert res["tokens"] == full[k:], (plen, n, k)
@@ -440,8 +446,7 @@ def test_continuation_replay_on_paged_engine_bit_identical(params, engine):
 def test_paged_config_validation_and_auto_sizing(params):
     blocks_per_row = -(-MAX_LEN // BS)
     eng = DecodeEngine(params, num_heads=HEADS, num_slots=SLOTS,
-                       max_len=MAX_LEN, prefill_buckets=BUCKETS,
-                       name="paged_auto", kv_layout="paged",
+                       max_len=MAX_LEN, name="paged_auto", kv_layout="paged",
                        kv_block_size=BS, kv_num_blocks=0, warm=False)
     # auto-size = the slab-equivalent KV bytes + the scratch block
     assert eng._paged.pool.num_blocks == SLOTS * blocks_per_row + 1

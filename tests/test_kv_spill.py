@@ -59,8 +59,7 @@ def params():
 def spill_eng(params):
     """Tiny-pool chunked paged engine with the host tier attached."""
     return DecodeEngine(params, num_heads=HEADS, num_slots=SLOTS,
-                        max_len=MAX_LEN, prefill_buckets=(8, 16),
-                        name="spill_lm", kv_layout="paged",
+                        max_len=MAX_LEN, name="spill_lm", kv_layout="paged",
                         kv_block_size=BS, kv_num_blocks=POOL_BLOCKS,
                         prefill_chunk=CHUNK, kv_host_bytes=64 << 20)
 
@@ -69,8 +68,7 @@ def spill_eng(params):
 def twin_eng(params):
     """The cold-recompute twin: same trunk, same tiny pool, no tier."""
     return DecodeEngine(params, num_heads=HEADS, num_slots=SLOTS,
-                        max_len=MAX_LEN, prefill_buckets=(8, 16),
-                        name="spill_twin", kv_layout="paged",
+                        max_len=MAX_LEN, name="spill_twin", kv_layout="paged",
                         kv_block_size=BS, kv_num_blocks=POOL_BLOCKS,
                         prefill_chunk=CHUNK)
 
@@ -193,19 +191,16 @@ def test_engine_config_validation(params):
     ):
         with pytest.raises(ConfigError, match=match):
             DecodeEngine(params, num_heads=HEADS, num_slots=SLOTS,
-                         max_len=MAX_LEN, prefill_buckets=(8, 16),
-                         name="bad_spill", kv_block_size=BS,
+                         max_len=MAX_LEN, name="bad_spill", kv_block_size=BS,
                          prefill_chunk=CHUNK, warm=False, **kw)
 
 
 def test_restore_vs_recompute_routing_directions(params):
     """The analytic router (perf/analytic.predicted_restore_ms vs
     predicted_recompute_ms, consulted at seat time) must favor RESTORE
-    for a multi-block prefix and RECOMPUTE for a sub-chunk one — the
-    same both-directions gate the serving_kv_spill bench enforces."""
+    for a multi-block prefix and RECOMPUTE for a sub-chunk one."""
     eng = DecodeEngine(params, num_heads=HEADS, num_slots=SLOTS,
-                       max_len=MAX_LEN, prefill_buckets=(8, 16),
-                       name="route_lm", kv_layout="paged",
+                       max_len=MAX_LEN, name="route_lm", kv_layout="paged",
                        kv_block_size=BS, prefill_chunk=CHUNK,
                        kv_host_bytes=1 << 20, warm=False)
     long_v, long_r, long_c = eng._restore_predicted_faster(4 * BS)
@@ -348,8 +343,7 @@ def test_spill_storm_staggered_admissions_bit_identical(params, seed):
                              trg_vocab=1, d_model=D_MODEL,
                              num_heads=HEADS, dff=64, enc_layers=LAYERS,
                              dec_layers=0, max_len=MAX_LEN),
-            num_heads=HEADS, num_slots=SLOTS, max_len=MAX_LEN,
-            prefill_buckets=(8, 16), name=name, kv_layout="paged",
+            num_heads=HEADS, num_slots=SLOTS, max_len=MAX_LEN, name=name, kv_layout="paged",
             kv_block_size=BS, kv_num_blocks=POOL_BLOCKS,
             prefill_chunk=CHUNK, kv_host_bytes=host_bytes)
 

@@ -293,8 +293,7 @@ def cold_engine():
                               num_heads=HEADS, dff=64, enc_layers=LAYERS,
                               dec_layers=0, max_len=MAX_LEN)
     return DecodeEngine(params, num_heads=HEADS, num_slots=2,
-                        max_len=MAX_LEN, prefill_buckets=(8,),
-                        name="transfer_cold", warm=False,
+                        max_len=MAX_LEN, name="transfer_cold", warm=False,
                         kv_layout="paged", kv_block_size=BS,
                         kv_num_blocks=2 * (MAX_LEN // BS) + 1,
                         prefill_chunk=BS, kv_host_bytes=64 << 20)
@@ -345,8 +344,7 @@ def test_deliver_chain_blob_needs_host_tier():
                               num_heads=HEADS, dff=64, enc_layers=LAYERS,
                               dec_layers=0, max_len=MAX_LEN)
     eng = DecodeEngine(params, num_heads=HEADS, num_slots=2,
-                       max_len=MAX_LEN, prefill_buckets=(8,),
-                       name="transfer_tierless", warm=False,
+                       max_len=MAX_LEN, name="transfer_tierless", warm=False,
                        kv_layout="paged", kv_block_size=BS,
                        kv_num_blocks=13, prefill_chunk=BS)
     with pytest.raises(ConfigError, match="kv_host_bytes"):
@@ -374,7 +372,6 @@ def test_cross_process_handoff_bit_identical_exact_counters():
 
     n_tokens, max_len, bs, plen = 12, 64, 8, 32
     extra = ["--gen-slots", "4", "--gen-max-len", str(max_len),
-             "--gen-prefill-buckets", "8,16",
              "--gen-max-tokens", str(n_tokens),
              "--prefill-chunk", str(bs),
              "--kv-layout", "paged", "--kv-block-size", str(bs),

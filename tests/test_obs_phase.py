@@ -141,9 +141,9 @@ def _lm_params():
 
 
 @pytest.mark.parametrize("engine_kw", [
-    dict(prefill_buckets=(4, 8)),
+    dict(prefill_chunk=4),
     dict(kv_layout="paged", kv_block_size=8, prefill_chunk=4),
-], ids=["slab_ladder", "paged_chunked"])
+], ids=["slab", "paged"])
 def test_generation_loop_one_phase_sequence_per_counted_step(engine_kw):
     from paddle_tpu.serving.decode_engine import (DecodeEngine,
                                                   GenerationBatcher)
@@ -177,15 +177,14 @@ def test_generation_loop_one_phase_sequence_per_counted_step(engine_kw):
         assert it["t_start"] <= rows[1]["t_start"] \
             and rows[-1]["t_end"] <= it["t_end"]    # all inside the iter
     disp = next(p for p in phases if p["name"] == "engine.step.dispatch")
-    host_args = 2 + ("prefill_chunk" in engine_kw) \
-        + (engine_kw.get("kv_layout") == "paged")
-    assert disp["attrs"]["host_args"] == host_args
+    # tokens, positions, lane counts (+ the block tables)
+    assert disp["attrs"]["host_args"] == \
+        3 + (engine_kw.get("kv_layout") == "paged")
     assert disp["attrs"]["host_arg_bytes"] > 0
     emitted = sum(p["attrs"]["emitted"] for p in phases
                   if p["name"] == "gen.loop.emit")
-    # the ladder path delivers each request's first token at admission
-    at_admission = 0 if "prefill_chunk" in engine_kw else 3
-    assert emitted == engine.metrics.gen_tokens_total - at_admission
+    # every token, the first included, is delivered by an emit phase
+    assert emitted == engine.metrics.gen_tokens_total
     assert sum(p["attrs"]["finished"] for p in phases
                if p["name"] == "gen.loop.emit") == 3
     # waiting for work lies outside every iteration
@@ -204,8 +203,7 @@ def test_debug_traces_endpoint_carries_phases():
                                                   GenerationBatcher)
     from paddle_tpu.serving.server import make_server
     engine = DecodeEngine(_lm_params(), num_heads=2, num_slots=2,
-                          max_len=32, prefill_buckets=(4, 8),
-                          name="obs_phase_http")
+                          max_len=32, name="obs_phase_http")
     trace.enable(sample=1.0, capacity=256, process="unit")
     gen = GenerationBatcher(engine, default_max_tokens=2)
     httpd = make_server(None, port=0, gen_batcher=gen)

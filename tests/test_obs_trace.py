@@ -245,9 +245,9 @@ def test_tracing_enabled_adds_zero_jit_traces():
                               dff=32, enc_layers=1, dec_layers=0,
                               max_len=32)
     # warm up with tracing DISABLED, then serve with it ENABLED: the
-    # compiled step/admit/prefill surfaces must not trace again
+    # compiled step must not trace again
     engine = DecodeEngine(params, num_heads=2, num_slots=2, max_len=32,
-                          prefill_buckets=(4, 8), name="obs_nr")
+                          name="obs_nr")
     trace.enable(sample=1.0, capacity=256, process="unit")
     gen = GenerationBatcher(engine, default_max_tokens=4)
     try:
@@ -270,7 +270,7 @@ def test_tracing_enabled_adds_zero_jit_traces():
     assert all(s["attrs"]["reason"] == "length" for s in slots)
     assert all(s["attrs"]["tokens"] == 4 for s in slots)
     # no span leaked into the live registry: every started span ended
-    # (rejected submits, finished requests, prefill batches alike)
+    # (rejected submits and finished requests alike)
     assert trace.get_tracer()._active == {}
 
 
@@ -290,7 +290,7 @@ def test_propagation_across_router_and_replica_subprocess(tmp_path):
 
     trace.enable(sample=1.0, capacity=1024, process="router")
     extra = ["--gen-slots", "2", "--gen-max-len", "48",
-             "--gen-prefill-buckets", "8,16", "--gen-max-tokens", "6",
+             "--gen-max-tokens", "6",
              "--obs-trace", "1"]
     sup = ReplicaSupervisor(n_replicas=1, extra_args=extra, seed=0,
                             name="obs_prop")

@@ -5,7 +5,9 @@ slab stripe kernel and the block-table-walking paged kernel — against
 the reference XLA paths in ``models/transformer``:
 
 * kernel numerics vs ``_attend`` / the chain-gather path (allclose,
-  incl. grouped-KV head layouts);
+  incl. grouped-KV head layouts), as one-lane calls (K = 1: the paged
+  kernel in its block-a-grid-step form AND its tiled form) and at the
+  serving step's K lanes;
 * masked-width semantics at block boundaries (a position on the last
   slot of a block must not read the next block);
 * the reserved scratch block 0 is NEVER attended by an active row
@@ -42,7 +44,7 @@ from paddle_tpu.serving.decode_engine import DecodeEngine
 from paddle_tpu.testing import assert_no_retrace
 
 VOCAB, D_MODEL, LAYERS, HEADS = 64, 32, 2, 2
-MAX_LEN, SLOTS, BUCKETS, BS = 48, 4, (8, 16), 8
+MAX_LEN, SLOTS, PROMPT_TOP, BS = 48, 4, 16, 8
 
 
 @pytest.fixture(autouse=True)
@@ -65,8 +67,7 @@ def slab_engine(params):
     read at trace time = warm-up; later drives run the baked step)."""
     with dk.forced_mode("always"):
         eng = DecodeEngine(params, num_heads=HEADS, num_slots=SLOTS,
-                           max_len=MAX_LEN, prefill_buckets=BUCKETS,
-                           name="kern_slab")
+                           max_len=MAX_LEN, name="kern_slab")
     assert eng.decode_kernels
     return eng
 
@@ -75,7 +76,7 @@ def slab_engine(params):
 def paged_engine(params):
     with dk.forced_mode("always"):
         eng = DecodeEngine(params, num_heads=HEADS, num_slots=SLOTS,
-                           max_len=MAX_LEN, prefill_buckets=BUCKETS,
+                           max_len=MAX_LEN,
                            name="kern_paged", kv_layout="paged",
                            kv_block_size=BS)
     assert eng.decode_kernels
@@ -83,19 +84,21 @@ def paged_engine(params):
 
 
 def _prompt(rng, n=None):
-    return rng.randint(1, VOCAB, n or rng.randint(3, BUCKETS[-1] + 1)
+    return rng.randint(1, VOCAB, n or rng.randint(3, PROMPT_TOP + 1)
                        ).astype(np.int32)
 
 
-def _oracle(params, engine, prompt, n_tokens):
+def _oracle(params, prompt, n_tokens):
     """Single-request greedy lm_generate — runs the REFERENCE XLA path
     (kernels are off outside forced_mode on CPU), so engine-vs-oracle
-    equality crosses the kernel/reference boundary."""
-    bucket = engine.prefill_bucket_for(prompt.size)
-    padded = np.zeros((1, bucket), np.int32)
+    equality crosses the kernel/reference boundary.  (The prompt is
+    padded to a multiple of PROMPT_TOP: a shape or two to compile, and
+    lm_generate ignores the pad.)"""
+    width = -(-prompt.size // PROMPT_TOP) * PROMPT_TOP
+    padded = np.zeros((1, width), np.int32)
     padded[0, :prompt.size] = prompt
     ids = np.asarray(transformer.lm_generate(
-        params, padded, max_len=engine.max_len, num_heads=HEADS,
+        params, padded, max_len=MAX_LEN, num_heads=HEADS,
         prompt_lengths=np.asarray([prompt.size])))
     return ids[0, prompt.size:prompt.size + n_tokens].tolist()
 
@@ -132,6 +135,41 @@ def _ref_slab(q, k, v, positions, num_heads):
         num_heads, jnp.broadcast_to(pm, (q.shape[0], t))))[:, 0]
 
 
+def _slab_lane(q, k, v, pos, h):
+    """A one-lane call of the slab kernel: q [S, D], pos [S] -> [S, D]."""
+    with dk.forced_mode("always"):
+        out = dk.maybe_slab_chunk(jnp.asarray(q)[:, None], jnp.asarray(k),
+                                  jnp.asarray(v), jnp.asarray(pos)[:, None],
+                                  h)
+    assert out is not None
+    return np.asarray(out)[:, 0]
+
+
+@pytest.fixture(params=["block_a_step", "tiled"])
+def paged_lane(request, monkeypatch):
+    """A one-lane call of the paged kernel, q [S, D], pos [S] -> [S, D],
+    in both of its forms: G = 1 (a table entry a grid step) and the tile
+    ``paged_chunk_tile`` picks for a single lane (here the whole table)."""
+    tiled = request.param == "tiled"
+    if not tiled:
+        monkeypatch.setattr(dk, "paged_chunk_tile", lambda *a, **k: 1)
+    calls = []
+    real = dk._paged_chunk_tiled
+    monkeypatch.setattr(dk, "_paged_chunk_tiled",
+                        lambda *a, **k: calls.append(k["g"]) or real(*a, **k))
+
+    def call(q, kp, vp, pos, tables, h):
+        del calls[:]
+        with dk.forced_mode("always"):
+            out = dk.maybe_paged_chunk(
+                jnp.asarray(q)[:, None], jnp.asarray(kp), jnp.asarray(vp),
+                jnp.asarray(pos)[:, None], jnp.asarray(tables), h)
+        assert out is not None
+        assert bool(calls) == tiled and all(g > 1 for g in calls), calls
+        return np.asarray(out)[:, 0]
+    return call
+
+
 @pytest.mark.parametrize("h,hkv,dh,t", [(2, 2, 16, 24), (4, 2, 8, 48),
                                         (4, 1, 32, 16), (2, 2, 64, 130)])
 def test_slab_kernel_matches_attend(h, hkv, dh, t):
@@ -141,11 +179,7 @@ def test_slab_kernel_matches_attend(h, hkv, dh, t):
     k = rng.randn(s, t, dkv).astype(np.float32)
     v = rng.randn(s, t, dkv).astype(np.float32)
     pos = rng.randint(0, t, s).astype(np.int32)
-    with dk.forced_mode("always"):
-        out = dk.maybe_slab(jnp.asarray(q), jnp.asarray(k),
-                            jnp.asarray(v), jnp.asarray(pos), h)
-    assert out is not None
-    np.testing.assert_allclose(np.asarray(out),
+    np.testing.assert_allclose(_slab_lane(q, k, v, pos, h),
                                _ref_slab(q, k, v, pos, h),
                                rtol=1e-5, atol=1e-5)
 
@@ -176,23 +210,18 @@ def _ref_paged(q, kp, vp, pos, tables, num_heads):
 
 
 @pytest.mark.parametrize("h,hkv,dh,bs", [(2, 2, 16, 8), (4, 2, 8, 4)])
-def test_paged_kernel_matches_chain_gather(h, hkv, dh, bs):
+def test_paged_kernel_matches_chain_gather(paged_lane, h, hkv, dh, bs):
     rng = np.random.RandomState(h * 10 + bs)
     s, nb_row = 4, 3
     d, dkv = h * dh, hkv * dh
     q, kp, vp, pos, tables, _t = _paged_setup(rng, s, 13, bs, nb_row,
                                               dkv, d)
-    with dk.forced_mode("always"):
-        out = dk.maybe_paged(jnp.asarray(q), jnp.asarray(kp),
-                             jnp.asarray(vp), jnp.asarray(pos),
-                             jnp.asarray(tables), h)
-    assert out is not None
-    np.testing.assert_allclose(np.asarray(out),
+    np.testing.assert_allclose(paged_lane(q, kp, vp, pos, tables, h),
                                _ref_paged(q, kp, vp, pos, tables, h),
                                rtol=1e-5, atol=1e-5)
 
 
-def test_block_boundary_positions():
+def test_block_boundary_positions(paged_lane):
     """Masked-width semantics at the block seams: a row whose position
     sits on the LAST slot of a block (p % bs == bs-1) must attend that
     whole block and nothing of the next; the first slot of a block
@@ -206,25 +235,18 @@ def test_block_boundary_positions():
     from paddle_tpu.testing.kernel_smoke import build_private_tables
     pos = np.asarray([bs - 1, bs, 2 * bs - 1, 0], np.int32)
     tables = build_private_tables(pos, nb_row, bs, 13)
-    with dk.forced_mode("always"):
-        out = dk.maybe_paged(jnp.asarray(q), jnp.asarray(kp),
-                             jnp.asarray(vp), jnp.asarray(pos),
-                             jnp.asarray(tables), h)
-    np.testing.assert_allclose(np.asarray(out),
+    np.testing.assert_allclose(paged_lane(q, kp, vp, pos, tables, h),
                                _ref_paged(q, kp, vp, pos, tables, h),
                                rtol=1e-5, atol=1e-5)
     # slab twin at the same boundary positions
     ks = rng.randn(s, t, dkv).astype(np.float32)
     vs = rng.randn(s, t, dkv).astype(np.float32)
-    with dk.forced_mode("always"):
-        out_s = dk.maybe_slab(jnp.asarray(q), jnp.asarray(ks),
-                              jnp.asarray(vs), jnp.asarray(pos), h)
-    np.testing.assert_allclose(np.asarray(out_s),
+    np.testing.assert_allclose(_slab_lane(q, ks, vs, pos, h),
                                _ref_slab(q, ks, vs, pos, h),
                                rtol=1e-5, atol=1e-5)
 
 
-def test_scratch_block_rows_never_attended():
+def test_scratch_block_rows_never_attended(paged_lane):
     """Poison the reserved scratch block 0 with NaN: every ACTIVE row's
     output must be bit-identical to the clean-pool kernel run — the
     clamped table walk never even addresses block 0 for a row that owns
@@ -234,19 +256,13 @@ def test_scratch_block_rows_never_attended():
     d = dkv = h * dh
     q, kp, vp, pos, tables, _t = _paged_setup(rng, 6, 19, bs, nb_row,
                                               dkv, d)
-    with dk.forced_mode("always"):
-        clean = dk.maybe_paged(jnp.asarray(q), jnp.asarray(kp),
-                               jnp.asarray(vp), jnp.asarray(pos),
-                               jnp.asarray(tables), h)
-        kp2, vp2 = kp.copy(), vp.copy()
-        kp2[0] = np.nan
-        vp2[0] = np.nan
-        poisoned = dk.maybe_paged(jnp.asarray(q), jnp.asarray(kp2),
-                                  jnp.asarray(vp2), jnp.asarray(pos),
-                                  jnp.asarray(tables), h)
-    np.testing.assert_array_equal(np.asarray(poisoned),
-                                  np.asarray(clean))
-    assert np.all(np.isfinite(np.asarray(poisoned)))
+    clean = paged_lane(q, kp, vp, pos, tables, h)
+    kp2, vp2 = kp.copy(), vp.copy()
+    kp2[0] = np.nan
+    vp2[0] = np.nan
+    poisoned = paged_lane(q, kp2, vp2, pos, tables, h)
+    np.testing.assert_array_equal(poisoned, clean)
+    assert np.all(np.isfinite(poisoned))
 
 
 # ------------------------------------------------ the paged chunk tile
@@ -401,11 +417,12 @@ def test_paged_chunk_tile_rule(monkeypatch):
 def test_tile_positions_names_each_kernels_step(slab_engine, paged_engine):
     """What ``DecodeEngine.warmup`` logs beside the resolved path: the
     K/V positions one step of the serving kernel covers — the slab
-    kernels' k-tile, a pool block at Tq=1, G blocks at Tq=chunk — on the
-    per-chip stripe, like ``decline_reason``."""
+    kernel's k-tile, G pool blocks for the paged one (a one-lane call
+    included) — on the per-chip stripe, like ``decline_reason``."""
     assert dk.tile_positions(2, 32, 32, 48) == 48
     assert dk.tile_positions(2, 32, 32, 1024) == 512     # the flag's cap
-    assert dk.tile_positions(2, 32, 32, 8, nb_row=6, paged=True) == 8
+    # a table of 6 blocks of 8 is under a lane row: one tile holds it
+    assert dk.tile_positions(2, 32, 32, 8, nb_row=6, paged=True) == 48
     assert dk.tile_positions(32, 2048, 2048, 16, nb_row=128, paged=True,
                              chunk=8) == 128
     assert dk.tile_positions(32, 2048, 2048, 16, nb_row=128, paged=True,
@@ -413,9 +430,9 @@ def test_tile_positions_names_each_kernels_step(slab_engine, paged_engine):
     assert dk.tile_positions(32, 2048, 2048, 16, nb_row=128, paged=True,
                              chunk=8, quant=True) == 16
     assert slab_engine.decode_tile == MAX_LEN
-    assert paged_engine.decode_tile == BS
+    assert paged_engine.decode_tile == MAX_LEN
     assert paged_engine._kernel_path() == \
-        f"fused-pallas, {BS} positions a step"
+        f"fused-pallas, {MAX_LEN} positions a step"
 
 
 @pytest.mark.parametrize("g", [1, 8])
@@ -452,16 +469,16 @@ def test_dispatch_gating():
     """auto on CPU -> reference path (None); off -> None even when
     forced upstream; always -> kernel output; bad mode -> error."""
     rng = np.random.RandomState(5)
-    q = jnp.asarray(rng.randn(2, 32), jnp.float32)
+    q = jnp.asarray(rng.randn(2, 1, 32), jnp.float32)      # one lane
     k = jnp.asarray(rng.randn(2, 16, 32), jnp.float32)
     v = jnp.asarray(rng.randn(2, 16, 32), jnp.float32)
-    pos = jnp.asarray([3, 7], jnp.int32)
+    pos = jnp.asarray([[3], [7]], jnp.int32)
     with dk.forced_mode("auto"):
-        assert dk.maybe_slab(q, k, v, pos, 2) is None   # CPU backend
+        assert dk.maybe_slab_chunk(q, k, v, pos, 2) is None  # CPU backend
     with dk.forced_mode("off"):
-        assert dk.maybe_slab(q, k, v, pos, 2) is None
+        assert dk.maybe_slab_chunk(q, k, v, pos, 2) is None
     with dk.forced_mode("always"):
-        assert dk.maybe_slab(q, k, v, pos, 2) is not None
+        assert dk.maybe_slab_chunk(q, k, v, pos, 2) is not None
     with dk.forced_mode("bogus"), pytest.raises(ValueError,
                                                 match="pallas_decode"):
         dk.decode_kernels_enabled()
@@ -486,16 +503,42 @@ def test_untileable_shapes_fall_back_not_crash():
     rng = np.random.RandomState(6)
     with dk.forced_mode("always"):
         assert not dk.covers(2, 32, 32, 136, paged=True)
-        q = jnp.asarray(rng.randn(2, 32), jnp.float32)
+        q = jnp.asarray(rng.randn(2, 1, 32), jnp.float32)  # one lane
         kp = jnp.asarray(rng.randn(5, 136, 32), jnp.float32)
         tbl = jnp.zeros((2, 2), jnp.int32)
-        pos = jnp.asarray([3, 7], jnp.int32)
-        assert dk.maybe_paged(q, kp, kp, pos, tbl, 2) is None
+        pos = jnp.asarray([[3], [7]], jnp.int32)
+        assert dk.maybe_paged_chunk(q, kp, kp, pos, tbl, 2) is None
         # dh = 136: _lanes on the [H, dh] accumulator can't tile either
         assert not dk.covers(2, 272, 272, 16, paged=True)
-        q2 = jnp.asarray(rng.randn(2, 272), jnp.float32)
+        q2 = jnp.asarray(rng.randn(2, 1, 272), jnp.float32)
         k2 = jnp.asarray(rng.randn(2, 16, 272), jnp.float32)
-        assert dk.maybe_slab(q2, k2, k2, pos, 2) is None
+        assert dk.maybe_slab_chunk(q2, k2, k2, pos, 2) is None
+
+
+def test_one_lane_call_takes_the_kernel_or_says_why(monkeypatch):
+    """A one-lane call (``chunk=1``: the draft's rollout steps, an engine
+    at ``prefill_chunk=1``) is a legal shape of both kernels.  Compiled,
+    the [K*H, .] blocks want whole sublanes, so it takes the kernel where
+    the heads are a multiple of 8 and carries its sentence where not;
+    interpreted, any head count runs."""
+    with dk.forced_mode("always"):
+        for paged, blk in ((False, 256), (True, 16)):
+            assert dk.decline_reason(2, 32, 32, blk, paged=paged,
+                                     chunk=1) is None
+        monkeypatch.setattr(dk, "_interpret", lambda i: False)
+        for paged, blk in ((False, 256), (True, 16)):
+            assert dk.decline_reason(16, 2048, 2048, blk, paged=paged,
+                                     chunk=1) is None
+            why = dk.decline_reason(4, 512, 512, blk, paged=paged, chunk=1)
+            assert why == ("chunk 1 x heads 4 is not a multiple of 8 "
+                           "sublanes")
+        # compiled, one lane of two heads a panel is 2 rows, not a whole
+        # sublane tile: the paged call runs block-a-grid-step (G = 1);
+        # a grouped layout whose panel holds 8 rows tiles
+        assert dk.tile_positions(16, 1024, 1024, 16, nb_row=128,
+                                 paged=True, chunk=1) == 16
+        assert dk.tile_positions(16, 2048, 256, 16, nb_row=128,
+                                 paged=True, chunk=1) == 128
 
 
 def test_covers_judges_the_per_chip_stripe():
@@ -549,7 +592,7 @@ def test_slab_engine_greedy_bit_identical_no_retrace(params, slab_engine):
         bat.close()
     assert all(e is None for e in excs), excs
     for (prompt, n), res in zip(cases, results):
-        assert res["tokens"] == _oracle(params, eng, prompt, n), \
+        assert res["tokens"] == _oracle(params, prompt, n), \
             f"prompt len {prompt.size}, n {n}"
     assert eng.free_slots == SLOTS
 
@@ -569,11 +612,15 @@ def test_paged_engine_greedy_bit_identical_under_churn(params,
                            + eng._write_traces[0] + eng._copy_traces[0],
                            "fused paged churn (admit/CoW/evict)"):
         bat = GenerationBatcher(eng, default_max_tokens=8)
-        results, excs = _drive(bat, cases)
+        # the leader alone first: a prompt's chain is published at its
+        # first token, once its chunks are in
+        lead, lead_excs = _drive(bat, cases[:1])
+        results, excs = _drive(bat, cases[1:])
+        results, excs = lead + results, lead_excs + excs
         bat.close()
     assert all(e is None for e in excs), excs
     for (prompt, n), res in zip(cases, results):
-        assert res["tokens"] == _oracle(params, eng, prompt, n), \
+        assert res["tokens"] == _oracle(params, prompt, n), \
             f"prompt len {prompt.size}, n {n}"
     snap = eng.metrics.snapshot()
     assert snap["prefix_cache_hits_total"] >= 1
@@ -630,7 +677,7 @@ def test_supervisor_recovery_with_kernels_bit_identical(params,
     eng.metrics = ServingMetrics()
     rng = np.random.RandomState(13)
     cases = [(_prompt(rng), 4 + (i % 5)) for i in range(8)]
-    ref = [_oracle(params, eng, p, n) for p, n in cases]
+    ref = [_oracle(params, p, n) for p, n in cases]
     sup = Supervisor(breaker_threshold=10)
     bat = GenerationBatcher(eng, supervisor=sup)
     faults.install_spec("serving.decode_step:at=6")
@@ -671,11 +718,11 @@ def test_fusion_proof_gate_both_directions(paged_engine):
         # flipping the mode around eng.lower() would silently reuse the
         # warm-up trace
         with dk.forced_mode(mode):
-            def fn(p, c, tok, po, tbl):
-                return transformer.lm_decode_step_paged(p, tok, po, c,
-                                                        tbl, HEADS)
+            def fn(p, c, tok, po, ln, tbl):
+                return transformer.lm_decode_chunk_paged(p, tok, po, ln, c,
+                                                         tbl, HEADS)
             return jax.jit(fn).lower(
-                eng.params, eng._cache, eng._tokens, eng._pos,
+                eng.params, eng._cache, eng._tokens, eng._pos, eng._len,
                 eng._paged.tables).compile().as_text()
 
     ref_text = staged("off")
@@ -717,8 +764,8 @@ def test_rope_trunk_slab_kernel_bit_identical():
                                    max_len=MAX_LEN, pos_type="rope")
     with dk.forced_mode("always"):
         eng = DecodeEngine(rope_params, num_heads=HEADS, num_slots=2,
-                           max_len=MAX_LEN, prefill_buckets=(8,),
-                           name="kern_rope", pos_type="rope")
+                           max_len=MAX_LEN, name="kern_rope",
+                           pos_type="rope")
     assert eng.decode_kernels
     bat = GenerationBatcher(eng, default_max_tokens=6)
     rng = np.random.RandomState(14)

@@ -3,8 +3,8 @@ gradient must agree (the dual-implementation discipline the reference
 applies to its fused CUDA LSTM in test_LayerGrad + test_RecurrentLayer).
 
 Runs the kernel in interpret mode on the CPU mesh; the same code lowers to
-Mosaic on a real chip (exercised by bench.py and the TPU differential
-sweep)."""
+Mosaic on a real chip (exercised by chip_smoke.py and the
+``lstm-h512_train`` benchmark cell)."""
 
 import numpy as np
 import pytest

@@ -1,27 +1,15 @@
-"""Analytic perf layer (paddle_tpu/perf): roofline math, cost/HLO
-extraction, the structural regression gate (injected de-fusion MUST trip
-it; identical snapshots MUST pass), and the committed golden snapshot for
-two small bench families.
-
-This is the chip-independent half of the perf evidence (ISSUE 3): every
-assertion here runs on the CPU backend, so the gate works every round
-regardless of the TPU's health.
+"""Analytic perf layer (paddle_tpu/perf): roofline math and cost/HLO
+extraction.  Chip-independent counts: every assertion here runs on the
+CPU backend.  (The structure gates of perf/analytic.py are tested where
+they are used: test_pallas_decode.py, test_quant.py, test_flash_quant.py,
+test_chunked_prefill.py.)
 """
-
-import json
-import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-from paddle_tpu.perf import analytic, cost, roofline
-from paddle_tpu.scripts import perf_report
-
-_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_GOLDEN = os.path.join(_ROOT, "tests", "golden", "analytic_smoke.json")
+from paddle_tpu.perf import cost, roofline
 
 _S = jax.ShapeDtypeStruct
 
@@ -70,6 +58,15 @@ def test_roofline_rejects_negative():
         roofline.predict(-1.0, 10.0, "v5e")
 
 
+def test_unknown_device_kind_is_an_error():
+    """The MFU denominator comes from the one peaks table; a device that is
+    not in it raises instead of returning peak=None or a guessed row."""
+    assert roofline.for_device_kind("TPU v5 lite") is roofline.SPECS["v5e"]
+    assert roofline.for_device_kind("cpu") is roofline.SPECS["cpu"]
+    with pytest.raises(KeyError, match="TPU v99"):
+        roofline.for_device_kind("TPU v99")
+
+
 # ------------------------------------------------------ cost extraction
 
 def test_op_histogram_parses_tuple_types_and_skips_bookkeeping():
@@ -99,130 +96,3 @@ def test_extract_on_compiled_step():
     assert row["arithmetic_intensity"] == pytest.approx(
         row["flops"] / row["bytes_accessed"])
     assert row["hlo_op_total"] == sum(row["hlo_op_histogram"].values())
-
-
-# ------------------------------------------------- the regression gate
-
-def _tiny_snapshot(step_fn):
-    c = jax.jit(step_fn).lower(
-        _S((128, 256), jnp.float32), _S((256, 512), jnp.float32),
-        _S((512, 128), jnp.float32)).compile()
-    return {"schema": 1, "families": {"tiny": cost.extract(c)}}
-
-
-def _fused_step(x, w1, w2):
-    return (jnp.tanh(x @ w1) @ w2).sum()
-
-
-def _defused_step(x, w1, w2):
-    # same math, deliberately de-fused: the first matmul split into
-    # column blocks (re-reads x per block, 8 extra dots + a concatenate)
-    blocks = [jnp.tanh(x @ w1[:, i * 64:(i + 1) * 64]) for i in range(8)]
-    return (jnp.concatenate(blocks, axis=1) @ w2).sum()
-
-
-def test_identical_snapshots_pass():
-    snap = _tiny_snapshot(_fused_step)
-    assert perf_report.analytic_diff(snap, snap) == []
-
-
-def test_injected_defusion_is_flagged():
-    fused = _tiny_snapshot(_fused_step)
-    defused = _tiny_snapshot(_defused_step)
-    # the injected split really changed the structure (guards the guard)
-    assert defused["families"]["tiny"]["dot_count"] \
-        > fused["families"]["tiny"]["dot_count"]
-    regs = perf_report.analytic_diff(fused, defused)
-    assert regs, "de-fused step must trip the structural gate"
-    assert any("dot" in r or "bytes" in r for r in regs)
-    # and the gate is one-directional: the FIX (defused -> fused) passes
-    assert perf_report.analytic_diff(defused, fused) == []
-
-
-def test_fusion_collapse_with_flat_total_is_flagged():
-    """The third de-fusion face: ops migrate out of fusion bodies (total
-    flat, fusions collapse, bytes possibly under bytes_tol) must flag;
-    a genuine simplification (total shrinks too) must not."""
-    base_hist = {"fusion": 10, "dot": 6, "add": 24, "multiply": 20}
-    row = {"flops": 1e9, "bytes_accessed": 1e8,
-           "hlo_op_histogram": base_hist}
-    old = {"families": {"fam": row}}
-    collapsed = dict(row, hlo_op_histogram={
-        "fusion": 3, "dot": 6, "add": 29, "multiply": 22})   # total flat
-    regs = perf_report.analytic_diff(old, {"families": {"fam": collapsed}})
-    assert any("fusion count collapsed" in r for r in regs), regs
-    simplified = dict(row, hlo_op_histogram={
-        "fusion": 3, "dot": 2, "add": 6, "multiply": 5})     # total -73%
-    assert perf_report.analytic_diff(
-        old, {"families": {"fam": simplified}}) == []
-
-
-def test_missing_and_errored_families_flagged():
-    snap = _tiny_snapshot(_fused_step)
-    assert perf_report.analytic_diff(snap, {"families": {}}) \
-        == ["tiny: family missing from new snapshot"]
-    broken = {"families": {"tiny": {"error": "XlaRuntimeError: boom"}}}
-    regs = perf_report.analytic_diff(snap, broken)
-    assert regs and "fails to build" in regs[0]
-
-
-def test_analytic_diff_cli_exit_codes(tmp_path):
-    """Acceptance: perf_report --analytic-diff exits non-zero on the
-    injected de-fusion and zero on identical snapshots — via a real
-    subprocess so the exit code itself is what's proven."""
-    fused = _tiny_snapshot(_fused_step)
-    defused = _tiny_snapshot(_defused_step)
-    a, b = tmp_path / "a.json", tmp_path / "c.json"
-    a.write_text(json.dumps(fused))
-    b.write_text(json.dumps(defused))
-    base = [sys.executable, "-m", "paddle_tpu.scripts.perf_report",
-            "--analytic-diff"]
-    ok = subprocess.run(base + [str(a), str(a)], cwd=_ROOT,
-                        capture_output=True, text=True)
-    assert ok.returncode == 0, ok.stdout + ok.stderr
-    bad = subprocess.run(base + [str(a), str(b)], cwd=_ROOT,
-                         capture_output=True, text=True)
-    assert bad.returncode != 0
-    assert "ANALYTIC REGRESSION" in bad.stdout
-
-
-# ------------------------------------------------- golden snapshot gate
-
-def _fresh_smoke_snapshot():
-    rows = {}
-    for name, model, batch in analytic.FAMILIES:
-        if name in ("smallnet", "trainer_prefetch"):
-            rows[name] = analytic.capture(name, model, batch)
-    return {"schema": 1, "families": rows}
-
-
-def test_golden_snapshot_still_matches():
-    """The committed golden (two small families) vs a fresh capture: the
-    structural gate must stay quiet — i.e. today's code has not de-fused
-    or bytes-inflated the smallnet / trainer_prefetch steps since the
-    golden was cut.  Regenerate the golden when an INTENDED change trips
-    this:  python bench.py --analytic --families smallnet,trainer_prefetch
-    --out tests/golden/analytic_smoke.json
-    (--out matters: the default path is the committed full snapshot)."""
-    with open(_GOLDEN) as f:
-        golden = json.load(f)
-    fresh = _fresh_smoke_snapshot()
-    for name, row in fresh["families"].items():
-        assert "error" not in row, row.get("error")
-        for key in ("flops", "bytes_accessed", "arithmetic_intensity",
-                    "hlo_op_histogram", "predicted_ms", "predicted_mfu",
-                    "bottleneck"):
-            assert key in row
-    regs = perf_report.analytic_diff(golden, fresh)
-    assert regs == [], f"analytic regressions vs committed golden: {regs}"
-
-
-def test_snapshot_families_cover_bench():
-    """Every analytic family name must resolve to a real bench.py model
-    (the registry can't silently drift from the bench)."""
-    sys.path.insert(0, _ROOT)
-    import bench
-    for _name, model, batch in analytic.FAMILIES:
-        assert model in bench._BENCHES, model
-        if batch is not None:
-            assert batch > 0

@@ -188,13 +188,7 @@ def _drive(engine, prompts, n_tok=10):
     return outs
 
 
-@pytest.mark.parametrize("layout,chunk", [
-    # the ladder (chunk=0) engines compile a prefill-bucket ladder each
-    # — slow lane; the chunked default (the serving CLI's mode) stays
-    # in the fast lane
-    pytest.param("slab", 0, marks=pytest.mark.slow),
-    pytest.param("paged", 0, marks=pytest.mark.slow),
-    ("paged", 4)])
+@pytest.mark.parametrize("layout,chunk", [("slab", 4), ("paged", 4)])
 @pytest.mark.slow
 def test_int8_engine_matches_quantized_oracle(layout, chunk):
     """Inside the int8 mode greedy decode stays fully deterministic:
@@ -205,7 +199,7 @@ def test_int8_engine_matches_quantized_oracle(layout, chunk):
     params = qw.quantize_lm(_trunk())
     n_tok = 8
     eng = DecodeEngine(params, num_heads=HEADS, num_slots=4,
-                       max_len=MAXLEN, prefill_buckets=(8, 16),
+                       max_len=MAXLEN,
                        kv_layout=layout, kv_block_size=8,
                        kv_dtype="int8", prefill_chunk=chunk,
                        name=f"q_{layout}{chunk}")
@@ -231,16 +225,15 @@ def test_int8_paged_churn_prefix_cow_no_retrace():
                                rng.randint(1, V, 3).astype(np.int32)])
                for _ in range(4)]
     prompts[1] = prompts[0].copy()          # exact duplicate: CoW fork
-    # chunked engines (the serving default): no ladder to warm, so the
-    # churn test exercises prefix-hit seating + span growth + CoW on
-    # the ONE unified int8 step
+    # the churn exercises prefix-hit seating + span growth + CoW on
+    # the ONE int8 step
     paged = DecodeEngine(params, num_heads=HEADS, num_slots=4,
-                         max_len=MAXLEN, prefill_buckets=(8, 16),
+                         max_len=MAXLEN,
                          kv_layout="paged", kv_block_size=8,
                          kv_dtype="int8", prefill_chunk=4,
                          name="q_churn")
     slab = DecodeEngine(params, num_heads=HEADS, num_slots=4,
-                        max_len=MAXLEN, prefill_buckets=(8, 16),
+                        max_len=MAXLEN,
                         kv_dtype="int8", prefill_chunk=4,
                         name="q_churn_slab")
     # leader first (registers the prefix chains), then the churners —
@@ -261,10 +254,10 @@ def test_int8_paged_churn_prefix_cow_no_retrace():
 def test_int8_paged_auto_doubles_blocks_at_equal_bytes():
     params = _trunk()
     f32 = DecodeEngine(params, num_heads=HEADS, num_slots=4,
-                       max_len=MAXLEN, prefill_buckets=(8, 16),
+                       max_len=MAXLEN,
                        kv_layout="paged", kv_block_size=8, warm=False)
     i8 = DecodeEngine(params, num_heads=HEADS, num_slots=4,
-                      max_len=MAXLEN, prefill_buckets=(8, 16),
+                      max_len=MAXLEN,
                       kv_layout="paged", kv_block_size=8,
                       kv_dtype="int8", warm=False)
     assert i8._paged.pool.num_allocatable \
@@ -290,19 +283,19 @@ def test_kv_dtype_validation():
 @pytest.mark.slow
 def test_recovery_replay_bit_identical_int8():
     """PR-6 supervised recovery on the int8 engine: an injected step
-    fault rebuilds the slab and re-prefills (through the QUANTIZED
-    prefill, whose composition with the step is exact) — recovered
+    fault rebuilds the slab and re-seats every stream (its context
+    re-quantized on the way back in through the step) — recovered
     streams stay identical to the unfaulted int8 twin."""
     from paddle_tpu.resilience import faults
     from paddle_tpu.resilience.supervisor import Supervisor
     params = _trunk()
     prompts = _prompts(5, n=3)
     clean = DecodeEngine(params, num_heads=HEADS, num_slots=4,
-                         max_len=MAXLEN, prefill_buckets=(8, 16),
+                         max_len=MAXLEN,
                          kv_dtype="int8", name="q_clean")
     want = _drive(clean, prompts, n_tok=12)
     chaos = DecodeEngine(params, num_heads=HEADS, num_slots=4,
-                         max_len=MAXLEN, prefill_buckets=(8, 16),
+                         max_len=MAXLEN,
                          kv_dtype="int8", name="q_chaos")
     faults.install_spec("serving.decode_step:at=4")
     try:
@@ -345,9 +338,10 @@ def test_analytic_quant_gates_both_directions():
             p, num_blocks, bs, max_len=MAXLEN, kv_dtype=kv_dtype,
             num_heads=HEADS)
         with dk.forced_mode(mode):
-            def fn(pp, c, tok, po, tbl):
-                logits, c = transformer.lm_decode_step_paged(
-                    pp, tok, po, c, tbl, HEADS)
+            def fn(pp, c, tok, po, tbl):      # a one-lane step
+                logits, c = transformer.lm_decode_chunk_paged(
+                    pp, tok[:, None], po, jnp.ones_like(po), c, tbl,
+                    HEADS)
                 return jnp.argmax(logits, axis=-1), c
             return jax.jit(fn).lower(p, cache, tokens, pos,
                                      tables).compile().as_text()
@@ -391,9 +385,9 @@ def test_weights_gate_tolerates_shape_collisions():
     tokens = np.zeros((2,), np.int32)
     pos = np.zeros((2,), np.int32)
 
-    def fn(p, c, tok, po):
-        logits, c = transformer.lm_decode_step_slots(p, tok, po, c,
-                                                     HEADS)
+    def fn(p, c, tok, po):                    # a one-lane step
+        logits, c = transformer.lm_decode_chunk_slots(
+            p, tok[:, None], po, jnp.ones_like(po), c, HEADS)
         return jnp.argmax(logits, axis=-1), c
 
     hlo = jax.jit(fn).lower(qp, cache, tokens,

@@ -36,7 +36,7 @@ from paddle_tpu.serving.decode_engine import DecodeEngine
 from paddle_tpu.testing import assert_no_retrace, forbid_retrace
 from paddle_tpu.utils.error import ConfigError
 
-VOCAB, HEADS, MAX_LEN, SLOTS, BUCKETS = 64, 2, 48, 4, (8, 16)
+VOCAB, HEADS, MAX_LEN, SLOTS, PROMPT_TOP = 64, 2, 48, 4, 16
 
 
 @pytest.fixture(autouse=True)
@@ -58,13 +58,12 @@ def params():
 @pytest.fixture(scope="module")
 def engine(params):
     return DecodeEngine(params, num_heads=HEADS, num_slots=SLOTS,
-                        max_len=MAX_LEN, prefill_buckets=BUCKETS,
-                        name="chaos_lm")
+                        max_len=MAX_LEN, name="chaos_lm")
 
 
 def _prompts(seed, n):
     rng = np.random.RandomState(seed)
-    return [rng.randint(1, VOCAB, rng.randint(3, BUCKETS[-1] + 1)
+    return [rng.randint(1, VOCAB, rng.randint(3, PROMPT_TOP + 1)
                         ).astype(np.int32) for _ in range(n)]
 
 
@@ -231,26 +230,46 @@ def test_supervised_no_faults_is_zero_cost(engine):
     assert snap["faults_fired"] == {}
 
 
-# ------------------------------------------------------------ prefill
+# ---------------------------------------------------- mid-ingestion
 
 
-def test_prefill_fault_isolated_under_load(engine):
-    """An injected prefill failure fails only its admission group; the
-    other concurrent requests complete and the engine keeps serving."""
+def test_step_fault_mid_ingestion_isolated_under_load(engine):
+    """Unsupervised, under load: a device-step fault that lands while
+    long prompts are still being ingested (their second chunk of five)
+    fails the requests of THAT step alone — none of them had emitted a
+    token — while the requests queued behind them complete, identical
+    to their clean runs, and the engine keeps serving."""
     engine.metrics = ServingMetrics()
-    sup = Supervisor(breaker_threshold=10)
-    bat = GenerationBatcher(engine, supervisor=sup)
-    cases = [(p, 4) for p in _prompts(4, 8)]
-    faults.install_spec("serving.prefill:at=2")
-    results, excs = _drive_concurrent(bat, cases, stagger_s=0.01)
+    rng = np.random.RandomState(4)
+    long_prompts = [rng.randint(1, VOCAB, 35).astype(np.int32)
+                    for _ in range(SLOTS)]
+    cases = [(p, 3) for p in long_prompts] \
+        + [(p, 4) for p in _prompts(4, 6)]
+    ref = _reference(engine, cases)
+    engine.metrics = ServingMetrics()
+    bat = GenerationBatcher(engine)
+    seen = [[] for _ in cases]
+    faults.install_spec("serving.decode_step:at=2")
+    # one thread, one tight loop: the long prompts are what is in the
+    # slots at the second step (all four, unless the worker outran this
+    # loop), the rest queue behind them
+    futs = [bat.submit(p, max_tokens=n, on_token=seen[i].append)
+            for i, (p, n) in enumerate(cases)]
+    failed = []
+    for i, f in enumerate(futs):
+        try:
+            assert f.result(120)["tokens"] == ref[i], i
+        except BatchExecutionError:
+            failed.append(i)
+    assert faults.fired_counts() == {"serving.decode_step": 1}
     faults.clear()
-    failed = [e for e in excs if e is not None]
-    assert all(isinstance(e, BatchExecutionError) for e in failed), excs
-    assert len(failed) >= 1
-    assert len([r for r in results if r is not None]) \
-        == len(cases) - len(failed)
-    ok = bat.submit(cases[0][0], max_tokens=3).result(60)
-    assert len(ok["tokens"]) == 3       # still serving
+    assert failed and failed[-1] < SLOTS    # the step's requests, alone
+    assert all(seen[i] == [] for i in failed)
+    snap = engine.metrics.snapshot()
+    assert snap["errors_total"] == len(failed)
+    assert snap["evictions"]["error"] == len(failed)
+    ok = bat.submit(long_prompts[0], max_tokens=3).result(60)
+    assert ok["tokens"] == ref[0]       # still serving, same numerics
     bat.close()
     assert engine.free_slots == SLOTS
 

@@ -186,16 +186,34 @@ def test_sharded_trace_discipline(sharded_engine):
     assert sharded_engine.draft.trace_count == 1
 
 
+def test_sharded_step_holds_exactly_its_three_seams(params):
+    """The compiled sharded step carries exactly ``layers + 1``
+    all-gathers (one per layer's attention output, one for the logits)
+    and the embedding psum — nothing sums float partial products — while
+    the single-chip twin carries no collective at all."""
+    import re
+
+    def collectives(eng):
+        ops = re.findall(r"= \S+ ([a-z][a-z0-9\-]*)\(",
+                         eng.lower().compile().as_text())
+        return (sum(o == "all-gather" for o in ops),
+                sum(o in ("all-reduce", "reduce-scatter") for o in ops))
+
+    kw = dict(kv_layout="paged", kv_block_size=BS, warm=False)
+    gathers, reduces = collectives(_engine(params, name="seams", **kw))
+    assert gathers == LAYERS + 1 and reduces >= 1, (gathers, reduces)
+    assert collectives(_engine(params, shards=0, name="seams_twin",
+                               **kw)) == (0, 0)
+
+
 def test_sharded_config_validation(params):
     """The config seams fail fast at construction: a mesh without the
-    'model' axis, the legacy prefill ladder, an indivisible trunk, and
-    a draft on a different mesh."""
+    'model' axis, an indivisible trunk, and a draft on a different
+    mesh."""
     from jax.sharding import Mesh
     with pytest.raises(ConfigError, match="axis"):
         _engine(params, shards=0,
                 mesh=Mesh(np.asarray(jax.devices()[:2]), ("data",)))
-    with pytest.raises(ConfigError, match="chunked"):
-        _engine(params, prefill_chunk=0, prefill_buckets=(8, 16))
     with pytest.raises(ConfigError, match="cannot shard"):
         _engine(params, shards=3)       # 2 heads / 64 vocab don't split 3
     with pytest.raises(ConfigError, match="mesh"):

@@ -222,7 +222,7 @@ def test_max_tokens_boundary_mid_run(params, spec_engine):
 
 
 def test_metrics_swap_reapplies_speculate_k(params, spec_engine):
-    """The bench's per-drive metrics reset: a swapped-in ServingMetrics
+    """A per-drive metrics reset: a swapped-in ServingMetrics
     inherits the speculate_k gauge immediately (config, like the chunk
     gauge) and the spec counters grow on the NEW object only."""
     eng = spec_engine
@@ -255,13 +255,10 @@ def test_spec_trace_discipline(spec_engine):
 
 
 def test_spec_config_validation(params):
-    """The config seams: a draft without speculate_k, speculate_k
-    without the unified chunked step, and a mismatched DraftTrunk all
-    fail fast at construction."""
+    """The config seams: a draft without speculate_k and a mismatched
+    DraftTrunk both fail fast at construction."""
     with pytest.raises(ConfigError, match="draft"):
         _engine(params, speculate_k=0, draft=make_draft(params, layers=1))
-    with pytest.raises(ConfigError, match="chunked"):
-        _engine(params, prefill_chunk=0)
     with pytest.raises(ConfigError, match="does not match"):
         mismatched = DraftTrunk(make_draft(params, layers=1),
                                 k=SPEC_K + 1, num_slots=SLOTS,
