@@ -245,12 +245,16 @@ class Supervisor:
         return self._worker
 
     def run_step(self, engine):
-        """One supervised slab step.  Without a deadline this is a plain
-        call; with one, the step runs on the persistent worker thread and
-        a deadline miss raises ``WatchdogTimeout`` (the wedged worker is
-        abandoned and replaced on the next step).  A late finisher is
-        harmless: the engine's epoch guard discards its commit after the
-        recovery path resets the slab."""
+        """One supervised slab step, hand-over to tokens.  Without a
+        deadline this is a plain call; with one, the step runs on the
+        persistent worker thread and a deadline miss raises
+        ``WatchdogTimeout`` (the wedged worker is abandoned and replaced
+        on the next step).  A late finisher is harmless: the engine's
+        epoch guard discards its commit after the recovery path resets
+        the slab.  The deadline times ONE whole step, so the batcher
+        comes here only with a deadline set and keeps no step in flight
+        then; without one it calls the engine's ``dispatch_step`` /
+        ``collect_step`` itself, a step apart."""
         if self.step_deadline_s is None:
             return engine.step()
         _t, inq, outq = self._step_worker()

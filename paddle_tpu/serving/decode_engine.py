@@ -87,6 +87,32 @@ from paddle_tpu.testing.trace import expect_traces
 from paddle_tpu.utils.error import ConfigError
 from paddle_tpu.utils.logging import logger
 
+# lane 0 of a row whose next token is the pick of the step before, which
+# has not left the device: ids are validated non-negative, so a negative
+# one can carry this meaning through the host arrays the step takes anyway
+PICK_IN_FLIGHT = -1
+
+
+def _lane0_from_device(tokens, prev):
+    """The step's token lanes with ``PICK_IN_FLIGHT`` in lane 0 replaced
+    by that row of ``prev``, the step before's picks."""
+    lane0 = tokens[:, 0]
+    return tokens.at[:, 0].set(jnp.where(lane0 < 0, prev, lane0))
+
+
+class _StepHandle:
+    """One dispatched device step until its tokens are read: the device
+    outputs, the host arrays it was fed, and when it was handed over."""
+
+    __slots__ = ("nxt", "prev", "tokens", "pos", "lens", "aux",
+                 "spec_armed", "n_active", "epoch", "t0", "step", "done")
+
+    def __init__(self, **kw):
+        self.done = False
+        for k, v in kw.items():
+            setattr(self, k, v)
+
+
 class DecodeEngine:
     """Slot-based continuous-batching decoder over a decoder-only LM trunk
     (``models/transformer`` params with ``dec_layers=0``).
@@ -423,6 +449,12 @@ class DecodeEngine:
         # chosen experts), left on the device; None for the trunk
         self.step_aux = None
         self._step_log = None      # a list while record_steps() is on
+        # the picks of the step dispatched last, on the device: the next
+        # step's argument (zeros before the first step and after a reset),
+        # born and replaced together with the cache it belongs to
+        self._prev_pick = self._new_pick()
+        self._last_step = None     # that step's handle
+        self._t_ready = 0.0        # when a step's tokens were last read
 
         # all_lanes is a TRACE-TIME constant: a speculating engine's
         # step returns EVERY lane's argmax [S, K] (the verify surface —
@@ -436,10 +468,11 @@ class DecodeEngine:
         heads = (self.num_heads // self.mesh_shards if axis is not None
                  else self.num_heads)
         if model is not None:
-            def _step_fn(p, cache, tokens, pos, lens, tables):
+            def _step_fn(p, cache, prev, tokens, pos, lens, tables):
                 self._step_traces[0] += 1  # runs only under tracing
                 logits, cache, aux = model.decode_chunk(
-                    p, tokens, pos, lens, cache, tables)
+                    p, _lane0_from_device(tokens, prev), pos, lens, cache,
+                    tables)
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 return (nxt, aux), cache
         elif self.kv_layout == "paged":
@@ -451,9 +484,10 @@ class DecodeEngine:
                 return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
             body = self._shard_body(_model, n_data=4)
 
-            def _step_fn(p, cache, tokens, pos, lens, tables):
+            def _step_fn(p, cache, prev, tokens, pos, lens, tables):
                 self._step_traces[0] += 1  # runs only under tracing
-                return body(p, cache, tokens, pos, lens, tables)
+                return body(p, cache, _lane0_from_device(tokens, prev),
+                            pos, lens, tables)
         else:
             def _model(p, cache, tokens, pos, lens):
                 logits, cache = transformer.lm_decode_chunk_slots(
@@ -463,9 +497,10 @@ class DecodeEngine:
                 return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
             body = self._shard_body(_model, n_data=3)
 
-            def _step_fn(p, cache, tokens, pos, lens):
+            def _step_fn(p, cache, prev, tokens, pos, lens):
                 self._step_traces[0] += 1  # runs only under tracing
-                return body(p, cache, tokens, pos, lens)
+                return body(p, cache, _lane0_from_device(tokens, prev),
+                            pos, lens)
         # donate the cache: the step rewrites a few positions per row, the
         # rest is carried through — without donation every step would copy
         # the whole slab/pool
@@ -573,6 +608,15 @@ class DecodeEngine:
         if self._shard_axis is None:
             return build()
         return self._psh.new_lm_cache(build, self.mesh, self._shard_axis)
+
+    def _new_pick(self):
+        """Zeros in the shape and placement of a step's picks ``[S]``."""
+        zeros = jnp.zeros((self.num_slots,), jnp.int32)
+        if self._shard_axis is None:
+            return zeros
+        from jax.sharding import NamedSharding, PartitionSpec
+        return jax.device_put(zeros,
+                              NamedSharding(self.mesh, PartitionSpec()))
 
     def _shard_body(self, fn, n_data):
         """Wrap a step body in ``parallel.sharding.shard_map``
@@ -830,6 +874,12 @@ class DecodeEngine:
         """The attached host-RAM spill tier (None unless
         ``kv_host_bytes > 0`` on a paged engine)."""
         return self._host_tier
+
+    @property
+    def restores_pending(self):
+        """True while a host-tier restore is staged or waits for its
+        commit (``poll_restores``)."""
+        return bool(self._pending_restores)
 
     def _spill_chain(self, key, covered, chain):
         """``PrefixIndex`` eviction hook: gather the chain's block rows
@@ -1234,19 +1284,40 @@ class DecodeEngine:
         their cache rows are overwritten by the next occupant.  Callers
         then bump their active slots via ``advance``.
 
-        Epoch-guarded: inputs are snapshotted up front and the result is
-        only committed if no ``reset()`` happened meanwhile — so a
-        watchdog-abandoned step that finishes late consumes its own
-        (already orphaned) cache buffer and then discards itself,
-        instead of poisoning the rebuilt slab."""
-        # the two host phases of a device step (obs/trace.py phase()), by
-        # the ordinal the step will be counted under: handing the step
-        # over, and waiting for its tokens.  A supervised step runs both
-        # on the watchdog's thread.
-        step = self.metrics.decode_steps_total
-        with obstrace.phase("engine.step.dispatch", step=step) as ph:
+        One synchronous step: ``dispatch_step`` followed at once by
+        ``collect_step``.  The generation loop calls the two apart, and
+        hands the device step n+1 before it reads step n's tokens."""
+        return self.collect_step(self.dispatch_step())
+
+    @property
+    def steps_dispatched(self):
+        """Ordinal of the next device step: the steps counted, and the
+        one whose tokens are not read yet."""
+        last = self._last_step
+        return self.metrics.decode_steps_total \
+            + int(last is not None and not last.done)
+
+    def dispatch_step(self):
+        """Hand the device one step over every slot's armed lanes and
+        return its handle without reading anything back.  A row whose
+        lane 0 holds ``PICK_IN_FLIGHT`` is fed the step before's pick,
+        on the device.
+
+        Epoch-guarded: inputs are snapshotted up front and the returned
+        cache is committed only if no ``reset()`` happened meanwhile — a
+        watchdog-abandoned step that gets here late consumes its own
+        (already orphaned) cache buffer and discards itself, instead of
+        poisoning the rebuilt slab."""
+        # the two host phases of a device step (obs/trace.py phase()):
+        # handing the step over, here, and waiting for its tokens, in
+        # collect_step.  A supervised step runs both on the watchdog's
+        # thread.
+        step = self.steps_dispatched
+        in_flight = step - self.metrics.decode_steps_total
+        with obstrace.phase("engine.step.dispatch", step=step,
+                            in_flight=in_flight) as ph:
             epoch = self._epoch
-            params, cache = self.params, self._cache
+            params, cache, prev = self.params, self._cache, self._prev_pick
             # the host arrays the call takes: snapshotted, so an eviction
             # racing the step changes nothing it reads
             tokens = self._tokens.copy()
@@ -1268,19 +1339,59 @@ class DecodeEngine:
             t0 = time.perf_counter()
             ph.set(host_args=len(host),
                    host_arg_bytes=sum(a.nbytes for a in host))
-            nxt, cache = self._jit_step(params, cache, *host)
+            nxt, cache = self._jit_step(params, cache, prev, *host)
+            aux = None
             if self._model is not None:
-                nxt, self.step_aux = nxt
-        with obstrace.phase("engine.step.wait", step=step):
-            nxt = np.asarray(nxt)
-        with self._epoch_lock:
-            if epoch != self._epoch:
-                raise RuntimeError(
-                    f"{self.name}: engine was reset mid-step; stale step "
-                    "result discarded")
-            self._cache = cache
-        if self._step_log is not None:
-            self._step_log.append((tokens, host[1], lens, self.step_aux))
+                nxt, aux = nxt
+            handle = _StepHandle(
+                nxt=nxt, prev=prev, tokens=tokens, pos=host[1], lens=lens,
+                aux=aux, spec_armed=spec_armed, n_active=self.num_active,
+                epoch=epoch, t0=t0, step=step)
+            with self._epoch_lock:
+                if epoch != self._epoch:
+                    raise RuntimeError(
+                        f"{self.name}: engine was reset mid-step; stale "
+                        "step result discarded")
+                self._cache = cache
+                self.step_aux = aux
+                if nxt.ndim == 1:
+                    # (a speculating step returns every lane's pick, and
+                    # its rows never wait for one: acceptance is host work)
+                    self._prev_pick = nxt
+                self._last_step = handle
+        if in_flight:
+            self.metrics.observe_step_overlapped()
+        return handle
+
+    def collect_step(self, handle, during=None):
+        """Read a dispatched step's tokens ([num_slots] np.int32) and
+        account for the step.  ``during``: the ordinal of the loop
+        iteration that waits, where it is not the step's own (the phase
+        joins the iteration's others by it)."""
+        try:
+            return self._collect(handle, during)
+        finally:
+            handle.done = True      # read and counted, or lost
+
+    def _collect(self, handle, during):
+        with obstrace.phase("engine.step.wait",
+                            step=handle.step if during is None else during,
+                            of_step=handle.step):
+            nxt = np.asarray(handle.nxt)
+        t_ready = time.perf_counter()
+        if handle.epoch != self._epoch:
+            raise RuntimeError(
+                f"{self.name}: engine was reset mid-step; stale step "
+                "result discarded")
+        tokens, lens = handle.tokens, handle.lens
+        log = self._step_log        # (record_steps() may end it meanwhile)
+        if log is not None:
+            # what the lanes were really fed: the picks a row took on the
+            # device are on the host by now (steps are read in order)
+            waited = tokens[:, 0] < 0
+            if waited.any():
+                tokens[waited, 0] = np.asarray(handle.prev)[waited]
+            log.append((tokens, handle.pos, lens, handle.aux))
         # teacher-forced lanes this step fed beyond the per-slot token
         # (the chunked-prefill occupancy surface)
         chunk_lanes = int(lens.sum() - self.num_slots)
@@ -1298,7 +1409,7 @@ class DecodeEngine:
             rows = nxt
             nxt = rows[np.arange(self.num_slots), lens - 1]
             accepted = drafted = 0
-            for slot, k_eff in spec_armed.items():
+            for slot, k_eff in handle.spec_armed.items():
                 row, want = rows[slot], tokens[slot, 1:1 + k_eff]
                 j = 0
                 while j < k_eff and int(row[j]) == int(want[j]):
@@ -1313,9 +1424,14 @@ class DecodeEngine:
             # observe_decode_step with the old signature stay valid on
             # non-speculating engines
             kw = dict(accepted_tokens=accepted, drafted_tokens=drafted,
-                      spec_slots=len(spec_armed))
-        self.metrics.observe_decode_step(self.num_active, self.num_slots,
-                                         time.perf_counter() - t0,
+                      spec_slots=len(handle.spec_armed))
+        # the interval a client sees between tokens: from this step's
+        # hand-over, or from the tokens of the step before becoming ready
+        # where this one was handed over ahead of that
+        seconds = t_ready - max(handle.t0, self._t_ready)
+        self._t_ready = t_ready
+        self.metrics.observe_decode_step(handle.n_active, self.num_slots,
+                                         seconds,
                                          prefill_lanes=chunk_lanes, **kw)
         if self.kv_layout == "paged":
             self.metrics.set_kv_pool(self._paged.pool.num_free,
@@ -1326,7 +1442,9 @@ class DecodeEngine:
         """Record the token fed at the next step for ``slot``, advanced
         past the ``consumed`` lanes the last step processed (1 = plain
         decode; a chunk advances by its lane count — the per-slot
-        variable advance)."""
+        variable advance).  ``token`` is ``PICK_IN_FLIGHT`` while the
+        step that picks it runs; ``advance(slot, pick, 0)`` fills it in
+        once read, for a slot no later step has taken it from."""
         if self._draft is not None:
             # every committed token re-feeds the draft cache (matched
             # drafts rewrite identical K/V; a mismatch feeds the
@@ -1379,6 +1497,9 @@ class DecodeEngine:
                     lambda: self._transformer.init_lm_cache(
                         self.params, self.num_slots, self.max_len,
                         kv_dtype=self.kv_dtype, num_heads=self.num_heads))
+            # a step in flight is void with the cache it wrote
+            self._prev_pick = self._new_pick()
+            self._last_step = None
         self._tokens[:] = 0
         self._pos[:] = 0
         self._len[:] = 1
@@ -1482,8 +1603,9 @@ class DecodeEngine:
                     f"decode[{self.name}]: paged step warm-up",
                     hint="the decode step is not shape-stable"):
                 nxt, self._cache = self._jit_step(
-                    self.params, self._cache, self._tokens,
-                    self._pos, self._len, self._paged.tables.copy())
+                    self.params, self._cache, self._prev_pick,
+                    self._tokens, self._pos, self._len,
+                    self._paged.tables.copy())
                 jax.block_until_ready(nxt)
         else:
             with expect_traces(
@@ -1491,8 +1613,8 @@ class DecodeEngine:
                     f"decode[{self.name}]: slab step warm-up",
                     hint="the decode step is not shape-stable"):
                 nxt, self._cache = self._jit_step(
-                    self.params, self._cache, self._tokens,
-                    self._pos, self._len)
+                    self.params, self._cache, self._prev_pick,
+                    self._tokens, self._pos, self._len)
                 jax.block_until_ready(nxt)
         self._warm = True
         logger.info(
@@ -1529,10 +1651,12 @@ class DecodeEngine:
                 f"{self.name}: lower({what!r}) (takes 'step' | 'draft')")
         if self.kv_layout == "paged":
             return self._jit_step.lower(self.params, self._cache,
-                                        self._tokens, self._pos,
-                                        self._len, self._paged.tables)
+                                        self._prev_pick, self._tokens,
+                                        self._pos, self._len,
+                                        self._paged.tables)
         return self._jit_step.lower(self.params, self._cache,
-                                    self._tokens, self._pos, self._len)
+                                    self._prev_pick, self._tokens,
+                                    self._pos, self._len)
 
     # ------------------------------------------------------------ validate
 
@@ -1703,10 +1827,19 @@ class GenerationBatcher:
     streaming (per-token callbacks) and slot scheduling.
 
     ONE worker thread runs the loop: seat queued requests into free
-    slots, load each feeding slot's next chunk, run one step, deliver
-    each active slot's token, evict finished slots.  Freed slots refill
-    from the queue between ANY two steps; admission happens strictly
-    BETWEEN steps, so the compiled step never sees a shape change.
+    slots, load each feeding slot's next chunk, hand the device one step,
+    deliver each active slot's token, evict finished slots.  Freed slots
+    refill from the queue between ANY two steps; admission happens
+    strictly BETWEEN dispatches, so the compiled step never sees a shape
+    change.
+
+    The loop keeps ONE step in flight (docs/serving.md "One step in
+    flight"): it hands the device step n+1 before it reads step n's
+    tokens, since nothing it does for n+1 needs them — the picked id
+    stays on the device (``PICK_IN_FLIGHT``).  Where host work does need
+    committed tokens or a quiescent cache (``_overlaps``), the iteration
+    reads first and runs the two in today's order; both are the same
+    code with the read earlier or later.
     """
 
     def __init__(self, engine, queue_size=256, default_deadline_ms=None,
@@ -1746,6 +1879,10 @@ class GenerationBatcher:
         # their streams continue bit-identically
         self._waiting = collections.deque()
         self._preempted = []
+        # the step whose tokens are not read yet (worker-thread-only):
+        # (handle, or with a watchdog the tokens themselves; the step's
+        # ordinal; the (request, slot) rows it emits for)
+        self._flying = None
         self.name = name or f"gen_batcher[{engine.name}]"
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name=self.name)
@@ -1918,10 +2055,16 @@ class GenerationBatcher:
         return req
 
     def _finish(self, req, reason):
-        """Evict a slotted request and resolve its future."""
-        self.engine.evict(req.slot, reason)
-        del self._by_slot[req.slot]
-        req.slot = None
+        """Resolve a request's future, evicting its slot if it holds
+        one.  It may not: its last token by count left its slot free
+        while the step ran, or the pool took the slot with a token in
+        flight that ends the stream."""
+        if req.slot is not None:
+            self.engine.evict(req.slot, reason)
+            del self._by_slot[req.slot]
+            req.slot = None
+        elif req in self._preempted:
+            self._preempted.remove(req)
         self._resolve(req, reason)
 
     def _resolve(self, req, reason):
@@ -2169,8 +2312,6 @@ class GenerationBatcher:
             if first_emit:
                 req.slot_span.event("first_token")
                 self.metrics.observe_ttft(req.t_first - req.t_submit)
-                if req.replay_ctx is None:
-                    self.engine.register_context(slot, req.prompt)
             self.metrics.observe_gen_tokens(1)
             if req.eos_id is not None and tok == req.eos_id:
                 req.slot_span.event("accept", accepted=len(run) - 1,
@@ -2200,7 +2341,7 @@ class GenerationBatcher:
         request whose recovery budget ran out fails with the cause;
         everything else keeps streaming."""
         sup = self.supervisor
-        victims = list(self._by_slot.values())
+        victims = list(self._by_slot.values()) + self._void_flying()
         self._by_slot.clear()
         logger.warning("%s: supervised step over %d request(s) failed: "
                        "%s: %s — rebuilding slab + re-prefilling",
@@ -2271,12 +2412,24 @@ class GenerationBatcher:
             self.metrics.observe_slot_reprefill()
         recover_sp.end(recovered=len(self._by_slot))
 
-    def _fail_all_inflight(self, e, extra=()):
+    def _void_flying(self):
+        """Forget the step in flight, whose tokens are lost with the cache
+        (the caller resets the engine, which drops the handle).  Returns
+        the requests that step was the LAST of by count: they hold no slot
+        any more and are victims all the same.  Every other request of
+        the step is seated, or preempted and re-seats from its delivered
+        tokens, which no step in flight has touched."""
+        rows = self._flying[2] if self._flying is not None else ()
+        self._flying = None
+        return [req for req, _slot in rows
+                if req.slot is None and not req.future.done()
+                and req not in self._preempted]
+
+    def _fail_all_inflight(self, e):
         """A device operation (step or slot admission) failed: fail every
-        in-flight request (plus ``extra`` ones caught mid-admission) with
-        the cause, reset the engine (the donated slab may be consumed),
-        and let the loop keep serving."""
-        victims = list(self._by_slot.values()) + list(extra)
+        in-flight request with the cause, reset the engine (the donated
+        slab may be consumed), and let the loop keep serving."""
+        victims = list(self._by_slot.values()) + self._void_flying()
         logger.warning("%s: device op over %d request(s) failed: %s: %s",
                        self.name, len(victims), type(e).__name__, e)
         self.metrics.observe_error(len(victims))
@@ -2293,6 +2446,7 @@ class GenerationBatcher:
             if self._closed.is_set() and not self._drain:
                 # the worker owns slot state: fail the in-flight requests
                 # here, never from close()'s thread
+                self._land()
                 for slot, req in list(self._by_slot.items()):
                     req.fail(ShutdownError(
                         "generation batcher closed without drain"))
@@ -2319,12 +2473,17 @@ class GenerationBatcher:
                         self.engine.poll_restores(timeout=0.005)
                     continue
             # the phases of one iteration (obs/trace.py phase()) share
-            # ``step``: the ordinal the device step they surround will be
-            # counted under
-            step = self.engine.metrics.decode_steps_total
+            # ``step``: the ordinal of the device step it hands over (the
+            # tokens it reads may be those of the step before: ``of_step``)
+            step = self.engine.steps_dispatched
             with obstrace.phase("gen.loop.iter", step=step,
                                 active=len(self._by_slot)):
                 self._iterate(step)
+                if not self._by_slot:
+                    # nothing left to hand over: the loop is about to wait
+                    # for work, and the step in flight holds the last
+                    # tokens of the requests that just left
+                    self._land(step)
 
     def _admit(self, block):
         """What lands strictly between steps, in order."""
@@ -2340,7 +2499,9 @@ class GenerationBatcher:
 
     def _step_failed(self, e):
         """A device operation of this iteration raised: isolate it to
-        the requests in flight; the loop keeps serving."""
+        the requests in flight — those of the step being handed over and
+        those of the step not read yet alike, both void; the loop keeps
+        serving."""
         sup = self.supervisor
         if sup is None:
             self._fail_all_inflight(e)
@@ -2355,10 +2516,30 @@ class GenerationBatcher:
                 sup.breaker.cooldown_s)
         self._recover_inflight(e)
 
+    def _overlaps(self):
+        """Whether this iteration may hand the device its step before the
+        last one's tokens are read.  Not where host work needs committed
+        tokens or a quiescent cache: a draft trunk (acceptance is host
+        work on the step's output), a step deadline (the watchdog times
+        one whole step), a preempted request waiting to re-seat from its
+        delivered tokens, a host-tier restore to commit or an export to
+        serve from the cache."""
+        sup = self.supervisor
+        return not (self.engine.speculating
+                    or (sup is not None and sup.step_deadline_s is not None)
+                    or self._preempted
+                    or self.engine.restores_pending
+                    or not self._export_q.empty())
+
     def _iterate(self, step):
-        """One iteration with a slot active: admit, prepare, step, emit
-        — each a phase on the profiler's clock and in the tracer's phase
-        ring, so a device gap names what the host did in it."""
+        """One iteration with a slot active: admit, prepare, hand the
+        step over, read the tokens of the step before (or, with nothing
+        in flight, this one's) and emit them — each a phase on the
+        profiler's clock and in the tracer's phase ring, so a device gap
+        names what the host did in it."""
+        overlap = self._overlaps()
+        if not overlap and not self._land(step):
+            return
         with obstrace.phase("gen.loop.admit", step=step) as ph:
             seated = len(self._by_slot)
             self._admit(block=False)
@@ -2372,8 +2553,8 @@ class GenerationBatcher:
             try:
                 # paged layout: provision every active slot's write block
                 # (chain growth + copy-on-write forks) strictly BETWEEN
-                # steps; pool exhaustion preempts the youngest slots —
-                # their requests re-seat via _reseat_preempted and their
+                # dispatches; pool exhaustion preempts the youngest slots
+                # — their requests re-seat via _reseat_preempted and their
                 # streams continue bit-identically
                 victims = self.engine.prepare_step()
                 for slot in victims:
@@ -2390,57 +2571,109 @@ class GenerationBatcher:
             return                  # everything was preempted
         sup = self.supervisor
         try:
-            if sup is None:
-                nxt = self.engine.step()
-            else:
+            if sup is not None and sup.step_deadline_s is not None:
                 try:
-                    nxt = sup.run_step(self.engine)
+                    out = sup.run_step(self.engine)     # the tokens
                 except WatchdogTimeout:
                     self.metrics.observe_watchdog_trip()
                     raise
-                sup.breaker.record_success()
-                self._snap_breaker()
+            else:
+                out = self.engine.dispatch_step()       # a handle
         except Exception as e:    # noqa: BLE001 — see _step_failed
             self._step_failed(e)
             return
-        with obstrace.phase("gen.loop.emit", step=step) as ph:
-            seated = len(self._by_slot)
-            emitted = self.metrics.gen_tokens_total
-            self._emit(nxt)
-            ph.set(emitted=self.metrics.gen_tokens_total - emitted,
-                   finished=seated - len(self._by_slot))
+        if not self._land(step):
+            return
+        self._flying = (out, step, self._advance_rows())
+        if not overlap:
+            self._land(step)
 
-    def _emit(self, nxt):
-        """Deliver the step's token to every active slot, then advance
-        or finish it."""
+    def _land(self, during=None):
+        """Read the tokens of the step in flight, if there is one, and
+        emit them, inside the iteration ``during``.  False where the read
+        failed: recovery has run, and the iteration is over."""
+        if self._flying is None:
+            return True
+        out, of_step, rows = self._flying
+        try:
+            nxt = out if isinstance(out, np.ndarray) \
+                else self.engine.collect_step(out, during)
+        except Exception as e:    # noqa: BLE001 — see _step_failed
+            self._step_failed(e)
+            return False
+        self._flying = None
+        if self.supervisor is not None:
+            self.supervisor.breaker.record_success()
+            self._snap_breaker()
+        with obstrace.phase("gen.loop.emit",
+                            step=of_step if during is None else during,
+                            of_step=of_step) as ph:
+            emitted = self.metrics.gen_tokens_total
+            finished = self.metrics.responses_total
+            self._emit(rows, nxt)
+            ph.set(emitted=self.metrics.gen_tokens_total - emitted,
+                   finished=self.metrics.responses_total - finished)
+        return True
+
+    def _advance_rows(self):
+        """The half of delivering a step that needs no token, done while
+        the step runs: every seated row moves past the lanes it was fed.
+        A row still ingesting takes its next token from the recorded
+        stream, as ever.  A row that emits is returned as ``(request,
+        slot)`` for ``_emit``, and unless acceptance has yet to say how
+        far it got (a draft trunk) moves on now: to lane 0 =
+        ``PICK_IN_FLIGHT``, the pick the device already holds, or — its
+        token being its last by ``max_tokens``, which is known before the
+        read — out of its slot, which the next admission may fill."""
+        engine = self.engine
+        rows = []
         for slot, req in list(self._by_slot.items()):
-            if req.future in self._abandoned:
-                # abandon() raced the seating window: the flag landed
-                # in the set after admission's check — honor it here
-                self._abandoned.discard(req.future)
-                req.abandoned = True
-            if req.abandoned:
+            if self._flag_abandoned(req):
                 self._finish(req, "abandoned")
                 continue
-            # lanes this step processed for the slot (1 = plain
-            # decode; >1 = a prefill/replay chunk)
-            consumed = self.engine.chunk_len(slot)
+            # lanes the step processes for the slot (1 = plain decode;
+            # >1 = a prefill/replay chunk)
+            consumed = engine.chunk_len(slot)
             if req.replay_feed:
                 if len(req.replay_feed) >= consumed:
                     # teacher-forced feeding continues: this step's
                     # emission re-derives an already-known token —
                     # swallow it and feed the recorded stream, until
                     # the slot reaches the end of its context
-                    self.engine.advance(
-                        slot, req.replay_feed[consumed - 1],
-                        consumed)
+                    engine.advance(slot, req.replay_feed[consumed - 1],
+                                   consumed)
                     del req.replay_feed[:consumed]
                     continue
-                # the feed drained EXACTLY at this step's last lane:
-                # its emission is the first real one — fall through
+                # the feed drains EXACTLY at this step's last lane: its
+                # emission is the first real one
                 del req.replay_feed[:]
-            if self.engine.speculating:
-                run = self.engine.take_spec_result(slot)
+            rows.append((req, slot))
+            if req.t_first is None and req.replay_ctx is None:
+                # the step that fed the prompt's last chunk is on its way,
+                # and whoever reads its K/V runs later on the device:
+                # publish the prompt to the paged prefix index (no-op on
+                # slab) before the slot writes on, or goes
+                engine.register_context(slot, req.prompt)
+            if engine.speculating:
+                continue
+            if len(req.tokens) + 1 < req.max_tokens:
+                engine.advance(slot, PICK_IN_FLIGHT, consumed)
+            else:
+                engine.evict(slot, "length")
+                del self._by_slot[slot]
+                req.slot = None
+        return rows
+
+    def _emit(self, rows, nxt):
+        """Deliver a step's tokens ``nxt`` to the rows that emit, and
+        finish the streams they end."""
+        engine = self.engine
+        for req, slot in rows:
+            if self._flag_abandoned(req):
+                self._finish(req, "abandoned")
+                continue
+            if engine.speculating:
+                run = engine.take_spec_result(slot)
                 if run is not None:
                     # a verify step: the whole accepted run emits in
                     # one go (and does its own advance/finish)
@@ -2452,18 +2685,18 @@ class GenerationBatcher:
             if first_emit:
                 req.slot_span.event("first_token")
                 self.metrics.observe_ttft(req.t_first - req.t_submit)
-                if req.replay_ctx is None:
-                    # the prompt's K/V is fully resident exactly
-                    # now: publish it to the paged prefix index
-                    # (no-op on slab)
-                    self.engine.register_context(slot, req.prompt)
             self.metrics.observe_gen_tokens(1)
             if req.eos_id is not None and tok == req.eos_id:
+                # not known before the read: with a step in flight the
+                # row ran one surplus lane in it, in a block of its own,
+                # whose pick no one reads (_advance_rows skips the slot)
                 self._finish(req, "eos")
             elif len(req.tokens) >= req.max_tokens:
                 self._finish(req, "length")
-            else:
-                self.engine.advance(slot, tok, consumed)
+            elif engine.speculating:
+                engine.advance(slot, tok, engine.chunk_len(slot))
+            elif req.slot == slot:
+                engine.advance(slot, tok, 0)
 
     # ------------------------------------------------------------ shutdown
 

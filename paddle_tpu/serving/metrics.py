@@ -84,13 +84,18 @@ class ServingMetrics:
         # streams
         self.ttft = Histogram(f"{name}_ttft", max_samples=max_samples,
                               keep="last", clock=self.clock)
-        # time-per-output-token: one slab decode step's wall time — every
-        # active request emits exactly one token per step, so this IS the
-        # per-token latency of the stream
+        # time-per-output-token: the interval between consecutive steps'
+        # tokens becoming ready (one step's wall time, hand-over to read,
+        # where the loop keeps none in flight) — every active request
+        # emits exactly one token per step, so this IS the per-token
+        # latency of the stream
         self.tpot = Histogram(f"{name}_tpot", max_samples=max_samples,
                               keep="last", clock=self.clock)
         self.gen_tokens_total = 0        # useful (delivered) tokens
         self.decode_steps_total = 0
+        # steps handed to the device before the tokens of the step
+        # before were read (the loop's one step in flight)
+        self.decode_steps_overlapped_total = 0
         self.active_slot_steps_total = 0  # sum of active slots over steps
         self.slot_count = 0              # gauge, set by the decode engine
         # ---- unified chunked prefill (decode_engine.py prefill_chunk):
@@ -212,6 +217,12 @@ class ServingMetrics:
                 self.spec_steps_total += 1
                 self.spec_slot_steps_total += int(spec_slots)
         self.tpot.add(seconds)
+
+    def observe_step_overlapped(self):
+        """One decode step dispatched with the step before still in
+        flight."""
+        with self._lock:
+            self.decode_steps_overlapped_total += 1
 
     def observe_prefill_chunk(self, lanes):
         """One prefill chunk loaded into the next step (``lanes``
@@ -436,6 +447,8 @@ class ServingMetrics:
                 "batch_slots_total": self.batch_slots_total,
                 "gen_tokens_total": self.gen_tokens_total,
                 "decode_steps_total": self.decode_steps_total,
+                "decode_steps_overlapped_total":
+                    self.decode_steps_overlapped_total,
                 "slot_count": self.slot_count,
                 "prefill_chunks_total": self.prefill_chunks_total,
                 "prefill_chunk_lanes_total":
@@ -571,6 +584,10 @@ class ServingMetrics:
                  "generated tokens delivered to requests"),
                 ("decode_steps_total", self.decode_steps_total,
                  "continuous-batching slab decode steps executed"),
+                ("decode_steps_overlapped_total",
+                 self.decode_steps_overlapped_total,
+                 "decode steps handed to the device before the previous "
+                 "step's tokens were read"),
                 ("engine_cache_evictions_total",
                  self.engine_cache_evictions,
                  "compiled engines evicted from the per-row-signature "

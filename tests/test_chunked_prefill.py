@@ -395,6 +395,114 @@ def test_supervisor_recovery_rides_chunks_bit_identical(params):
     eng._paged.check()
 
 
+# ------------------------------------------------ one step in flight
+
+
+def _nothing_in_flight(eng):
+    return eng._last_step is None or eng._last_step.done
+
+
+def test_pool_preemption_with_a_step_in_flight_bit_identical(params):
+    """A pool too tight for its traffic takes slots from requests whose
+    last token is still on the device; that token reaches the client, the
+    request re-seats from what was delivered (with nothing in flight:
+    the iteration that re-seats reads first), and every stream, chunk
+    boundaries and all, is the oracle's."""
+    eng = _engine(params, name="cp_tight_fly", kv_layout="paged",
+                  kv_block_size=BS, kv_num_blocks=10)
+    eng.metrics = ServingMetrics()
+    bat = GenerationBatcher(eng, default_max_tokens=16)
+    seen_at_reseat = []
+    orig = bat._reseat_preempted
+
+    def spy():
+        if bat._preempted and eng.free_slots:
+            seen_at_reseat.append(_nothing_in_flight(eng))
+        return orig()
+    bat._reseat_preempted = spy
+    rng = np.random.RandomState(31)
+    cases = [(_prompt(rng, n), 14) for n in (16, 13, 12, 9, 16)]
+    futs = [bat.submit(p, max_tokens=n) for p, n in cases]
+    results = [f.result(300) for f in futs]
+    bat.close()
+    for (prompt, n), res in zip(cases, results):
+        assert res["tokens"] == _oracle(params, prompt, n), prompt.size
+    snap = eng.metrics.snapshot()
+    assert snap["evictions"]["pool_exhausted"] >= 1, snap
+    assert snap["slot_reprefills_total"] >= 1, snap
+    assert snap["decode_steps_overlapped_total"] > 0
+    assert seen_at_reseat and all(seen_at_reseat)
+    assert eng.step_trace_count == 1
+    eng._paged.check()
+    assert eng.free_slots == SLOTS
+
+
+@pytest.mark.parametrize("what", ["speculating", "export", "restore"])
+def test_host_work_on_the_cache_reads_the_step_in_flight_first(params, what):
+    """Where host work needs committed tokens or a quiescent cache the
+    loop reads the step in flight before going on — a draft trunk's
+    acceptance, a cross-replica export, a host-tier restore commit — and
+    hands steps over ahead of the read again once it is done.  Streams
+    are the oracle's throughout."""
+    kw = dict(name=f"cp_drain_{what}", kv_layout="paged", kv_block_size=BS,
+              prefill_chunk=8)
+    if what == "speculating":
+        from paddle_tpu.serving.speculative import make_draft
+        kw.update(speculate_k=2, draft=make_draft(params, layers=1))
+    if what == "restore":
+        kw.update(kv_num_blocks=2 * (MAX_LEN // BS) + 1,
+                  kv_host_bytes=64 << 20)
+    eng = _engine(params, **kw)
+    eng.metrics = ServingMetrics()
+    quiet = []
+    if what == "export":
+        orig = eng.export_chain
+        eng.export_chain = lambda toks: (
+            quiet.append(_nothing_in_flight(eng)), orig(toks))[1]
+    if what == "restore":
+        orig = eng._paged.commit_pending
+
+        def commit(key, covered):
+            quiet.append(_nothing_in_flight(eng))
+            return orig(key, covered)
+        eng._paged.commit_pending = commit
+    bat = GenerationBatcher(eng, default_max_tokens=6)
+    rng = np.random.RandomState(32)
+    shared, long_prompt = _prompt(rng, 4 * BS), _prompt(rng, 4)
+    first = bat.submit(shared, max_tokens=6).result(60)["tokens"]
+    assert first == _oracle(params, shared, 6)
+    if what == "restore":
+        for _ in range(4):              # churn the shared chain out
+            bat.submit(_prompt(rng, 28), max_tokens=4).result(60)
+        assert eng._paged.lookup_prefix(shared)[0] == 0
+        assert eng.host_tier.covers(tuple(int(t) for t in shared))
+    streamed = []
+    long_one = bat.submit(long_prompt, max_tokens=34,
+                          on_token=streamed.append)
+    while len(streamed) < 3:            # it decodes, a step in flight
+        time.sleep(0.001)
+    if what == "export":
+        key, covered, blob = bat.export_chain(shared)
+        assert covered == 4 * BS and blob
+    again = bat.submit(shared, max_tokens=6).result(60)["tokens"]
+    assert again == first
+    assert long_one.result(60)["tokens"] == _oracle(params, long_prompt, 34)
+    bat.close()
+    snap = eng.metrics.snapshot()
+    if what == "speculating":
+        assert snap["decode_steps_overlapped_total"] == 0
+        assert snap["spec_steps_total"] > 0
+    else:
+        assert quiet and all(quiet), quiet
+        assert snap["decode_steps_overlapped_total"] \
+            > 0.5 * snap["decode_steps_total"], snap
+    if what == "restore":
+        assert snap["kv_restore_hits_total"] == 1, snap
+    assert eng.step_trace_count == 1
+    eng._paged.check()
+    assert eng.free_slots == SLOTS
+
+
 # --------------------------------------------------------- validation
 
 

@@ -264,13 +264,14 @@ def test_prompt_bounded_by_max_len_alone(params, engine):
 
 
 def _stall_engine(engine, stall_s):
-    """Make each step slow — deterministic queue buildup."""
-    orig = engine.step
+    """Make each step slow to give its tokens — deterministic queue
+    buildup."""
+    orig = engine.collect_step
 
-    def slow():
+    def slow(*a, **kw):
         time.sleep(stall_s)
-        return orig()
-    engine.step = slow
+        return orig(*a, **kw)
+    engine.collect_step = slow
     return orig
 
 
@@ -298,7 +299,7 @@ def test_overload_deadline_and_metrics(engine):
         assert snap["rejected"]["deadline"] == 1
         bat.close()
     finally:
-        engine.step = orig
+        engine.collect_step = orig
 
 
 # ------------------------------------------------------------ faults
@@ -319,10 +320,11 @@ def test_step_failure_isolated_and_engine_recovers(params, engine):
         raise RuntimeError("injected step failure")
     victim = bat.submit(prompt)
     time.sleep(0.1)                     # it reaches a slot, mid-decode
-    engine.step = boom
+    engine.dispatch_step = boom
     with pytest.raises(BatchExecutionError):
         victim.result(60)
-    engine.step = orig
+    del engine.dispatch_step
+    engine.collect_step = orig
     res = bat.submit(prompt, max_tokens=6).result(60)
     assert res["tokens"] == _oracle(params, prompt, 6)
     snap = engine.metrics.snapshot()
@@ -381,7 +383,261 @@ def test_abandon_reclaims_slot_midflight(engine):
         assert engine.metrics.snapshot()["evictions"]["abandoned"] == 1
         bat.close()
     finally:
-        engine.step = orig
+        engine.collect_step = orig
+
+
+# ------------------------------------------------ one step in flight
+
+
+def _gate_collect(engine):
+    """Hold every read of a step's tokens until ``gate`` is set, and
+    count the reads that have started: a test can act while a step is
+    provably in flight.  Returns (gate, reads, undo)."""
+    gate, reads = threading.Event(), []
+    orig = engine.collect_step
+
+    def held(*a, **kw):
+        reads.append(time.perf_counter())
+        assert gate.wait(60)
+        return orig(*a, **kw)
+    engine.collect_step = held
+
+    def undo():
+        gate.set()
+        engine.collect_step = orig
+    return gate, reads, undo
+
+
+def _hold_admission(engine):
+    """Keep the worker's next admission waiting until ``go`` is set, so
+    that requests submitted meanwhile seat together.  Returns (go,
+    undo)."""
+    go, poll = threading.Event(), engine.poll_restores
+    engine.poll_restores = lambda *a, **kw: (go.wait(30), poll(*a, **kw))[1]
+
+    def undo():
+        go.set()
+        engine.poll_restores = poll
+    return go, undo
+
+
+def _wait_idle(engine):
+    """Every slot free and no step in flight (the loop reads the last
+    step's tokens after the slots have gone)."""
+    _wait_for(lambda: engine.free_slots == SLOTS and engine.steps_dispatched
+              == engine.metrics.decode_steps_total, "the loop to run dry")
+
+
+def _wait_for(cond, what, timeout=30):
+    deadline = time.time() + timeout
+    while not cond():
+        assert time.time() < deadline, f"timed out waiting for {what}"
+        time.sleep(0.002)
+
+
+def test_one_step_in_flight_bit_identical_and_engages(params, engine):
+    """Staggered admissions whose prompts end inside a chunk and exactly
+    at a chunk boundary (the engine's K is 8): every stream is
+    lm_generate's, nothing retraces, and in steady decoding nearly every
+    step is handed over while the one before is still in flight."""
+    engine.metrics = ServingMetrics()
+    bat = GenerationBatcher(engine, default_max_tokens=24)
+    rng = np.random.RandomState(21)
+    cases = [(_prompt(rng, n), 24) for n in (8, 5, 16, 9, 17, 3, 15, 24)]
+    with assert_no_retrace(lambda: engine.step_trace_count,
+                           "decode with a step in flight"):
+        futs = []
+        for prompt, n in cases:
+            futs.append(bat.submit(prompt, max_tokens=n))
+            time.sleep(0.005)
+        results = [f.result(120) for f in futs]
+    bat.close()
+    for (prompt, n), res in zip(cases, results):
+        assert res["tokens"] == _oracle(params, prompt, n), prompt.size
+    assert engine.step_trace_count == 1
+    snap = engine.metrics.snapshot()
+    assert snap["decode_steps_overlapped_total"] \
+        > 0.9 * snap["decode_steps_total"], snap
+    assert "decode_steps_overlapped_total" in engine.metrics.render_prometheus()
+    assert engine.free_slots == SLOTS
+
+
+def test_eos_with_a_step_in_flight_drops_the_surplus_lane(params, engine):
+    """An EOS is not known until it is read, and by then the next step
+    is on its way with one more lane for that row: its pick is never
+    streamed, the stream ends at the EOS, the slot's next occupant
+    streams right, and the pool's ledger holds (the surplus write went
+    to a block of the slot's own, published nowhere)."""
+    engine.metrics = ServingMetrics()
+    bat = GenerationBatcher(engine)
+    rng = np.random.RandomState(22)
+    prompt = _prompt(rng, 11)
+    free = bat.submit(prompt, max_tokens=12).result(60)["tokens"]
+    eos = free[5]
+    k = free.index(eos) + 1
+    engine.record_steps(True)
+    seen = []
+    res = bat.submit(prompt, max_tokens=12, eos_id=eos,
+                     on_token=seen.append).result(60)
+    _wait_idle(engine)
+    steps = engine.recorded_steps()
+    engine.record_steps(False)
+    assert res["finish_reason"] == "eos"
+    assert seen == res["tokens"] == free[:k] \
+        == _oracle(params, prompt, k, eos_id=eos)
+    # the surplus lane ran, one step after the EOS was picked, and was
+    # really fed the EOS the device had kept: position prompt + k - 1
+    tokens, pos, lens, _aux = steps[-1]
+    slot = int(np.argmax(pos))
+    assert (int(pos[slot]), int(lens[slot])) == (prompt.size + k - 1, 1)
+    assert int(tokens[slot, 0]) == eos
+    # the slots' next occupants stream right
+    others = [(_prompt(rng), 6) for _ in range(SLOTS + 1)]
+    futs = [bat.submit(p, max_tokens=n) for p, n in others]
+    for (p, n), f in zip(others, futs):
+        assert f.result(60)["tokens"] == _oracle(params, p, n)
+    bat.close()
+    assert engine.free_slots == SLOTS
+    if engine.kv_layout == "paged":
+        engine._paged.check()
+        # what the index holds of that stream ends with its prompt
+        mine = [covered for key, (covered, _chain)
+                in engine._paged.index._entries.items()
+                if key[:BS] == tuple(prompt[:BS])]
+        assert mine and max(mine) == prompt.size
+
+
+def test_max_tokens_finish_takes_no_lane_in_the_next_step(params, engine):
+    """A token that is the last by ``max_tokens`` is known to be before
+    it is read: the row takes no lane in the step after, and its slot is
+    free for the very next hand-over, as early as in the serial loop."""
+    engine.metrics = ServingMetrics()
+    bat = GenerationBatcher(engine)
+    rng = np.random.RandomState(23)
+    prompt, n = _prompt(rng, 10), 5
+    engine.record_steps(True)
+    res = bat.submit(prompt, max_tokens=n).result(60)
+    _wait_idle(engine)
+    steps = engine.recorded_steps()
+    assert res["tokens"] == _oracle(params, prompt, n)
+    # two steps of ingestion (8 + 2 lanes), then n - 1 decode lanes: the
+    # last one fed token n - 1, and no step ran after it
+    assert len(steps) == 2 + n - 1 == engine.metrics.decode_steps_total
+    _tokens, pos, lens, _aux = steps[-1]
+    assert int(pos.max()) == prompt.size + n - 2 and int(lens.max()) == 1
+    # a full house of such requests and one more behind them: the queued
+    # one is seated by the step right after their last
+    engine.record_steps(True)
+    wave = [(_prompt(rng, 6), 4) for _ in range(SLOTS)] \
+        + [(_prompt(rng, 7), 3)]
+    go, undo = _hold_admission(engine)
+    try:
+        time.sleep(0.1)                 # the worker is at the gate
+        futs = [bat.submit(p, max_tokens=m) for p, m in wave]
+        go.set()
+        for (p, m), f in zip(wave, futs):
+            assert f.result(60)["tokens"] == _oracle(params, p, m)
+    finally:
+        undo()
+    _wait_idle(engine)
+    steps = engine.recorded_steps()
+    engine.record_steps(False)
+    bat.close()
+    fresh = [i for i, (_t, pos, lens, _a) in enumerate(steps)
+             if ((pos == 0) & (lens > 1)).any()]
+    # the wave's own first chunks, then the fifth request's: the wave
+    # takes 1 + 3 steps from its last seat (6 lanes, then 3 decode lanes)
+    assert fresh[-1] - fresh[-2] == 4, fresh
+
+
+def test_read_failure_with_a_step_in_flight_fails_exactly_those(params,
+                                                                engine):
+    """A step whose tokens cannot be read, with the next one already
+    handed over: both are void, exactly the requests in flight fail, and
+    the ones queued behind them are served with unchanged numerics."""
+    engine.metrics = ServingMetrics()
+    bat = GenerationBatcher(engine, default_max_tokens=20)
+    rng = np.random.RandomState(24)
+    inflight = [_prompt(rng, 5) for _ in range(SLOTS)]
+    queued = [_prompt(rng, 6) for _ in range(2)]
+    orig, reads = engine.collect_step, []
+
+    def third_read_fails(handle, *a, **kw):
+        reads.append(handle.step)
+        if len(reads) == 3:
+            assert engine._last_step.step == handle.step + 1  # one behind
+            handle.done = True
+            raise RuntimeError("injected read failure")
+        return orig(handle, *a, **kw)
+    engine.collect_step = third_read_fails
+    go, undo = _hold_admission(engine)
+    try:
+        time.sleep(0.1)                 # the worker is at the gate
+        seen = [[] for _ in inflight]
+        futs = [bat.submit(p, on_token=s.append)
+                for p, s in zip(inflight, seen)]
+        later = [bat.submit(p, max_tokens=6) for p in queued]
+        go.set()
+        for f in futs:
+            with pytest.raises(BatchExecutionError, match="injected read"):
+                f.result(60)
+        for p, f in zip(queued, later):
+            assert f.result(60)["tokens"] == _oracle(params, p, 6)
+    finally:
+        undo()
+        engine.collect_step = orig
+    bat.close()
+    # what had been streamed before the failure was right
+    for p, s in zip(inflight, seen):
+        assert s == _oracle(params, p, len(s))
+    snap = engine.metrics.snapshot()
+    assert snap["errors_total"] == SLOTS
+    assert engine.free_slots == SLOTS
+
+
+def test_abandon_and_deadline_while_a_step_is_in_flight(params, engine):
+    """With a step provably in flight (its read is held): a caller
+    leaves, and a queued request outlives its deadline.  The one who left
+    is streamed nothing more, the late one is refused, the survivor's
+    stream and the slot's next occupant's are the oracle's."""
+    engine.metrics = ServingMetrics()
+    bat = GenerationBatcher(engine, default_max_tokens=30)
+    rng = np.random.RandomState(25)
+    gate, reads, undo = _gate_collect(engine)
+    try:
+        seen = []
+        filler = [bat.submit(_prompt(rng, 4), max_tokens=30)
+                  for _ in range(SLOTS - 2)]
+        victim = bat.submit(_prompt(rng, 4), on_token=seen.append)
+        keep = _prompt(rng, 4)
+        survivor = bat.submit(keep, max_tokens=9)
+        _wait_for(lambda: reads, "the first read")
+        gate.set()                      # free running, then hold again
+        _wait_for(lambda: len(seen) >= 3, "the victim to stream")
+        gate.clear()
+        n_reads = len(reads)
+        _wait_for(lambda: len(reads) > n_reads, "a held read")
+        assert engine.steps_dispatched > engine.metrics.decode_steps_total
+        bat.abandon(victim)
+        streamed = len(seen)
+        dead = bat.submit(_prompt(rng, 4), deadline_ms=1)
+        nxt_prompt = _prompt(rng, 9)
+        nxt = bat.submit(nxt_prompt, max_tokens=5)
+        time.sleep(0.02)
+        gate.set()
+        with pytest.raises(DeadlineExceededError):
+            dead.result(60)
+        assert victim.result(60)["finish_reason"] == "abandoned"
+        assert len(seen) == streamed    # nothing after the caller left
+        assert survivor.result(60)["tokens"] == _oracle(params, keep, 9)
+        assert nxt.result(60)["tokens"] == _oracle(params, nxt_prompt, 5)
+        for f in filler:
+            assert len(f.result(60)["tokens"]) == 30
+    finally:
+        undo()
+    bat.close()
+    assert engine.metrics.snapshot()["evictions"]["abandoned"] == 1
+    assert engine.free_slots == SLOTS
 
 
 # ------------------------------------------------------------ drain
@@ -403,7 +659,7 @@ def test_drain_finishes_queued_and_inflight(engine):
             assert len(f.result(0)["tokens"]) == 6  # all completed
         assert engine.free_slots == SLOTS
     finally:
-        engine.step = orig
+        engine.collect_step = orig
 
 
 @pytest.mark.parametrize("drain", [True, False])
@@ -458,7 +714,7 @@ def test_close_without_drain_fails_inflight_and_queued(engine):
         assert failed == 6
         assert engine.free_slots == SLOTS       # slots reclaimed
     finally:
-        engine.step = orig
+        engine.collect_step = orig
 
 
 # ------------------------------------------------------------ HTTP

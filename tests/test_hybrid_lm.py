@@ -272,14 +272,20 @@ def test_state_holding_model_refuses(hf, params, what):
     assert needle in str(e.value)
 
 
-def test_engine_serves_the_hybrid_trunk(hf, params):
+@pytest.fixture(scope="module")
+def served(hf, params):
+    """(model, engine): the hybrid trunk behind a two-slot engine."""
+    model = hybrid_lm.Served(hybrid_lm.config_from_hf(hf))
+    return model, DecodeEngine(
+        params, model=model, num_slots=2, max_len=64, kv_layout="paged",
+        kv_block_size=BLOCK, prefix_cache=False, prefill_chunk=8, name="hy")
+
+
+def test_engine_serves_the_hybrid_trunk(hf, params, served):
     """Through DecodeEngine -> GenerationBatcher with more requests than
     slots: every stream is the reference's greedy continuation, the step
     traced once, slots reseated, the gauges and the counter set."""
-    model = hybrid_lm.Served(hybrid_lm.config_from_hf(hf))
-    engine = DecodeEngine(params, model=model, num_slots=2, max_len=64,
-                          kv_layout="paged", kv_block_size=BLOCK,
-                          prefix_cache=False, prefill_chunk=8, name="hy")
+    model, engine = served
     assert engine.kda_kernels is False
     assert "pallas_decode" in engine.kda_decline_reason
     reqs = prompts([21, 5, 30, 11], seed=2)
@@ -316,3 +322,41 @@ def test_engine_serves_the_hybrid_trunk(hf, params):
     for name in ("recurrent_state_bytes", "latent_pool_bytes",
                  "state_resets_total"):
         assert name in text
+
+
+def test_recorded_steps_hold_what_each_step_was_really_fed(served):
+    """With a step in flight a decoding row's token goes from one step to
+    the next on the device.  The log shows it all the same: one entry a
+    device step, lane 0 as the device fed it, and that step's OWN report
+    of its experts (a check hands both to its reference)."""
+    from paddle_tpu.serving import ServingMetrics
+    _model, engine = served
+    engine.metrics = ServingMetrics()
+    reqs = prompts([9, 19, 6], seed=5)
+    engine.record_steps(True)
+    with GenerationBatcher(engine) as gen:
+        outs = [f.result(120)["tokens"] for f in
+                [gen.submit(p, max_tokens=6) for p in reqs]]
+    steps = engine.recorded_steps()
+    engine.record_steps(False)
+    m = engine.metrics
+    assert len(steps) == m.decode_steps_total
+    assert m.decode_steps_overlapped_total > 0.8 * m.decode_steps_total
+    streams, seat = [], {}
+    for tokens, pos, lens, chosen in steps:
+        assert (tokens[:, 0] >= 0).all()        # no pick left unresolved
+        assert np.asarray(chosen).shape == (3, 2, 8, 4)
+        for slot in range(2):
+            if pos[slot] + lens[slot] <= 1:
+                continue                        # a free slot idling
+            if pos[slot] == 0:
+                seat[slot] = {}
+                streams.append(seat[slot])
+            for j in range(int(lens[slot])):
+                seat[slot][int(pos[slot]) + j] = int(tokens[slot, j])
+    # every stream was fed its prompt and then each token it was streamed
+    # but the last, position by position
+    fed = sorted([t for _p, t in sorted(s.items())] for s in streams)
+    assert fed == sorted(list(p) + o[:-1] for p, o in zip(reqs, outs))
+    assert len({id(chosen) for *_fed, chosen in steps}) == len(steps)
+    assert engine.step_trace_count == 1

@@ -170,17 +170,37 @@ def test_generation_loop_one_phase_sequence_per_counted_step(engine_kw):
     assert sorted(by_step) == list(range(steps))
     for step, rows in by_step.items():
         rows.sort(key=lambda p: (p["t_start"], -p["t_end"]))
-        assert [p["name"] for p in rows] == ITER_ORDER, step
+        names = [p["name"] for p in rows]
+        # the order of an iteration, as ever; what follows the hand-over
+        # is the tokens of the step before (one step is in flight), none
+        # (the first such step) or both (the loop runs dry): wait, emit
+        assert names[:4] == ITER_ORDER[:4], step
+        assert names[4:] == ITER_ORDER[4:] * (len(names[4:]) // 2), step
         it = rows[0]
         for a, b in zip(rows[1:], rows[2:]):
             assert a["t_end"] <= b["t_start"]       # one after the other
         assert it["t_start"] <= rows[1]["t_start"] \
             and rows[-1]["t_end"] <= it["t_end"]    # all inside the iter
-    disp = next(p for p in phases if p["name"] == "engine.step.dispatch")
-    # tokens, positions, lane counts (+ the block tables)
-    assert disp["attrs"]["host_args"] == \
-        3 + (engine_kw.get("kv_layout") == "paged")
-    assert disp["attrs"]["host_arg_bytes"] > 0
+        disp = rows[3]["attrs"]
+        # tokens, positions, lane counts (+ the block tables): the picks
+        # of the step before never leave the device
+        assert disp["host_args"] == \
+            3 + (engine_kw.get("kv_layout") == "paged")
+        assert disp["host_arg_bytes"] > 0
+        assert disp["in_flight"] in (0, 1)
+        of = [p["attrs"]["of_step"] for p in rows[4:]]
+        assert of[0::2] == of[1::2]         # emit what was waited for
+        if disp["in_flight"]:
+            assert of[0] == step - 1        # read after the hand-over
+        assert all(o <= step for o in of)
+    # every device step's tokens are waited for and emitted once, in order
+    for name in ITER_ORDER[4:]:
+        assert [p["attrs"]["of_step"] for p in phases
+                if p["name"] == name] == list(range(steps))
+    overlapped = sum(p["attrs"]["in_flight"] for p in phases
+                     if p["name"] == "engine.step.dispatch")
+    assert overlapped == engine.metrics.decode_steps_overlapped_total
+    assert overlapped >= steps - 3      # three runs of steps at most
     emitted = sum(p["attrs"]["emitted"] for p in phases
                   if p["name"] == "gen.loop.emit")
     # every token, the first included, is delivered by an emit phase

@@ -203,6 +203,8 @@ def test_decode_step_hang_watchdog_rebuild_bit_identical(engine):
     snap = engine.metrics.snapshot()
     assert snap["watchdog_trips_total"] == 1
     assert snap["slot_reprefills_total"] >= 1
+    # the watchdog times one whole step: none is handed over ahead
+    assert snap["decode_steps_overlapped_total"] == 0
     time.sleep(0.9)     # let the stale thread finish against the epoch
     #                     guard before the next test reuses the engine
 
@@ -228,6 +230,59 @@ def test_supervised_no_faults_is_zero_cost(engine):
     assert snap["retries_total"] == 0
     assert snap["breaker_state"] == 0
     assert snap["faults_fired"] == {}
+    # and without a step deadline it keeps a step in flight like any other
+    assert snap["decode_steps_overlapped_total"] \
+        > 0.5 * snap["decode_steps_total"], snap
+
+
+@pytest.mark.parametrize("fail_read", [1, 2])
+def test_read_failure_with_a_step_in_flight_recovers_bit_identical(
+        engine, fail_read):
+    """A step whose tokens cannot be read while the loop keeps one in
+    flight: the step handed over behind it is void too, and recovery
+    replays from the tokens DELIVERED.  Four requests of one prompt chunk
+    and three tokens: read 1 fails with step 2 handed over for seated
+    rows; read 2 fails after their third token, the last by count, had
+    already cost them their slots.  Either way every stream completes
+    with exactly its clean run's tokens."""
+    cases = [(p[:4], 3) for p in _prompts(4, SLOTS)]
+    ref = _reference(engine, cases)
+    engine.metrics = ServingMetrics()
+    # the worker's first admission waits until all four are queued, so
+    # that they seat together and the reads below are the same every run
+    go, poll = threading.Event(), engine.poll_restores
+    engine.poll_restores = lambda *a, **kw: (go.wait(30), poll(*a, **kw))[1]
+    bat = GenerationBatcher(engine, supervisor=Supervisor())
+    orig, reads = engine.collect_step, []
+
+    def flaky(handle, *a, **kw):
+        reads.append((handle.step, engine._last_step.step,
+                      engine.free_slots))
+        if len(reads) == fail_read + 1:
+            handle.done = True
+            raise RuntimeError("injected read failure")
+        return orig(handle, *a, **kw)
+    engine.collect_step = flaky
+    try:
+        with assert_no_retrace(lambda: engine.step_trace_count,
+                               "recovery with a step in flight"):
+            futs = [bat.submit(p, max_tokens=n) for p, n in cases]
+            go.set()
+            results = [f.result(120) for f in futs]
+            bat.close()
+    finally:
+        go.set()
+        engine.collect_step, engine.poll_restores = orig, poll
+    assert [r["tokens"] for r in results] == ref
+    of_step, last, free = reads[fail_read]
+    if fail_read == 1:
+        assert (of_step, last, free) == (1, 2, 0)   # step 2 was behind it
+    else:
+        assert (of_step, last, free) == (2, 2, SLOTS)   # slots gone already
+    snap = engine.metrics.snapshot()
+    assert snap["slot_reprefills_total"] == SLOTS
+    assert snap["errors_total"] == 0
+    assert engine.free_slots == SLOTS
 
 
 # ---------------------------------------------------- mid-ingestion
