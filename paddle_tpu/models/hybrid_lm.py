@@ -7,6 +7,15 @@ the experts plus a shared expert, ops/moe.py).
 
     x += Attn_l(RMSNorm(x));  x += FFN_l(RMSNorm(x));  logits = RMSNorm(x_L) W_head
 
+With ``post_norms`` each sublayer's result is normed again before it joins
+the stream (a sandwich: ``x += RMSNorm(Attn_l(RMSNorm(x)))``, four gains a
+layer).  An MLA layer may have a low-rank query with its own norm
+(``q_rank``) and rotate its 64 query columns and the shared key part
+(``rope_theta``).  Two published families build a ``Config``
+(``config_from_hf``): ``kimi_linear`` (KDA and unrotated MLA, 3 to 1) and
+``pangu_ultra_moe`` (MLA in every layer, rotated, a low-rank query,
+sandwich norms: a cache of latent pools only, no slot owns state).
+
 ``DecodeEngine(params, model=Served(cfg))`` serves it through the one
 chunked paged step (docs/serving.md "Models that hold state").  The cache
 has two kinds of leaf, which ``cache_kinds`` declares: the MLA layers'
@@ -53,6 +62,10 @@ class Config:
     top_k: int
     routed_scale: float
     shared_experts: int
+    # what a family adds to the block
+    q_rank: int = None          # MLA: a low-rank query with its own norm
+    rope_theta: float = None    # MLA: rotate q's rope columns and k_r
+    post_norms: bool = False    # a norm after each sublayer as well
 
     @property
     def latent_width(self):
@@ -64,33 +77,46 @@ class Config:
 
 
 def config_from_hf(c):
-    """``Config`` from a published ``config.json`` of the kimi_linear
-    family (a dict), plus the groups a cut adds: ``assumed.kda_gate_rank``
-    and ``expert_parallel`` (``num_experts`` are then the experts held of
-    ``num_experts_published``, by rank ``rank``)."""
-    la = c["linear_attn_config"]
-    full = set(la["full_attn_layers"])
+    """``Config`` from a published ``config.json`` (a dict), by the
+    mechanisms its keys state and not by ``model_type``: the layers of
+    ``linear_attn_config`` are KDA and the rest MLA; ``q_lora_rank`` is a
+    low-rank query; ``sandwich_norm`` the norms after the sublayers;
+    ``rope_theta`` rotates the small key part unless ``mla_use_nope`` says
+    the published base is unused.  The families' synonyms for the routed
+    layer are read by presence.  Plus the groups a cut adds:
+    ``assumed.kda_gate_rank`` and ``expert_parallel`` (the key that counts
+    the routed experts then gives those held of ``num_experts_published``,
+    by rank ``rank``)."""
+    def either(*keys):
+        return next(c[k] for k in keys if k in c)
+    la = c.get("linear_attn_config")
+    full = set(la["full_attn_layers"]) if la else None
     ep = c.get("expert_parallel") or {}
-    count = c["num_experts"]
+    count = either("n_routed_experts", "num_experts")
+    theta = None if c.get("mla_use_nope") else c.get("rope_theta")
     return Config(
         vocab_size=c["vocab_size"], hidden_size=c["hidden_size"],
-        layers=tuple(("mla" if l in full else "kda",
+        layers=tuple(("mla" if full is None or l in full else "kda",
                       "dense" if l <= c["first_k_dense_replace"] else "moe")
                      for l in range(1, c["num_hidden_layers"] + 1)),
         rms_norm_eps=c["rms_norm_eps"],
-        kda_heads=la["num_heads"], kda_head_dim=la["head_dim"],
-        conv_kernel=la["short_conv_kernel_size"],
-        kda_gate_rank=(c.get("assumed") or {}).get("kda_gate_rank",
-                                                   la["head_dim"]),
+        kda_heads=la["num_heads"] if la else 0,
+        kda_head_dim=la["head_dim"] if la else 0,
+        conv_kernel=la["short_conv_kernel_size"] if la else 0,
+        kda_gate_rank=(c.get("assumed") or {}).get(
+            "kda_gate_rank", la["head_dim"]) if la else 0,
         mla_heads=c["num_attention_heads"], qk_nope=c["qk_nope_head_dim"],
         qk_rope=c["qk_rope_head_dim"], v_head_dim=c["v_head_dim"],
         kv_rank=c["kv_lora_rank"], dense_width=c["intermediate_size"],
         expert_width=c["moe_intermediate_size"],
         router_width=ep.get("num_experts_published", count),
         held=(ep.get("rank", 0) * count, count),
-        top_k=c["num_experts_per_token"],
+        top_k=either("num_experts_per_tok", "num_experts_per_token"),
         routed_scale=c["routed_scaling_factor"],
-        shared_experts=c["num_shared_experts"])
+        shared_experts=either("n_shared_experts", "num_shared_experts"),
+        q_rank=c.get("q_lora_rank"),
+        rope_theta=float(theta) if theta else None,
+        post_norms=bool(c.get("sandwich_norm")))
 
 
 # ------------------------------------------------------------ parameters
@@ -104,8 +130,13 @@ def _init_attn(key, cfg, kind, dtype):
     ks = jax.random.split(key, 12)
     lin = lambda k, i, o: _normal(k, (i, o), i ** -0.5, dtype)
     if kind == "mla":
+        qw = cfg.mla_heads * (cfg.qk_nope + cfg.qk_rope)
+        query = {"wq": lin(ks[0], d, qw)} if cfg.q_rank is None else {
+            "wqa": lin(ks[0], d, cfg.q_rank),
+            "q_norm": jnp.ones((cfg.q_rank,), jnp.float32),
+            "wqb": lin(ks[4], cfg.q_rank, qw)}
         return {
-            "wq": lin(ks[0], d, cfg.mla_heads * (cfg.qk_nope + cfg.qk_rope)),
+            **query,
             "wkva": lin(ks[1], d, cfg.latent_width),
             "kv_norm": jnp.ones((cfg.kv_rank,), jnp.float32),
             "wkvb": lin(ks[2], cfg.kv_rank,
@@ -152,8 +183,9 @@ def _init_ffn(key, cfg, kind, dtype):
 def _init_layer(key, cfg, kinds, dtype):
     ka, kf = jax.random.split(key)
     d = cfg.hidden_size
-    return {"norm1": jnp.ones((d,), jnp.float32),
-            "norm2": jnp.ones((d,), jnp.float32),
+    gains = ("norm1", "norm2") + (("post_attn", "post_ffn")
+                                  if cfg.post_norms else ())
+    return {**{g: jnp.ones((d,), jnp.float32) for g in gains},
             "attn": _init_attn(ka, cfg, kinds[0], dtype),
             "ffn": _init_ffn(kf, cfg, kinds[1], dtype)}
 
@@ -231,24 +263,28 @@ def decode_chunk(params, cfg, tokens, positions, lengths, cache, tables,
             y, pool = mla.mla_chunk(
                 lp["attn"], h, c["latent"], li, qpos, tables,
                 num_heads=cfg.mla_heads, nope=cfg.qk_nope, rope=cfg.qk_rope,
-                v_dim=cfg.v_head_dim, rank=cfg.kv_rank, eps=eps)
+                v_dim=cfg.v_head_dim, rank=cfg.kv_rank, eps=eps,
+                rope_theta=cfg.rope_theta)
             new_cache.append({"latent": pool})
-        x = x + y
+        x = x + (kda.rms_norm(y, lp["post_attn"], eps) if cfg.post_norms
+                 else y)
         h = kda.rms_norm(x, lp["norm2"], eps)
         f = lp["ffn"]
         if ffn_kind == "dense":
-            x = x + moe.gated_ffn(h, f["wg"], f["wu"], f["wd"])
-            continue
-        flat = h.reshape(s * kk, -1)
-        idx, weights = moe.sigmoid_router(flat, f["router"],
-                                          f["router_bias"], cfg.top_k,
-                                          cfg.routed_scale)
-        y = moe.routed_experts(flat, idx, weights, f, cfg.held,
-                               valid=live.reshape(-1))
-        sh = f["shared"]
-        y = y + moe.gated_ffn(flat, sh["wg"], sh["wu"], sh["wd"])
-        x = x + y.reshape(s, kk, -1)
-        routes.append(idx.reshape(s, kk, -1))
+            y = moe.gated_ffn(h, f["wg"], f["wu"], f["wd"])
+        else:
+            flat = h.reshape(s * kk, -1)
+            idx, weights = moe.sigmoid_router(flat, f["router"],
+                                              f["router_bias"], cfg.top_k,
+                                              cfg.routed_scale)
+            y = moe.routed_experts(flat, idx, weights, f, cfg.held,
+                                   valid=live.reshape(-1))
+            sh = f["shared"]
+            y = (y + moe.gated_ffn(flat, sh["wg"], sh["wu"], sh["wd"])) \
+                .reshape(s, kk, -1)
+            routes.append(idx.reshape(s, kk, -1))
+        x = x + (kda.rms_norm(y, lp["post_ffn"], eps) if cfg.post_norms
+                 else y)
     last = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)[:, 0]
     logits = linear.matmul(kda.rms_norm(last, params["norm_f"], eps),
                            params["head"])
@@ -286,14 +322,22 @@ class Served:
         aux = jnp.stack(routes) if routes else jnp.zeros((0,), jnp.int32)
         return logits, cache, aux
 
-    def kernel_report(self, kk):
-        """{"kda_kernels": bool, "kda_decline_reason": str | None} for a
-        step of ``kk`` lanes, from the kernel's own predicate."""
-        from paddle_tpu.ops.pallas import kda as kernel
-        if not any(kind == "kda" for kind, _f in self.cfg.layers):
-            return {"kda_kernels": False,
-                    "kda_decline_reason": "the model has no KDA layer"}
-        why = kernel.decline_reason(kk, self.cfg.kda_heads,
-                                    self.cfg.kda_head_dim,
-                                    self.cfg.kda_head_dim)
-        return {"kda_kernels": why is None, "kda_decline_reason": why}
+    def kernel_report(self, kk, block):
+        """{"kda_kernels", "kda_decline_reason", "mla_kernels",
+        "mla_decline_reason"} for a step of ``kk`` lanes over blocks of
+        ``block`` positions, each from its kernel's own predicate; False
+        and no reason for a kind of layer the model does not have."""
+        from paddle_tpu.ops.pallas import kda as kda_kernel
+        from paddle_tpu.ops.pallas import mla as mla_kernel
+        cfg = self.cfg
+        kinds = {kind for kind, _f in cfg.layers}
+        why = {"kda": kda_kernel.decline_reason(
+                   kk, cfg.kda_heads, cfg.kda_head_dim, cfg.kda_head_dim)
+               if "kda" in kinds else None,
+               "mla": mla_kernel.decline_reason(
+                   kk, cfg.mla_heads, mla.pool_width(cfg.latent_width),
+                   cfg.kv_rank, block, self.latent_dtype)
+               if "mla" in kinds else None}
+        return {**{k + "_kernels": k in kinds and why[k] is None
+                   for k in why},
+                **{k + "_decline_reason": why[k] for k in why}}

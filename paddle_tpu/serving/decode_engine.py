@@ -179,6 +179,9 @@ class DecodeEngine:
                 speculate_k=speculate_k, kv_host_bytes=kv_host_bytes,
                 mesh=mesh, kv_dtype=kv_dtype)
             self._leaf_kinds = model.cache_kinds()
+        # does a slot own state that seating must reset (a SLOT_LEAF)
+        self._slot_state = SLOT_LEAF in jax.tree_util.tree_leaves(
+            self._leaf_kinds)
         if params.get("dec"):
             raise ConfigError(
                 "DecodeEngine serves the decoder-only LM trunk "
@@ -442,9 +445,12 @@ class DecodeEngine:
         # (decode_attention.tile_positions); None on the reference path
         self.decode_tile = None
         # the same for a model's recurrent kernel (ops/pallas/kda.py):
-        # False and None where the model has no such layer
+        # False and None where there is no model or it has no such layer
         self.kda_kernels = False
         self.kda_decline_reason = None
+        # and for its latent-attention kernel (ops/pallas/mla.py)
+        self.mla_kernels = False
+        self.mla_decline_reason = None
         # what a model's last step reported of itself (hybrid_lm: the
         # chosen experts), left on the device; None for the trunk
         self.step_aux = None
@@ -743,7 +749,7 @@ class DecodeEngine:
                 raise
         self._arm(slot, full[0], 0)
         self._draft_seed(slot, full[:1])
-        if self._model is not None:
+        if self._slot_state:
             # the step zeroes the slot's state itself, on seeing position 0
             self.metrics.observe_state_reset()
         return slot, [int(t) for t in full[1:]]
@@ -1261,6 +1267,13 @@ class DecodeEngine:
                     obstrace.instant("kv.cow_fork", slot=slot,
                                      src=int(src), dst=int(dst))
                     self.metrics.observe_cow_fork()
+        # what the step's lanes will attend: a seated row at position p
+        # feeding n lanes adds (p + 1) + ... + (p + n)
+        seated = np.ones(self.num_slots, bool)
+        seated[self._free] = False
+        n, p = self._len[seated].astype(np.int64), self._pos[seated]
+        self.metrics.observe_attended_positions(
+            int((n * p + n * (n + 1) // 2).sum()))
         return victims
 
     def evict(self, slot, reason):
@@ -1560,14 +1573,19 @@ class DecodeEngine:
                     "reference path: %s", self.name,
                     self.decode_decline_reason)
         if self._model is not None:
-            report = self._model.kernel_report(self._kk)
-            self.kda_kernels = report["kda_kernels"]
-            self.kda_decline_reason = report["kda_decline_reason"]
-            if not self.kda_kernels:
-                logger.warning(
-                    "decode[%s]: kda_chunk kernel declined -> XLA scan "
-                    "(every lane rewrites the state): %s", self.name,
-                    self.kda_decline_reason)
+            report = self._model.kernel_report(self._kk, self.block_size)
+            for key, value in report.items():
+                setattr(self, key, value)
+            for kernel, instead in (
+                    ("kda", "XLA scan (every lane rewrites the state)"),
+                    ("mla", "XLA gather and [S, K, H, T] scores")):
+                if report[kernel + "_decline_reason"]:
+                    logger.warning(
+                        "decode[%s]: %s_chunk kernel declined -> %s: %s",
+                        self.name, kernel, instead,
+                        report[kernel + "_decline_reason"])
+            self.metrics.set_model_kernels(self.kda_kernels,
+                                           self.mla_kernels)
         self.metrics.set_prefill_chunk(self.prefill_chunk)
         self.metrics.set_kv_dtype(self.kv_dtype)
         self.metrics.set_speculate_k(self.speculate_k)
@@ -1630,9 +1648,11 @@ class DecodeEngine:
         """The warm line's account of the path the compiled step took."""
         if self.decode_kernels:
             return f"fused-pallas, {self.decode_tile} positions a step"
-        if self.kda_kernels:
-            return "fused-pallas"
+        fused = [k for k in ("kda", "mla") if getattr(self, k + "_kernels")]
+        if fused:
+            return "fused-pallas (%s)" % ", ".join(fused)
         return "xla-ref (%s)" % (self.kda_decline_reason
+                                 or self.mla_decline_reason
                                  or self.decode_decline_reason)
 
     def lower(self, what="step"):

@@ -103,6 +103,14 @@ class ServingMetrics:
         self.prefill_chunks_total = 0    # chunks loaded into steps
         self.prefill_chunk_lanes_total = 0  # teacher-forced lanes loaded
         self.prefill_lane_steps_total = 0   # sum of per-step chunk lanes
+        # cached positions the seated rows' lanes attended, their own
+        # included (paged steps, counted at prepare_step): the work of an
+        # attention kernel, whatever it moves
+        self.attended_positions_total = 0
+        # facts, set at warm-up: did a model's step (DecodeEngine(model=))
+        # take its recurrent (kda_chunk) and latent (mla_chunk) kernels
+        self.kda_kernels = 0
+        self.mla_kernels = 0
         self.prefill_chunk_size = 0      # gauge: engine K (0 = ladder)
         self.evictions = {r: 0 for r in EVICT_REASONS}
         # ---- speculative decoding (serving/speculative.py): draft
@@ -230,6 +238,16 @@ class ServingMetrics:
         with self._lock:
             self.prefill_chunks_total += 1
             self.prefill_chunk_lanes_total += int(lanes)
+
+    def observe_attended_positions(self, n):
+        """Positions the lanes of the step being prepared attend."""
+        with self._lock:
+            self.attended_positions_total += int(n)
+
+    def set_model_kernels(self, kda, mla):
+        """Facts: the paths a model's compiled step took."""
+        with self._lock:
+            self.kda_kernels, self.mla_kernels = int(kda), int(mla)
 
     def set_prefill_chunk(self, k):
         """Gauge: the engine's chunk size K (0 = legacy ladder mode)."""
@@ -453,6 +471,9 @@ class ServingMetrics:
                 "prefill_chunks_total": self.prefill_chunks_total,
                 "prefill_chunk_lanes_total":
                     self.prefill_chunk_lanes_total,
+                "attended_positions_total": self.attended_positions_total,
+                "kda_kernels": self.kda_kernels,
+                "mla_kernels": self.mla_kernels,
                 "prefill_chunk_size": self.prefill_chunk_size,
                 "speculate_k": self.speculate_k,
                 "mesh_shards": self.mesh_shards,
@@ -618,6 +639,9 @@ class ServingMetrics:
                  self.prefill_chunk_lanes_total,
                  "teacher-forced chunk lanes fed through the unified "
                  "decode step (chunked prefill)"),
+                ("attended_positions_total", self.attended_positions_total,
+                 "cached positions the seated rows' lanes attended, "
+                 "their own included (paged steps)"),
                 ("drafted_tokens_total", self.drafted_tokens_total,
                  "draft lanes scored by verify steps (speculative "
                  "decoding)"),
@@ -647,6 +671,7 @@ class ServingMetrics:
             mesh_shards = self.mesh_shards
             state_bytes = self.recurrent_state_bytes
             latent_bytes = self.latent_pool_bytes
+            kda_kernels, mla_kernels = self.kda_kernels, self.mla_kernels
         for metric, value, help_ in gen_counters:
             emit(metric, value, help_, mtype="counter")
         emit("prefill_chunk_size", chunk_size,
@@ -680,6 +705,10 @@ class ServingMetrics:
         emit("latent_pool_bytes", latent_bytes,
              "bytes of a served model's block-addressed pools (latent "
              "attention; 0 = the transformer trunk)")
+        emit("kda_kernels", kda_kernels,
+             "1 when a served model's step took the kda_chunk kernel")
+        emit("mla_kernels", mla_kernels,
+             "1 when a served model's step took the mla_chunk kernel")
         emit("kv_cache_int8", int(kv_int8),
              "1 when the KV cache stores int8 + per-head scale sidecars "
              "(quantized serving; docs/serving.md)")

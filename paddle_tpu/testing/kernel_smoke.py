@@ -62,6 +62,14 @@ class Widths:
     kda_slots: int = 4      # kda_chunk: rows, heads of head_dim x head_dim
     kda_heads: int = 8      # state, lanes a row
     kda_chunk: int = 16
+    # mla_chunk: rows x lanes x heads over a latent pool of (rank + rope)
+    # values a position, padded to whole lane tiles; blocks of block_size
+    mla_slots: int = 4
+    mla_heads: int = 8
+    mla_chunk: int = 8
+    mla_rank: int = 64
+    mla_rope: int = 16
+    mla_blocks_per_row: int = 4
     # the opt1.3b_chat cell's own paged chunk call: MHA heads of cell_head_dim
     # over a pool of block 16, rows at contexts drawn from cell_contexts
     # (lo, hi) around cell_mean_context
@@ -88,6 +96,10 @@ SERVING = Widths(heads=16, kv_heads=16, head_dim=128, slots=8,
                  lstm_tiled_hidden=1280, lstm_tiled_len=25, blocked_hidden=1280, blocked_len=25,
                  # the kimilinear_reason cell's step: 32 slots of 32 heads
                  kda_slots=32, kda_heads=32, kda_chunk=16,
+                 # the pangu_longdoc cell's step at four of its 16 rows and
+                 # contexts to 1,024: 128 heads x 64 lanes over 512 + 64
+                 mla_slots=4, mla_heads=128, mla_chunk=64, mla_rank=512,
+                 mla_rope=64, mla_blocks_per_row=64,
                  # benchmark/configs/lm-opt-1.3b.json x chat_open.json
                  cell_heads=32, cell_head_dim=64, cell_blocks_per_row=128,
                  cell_contexts=(100, 700), cell_mean_context=330)
@@ -572,6 +584,57 @@ def _kda_case(w):
                 tol=(_TOL_INTERPRETED, _WHY_KDA))
 
 
+# ---------------------------------------------------- latent attention
+
+def _mla_case(w):
+    """``mla_chunk`` (ops/pallas/mla.mla_attend) against gathered blocks and
+    ``[S, K, H, T]`` scores: row 0 decodes deep in its context (one lane),
+    row 1 fills every lane across tiles, row 2 ends inside a block, row 3
+    starts at position 0.  Queries are scaled as attention's are; the lanes
+    past a row's length, which the kernel leaves unwritten, are zeroed on
+    both sides."""
+    from paddle_tpu.ops import mla
+    from paddle_tpu.ops.pallas import mla as kernel
+    s, kk, h, rank = w.mla_slots, w.mla_chunk, w.mla_heads, w.mla_rank
+    bs, nb_row = w.block_size, w.mla_blocks_per_row
+    width = mla.pool_width(rank + w.mla_rope)
+    why = kernel.shape_problem(kk, h, width, rank, bs, jnp.float32,
+                               interpret=jax.default_backend() != "tpu")
+    if why:
+        return Declined(why)
+    ks = jax.random.split(jax.random.PRNGKey(120), 3)
+    blocks = s * nb_row + 1
+    pool = (0.5 * jax.random.normal(ks[0], (blocks, bs, width))) \
+        .at[..., rank + w.mla_rope:].set(0.0)
+    q = 0.5 * jax.random.normal(ks[1], (s, kk, h, width)) * width ** -0.5
+    tables = jax.random.permutation(ks[2], jnp.arange(1, blocks)) \
+        .reshape(s, nb_row).astype(jnp.int32)
+    span = nb_row * bs
+    pos = jnp.asarray([span - 2, span // 2 - kk // 2, bs + 1, 0][:s]
+                      + [0] * max(0, s - 4), jnp.int32)
+    lens = jnp.asarray([1, kk, min(kk, bs // 2), max(1, kk - 1)][:s]
+                       + [1] * max(0, s - 4), jnp.int32)
+    lane = jnp.arange(kk)[None, :]
+    qpos = pos[:, None] + jnp.minimum(lane, lens[:, None] - 1)
+    live = (lane < lens[:, None])[:, :, None, None]
+
+    def fn(q, pool):
+        return jnp.where(live, kernel.mla_attend(q, pool, qpos, tables,
+                                                 rank=rank), 0.0)
+
+    def oracle(q, pool):
+        lat = pool[tables].reshape(s, span, width)
+        scores = jnp.einsum("skhc,stc->skht", q, lat)
+        seen = jnp.arange(span)[None, None, :] <= qpos[:, :, None]
+        probs = jax.nn.softmax(
+            jnp.where(seen[:, :, None, :], scores, -jnp.inf), axis=-1)
+        return jnp.where(live, jnp.einsum("skht,str->skhr", probs,
+                                          lat[..., :rank]), 0.0)
+
+    return Case(fn=fn, oracle=oracle, args=(q, pool), err=_max_err,
+                facts={"pool_bytes": int(pool.size) * 4})
+
+
 def _decode(paged, chunk, quant, seed):
     return lambda w: _decode_case(w, paged=paged, chunk=chunk, quant=quant,
                                   seed=seed)
@@ -602,6 +665,7 @@ CASES = {
         _cell(w), paged=True, chunk=True, quant=False, seed=100,
         contexts=w.cell_contexts),
     "kda_chunk": _kda_case,
+    "mla_chunk": _mla_case,
 }
 
 
