@@ -17,7 +17,9 @@ layer).  An MLA layer may have a low-rank query with its own norm
 sandwich norms: a cache of latent pools only, no slot owns state).
 
 ``DecodeEngine(params, model=Served(cfg))`` serves it through the one
-chunked paged step (docs/serving.md "Models that hold state").  The cache
+chunked paged step (docs/serving.md "Models that hold state"), whose
+residual stream holds the lanes the step's rows feed, packed, at the
+narrowest of up to three compiled widths ("The packed lanes").  The cache
 has two kinds of leaf, which ``cache_kinds`` declares: the MLA layers'
 latent pools are block-addressed like K/V; a KDA layer's recurrent state
 and convolution tail are slot-addressed, zeroed as data inside the step when
@@ -31,6 +33,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from paddle_tpu.models.transformer import _chunk_lanes
 from paddle_tpu.ops import kda, linear, mla, moe
@@ -236,20 +239,67 @@ def cache_kinds(cfg):
 
 # ------------------------------------------------------------------ step
 
+STEP_WIDTH_FRACTIONS = (4, 2, 1)    # of S x K, narrowest first
+
+
+def step_widths(s, kk):
+    """The packed widths ``Served.decode_chunk`` is compiled at: a quarter,
+    a half and the whole of the step's ``S x K`` lanes, those that hold a
+    lane of every row (each row feeds at least one)."""
+    return tuple(sorted({s * kk // f for f in STEP_WIDTH_FRACTIONS
+                         if s * kk // f >= s}))
+
+
+def pack_lanes(lengths, kk):
+    """The step's live lanes laid side by side, on the host (numpy):
+    lengths ``[S]`` in ``[1, K]`` -> (src ``[S x K]`` int32, back ``[S, K]``
+    int32).  ``src[n]`` is the lane (``row * K + lane``) that packed place
+    ``n`` holds, rows in order and each row's lanes in order; the places
+    past the live count repeat the last live lane.  ``back[r, j]`` is the
+    place of row r's lane j, a lane past the row's length that of the row's
+    last.  Any leading ``src[:N]`` that holds every live lane is a packing
+    at width ``N``.  A free slot is armed at one lane like any row and so
+    takes one place: whatever a kernel reads of its row is finite."""
+    lengths = np.asarray(lengths)
+    lane = np.arange(kk)[None, :]
+    live = np.flatnonzero(lane < lengths[:, None])
+    src = np.full(lengths.size * kk, live[-1], np.int32)
+    src[:live.size] = live
+    first = np.cumsum(lengths) - lengths
+    back = first[:, None] + np.minimum(lane, lengths[:, None] - 1)
+    return src, back.astype(np.int32)
+
+
 def decode_chunk(params, cfg, tokens, positions, lengths, cache, tables,
-                 with_routes=False):
+                 with_routes=False, packing=None):
     """``lm_decode_chunk_paged``'s lane semantics: tokens ``[S, K]``,
     positions ``[S]`` (lane 0's), lengths ``[S]`` in ``[1, K]``; row r
     advances ``lengths[r]`` positions.  -> (logits ``[S, V]`` at each
     row's last fed lane, new cache), and with ``with_routes`` the chosen
-    experts ``[S, K, top_k]`` of every expert layer, in layer order.  A row
-    at position 0 starts from zero state; lengths and positions are data."""
+    experts ``[S, K, top_k]`` of every expert layer, in layer order (a lane
+    past its row's length repeats the row's last).  A row at position 0
+    starts from zero state; lengths and positions are data.
+
+    The residual stream lives on a packed axis ``[N, D]`` of the step's
+    live lanes: the embedding, the norms, every projection, the router and
+    the experts run over ``N`` lanes, not ``S x K``.  ``packing = (src [N],
+    back [S, K])`` says which lanes (``pack_lanes``; ``N`` is ``src``'s
+    static length and must hold every live lane).  ``[S, K]`` is rebuilt
+    only where a kernel needs a row's lanes side by side (``mla_chunk``'s
+    attention, KDA's convolution and recurrence); the latents are written
+    and the head reads from the packed lanes.  Without ``packing`` every
+    lane keeps its place, ``N = S x K``: the widest case of the same
+    trunk."""
     s, kk = tokens.shape
-    # lanes past a row's length clamp to its last live lane
     li, qpos = _chunk_lanes(positions, lengths, kk)
-    live = jnp.arange(kk)[None, :] < lengths[:, None]
+    if packing is None:
+        back = jnp.arange(s)[:, None] * kk + li
+        packing = back.reshape(-1), back
+    src, back = (jnp.asarray(a) for a in packing)
+    valid = mla.own_places(src, back)
     eps = cfg.rms_norm_eps
-    x = params["emb"][tokens].astype(jnp.float32)
+    x = params["emb"][jnp.asarray(tokens).reshape(-1)[src]] \
+        .astype(jnp.float32)
     new_cache, routes = [], []
     for lp, c, (attn_kind, ffn_kind) in zip(params["layers"], cache,
                                             cfg.layers):
@@ -257,11 +307,12 @@ def decode_chunk(params, cfg, tokens, positions, lengths, cache, tables,
         if attn_kind == "kda":
             y, state, tail = kda.kda_chunk(
                 lp["attn"], h, c["state"], c["conv"], positions, lengths,
-                num_heads=cfg.kda_heads, head_dim=cfg.kda_head_dim, eps=eps)
+                src, back, num_heads=cfg.kda_heads,
+                head_dim=cfg.kda_head_dim, eps=eps)
             new_cache.append({"state": state, "conv": tail})
         else:
             y, pool = mla.mla_chunk(
-                lp["attn"], h, c["latent"], li, qpos, tables,
+                lp["attn"], h, c["latent"], qpos, tables, src, back,
                 num_heads=cfg.mla_heads, nope=cfg.qk_nope, rope=cfg.qk_rope,
                 v_dim=cfg.v_head_dim, rank=cfg.kv_rank, eps=eps,
                 rope_theta=cfg.rope_theta)
@@ -273,19 +324,17 @@ def decode_chunk(params, cfg, tokens, positions, lengths, cache, tables,
         if ffn_kind == "dense":
             y = moe.gated_ffn(h, f["wg"], f["wu"], f["wd"])
         else:
-            flat = h.reshape(s * kk, -1)
-            idx, weights = moe.sigmoid_router(flat, f["router"],
+            idx, weights = moe.sigmoid_router(h, f["router"],
                                               f["router_bias"], cfg.top_k,
                                               cfg.routed_scale)
-            y = moe.routed_experts(flat, idx, weights, f, cfg.held,
-                                   valid=live.reshape(-1))
             sh = f["shared"]
-            y = (y + moe.gated_ffn(flat, sh["wg"], sh["wu"], sh["wd"])) \
-                .reshape(s, kk, -1)
-            routes.append(idx.reshape(s, kk, -1))
+            y = moe.routed_experts(h, idx, weights, f, cfg.held,
+                                   valid=valid) \
+                + moe.gated_ffn(h, sh["wg"], sh["wu"], sh["wd"])
+            routes.append(idx[back])
         x = x + (kda.rms_norm(y, lp["post_ffn"], eps) if cfg.post_norms
                  else y)
-    last = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)[:, 0]
+    last = x[jnp.take_along_axis(back, (lengths - 1)[:, None], axis=1)[:, 0]]
     logits = linear.matmul(kda.rms_norm(last, params["norm_f"], eps),
                            params["head"])
     if with_routes:
@@ -298,7 +347,8 @@ def decode_chunk(params, cfg, tokens, positions, lengths, cache, tables,
 class Served:
     """What ``DecodeEngine(params, model=...)`` asks of a model: the
     vocabulary, a cache and the kinds of its leaves, the chunk step (with
-    what it reports of itself), and which kernels the step will take."""
+    what it reports of itself) and the host's part of it, and which kernels
+    the step will take."""
 
     def __init__(self, cfg, latent_dtype="float32"):
         self.cfg = cfg
@@ -311,14 +361,34 @@ class Served:
     def cache_kinds(self):
         return cache_kinds(self.cfg)
 
+    def step_widths(self, slots, kk):
+        """The widths the engine compiles the step at, narrowest first
+        (each traced once, at warm-up)."""
+        return step_widths(slots, kk)
+
+    def pack(self, lengths, kk, width=None):
+        """The host's part of a step over rows feeding ``lengths`` of
+        ``kk`` lanes -> ``decode_chunk``'s two maps (src ``[N]``, back
+        ``[S, K]``), as data.  ``N`` is ``width``, or the narrowest of
+        ``step_widths`` that holds the live lanes."""
+        src, back = pack_lanes(lengths, kk)
+        if width is None:
+            live = int(np.sum(lengths))
+            width = next(w for w in step_widths(len(lengths), kk)
+                         if w >= live)
+        return src[:width], back
+
     def decode_chunk(self, params, tokens, positions, lengths, cache,
-                     tables):
+                     tables, src, back):
         """-> (logits, new cache, what the step reports of itself: the
         chosen experts ``[expert layers, S, K, top_k]`` int32, which the
-        engine keeps on the device unread, ``DecodeEngine.step_aux``)."""
+        engine keeps on the device unread, ``DecodeEngine.step_aux``).
+        The step runs at the width of ``src``, static under ``jit``: a
+        step that feeds a sixth of its lanes does not pay for all of
+        them."""
         logits, cache, routes = decode_chunk(
             params, self.cfg, tokens, positions, lengths, cache, tables,
-            with_routes=True)
+            with_routes=True, packing=(src, back))
         aux = jnp.stack(routes) if routes else jnp.zeros((0,), jnp.int32)
         return logits, cache, aux
 

@@ -1,6 +1,8 @@
 """Kimi Delta Attention (KDA): a gated delta rule with a per-channel decay,
 short causal convolutions on q, k, v and a gated head-wise RMSNorm, for the
-serving step's ``[S, K]`` token lanes.
+serving step's token lanes: the projections, the gates and the norm over
+the step's PACKED lanes ``[N, ...]`` (``hybrid_lm.pack_lanes``), the
+convolution and the recurrence over ``[S, K]`` rows.
 
     q, k, v = SiLU(conv_W(x W_qkv))             causal depthwise over time
     q, k    = q / |q| * dk^-0.5,  k / |k|       per head
@@ -76,17 +78,26 @@ def recurrence(q, k, v, a, beta, state, lengths, fresh):
     return recurrence_scan(q, k, v, a, beta, state, lengths, fresh)
 
 
-def kda_chunk(p, h, state, tail, positions, lengths, *, num_heads, head_dim,
-              eps):
-    """One KDA layer over the lanes.  p: the layer's ``attn`` parameters
-    (models/hybrid_lm.py), h ``[S, K, d]`` the normed input, state
-    ``[S, H, dk, dv]`` float32, tail ``[S, W-1, 3*H*dk]``, positions ``[S]``
-    (lane 0's), lengths ``[S]`` -> (y ``[S, K, d]``, new state, new tail)."""
-    s, kk, _d = h.shape
+def kda_chunk(p, h, state, tail, positions, lengths, src, back, *, num_heads,
+              head_dim, eps):
+    """One KDA layer over the step's packed lanes.  p: the layer's ``attn``
+    parameters (models/hybrid_lm.py), h ``[N, d]`` the normed input of the
+    packed lanes, state ``[S, H, dk, dv]`` float32, tail
+    ``[S, W-1, 3*H*dk]``, positions ``[S]`` (lane 0's), lengths ``[S]``, src
+    ``[N]`` / back ``[S, K]`` the packing (``hybrid_lm.pack_lanes``) ->
+    (y ``[N, d]``, new state, new tail).
+
+    The projections, the gates, the head-wise norm and ``wo`` run on the
+    ``N`` packed lanes.  The convolution and the recurrence need a row's
+    lanes side by side: their operands are laid out ``[S, K, ...]`` through
+    ``back`` (a lane past its row's length repeats the row's last; neither
+    reads it into the state or the tail), and the lanes' outputs are picked
+    out of the recurrence's ``[S, K, H, dv]`` through ``src``."""
+    n, (s, kk) = h.shape[0], back.shape
     heads, dk = num_heads, head_dim
     fresh = positions == 0
     tail = jnp.where(fresh[:, None, None], 0.0, tail)
-    z, tail = short_conv(linear.matmul(h, p["wqkv"]), tail,
+    z, tail = short_conv(linear.matmul(h, p["wqkv"])[back], tail,
                          p["conv"].astype(jnp.float32), lengths)
     q, k, v = (x.reshape(s, kk, heads, dk)
                for x in jnp.split(jax.nn.silu(z), 3, axis=-1))
@@ -95,10 +106,12 @@ def kda_chunk(p, h, state, tail, positions, lengths, *, num_heads, head_dim,
     q, k = unit(q) * dk ** -0.5, unit(k)
     f = linear.matmul(linear.matmul(h, p["wf1"]), p["wf2"]) + p["dt_bias"]
     decay = jnp.exp(-jnp.exp(p["a_log"].astype(jnp.float32))[:, None]
-                    * jax.nn.softplus(f).reshape(s, kk, heads, dk))
+                    * jax.nn.softplus(f).reshape(n, heads, dk))
     beta = jax.nn.sigmoid(linear.matmul(h, p["wb"]))
-    o, state = recurrence(q, k, v, decay, beta, state, lengths, fresh)
+    o, state = recurrence(q, k, v, decay[back], beta[back], state, lengths,
+                          fresh)
+    o = o.reshape(s * kk, heads, dk)[src]
     gate = linear.matmul(linear.matmul(h, p["wg1"]), p["wg2"])
     o = rms_norm(o, p["o_norm"], eps) \
-        * jax.nn.sigmoid(gate.reshape(s, kk, heads, dk))
-    return linear.matmul(o.reshape(s, kk, heads * dk), p["wo"]), state, tail
+        * jax.nn.sigmoid(gate.reshape(n, heads, dk))
+    return linear.matmul(o.reshape(n, heads * dk), p["wo"]), state, tail

@@ -34,7 +34,12 @@ the chip taught the XLA path (PR 27):
   static spans (an eighth, a quarter, a half, all of the table) that holds
   every row's furthest position, chosen by ``lax.switch`` on data: one
   program, no retrace, and contexts a quarter of ``max_len`` long do not
-  pay for the whole table."""
+  pay for the whole table.
+
+The layer's products run on the step's PACKED lanes ``[N, ...]`` (the lanes
+rows really feed, ``hybrid_lm.pack_lanes``), and so does the pool's write;
+only the attention keeps ``[S, K]`` rows, on either path (docs/serving.md
+"The packed lanes")."""
 
 import math
 
@@ -68,58 +73,76 @@ def rotate(x, pos, theta):
         .reshape(x.shape)
 
 
-def mla_chunk(p, h, pool, li, qpos, tables, *, num_heads, nope, rope, v_dim,
-              rank, eps, rope_theta=None):
-    """One MLA layer over the lanes.  p: the layer's ``attn`` parameters
-    (models/hybrid_lm.py: ``wq``, or ``wqa`` / ``q_norm`` / ``wqb`` for a
-    low-rank query), h ``[S, K, d]`` the normed input, pool
-    ``[blocks, block, pool_width(rank + rope)]``, li/qpos ``[S, K]`` the
-    clamped lane indices and their positions (``transformer._chunk_lanes``),
-    tables ``[S, blocks_per_row]`` -> (y ``[S, K, d]``, zero in the lanes
-    past a row's length; new pool).  The lanes' latents are written BEFORE
-    the read, so causality inside the chunk is the ordinary mask."""
+def own_places(src, back):
+    """Which places of a packing (``hybrid_lm.pack_lanes``: src ``[N]``,
+    back ``[S, K]``) hold a lane of their own, ``[N]`` bool: those their
+    lane points back at.  The rest repeat a lane (the packed tail; without a
+    packing, the lanes past a row's length): no expert sees them and they
+    write nothing."""
+    return back.reshape(-1)[src] == jnp.arange(src.shape[0])
+
+
+def mla_chunk(p, h, pool, qpos, tables, src, back, *, num_heads, nope, rope,
+              v_dim, rank, eps, rope_theta=None):
+    """One MLA layer over the step's packed lanes.  p: the layer's ``attn``
+    parameters (models/hybrid_lm.py: ``wq``, or ``wqa`` / ``q_norm`` /
+    ``wqb`` for a low-rank query), h ``[N, d]`` the normed input of the
+    packed lanes, pool ``[blocks, block, pool_width(rank + rope)]``, qpos
+    ``[S, K]`` the lanes' positions (``transformer._chunk_lanes``), tables
+    ``[S, blocks_per_row]``, src ``[N]`` / back ``[S, K]`` the packing
+    (``hybrid_lm.pack_lanes``) -> (y ``[N, d]``, new pool).
+
+    Every product runs on the ``N`` packed lanes and the latents are written
+    from them, position by position, BEFORE the read, so causality inside the
+    chunk is the ordinary mask.  Only the attention itself keeps rows: the
+    absorbed queries are laid out ``[S, K, H, W]`` through ``back`` (a lane
+    past its row's length repeats the row's last), and the lanes' results
+    are picked out of its ``[S, K, H, rank]`` through ``src``."""
     from paddle_tpu.core import dtypes
     from paddle_tpu.ops.pallas import mla as kernel
-    s, kk, _d = h.shape
+    n, (s, kk) = h.shape[0], qpos.shape
     block, width = pool.shape[1], pool.shape[2]
-    # lanes past a row's length rewrite its last live lane's latent
-    kva = jnp.take_along_axis(linear.matmul(h, p["wkva"]), li[:, :, None],
-                              axis=1)
+    row, pos = src // kk, qpos.reshape(-1)[src]
+    kva = linear.matmul(h, p["wkva"])
     k_r = kva[..., rank:]
     if rope_theta is not None:
-        k_r = rotate(k_r, qpos, rope_theta)
-    pad = jnp.zeros((s, kk, width - rank - rope), jnp.float32)
+        k_r = rotate(k_r, pos, rope_theta)
+    pad = jnp.zeros((n, width - rank - rope), jnp.float32)
     new = jnp.concatenate(
         [rms_norm(kva[..., :rank], p["kv_norm"], eps), k_r, pad], -1)
-    rows = jnp.arange(s)[:, None]
-    pool = pool.at[tables[rows, qpos // block], qpos % block].set(
-        new.astype(pool.dtype))
+    # a place that repeats a lane writes nothing: past an expert layer it
+    # no longer holds what its lane holds (``routed_experts`` skips it)
+    blk = jnp.where(own_places(src, back), tables[row, pos // block],
+                    pool.shape[0])
+    pool = pool.at[blk, pos % block].set(new.astype(pool.dtype), mode="drop")
 
     if "wqa" in p:
         q = linear.matmul(rms_norm(linear.matmul(h, p["wqa"]), p["q_norm"],
                                    eps), p["wqb"])
     else:
         q = linear.matmul(h, p["wq"])
-    q = q.reshape(s, kk, num_heads, nope + rope) / math.sqrt(nope + rope)
+    q = q.reshape(n, num_heads, nope + rope) / math.sqrt(nope + rope)
     q_r = q[..., nope:]
     if rope_theta is not None:
-        q_r = rotate(q_r, qpos[:, :, None], rope_theta)
+        q_r = rotate(q_r, pos[:, None], rope_theta)
     wkvb = p["wkvb"].reshape(rank, num_heads, nope + v_dim)
-    q_lat = linear.einsum("skhn,rhn->skhr", q[..., :nope], wkvb[..., :nope])
+    q_lat = linear.einsum("nhd,rhd->nhr", q[..., :nope], wkvb[..., :nope])
     q_all = jnp.concatenate(
         [q_lat, q_r,
-         jnp.broadcast_to(pad[:, :, None, :],
-                          (s, kk, num_heads, pad.shape[-1]))], -1)
+         jnp.broadcast_to(pad[:, None, :], (n, num_heads, pad.shape[-1]))],
+        -1)
 
     if kernel.decline_reason(kk, num_heads, width, rank, block,
                              pool.dtype) is None:
-        o_lat = kernel.mla_attend(q_all.astype(dtypes.compute_dtype()), pool,
-                                  qpos, tables, rank=rank)
+        o_lat = kernel.mla_attend(q_all.astype(dtypes.compute_dtype())[back],
+                                  pool, qpos, tables, rank=rank)
     else:
+        q_rows = q_all[back]
+
         def attend(nb):
             """Over the first ``nb`` blocks of every row's table."""
             lat = pool[tables[:, :nb]].reshape(s, nb * block, width)
-            scores = linear.einsum("skhc,stc->skht", q_all, lat)
+            scores = linear.einsum("skhc,stc->skht", q_rows, lat)
             live = jnp.arange(nb * block)[None, None, :] <= qpos[:, :, None]
             probs = jax.nn.softmax(
                 jnp.where(live[:, :, None, :], scores, -jnp.inf), axis=-1)
@@ -131,7 +154,8 @@ def mla_chunk(p, h, pool, li, qpos, tables, *, num_heads, nope, rope, v_dim,
         which = sum((need > nb).astype(jnp.int32) for nb in spans[:-1])
         o_lat = jax.lax.switch(which,
                                [lambda nb=nb: attend(nb) for nb in spans])
-    o = linear.einsum("skhr,rhv->skhv", o_lat, wkvb[..., nope:])
-    y = linear.matmul(o.reshape(s, kk, num_heads * v_dim), p["wo"])
-    # the kernel leaves the lanes past a row's length unwritten
-    return jnp.where((li == jnp.arange(kk))[:, :, None], y, 0.0), pool
+    # (the kernel leaves the lanes past a row's length unwritten: src names
+    # none of them)
+    o_lat = o_lat.reshape(s * kk, num_heads, rank)[src]
+    o = linear.einsum("nhr,rhv->nhv", o_lat, wkvb[..., nope:])
+    return linear.matmul(o.reshape(n, num_heads * v_dim), p["wo"]), pool

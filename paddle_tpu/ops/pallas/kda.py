@@ -90,12 +90,16 @@ def heads_per_program(kk, heads):
     return hp
 
 
+@functools.partial(jax.jit, static_argnames=("hp", "interpret"))
 def kda_chunk(q, k, v, a, beta, state, lengths, fresh, *, hp=None,
               interpret=None):
     """q, k, a ``[S, K, H, dk]``, v ``[S, K, H, dv]``, beta ``[S, K, H]``,
     state ``[S, H, dk, dv]``, all float32; lengths ``[S]`` in ``[1, K]``,
     fresh ``[S]`` bool -> (o ``[S, K, H, dv]``, new state).  Lanes at or
-    past ``lengths`` leave the state alone and read 0 in ``o``."""
+    past ``lengths`` leave the state alone and read 0 in ``o``.  Jitted so
+    that a step's layers, and the widths a step is compiled at, share one
+    trace and one Mosaic lowering (1.6 s and 0.5 s each at the serving
+    shape, PERF.md 32.2)."""
     interpret = _dk._interpret(interpret)
     s, kk, heads, dk = q.shape
     dv = v.shape[-1]

@@ -17,7 +17,9 @@ prefill, over a vLLM-style cache):
   per-slot lane counts are data, so the chunk budget tunes without
   retracing; there is no separate prefill program, no admission write
   and no prompt cap below ``max_len`` — one executable is the whole
-  serving hot path.  Admission and eviction happen BETWEEN steps,
+  serving hot path (a model's step packs the lanes its rows feed and is
+  the same program compiled at up to three widths, each at warm-up:
+  docs/serving.md "The packed lanes").  Admission and eviction happen BETWEEN steps,
   entirely on the host, so scheduling never touches compiled code and
   the step traces exactly once at warm-up and never again
   (``expect_traces`` discipline, shared with ``InferenceEngine.warmup``
@@ -435,7 +437,7 @@ class DecodeEngine:
         # could pass the check and then overwrite the fresh slab.
         self._epoch = 0
         self._epoch_lock = threading.Lock()
-        self._step_traces = [0]
+        self._step_traces = {}     # lanes a compiled step computes -> traces
         # resolved at warm-up (the step's trace time): did the compiled
         # step take the fused Pallas decode-attention path, and if not,
         # the guard's reason (ops/pallas/decode_attention.decline_reason)
@@ -473,12 +475,17 @@ class DecodeEngine:
         axis = self._shard_axis
         heads = (self.num_heads // self.mesh_shards if axis is not None
                  else self.num_heads)
+
+        def _traced(width):     # runs only under tracing
+            self._step_traces[width] = self._step_traces.get(width, 0) + 1
+
         if model is not None:
-            def _step_fn(p, cache, prev, tokens, pos, lens, tables):
-                self._step_traces[0] += 1  # runs only under tracing
+            def _step_fn(p, cache, prev, tokens, pos, lens, tables, src,
+                         back):
+                _traced(src.size)
                 logits, cache, aux = model.decode_chunk(
                     p, _lane0_from_device(tokens, prev), pos, lens, cache,
-                    tables)
+                    tables, src, back)
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 return (nxt, aux), cache
         elif self.kv_layout == "paged":
@@ -491,7 +498,7 @@ class DecodeEngine:
             body = self._shard_body(_model, n_data=4)
 
             def _step_fn(p, cache, prev, tokens, pos, lens, tables):
-                self._step_traces[0] += 1  # runs only under tracing
+                _traced(tokens.size)
                 return body(p, cache, _lane0_from_device(tokens, prev),
                             pos, lens, tables)
         else:
@@ -504,13 +511,19 @@ class DecodeEngine:
             body = self._shard_body(_model, n_data=3)
 
             def _step_fn(p, cache, prev, tokens, pos, lens):
-                self._step_traces[0] += 1  # runs only under tracing
+                _traced(tokens.size)
                 return body(p, cache, _lane0_from_device(tokens, prev),
                             pos, lens)
         # donate the cache: the step rewrites a few positions per row, the
         # rest is carried through — without donation every step would copy
-        # the whole slab/pool
+        # the whole slab/pool.  A model's step is compiled once for each of
+        # its widths (the shape of ``src``), each with its cache in place
         self._jit_step = jax.jit(_step_fn, donate_argnums=(1,))
+        # the lanes a step may compute, narrowest first: the trunk has one
+        # shape; a model packs the lanes its rows feed and names the
+        # widths it is worth compiling (hybrid_lm.step_widths)
+        self.step_widths = (self.num_slots * self._kk,) if model is None \
+            else tuple(model.step_widths(self.num_slots, self._kk))
 
         # block writes and copies touch the block-addressed leaves alone
         # (every leaf of the transformer trunk's cache)
@@ -672,10 +685,18 @@ class DecodeEngine:
 
     @property
     def step_trace_count(self):
-        """Traces of the decode step (the no-retrace discipline:
-        exactly 1 after warm-up, flat across admission/eviction churn).
-        ``lower()`` is an offline tool and re-stages (+1)."""
-        return self._step_traces[0]
+        """Traces of the decode step at the width traced most (the
+        no-retrace discipline: exactly 1 after warm-up, flat across
+        admission/eviction churn).  The trunk has one width; a model's
+        step is compiled at each of ``step_widths``, once each at warm-up,
+        and a retrace of any of them reads 2 here (``step_traces`` keeps
+        them apart).  ``lower()`` is an offline tool and re-stages (+1)."""
+        return max(self._step_traces.values(), default=0)
+
+    @property
+    def step_traces(self):
+        """{lanes a compiled step computes: times it was traced}."""
+        return dict(self._step_traces)
 
     @property
     def ready(self):
@@ -1292,9 +1313,13 @@ class DecodeEngine:
     def step(self):
         """Advance EVERY slot by its armed lanes; returns the next token
         per slot ([num_slots] np.int32: the pick after each slot's last
-        fed lane).  Free slots compute too (fixed shape — that is the
-        cost model) but their output is garbage the caller ignores and
-        their cache rows are overwritten by the next occupant.  Callers
+        fed lane).  Free slots compute too — the trunk's step has ONE
+        shape, all ``S x K`` lanes, and that is its cost model; a model's
+        step (``model=``) packs the lanes its rows feed, a free slot's one
+        armed lane among them, and runs at the narrowest of its compiled
+        widths that holds them (docs/serving.md "The packed lanes") — but
+        a free slot's output is garbage the caller ignores and
+        its cache rows are overwritten by the next occupant.  Callers
         then bump their active slots via ``advance``.
 
         One synchronous step: ``dispatch_step`` followed at once by
@@ -1340,6 +1365,19 @@ class DecodeEngine:
                 # block tables ride as DATA (snapshotted, like
                 # tokens/pos): table churn between steps never retraces
                 host.append(self._paged.tables.copy())
+            # the lanes the step computes, and those of them that rows
+            # feed (a free slot's one armed lane among them).  The trunk
+            # computes all S x K; a model packs the fed lanes and runs
+            # the narrowest of its widths that holds them, the packing
+            # riding as data too (hybrid_lm.Served.pack)
+            width = tokens.size
+            if self._model is not None:
+                src, back = self._model.pack(lens, self._kk)
+                host += [src, back]
+                width = src.size
+            live = int(lens.sum())
+            ph.set(width=width, live=live, lanes=tokens.size)
+            self.metrics.observe_step_lanes(width, live)
             # verify spans armed for THIS step (speculative mode); popped
             # with the snapshot so an eviction racing the step can never
             # resurrect a stale acceptance
@@ -1532,7 +1570,8 @@ class DecodeEngine:
     def warmup(self):
         """Compile + execute the step (and, paged, the block fork and
         the restore write) before traffic, asserting the trace
-        discipline: the step's Python body traces exactly ONCE here and
+        discipline: the step's Python body traces exactly ONCE here for
+        each width it may take (one for the trunk; ``step_widths``) and
         never again in steady state (admission/eviction are host-side, so
         churn cannot retrace by construction — the churn test pins it).
         Idempotent."""
@@ -1616,23 +1655,17 @@ class DecodeEngine:
                                "warm-up"):
                 self._cache = self._jit_copy(self._cache, np.int32(0),
                                              np.int32(0))
+        # every width the step may take, compiled and run once (all slots
+        # free: one lane a row, which the narrowest holds)
+        for width in self.step_widths:
             with expect_traces(
-                    lambda: self.step_trace_count, 1,
-                    f"decode[{self.name}]: paged step warm-up",
+                    lambda: sum(self._step_traces.values()), 1,
+                    f"decode[{self.name}]: {self.kv_layout} step warm-up "
+                    f"at {width} lanes",
                     hint="the decode step is not shape-stable"):
                 nxt, self._cache = self._jit_step(
                     self.params, self._cache, self._prev_pick,
-                    self._tokens, self._pos, self._len,
-                    self._paged.tables.copy())
-                jax.block_until_ready(nxt)
-        else:
-            with expect_traces(
-                    lambda: self.step_trace_count, 1,
-                    f"decode[{self.name}]: slab step warm-up",
-                    hint="the decode step is not shape-stable"):
-                nxt, self._cache = self._jit_step(
-                    self.params, self._cache, self._prev_pick,
-                    self._tokens, self._pos, self._len)
+                    *self._step_args(width))
                 jax.block_until_ready(nxt)
         self._warm = True
         logger.info(
@@ -1643,6 +1676,17 @@ class DecodeEngine:
             self.kv_dtype, self._kernel_path(),
             self.prefill_chunk, self.prefill_chunk_budget or "inf",
             self.speculate_k, self.mesh_shards)
+
+    def _step_args(self, width):
+        """The host arrays a step of ``width`` lanes takes, as the slots
+        stand (warm-up and ``lower()``; ``dispatch_step`` snapshots its
+        own)."""
+        args = [self._tokens, self._pos, self._len]
+        if self.kv_layout == "paged":
+            args.append(self._paged.tables.copy())
+        if self._model is not None:
+            args += self._model.pack(self._len, self._kk, width)
+        return args
 
     def _kernel_path(self):
         """The warm line's account of the path the compiled step took."""
@@ -1669,14 +1713,9 @@ class DecodeEngine:
         if what != "step":
             raise ConfigError(
                 f"{self.name}: lower({what!r}) (takes 'step' | 'draft')")
-        if self.kv_layout == "paged":
-            return self._jit_step.lower(self.params, self._cache,
-                                        self._prev_pick, self._tokens,
-                                        self._pos, self._len,
-                                        self._paged.tables)
         return self._jit_step.lower(self.params, self._cache,
-                                    self._prev_pick, self._tokens,
-                                    self._pos, self._len)
+                                    self._prev_pick,
+                                    *self._step_args(self.step_widths[-1]))
 
     # ------------------------------------------------------------ validate
 

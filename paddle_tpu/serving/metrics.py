@@ -107,6 +107,11 @@ class ServingMetrics:
         # included (paged steps, counted at prepare_step): the work of an
         # attention kernel, whatever it moves
         self.attended_positions_total = 0
+        # lanes the steps computed (the trunk: S x K; a model: the packed
+        # width the step ran at) and lanes rows fed into them, a free
+        # slot's one armed lane included; counted at the hand-over
+        self.step_lanes_computed_total = 0
+        self.step_lanes_live_total = 0
         # facts, set at warm-up: did a model's step (DecodeEngine(model=))
         # take its recurrent (kda_chunk) and latent (mla_chunk) kernels
         self.kda_kernels = 0
@@ -243,6 +248,12 @@ class ServingMetrics:
         """Positions the lanes of the step being prepared attend."""
         with self._lock:
             self.attended_positions_total += int(n)
+
+    def observe_step_lanes(self, computed, live):
+        """The width of the step being handed over and the lanes fed."""
+        with self._lock:
+            self.step_lanes_computed_total += int(computed)
+            self.step_lanes_live_total += int(live)
 
     def set_model_kernels(self, kda, mla):
         """Facts: the paths a model's compiled step took."""
@@ -472,6 +483,8 @@ class ServingMetrics:
                 "prefill_chunk_lanes_total":
                     self.prefill_chunk_lanes_total,
                 "attended_positions_total": self.attended_positions_total,
+                "step_lanes_computed_total": self.step_lanes_computed_total,
+                "step_lanes_live_total": self.step_lanes_live_total,
                 "kda_kernels": self.kda_kernels,
                 "mla_kernels": self.mla_kernels,
                 "prefill_chunk_size": self.prefill_chunk_size,
@@ -642,6 +655,13 @@ class ServingMetrics:
                 ("attended_positions_total", self.attended_positions_total,
                  "cached positions the seated rows' lanes attended, "
                  "their own included (paged steps)"),
+                ("step_lanes_computed_total",
+                 self.step_lanes_computed_total,
+                 "lanes the decode steps computed (a model's steps: the "
+                 "packed width each ran at)"),
+                ("step_lanes_live_total", self.step_lanes_live_total,
+                 "lanes rows fed into the decode steps, a free slot's "
+                 "one included"),
                 ("drafted_tokens_total", self.drafted_tokens_total,
                  "draft lanes scored by verify steps (speculative "
                  "decoding)"),

@@ -203,6 +203,37 @@ def test_one_warmup_trace_zero_retraces_across_churn(params):
         bat.close()
 
 
+@pytest.mark.parametrize("layout", ["slab", "paged"])
+def test_the_trunk_computes_every_lane_and_says_so(params, layout):
+    """The transformer trunk has ONE width: each dispatch phase carries
+    ``width == lanes == S x K`` beside the lanes rows fed (a free slot's
+    one among them), and the two counters add the same up."""
+    from paddle_tpu.obs import trace as obstrace
+    eng = DecodeEngine(params, num_heads=HEADS, num_slots=SLOTS,
+                       max_len=MAX_LEN, kv_layout=layout, kv_block_size=BS,
+                       prefill_chunk=4, name=f"lanes_{layout}")
+    eng.metrics = ServingMetrics()
+    obstrace.enable(sample=1.0, capacity=4096)
+    try:
+        slot, _feed = eng.seat_chunked(np.arange(1, 8, dtype=np.int32))
+        eng.load_chunk(slot, np.arange(2, 5, dtype=np.int32))
+        eng.prepare_step()
+        eng.step()                      # 4 lanes + three free slots' one
+        eng.advance(slot, 5, consumed=4)
+        eng.prepare_step()
+        eng.step()                      # one lane a row
+        phases = obstrace.debug_payload()["phases"]
+    finally:
+        obstrace.disable()
+    stats = [ph["attrs"] for ph in phases
+             if ph["name"] == "engine.step.dispatch"]
+    assert [(st["width"], st["live"], st["lanes"]) for st in stats] \
+        == [(16, 7, 16), (16, 4, 16)]
+    m = eng.metrics
+    assert (m.step_lanes_computed_total, m.step_lanes_live_total) == (32, 11)
+    assert eng.step_trace_count == 1
+
+
 def test_default_engine_is_chunked_and_the_ladder_is_gone():
     """``DecodeEngine(params)`` with no keywords is the engine the CLI
     builds (slab, 8 lanes a step); asking for the ladder is an error that

@@ -360,3 +360,161 @@ def test_recorded_steps_hold_what_each_step_was_really_fed(served):
     assert fed == sorted(list(p) + o[:-1] for p, o in zip(reqs, outs))
     assert len({id(chosen) for *_fed, chosen in steps}) == len(steps)
     assert engine.step_trace_count == 1
+
+
+# ----------------------------------------------- the packed lanes (PR 32)
+
+def _family(name):
+    """(hf, parameters) of a tiny configuration under benchmark/testdata:
+    ``tiny-hybrid`` (KDA + MLA + experts) or ``tiny-pangu`` (MLA in every
+    layer, a low-rank query, rotation, post-norms)."""
+    with open(os.path.join(ROOT, "benchmark", "testdata", "configs",
+                           name + ".json")) as f:
+        hf = json.load(f)
+    return hf, serve_hybrid.make_params(hf, 11)
+
+
+@pytest.fixture(scope="module", params=["tiny-hybrid", "tiny-pangu"])
+def mixed_step(request):
+    """One step of six rows of sixteen lanes over a cache that already holds
+    something: a row prefilling all 16 lanes from position 0, a row whose
+    chunk is three lanes ending inside a block, two rows decoding one lane
+    deep in their contexts, two free rows (one lane, position 0, the scratch
+    block).  23 live lanes of 96: every width of the ladder holds them."""
+    hf, params = _family(request.param)
+    cfg = hybrid_lm.config_from_hf(hf)
+    s, kk, block, nb_row = 6, 16, 16, 3
+    lens = np.asarray([16, 3, 1, 1, 1, 1], np.int32)
+    pos = np.asarray([0, 16, 37, 5, 0, 0], np.int32)
+    tables = np.zeros((s, nb_row), np.int32)
+    tables[:4] = np.arange(1, 4 * nb_row + 1).reshape(4, nb_row)
+    rng = np.random.RandomState(3)
+    tok = rng.randint(1, cfg.vocab_size, (s, kk)).astype(np.int32)
+    cache = jax.tree_util.tree_map(
+        lambda x: jnp.asarray(0.1 * rng.randn(*x.shape), x.dtype),
+        hybrid_lm.init_cache(cfg, s, 4 * nb_row + 1, block))
+    args = (params, cfg, tok, pos, lens, cache, jnp.asarray(tables))
+    whole = jax.jit(lambda: hybrid_lm.decode_chunk(*args, with_routes=True))()
+    return args, whole
+
+
+@pytest.mark.parametrize("width", [24, 48, 96])
+def test_every_width_gives_what_the_whole_width_gives(mixed_step, width):
+    """``decode_chunk`` packed at each width of the ladder (6 x 16 lanes: a
+    quarter, a half, the whole) against the unpacked call: the seated rows'
+    logits, what they wrote into the cache, the live lanes' experts."""
+    args, (want, want_cache, want_routes) = mixed_step
+    lens = args[4]
+    assert hybrid_lm.step_widths(6, 16) == (24, 48, 96)
+    src, back = hybrid_lm.pack_lanes(lens, 16)
+    got, cache, routes = jax.jit(lambda s, b: hybrid_lm.decode_chunk(
+        *args, with_routes=True, packing=(s, b)))(src[:width], back)
+    np.testing.assert_allclose(got[:4], want[:4], atol=2e-5)
+    for kinds, new, ref in zip(hybrid_lm.cache_kinds(args[1]), cache,
+                               want_cache):
+        for name, kind in kinds.items():
+            # slot leaves by row, block leaves past the scratch block
+            keep = slice(0, 4) if kind == "slot" else slice(1, None)
+            np.testing.assert_allclose(new[name][keep], ref[name][keep],
+                                       atol=2e-5)
+    live = np.arange(16)[None, :] < lens[:, None]
+    assert len(routes) == len(want_routes) > 0
+    for r, w in zip(routes, want_routes):
+        assert r.shape == (6, 16, args[1].top_k)
+        np.testing.assert_array_equal(np.asarray(r)[live],
+                                      np.asarray(w)[live])
+
+
+def test_pack_lanes_lays_live_lanes_side_by_side():
+    lens = np.asarray([3, 1, 4, 1])
+    src, back = hybrid_lm.pack_lanes(lens, 4)
+    # rows in order, a row's lanes in order, the tail repeating the last
+    assert src.tolist() == [0, 1, 2, 4, 8, 9, 10, 11, 12] + [12] * 7
+    assert back.tolist() == [[0, 1, 2, 2], [3, 3, 3, 3], [4, 5, 6, 7],
+                             [8, 8, 8, 8]]
+    # a place is a lane's own where the lane points back at it
+    own = back.reshape(-1)[src] == np.arange(16)
+    assert own.tolist() == [True] * 9 + [False] * 7
+
+
+@pytest.mark.parametrize("live,width", [(4, 8), (8, 8), (9, 16), (16, 16),
+                                        (17, 32), (32, 32)])
+def test_step_takes_the_narrowest_width_that_holds_its_lanes(hf, live,
+                                                             width):
+    """4 rows x 8 lanes: a quarter, a half, the whole.  No row seated is
+    four lanes (a free slot feeds one); the edges fall on either side."""
+    widths = hybrid_lm.step_widths(4, 8)
+    assert widths == (8, 16, 32)
+    lens = np.ones(4, np.int32)
+    for r in range(4):              # fill rows until ``live`` lanes are fed
+        lens[r] += min(7, live - lens.sum())
+    assert lens.sum() == live
+    model = hybrid_lm.Served(hybrid_lm.config_from_hf(hf))
+    assert model.step_widths(4, 8) == widths
+    src, back = model.pack(lens, 8)
+    assert src.shape == (width,) and back.shape == (4, 8)
+    # every live lane has its place, and points back at it
+    assert sorted(set(src.tolist())) == np.flatnonzero(
+        np.arange(8)[None, :] < lens[:, None]).tolist()
+    assert (back.reshape(-1)[src[:live]] == np.arange(live)).all()
+    assert model.pack(lens, 8, 32)[0].shape == (32,)
+    # K under four lanes: a quarter would not hold a lane of every row
+    assert hybrid_lm.step_widths(4, 2) == (4, 8)
+    assert hybrid_lm.step_widths(4, 1) == (4,)
+
+
+def _drive(engine, reqs, max_tokens=5):
+    with GenerationBatcher(engine, default_max_tokens=max_tokens) as gen:
+        return [f.result(120)["tokens"] for f in
+                [gen.submit(p, max_tokens=max_tokens) for p in reqs]]
+
+
+def test_narrow_steps_stream_what_wide_steps_stream(hf, params, monkeypatch):
+    """The same requests through an engine whose steps take the ladder and
+    through one held to the whole width: token for token; each width traced
+    once, at warm-up, whatever the churn (five requests over three slots:
+    admission, eviction, re-seating)."""
+    from paddle_tpu.obs import trace as obstrace
+    from paddle_tpu.serving import ServingMetrics
+    kw = dict(num_slots=3, max_len=64, kv_layout="paged",
+              kv_block_size=BLOCK, prefix_cache=False, prefill_chunk=8)
+    reqs = prompts([21, 5, 30, 11, 2], seed=7)
+    model = hybrid_lm.Served(hybrid_lm.config_from_hf(hf))
+    engine = DecodeEngine(params, model=model, name="narrow", **kw)
+    # each width traced once at warm-up: the discipline reads 1
+    assert engine.step_widths == (6, 12, 24)
+    assert engine.step_traces == {6: 1, 12: 1, 24: 1}
+    assert engine.step_trace_count == 1
+    engine.metrics = ServingMetrics()
+    obstrace.enable(sample=1.0, capacity=65536)
+    try:
+        narrow = _drive(engine, reqs)
+        phases = obstrace.debug_payload()["phases"]
+    finally:
+        obstrace.disable()
+    assert engine.step_traces == {6: 1, 12: 1, 24: 1}
+    m = engine.metrics
+    # the counters and the phase say the same of every step
+    stats = [ph["attrs"] for ph in phases
+             if ph["name"] == "engine.step.dispatch"]
+    assert len(stats) == m.decode_steps_total
+    assert {st["lanes"] for st in stats} == {24}
+    assert {st["width"] for st in stats} <= {6, 12, 24}
+    assert all(st["live"] <= st["width"] and
+               (st["width"] == 6 or st["live"] > st["width"] // 2)
+               for st in stats)
+    assert m.step_lanes_computed_total == sum(st["width"] for st in stats)
+    assert m.step_lanes_live_total == sum(st["live"] for st in stats)
+    assert m.step_lanes_live_total < m.step_lanes_computed_total \
+        < 24 * m.decode_steps_total
+    snap = m.snapshot()
+    assert snap["step_lanes_computed_total"] == m.step_lanes_computed_total
+    assert "step_lanes_live_total" in m.render_prometheus()
+
+    monkeypatch.setattr(hybrid_lm, "STEP_WIDTH_FRACTIONS", (1,))
+    wide_engine = DecodeEngine(params, model=model, name="wide", **kw)
+    assert wide_engine.step_traces == {24: 1}
+    wide_engine.metrics = ServingMetrics()
+    assert _drive(wide_engine, reqs) == narrow
+    wm = wide_engine.metrics
+    assert wm.step_lanes_computed_total == 24 * wm.decode_steps_total

@@ -170,3 +170,31 @@ def test_moe_layer_nested_and_multi_input():
     b = L.data_layer("b", size=8)
     with pytest.raises(ConfigError, match="single input"):
         L.moe_layer([a, b], n_experts=2)
+
+
+@pytest.mark.parametrize("places", [12, 16, 32])
+def test_routed_experts_skip_the_places_that_repeat_a_lane(places):
+    """The served layer over a packed axis (models/hybrid_lm.py): 12 live
+    tokens and a tail that repeats the last one, marked not ``valid``.  The
+    live tokens get what they get alone whatever the width, the repeats get
+    nothing, and the grouped products see the live pairs only."""
+    d, f, experts, k = 16, 8, 8, 2
+    ks = jax.random.split(jax.random.PRNGKey(1), 6)
+    layer = {"wg": jax.random.normal(ks[0], (4, d, f)) * d ** -0.5,
+             "wu": jax.random.normal(ks[1], (4, d, f)) * d ** -0.5,
+             "wd": jax.random.normal(ks[2], (4, f, d)) * f ** -0.5}
+    router = jax.random.normal(ks[3], (d, experts))
+    x = jax.random.normal(ks[4], (12, d))
+    held = (2, 4)                   # experts 2..5 of 8 are held here
+
+    def layer_over(x, valid):
+        idx, w = moe.sigmoid_router(x, router, jnp.zeros((experts,)), k, 2.5)
+        return moe.routed_experts(x, idx, w, layer, held, valid=valid), idx
+
+    want, idx = layer_over(x, None)
+    packed = jnp.concatenate([x, jnp.broadcast_to(x[-1], (places - 12, d))])
+    got, _ = layer_over(packed, jnp.arange(places) < 12)
+    np.testing.assert_allclose(got[:12], want, atol=1e-5)
+    assert float(jnp.abs(got[12:]).max(initial=0.0)) == 0.0
+    mine = (np.asarray(idx) >= 2) & (np.asarray(idx) < 6)
+    assert 0 < mine.sum() < 12 * k and float(jnp.abs(want).max()) > 0

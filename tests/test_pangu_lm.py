@@ -166,12 +166,17 @@ def _layer_case(rotation, query_rank, seed=0):
     tables = np.random.RandomState(seed).permutation(
         np.arange(1, blocks)).reshape(s, nb_row).astype(np.int32)
     tables[4] = 0
-    h = jax.random.normal(ks[6], (s, kk, d))
-    li, qpos = _chunk_lanes(jnp.asarray([41, 13, 30, 0, 0]),
-                            jnp.asarray([1, 8, 3, 5, 1]), kk)
+    lens = np.asarray([1, 8, 3, 5, 1])
+    _li, qpos = _chunk_lanes(jnp.asarray([41, 13, 30, 0, 0]),
+                             jnp.asarray(lens), kk)
+    # the 18 live lanes packed into 24 places: the last six repeat a lane
+    src, back = hybrid_lm.pack_lanes(lens, kk)
+    src = jnp.asarray(src[:24])
+    h = jax.random.normal(ks[6], (s * kk, d))[src]
     kw = dict(num_heads=heads, nope=nope, rope=rope, v_dim=v, rank=rank,
               eps=1e-5, rope_theta=1e4 if rotation else None)
-    return (p, h, pool, li, qpos, jnp.asarray(tables)), kw
+    return (p, h, pool, qpos, jnp.asarray(tables), src,
+            jnp.asarray(back)), kw
 
 
 @pytest.mark.parametrize("lg", [8, 2], ids=["one_group", "groups_of_2"])
@@ -189,10 +194,13 @@ def test_mla_kernel_interpreted_matches_xla(monkeypatch, rotation,
     mla_kernel.mla_attend.clear_cache()
     np.testing.assert_array_equal(got_pool, want_pool)
     np.testing.assert_allclose(got, want, atol=1e-5)
-    # lanes past a row's length read zero on either path
-    live = np.asarray(args[3]) == np.arange(8)
-    assert float(jnp.abs(jnp.where(live[:, :, None], 0.0, got)).max()) == 0.0
-    assert float(jnp.abs(got[1]).min()) > 0.0
+    # every place holds its lane's result, the repeats their lane's too,
+    # and the repeats wrote nothing (a poisoned one would have won a place)
+    assert got.shape == (24, 32) and float(jnp.abs(got).min()) > 0.0
+    np.testing.assert_array_equal(got[18:], jnp.broadcast_to(got[17], (6, 32)))
+    poisoned = (args[0], args[1].at[18:].set(7.0)) + args[2:]
+    _y, pool = jax.jit(lambda *a: mla.mla_chunk(*a, **kw))(*poisoned)
+    np.testing.assert_array_equal(pool, want_pool)
 
 
 def test_mla_kernel_guard_names_its_reason():
