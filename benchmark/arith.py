@@ -32,3 +32,57 @@ def token_gaps_ms(token_times):
 
 def intervals(times):
     return [b - a for a, b in zip(times, times[1:])]
+
+
+def loss_noise(exact, stated):
+    """How far the STATED precision alone moves a mean cross-entropy on
+    this batch.  ``exact`` and ``stated`` are the reference's margins of the
+    same rows (a row's loss is softplus(-margin)), computed in float32 and
+    as the configuration states the program computes.  A row's share of the
+    loss error is off_label x d, with off_label = sigmoid(-margin) the slope
+    of its loss and d the margin's error; the shares of B rows add like
+    B independent errors plus one they have in common (every row sees the
+    same rounded weights), so the root of the mean SQUARE of the shares is
+    the scale of both: returns sqrt(mean((off_label x d)^2))."""
+    total = 0.0
+    for m, ms in zip(exact, stated):
+        off = 0.5 * (1.0 - math.tanh(0.5 * m))
+        total += (off * (ms - m)) ** 2
+    return math.sqrt(total / len(exact))
+
+
+def loss_limit(rc, noise):
+    """How far a loss may lie from the reference's as stated: the
+    configuration's share of ``noise`` (loss_noise, on this batch) and a
+    floor of a few float32 steps of the loss itself."""
+    return rc["loss_noise_share"] * noise + rc["loss_floor"]
+
+
+def worst_leaf_gap(got, want, live=None):
+    """(gap, leaf): the widest gap between a leaf's norm as the program has
+    it and as the reference has it, against the reference's norm of that
+    leaf or of its median leaf, whichever is larger (some leaves are all
+    but zero).  ``got`` and ``want`` map leaf names to norms; ``live``
+    keeps only those leaves."""
+    median = percentile(list(want.values()), 50)
+    names = [k for k in want if live is None or k in live]
+    gaps = {}
+    for k in names:
+        gap, scale = abs(got[k] - want[k]), max(want[k], median)
+        # nothing moved on either side (a learning rate of nought): no gap
+        gaps[k] = gap / scale if scale > 0.0 else (math.inf if gap else 0.0)
+    worst = max(gaps, key=gaps.get)
+    return gaps[worst], worst
+
+
+def steps_to_fall(losses, first, share=0.7, span=20):
+    """The number of steps after which the mean of the last ``span`` losses
+    first lay under ``share`` x ``first``; None if it never did."""
+    total = 0.0
+    for i, x in enumerate(losses):
+        total += x
+        if i >= span:
+            total -= losses[i - span]
+        if i >= span - 1 and total / span < share * first:
+            return i + 1
+    return None

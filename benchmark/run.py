@@ -8,7 +8,8 @@ name, and the driver the configuration names; refuses to run without the TPU
 the cell asks for; measures end-to-end metrics (--trace 0) or per-layer
 metrics under the profiler (--trace 1).  Detail goes on earlier lines; the
 last line holds ``correct``, ``attempted``, ``failed``, ``metrics``,
-``device`` and, traced, ``breakdown`` — and nothing else.
+``device``, traced ``breakdown`` and, where the driver gives them, last,
+``compared``: each number compared with its limit — and nothing else.
 
 ``--rehearsal`` runs the tiny cells of benchmark/testdata/BENCHMARK.json on
 whatever backend is there, marks every line ``"rehearsal": true`` and exits
@@ -67,7 +68,11 @@ def main(argv=None):
         value = spec.reader(group, m["name"]).read(obs)
         if value is not None:
             metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
-    device["memory_peak_bytes"] = harness.memory_peak_bytes(obs["devices"])
+    # a driver that runs its reference after the window reads the peak
+    # itself, before it: a process's peak never falls again
+    device["memory_peak_bytes"] = obs["memory_peak_bytes"] \
+        if "memory_peak_bytes" in obs \
+        else harness.memory_peak_bytes(obs["devices"])
     result = {"correct": bool(obs["correct"]), "attempted": obs["attempted"],
               "failed": obs["failed"], "metrics": metrics, "device": device}
     if args.trace and obs.get("trace"):
@@ -76,6 +81,13 @@ def main(argv=None):
         result["breakdown"] = obs["trace"]["breakdown"]
     if args.rehearsal:
         result["rehearsal"] = True
+    if obs.get("compared"):
+        # each number the driver compared beside its limit (null: not judged
+        # in this run): last in the line, and the last lines of stderr
+        result["compared"] = obs["compared"]
+        for name, (value, limit) in obs["compared"].items():
+            print(f"compared {name}: {value!r} limit {limit!r}",
+                  file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     if args.rehearsal:
         return harness.RC_REHEARSAL_OK if result["correct"] else 1

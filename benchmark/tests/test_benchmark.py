@@ -244,8 +244,11 @@ def test_each_driver_end_to_end_at_a_tiny_size(cell, trace, expect):
              if ln.startswith("{")]
     assert all(ln.get("rehearsal") is True for ln in lines)
     last = lines[-1]
-    assert set(last) == {"correct", "attempted", "failed", "metrics",
-                         "device", "rehearsal"}
+    # a driver that says what it compared has it last in the line
+    assert set(last) - {"compared"} == {"correct", "attempted", "failed",
+                                        "metrics", "device", "rehearsal"}
+    assert ("compared" in last) == (cell == "tiny_train")
+    assert "compared" not in last or list(last)[-1] == "compared"
     assert last["correct"] is True and last["failed"] == 0
     assert last["attempted"] > 0
     assert set(last["metrics"]) == expect

@@ -91,10 +91,15 @@ def test_a_trace_without_phases_answers_nothing():
 @pytest.mark.parametrize("cell,name", [(c, n) for c, ns in NEW.items()
                                        for n in ns])
 def test_reader_gives_none_without_a_device_trace(cell, name):
-    """The CPU rehearsal: the driver's ``trace`` is None."""
-    spec = harness.Spec()
+    phase_reader_entry_holds(harness.Spec(), cell, name)
+
+
+def phase_reader_entry_holds(spec, cell, name):
+    """The entry reads the program's phases and lists the cell it was
+    accepted for (later PRs may give it more); the CPU rehearsal, where the
+    driver's ``trace`` is None, reads nothing."""
     entry, = [m for m in spec.manifest["per_layer"] if m["name"] == name]
-    assert entry["source"] == "program_span" and entry["workloads"] == [cell]
+    assert entry["source"] == "program_span" and cell in entry["workloads"]
     read = spec.reader("per_layer", name).read
     assert read({"trace": None, "cell": spec.cell(cell)}) is None
 
