@@ -1,9 +1,12 @@
 """A served decoder-only trunk built from a configuration: RMSNorm, no
-biases, an untied head, a per-layer attention kind (``"kda"``: the gated
-delta rule with per-slot state, ops/kda.py; ``"mla"``: latent attention over
-the paged pool, ops/mla.py) and a per-layer FFN kind (``"dense"``: a gated
-SiLU FFN; ``"moe"``: a sigmoid-routed expert layer that holds its share of
-the experts plus a shared expert, ops/moe.py).
+biases, a head of its own or the embedding table's (``tie_embeddings``), a
+per-layer mixer kind (``"kda"``: the gated delta rule with per-slot state,
+ops/kda.py; ``"mla"``: latent attention over the paged pool, ops/mla.py;
+``"mamba"``: a selective scan with per-slot state, ops/mamba.py; ``"attn"``:
+softmax attention with grouped K/V over paged K and V pools,
+``attn_chunk``) and a per-layer FFN kind (``"dense"``: a gated SiLU FFN;
+``"moe"``: a sigmoid-routed expert layer that holds its share of the experts
+plus a shared expert, ops/moe.py).
 
     x += Attn_l(RMSNorm(x));  x += FFN_l(RMSNorm(x));  logits = RMSNorm(x_L) W_head
 
@@ -11,19 +14,22 @@ With ``post_norms`` each sublayer's result is normed again before it joins
 the stream (a sandwich: ``x += RMSNorm(Attn_l(RMSNorm(x)))``, four gains a
 layer).  An MLA layer may have a low-rank query with its own norm
 (``q_rank``) and rotate its 64 query columns and the shared key part
-(``rope_theta``).  Two published families build a ``Config``
-(``config_from_hf``): ``kimi_linear`` (KDA and unrotated MLA, 3 to 1) and
+(``rope_theta``).  Three published families build a ``Config``
+(``config_from_hf``): ``kimi_linear`` (KDA and unrotated MLA, 3 to 1),
 ``pangu_ultra_moe`` (MLA in every layer, rotated, a low-rank query,
-sandwich norms: a cache of latent pools only, no slot owns state).
+sandwich norms: a cache of latent pools only, no slot owns state) and
+``jamba`` (Mamba with one unrotated multi-query attention layer a period,
+dense FFNs, a tied head, no positional signal of any kind).
 
 ``DecodeEngine(params, model=Served(cfg))`` serves it through the one
 chunked paged step (docs/serving.md "Models that hold state"), whose
 residual stream holds the lanes the step's rows feed, packed, at the
 narrowest of up to three compiled widths ("The packed lanes").  The cache
 has two kinds of leaf, which ``cache_kinds`` declares: the MLA layers'
-latent pools are block-addressed like K/V; a KDA layer's recurrent state
-and convolution tail are slot-addressed, zeroed as data inside the step when
-a row starts at position 0, and left alone by lanes past a row's length.
+latent pools and the attention layers' K and V pools are block-addressed; a
+KDA or Mamba layer's recurrent state and convolution tail are
+slot-addressed, zeroed as data inside the step when a row starts at
+position 0, and left alone by lanes past a row's length.
 
 The residual stream, the norms, the router and the recurrence are float32;
 matrix products follow ``ops/linear.matmul``."""
@@ -36,7 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from paddle_tpu.models.transformer import _chunk_lanes
-from paddle_tpu.ops import kda, linear, mla, moe
+from paddle_tpu.ops import kda, linear, mamba, mla, moe
 from paddle_tpu.serving.kv_pool import BLOCK_LEAF, SLOT_LEAF
 
 
@@ -69,6 +75,16 @@ class Config:
     q_rank: int = None          # MLA: a low-rank query with its own norm
     rope_theta: float = None    # MLA: rotate q's rope columns and k_r
     post_norms: bool = False    # a norm after each sublayer as well
+    # mamba
+    mamba_inner: int = 0
+    mamba_state: int = 0
+    mamba_conv: int = 0
+    mamba_dt_rank: int = 0
+    # attn: softmax attention, ``attn_kv_heads`` K/V heads for all queries
+    attn_heads: int = 0
+    attn_kv_heads: int = 0
+    attn_head_dim: int = 0
+    tie_embeddings: bool = False    # the head is the embedding table
 
     @property
     def latent_width(self):
@@ -89,7 +105,11 @@ def config_from_hf(c):
     layer are read by presence.  Plus the groups a cut adds:
     ``assumed.kda_gate_rank`` and ``expert_parallel`` (the key that counts
     the routed experts then gives those held of ``num_experts_published``,
-    by rank ``rank``)."""
+    by rank ``rank``).  ``mamba_d_state`` and ``attn_layer_period`` are the
+    third family (``_jamba_config``)."""
+    if "mamba_d_state" in c and "attn_layer_period" in c:
+        return _jamba_config(c)
+
     def either(*keys):
         return next(c[k] for k in keys if k in c)
     la = c.get("linear_attn_config")
@@ -122,6 +142,37 @@ def config_from_hf(c):
         post_norms=bool(c.get("sandwich_norm")))
 
 
+def _jamba_config(c):
+    """The ``jamba`` family: layer i (from 0) attends when ``i %
+    attn_layer_period == attn_layer_offset`` and is a Mamba layer otherwise
+    (``layers_block_type`` of its ``configuration_jamba.py``); every
+    feed-forward part is the dense gated FFN, which is what ``num_experts``
+    1 makes of ``expert_layer_*``."""
+    if c["num_experts"] != 1:
+        raise NotImplementedError(
+            "routed experts inside a state-space model (num_experts "
+            f"{c['num_experts']}): the jamba family is served with dense "
+            "FFNs only")
+    if c.get("sliding_window"):
+        raise NotImplementedError("window attention layers are not served")
+    d, heads = c["hidden_size"], c["num_attention_heads"]
+    return Config(
+        vocab_size=c["vocab_size"], hidden_size=d,
+        layers=tuple(
+            ("attn" if i % c["attn_layer_period"] == c["attn_layer_offset"]
+             else "mamba", "dense") for i in range(c["num_hidden_layers"])),
+        rms_norm_eps=c["rms_norm_eps"],
+        kda_heads=0, kda_head_dim=0, conv_kernel=0, kda_gate_rank=0,
+        mla_heads=0, qk_nope=0, qk_rope=0, v_head_dim=0, kv_rank=0,
+        dense_width=c["intermediate_size"], expert_width=0, router_width=0,
+        held=(0, 0), top_k=0, routed_scale=0.0, shared_experts=0,
+        mamba_inner=c["mamba_expand"] * d, mamba_state=c["mamba_d_state"],
+        mamba_conv=c["mamba_d_conv"], mamba_dt_rank=c["mamba_dt_rank"],
+        attn_heads=heads, attn_kv_heads=c["num_key_value_heads"],
+        attn_head_dim=c.get("head_dim") or d // heads,
+        tie_embeddings=bool(c["tie_word_embeddings"]))
+
+
 # ------------------------------------------------------------ parameters
 
 def _normal(key, shape, std, dtype):
@@ -145,6 +196,34 @@ def _init_attn(key, cfg, kind, dtype):
             "wkvb": lin(ks[2], cfg.kv_rank,
                         cfg.mla_heads * (cfg.qk_nope + cfg.v_head_dim)),
             "wo": lin(ks[3], cfg.mla_heads * cfg.v_head_dim, d)}
+    if kind == "attn":
+        dh = cfg.attn_head_dim
+        return {"wqkv": lin(ks[0], d, (cfg.attn_heads
+                                       + 2 * cfg.attn_kv_heads) * dh),
+                "wo": lin(ks[1], cfg.attn_heads * dh, d)}
+    if kind == "mamba":
+        di, n, r = cfg.mamba_inner, cfg.mamba_state, cfg.mamba_dt_rank
+        # Mamba's own start: A = 1..n in every column, D = 1, dt in
+        # [1e-3, 1e-1] through the inverse softplus
+        dt = jnp.exp(jax.random.uniform(ks[4], (di,), jnp.float32,
+                                        jnp.log(1e-3), jnp.log(1e-1)))
+        return {
+            "w_in": lin(ks[0], d, 2 * di),
+            "conv": _normal(ks[1], (cfg.mamba_conv, di),
+                            cfg.mamba_conv ** -0.5, jnp.float32),
+            "conv_bias": jnp.zeros((di,), jnp.float32),
+            "w_x": lin(ks[2], di, r + 2 * n),
+            "dt_norm": jnp.ones((r,), jnp.float32),
+            "b_norm": jnp.ones((n,), jnp.float32),
+            "c_norm": jnp.ones((n,), jnp.float32),
+            "w_dt": lin(ks[3], r, di),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            # [n, d_inner]: d_state on sublanes, as the state lies
+            "a_log": jnp.broadcast_to(
+                jnp.log(jnp.arange(1, n + 1, dtype=jnp.float32))[:, None],
+                (n, di)),
+            "d": jnp.ones((di,), jnp.float32),
+            "w_out": lin(ks[5], di, d)}
     w, r = cfg.kda_width, cfg.kda_gate_rank
     # flash-linear-attention's own start: A in [1, 16], dt in [1e-3, 1e-1]
     dt = jnp.exp(jax.random.uniform(ks[4], (w,), jnp.float32,
@@ -195,7 +274,8 @@ def _init_layer(key, cfg, kinds, dtype):
 
 def init(key, cfg, dtype=jnp.float32, emb_std=0.02):
     """Seeded parameters: N(0, 1/fan_in) projections, N(0, emb_std)
-    embeddings, gains at 1, the router bias at 0.  ``dtype`` is that of the
+    embeddings (no ``head`` leaf where the table is the head), gains at 1,
+    the router bias at 0.  ``dtype`` is that of the
     matrices; gains, biases, the convolution and the router stay float32.
     Made one layer a jitted call, so that a model that nearly fills the
     device is never beside a second copy of itself."""
@@ -206,8 +286,9 @@ def init(key, cfg, dtype=jnp.float32, emb_std=0.02):
     by_kind = {kinds: layer(kinds) for kinds in set(cfg.layers)}
     table = jax.jit(lambda k, shape, std: _normal(k, shape, std, dtype),
                     static_argnums=(1, 2))
-    return {"emb": table(keys[0], (v, d), emb_std),
-            "head": table(keys[1], (d, v), d ** -0.5),
+    head = {} if cfg.tie_embeddings else {
+        "head": table(keys[1], (d, v), d ** -0.5)}
+    return {"emb": table(keys[0], (v, d), emb_std), **head,
             "norm_f": jnp.ones((d,), jnp.float32),
             "layers": [by_kind[kinds](k)
                        for kinds, k in zip(cfg.layers, keys[2:])]}
@@ -219,22 +300,43 @@ def init_cache(cfg, slots, blocks, block, latent_dtype=jnp.float32):
     """One entry a layer.  KDA: ``{"state" [slots, H, dk, dv] float32,
     "conv" [slots, W-1, 3*H*dk] float32}``, owned by the slot; MLA:
     ``{"latent" [blocks, block, pool_width(rank + rope)]}``, addressed
-    through the block tables (block 0 is the scratch block free rows point at)."""
+    through the block tables (block 0 is the scratch block free rows point
+    at).  Mamba: ``{"state" [slots, n, d_inner] float32, "conv" [slots, W-1,
+    d_inner] float32}``, owned by the slot; attn: ``{"k", "v" [blocks,
+    block, kv heads x head dim]}`` in the pools' dtype, addressed through
+    the tables."""
     h, dk = cfg.kda_heads, cfg.kda_head_dim
-    return [{"state": jnp.zeros((slots, h, dk, dk), jnp.float32),
-             "conv": jnp.zeros((slots, cfg.conv_kernel - 1, 3 * h * dk),
-                               jnp.float32)} if kind == "kda"
-            else {"latent": jnp.zeros(
-                (blocks, block, mla.pool_width(cfg.latent_width)),
-                latent_dtype)}
-            for kind, _ffn in cfg.layers]
+
+    def layer(kind):
+        if kind == "kda":
+            return {"state": jnp.zeros((slots, h, dk, dk), jnp.float32),
+                    "conv": jnp.zeros((slots, cfg.conv_kernel - 1,
+                                       3 * h * dk), jnp.float32)}
+        if kind == "mamba":
+            return {"state": jnp.zeros((slots, cfg.mamba_state,
+                                        cfg.mamba_inner), jnp.float32),
+                    "conv": jnp.zeros((slots, cfg.mamba_conv - 1,
+                                       cfg.mamba_inner), jnp.float32)}
+        if kind == "attn":
+            shape = (blocks, block, cfg.attn_kv_heads * cfg.attn_head_dim)
+            return {"k": jnp.zeros(shape, latent_dtype),
+                    "v": jnp.zeros(shape, latent_dtype)}
+        return {"latent": jnp.zeros(
+            (blocks, block, mla.pool_width(cfg.latent_width)), latent_dtype)}
+
+    return [layer(kind) for kind, _ffn in cfg.layers]
+
+
+_LEAF_KINDS = {"kda": {"state": SLOT_LEAF, "conv": SLOT_LEAF},
+               "mamba": {"state": SLOT_LEAF, "conv": SLOT_LEAF},
+               "attn": {"k": BLOCK_LEAF, "v": BLOCK_LEAF},
+               "mla": {"latent": BLOCK_LEAF}}
 
 
 def cache_kinds(cfg):
     """The cache's tree with ``kv_pool.SLOT_LEAF`` / ``BLOCK_LEAF`` in
     place of each buffer."""
-    return [{"state": SLOT_LEAF, "conv": SLOT_LEAF} if kind == "kda"
-            else {"latent": BLOCK_LEAF} for kind, _ffn in cfg.layers]
+    return [dict(_LEAF_KINDS[kind]) for kind, _ffn in cfg.layers]
 
 
 # ------------------------------------------------------------------ step
@@ -270,6 +372,51 @@ def pack_lanes(lengths, kk):
     return src, back.astype(np.int32)
 
 
+def attn_chunk(p, h, k_pool, v_pool, qpos, tables, src, back, *, num_heads,
+               kv_heads, head_dim):
+    """One softmax attention layer with grouped K/V over the step's packed
+    lanes, nothing rotated.  p: ``wqkv`` (q | k | v columns) and ``wo``, h
+    ``[N, d]`` the normed input of the packed lanes, k_pool / v_pool
+    ``[blocks, block, kv_heads x head_dim]``, qpos ``[S, K]`` the lanes'
+    positions, tables ``[S, blocks_per_row]``, src ``[N]`` / back ``[S, K]``
+    the packing -> (y ``[N, d]``, new K pool, new V pool).
+
+    The projections run on the ``N`` packed lanes and K and V are written
+    from them, position by position, BEFORE the read (a place that repeats
+    a lane writes nothing), so causality inside the chunk is the ordinary
+    mask.  The attention keeps ``[S, K]`` rows: the paged decode kernel
+    (``decode_attention.maybe_paged_chunk``), or where it declines each
+    row's blocks gathered through its table and ``[S, K, H, T]`` scores."""
+    from paddle_tpu.ops.pallas import decode_attention
+    s, kk = qpos.shape
+    block, dkv = k_pool.shape[1], kv_heads * head_dim
+    d_q = num_heads * head_dim
+    qkv = linear.matmul(h, p["wqkv"])
+    row, pos = src // kk, qpos.reshape(-1)[src]
+    blk = jnp.where(mla.own_places(src, back), tables[row, pos // block],
+                    k_pool.shape[0])
+    write = lambda pool, new: pool.at[blk, pos % block].set(
+        new.astype(pool.dtype), mode="drop")
+    k_pool = write(k_pool, qkv[:, d_q:d_q + dkv])
+    v_pool = write(v_pool, qkv[:, d_q + dkv:])
+    q = qkv[:, :d_q].astype(k_pool.dtype)[back]             # [S, K, H x dh]
+    o = decode_attention.maybe_paged_chunk(q, k_pool, v_pool, qpos, tables,
+                                           num_heads)
+    if o is None:
+        group = num_heads // kv_heads
+        rows = lambda pool: pool[tables].reshape(s, -1, kv_heads, head_dim)
+        keys, values = rows(k_pool), rows(v_pool)
+        scores = linear.einsum(
+            "skvgd,stvd->skvgt", q.reshape(s, kk, kv_heads, group, head_dim),
+            keys) * head_dim ** -0.5
+        live = jnp.arange(keys.shape[1])[None, None, :] <= qpos[:, :, None]
+        probs = jax.nn.softmax(
+            jnp.where(live[:, :, None, None, :], scores, -jnp.inf), axis=-1)
+        o = linear.einsum("skvgt,stvd->skvgd", probs, values)
+    o = o.reshape(s * kk, d_q)[src]
+    return linear.matmul(o, p["wo"]), k_pool, v_pool
+
+
 def decode_chunk(params, cfg, tokens, positions, lengths, cache, tables,
                  with_routes=False, packing=None):
     """``lm_decode_chunk_paged``'s lane semantics: tokens ``[S, K]``,
@@ -286,7 +433,8 @@ def decode_chunk(params, cfg, tokens, positions, lengths, cache, tables,
     back [S, K])`` says which lanes (``pack_lanes``; ``N`` is ``src``'s
     static length and must hold every live lane).  ``[S, K]`` is rebuilt
     only where a kernel needs a row's lanes side by side (``mla_chunk``'s
-    attention, KDA's convolution and recurrence); the latents are written
+    attention, KDA's convolution and recurrence, ``attn_chunk``'s
+    attention; a Mamba layer never does); the latents, K and V are written
     and the head reads from the packed lanes.  Without ``packing`` every
     lane keeps its place, ``N = S x K``: the widest case of the same
     trunk."""
@@ -310,6 +458,17 @@ def decode_chunk(params, cfg, tokens, positions, lengths, cache, tables,
                 src, back, num_heads=cfg.kda_heads,
                 head_dim=cfg.kda_head_dim, eps=eps)
             new_cache.append({"state": state, "conv": tail})
+        elif attn_kind == "mamba":
+            y, state, tail = mamba.mamba_chunk(
+                lp["attn"], h, c["state"], c["conv"], positions, lengths,
+                src, back, dt_rank=cfg.mamba_dt_rank, eps=eps)
+            new_cache.append({"state": state, "conv": tail})
+        elif attn_kind == "attn":
+            y, k_pool, v_pool = attn_chunk(
+                lp["attn"], h, c["k"], c["v"], qpos, tables, src, back,
+                num_heads=cfg.attn_heads, kv_heads=cfg.attn_kv_heads,
+                head_dim=cfg.attn_head_dim)
+            new_cache.append({"k": k_pool, "v": v_pool})
         else:
             y, pool = mla.mla_chunk(
                 lp["attn"], h, c["latent"], qpos, tables, src, back,
@@ -335,8 +494,10 @@ def decode_chunk(params, cfg, tokens, positions, lengths, cache, tables,
         x = x + (kda.rms_norm(y, lp["post_ffn"], eps) if cfg.post_norms
                  else y)
     last = x[jnp.take_along_axis(back, (lengths - 1)[:, None], axis=1)[:, 0]]
-    logits = linear.matmul(kda.rms_norm(last, params["norm_f"], eps),
-                           params["head"])
+    last = kda.rms_norm(last, params["norm_f"], eps)
+    # a tied head contracts the table's own columns: no transposed copy
+    logits = linear.einsum("sd,vd->sv", last, params["emb"]) \
+        if cfg.tie_embeddings else linear.matmul(last, params["head"])
     if with_routes:
         return logits, new_cache, routes
     return logits, new_cache
@@ -392,12 +553,18 @@ class Served:
         aux = jnp.stack(routes) if routes else jnp.zeros((0,), jnp.int32)
         return logits, cache, aux
 
-    def kernel_report(self, kk, block):
+    def kernel_report(self, kk, block, slots):
         """{"kda_kernels", "kda_decline_reason", "mla_kernels",
-        "mla_decline_reason"} for a step of ``kk`` lanes over blocks of
-        ``block`` positions, each from its kernel's own predicate; False
-        and no reason for a kind of layer the model does not have."""
+        "mla_decline_reason", "mamba_kernels", "mamba_decline_reason",
+        "attn_kernels", "attn_decline_reason"} for a step of ``slots`` rows
+        of ``kk`` lanes over blocks of ``block`` positions, each from its
+        kernel's own predicate (``attn``: the paged decode-attention kernel
+        under the ``"attn"`` layers; ``mamba``: at every width the step is
+        compiled at); False and no reason for a kind of layer the model
+        does not have."""
+        from paddle_tpu.ops.pallas import decode_attention
         from paddle_tpu.ops.pallas import kda as kda_kernel
+        from paddle_tpu.ops.pallas import mamba as mamba_kernel
         from paddle_tpu.ops.pallas import mla as mla_kernel
         cfg = self.cfg
         kinds = {kind for kind, _f in cfg.layers}
@@ -407,7 +574,17 @@ class Served:
                "mla": mla_kernel.decline_reason(
                    kk, cfg.mla_heads, mla.pool_width(cfg.latent_width),
                    cfg.kv_rank, block, self.latent_dtype)
-               if "mla" in kinds else None}
+               if "mla" in kinds else None,
+               "mamba": next(filter(None, (
+                   mamba_kernel.decline_reason(
+                       width, slots, cfg.mamba_inner, cfg.mamba_state)
+                   for width in step_widths(slots, kk))), None)
+               if "mamba" in kinds else None,
+               "attn": decode_attention.decline_reason(
+                   cfg.attn_heads, cfg.attn_heads * cfg.attn_head_dim,
+                   cfg.attn_kv_heads * cfg.attn_head_dim, block, paged=True,
+                   chunk=kk)
+               if "attn" in kinds else None}
         return {**{k + "_kernels": k in kinds and why[k] is None
                    for k in why},
                 **{k + "_decline_reason": why[k] for k in why}}

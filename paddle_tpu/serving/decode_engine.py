@@ -130,7 +130,13 @@ class DecodeEngine:
     docs/serving.md "Chunked prefill"), a decoding slot feeds one.
     prefill_chunk_budget: max teacher-forced lanes one step may feed
     across all slots (0 = unbounded) — pure data, bounds per-step
-    prefill work and hence TPOT jitter.
+    prefill work and hence TPOT jitter.  With ``model=`` it also bounds
+    the widths the step is compiled at: no step feeds more than each
+    row's own lane and the budget's.
+    report_logits (``model=`` only): every step also leaves its logits
+    ``[num_slots, vocab]`` on the device, beside what the model reports
+    (``step_aux``, ``recorded_steps()``): the compiled step that serves is
+    then the one a check or a log-probability surface reads.
 
     kv_layout: ``"slab"`` (default — one ``[num_slots, max_len, Dkv]``
     row per slot) or ``"paged"`` (a shared ``[kv_num_blocks,
@@ -165,7 +171,7 @@ class DecodeEngine:
                  prefix_cache=True, prefill_chunk=8,
                  prefill_chunk_budget=0, kv_dtype="float32",
                  speculate_k=0, draft=None, mesh=None, kv_host_bytes=0,
-                 model=None):
+                 model=None, report_logits=False):
         from paddle_tpu.models import transformer
         self._transformer = transformer
         # model=None: the transformer trunk (every default below).  A
@@ -181,6 +187,11 @@ class DecodeEngine:
                 speculate_k=speculate_k, kv_host_bytes=kv_host_bytes,
                 mesh=mesh, kv_dtype=kv_dtype)
             self._leaf_kinds = model.cache_kinds()
+        elif report_logits:
+            raise ConfigError(
+                "report_logits serves with model= only: the trunk's step "
+                "takes its argmax inside the sharded body")
+        self.report_logits = bool(report_logits)
         # does a slot own state that seating must reset (a SLOT_LEAF)
         self._slot_state = SLOT_LEAF in jax.tree_util.tree_leaves(
             self._leaf_kinds)
@@ -453,6 +464,13 @@ class DecodeEngine:
         # and for its latent-attention kernel (ops/pallas/mla.py)
         self.mla_kernels = False
         self.mla_decline_reason = None
+        # its selective-scan kernel (ops/pallas/mamba.py)
+        self.mamba_kernels = False
+        self.mamba_decline_reason = None
+        # and the paged decode-attention kernel under its softmax
+        # attention layers (the trunk's own is ``decode_kernels``)
+        self.attn_kernels = False
+        self.attn_decline_reason = None
         # what a model's last step reported of itself (hybrid_lm: the
         # chosen experts), left on the device; None for the trunk
         self.step_aux = None
@@ -487,6 +505,8 @@ class DecodeEngine:
                     p, _lane0_from_device(tokens, prev), pos, lens, cache,
                     tables, src, back)
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                if self.report_logits:
+                    aux = (aux, logits)
                 return (nxt, aux), cache
         elif self.kv_layout == "paged":
             def _model(p, cache, tokens, pos, lens, tables):
@@ -524,6 +544,13 @@ class DecodeEngine:
         # widths it is worth compiling (hybrid_lm.step_widths)
         self.step_widths = (self.num_slots * self._kk,) if model is None \
             else tuple(model.step_widths(self.num_slots, self._kk))
+        if model is not None and self.prefill_chunk_budget:
+            # a budgeted step feeds each row's own lane and the budget's
+            # at most: a wider program would be compiled and never run
+            most = self.num_slots + self.prefill_chunk_budget
+            self.step_widths = tuple(
+                w for i, w in enumerate(self.step_widths)
+                if i == 0 or self.step_widths[i - 1] < most)
 
         # block writes and copies touch the block-addressed leaves alone
         # (every leaf of the transformer trunk's cache)
@@ -601,8 +628,20 @@ class DecodeEngine:
 
     def recorded_steps(self):
         """[(tokens [S, K], positions [S], lengths [S], report)] of the
-        steps since ``record_steps()``, oldest first."""
+        steps since ``record_steps()``, oldest first; with
+        ``report_logits`` the report is (the model's, logits [S, V]), on
+        the device."""
         return list(self._step_log or ())
+
+    def slot_state(self, slot):
+        """Host copies of what ``slot`` owns of a model's cache, as the
+        last committed step left it: the cache's tree with the slot's part
+        of each slot-addressed leaf, ``None`` for a block-addressed one.
+        For a check BETWEEN steps (nothing in flight: a step handed over
+        meanwhile donates the buffers this reads)."""
+        return jax.tree_util.tree_map(
+            lambda leaf, kind: np.asarray(leaf[slot])
+            if kind == SLOT_LEAF else None, self._cache, self._leaf_kinds)
 
     def _build_paged_cache(self):
         """A fresh, zeroed paged cache: the model's own, or the
@@ -668,12 +707,15 @@ class DecodeEngine:
         self._pos[slot] = pos
 
     def _set_cache_gauges(self):
-        """The bytes a model's two kinds of cache leaf hold (0 and 0 for
-        the transformer trunk, whose pool ``kv_blocks_*`` describe)."""
+        """The bytes a model's two kinds of cache leaf hold, and the share
+        of the first that ONE slot owns (0 for the transformer trunk, whose
+        pool ``kv_blocks_*`` describe)."""
         if self._model is not None:
+            slot_bytes = leaf_bytes(self._cache, self._leaf_kinds, SLOT_LEAF)
             self._metrics.set_state_cache_bytes(
-                leaf_bytes(self._cache, self._leaf_kinds, SLOT_LEAF),
-                leaf_bytes(self._cache, self._leaf_kinds, BLOCK_LEAF))
+                slot_bytes,
+                leaf_bytes(self._cache, self._leaf_kinds, BLOCK_LEAF),
+                slot_bytes // self.num_slots)
 
     @property
     def free_slots(self):
@@ -1612,19 +1654,23 @@ class DecodeEngine:
                     "reference path: %s", self.name,
                     self.decode_decline_reason)
         if self._model is not None:
-            report = self._model.kernel_report(self._kk, self.block_size)
+            report = self._model.kernel_report(self._kk, self.block_size,
+                                               self.num_slots)
             for key, value in report.items():
                 setattr(self, key, value)
             for kernel, instead in (
                     ("kda", "XLA scan (every lane rewrites the state)"),
-                    ("mla", "XLA gather and [S, K, H, T] scores")):
+                    ("mla", "XLA gather and [S, K, H, T] scores"),
+                    ("mamba", "XLA scan (every lane rewrites every state)"),
+                    ("attn", "XLA gather and [S, K, H, T] scores")):
                 if report[kernel + "_decline_reason"]:
                     logger.warning(
-                        "decode[%s]: %s_chunk kernel declined -> %s: %s",
+                        "decode[%s]: %s kernel declined -> %s: %s",
                         self.name, kernel, instead,
                         report[kernel + "_decline_reason"])
             self.metrics.set_model_kernels(self.kda_kernels,
-                                           self.mla_kernels)
+                                           self.mla_kernels,
+                                           self.mamba_kernels)
         self.metrics.set_prefill_chunk(self.prefill_chunk)
         self.metrics.set_kv_dtype(self.kv_dtype)
         self.metrics.set_speculate_k(self.speculate_k)
@@ -1692,12 +1738,13 @@ class DecodeEngine:
         """The warm line's account of the path the compiled step took."""
         if self.decode_kernels:
             return f"fused-pallas, {self.decode_tile} positions a step"
-        fused = [k for k in ("kda", "mla") if getattr(self, k + "_kernels")]
+        model = ("kda", "mla", "mamba", "attn")
+        fused = [k for k in model if getattr(self, k + "_kernels")]
         if fused:
             return "fused-pallas (%s)" % ", ".join(fused)
-        return "xla-ref (%s)" % (self.kda_decline_reason
-                                 or self.mla_decline_reason
-                                 or self.decode_decline_reason)
+        return "xla-ref (%s)" % next(filter(None, (
+            getattr(self, k + "_decline_reason")
+            for k in model + ("decode",))), None)
 
     def lower(self, what="step"):
         """``jax.stages.Lowered`` of the decode step (the structure

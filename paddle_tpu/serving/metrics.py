@@ -113,9 +113,11 @@ class ServingMetrics:
         self.step_lanes_computed_total = 0
         self.step_lanes_live_total = 0
         # facts, set at warm-up: did a model's step (DecodeEngine(model=))
-        # take its recurrent (kda_chunk) and latent (mla_chunk) kernels
+        # take its recurrent (kda_chunk), latent (mla_chunk) and
+        # selective-scan (mamba_chunk) kernels
         self.kda_kernels = 0
         self.mla_kernels = 0
+        self.mamba_kernels = 0
         self.prefill_chunk_size = 0      # gauge: engine K (0 = ladder)
         self.evictions = {r: 0 for r in EVICT_REASONS}
         # ---- speculative decoding (serving/speculative.py): draft
@@ -142,6 +144,7 @@ class ServingMetrics:
         # ---- models that hold state (DecodeEngine(model=...)): what the
         # two kinds of cache leaf hold, and how often a slot started over
         self.recurrent_state_bytes = 0   # gauge: slot-addressed leaves
+        self.slot_state_bytes = 0        # gauge: those one slot owns
         self.latent_pool_bytes = 0       # gauge: the model's block pools
         self.state_resets_total = 0      # slots seated at position 0
         # ---- hierarchical KV host tier (decode_engine.py kv_host_bytes
@@ -255,10 +258,11 @@ class ServingMetrics:
             self.step_lanes_computed_total += int(computed)
             self.step_lanes_live_total += int(live)
 
-    def set_model_kernels(self, kda, mla):
+    def set_model_kernels(self, kda, mla, mamba):
         """Facts: the paths a model's compiled step took."""
         with self._lock:
             self.kda_kernels, self.mla_kernels = int(kda), int(mla)
+            self.mamba_kernels = int(mamba)
 
     def set_prefill_chunk(self, k):
         """Gauge: the engine's chunk size K (0 = legacy ladder mode)."""
@@ -305,12 +309,14 @@ class ServingMetrics:
         with self._lock:
             self.cow_forks += int(n)
 
-    def set_state_cache_bytes(self, slot_bytes, block_bytes):
-        """Gauges: bytes of a served model's slot-addressed state and of
-        its block-addressed pools (both 0 for the transformer trunk)."""
+    def set_state_cache_bytes(self, slot_bytes, block_bytes, per_slot):
+        """Gauges: bytes of a served model's slot-addressed state, of its
+        block-addressed pools, and of the state ONE slot owns over all
+        layers (all 0 for the transformer trunk)."""
         with self._lock:
             self.recurrent_state_bytes = int(slot_bytes)
             self.latent_pool_bytes = int(block_bytes)
+            self.slot_state_bytes = int(per_slot)
 
     def observe_state_reset(self, n=1):
         """A slot of a state-holding model was seated at position 0: the
@@ -487,6 +493,7 @@ class ServingMetrics:
                 "step_lanes_live_total": self.step_lanes_live_total,
                 "kda_kernels": self.kda_kernels,
                 "mla_kernels": self.mla_kernels,
+                "mamba_kernels": self.mamba_kernels,
                 "prefill_chunk_size": self.prefill_chunk_size,
                 "speculate_k": self.speculate_k,
                 "mesh_shards": self.mesh_shards,
@@ -509,6 +516,7 @@ class ServingMetrics:
                 "cow_forks_total": self.cow_forks,
                 "recurrent_state_bytes": self.recurrent_state_bytes,
                 "latent_pool_bytes": self.latent_pool_bytes,
+                "slot_state_bytes": self.slot_state_bytes,
                 "state_resets_total": self.state_resets_total,
                 "kv_spill_blocks_total": self.kv_spill_blocks_total,
                 "kv_restore_hits_total": self.kv_restore_hits_total,
@@ -691,7 +699,9 @@ class ServingMetrics:
             mesh_shards = self.mesh_shards
             state_bytes = self.recurrent_state_bytes
             latent_bytes = self.latent_pool_bytes
+            slot_state_bytes = self.slot_state_bytes
             kda_kernels, mla_kernels = self.kda_kernels, self.mla_kernels
+            mamba_kernels = self.mamba_kernels
         for metric, value, help_ in gen_counters:
             emit(metric, value, help_, mtype="counter")
         emit("prefill_chunk_size", chunk_size,
@@ -725,10 +735,15 @@ class ServingMetrics:
         emit("latent_pool_bytes", latent_bytes,
              "bytes of a served model's block-addressed pools (latent "
              "attention; 0 = the transformer trunk)")
+        emit("slot_state_bytes", slot_state_bytes,
+             "bytes of recurrent state ONE slot of a served model owns, all "
+             "layers (0 = the transformer trunk)")
         emit("kda_kernels", kda_kernels,
              "1 when a served model's step took the kda_chunk kernel")
         emit("mla_kernels", mla_kernels,
              "1 when a served model's step took the mla_chunk kernel")
+        emit("mamba_kernels", mamba_kernels,
+             "1 when a served model's step took the mamba_chunk kernel")
         emit("kv_cache_int8", int(kv_int8),
              "1 when the KV cache stores int8 + per-head scale sidecars "
              "(quantized serving; docs/serving.md)")

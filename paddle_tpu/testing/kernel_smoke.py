@@ -62,6 +62,12 @@ class Widths:
     kda_slots: int = 4      # kda_chunk: rows, heads of head_dim x head_dim
     kda_heads: int = 8      # state, lanes a row
     kda_chunk: int = 16
+    # mamba_chunk: rows x lanes packed at the narrowest step width, states
+    # of mamba_state x mamba_inner
+    mamba_slots: int = 4
+    mamba_chunk: int = 8
+    mamba_inner: int = 256
+    mamba_state: int = 8
     # mla_chunk: rows x lanes x heads over a latent pool of (rank + rope)
     # values a position, padded to whole lane tiles; blocks of block_size
     mla_slots: int = 4
@@ -96,6 +102,9 @@ SERVING = Widths(heads=16, kv_heads=16, head_dim=128, slots=8,
                  lstm_tiled_hidden=1280, lstm_tiled_len=25, blocked_hidden=1280, blocked_len=25,
                  # the kimilinear_reason cell's step: 32 slots of 32 heads
                  kda_slots=32, kda_heads=32, kda_chunk=16,
+                 # the jamba3b_longctx cell's step: 16 slots of 16 x 5,120
+                 mamba_slots=16, mamba_chunk=64, mamba_inner=5120,
+                 mamba_state=16,
                  # the pangu_longdoc cell's step at four of its 16 rows and
                  # contexts to 1,024: 128 heads x 64 lanes over 512 + 64
                  mla_slots=4, mla_heads=128, mla_chunk=64, mla_rank=512,
@@ -584,6 +593,59 @@ def _kda_case(w):
                 tol=(_TOL_INTERPRETED, _WHY_KDA))
 
 
+# ------------------------------------------------------ selective scan
+
+_WHY_MAMBA = ("mamba_chunk is float32 VPU and EUP arithmetic whether Mosaic "
+              "compiles it or the interpreter runs it (no MXU pass): "
+              "summation order and the exp are all that differ from the "
+              "float32 scan, and a bfloat16 state is 2^-9 * max|h| off and "
+              "fails")
+
+
+def _mamba_case(w):
+    """``mamba_chunk`` over a step's packed places against the XLA scan
+    over rows (ops/mamba.scan_xla): row 0 decodes (one lane), row 1 fills
+    every lane, row 2 is fresh (its stale state must not leak) and feeds
+    half, the rest decode; packed at the narrowest width that holds them,
+    so the last places repeat a lane and must be stepped over."""
+    from paddle_tpu.models import hybrid_lm
+    from paddle_tpu.ops import mamba
+    from paddle_tpu.ops.pallas import mamba as kernel
+    s, kk, d, n = w.mamba_slots, w.mamba_chunk, w.mamba_inner, w.mamba_state
+    lens = np.asarray([1, kk, kk // 2] + [1] * (s - 3))
+    width = next(x for x in hybrid_lm.step_widths(s, kk) if x >= lens.sum())
+    why = kernel.shape_problem(width, s, d, n,
+                               interpret=jax.default_backend() != "tpu")
+    if why:
+        return Declined(why)
+    src, back = hybrid_lm.pack_lanes(lens, kk)
+    src, back = jnp.asarray(src[:width]), jnp.asarray(back)
+    ks = jax.random.split(jax.random.PRNGKey(130), 6)
+    u = 0.5 * jax.random.normal(ks[0], (width, d))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (width, d)) - 2.0)
+    b, c = (0.5 * jax.random.normal(k, (width, n)) for k in ks[2:4])
+    a = -jnp.broadcast_to(jnp.arange(1.0, n + 1)[:, None], (n, d))
+    state = 0.5 * jax.random.normal(ks[4], (s, n, d))
+    fresh = jnp.zeros((s,), bool).at[2].set(True)
+    own = mamba.own_places(src, back)
+
+    def fn(u, dt, b, c, a, state):
+        y, new = kernel.mamba_chunk(u, dt, b, c, a, state,
+                                    *mamba.walk(src, back, fresh))
+        return jnp.where(own[:, None], y, 0.0), new
+
+    def oracle(u, dt, b, c, a, state):
+        y, new = mamba.scan_xla(u, dt, b, c, a, state, jnp.asarray(lens),
+                                fresh, src, back)
+        return jnp.where(own[:, None], y, 0.0), new
+
+    return Case(fn=fn, oracle=oracle, args=(u, dt, b, c, a, state),
+                err=_tree_rel_err,
+                facts={"state_bytes": int(state.size) * 4,
+                       "places": int(width), "live": int(lens.sum())},
+                tol=(_TOL_INTERPRETED, _WHY_MAMBA))
+
+
 # ---------------------------------------------------- latent attention
 
 def _mla_case(w):
@@ -666,6 +728,7 @@ CASES = {
         contexts=w.cell_contexts),
     "kda_chunk": _kda_case,
     "mla_chunk": _mla_case,
+    "mamba_chunk": _mamba_case,
 }
 
 

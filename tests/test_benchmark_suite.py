@@ -16,37 +16,36 @@ import os
 # and that is not listed raises at collection, and so does a listed name that
 # ``benchmark/tests`` no longer has: nothing is shadowed by accident.
 _OVERRIDDEN = {
-    "test_step_overlap_share_manifest_entry":
-        "benchmark/tests/test_step_overlap_share.py pins the entry to "
-        "per_layer[-1] and to PR 30's two cells; a PR that appends a metric "
-        "or a cell, as the contract says to, cannot keep that and may not "
-        "edit the file.  A `benchmark` PR relaxes the original and this "
-        "entry goes with its stand-in (PERF.md 7(h))",
+    "test_step_narrow_share_manifest_entry_lists_the_hybrid_cells":
+        "benchmark/tests/test_step_narrow_share.py pins the entry's cells to "
+        "PR 32's two; a PR that appends a cell to the list, as the contract "
+        "says to, cannot keep that and may not edit the file.  A `benchmark` "
+        "PR relaxes the original as PR 34 relaxed step_overlap_share's, and "
+        "this entry goes with its stand-in (PERF.md 7)",
 }
 
 
-def test_step_overlap_share_manifest_entry():
-    """The stand-in (``_OVERRIDDEN``).  Every assertion of the original that
-    still holds is kept: the entry as accepted, the cells it lists as
-    committed, each of them reporting it, the training cell not; and for
-    ``per_layer[-1]``, that the entry sits where PR 30 left it, after every
-    entry that PR left, so that what follows it was appended."""
+def test_step_narrow_share_manifest_entry_lists_the_hybrid_cells():
+    """The stand-in (``_OVERRIDDEN``), in the form PR 34 gave the
+    ``step_overlap_share`` test: the entry found by name with the fields it
+    was accepted with; its cells include PR 32's two, first and in order;
+    each of them reports it, and the trunk's and the trainer's cells do
+    not."""
     from benchmark import harness
     spec = harness.Spec()
-    per_layer = spec.manifest["per_layer"]
-    names = [m["name"] for m in per_layer]
-    assert names.index("step_overlap_share") == 26    # PR 30's list had 27
-    assert per_layer[26] == {
-        "name": "step_overlap_share", "unit": "%", "better": "higher",
+    entry, = [m for m in spec.manifest["per_layer"]
+              if m["name"] == "step_narrow_share"]
+    assert {k: v for k, v in entry.items() if k != "workloads"} == {
+        "name": "step_narrow_share", "unit": "%", "better": "higher",
         "source": "program_span", "layer": "the one jitted step",
-        "moves": "itl_p95_ms",
-        "workloads": ["opt1.3b_chat", "kimilinear_reason", "pangu_longdoc"]}
-    for cell in per_layer[26]["workloads"]:
-        assert "step_overlap_share" in [
+        "moves": "itl_p95_ms"}
+    assert entry["workloads"][:2] == ["kimilinear_reason", "pangu_longdoc"]
+    for cell in entry["workloads"]:
+        assert "step_narrow_share" in [
             m["name"] for m in spec.metrics_for(spec.cell(cell), "per_layer")]
-    assert "step_overlap_share" not in [
-        m["name"] for m in spec.metrics_for(spec.cell("lstm-h512_train"),
-                                            "per_layer")]
+    for cell in ("opt1.3b_chat", "lstm-h512_train"):
+        assert "step_narrow_share" not in [
+            m["name"] for m in spec.metrics_for(spec.cell(cell), "per_layer")]
 
 
 def _reexport(own):
