@@ -21,6 +21,7 @@ Each layer type registers a LayerImpl:
   apply(ctx, cfg, params, *inputs) -> output value
 """
 
+import collections
 import dataclasses
 from typing import Any, Callable, Dict, List, Optional
 
@@ -207,6 +208,7 @@ class Topology:
             outputs = [outputs]
         self.outputs = list(outputs)
         self.order = self._topo_sort(self.outputs)
+        self._lstm_projections = self._find_lstm_projections()
         self.data_layers = {n.name: n for n in self.order
                             if n.layer_type == "data"}
         for feed in extra_feeds:
@@ -230,6 +232,30 @@ class Topology:
         for out in outputs:
             visit(out, frozenset())
         return order
+
+    def _find_lstm_projections(self):
+        """{id(lstmemory node): the fc that feeds it} for every pair built
+        as ``networks.simple_lstm`` builds it: an ``fc`` of ONE input with
+        no activation, dropout or error clipping, read by that lstmemory
+        alone and not an output.  ``apply`` hands such an fc's input and
+        weight to the lstmemory unapplied (``ops.rnn.lstm(proj=)``: the
+        fused kernel forms the gate inputs in VMEM), unless the parameters
+        hold a bias for the fc or the call wants its value."""
+        readers = collections.Counter(
+            id(i) for node in self.order for i in node.inputs)
+        outputs = {id(o) for o in self.outputs}
+        pairs = {}
+        for node in self.order:
+            if node.layer_type != "lstmemory" or len(node.inputs) != 1:
+                continue
+            fc = node.inputs[0]
+            if (fc.layer_type == "fc" and len(fc.inputs) == 1
+                    and fc.cfg.get("act") is None
+                    and not fc.cfg.get("drop_rate")
+                    and not fc.cfg.get("error_clipping_threshold")
+                    and readers[id(fc)] == 1 and id(fc) not in outputs):
+                pairs[id(node)] = fc
+        return pairs
 
     def init(self, rng):
         """Initialize all parameters: {layer_name: {param_name: array}}.
@@ -279,7 +305,16 @@ class Topology:
         instead of re-applied."""
         ctx = Context(mode=mode, rng=rng, state=state, params=params)
         cache = {}
+        wanted = {id(o) for o in extra_outputs}
+        projections = {
+            lstm: fc for lstm, fc in self._lstm_projections.items()
+            if id(fc) not in wanted
+            and not (precomputed and fc.name in precomputed)
+            and "b" not in params.get(self._param_key(fc), {})}
+        unapplied = {id(fc) for fc in projections.values()}
         for node in self.order:
+            if id(node) in unapplied:
+                continue
             if precomputed and node.name in precomputed:
                 cache[id(node)] = precomputed[node.name]
                 continue
@@ -294,10 +329,14 @@ class Topology:
                 cache[id(node)] = feed[node.name]
                 continue
             impl = get_impl(node.layer_type)
-            ins = [cache[id(i)] for i in node.inputs]
+            fc = projections.get(id(node))
+            ins = [cache[id(i)]
+                   for i in (node if fc is None else fc).inputs]
             p = params.get(self._param_key(node), {})
             try:
-                val = impl.apply(ctx, node.cfg, p, *ins)
+                kwargs = {} if fc is None else {
+                    "proj": params[self._param_key(fc)]["w0"]}
+                val = impl.apply(ctx, node.cfg, p, *ins, **kwargs)
                 # reference ExtraLayerAttribute(drop_rate=...) applies to any
                 # layer's output; fc/mixed/dropout handle it inside their
                 # impls, everything else gets it here

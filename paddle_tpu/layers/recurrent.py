@@ -84,7 +84,10 @@ class _LstmImpl:
             p["b"] = jnp.zeros((7 * d,), dtypes.param_dtype())
         return p
 
-    def apply(self, ctx, cfg, params, x):
+    def apply(self, ctx, cfg, params, x, proj=None):
+        """``proj``: ``x`` is the input of the bias-free fc that feeds this
+        layer and ``proj`` its weight (graph.Topology hands the pair over
+        unapplied): the projection is the LSTM's own to compute."""
         d = cfg["size"]
         b = params.get("b")
         bias = b[:4 * d] if b is not None else None
@@ -96,7 +99,7 @@ class _LstmImpl:
             init = rnn_ops.LstmState(h=init[..., :d], c=init[..., d:])
         out, final = rnn_ops.lstm(as_seq(x), params["w"], bias=bias,
                                   check_i=ci, check_f=cf, check_o=co,
-                                  init_state=init,
+                                  init_state=init, proj=proj,
                                   reverse=cfg.get("reverse", False),
                                   act=cfg.get("act", "tanh"),
                                   gate_act=cfg.get("gate_act", "sigmoid"),

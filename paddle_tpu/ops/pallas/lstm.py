@@ -10,6 +10,15 @@ w_r and the peephole vectors stay resident in VMEM across all T steps,
 h/c live in VMEM scratch, and each step streams only its [bt, 4D] gate
 input in and its [bt, D] output out.
 
+The forward forms its own gate inputs where the caller hands it the layer's
+INPUT and the projection's weight (``lstm_fused(..., proj=W_x, bias=b)``):
+W_x [in, 4D] sits in VMEM beside w_r, each step streams x_t [bt, in] and
+computes (x_t W_x + b) + h_{t-1} W_r, so the [T, B, 4D] pre-activations
+are neither written to HBM by a projection nor read back here (4D values a
+token become ``in``).  The backward is the same kernel either way — it
+reads the saved activations, never the gate inputs — and the projection's
+own gradients are XLA products of its ``dgates`` output.
+
 The batch is tiled: grid = (B // bt, T), time innermost, both axes
 sequential.  Rows of a batch never interact in the recurrence, so a tile
 is a whole LSTM over bt rows; w_r and the peepholes keep a constant block
@@ -37,16 +46,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from paddle_tpu.core import dtypes
+from paddle_tpu.ops import linear
 from paddle_tpu.ops.pallas.common import (
     LANES as _LANES, lanes as _lanes, vmem_budget_bytes, vmem_limit_bytes)
 
 
-def _fwd_kernel(xs_ref, wr_ref, chk_ref, mask_ref,
+def _fwd_kernel(x_ref, wx_ref, b_ref, wr_ref, chk_ref, mask_ref,
                 hs_ref, cfin_ref, cs_ref, acts_ref, h_scr, c_scr,
                 *, d, nt, save_residuals):
     """cs_ref/acts_ref are None in the lean (inference) variant — the
     residual tensors are ~5x the HBM traffic of the h output, so
-    forward-only calls must not pay for them."""
+    forward-only calls must not pay for them.  wx_ref/b_ref are None where
+    x_ref already holds the gate inputs [bt, 4D]."""
     t = pl.program_id(1)          # axis 0 walks the batch tiles
 
     @pl.when(t == 0)
@@ -55,7 +67,16 @@ def _fwd_kernel(xs_ref, wr_ref, chk_ref, mask_ref,
         c_scr[:] = jnp.zeros_like(c_scr)
 
     h, c = h_scr[:], c_scr[:]
-    x4 = xs_ref[0].astype(jnp.float32)
+    if wx_ref is None:
+        x4 = x_ref[0].astype(jnp.float32)
+    else:
+        # the input projection as linear.matmul computes it (operands in
+        # the compute dtype, which W_x arrives in; float32 sums), then the
+        # bias, then the recurrent product: the order of the sums outside
+        x4 = jax.lax.dot_general(
+            x_ref[0].astype(wx_ref.dtype), wx_ref[:],
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) + b_ref[:]
     wr = wr_ref[:].astype(jnp.float32)
     gates = x4 + jax.lax.dot_general(
         h, wr, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
@@ -161,25 +182,49 @@ def _bwd_kernel(acts_ref, cs_ref, csp_ref, hsp_ref, wr_ref, chk_ref,
         dwr_ref[:] = dwr_scr[:]
 
 
-def _compiler_params(bt, d):
+def _compiler_params(plan_bytes):
     """grid = (batch tiles, time): the carry makes time sequential, the
     shared dW_r accumulator makes the tiles sequential.  The scoped-VMEM
     limit follows the plan ``batch_tile`` chose ``bt`` by: at d=512 even 64
     rows are over Mosaic's default 16 MiB (docs/kernels.md, VMEM table)."""
     return pltpu.CompilerParams(
         dimension_semantics=("arbitrary", "arbitrary"),
-        vmem_limit_bytes=vmem_limit_bytes(vmem_bytes(bt, d)))
+        vmem_limit_bytes=vmem_limit_bytes(plan_bytes))
 
 
-def _fwd(xs, w_r, checks, mask, interpret, bt, save_residuals):
-    nt, b, g = xs.shape
-    d = g // 4
+def _fwd(x, w_x, bias, w_r, checks, mask, interpret, bt, save_residuals):
+    """x: the gate inputs [T, B, 4D] (``w_x`` None), or the layer's input
+    [T, B, in] with its projection ``w_x`` [in, 4D] and ``bias`` [4D]."""
+    nt, b, d_in = x.shape
+    d = w_r.shape[0]
+    g = 4 * d
+    projected = w_x is not None
+
+    def const(ib, t):
+        return (0, 0)
+
+    in_specs = [pl.BlockSpec((1, bt, d_in), lambda ib, t: (t, ib, 0))]
+    operands = [x]
+    plan, out_dtype = vmem_bytes(bt, d), x.dtype
+    if projected:
+        cd = dtypes.compute_dtype()
+        out_dtype = jnp.promote_types(cd, jnp.float32)
+        in_specs += [pl.BlockSpec((d_in, g), const),
+                     pl.BlockSpec((1, g), const)]
+        operands += [w_x.astype(cd), bias.astype(jnp.float32).reshape(1, g)]
+        plan = max(plan, fwd_vmem_bytes(bt, d, d_in))
+    in_specs += [
+        pl.BlockSpec((d, g), const),
+        pl.BlockSpec((3, d), const),
+        pl.BlockSpec((1, bt, _LANES), lambda ib, t: (t, ib, 0)),
+    ]
+    operands += [w_r, checks, mask]
     out_specs = [
         pl.BlockSpec((1, bt, d), lambda ib, t: (t, ib, 0)),    # hs
         pl.BlockSpec((1, bt, d), lambda ib, t: (0, ib, 0)),    # c_final
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((nt, b, d), xs.dtype),
+        jax.ShapeDtypeStruct((nt, b, d), out_dtype),
         jax.ShapeDtypeStruct((1, b, d), jnp.float32),
     ]
     if save_residuals:
@@ -192,35 +237,31 @@ def _fwd(xs, w_r, checks, mask, interpret, bt, save_residuals):
             jax.ShapeDtypeStruct((nt, b, g), jnp.float32),
         ]
 
-    def kernel(xs_ref, wr_ref, chk_ref, mask_ref, hs_ref, cfin_ref,
-               *rest):
-        if save_residuals:
-            cs_ref, acts_ref, h_scr, c_scr = rest
-        else:
-            (h_scr, c_scr), cs_ref, acts_ref = rest, None, None
-        _fwd_kernel(xs_ref, wr_ref, chk_ref, mask_ref, hs_ref, cfin_ref,
-                    cs_ref, acts_ref, h_scr, c_scr,
+    def kernel(x_ref, *refs):
+        refs = list(refs)
+        wx_ref, b_ref = (refs.pop(0), refs.pop(0)) if projected \
+            else (None, None)
+        wr_ref, chk_ref, mask_ref, hs_ref, cfin_ref = refs[:5]
+        cs_ref, acts_ref = refs[5:7] if save_residuals else (None, None)
+        h_scr, c_scr = refs[-2:]
+        _fwd_kernel(x_ref, wx_ref, b_ref, wr_ref, chk_ref, mask_ref,
+                    hs_ref, cfin_ref, cs_ref, acts_ref, h_scr, c_scr,
                     d=d, nt=nt, save_residuals=save_residuals)
 
     outs = pl.pallas_call(
         kernel,
         name="lstm_fwd",
         grid=(b // bt, nt),
-        in_specs=[
-            pl.BlockSpec((1, bt, g), lambda ib, t: (t, ib, 0)),
-            pl.BlockSpec((d, g), lambda ib, t: (0, 0)),
-            pl.BlockSpec((3, d), lambda ib, t: (0, 0)),
-            pl.BlockSpec((1, bt, _LANES), lambda ib, t: (t, ib, 0)),
-        ],
+        in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((bt, d), jnp.float32),
             pltpu.VMEM((bt, d), jnp.float32),
         ],
-        compiler_params=_compiler_params(bt, d),
+        compiler_params=_compiler_params(plan),
         interpret=interpret,
-    )(xs, w_r, checks, mask)
+    )(*operands)
     if save_residuals:
         hs, cfin, cs, acts = outs
         return hs, cfin, cs, acts
@@ -229,9 +270,9 @@ def _fwd(xs, w_r, checks, mask, interpret, bt, save_residuals):
 
 
 def _bwd(interpret, bt, res, g_out):
-    w_r, checks, mask, hs, cs, acts = res
+    x, w_x, bias, w_r, checks, mask, hs, cs, acts = res
     dh_out, dcfin = g_out
-    xs_dtype = hs.dtype              # hs was emitted in xs.dtype
+    xs_dtype = hs.dtype      # hs was emitted in the gate inputs' dtype
     nt, b, dd = dh_out.shape
     d = dd
     gcols = 4 * d
@@ -273,26 +314,33 @@ def _bwd(interpret, bt, res, g_out):
             pltpu.VMEM((d, gcols), jnp.float32),
             pltpu.VMEM((bt, 3 * d), jnp.float32),
         ],
-        compiler_params=_compiler_params(bt, d),
+        compiler_params=_compiler_params(vmem_bytes(bt, d)),
         interpret=interpret,
     )(acts, cs, cs, hs, w_r, checks, mask, dh_out,
       dcfin.astype(jnp.float32))
 
     dchecks = dchk.sum(axis=0).reshape(3, d).astype(checks.dtype)
-    return dxs, dwr.astype(w_r.dtype), dchecks, None
+    dx, dwx, db = dxs, None, None
+    if w_x is not None:
+        # the kernel's dxs are the gate inputs' gradients: the projection's
+        # own are what autodiff gives for the fc it stands for
+        dx, dwx, db = jax.vjp(linear.fc, x, w_x, bias)[1](dxs)
+    return dx, dwx, db, dwr.astype(w_r.dtype), dchecks, None
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _fused(xs, w_r, checks, mask, interpret, bt):
-    hs, cfin, _, _ = _fwd(xs, w_r, checks, mask, interpret, bt,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _fused(x, w_x, bias, w_r, checks, mask, interpret, bt):
+    hs, cfin, _, _ = _fwd(x, w_x, bias, w_r, checks, mask, interpret, bt,
                           save_residuals=False)
     return hs, cfin
 
 
-def _fused_fwd_rule(xs, w_r, checks, mask, interpret, bt):
-    hs, cfin, cs, acts = _fwd(xs, w_r, checks, mask, interpret, bt,
-                              save_residuals=True)
-    return (hs, cfin), (w_r, checks, mask, hs, cs, acts)
+def _fused_fwd_rule(x, w_x, bias, w_r, checks, mask, interpret, bt):
+    hs, cfin, cs, acts = _fwd(x, w_x, bias, w_r, checks, mask, interpret,
+                              bt, save_residuals=True)
+    # the gate inputs themselves are no residual: the backward reads acts
+    x_res = None if w_x is None else x
+    return (hs, cfin), (x_res, w_x, bias, w_r, checks, mask, hs, cs, acts)
 
 
 _fused.defvjp(_fused_fwd_rule, _bwd)
@@ -314,6 +362,22 @@ def vmem_bytes(bt, d):
     return 4 * (resident + bt * per_row)
 
 
+def fwd_vmem_bytes(bt, d, d_in):
+    """The projected FORWARD's footprint, residuals saved: w_r (f32) and
+    W_x (compute dtype) resident in one buffer each, the bias and the
+    peepholes, and per row the h/c scratch (2d), the gate pre-activations
+    as a value (4d), x in the compute dtype, plus two buffers of every
+    streamed block: x (d_in), hs, cs and c_final (d each), acts (4d), the
+    mask (128).  From 0.1% under to 26% over the v5e compiler's own count
+    at d = 128..640 (docs/kernels.md carries the table); under the
+    backward's plan, which sets the tile, unless d_in is over 8d: a wide
+    input over a narrow state is what it is counted for."""
+    cd = jnp.dtype(dtypes.compute_dtype()).itemsize
+    resident = 4 * (4 * d * d + 7 * d) + cd * d_in * 4 * d
+    per_row = 4 * (6 * d + 2 * (7 * d + d_in + _LANES)) + cd * d_in
+    return resident + bt * per_row
+
+
 def batch_tile(b, d):
     """Rows per batch tile: the largest multiple of 8 that divides ``b``
     and whose ``vmem_bytes`` fits the budget — ``b`` itself (one tile)
@@ -326,35 +390,47 @@ def batch_tile(b, d):
     return 0
 
 
-def supported(b, d, act, gate_act, state_act, init_state):
+def supported(b, d, act, gate_act, state_act, init_state, d_in=None):
     """Kernel path preconditions; callers fall back to the scan otherwise.
     reverse is handled by the caller's time-flip (see rnn._fused_seq_apply).
     The VMEM guard keeps weights that cannot be resident off the kernel
     path (d=1280: w_r, its gradient's accumulator and output block are
     79 MB f32 — over a 16 MiB core, inside a v5e's 128 MiB); a batch too
-    large for one block is tiled, not declined."""
+    large for one block is tiled, not declined.  ``d_in``: the kernel is to
+    project its own input of that width (``lstm_fused(proj=)``), so W_x has
+    to fit beside w_r at the tile the backward's plan chose."""
+    bt = batch_tile(b, d)
     return (act == "tanh" and gate_act == "sigmoid" and state_act == "tanh"
             and init_state is None
-            and d % _LANES == 0 and batch_tile(b, d) > 0)
+            and d % _LANES == 0 and bt > 0
+            and (d_in is None or fwd_vmem_bytes(bt, d, d_in)
+                 <= vmem_budget_bytes(scoped_limit_raised=True)))
 
 
 def lstm_fused(xs_tm, mask_tm, w_r, check_i, check_f, check_o,
-               interpret=None):
+               proj=None, bias=None, interpret=None):
     """Whole-sequence fused LSTM.
 
-    xs_tm: [T, B, 4D] time-major pre-projected gate inputs (bias included).
+    xs_tm: [T, B, 4D] time-major pre-projected gate inputs (bias included);
+    or, with ``proj`` [in, 4D], the layer's input [T, B, in] itself, which
+    the forward kernel projects and adds ``bias`` [4D] (or nothing) to.
     mask_tm: [T, B] float 0/1.  Returns (hs_tm [T, B, D], final (h, c)).
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    nt, b, g = xs_tm.shape
-    d = g // 4
+    nt, b, _ = xs_tm.shape
+    d = w_r.shape[0]
     bt = batch_tile(b, d)
     assert bt, f"lstm_fused: no batch tile for b={b}, d={d} (supported())"
+    if proj is None and bias is not None:
+        raise ValueError("lstm_fused: a bias without proj= is already part "
+                         "of the gate inputs")
+    if proj is not None and bias is None:
+        bias = jnp.zeros((4 * d,), jnp.float32)
     checks = jnp.stack([
         jnp.zeros((d,), jnp.float32) if v is None else v.astype(jnp.float32)
         for v in (check_i, check_f, check_o)])
     mask_r = jnp.broadcast_to(
         mask_tm.astype(jnp.float32)[:, :, None], (nt, b, _LANES))
-    hs, cfin = _fused(xs_tm, w_r, checks, mask_r, interpret, bt)
+    hs, cfin = _fused(xs_tm, proj, bias, w_r, checks, mask_r, interpret, bt)
     return hs, (hs[-1], cfin[0].astype(hs.dtype))

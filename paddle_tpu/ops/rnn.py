@@ -128,6 +128,11 @@ def _fused_lstm_enabled():
 #: supported()'s decision externally.
 FUSED_DISPATCH_COUNT = 0
 
+#: of those, the LSTM dispatches in which the forward kernel took the
+#: layer's input and its projection (``lstm(proj=)``) and formed the gate
+#: inputs itself; counted at trace time like its neighbour.
+PROJECTED_DISPATCH_COUNT = 0
+
 
 # The mesh axis a multi-device jit shards the batch over, as (mesh, axis) —
 # set by the trainer around its step's trace (``batch_sharded_over``).
@@ -207,41 +212,51 @@ def _masked_scan(step, init_carry, xs_time_major, mask_time_major, reverse=False
 
 def lstm(seq: SequenceBatch, w_r, bias=None, check_i=None, check_f=None,
          check_o=None, reverse=False, act="tanh", gate_act="sigmoid",
-         state_act="tanh", init_state=None):
+         state_act="tanh", init_state=None, proj=None):
     """Whole-sequence LSTM (reference LstmLayer + SequenceToBatch).
 
     seq.data: [B, T, 4D] pre-projected gate inputs (the reference's lstmemory
-    also expects a 4*size mixed input).  bias: [4D].  Returns
+    also expects a 4*size mixed input); or, with ``proj`` [in, 4D], the
+    [B, T, in] input of that bias-free projection, which the fused forward
+    kernel then computes itself (the [T, B, 4D] gate inputs never reach
+    HBM) and every other path forms first.  bias: [4D].  Returns
     (SequenceBatch of h [B, T, D], final LstmState).
     """
-    b, t, d4 = seq.data.shape
-    d = d4 // 4
-    x = seq.data if bias is None else seq.data + bias
-    xs = x.transpose(1, 0, 2)                       # time-major [T, B, 4D]
+    global PROJECTED_DISPATCH_COUNT
+    b, d = seq.data.shape[0], w_r.shape[0]
     ms = seq.mask().transpose(1, 0)                 # [T, B]
-
+    fused = blocked = projected = False
     if _fused_lstm_enabled():
         # import inside the branch: a broken pallas install must not take
         # the scan fallback down with it
         from paddle_tpu.ops.pallas import lstm as pl_lstm
         from paddle_tpu.ops.pallas import lstm_blocked as pl_lstm_blk
-        b_k = _local_batch(b)
-        weights = (w_r, check_i, check_f, check_o)
-        if pl_lstm.supported(b_k, d, act, gate_act, state_act, init_state):
-            sb, (fh, fc) = _fused_seq_apply(
-                seq, xs, ms, reverse,
-                lambda x, m, w: pl_lstm.lstm_fused(x, m, *w), weights)
-            return sb, LstmState(h=fh, c=fc)
+        guard = (_local_batch(b), d, act, gate_act, state_act, init_state)
+        fused = pl_lstm.supported(*guard)
+        projected = proj is not None and pl_lstm.supported(
+            *guard, d_in=proj.shape[0])
         # over-VMEM hidden sizes: the gate-blocked forward keeps the carry
         # in VMEM and fuses the cell while streaming weight blocks (scan-
         # equivalent weight traffic; docs/kernels.md blocked-variant notes)
-        if pl_lstm_blk.supported(b_k, d, act, gate_act, state_act,
-                                 init_state):
-            sb, (fh, fc) = _fused_seq_apply(
-                seq, xs, ms, reverse,
-                lambda x, m, w: pl_lstm_blk.lstm_fused_blocked(x, m, *w),
-                weights)
-            return sb, LstmState(h=fh, c=fc)
+        blocked = not fused and pl_lstm_blk.supported(*guard)
+
+    x = seq.data
+    if not projected:
+        if proj is not None:
+            x = matmul(x, proj)
+        if bias is not None:
+            x = x + bias
+    xs = x.transpose(1, 0, 2)       # time-major [T, B, 4D], or [T, B, in]
+
+    if fused or blocked:
+        PROJECTED_DISPATCH_COUNT += projected
+        kernel = pl_lstm.lstm_fused if fused \
+            else pl_lstm_blk.lstm_fused_blocked
+        weights = (w_r, check_i, check_f, check_o)
+        sb, (fh, fc) = _fused_seq_apply(
+            seq, xs, ms, reverse, lambda x, m, w: kernel(x, m, *w),
+            (weights + (proj, bias)) if projected else weights)
+        return sb, LstmState(h=fh, c=fc)
 
     if init_state is None:
         init_state = LstmState(h=jnp.zeros((b, d), x.dtype),

@@ -223,10 +223,13 @@ def _tree_rel_err(got, want):
 
 # ------------------------------------------------------------------ RNN
 
-def _rnn_case(kind, w, batch=None, length=None, hidden=None):
+def _rnn_case(kind, w, batch=None, length=None, hidden=None, proj_in=None):
     """Fused-vs-scan equality (fwd + full BPTT grads) through the public
     rnn.{lstm,gru,simple_rnn} dispatch.  The dispatch mode is read at
-    TRACE time, so each side sets it inside its own traced body."""
+    TRACE time, so each side sets it inside its own traced body.
+    ``proj_in``: the LSTM is handed an input of that width and its
+    projection (``lstm(proj=)``: the forward kernel forms the gate inputs),
+    and ``wr`` is (w_r, W_x, the gate bias)."""
     from paddle_tpu.ops import rnn
 
     b, t, d = (batch or w.rnn_batch, length or w.rnn_len,
@@ -234,7 +237,8 @@ def _rnn_case(kind, w, batch=None, length=None, hidden=None):
     facts = {}
     gates = {"lstm": 4, "gru": 3, "simple_rnn": 1}[kind]
     rng = np.random.RandomState(7)
-    data = jnp.asarray(rng.randn(b, t, gates * d) * 0.3, jnp.float32)
+    data = jnp.asarray(rng.randn(b, t, proj_in or gates * d) * 0.3,
+                       jnp.float32)
     lengths = jnp.asarray(rng.randint(1, t + 1, (b,)), jnp.int32)
     probe = jnp.asarray(rng.randn(b, t, d), jnp.float32)
     scale = 1.0 / np.sqrt(d)
@@ -247,10 +251,17 @@ def _rnn_case(kind, w, batch=None, length=None, hidden=None):
         checks = [jnp.asarray(rng.randn(d) * 0.1, jnp.float32)
                   for _ in range(3)]
 
+        if proj_in:
+            wr = (wr, jnp.asarray(rng.randn(proj_in, 4 * d)
+                                  / np.sqrt(proj_in), jnp.float32),
+                  jnp.asarray(rng.randn(4 * d) * 0.1, jnp.float32))
+
         def loss(data, wr):
+            wr, *own = wr if proj_in else (wr,)
             out, final = rnn.lstm(SequenceBatch(data=data, lengths=lengths),
                                   wr, check_i=checks[0], check_f=checks[1],
-                                  check_o=checks[2])
+                                  check_o=checks[2],
+                                  **dict(zip(("proj", "bias"), own)))
             return (jnp.sum(out.data * probe) + jnp.sum(final.h)
                     + jnp.sum(final.c))
     elif kind == "gru":
@@ -706,6 +717,8 @@ CASES = {
     "lstm_fused": lambda w: _rnn_case("lstm", w),
     "lstm_fused_cell_batch": lambda w: _rnn_case(
         "lstm", w, batch=w.lstm_cell_batch),
+    "lstm_fused_projected": lambda w: _rnn_case(
+        "lstm", w, batch=w.lstm_cell_batch, proj_in=w.rnn_hidden // 4),
     "lstm_fused_tiled": lambda w: _rnn_case(
         "lstm", w, batch=w.lstm_tiled_batch, length=w.lstm_tiled_len,
         hidden=w.lstm_tiled_hidden),

@@ -291,12 +291,16 @@ def test_limit_handed_to_mosaic_covers_its_own_count(bt, d, in_context_mib):
     assert common.vmem_limit_bytes(pl.vmem_bytes(8, 128)) == 16 * mib
 
 
-def test_fused_kernel_takes_its_batch_shard_under_a_data_mesh(np_rng):
+@pytest.mark.parametrize("projected", [False, True],
+                         ids=["gate_inputs", "projected"])
+def test_fused_kernel_takes_its_batch_shard_under_a_data_mesh(
+        np_rng, projected):
     """GSPMD cannot partition a Mosaic kernel (on the chip a batch-sharded
     jit raises "Mosaic kernels cannot be automatically partitioned"), so
     under ``rnn.batch_sharded_over`` the kernels run per batch shard in a
     shard_map: forward and every gradient — the replicated weights'
-    included, summed over the shards — equal the single-device scan."""
+    included, summed over the shards; with ``proj=`` W_x and the bias ride
+    among them — equal the single-device scan."""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from paddle_tpu.parallel.mesh import AXIS_DATA, MeshConfig, make_mesh
     mesh = make_mesh(MeshConfig(data=2), devices=jax.devices()[:2])
@@ -305,33 +309,40 @@ def test_fused_kernel_takes_its_batch_shard_under_a_data_mesh(np_rng):
     w_r = jnp.asarray(np_rng.randn(D, 4 * D) * 0.1, jnp.float32)
     checks = [jnp.asarray(np_rng.randn(D) * 0.1, jnp.float32)
               for _ in range(3)]
+    own = {}
+    if projected:
+        own = {"proj": jnp.asarray(np_rng.randn(4 * D, 4 * D) * 0.05,
+                                   jnp.float32),
+               "bias": jnp.asarray(np_rng.randn(4 * D) * 0.1, jnp.float32)}
 
-    def loss(x, w_r, checks):
+    def loss(x, w_r, checks, own):
         out, final = rnn.lstm(SequenceBatch(data=x, lengths=lengths), w_r,
                               check_i=checks[0], check_f=checks[1],
-                              check_o=checks[2])
+                              check_o=checks[2], **own)
         return jnp.sum(out.data ** 2) + jnp.sum(final.c ** 2)
 
-    grad = jax.value_and_grad(loss, argnums=(0, 1, 2))
+    grad = jax.value_and_grad(loss, argnums=(0, 1, 2, 3))
     prior = rnn.FUSED_LSTM
     try:
         rnn.FUSED_LSTM = "always"
-        before = rnn.FUSED_DISPATCH_COUNT
+        before = rnn.FUSED_DISPATCH_COUNT, rnn.PROJECTED_DISPATCH_COUNT
 
-        def sharded(x, w_r, checks):
+        def sharded(x, w_r, checks, own):
             with rnn.batch_sharded_over(mesh, AXIS_DATA):
-                return grad(x, w_r, checks)
+                return grad(x, w_r, checks, own)
 
+        whole = NamedSharding(mesh, P())
         got = jax.jit(sharded, in_shardings=(
-            NamedSharding(mesh, P(AXIS_DATA)), NamedSharding(mesh, P()),
-            NamedSharding(mesh, P())))(x, w_r, checks)
-        assert rnn.FUSED_DISPATCH_COUNT == before + 1
+            NamedSharding(mesh, P(AXIS_DATA)), whole, whole, whole))(
+                x, w_r, checks, own)
+        assert (rnn.FUSED_DISPATCH_COUNT, rnn.PROJECTED_DISPATCH_COUNT) \
+            == (before[0] + 1, before[1] + projected)
         rnn.FUSED_LSTM = "0"
-        want = jax.jit(grad)(x, w_r, checks)
+        want = jax.jit(grad)(x, w_r, checks, own)
     finally:
         rnn.FUSED_LSTM = prior
     for g, w in zip(jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(want)):
+                    jax.tree_util.tree_leaves(want), strict=True):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    rtol=1e-4, atol=1e-4)
     # the guard judges the PER-SHARD batch: 8 rows a shard is supported,
@@ -340,3 +351,157 @@ def test_fused_kernel_takes_its_batch_shard_under_a_data_mesh(np_rng):
         assert rnn._local_batch(2 * B) == B
         assert rnn._local_batch(2 * B + 1) == 0
     assert rnn._local_batch(2 * B) == 2 * B
+
+
+# ------------------------------------------- the forward's own projection
+
+def _projected_case(np_rng, b, d_in, d):
+    x = jnp.asarray(np_rng.randn(b, T, d_in) * 0.3, jnp.float32)
+    lengths = jnp.asarray(np_rng.randint(1, T + 1, (b,)), jnp.int32)
+    w_x = jnp.asarray(np_rng.randn(d_in, 4 * d) * 0.1, jnp.float32)
+    w_r = jnp.asarray(np_rng.randn(d, 4 * d) * 0.1, jnp.float32)
+    bias = jnp.asarray(np_rng.randn(4 * d) * 0.1, jnp.float32)
+    checks = [jnp.asarray(np_rng.randn(d) * 0.1, jnp.float32)
+              for _ in range(3)]
+    return x, lengths, w_x, w_r, bias, checks
+
+
+def _lstm_of_input(x, lengths, w_x, w_r, bias, checks, projected, mode,
+                   **kw):
+    """``rnn.lstm`` of the layer's INPUT: handed the projection
+    (``proj=``), or the gate inputs formed outside as an fc layer would."""
+    from paddle_tpu.ops.linear import matmul
+    prior = rnn.FUSED_LSTM
+    rnn.FUSED_LSTM = mode
+    try:
+        data = x if projected else matmul(x, w_x)
+        return rnn.lstm(SequenceBatch(data=data, lengths=lengths), w_r,
+                        bias=bias, check_i=checks[0], check_f=checks[1],
+                        check_o=checks[2],
+                        proj=w_x if projected else None, **kw)
+    finally:
+        rnn.FUSED_LSTM = prior
+
+
+@pytest.mark.parametrize("tiles", [1, 2])
+@pytest.mark.parametrize("reverse", [False, True], ids=["fwd", "rev"])
+@pytest.mark.parametrize("d_in,d", [(128, 128), (128, 256)],
+                         ids=["in==d", "in<d"])
+def test_projected_kernel_matches_the_unprojected(
+        np_rng, monkeypatch, pallas_grids, d_in, d, reverse, tiles):
+    """The forward kernel that forms x_t W_x + b itself against the same
+    kernel fed the gate inputs: ``hs``, the final (h, c) and the gradients
+    of x, W_x, w_r, the gate bias and the three peepholes, on ragged rows
+    (masked steps), both directions, one and two batch tiles."""
+    b = 16
+    _force_tile(monkeypatch, b, d, b // tiles)
+    args = _projected_case(np_rng, b, d_in, d)
+    lengths = args[1]
+    probe = jnp.asarray(np_rng.randn(b, T, d), jnp.float32)
+    probe_c = jnp.asarray(np_rng.randn(b, d), jnp.float32)
+
+    def loss(projected, x, w_x, w_r, bias, checks):
+        out, final = _lstm_of_input(x, lengths, w_x, w_r, bias, checks,
+                                    projected, "always", reverse=reverse)
+        return (jnp.sum(out.data * probe) + jnp.sum(final.c * probe_c)
+                + jnp.sum(final.h)), (out.data, final.h, final.c)
+
+    diff = (args[0],) + args[2:]
+    before = (rnn.FUSED_DISPATCH_COUNT, rnn.PROJECTED_DISPATCH_COUNT)
+    got = jax.value_and_grad(lambda *a: loss(True, *a), has_aux=True,
+                             argnums=(0, 1, 2, 3, 4))(*diff)
+    assert (rnn.FUSED_DISPATCH_COUNT, rnn.PROJECTED_DISPATCH_COUNT) \
+        == (before[0] + 1, before[1] + 1)
+    assert pallas_grids == [("lstm_fwd", (tiles, T)),
+                            ("lstm_bwd", (tiles, T))]
+    want = jax.value_and_grad(lambda *a: loss(False, *a), has_aux=True,
+                              argnums=(0, 1, 2, 3, 4))(*diff)
+    assert (rnn.FUSED_DISPATCH_COUNT, rnn.PROJECTED_DISPATCH_COUNT) \
+        == (before[0] + 2, before[1] + 1)
+    labels = ["loss", "hs", "h", "c", "dx", "dw_x", "dw_r", "dbias",
+              "dci", "dcf", "dco"]
+    for la, g, w in zip(labels, jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want), strict=True):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=2e-4, atol=2e-5, err_msg=la)
+
+
+@pytest.mark.parametrize("why", ["scan", "init_state", "activation",
+                                 "w_x_over_vmem", "blocked", "no_bias"])
+def test_projection_is_the_kernels_only_where_it_can_be(
+        np_rng, monkeypatch, why):
+    """``lstm(proj=)`` is ONE entry: where the fused forward cannot take
+    the projection (the scan, a carried state, another activation, a W_x
+    that does not fit beside w_r, the gate-blocked kernel of a w_r that
+    does not fit at all) ``lstm`` forms the gate inputs itself,
+    bit for bit what the fc layer outside would have handed it, and
+    ``PROJECTED_DISPATCH_COUNT`` stays; ``FUSED_DISPATCH_COUNT`` moves
+    whenever a kernel ran at all."""
+    from paddle_tpu.ops.pallas import lstm as pl
+    d_in = 2048 if why == "w_x_over_vmem" else D
+    x, lengths, w_x, w_r, bias, checks = _projected_case(np_rng, B, d_in, D)
+    kw, mode, fused, projected = {}, "always", 1, 0
+    if why == "scan":
+        mode, fused = "0", 0
+    elif why == "init_state":
+        zero = jnp.zeros((B, D), jnp.float32)
+        kw, fused = {"init_state": rnn.LstmState(h=zero + 0.1, c=zero)}, 0
+    elif why == "activation":
+        kw, fused = {"act": "relu"}, 0
+    elif why == "w_x_over_vmem":
+        # the backward's plan fits, the forward with W_x resident does not
+        monkeypatch.setenv("PADDLE_TPU_KERNEL_VMEM_MB", "2")
+        assert pl.vmem_bytes(B, D) < 2 * 2 ** 20 \
+            < pl.fwd_vmem_bytes(B, D, d_in)
+    elif why == "blocked":
+        from paddle_tpu.ops.pallas import lstm_blocked as blk
+        monkeypatch.setenv("PADDLE_TPU_KERNEL_VMEM_MB",
+                           repr(1.2 * blk.vmem_bytes(B, D) / 2 ** 20))
+        assert not pl.supported(B, D, "tanh", "sigmoid", "tanh", None)
+        assert blk.supported(B, D, "tanh", "sigmoid", "tanh", None)
+    else:
+        bias, projected = None, 1
+
+    def run(handed):
+        before = (rnn.FUSED_DISPATCH_COUNT, rnn.PROJECTED_DISPATCH_COUNT)
+        out, final = _lstm_of_input(x, lengths, w_x, w_r, bias, checks,
+                                    handed, mode, **kw)
+        moved = (rnn.FUSED_DISPATCH_COUNT - before[0],
+                 rnn.PROJECTED_DISPATCH_COUNT - before[1])
+        return moved, (out.data, final.h, final.c)
+
+    moved, got = run(True)
+    assert moved == (fused, projected)
+    moved, want = run(False)
+    assert moved == (fused, 0)
+    for g, w in zip(got, want, strict=True):
+        if projected:
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=2e-5, atol=2e-6)
+        else:
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("bt,d,d_in,mosaic_mib", [
+    (1024, 512, 512, 42.34), (1024, 512, 128, 44.07), (512, 512, 512, 23.15),
+    (1024, 128, 128, 11.71), (1024, 256, 256, 25.12), (1024, 384, 384, 34.59),
+    (1024, 640, 640, 53.29), (1024, 128, 2048, 29.05),
+    (1024, 128, 4096, 48.99), (512, 256, 4096, 29.30)])
+def test_projected_forward_is_planned_with_w_x_resident(
+        monkeypatch, bt, d, d_in, mosaic_mib):
+    """``fwd_vmem_bytes`` against the smallest ``vmem_limit_bytes`` under
+    which the projected forward (residuals saved, bfloat16 W_x) compiled
+    for the v5e (bisected chip-free, T=4, PR 36): the limit handed to
+    Mosaic, the larger of the two passes' plans plus a sixteenth, covers
+    it, and the backward's plan still sets the benchmark's tile."""
+    from paddle_tpu.core import dtypes
+    from paddle_tpu.ops.pallas import common, lstm as pl
+    monkeypatch.setattr(dtypes, "_compute_dtype", jnp.bfloat16)
+    mib = 2 ** 20
+    plan = max(pl.vmem_bytes(bt, d), pl.fwd_vmem_bytes(bt, d, d_in))
+    assert mosaic_mib * mib <= common.vmem_limit_bytes(plan)
+    assert pl.fwd_vmem_bytes(bt, d, d_in) >= 0.99 * mosaic_mib * mib
+    monkeypatch.setenv("PADDLE_TPU_KERNEL_VMEM_MB", V5E_BUDGET_MB)
+    assert pl.supported(1024, 512, "tanh", "sigmoid", "tanh", None,
+                        d_in=512)
+    assert pl.batch_tile(1024, 512) == 1024
