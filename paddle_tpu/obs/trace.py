@@ -40,7 +40,14 @@ Core surface:
   gets the phase in the host plane of its ``.xplane.pb``, on the
   profiler's clock, next to the device operations — tracer on or off;
   with the tracer on, also a record in a ring of its own (never the
-  request spans' ring).  Never per token or per slot.
+  request spans' ring).  Never per token or per slot.  Request spans
+  are on ``time.time()`` and phases on the profiler's clock; the two are
+  joined by an ORDINAL, never by converting clocks (the profiler lays
+  the device's clock against the host's anew in every session): a slot
+  span's ``prefill_chunk`` / ``prefill_stall`` events carry the ``step``
+  they rode and its ``first_token`` the ``of_step`` whose read produced
+  it -> the ``engine.step.dispatch`` phase of that ``step`` (which says
+  what rows the step carried) -> the device's module execution n.
 * ``extract(header)`` / ``inject(headers)`` — W3C-traceparent-style
   cross-process propagation (``00-<trace_id>-<span_id>-01``): the router
   injects on its upstream dispatches, the replica server extracts, and
@@ -514,7 +521,11 @@ def chrome_trace(spans=None, phases=None):
     a MERGED list from several processes' ``/debug/traces`` — that is
     the point: one file shows the whole fleet on one timeline.
     ``phases`` (the payload's ``"phases"``; this process's own when
-    ``spans`` is None too) lie on one ``loop`` track per process."""
+    ``spans`` is None too) lie on one ``loop`` track per process.  A
+    request's ``prefill_chunk`` / ``prefill_stall`` / ``first_token``
+    instants carry the device step they rode as ``step`` in their args,
+    the attr every phase of that step has: search the one for the
+    other."""
     if spans is None:
         spans = snapshot()
         if phases is None and _tracer is not None:
@@ -554,11 +565,15 @@ def chrome_trace(spans=None, phases=None):
             "pid": pid, "tid": tid, "args": args,
         })
         for ev in s.get("events", ()):
+            args = dict(ev.get("attrs", {}), trace_id=s["trace_id"])
+            if "of_step" in args:
+                # the join key under ONE name: a first token is the read
+                # of device step ``of_step``, whose phases say ``step``
+                args.setdefault("step", args["of_step"])
             events.append({
                 "name": ev["name"], "cat": "obs", "ph": "i", "s": "t",
                 "ts": round(ev["t"] * 1e6, 3), "pid": pid, "tid": tid,
-                "args": dict(ev.get("attrs", {}),
-                             trace_id=s["trace_id"]),
+                "args": args,
             })
     meta = []
     for proc, pid in pids.items():

@@ -481,6 +481,7 @@ class DecodeEngine:
         self._prev_pick = self._new_pick()
         self._last_step = None     # that step's handle
         self._t_ready = 0.0        # when a step's tokens were last read
+        self._attended = 0         # positions the prepared step attends
 
         # all_lanes is a TRACE-TIME constant: a speculating engine's
         # step returns EVERY lane's argmax [S, K] (the verify surface —
@@ -1335,8 +1336,9 @@ class DecodeEngine:
         seated = np.ones(self.num_slots, bool)
         seated[self._free] = False
         n, p = self._len[seated].astype(np.int64), self._pos[seated]
-        self.metrics.observe_attended_positions(
-            int((n * p + n * (n + 1) // 2).sum()))
+        # (kept for the step's own dispatch phase: its ``attended`` stat)
+        self._attended = int((n * p + n * (n + 1) // 2).sum())
+        self.metrics.observe_attended_positions(self._attended)
         return victims
 
     def evict(self, slot, reason):
@@ -1418,20 +1420,28 @@ class DecodeEngine:
                 host += [src, back]
                 width = src.size
             live = int(lens.sum())
-            ph.set(width=width, live=live, lanes=tokens.size)
-            self.metrics.observe_step_lanes(width, live)
             # verify spans armed for THIS step (speculative mode); popped
             # with the snapshot so an eviction racing the step can never
             # resurrect a stale acceptance
             spec_armed = {}
             if self._draft is not None:
                 spec_armed, self._spec_armed = self._spec_armed, {}
+            # what the step carries, the load its time follows: seated
+            # rows, those of them fed a prompt chunk (draft lanes are
+            # speculation, as in _collect), the chunks' lanes, and the
+            # positions its lanes attend (prepare_step's count, paged)
+            prefill_rows = int((lens > 1).sum()) - len(spec_armed)
+            ph.set(width=width, live=live, lanes=tokens.size,
+                   rows=self.num_active, prefill_rows=prefill_rows,
+                   prefill_lanes=live - self.num_slots
+                   - sum(spec_armed.values()),
+                   attended=self._attended)
+            self._attended = 0
+            self.metrics.observe_step_lanes(width, live, prefill_rows)
             # the fault point sits at the device-step boundary: a hang
             # here models a wedged device step for the watchdog to catch
             faults.hit("serving.decode_step")
             t0 = time.perf_counter()
-            ph.set(host_args=len(host),
-                   host_arg_bytes=sum(a.nbytes for a in host))
             nxt, cache = self._jit_step(params, cache, prev, *host)
             aux = None
             if self._model is not None:
@@ -1714,14 +1724,20 @@ class DecodeEngine:
                     *self._step_args(width))
                 jax.block_until_ready(nxt)
         self._warm = True
+        # the host arrays every step hands over (tokens, positions, lane
+        # counts, the block tables, a model's packing; the picks of the
+        # step before stay on the device): fixed by the engine's shapes
+        host = self._step_args(self.step_widths[-1])
         logger.info(
             "decode[%s]: warm (%d slots, max_len %d, kv %s/%s, decode "
             "kernels %s, chunked prefill K=%d budget=%s, "
-            "speculate_k=%d, mesh_shards=%d)", self.name,
+            "speculate_k=%d, mesh_shards=%d, host args a step %d of %d "
+            "bytes)", self.name,
             self.num_slots, self.max_len, self.kv_layout,
             self.kv_dtype, self._kernel_path(),
             self.prefill_chunk, self.prefill_chunk_budget or "inf",
-            self.speculate_k, self.mesh_shards)
+            self.speculate_k, self.mesh_shards, len(host),
+            sum(a.nbytes for a in host))
 
     def _step_args(self, width):
         """The host arrays a step of ``width`` lanes takes, as the slots
@@ -1845,7 +1861,8 @@ class DecodeEngine:
 
 class _GenRequest:
     __slots__ = ("prompt", "max_tokens", "eos_id", "future", "deadline",
-                 "t_submit", "t_first", "on_token", "tokens", "slot",
+                 "t_submit", "t_seat", "t_first", "on_token", "tokens",
+                 "slot",
                  "abandoned", "recoveries", "replay_feed", "replay_ctx",
                  "started", "admit_covered", "prefix_counted",
                  "trace_ctx", "queue_span", "slot_span")
@@ -1887,6 +1904,7 @@ class _GenRequest:
         self.future = Future()
         self.deadline = deadline          # absolute perf_counter() or None
         self.t_submit = time.perf_counter()
+        self.t_seat = None                # first seated (perf_counter())
         self.t_first = None
         self.on_token = on_token
         self.tokens = []
@@ -2296,10 +2314,19 @@ class GenerationBatcher:
                     mode = "prefix_hit"
                 else:
                     mode = "prefill"        # fresh admission
+                if req.t_seat is None:      # (a deferred seat retries)
+                    req.t_seat = time.perf_counter()
                 req.slot_span = obstrace.start_span(
                     "slot", ctx=req.trace_ctx, root=False,
                     slot=int(req.slot), mode=mode,
                     teacher_forced=len(req.replay_feed))
+                if req.slot_span.recording:
+                    # the join key (docs/observability.md): the first
+                    # device step that can carry the request
+                    req.slot_span.set(
+                        step=self.engine.steps_dispatched,
+                        prompt_tokens=int(req.prompt.size),
+                        chunk=self.engine.prefill_chunk)
         if hard is not None:
             # a seat that failed for anything but pool space left the
             # engine's slot state in doubt — fail everything in flight
@@ -2357,7 +2384,7 @@ class GenerationBatcher:
             # consumed buffer
             self._fail_all_inflight(hard)
 
-    def _load_chunks(self):
+    def _load_chunks(self, step):
         """Strictly between steps: arm each feeding slot's
         next up-to-(K-1)-token chunk (prompt ingestion, continuation
         replay, recovery replay — one mechanism), bounded by the
@@ -2365,22 +2392,29 @@ class GenerationBatcher:
         decode rows with chunking rows never retraces.  A slot that gets
         no lanes this step (budget spent) still advances one
         teacher-forced token through its lane 0, so feeding always makes
-        progress.  Returns the lanes armed."""
+        progress.  Returns the lanes armed.  Each row's slot span says
+        what ``step`` fed it (``prefill_chunk``: the lanes it got and the
+        lanes it ``wanted``) or left it out (``prefill_stall``)."""
         kk = self.engine.prefill_chunk
         budget = self.engine.prefill_chunk_budget
-        used = 0
+        used = stalled = 0
         for slot, req in self._by_slot.items():
             if not req.replay_feed or kk < 2:
                 continue
-            n = min(kk - 1, len(req.replay_feed))
-            if budget:
-                n = min(n, budget - used)
+            wanted = min(kk - 1, len(req.replay_feed))
+            n = min(wanted, budget - used) if budget else wanted
             if n <= 0:
+                stalled += 1
+                req.slot_span.event("prefill_stall", step=step)
                 continue
             self.engine.load_chunk(slot, req.replay_feed[:n])
             used += n
-            req.slot_span.event("prefill_chunk", lanes=int(n),
-                                pos=int(self.engine._pos[slot]))
+            if req.slot_span.recording:
+                req.slot_span.event("prefill_chunk", step=step, lanes=n,
+                                    wanted=wanted,
+                                    pos=int(self.engine._pos[slot]))
+        if stalled:
+            self.metrics.observe_prefill_stalled(stalled)
         return used
 
     def _load_spec(self):
@@ -2398,11 +2432,12 @@ class GenerationBatcher:
                 continue
             budgets[slot] = req.max_tokens - len(req.tokens)
         for slot, k_eff in self.engine.speculate(budgets).items():
-            self._by_slot[slot].slot_span.event(
-                "speculate", k=int(k_eff),
-                pos=int(self.engine._pos[slot]))
+            span = self._by_slot[slot].slot_span
+            if span.recording:
+                span.event("speculate", k=int(k_eff),
+                           pos=int(self.engine._pos[slot]))
 
-    def _emit_spec_run(self, req, slot, run):
+    def _emit_spec_run(self, req, slot, run, of_step):
         """Deliver one verify step's accepted run (matched drafts + the
         target's own token at the first mismatch) with full per-token
         semantics: EOS inside the run finishes the stream THERE (the
@@ -2416,8 +2451,7 @@ class GenerationBatcher:
             req.emit(tok, self.name)
             emitted += 1
             if first_emit:
-                req.slot_span.event("first_token")
-                self.metrics.observe_ttft(req.t_first - req.t_submit)
+                self._first_token(req, of_step)
             self.metrics.observe_gen_tokens(1)
             if req.eos_id is not None and tok == req.eos_id:
                 req.slot_span.event("accept", accepted=len(run) - 1,
@@ -2432,6 +2466,14 @@ class GenerationBatcher:
         req.slot_span.event("accept", accepted=len(run) - 1,
                             emitted=emitted)
         self.engine.advance(slot, run[-1], len(run))
+
+    def _first_token(self, req, of_step):
+        """A request's first token was just emitted: ``of_step``, the
+        device step whose read produced it, joins the slot span to that
+        step's phases (docs/observability.md)."""
+        req.slot_span.event("first_token", of_step=of_step)
+        self.metrics.observe_ttft(req.t_first - req.t_submit)
+        self.metrics.observe_prefill(req.t_first - req.t_seat)
 
     def _snap_breaker(self):
         """Mirror the breaker's state into the metrics gauge."""
@@ -2653,7 +2695,7 @@ class GenerationBatcher:
         if not self._by_slot:
             return                  # a failed admission cleared the slots
         with obstrace.phase("gen.loop.prepare", step=step) as ph:
-            lanes = self._load_chunks()
+            lanes = self._load_chunks(step)
             if self.engine.speculating:
                 self._load_spec()
             try:
@@ -2716,7 +2758,7 @@ class GenerationBatcher:
                             of_step=of_step) as ph:
             emitted = self.metrics.gen_tokens_total
             finished = self.metrics.responses_total
-            self._emit(rows, nxt)
+            self._emit(rows, nxt, of_step)
             ph.set(emitted=self.metrics.gen_tokens_total - emitted,
                    finished=self.metrics.responses_total - finished)
         return True
@@ -2770,9 +2812,9 @@ class GenerationBatcher:
                 req.slot = None
         return rows
 
-    def _emit(self, rows, nxt):
-        """Deliver a step's tokens ``nxt`` to the rows that emit, and
-        finish the streams they end."""
+    def _emit(self, rows, nxt, of_step):
+        """Deliver the tokens ``nxt`` of device step ``of_step`` to the
+        rows that emit, and finish the streams they end."""
         engine = self.engine
         for req, slot in rows:
             if self._flag_abandoned(req):
@@ -2783,14 +2825,13 @@ class GenerationBatcher:
                 if run is not None:
                     # a verify step: the whole accepted run emits in
                     # one go (and does its own advance/finish)
-                    self._emit_spec_run(req, slot, run)
+                    self._emit_spec_run(req, slot, run, of_step)
                     continue
             tok = int(nxt[slot])
             first_emit = req.t_first is None
             req.emit(tok, self.name)
             if first_emit:
-                req.slot_span.event("first_token")
-                self.metrics.observe_ttft(req.t_first - req.t_submit)
+                self._first_token(req, of_step)
             self.metrics.observe_gen_tokens(1)
             if req.eos_id is not None and tok == req.eos_id:
                 # not known before the read: with a step in flight the

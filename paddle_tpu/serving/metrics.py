@@ -91,8 +91,18 @@ class ServingMetrics:
         # latency of the stream
         self.tpot = Histogram(f"{name}_tpot", max_samples=max_samples,
                               keep="last", clock=self.clock)
+        # seat -> first token: the part of ttft the engine's steps take,
+        # one step a chunk of prompt, after the queue and before the front
+        self.prefill = Histogram(f"{name}_prefill", max_samples=max_samples,
+                                 keep="last", clock=self.clock)
         self.gen_tokens_total = 0        # useful (delivered) tokens
         self.decode_steps_total = 0
+        # steps that carried a prompt chunk for at least one row (counted
+        # at the hand-over): the steps whose time is the tail of tpot
+        self.decode_steps_with_prefill_total = 0
+        # row-steps in which a row with prompt left to feed got NO chunk
+        # lanes (prefill_chunk_budget spent: one token through lane 0)
+        self.prefill_stalled_row_steps_total = 0
         # steps handed to the device before the tokens of the step
         # before were read (the loop's one step in flight)
         self.decode_steps_overlapped_total = 0
@@ -252,11 +262,22 @@ class ServingMetrics:
         with self._lock:
             self.attended_positions_total += int(n)
 
-    def observe_step_lanes(self, computed, live):
-        """The width of the step being handed over and the lanes fed."""
+    def observe_step_lanes(self, computed, live, prefill_rows=0):
+        """The width of the step being handed over, the lanes fed and
+        the rows among them that are fed a prompt chunk."""
         with self._lock:
             self.step_lanes_computed_total += int(computed)
             self.step_lanes_live_total += int(live)
+            self.decode_steps_with_prefill_total += int(prefill_rows > 0)
+
+    def observe_prefill_stalled(self, rows):
+        """Rows of the step being prepared that still have prompt to
+        feed and got no chunk lanes."""
+        with self._lock:
+            self.prefill_stalled_row_steps_total += int(rows)
+
+    def observe_prefill(self, seconds):
+        self.prefill.add(seconds)
 
     def set_model_kernels(self, kda, mla, mamba):
         """Facts: the paths a model's compiled step took."""
@@ -470,6 +491,7 @@ class ServingMetrics:
         lat = self.latency.percentiles(_QUANTILES)
         bt = self.batch_time.percentiles(_QUANTILES)
         ttft = self.ttft.percentiles(_QUANTILES)
+        prefill = self.prefill.percentiles(_QUANTILES)
         tpot = self.tpot.percentiles(_QUANTILES)
         with self._lock:
             out = {
@@ -484,6 +506,10 @@ class ServingMetrics:
                 "decode_steps_total": self.decode_steps_total,
                 "decode_steps_overlapped_total":
                     self.decode_steps_overlapped_total,
+                "decode_steps_with_prefill_total":
+                    self.decode_steps_with_prefill_total,
+                "prefill_stalled_row_steps_total":
+                    self.prefill_stalled_row_steps_total,
                 "slot_count": self.slot_count,
                 "prefill_chunks_total": self.prefill_chunks_total,
                 "prefill_chunk_lanes_total":
@@ -549,6 +575,8 @@ class ServingMetrics:
                                 for q, v in bt.items()}
         out["ttft_ms"] = {f"p{q}": round(v * 1e3, 3)
                           for q, v in ttft.items()}
+        out["prefill_ms"] = {f"p{q}": round(v * 1e3, 3)
+                             for q, v in prefill.items()}
         out["tpot_ms"] = {f"p{q}": round(v * 1e3, 3)
                           for q, v in tpot.items()}
         out["kv_restore_ms"] = {
@@ -619,6 +647,7 @@ class ServingMetrics:
 
         # ---- generation serving (decode_engine.py) ----
         ttft = self.ttft.percentiles(_QUANTILES)
+        prefill = self.prefill.percentiles(_QUANTILES)
         tpot = self.tpot.percentiles(_QUANTILES)
         with self._lock:
             gen_counters = [
@@ -630,6 +659,14 @@ class ServingMetrics:
                  self.decode_steps_overlapped_total,
                  "decode steps handed to the device before the previous "
                  "step's tokens were read"),
+                ("decode_steps_with_prefill_total",
+                 self.decode_steps_with_prefill_total,
+                 "decode steps that carried a prompt chunk for at least "
+                 "one row"),
+                ("prefill_stalled_row_steps_total",
+                 self.prefill_stalled_row_steps_total,
+                 "row-steps in which a row with prompt left to feed got "
+                 "no chunk lanes (prefill_chunk_budget spent)"),
                 ("engine_cache_evictions_total",
                  self.engine_cache_evictions,
                  "compiled engines evicted from the per-row-signature "
@@ -794,6 +831,13 @@ class ServingMetrics:
         for q, v in ttft.items():
             lines.append(f'{n}_ttft_seconds{{quantile="0.{q}"}} {v:.6f}')
         lines.append(f"{n}_ttft_seconds_count {self.ttft.count}")
+        lines.append(f"# HELP {n}_prefill_seconds seat to first token "
+                     "(the steps that feed the prompt), recent-window "
+                     "quantiles")
+        lines.append(f"# TYPE {n}_prefill_seconds summary")
+        for q, v in prefill.items():
+            lines.append(f'{n}_prefill_seconds{{quantile="0.{q}"}} {v:.6f}')
+        lines.append(f"{n}_prefill_seconds_count {self.prefill.count}")
         lines.append(f"# HELP {n}_tpot_seconds per-output-token latency "
                      "(one slab decode step), recent-window quantiles")
         lines.append(f"# TYPE {n}_tpot_seconds summary")

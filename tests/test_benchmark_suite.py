@@ -7,8 +7,10 @@ the yardstick is held by the same run that holds the program."""
 
 import glob
 import importlib.util
+import json
 import os
 
+import pytest
 
 
 # Tests of ``benchmark/tests`` that this module replaces, each with its
@@ -21,6 +23,12 @@ _OVERRIDDEN = {
         "PR 32's two; a PR that appends a cell to the list, as the contract "
         "says to, cannot keep that and may not edit the file.  A `benchmark` "
         "PR relaxes the original as PR 34 relaxed step_overlap_share's, and "
+        "this entry goes with its stand-in (PERF.md 7)",
+    "test_jamba_driver_runs_tiny_cell":
+        "benchmark/tests/test_serve_jamba.py pins the NUMBER of per-layer "
+        "metrics `jamba3b_longctx` lists to PR 35's ten; PR 37 appends the "
+        "cell to seven more entries, as its issue says to, and may not edit "
+        "the file.  A `benchmark` PR relaxes the original to `>= 10` and "
         "this entry goes with its stand-in (PERF.md 7)",
 }
 
@@ -46,6 +54,69 @@ def test_step_narrow_share_manifest_entry_lists_the_hybrid_cells():
     for cell in ("opt1.3b_chat", "lstm-h512_train"):
         assert "step_narrow_share" not in [
             m["name"] for m in spec.metrics_for(spec.cell(cell), "per_layer")]
+
+
+@pytest.mark.parametrize("trace", [False, True], ids=["timed", "traced"])
+def test_jamba_driver_runs_tiny_cell(trace, tmp_path):
+    """The stand-in (``_OVERRIDDEN``): the original, line for line, but
+    that the cell lists AT LEAST the ten metrics PR 35 gave it; and of PR
+    37's seven the three that read spans alone answer in the traced run,
+    the four that need a device trace say nothing on the CPU."""
+    from benchmark import harness
+    from benchmark.drivers import serve_jamba
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+
+    def testdata(*parts):
+        with open(os.path.join(bench, "testdata", *parts)) as f:
+            return json.load(f)
+
+    cfg = testdata("configs", "tiny-jamba.json")
+    obs = serve_jamba.run({
+        "cell": {"name": "tiny_longctx", "chips": 1}, "config": cfg,
+        "traffic": testdata("traffic", "tiny_longctx_open.json"),
+        "seed": 3500000131, "seconds": 1.0, "trace": trace,
+        "rehearsal": True, "phases": harness.Phases(),
+        "trace_dir": str(tmp_path / "trace")})
+    assert obs["correct"] and obs["failed"] == 0 and obs["attempted"] >= 4
+    assert obs["mamba_kernels"] is False        # the CPU scans in XLA
+    assert obs["attn_kernels"] is False
+    after, before = obs["counters_after"], obs["counters_before"]
+    moved = {k: after[k] - before[k] for k in after}
+    assert moved["attended_positions_total"] > moved[
+        "prefill_chunk_lanes_total"] > 0
+    assert moved["state_resets_total"] >= obs["attempted"]
+    assert obs["weight_bytes"] > 0 and obs["tpot_s"]
+    spec = harness.Spec()
+    cell = spec.cell("jamba3b_longctx")
+    obs.update(cell=cell, config=cfg, peaks=None)
+    listed = {g: [m["name"] for m in spec.metrics_for(cell, g)]
+              for g in ("end_to_end", "per_layer")}
+    assert listed["end_to_end"] == ["ttft_per_token_p50_ms", "itl_p95_ms",
+                                    "setup_s"]
+    for name in listed["end_to_end"]:
+        assert spec.reader("end_to_end", name).read(obs) > 0
+    values = {name: spec.reader("per_layer", name).read(obs)
+              for name in listed["per_layer"]}
+    assert {"mamba_scan_share", "mamba_scan_roofline", "mamba_kernel_on",
+            "paged_attn_share"} <= set(values) and len(values) >= 10
+    assert values["decode_step_ms_p50"] > 0
+    assert values["mamba_kernel_on"] == 0.0
+    # no device plane on the CPU: the trace-fed readers say nothing
+    for name in ("mamba_scan_share", "mamba_scan_roofline",
+                 "paged_attn_share", "serve_device_idle_share",
+                 "weight_stream_roofline", "first_token_tail_ms_p50",
+                 "row_step_ms_p95", "prefill_step_share",
+                 "prefill_step_ms_p50"):
+        assert values[name] is None
+    spans_only = ("queue_wait_ms_p50", "prefill_ms_per_token_p50",
+                  "first_token_front_ms_p50")
+    for name in spans_only:
+        assert (values[name] is not None) == trace, name
+    if trace:
+        # the tiny budget (14 lanes: two rows' chunks) cuts rows that prefill
+        # side by side: a share of the steps, never all of them
+        assert 0.0 <= values["prefill_stall_share"] < 100.0
 
 
 def _reexport(own):

@@ -182,11 +182,18 @@ def test_generation_loop_one_phase_sequence_per_counted_step(engine_kw):
         assert it["t_start"] <= rows[1]["t_start"] \
             and rows[-1]["t_end"] <= it["t_end"]    # all inside the iter
         disp = rows[3]["attrs"]
-        # tokens, positions, lane counts (+ the block tables): the picks
-        # of the step before never leave the device
-        assert disp["host_args"] == \
-            3 + (engine_kw.get("kv_layout") == "paged")
-        assert disp["host_arg_bytes"] > 0
+        # what the step carried: seated rows, those fed a prompt chunk,
+        # the chunks' lanes (what gen.loop.prepare armed) and, where the
+        # pool counts them, the positions attended.  The host arrays are
+        # fixed by the engine's shapes and said once, in its warm line
+        assert "host_args" not in disp and "host_arg_bytes" not in disp
+        assert 1 <= disp["rows"] <= 2
+        assert 0 <= disp["prefill_rows"] <= disp["rows"]
+        assert disp["prefill_lanes"] == rows[2]["attrs"]["chunk_lanes"] \
+            == disp["live"] - 2
+        assert (disp["prefill_rows"] > 0) == (disp["prefill_lanes"] > 0)
+        assert (disp["attended"] > 0) \
+            == (engine_kw.get("kv_layout") == "paged")
         assert disp["in_flight"] in (0, 1)
         of = [p["attrs"]["of_step"] for p in rows[4:]]
         assert of[0::2] == of[1::2]         # emit what was waited for
@@ -201,6 +208,15 @@ def test_generation_loop_one_phase_sequence_per_counted_step(engine_kw):
                      if p["name"] == "engine.step.dispatch")
     assert overlapped == engine.metrics.decode_steps_overlapped_total
     assert overlapped >= steps - 3      # three runs of steps at most
+    dispatches = [p["attrs"] for p in phases
+                  if p["name"] == "engine.step.dispatch"]
+    assert sum(d["prefill_rows"] > 0 for d in dispatches) \
+        == engine.metrics.decode_steps_with_prefill_total > 0
+    assert sum(d["prefill_lanes"] for d in dispatches) \
+        == engine.metrics.prefill_lane_steps_total
+    if engine_kw.get("kv_layout") == "paged":
+        assert sum(d["attended"] for d in dispatches) \
+            == engine.metrics.attended_positions_total
     emitted = sum(p["attrs"]["emitted"] for p in phases
                   if p["name"] == "gen.loop.emit")
     # every token, the first included, is delivered by an emit phase
@@ -216,6 +232,147 @@ def test_generation_loop_one_phase_sequence_per_counted_step(engine_kw):
                            for s, e in iters)
     # the request spans are what they were: no phase among them
     assert not {s["name"] for s in trace.snapshot()} & set(ITER_ORDER)
+
+
+def _slot_events(span, name):
+    return [e["attrs"] for e in span["events"] if e["name"] == name]
+
+
+@pytest.mark.parametrize("engine_kw", [
+    dict(), dict(kv_layout="paged", kv_block_size=8),
+], ids=["slab", "paged"])
+def test_a_request_alone_says_which_step_fed_each_chunk(engine_kw):
+    """The join (docs/observability.md): request event -> ``step`` ->
+    the ``engine.step.dispatch`` phase of that step.  Lane 0 holds the
+    row's current token and ``load_chunk`` arms lanes 1..n, so a step
+    consumes ``lanes + 1`` of the feed until its last chunk drains it."""
+    from paddle_tpu.serving.decode_engine import (DecodeEngine,
+                                                  GenerationBatcher)
+    kk, prompt = 4, np.arange(1, 15) % 60           # 13 to feed: 4 chunks
+    engine = DecodeEngine(_lm_params(), num_heads=2, num_slots=2,
+                          max_len=32, prefill_chunk=kk, name="obs_join",
+                          **engine_kw)
+    trace.enable(sample=1.0, capacity=4096, process="unit")
+    gen = GenerationBatcher(engine, default_max_tokens=3)
+    try:
+        assert len(gen.generate(prompt, timeout=60)["tokens"]) == 3
+    finally:
+        gen.close()
+    slot, = [s for s in trace.snapshot() if s["name"] == "slot"]
+    attrs = slot["attrs"]
+    assert (attrs["mode"], attrs["prompt_tokens"], attrs["chunk"],
+            attrs["teacher_forced"]) == ("prefill", 14, kk, 13)
+    chunks = _slot_events(slot, "prefill_chunk")
+    steps = [c["step"] for c in chunks]
+    assert steps == list(range(attrs["step"], attrs["step"] + 4))
+    left = attrs["teacher_forced"]
+    for c in chunks:
+        assert c["lanes"] == c["wanted"] == min(kk - 1, left)
+        left -= min(c["lanes"] + 1, left)
+    assert left == 0                        # the chunks use the feed up
+    assert _slot_events(slot, "prefill_stall") == []
+    first, = _slot_events(slot, "first_token")
+    assert first == {"of_step": steps[-1]}
+    by_step = {p["step"]: p["attrs"] for p in trace.get_tracer().phases()
+               if p["name"] == "engine.step.dispatch"}
+    for c in chunks:
+        disp = by_step[c["step"]]
+        assert (disp["rows"], disp["prefill_rows"],
+                disp["prefill_lanes"]) == (1, 1, c["lanes"])
+    # the steps after the first token decode: one row, no chunk
+    assert all((d["rows"], d["prefill_rows"]) == (1, 0)
+               for step, d in by_step.items() if step > steps[-1])
+    m = engine.metrics
+    assert m.decode_steps_with_prefill_total == 4
+    assert m.prefill_stalled_row_steps_total == 0
+    snap = m.snapshot()
+    assert 0 < snap["prefill_ms"]["p50"] <= snap["ttft_ms"]["p50"]
+    assert m.prefill.count == m.ttft.count == 1
+    text = m.render_prometheus()
+    assert 'prefill_seconds{quantile="0.50"}' in text
+    assert "decode_steps_with_prefill_total 4" in text
+    assert "prefill_stalled_row_steps_total 0" in text
+
+
+def test_a_row_the_budget_cuts_leaves_a_stall_event_and_a_count():
+    """``prefill_chunk_budget`` 3 is ONE row's chunk at K = 4: while two
+    rows have prompt left, the second gets no lanes (one token through its
+    lane 0) and says so, where it used to leave no mark at all."""
+    from paddle_tpu.serving.decode_engine import (DecodeEngine,
+                                                  GenerationBatcher)
+    engine = DecodeEngine(_lm_params(), num_heads=2, num_slots=2,
+                          max_len=32, prefill_chunk=4,
+                          prefill_chunk_budget=3, name="obs_stall")
+    trace.enable(sample=1.0, capacity=4096, process="unit")
+    gen = GenerationBatcher(engine, default_max_tokens=2)
+    try:
+        futs = [gen.submit((np.arange(1, 29) + i) % 60, max_tokens=2)
+                for i in range(2)]
+        assert all(len(f.result(60)["tokens"]) == 2 for f in futs)
+    finally:
+        gen.close()
+    slots = [s for s in trace.snapshot() if s["name"] == "slot"]
+    assert len(slots) == 2
+    stalls = [e for s in slots for e in _slot_events(s, "prefill_stall")]
+    assert stalls and all(set(e) == {"step"} for e in stalls)
+    assert len(stalls) == engine.metrics.prefill_stalled_row_steps_total
+    by_step = {p["step"]: p["attrs"] for p in trace.get_tracer().phases()
+               if p["name"] == "engine.step.dispatch"}
+    fed = {c["step"] for s in slots
+           for c in _slot_events(s, "prefill_chunk")}
+    for e in stalls:
+        # the step a row sat out fed another row its whole budget: two
+        # rows seated, one of them prefilling
+        assert e["step"] in fed
+        disp = by_step[e["step"]]
+        assert (disp["rows"], disp["prefill_rows"],
+                disp["prefill_lanes"]) == (2, 1, 3)
+    # a stalled row took more steps than its feed needs at a whole chunk
+    # a step; every first token still names the step that produced it
+    for s in slots:
+        chunks = _slot_events(s, "prefill_chunk")
+        assert all(c["lanes"] <= c["wanted"] <= 3 for c in chunks)
+        first, = _slot_events(s, "first_token")
+        assert chunks[-1]["step"] <= first["of_step"] \
+            <= chunks[-1]["step"] + 1
+    taken = [max(c["step"] for c in _slot_events(s, "prefill_chunk"))
+             - s["attrs"]["step"] + 1 for s in slots]
+    assert max(taken) > -(-27 // 4)
+
+
+def test_step_stats_reach_the_host_plane_with_the_tracer_off(tmp_path):
+    from paddle_tpu.serving.decode_engine import (DecodeEngine,
+                                                  GenerationBatcher)
+    engine = DecodeEngine(_lm_params(), num_heads=2, num_slots=2,
+                          max_len=32, prefill_chunk=4, kv_layout="paged",
+                          kv_block_size=8, name="obs_off")
+    old = trace.enable(sample=1.0, capacity=8)
+    old._lock = _CountingLock()
+    trace.disable()
+    gen = GenerationBatcher(engine, default_max_tokens=2)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        out = gen.generate(np.arange(1, 11) % 60, timeout=60)
+        assert len(out["tokens"]) == 2 and trace.current() is None
+    finally:
+        jax.profiler.stop_trace()
+        gen.close()
+    # no ring, no lock, no span of the request anywhere
+    assert trace.get_tracer() is None and old._lock.entered == 0
+    assert len(old._phases) == len(old._done) == len(old._active) == 0
+    disp = _host_events(tmp_path)["engine.step.dispatch"]
+    assert len(disp) == engine.metrics.decode_steps_total
+    for st in disp:
+        assert {"step", "in_flight", "width", "live", "lanes", "rows",
+                "prefill_rows", "prefill_lanes", "attended"} == set(st)
+    # 9 to feed at K = 4: chunks of 3, 3 and 1 lanes, then decode steps
+    assert [st["prefill_lanes"] for st in disp][:4] == [3, 3, 1, 0]
+    assert [st["prefill_rows"] for st in disp][:4] == [1, 1, 1, 0]
+    assert sum(st["attended"] for st in disp) \
+        == engine.metrics.attended_positions_total > 0
+    # the counters an operator has without a profiler moved all the same
+    assert engine.metrics.decode_steps_with_prefill_total == 3
+    assert engine.metrics.prefill.count == 1
 
 
 def test_debug_traces_endpoint_carries_phases():

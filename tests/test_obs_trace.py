@@ -217,6 +217,37 @@ def test_chrome_trace_export_shape():
     assert [e["name"] for e in instants] == ["first_token"]
 
 
+def test_chrome_trace_request_events_carry_their_step():
+    """A request's chunk, stall and first-token instants say which device
+    step they rode under ONE key, ``step``: the attr every loop phase of
+    that step has, so the ``loop`` track's phase is one search away."""
+    trace.enable(sample=1.0, capacity=64, process="replica:1")
+    sl = trace.start_span("slot", slot=1, mode="prefill", step=7,
+                          prompt_tokens=9, chunk=4)
+    sl.event("prefill_chunk", step=7, lanes=3, wanted=3, pos=0)
+    sl.event("prefill_stall", step=8)
+    sl.event("prefill_chunk", step=9, lanes=1, wanted=3, pos=5)
+    sl.event("first_token", of_step=9)
+    sl.end(reason="length")
+    with trace.phase("engine.step.dispatch", step=9, prefill_rows=1):
+        pass
+    evs = trace.chrome_trace()["traceEvents"]
+    got = [(e["name"], e["args"]["step"]) for e in evs if e["ph"] == "i"]
+    assert got == [("prefill_chunk", 7), ("prefill_stall", 8),
+                   ("prefill_chunk", 9), ("first_token", 9)]
+    first = [e for e in evs if e["name"] == "first_token"][0]
+    assert first["args"]["of_step"] == 9 and first["tid"] == 101
+    loop, = [e for e in evs if e.get("cat") == "loop"]
+    assert loop["args"]["step"] == 9        # the phase the search finds
+    # an event of a program from before the stamps is drawn as it was
+    old = {"trace_id": "t", "span_id": "s", "name": "slot", "process": "p",
+           "t_start": 1.0, "t_end": 2.0, "attrs": {"slot": 0},
+           "events": [{"t": 1.5, "name": "first_token"}]}
+    inst, = [e for e in trace.chrome_trace([old])["traceEvents"]
+             if e["ph"] == "i"]
+    assert inst["args"] == {"trace_id": "t"}
+
+
 def test_slowest_surfaces_worst_roots():
     trace.enable(sample=1.0, capacity=64, process="unit")
     import time
