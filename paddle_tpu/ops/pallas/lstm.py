@@ -15,9 +15,13 @@ INPUT and the projection's weight (``lstm_fused(..., proj=W_x, bias=b)``):
 W_x [in, 4D] sits in VMEM beside w_r, each step streams x_t [bt, in] and
 computes (x_t W_x + b) + h_{t-1} W_r, so the [T, B, 4D] pre-activations
 are neither written to HBM by a projection nor read back here (4D values a
-token become ``in``).  The backward is the same kernel either way — it
-reads the saved activations, never the gate inputs — and the projection's
-own gradients are XLA products of its ``dgates`` output.
+token become ``in``).  The backward then finishes the projection's
+gradients where it forms ``dgates``: each reversed step streams x_t in and
+dx_t = dgates_t W_x^T out, and accumulates dW_x += x_t^T dgates_t and the
+bias's row sums in VMEM across every step and tile, so ``dgates`` leaves
+the kernel in no form.  Given gate inputs, it emits ``dgates`` as their
+gradient instead.  One plan decides both passes: the projection is the
+kernels' in both or in neither (``supported(..., d_in=)``).
 
 The batch is tiled: grid = (B // bt, T), time innermost, both axes
 sequential.  Rows of a batch never interact in the recurrence, so a tile
@@ -34,9 +38,9 @@ from c_new, masked steps freeze the carry): tests/test_pallas_lstm.py
 proves forward+grad equality against the scan path.
 
 Backward is a second time-reversed kernel (BPTT): recomputes nothing,
-reads the forward-saved activations, accumulates dW_r in a VMEM f32
-accumulator and the peephole/bias-free input grads as streamed outputs.
-Per-batch peephole partials are reduced outside the kernel.
+reads the forward-saved activations, accumulates dW_r (and dW_x, db) in
+VMEM f32 accumulators and streams the input's gradient out.  Per-batch
+peephole partials are reduced outside the kernel.
 """
 
 import functools
@@ -47,7 +51,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.core import dtypes
-from paddle_tpu.ops import linear
 from paddle_tpu.ops.pallas.common import (
     LANES as _LANES, lanes as _lanes, vmem_budget_bytes, vmem_limit_bytes)
 
@@ -108,13 +111,16 @@ def _fwd_kernel(x_ref, wx_ref, b_ref, wr_ref, chk_ref, mask_ref,
         cfin_ref[0] = c_scr[:].astype(cfin_ref.dtype)
 
 
-def _bwd_kernel(acts_ref, cs_ref, csp_ref, hsp_ref, wr_ref, chk_ref,
-                mask_ref, dh_out_ref, dcfin_ref,
-                dxs_ref, dwr_ref, dchk_ref,
-                dh_scr, dc_scr, dwr_scr, dchk_scr, *, d, nt):
+def _bwd_kernel(acts_ref, cs_ref, csp_ref, hsp_ref, x_ref, wx_ref, wr_ref,
+                chk_ref, mask_ref, dh_out_ref, dcfin_ref,
+                dx_ref, dwx_ref, db_ref, dwr_ref, dchk_ref,
+                dh_scr, dc_scr, dwr_scr, dchk_scr, dwx_scr, db_scr, *, d, nt):
+    """x_ref/wx_ref, dwx_ref/db_ref and their scratch are None where the
+    forward was handed gate inputs: dx_ref then takes ``dgates`` itself."""
     ib = pl.program_id(0)         # batch tile
     j = pl.program_id(1)          # reversed: actual time t = nt - 1 - j
     t = nt - 1 - j
+    projected = wx_ref is not None
 
     @pl.when(j == 0)
     def _():
@@ -124,10 +130,14 @@ def _bwd_kernel(acts_ref, cs_ref, csp_ref, hsp_ref, wr_ref, chk_ref,
         dc_scr[:] = dcfin_ref[0].astype(jnp.float32)
         dchk_scr[:] = jnp.zeros_like(dchk_scr)
 
-    # dW_r sums over every row of the batch: one accumulator for all tiles
+    # the weights' gradients sum over every row of the batch: one
+    # accumulator each for all tiles
     @pl.when((ib == 0) & (j == 0))
     def _():
         dwr_scr[:] = jnp.zeros_like(dwr_scr)
+        if projected:
+            dwx_scr[:] = jnp.zeros_like(dwx_scr)
+            db_scr[:] = jnp.zeros_like(db_scr)
 
     a = acts_ref[0, :, 0:d]
     i = acts_ref[0, :, d:2 * d]
@@ -171,7 +181,23 @@ def _bwd_kernel(acts_ref, cs_ref, csp_ref, hsp_ref, wr_ref, chk_ref,
     dchk_scr[:, d:2 * d] = dchk_scr[:, d:2 * d] + m * dfg * c_prev
     dchk_scr[:, 2 * d:3 * d] = dchk_scr[:, 2 * d:3 * d] + m * dog * c_t
 
-    dxs_ref[0] = dgates.astype(dxs_ref.dtype)
+    if projected:
+        # the projection's gradients as autodiff of linear.fc forms them:
+        # operands in the compute dtype W_x arrives in, float32 sums, each
+        # product leaving in that dtype, for it is the cotangent of an
+        # operand cast to it (the conversion back is outside, where
+        # autodiff puts the cast's transpose)
+        cd = wx_ref.dtype
+        dg = dgates.astype(cd)
+        dx_ref[0] = jax.lax.dot_general(
+            dg, wx_ref[:], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(cd)
+        dwx_scr[:] = dwx_scr[:] + jax.lax.dot_general(
+            x_ref[0].astype(cd), dg, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        db_scr[:] = db_scr[:] + jnp.sum(dgates, axis=0, keepdims=True)
+    else:
+        dx_ref[0] = dgates.astype(dx_ref.dtype)
 
     @pl.when(j == nt - 1)
     def _():
@@ -180,16 +206,20 @@ def _bwd_kernel(acts_ref, cs_ref, csp_ref, hsp_ref, wr_ref, chk_ref,
     @pl.when((ib == pl.num_programs(0) - 1) & (j == nt - 1))
     def _():
         dwr_ref[:] = dwr_scr[:]
+        if projected:
+            dwx_ref[:] = dwx_scr[:].astype(dwx_ref.dtype)
+            db_ref[:] = db_scr[:]
 
 
-def _compiler_params(plan_bytes):
+def _compiler_params(bt, d, d_in):
     """grid = (batch tiles, time): the carry makes time sequential, the
-    shared dW_r accumulator makes the tiles sequential.  The scoped-VMEM
-    limit follows the plan ``batch_tile`` chose ``bt`` by: at d=512 even 64
-    rows are over Mosaic's default 16 MiB (docs/kernels.md, VMEM table)."""
+    shared weight-gradient accumulators make the tiles sequential.  The
+    scoped-VMEM limit follows the plan ``batch_tile`` chose ``bt`` by
+    (``plan_bytes``): at d=512 even 64 rows are over Mosaic's default
+    16 MiB (docs/kernels.md, VMEM table)."""
     return pltpu.CompilerParams(
         dimension_semantics=("arbitrary", "arbitrary"),
-        vmem_limit_bytes=vmem_limit_bytes(plan_bytes))
+        vmem_limit_bytes=vmem_limit_bytes(plan_bytes(bt, d, d_in)))
 
 
 def _fwd(x, w_x, bias, w_r, checks, mask, interpret, bt, save_residuals):
@@ -205,14 +235,13 @@ def _fwd(x, w_x, bias, w_r, checks, mask, interpret, bt, save_residuals):
 
     in_specs = [pl.BlockSpec((1, bt, d_in), lambda ib, t: (t, ib, 0))]
     operands = [x]
-    plan, out_dtype = vmem_bytes(bt, d), x.dtype
+    out_dtype = x.dtype
     if projected:
         cd = dtypes.compute_dtype()
         out_dtype = jnp.promote_types(cd, jnp.float32)
         in_specs += [pl.BlockSpec((d_in, g), const),
                      pl.BlockSpec((1, g), const)]
         operands += [w_x.astype(cd), bias.astype(jnp.float32).reshape(1, g)]
-        plan = max(plan, fwd_vmem_bytes(bt, d, d_in))
     in_specs += [
         pl.BlockSpec((d, g), const),
         pl.BlockSpec((3, d), const),
@@ -259,7 +288,8 @@ def _fwd(x, w_x, bias, w_r, checks, mask, interpret, bt, save_residuals):
             pltpu.VMEM((bt, d), jnp.float32),
             pltpu.VMEM((bt, d), jnp.float32),
         ],
-        compiler_params=_compiler_params(plan),
+        compiler_params=_compiler_params(
+            bt, d, d_in if projected else None),
         interpret=interpret,
     )(*operands)
     if save_residuals:
@@ -272,10 +302,9 @@ def _fwd(x, w_x, bias, w_r, checks, mask, interpret, bt, save_residuals):
 def _bwd(interpret, bt, res, g_out):
     x, w_x, bias, w_r, checks, mask, hs, cs, acts = res
     dh_out, dcfin = g_out
-    xs_dtype = hs.dtype      # hs was emitted in the gate inputs' dtype
-    nt, b, dd = dh_out.shape
-    d = dd
-    gcols = 4 * d
+    nt, b, d = dh_out.shape
+    g = 4 * d
+    projected = w_x is not None
 
     def now(ib, j):               # the step being differentiated
         return (nt - 1 - j, ib, 0)
@@ -283,48 +312,86 @@ def _bwd(interpret, bt, res, g_out):
     def prev(ib, j):              # its predecessor (zeroed in-kernel at t=0)
         return (jnp.maximum(nt - 2 - j, 0), ib, 0)
 
-    dxs, dwr, dchk = pl.pallas_call(
-        functools.partial(_bwd_kernel, d=d, nt=nt),
+    def const(ib, j):
+        return (0, 0)
+
+    in_specs = [
+        pl.BlockSpec((1, bt, g), now),                     # acts
+        pl.BlockSpec((1, bt, d), now),                     # cs
+        pl.BlockSpec((1, bt, d), prev),                    # c_{t-1}
+        pl.BlockSpec((1, bt, d), prev),                    # h_{t-1}
+    ]
+    operands = [acts, cs, cs, hs]
+    if projected:
+        d_in = x.shape[-1]
+        cd = dtypes.compute_dtype()
+        in_specs += [pl.BlockSpec((1, bt, d_in), now),     # x
+                     pl.BlockSpec((d_in, g), const)]       # W_x
+        operands += [x, w_x.astype(cd)]
+        out_specs = [pl.BlockSpec((1, bt, d_in), now),     # dx
+                     pl.BlockSpec((d_in, g), const),       # dW_x
+                     pl.BlockSpec((1, g), const)]          # db
+        out_shape = [jax.ShapeDtypeStruct((nt, b, d_in), cd),
+                     jax.ShapeDtypeStruct((d_in, g), cd),
+                     jax.ShapeDtypeStruct((1, g), jnp.float32)]
+        own_scratch = [pltpu.VMEM((d_in, g), jnp.float32),
+                       pltpu.VMEM((1, g), jnp.float32)]
+    else:
+        d_in = None
+        # hs was emitted in the gate inputs' dtype
+        out_specs = [pl.BlockSpec((1, bt, g), now)]        # dgates
+        out_shape = [jax.ShapeDtypeStruct((nt, b, g), hs.dtype)]
+        own_scratch = []
+    in_specs += [
+        pl.BlockSpec((d, g), const),
+        pl.BlockSpec((3, d), const),
+        pl.BlockSpec((1, bt, _LANES), now),                # mask
+        pl.BlockSpec((1, bt, d), now),                     # dh_out
+        pl.BlockSpec((1, bt, d), lambda ib, j: (0, ib, 0)),    # dcfin
+    ]
+    operands += [w_r, checks, mask, dh_out, dcfin.astype(jnp.float32)]
+    out_specs += [
+        pl.BlockSpec((d, g), const),                       # dW_r
+        pl.BlockSpec((bt, 3 * d), lambda ib, j: (ib, 0)),  # dchk rows
+    ]
+    out_shape += [
+        jax.ShapeDtypeStruct((d, g), jnp.float32),
+        jax.ShapeDtypeStruct((b, 3 * d), jnp.float32),
+    ]
+    scratch = [
+        pltpu.VMEM((bt, d), jnp.float32),
+        pltpu.VMEM((bt, d), jnp.float32),
+        pltpu.VMEM((d, g), jnp.float32),
+        pltpu.VMEM((bt, 3 * d), jnp.float32),
+    ] + own_scratch
+
+    def kernel(*refs):
+        refs = list(refs)
+
+        def take(n, present=True):
+            return [refs.pop(0) for _ in range(n)] if present else [None] * n
+
+        _bwd_kernel(*take(4), *take(2, projected), *take(5),        # inputs
+                    *take(1), *take(2, projected), *take(2),        # outputs
+                    *take(4), *take(2, projected), d=d, nt=nt)      # scratch
+
+    outs = pl.pallas_call(
+        kernel,
         name="lstm_bwd",
         grid=(b // bt, nt),
-        in_specs=[
-            pl.BlockSpec((1, bt, gcols), now),                 # acts
-            pl.BlockSpec((1, bt, d), now),                     # cs
-            pl.BlockSpec((1, bt, d), prev),                    # c_{t-1}
-            pl.BlockSpec((1, bt, d), prev),                    # h_{t-1}
-            pl.BlockSpec((d, gcols), lambda ib, j: (0, 0)),
-            pl.BlockSpec((3, d), lambda ib, j: (0, 0)),
-            pl.BlockSpec((1, bt, _LANES), now),                # mask
-            pl.BlockSpec((1, bt, d), now),                     # dh_out
-            pl.BlockSpec((1, bt, d), lambda ib, j: (0, ib, 0)),    # dcfin
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bt, gcols), now),                 # dxs
-            pl.BlockSpec((d, gcols), lambda ib, j: (0, 0)),    # dW_r
-            pl.BlockSpec((bt, 3 * d), lambda ib, j: (ib, 0)),  # dchk rows
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nt, b, gcols), xs_dtype),
-            jax.ShapeDtypeStruct((d, gcols), jnp.float32),
-            jax.ShapeDtypeStruct((b, 3 * d), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bt, d), jnp.float32),
-            pltpu.VMEM((bt, d), jnp.float32),
-            pltpu.VMEM((d, gcols), jnp.float32),
-            pltpu.VMEM((bt, 3 * d), jnp.float32),
-        ],
-        compiler_params=_compiler_params(vmem_bytes(bt, d)),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        compiler_params=_compiler_params(bt, d, d_in),
         interpret=interpret,
-    )(acts, cs, cs, hs, w_r, checks, mask, dh_out,
-      dcfin.astype(jnp.float32))
-
+    )(*operands)
+    dx, dwx, db = outs[0], None, None
+    if projected:
+        dx, dwx = dx.astype(x.dtype), outs[1].astype(w_x.dtype)
+        db = outs[2][0].astype(bias.dtype)
+    dwr, dchk = outs[-2:]
     dchecks = dchk.sum(axis=0).reshape(3, d).astype(checks.dtype)
-    dx, dwx, db = dxs, None, None
-    if w_x is not None:
-        # the kernel's dxs are the gate inputs' gradients: the projection's
-        # own are what autodiff gives for the fc it stands for
-        dx, dwx, db = jax.vjp(linear.fc, x, w_x, bias)[1](dxs)
     return dx, dwx, db, dwr.astype(w_r.dtype), dchecks, None
 
 
@@ -369,23 +436,44 @@ def fwd_vmem_bytes(bt, d, d_in):
     as a value (4d), x in the compute dtype, plus two buffers of every
     streamed block: x (d_in), hs, cs and c_final (d each), acts (4d), the
     mask (128).  From 0.1% under to 26% over the v5e compiler's own count
-    at d = 128..640 (docs/kernels.md carries the table); under the
-    backward's plan, which sets the tile, unless d_in is over 8d: a wide
-    input over a narrow state is what it is counted for."""
+    at d = 128..640 (docs/kernels.md carries the table)."""
     cd = jnp.dtype(dtypes.compute_dtype()).itemsize
     resident = 4 * (4 * d * d + 7 * d) + cd * d_in * 4 * d
     per_row = 4 * (6 * d + 2 * (7 * d + d_in + _LANES)) + cd * d_in
     return resident + bt * per_row
 
 
-def batch_tile(b, d):
+def bwd_vmem_bytes(bt, d, d_in):
+    """The projected BACKWARD's footprint: ``vmem_bytes``'s without the
+    two buffers of the [bt, 4D] ``dxs`` block, with W_x resident, the dW_x
+    accumulator (f32) and its output block (compute dtype), the bias's
+    sums, and per row two buffers each of x_t (f32) and dx_t (compute
+    dtype) and the compute-dtype copies of dgates and x_t."""
+    cd = jnp.dtype(dtypes.compute_dtype()).itemsize
+    resident = (4 * (12 * d * d + 6 * d + d_in * 4 * d + 2 * 8 * 4 * d)
+                + 2 * cd * d_in * 4 * d)
+    per_row = (4 * (5 * d + 2 * (12 * d + d_in + _LANES))
+               + cd * (4 * d + 3 * d_in))
+    return resident + bt * per_row
+
+
+def plan_bytes(bt, d, d_in=None):
+    """The larger of the two passes' plans at a tile of ``bt`` rows: the
+    tile is chosen by it and both calls hand Mosaic it plus a sixteenth.
+    ``d_in``: the kernels project an input of that width themselves."""
+    if d_in is None:
+        return vmem_bytes(bt, d)
+    return max(fwd_vmem_bytes(bt, d, d_in), bwd_vmem_bytes(bt, d, d_in))
+
+
+def batch_tile(b, d, d_in=None):
     """Rows per batch tile: the largest multiple of 8 that divides ``b``
-    and whose ``vmem_bytes`` fits the budget — ``b`` itself (one tile)
+    and whose ``plan_bytes`` fits the budget — ``b`` itself (one tile)
     whenever the whole batch fits; 0 when no such tile exists (the weights
     alone are over the budget, or no multiple of 8 divides ``b``)."""
     budget = vmem_budget_bytes(scoped_limit_raised=True)
     for bt in range(b - b % 8, 0, -8):
-        if b % bt == 0 and vmem_bytes(bt, d) <= budget:
+        if b % bt == 0 and plan_bytes(bt, d, d_in) <= budget:
             return bt
     return 0
 
@@ -396,15 +484,13 @@ def supported(b, d, act, gate_act, state_act, init_state, d_in=None):
     The VMEM guard keeps weights that cannot be resident off the kernel
     path (d=1280: w_r, its gradient's accumulator and output block are
     79 MB f32 — over a 16 MiB core, inside a v5e's 128 MiB); a batch too
-    large for one block is tiled, not declined.  ``d_in``: the kernel is to
-    project its own input of that width (``lstm_fused(proj=)``), so W_x has
-    to fit beside w_r at the tile the backward's plan chose."""
-    bt = batch_tile(b, d)
+    large for one block is tiled, not declined.  ``d_in``: the kernels are
+    to project their own input of that width (``lstm_fused(proj=)``) in
+    BOTH passes, so W_x, dW_x and the x / dx streams have to fit beside
+    w_r at some tile."""
     return (act == "tanh" and gate_act == "sigmoid" and state_act == "tanh"
             and init_state is None
-            and d % _LANES == 0 and bt > 0
-            and (d_in is None or fwd_vmem_bytes(bt, d, d_in)
-                 <= vmem_budget_bytes(scoped_limit_raised=True)))
+            and d % _LANES == 0 and batch_tile(b, d, d_in) > 0)
 
 
 def lstm_fused(xs_tm, mask_tm, w_r, check_i, check_f, check_o,
@@ -413,14 +499,15 @@ def lstm_fused(xs_tm, mask_tm, w_r, check_i, check_f, check_o,
 
     xs_tm: [T, B, 4D] time-major pre-projected gate inputs (bias included);
     or, with ``proj`` [in, 4D], the layer's input [T, B, in] itself, which
-    the forward kernel projects and adds ``bias`` [4D] (or nothing) to.
+    the forward kernel projects and adds ``bias`` [4D] (or nothing) to, and
+    whose gradients the backward kernel forms.
     mask_tm: [T, B] float 0/1.  Returns (hs_tm [T, B, D], final (h, c)).
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    nt, b, _ = xs_tm.shape
+    nt, b, d_x = xs_tm.shape
     d = w_r.shape[0]
-    bt = batch_tile(b, d)
+    bt = batch_tile(b, d, None if proj is None else d_x)
     assert bt, f"lstm_fused: no batch tile for b={b}, d={d} (supported())"
     if proj is None and bias is not None:
         raise ValueError("lstm_fused: a bias without proj= is already part "

@@ -128,9 +128,10 @@ def _fused_lstm_enabled():
 #: supported()'s decision externally.
 FUSED_DISPATCH_COUNT = 0
 
-#: of those, the LSTM dispatches in which the forward kernel took the
-#: layer's input and its projection (``lstm(proj=)``) and formed the gate
-#: inputs itself; counted at trace time like its neighbour.
+#: of those, the LSTM dispatches in which the kernels took the layer's
+#: input and its projection (``lstm(proj=)``) in both passes: the forward
+#: formed the gate inputs itself, the backward the projection's gradients;
+#: counted at trace time like its neighbour.
 PROJECTED_DISPATCH_COUNT = 0
 
 
@@ -217,9 +218,10 @@ def lstm(seq: SequenceBatch, w_r, bias=None, check_i=None, check_f=None,
 
     seq.data: [B, T, 4D] pre-projected gate inputs (the reference's lstmemory
     also expects a 4*size mixed input); or, with ``proj`` [in, 4D], the
-    [B, T, in] input of that bias-free projection, which the fused forward
-    kernel then computes itself (the [T, B, 4D] gate inputs never reach
-    HBM) and every other path forms first.  bias: [4D].  Returns
+    [B, T, in] input of that bias-free projection, which the fused kernels
+    then compute, and differentiate, themselves (neither the [T, B, 4D]
+    gate inputs nor their gradient reaches HBM) and every other path forms
+    first.  bias: [4D].  Returns
     (SequenceBatch of h [B, T, D], final LstmState).
     """
     global PROJECTED_DISPATCH_COUNT
@@ -232,9 +234,9 @@ def lstm(seq: SequenceBatch, w_r, bias=None, check_i=None, check_f=None,
         from paddle_tpu.ops.pallas import lstm as pl_lstm
         from paddle_tpu.ops.pallas import lstm_blocked as pl_lstm_blk
         guard = (_local_batch(b), d, act, gate_act, state_act, init_state)
-        fused = pl_lstm.supported(*guard)
         projected = proj is not None and pl_lstm.supported(
             *guard, d_in=proj.shape[0])
+        fused = projected or pl_lstm.supported(*guard)
         # over-VMEM hidden sizes: the gate-blocked forward keeps the carry
         # in VMEM and fuses the cell while streaming weight blocks (scan-
         # equivalent weight traffic; docs/kernels.md blocked-variant notes)

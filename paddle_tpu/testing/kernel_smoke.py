@@ -246,7 +246,7 @@ def _rnn_case(kind, w, batch=None, length=None, hidden=None, proj_in=None):
     if kind == "lstm":
         from paddle_tpu.ops.pallas import lstm as pl_lstm
         # the dispatcher's own rule, so the row says how the batch was cut
-        facts = {"batch": b, "batch_tile": pl_lstm.batch_tile(b, d)}
+        facts = {"batch": b, "batch_tile": pl_lstm.batch_tile(b, d, proj_in)}
         wr = jnp.asarray(rng.randn(d, 4 * d) * scale, jnp.float32)
         checks = [jnp.asarray(rng.randn(d) * 0.1, jnp.float32)
                   for _ in range(3)]

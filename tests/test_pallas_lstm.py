@@ -122,13 +122,13 @@ def test_vmem_guard_routes_oversized_to_scan(monkeypatch):
 TILED_B = 32            # tiles of 32, 16 and 8 rows: one, two, four
 
 
-def _force_tile(monkeypatch, b, d, bt):
-    """Make ``batch_tile(b, d)`` come out as ``bt`` the way a small core
-    would: through the budget the guard already reads."""
+def _force_tile(monkeypatch, b, d, bt, d_in=None):
+    """Make ``batch_tile(b, d, d_in)`` come out as ``bt`` the way a small
+    core would: through the budget the guard already reads."""
     from paddle_tpu.ops.pallas import lstm as pl
     monkeypatch.setenv("PADDLE_TPU_KERNEL_VMEM_MB",
-                       repr((pl.vmem_bytes(bt, d) + 512) / 2 ** 20))
-    assert pl.batch_tile(b, d) == bt
+                       repr((pl.plan_bytes(bt, d, d_in) + 512) / 2 ** 20))
+    assert pl.batch_tile(b, d, d_in) == bt
 
 
 @pytest.fixture
@@ -291,21 +291,26 @@ def test_limit_handed_to_mosaic_covers_its_own_count(bt, d, in_context_mib):
     assert common.vmem_limit_bytes(pl.vmem_bytes(8, 128)) == 16 * mib
 
 
-@pytest.mark.parametrize("projected", [False, True],
-                         ids=["gate_inputs", "projected"])
+@pytest.mark.parametrize("projected,tiles", [(False, 1), (True, 1),
+                                              (True, 2)],
+                         ids=["gate_inputs", "projected", "projected-2tiles"])
 def test_fused_kernel_takes_its_batch_shard_under_a_data_mesh(
-        np_rng, projected):
+        np_rng, monkeypatch, pallas_grids, projected, tiles):
     """GSPMD cannot partition a Mosaic kernel (on the chip a batch-sharded
     jit raises "Mosaic kernels cannot be automatically partitioned"), so
     under ``rnn.batch_sharded_over`` the kernels run per batch shard in a
     shard_map: forward and every gradient — the replicated weights'
     included, summed over the shards; with ``proj=`` W_x and the bias ride
-    among them — equal the single-device scan."""
+    among them, their gradients formed by the backward kernel across the
+    shard's tiles — equal the single-device scan."""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from paddle_tpu.parallel.mesh import AXIS_DATA, MeshConfig, make_mesh
     mesh = make_mesh(MeshConfig(data=2), devices=jax.devices()[:2])
-    x = jnp.asarray(np_rng.randn(2 * B, T, 4 * D) * 0.3, jnp.float32)
-    lengths = jnp.asarray(np_rng.randint(1, T + 1, (2 * B,)), jnp.int32)
+    if tiles > 1:
+        _force_tile(monkeypatch, tiles * B, D, B, 4 * D)
+    x = jnp.asarray(np_rng.randn(2 * tiles * B, T, 4 * D) * 0.3, jnp.float32)
+    lengths = jnp.asarray(np_rng.randint(1, T + 1, (2 * tiles * B,)),
+                          jnp.int32)
     w_r = jnp.asarray(np_rng.randn(D, 4 * D) * 0.1, jnp.float32)
     checks = [jnp.asarray(np_rng.randn(D) * 0.1, jnp.float32)
               for _ in range(3)]
@@ -337,6 +342,8 @@ def test_fused_kernel_takes_its_batch_shard_under_a_data_mesh(
                 x, w_r, checks, own)
         assert (rnn.FUSED_DISPATCH_COUNT, rnn.PROJECTED_DISPATCH_COUNT) \
             == (before[0] + 1, before[1] + projected)
+        assert pallas_grids == [("lstm_fwd", (tiles, T)),
+                                ("lstm_bwd", (tiles, T))]
         rnn.FUSED_LSTM = "0"
         want = jax.jit(grad)(x, w_r, checks, own)
     finally:
@@ -394,7 +401,7 @@ def test_projected_kernel_matches_the_unprojected(
     of x, W_x, w_r, the gate bias and the three peepholes, on ragged rows
     (masked steps), both directions, one and two batch tiles."""
     b = 16
-    _force_tile(monkeypatch, b, d, b // tiles)
+    _force_tile(monkeypatch, b, d, b // tiles, d_in)
     args = _projected_case(np_rng, b, d_in, d)
     lengths = args[1]
     probe = jnp.asarray(np_rng.randn(b, T, d), jnp.float32)
@@ -426,19 +433,137 @@ def test_projected_kernel_matches_the_unprojected(
                                    rtol=2e-4, atol=2e-5, err_msg=la)
 
 
+def _pallas_outputs(jaxpr):
+    """``(name, [output shapes])`` of every pallas_call in a jaxpr."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append((eqn.params["name"],
+                          [a.shape for a in eqn.params["out_avals"]]))
+            continue
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (list, tuple)) else [p]:
+                if isinstance(sub, (ClosedJaxpr, Jaxpr)):
+                    found += _pallas_outputs(getattr(sub, "jaxpr", sub))
+    return found
+
+
+@pytest.mark.parametrize("projected", [True, False],
+                         ids=["projected", "gate_inputs"])
+def test_backward_writes_the_gate_gradient_only_where_it_was_handed_them(
+        np_rng, projected):
+    """The projected ``lstm_bwd`` finishes the projection's gradients
+    itself: its outputs are dx [T, B, in], dW_x [in, 4D] and the bias's
+    sums [1, 4D] beside dW_r and the peephole rows, and NO [T, B, 4D]
+    ``dgates``; handed gate inputs, it still emits their gradient."""
+    d_in = 256                       # neither D nor 4D: shapes tell apart
+    x, lengths, w_x, w_r, bias, checks = _projected_case(np_rng, B, d_in, D)
+
+    def loss(x, w_x, w_r, bias):
+        out, final = _lstm_of_input(x, lengths, w_x, w_r, bias, checks,
+                                    projected, "always")
+        return jnp.sum(out.data ** 2) + jnp.sum(final.c)
+
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2, 3)))(
+        x, w_x, w_r, bias).jaxpr
+    calls = _pallas_outputs(jaxpr)
+    assert [name for name, _ in calls] == ["lstm_fwd", "lstm_bwd"]
+    outputs = calls[1][1]
+    assert ((T, B, 4 * D) in outputs) == (not projected)
+    if projected:
+        assert outputs == [(T, B, d_in), (d_in, 4 * D), (1, 4 * D),
+                           (D, 4 * D), (B, 3 * D)]
+
+
+@pytest.mark.parametrize("tiles", [2, 4])
+def test_projection_gradients_sum_over_every_tile(
+        np_rng, monkeypatch, pallas_grids, tiles):
+    """dW_x and the bias's gradient live in ONE accumulator across all
+    batch tiles and time steps: on ragged rows (masked steps add nothing)
+    they equal x^T dgates and the sum of dgates, where dgates is the
+    gradient of the gate inputs the unprojected path reports."""
+    from paddle_tpu.ops.linear import matmul
+    b, d_in = 32, 128
+    _force_tile(monkeypatch, b, D, b // tiles, d_in)
+    x, lengths, w_x, w_r, bias, checks = _projected_case(np_rng, b, d_in, D)
+    probe = jnp.asarray(np_rng.randn(b, T, D), jnp.float32)
+
+    def loss(data, w_x, bias, projected):
+        out, final = rnn.lstm(SequenceBatch(data=data, lengths=lengths), w_r,
+                              bias=bias, check_i=checks[0],
+                              check_f=checks[1], check_o=checks[2],
+                              proj=w_x if projected else None)
+        return jnp.sum(out.data * probe) + jnp.sum(final.c)
+
+    prior = rnn.FUSED_LSTM
+    rnn.FUSED_LSTM = "always"
+    try:
+        dw_x, db = jax.grad(loss, argnums=(1, 2))(x, w_x, bias, True)
+        assert pallas_grids == [("lstm_fwd", (tiles, T)),
+                                ("lstm_bwd", (tiles, T))]
+        dgates = jax.grad(loss)(matmul(x, w_x), None, bias, False)
+    finally:
+        rnn.FUSED_LSTM = prior
+    mask = np.arange(T)[None, :] < np.asarray(lengths)[:, None]
+    assert not np.asarray(dgates)[~mask].any()
+    np.testing.assert_allclose(
+        np.asarray(dw_x), np.einsum("bti,btg->ig", np.asarray(x),
+                                    np.asarray(dgates)),
+        rtol=2e-4, atol=2e-5, err_msg="dw_x")
+    np.testing.assert_allclose(np.asarray(db),
+                               np.asarray(dgates).sum(axis=(0, 1)),
+                               rtol=2e-4, atol=2e-5, err_msg="dbias")
+
+
+def test_projection_gradients_keep_the_compute_dtypes_rounding(
+        np_rng, monkeypatch):
+    """Under a bfloat16 compute dtype the kernel's dx and dW_x are what
+    autodiff of ``linear.matmul`` gives the step — bfloat16 operands,
+    float32 sums, each product rounded to bfloat16 as the cotangent of an
+    operand cast to it — so every value is a bfloat16 one and they equal
+    the gate inputs' path to within the order of the sums; the bias's sum
+    stays float32."""
+    from paddle_tpu.core import dtypes
+    monkeypatch.setattr(dtypes, "_compute_dtype", jnp.bfloat16)
+    x, lengths, w_x, w_r, bias, checks = _projected_case(np_rng, B, D, D)
+
+    def grads(projected):
+        def loss(x, w_x, bias):
+            out, final = _lstm_of_input(x, lengths, w_x, w_r, bias, checks,
+                                        projected, "always")
+            return jnp.sum(out.data ** 2) + jnp.sum(final.c)
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(x, w_x, bias)
+
+    got, want = grads(True), grads(False)
+    for label, g, w in zip(["dx", "dw_x", "dbias"], got, want, strict=True):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.dtype == np.float32, label
+        if label != "dbias":
+            rounded = np.asarray(jnp.asarray(g).astype(jnp.bfloat16)
+                                 .astype(jnp.float32))
+            np.testing.assert_array_equal(g, rounded, err_msg=label)
+        np.testing.assert_allclose(g, w, rtol=0,
+                                   atol=2 ** -7 * np.abs(w).max(),
+                                   err_msg=label)
+
+
 @pytest.mark.parametrize("why", ["scan", "init_state", "activation",
-                                 "w_x_over_vmem", "blocked", "no_bias"])
+                                 "w_x_over_vmem", "dw_x_over_vmem",
+                                 "blocked", "no_bias"])
 def test_projection_is_the_kernels_only_where_it_can_be(
         np_rng, monkeypatch, why):
-    """``lstm(proj=)`` is ONE entry: where the fused forward cannot take
+    """``lstm(proj=)`` is ONE entry: where the fused kernels cannot take
     the projection (the scan, a carried state, another activation, a W_x
-    that does not fit beside w_r, the gate-blocked kernel of a w_r that
-    does not fit at all) ``lstm`` forms the gate inputs itself,
+    that does not fit beside w_r in the forward, or its gradient's
+    accumulator and streams beside dW_r in the backward, the gate-blocked
+    kernel of a w_r that does not fit at all) ``lstm`` forms the gate
+    inputs itself,
     bit for bit what the fc layer outside would have handed it, and
     ``PROJECTED_DISPATCH_COUNT`` stays; ``FUSED_DISPATCH_COUNT`` moves
     whenever a kernel ran at all."""
     from paddle_tpu.ops.pallas import lstm as pl
-    d_in = 2048 if why == "w_x_over_vmem" else D
+    d_in = {"w_x_over_vmem": 2048, "dw_x_over_vmem": 1024}.get(why, D)
     x, lengths, w_x, w_r, bias, checks = _projected_case(np_rng, B, d_in, D)
     kw, mode, fused, projected = {}, "always", 1, 0
     if why == "scan":
@@ -453,6 +578,12 @@ def test_projection_is_the_kernels_only_where_it_can_be(
         monkeypatch.setenv("PADDLE_TPU_KERNEL_VMEM_MB", "2")
         assert pl.vmem_bytes(B, D) < 2 * 2 ** 20 \
             < pl.fwd_vmem_bytes(B, D, d_in)
+    elif why == "dw_x_over_vmem":
+        # both forwards fit, and so does the backward handed gate inputs:
+        # the one that also forms dx and dW_x does not
+        monkeypatch.setenv("PADDLE_TPU_KERNEL_VMEM_MB", "4")
+        assert max(pl.vmem_bytes(B, D), pl.fwd_vmem_bytes(B, D, d_in)) \
+            < 4 * 2 ** 20 < pl.bwd_vmem_bytes(B, D, d_in)
     elif why == "blocked":
         from paddle_tpu.ops.pallas import lstm_blocked as blk
         monkeypatch.setenv("PADDLE_TPU_KERNEL_VMEM_MB",
@@ -493,15 +624,43 @@ def test_projected_forward_is_planned_with_w_x_resident(
     which the projected forward (residuals saved, bfloat16 W_x) compiled
     for the v5e (bisected chip-free, T=4, PR 36): the limit handed to
     Mosaic, the larger of the two passes' plans plus a sixteenth, covers
-    it, and the backward's plan still sets the benchmark's tile."""
+    it."""
     from paddle_tpu.core import dtypes
     from paddle_tpu.ops.pallas import common, lstm as pl
     monkeypatch.setattr(dtypes, "_compute_dtype", jnp.bfloat16)
     mib = 2 ** 20
-    plan = max(pl.vmem_bytes(bt, d), pl.fwd_vmem_bytes(bt, d, d_in))
-    assert mosaic_mib * mib <= common.vmem_limit_bytes(plan)
+    assert mosaic_mib * mib <= common.vmem_limit_bytes(
+        pl.plan_bytes(bt, d, d_in))
     assert pl.fwd_vmem_bytes(bt, d, d_in) >= 0.99 * mosaic_mib * mib
+
+
+@pytest.mark.parametrize("bt,d,d_in,mosaic_mib", [
+    (1024, 512, 512, 73.74), (1024, 512, 128, 65.74), (512, 512, 512, 45.01),
+    (1024, 128, 128, 14.41), (1024, 256, 256, 33.50), (1024, 384, 384, 53.01),
+    (1024, 640, 640, 98.40), (1024, 128, 2048, 47.78),
+    (1024, 128, 4096, 83.77), (512, 256, 4096, 74.16),
+    (256, 1024, 1024, 79.34), (8, 1536, 128, 112.72)])
+def test_projected_backward_is_planned_with_dw_x_resident(
+        monkeypatch, bt, d, d_in, mosaic_mib):
+    """``bwd_vmem_bytes`` against the smallest ``vmem_limit_bytes`` under
+    which the projected backward (bfloat16 W_x, dx and dW_x out, the
+    forward at its own limit) compiled for the v5e (bisected chip-free,
+    T=100: at T=4 XLA holds whole operands in VMEM and Mosaic counts less,
+    PR 38): the plan is over it, the limit handed over covers it, and the
+    benchmark's batch is ONE tile of both of its layers at the v5e's
+    budget."""
+    from paddle_tpu.core import dtypes
+    from paddle_tpu.ops.pallas import common, lstm as pl
+    monkeypatch.setattr(dtypes, "_compute_dtype", jnp.bfloat16)
+    mib = 2 ** 20
+    assert pl.bwd_vmem_bytes(bt, d, d_in) >= mosaic_mib * mib
+    assert mosaic_mib * mib <= common.vmem_limit_bytes(
+        pl.plan_bytes(bt, d, d_in))
     monkeypatch.setenv("PADDLE_TPU_KERNEL_VMEM_MB", V5E_BUDGET_MB)
-    assert pl.supported(1024, 512, "tanh", "sigmoid", "tanh", None,
-                        d_in=512)
-    assert pl.batch_tile(1024, 512) == 1024
+    for layer_in in (128, 512):            # lstm-h512_train's two layers
+        assert pl.batch_tile(1024, 512, layer_in) == 1024
+        assert pl.supported(1024, 512, "tanh", "sigmoid", "tanh", None,
+                            d_in=layer_in)
+    # at d=640 the plan (118.7 MiB at 1,024 rows) halves a tile that
+    # Mosaic's count (98.4) would take whole: the plan errs to the safe side
+    assert pl.batch_tile(1024, 640, 640) == 512
