@@ -4,9 +4,11 @@ per-layer mixer kind (``"kda"``: the gated delta rule with per-slot state,
 ops/kda.py; ``"mla"``: latent attention over the paged pool, ops/mla.py;
 ``"mamba"``: a selective scan with per-slot state, ops/mamba.py; ``"attn"``:
 softmax attention with grouped K/V over paged K and V pools,
-``attn_chunk``) and a per-layer FFN kind (``"dense"``: a gated SiLU FFN;
-``"moe"``: a sigmoid-routed expert layer that holds its share of the experts
-plus a shared expert, ops/moe.py).
+``attn_chunk``; ``"window"``: the same over the last ``window`` positions,
+whose K and V live in a per-slot ring) and a per-layer FFN kind
+(``"dense"``: a gated SiLU FFN; ``"moe"``: a sigmoid- or softmax-routed
+expert layer that holds its share of the experts plus a shared expert,
+ops/moe.py).
 
     x += Attn_l(RMSNorm(x));  x += FFN_l(RMSNorm(x));  logits = RMSNorm(x_L) W_head
 
@@ -14,12 +16,16 @@ With ``post_norms`` each sublayer's result is normed again before it joins
 the stream (a sandwich: ``x += RMSNorm(Attn_l(RMSNorm(x)))``, four gains a
 layer).  An MLA layer may have a low-rank query with its own norm
 (``q_rank``) and rotate its 64 query columns and the shared key part
-(``rope_theta``).  Three published families build a ``Config``
+(``rope_theta``); softmax attention may turn q and k (whole heads or
+their leading part, plain or YaRN frequencies) and gate each head's
+output.  Four published families build a ``Config``
 (``config_from_hf``): ``kimi_linear`` (KDA and unrotated MLA, 3 to 1),
 ``pangu_ultra_moe`` (MLA in every layer, rotated, a low-rank query,
-sandwich norms: a cache of latent pools only, no slot owns state) and
+sandwich norms: a cache of latent pools only, no slot owns state),
 ``jamba`` (Mamba with one unrotated multi-query attention layer a period,
-dense FFNs, a tied head, no positional signal of any kind).
+dense FFNs, a tied head, no positional signal of any kind) and ``laguna``
+(window and full attention 3 to 1 with their own head counts and
+rotations, per-head output gates, a softmax router).
 
 ``DecodeEngine(params, model=Served(cfg))`` serves it through the one
 chunked paged step (docs/serving.md "Models that hold state"), whose
@@ -29,13 +35,16 @@ has two kinds of leaf, which ``cache_kinds`` declares: the MLA layers'
 latent pools and the attention layers' K and V pools are block-addressed; a
 KDA or Mamba layer's recurrent state and convolution tail are
 slot-addressed, zeroed as data inside the step when a row starts at
-position 0, and left alone by lanes past a row's length.
+position 0, and left alone by lanes past a row's length; a window layer's
+ring is slot-addressed too, and needs no zeroing (a lane reads only
+positions its row wrote since position 0).
 
 The residual stream, the norms, the router and the recurrence are float32;
 matrix products follow ``ops/linear.matmul``."""
 
 import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -85,6 +94,16 @@ class Config:
     attn_kv_heads: int = 0
     attn_head_dim: int = 0
     tie_embeddings: bool = False    # the head is the embedding table
+    # what attention may add: q and k turned before K is written
+    # (``rope_frequencies``: (rotary_dim, inv_freq, cos/sin scale)), a
+    # per-head sigmoid gate before W_o, and the "window" layers' own heads,
+    # rotation and window (0: they attend every position, over the pool)
+    attn_rope: tuple = None
+    attn_gate: bool = False
+    window_heads: int = 0
+    window_rope: tuple = None
+    window: int = 0
+    router: str = "sigmoid"     # or "softmax": over all experts, no bias
 
     @property
     def latent_width(self):
@@ -93,6 +112,17 @@ class Config:
     @property
     def kda_width(self):
         return self.kda_heads * self.kda_head_dim
+
+    def attention(self, kind):
+        """``attn_chunk``'s keywords for a layer of ``kind`` ("attn" or
+        "window")."""
+        window = kind == "window"
+        return dict(num_heads=self.window_heads if window
+                    else self.attn_heads,
+                    kv_heads=self.attn_kv_heads, head_dim=self.attn_head_dim,
+                    rope=self.window_rope if window else self.attn_rope,
+                    gate=self.attn_gate,
+                    window=self.window if window else 0)
 
 
 def config_from_hf(c):
@@ -106,9 +136,12 @@ def config_from_hf(c):
     ``assumed.kda_gate_rank`` and ``expert_parallel`` (the key that counts
     the routed experts then gives those held of ``num_experts_published``,
     by rank ``rank``).  ``mamba_d_state`` and ``attn_layer_period`` are the
-    third family (``_jamba_config``)."""
+    third family (``_jamba_config``), ``layer_types`` with
+    ``num_attention_heads_per_layer`` the fourth (``_laguna_config``)."""
     if "mamba_d_state" in c and "attn_layer_period" in c:
         return _jamba_config(c)
+    if "layer_types" in c and "num_attention_heads_per_layer" in c:
+        return _laguna_config(c)
 
     def either(*keys):
         return next(c[k] for k in keys if k in c)
@@ -173,6 +206,91 @@ def _jamba_config(c):
         tie_embeddings=bool(c["tie_word_embeddings"]))
 
 
+LAGUNA_KINDS = {"full_attention": "attn", "sliding_attention": "window"}
+
+
+def _laguna_config(c):
+    """The ``laguna`` family: layer i's attention is ``layer_types[i]``
+    (full, or a window of ``sliding_window`` positions; each with its own
+    ``rope_parameters`` and its own ``num_attention_heads_per_layer[i]``
+    query heads, which must be alike within a kind), all on
+    ``num_key_value_heads`` K/V heads, a per-head output gate
+    (``gating``); its FFN is ``mlp_layer_types[i]``: the dense gated FFN or
+    a softmax-routed expert layer with a shared expert.  The first
+    ``num_hidden_layers`` entries of the lists are read, so a cut in depth
+    is that key alone; ``expert_parallel`` as for the other families."""
+    if not c.get("norm_topk_prob", True) \
+            or c.get("moe_router_logit_softcapping") \
+            or c.get("moe_apply_router_weight_on_input"):
+        raise NotImplementedError(
+            "the softmax router is served with its chosen shares "
+            "renormalised, no soft cap and its weights on the outputs")
+    n = c["num_hidden_layers"]
+    kinds = [LAGUNA_KINDS[t] for t in c["layer_types"][:n]]
+    heads = {}
+    for kind, h in zip(kinds, c["num_attention_heads_per_layer"][:n]):
+        if heads.setdefault(kind, h) != h:
+            raise NotImplementedError(
+                f"{kind} layers with {heads[kind]} and {h} query heads: one "
+                "count a kind is served")
+    dh = c["head_dim"]
+    ep = c.get("expert_parallel") or {}
+    count = c["num_experts"]
+    rope = {kind: rope_frequencies(dh, c["rope_parameters"][t])
+            for t, kind in LAGUNA_KINDS.items()}
+    return Config(
+        vocab_size=c["vocab_size"], hidden_size=c["hidden_size"],
+        layers=tuple((kind, "dense" if f == "dense" else "moe")
+                     for kind, f in zip(kinds, c["mlp_layer_types"][:n])),
+        rms_norm_eps=c["rms_norm_eps"],
+        kda_heads=0, kda_head_dim=0, conv_kernel=0, kda_gate_rank=0,
+        mla_heads=0, qk_nope=0, qk_rope=0, v_head_dim=0, kv_rank=0,
+        dense_width=c["intermediate_size"],
+        expert_width=c["moe_intermediate_size"],
+        router_width=ep.get("num_experts_published", count),
+        held=(ep.get("rank", 0) * count, count),
+        top_k=c["num_experts_per_tok"],
+        routed_scale=float(c["moe_routed_scaling_factor"]),
+        shared_experts=c["shared_expert_intermediate_size"]
+        // c["moe_intermediate_size"],
+        attn_heads=heads.get("attn", 0), attn_kv_heads=c["num_key_value_heads"],
+        attn_head_dim=dh, tie_embeddings=bool(c["tie_word_embeddings"]),
+        attn_rope=rope["attn"], attn_gate=bool(c.get("gating")),
+        window_heads=heads.get("window", 0), window_rope=rope["window"],
+        window=int(c.get("sliding_window") or 0), router="softmax")
+
+
+def rope_frequencies(head_dim, spec):
+    """One kind of layer's rotation from its ``rope_parameters`` entry ->
+    (rotary_dim, inv_freq (rotary_dim / 2 floats), cos/sin scale).  The
+    leading ``partial_rotary_factor`` of each head turns, in rotate-half
+    pairs (i, i + rotary_dim / 2) by position x inv_freq_i;
+    ``rope_type`` "yarn" blends each frequency with its ``factor``-fold
+    slower twin by Hugging Face's ``_compute_yarn_parameters`` (the ramp
+    between the dimensions that turn ``beta_fast`` and ``beta_slow`` times
+    over ``original_max_position_embeddings``) and scales cos and sin by
+    ``attention_factor`` (or 0.1 ln(factor) + 1)."""
+    dim = int(head_dim * spec.get("partial_rotary_factor", 1.0))
+    base = float(spec["rope_theta"])
+    inv = 1.0 / base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    scale = 1.0
+    if spec.get("rope_type", "default") == "yarn":
+        factor = float(spec["factor"])
+        orig = spec["original_max_position_embeddings"]
+
+        def turns_at(rot):      # the dimension that turns ``rot`` times
+            return dim * math.log(orig / (rot * 2 * math.pi)) \
+                / (2 * math.log(base))
+        low = max(math.floor(turns_at(spec.get("beta_fast", 32))), 0)
+        high = min(math.ceil(turns_at(spec.get("beta_slow", 1))), dim - 1)
+        ramp = np.clip((np.arange(dim // 2) - low)
+                       / ((high - low) or 0.001), 0.0, 1.0)
+        inv = inv / factor * ramp + inv * (1.0 - ramp)
+        scale = float(spec.get("attention_factor")
+                      or 0.1 * math.log(factor) + 1.0)
+    return dim, tuple(float(f) for f in np.float32(inv)), scale
+
+
 # ------------------------------------------------------------ parameters
 
 def _normal(key, shape, std, dtype):
@@ -196,11 +314,13 @@ def _init_attn(key, cfg, kind, dtype):
             "wkvb": lin(ks[2], cfg.kv_rank,
                         cfg.mla_heads * (cfg.qk_nope + cfg.v_head_dim)),
             "wo": lin(ks[3], cfg.mla_heads * cfg.v_head_dim, d)}
-    if kind == "attn":
-        dh = cfg.attn_head_dim
-        return {"wqkv": lin(ks[0], d, (cfg.attn_heads
-                                       + 2 * cfg.attn_kv_heads) * dh),
-                "wo": lin(ks[1], cfg.attn_heads * dh, d)}
+    if kind in ("attn", "window"):
+        dh, heads = cfg.attn_head_dim, cfg.attention(kind)["num_heads"]
+        out = {"wqkv": lin(ks[0], d, (heads + 2 * cfg.attn_kv_heads) * dh),
+               "wo": lin(ks[1], heads * dh, d)}
+        if cfg.attn_gate:
+            out["wgate"] = lin(ks[2], d, heads)
+        return out
     if kind == "mamba":
         di, n, r = cfg.mamba_inner, cfg.mamba_state, cfg.mamba_dt_rank
         # Mamba's own start: A = 1..n in every column, D = 1, dt in
@@ -256,7 +376,8 @@ def _init_ffn(key, cfg, kind, dtype):
     out = gated(ks[:3], cfg.expert_width, (cfg.held[1],))
     out["router"] = _normal(ks[3], (d, cfg.router_width), d ** -0.5,
                             jnp.float32)
-    out["router_bias"] = jnp.zeros((cfg.router_width,), jnp.float32)
+    if cfg.router == "sigmoid":
+        out["router_bias"] = jnp.zeros((cfg.router_width,), jnp.float32)
     out["shared"] = gated(jax.random.split(ks[4], 3),
                           cfg.expert_width * cfg.shared_experts)
     return out
@@ -296,7 +417,15 @@ def init(key, cfg, dtype=jnp.float32, emb_std=0.02):
 
 # ----------------------------------------------------------------- cache
 
-def init_cache(cfg, slots, blocks, block, latent_dtype=jnp.float32):
+def ring_positions(cfg, block, chunk):
+    """Positions of a window layer's per-slot ring: the window and a
+    chunk's lanes less one, in whole blocks, so that a step's own writes
+    never overwrite a position one of its lanes reads."""
+    return -(-(cfg.window + chunk - 1) // block) * block
+
+
+def init_cache(cfg, slots, blocks, block, latent_dtype=jnp.float32,
+               chunk=1):
     """One entry a layer.  KDA: ``{"state" [slots, H, dk, dv] float32,
     "conv" [slots, W-1, 3*H*dk] float32}``, owned by the slot; MLA:
     ``{"latent" [blocks, block, pool_width(rank + rope)]}``, addressed
@@ -304,10 +433,18 @@ def init_cache(cfg, slots, blocks, block, latent_dtype=jnp.float32):
     at).  Mamba: ``{"state" [slots, n, d_inner] float32, "conv" [slots, W-1,
     d_inner] float32}``, owned by the slot; attn: ``{"k", "v" [blocks,
     block, kv heads x head dim]}`` in the pools' dtype, addressed through
-    the tables."""
+    the tables; window (with ``cfg.window``): ``{"k", "v" [slots,
+    ring_positions(block, chunk) / block, block, kv heads x head dim]}``,
+    a RING owned by the slot (position p at ``p % ring``), for steps of
+    ``chunk`` lanes a row at most."""
     h, dk = cfg.kda_heads, cfg.kda_head_dim
 
     def layer(kind):
+        if kind == "window" and cfg.window:
+            shape = (slots, ring_positions(cfg, block, chunk) // block,
+                     block, cfg.attn_kv_heads * cfg.attn_head_dim)
+            return {"k": jnp.zeros(shape, latent_dtype),
+                    "v": jnp.zeros(shape, latent_dtype)}
         if kind == "kda":
             return {"state": jnp.zeros((slots, h, dk, dk), jnp.float32),
                     "conv": jnp.zeros((slots, cfg.conv_kernel - 1,
@@ -317,7 +454,7 @@ def init_cache(cfg, slots, blocks, block, latent_dtype=jnp.float32):
                                         cfg.mamba_inner), jnp.float32),
                     "conv": jnp.zeros((slots, cfg.mamba_conv - 1,
                                        cfg.mamba_inner), jnp.float32)}
-        if kind == "attn":
+        if kind in ("attn", "window"):
             shape = (blocks, block, cfg.attn_kv_heads * cfg.attn_head_dim)
             return {"k": jnp.zeros(shape, latent_dtype),
                     "v": jnp.zeros(shape, latent_dtype)}
@@ -330,13 +467,16 @@ def init_cache(cfg, slots, blocks, block, latent_dtype=jnp.float32):
 _LEAF_KINDS = {"kda": {"state": SLOT_LEAF, "conv": SLOT_LEAF},
                "mamba": {"state": SLOT_LEAF, "conv": SLOT_LEAF},
                "attn": {"k": BLOCK_LEAF, "v": BLOCK_LEAF},
+               "window": {"k": SLOT_LEAF, "v": SLOT_LEAF},
                "mla": {"latent": BLOCK_LEAF}}
 
 
 def cache_kinds(cfg):
     """The cache's tree with ``kv_pool.SLOT_LEAF`` / ``BLOCK_LEAF`` in
-    place of each buffer."""
-    return [dict(_LEAF_KINDS[kind]) for kind, _ffn in cfg.layers]
+    place of each buffer (a window layer without a window keeps the
+    pools)."""
+    return [dict(_LEAF_KINDS["attn" if kind == "window" and not cfg.window
+                             else kind]) for kind, _ffn in cfg.layers]
 
 
 # ------------------------------------------------------------------ step
@@ -372,36 +512,94 @@ def pack_lanes(lengths, kk):
     return src, back.astype(np.int32)
 
 
+def rotate(x, pos, heads, head_dim, rope):
+    """x ``[N, heads x head_dim]`` float32 at positions pos ``[N]`` -> the
+    same with the leading ``rotary_dim`` of each head turned in rotate-half
+    pairs (i, i + rotary_dim / 2) by pos x inv_freq_i, cos and sin times
+    the scale (``rope_frequencies``)."""
+    dim, inv, scale = rope
+    ang = pos.astype(jnp.float32)[:, None, None] \
+        * jnp.asarray(inv, jnp.float32)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
+    x = x.reshape(-1, heads, head_dim)
+    x1, x2 = x[..., :dim // 2], x[..., dim // 2:dim]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin,
+                            x[..., dim:]], -1).reshape(-1, heads * head_dim)
+
+
 def attn_chunk(p, h, k_pool, v_pool, qpos, tables, src, back, *, num_heads,
-               kv_heads, head_dim):
+               kv_heads, head_dim, rope=None, gate=False, window=0):
     """One softmax attention layer with grouped K/V over the step's packed
-    lanes, nothing rotated.  p: ``wqkv`` (q | k | v columns) and ``wo``, h
-    ``[N, d]`` the normed input of the packed lanes, k_pool / v_pool
-    ``[blocks, block, kv_heads x head_dim]``, qpos ``[S, K]`` the lanes'
-    positions, tables ``[S, blocks_per_row]``, src ``[N]`` / back ``[S, K]``
-    the packing -> (y ``[N, d]``, new K pool, new V pool).
+    lanes.  p: ``wqkv`` (q | k | v columns) and ``wo`` (and ``wgate`` [d,
+    H] with ``gate``), h ``[N, d]`` the normed input of the packed lanes,
+    k_pool / v_pool ``[blocks, block, kv_heads x head_dim]``, qpos ``[S,
+    K]`` the lanes' positions, tables ``[S, blocks_per_row]``, src ``[N]``
+    / back ``[S, K]`` the packing -> (y ``[N, d]``, new K pool, new V
+    pool).  ``rope`` (``rope_frequencies``) turns q and k before K is
+    written, so the pool holds turned keys; ``gate`` scales each head's
+    output by ``sigmoid(h W_gate)`` before ``W_o``; without either nothing
+    is turned or scaled.
 
     The projections run on the ``N`` packed lanes and K and V are written
     from them, position by position, BEFORE the read (a place that repeats
     a lane writes nothing), so causality inside the chunk is the ordinary
     mask.  The attention keeps ``[S, K]`` rows: the paged decode kernel
     (``decode_attention.maybe_paged_chunk``), or where it declines each
-    row's blocks gathered through its table and ``[S, K, H, T]`` scores."""
+    row's blocks gathered through its table and ``[S, K, H, T]`` scores.
+
+    ``window`` W: lane i attends ``(qpos_i - W, qpos_i]`` and k_pool /
+    v_pool are per-slot RINGS ``[S, R / block, block, kv_heads x
+    head_dim]`` (position p at ``p % R``, R at least W + K - 1:
+    ``ring_positions``) that no table addresses: the window kernel
+    (``decode_attention.maybe_window_chunk``, walking a ring a block at a
+    time) or, where it declines, ``[S, K, H, R]`` scores over the
+    rings."""
     from paddle_tpu.ops.pallas import decode_attention
     s, kk = qpos.shape
     block, dkv = k_pool.shape[1], kv_heads * head_dim
     d_q = num_heads * head_dim
     qkv = linear.matmul(h, p["wqkv"])
     row, pos = src // kk, qpos.reshape(-1)[src]
-    blk = jnp.where(mla.own_places(src, back), tables[row, pos // block],
-                    k_pool.shape[0])
-    write = lambda pool, new: pool.at[blk, pos % block].set(
-        new.astype(pool.dtype), mode="drop")
-    k_pool = write(k_pool, qkv[:, d_q:d_q + dkv])
+    if window:
+        ring_shape = k_pool.shape
+        block = ring_shape[2]
+        ring = ring_shape[1] * block
+        if ring < window + kk - 1:
+            raise ValueError(
+                f"a ring of {ring} positions does not hold a window of "
+                f"{window} and a chunk of {kk} lanes")
+        k_pool, v_pool = (c.reshape(s, ring, dkv) for c in (k_pool, v_pool))
+        slot = jnp.where(mla.own_places(src, back), row, s)
+        at = lambda: (slot, pos % ring)
+    else:
+        blk = jnp.where(mla.own_places(src, back), tables[row, pos // block],
+                        k_pool.shape[0])
+        at = lambda: (blk, pos % block)
+    # the index is formed at each write, as the unturned, unwindowed layer
+    # always formed it: that layer compiles to the program it did
+    write = lambda pool, new: pool.at[at()].set(new.astype(pool.dtype),
+                                                mode="drop")
+    k = qkv[:, d_q:d_q + dkv]
+    k_pool = write(k_pool, k if rope is None
+                   else rotate(k, pos, kv_heads, head_dim, rope))
     v_pool = write(v_pool, qkv[:, d_q + dkv:])
-    q = qkv[:, :d_q].astype(k_pool.dtype)[back]             # [S, K, H x dh]
-    o = decode_attention.maybe_paged_chunk(q, k_pool, v_pool, qpos, tables,
-                                           num_heads)
+    q = qkv[:, :d_q]
+    if rope is not None:
+        q = rotate(q, pos, num_heads, head_dim, rope)
+    q = q.astype(k_pool.dtype)[back]                        # [S, K, H x dh]
+    if window:
+        o = decode_attention.maybe_window_chunk(
+            q, k_pool, v_pool, qpos, num_heads, window, block=block,
+            entries=tables.shape[1])
+        if o is None:
+            o = _ring_attention(q, k_pool, v_pool, qpos, kv_heads, head_dim,
+                                window)
+        k_pool, v_pool = (c.reshape(ring_shape) for c in (k_pool, v_pool))
+    else:
+        o = decode_attention.maybe_paged_chunk(q, k_pool, v_pool, qpos,
+                                               tables, num_heads)
     if o is None:
         group = num_heads // kv_heads
         rows = lambda pool: pool[tables].reshape(s, -1, kv_heads, head_dim)
@@ -414,7 +612,34 @@ def attn_chunk(p, h, k_pool, v_pool, qpos, tables, src, back, *, num_heads,
             jnp.where(live[:, :, None, None, :], scores, -jnp.inf), axis=-1)
         o = linear.einsum("skvgt,stvd->skvgd", probs, values)
     o = o.reshape(s * kk, d_q)[src]
+    if gate:
+        g = jax.nn.sigmoid(linear.matmul(h, p["wgate"]))      # [N, H]
+        o = (o.reshape(-1, num_heads, head_dim) * g[:, :, None]) \
+            .reshape(-1, d_q)
     return linear.matmul(o, p["wo"]), k_pool, v_pool
+
+
+def _ring_attention(q, k_ring, v_ring, qpos, kv_heads, head_dim, window):
+    """The window's XLA path: ``[S, K, H, R]`` scores over each row's
+    ring.  Ring place i holds the latest position at or below the row's
+    last lane that is ``i`` modulo R, which the step has just written or
+    an earlier one did; a place the row has not written yet holds a
+    negative position, and the mask drops it."""
+    s, kk, d_q = q.shape
+    ring = k_ring.shape[1]
+    group = d_q // (kv_heads * head_dim)
+    last = qpos[:, -1:]                                        # [S, 1]
+    held = last - (last - jnp.arange(ring)[None, :]) % ring    # [S, R]
+    live = (held[:, None, :] <= qpos[:, :, None]) \
+        & (held[:, None, :] > qpos[:, :, None] - window) \
+        & (held[:, None, :] >= 0)                              # [S, K, R]
+    rows = lambda ring_: ring_.reshape(s, ring, kv_heads, head_dim)
+    scores = linear.einsum(
+        "skvgd,srvd->skvgr", q.reshape(s, kk, kv_heads, group, head_dim),
+        rows(k_ring)) * head_dim ** -0.5
+    probs = jax.nn.softmax(
+        jnp.where(live[:, :, None, None, :], scores, -jnp.inf), axis=-1)
+    return linear.einsum("skvgr,srvd->skvgd", probs, rows(v_ring))
 
 
 def decode_chunk(params, cfg, tokens, positions, lengths, cache, tables,
@@ -463,11 +688,10 @@ def decode_chunk(params, cfg, tokens, positions, lengths, cache, tables,
                 lp["attn"], h, c["state"], c["conv"], positions, lengths,
                 src, back, dt_rank=cfg.mamba_dt_rank, eps=eps)
             new_cache.append({"state": state, "conv": tail})
-        elif attn_kind == "attn":
+        elif attn_kind in ("attn", "window"):
             y, k_pool, v_pool = attn_chunk(
                 lp["attn"], h, c["k"], c["v"], qpos, tables, src, back,
-                num_heads=cfg.attn_heads, kv_heads=cfg.attn_kv_heads,
-                head_dim=cfg.attn_head_dim)
+                **cfg.attention(attn_kind))
             new_cache.append({"k": k_pool, "v": v_pool})
         else:
             y, pool = mla.mla_chunk(
@@ -483,9 +707,11 @@ def decode_chunk(params, cfg, tokens, positions, lengths, cache, tables,
         if ffn_kind == "dense":
             y = moe.gated_ffn(h, f["wg"], f["wu"], f["wd"])
         else:
-            idx, weights = moe.sigmoid_router(h, f["router"],
-                                              f["router_bias"], cfg.top_k,
-                                              cfg.routed_scale)
+            idx, weights = moe.softmax_router(
+                h, f["router"], cfg.top_k, cfg.routed_scale) \
+                if cfg.router == "softmax" else moe.sigmoid_router(
+                    h, f["router"], f["router_bias"], cfg.top_k,
+                    cfg.routed_scale)
             sh = f["shared"]
             y = moe.routed_experts(h, idx, weights, f, cfg.held,
                                    valid=valid) \
@@ -515,9 +741,37 @@ class Served:
         self.cfg = cfg
         self.latent_dtype = jnp.dtype(latent_dtype)
         self.vocab_size = cfg.vocab_size
+        # positions a window layer attends, 0 where no layer has a ring
+        self.window = cfg.window if any(
+            kind == "window" for kind, _f in cfg.layers) else 0
 
-    def init_cache(self, slots, blocks, block):
-        return init_cache(self.cfg, slots, blocks, block, self.latent_dtype)
+    def init_cache(self, slots, blocks, block, chunk=1):
+        """The cache for ``slots`` rows of steps of ``chunk`` lanes at
+        most (a window layer's ring holds the window and a chunk)."""
+        return init_cache(self.cfg, slots, blocks, block, self.latent_dtype,
+                          chunk)
+
+    def ring_bytes(self, cache):
+        """Bytes of the window layers' rings in ``cache``."""
+        if not self.window:
+            return 0
+        return sum(leaf.size * leaf.dtype.itemsize
+                   for (kind, _f), c in zip(self.cfg.layers, cache)
+                   if kind == "window" for leaf in c.values())
+
+    def window_counts(self, positions, lengths):
+        """What a step's lanes do in ONE window layer (numpy, on the host;
+        rows feed ``lengths`` lanes from ``positions``) -> (positions
+        attended: ``min(q + 1, window)`` a lane at q; positions a row's
+        lanes read between them, each once: ``max(0, p - window + 1) .. p
+        + n - 1``)."""
+        p = np.asarray(positions, np.int64)
+        n = np.asarray(lengths, np.int64)
+        lane = np.arange(int(np.max(n, initial=1)))[None, :]
+        at = np.minimum(p[:, None] + lane + 1, self.window)
+        attended = int(np.where(lane < n[:, None], at, 0).sum())
+        read = int((p + n - np.maximum(p - self.window + 1, 0)).sum())
+        return attended, read
 
     def cache_kinds(self):
         return cache_kinds(self.cfg)
@@ -553,15 +807,18 @@ class Served:
         aux = jnp.stack(routes) if routes else jnp.zeros((0,), jnp.int32)
         return logits, cache, aux
 
-    def kernel_report(self, kk, block, slots):
+    def kernel_report(self, kk, block, slots, entries=None):
         """{"kda_kernels", "kda_decline_reason", "mla_kernels",
         "mla_decline_reason", "mamba_kernels", "mamba_decline_reason",
-        "attn_kernels", "attn_decline_reason"} for a step of ``slots`` rows
-        of ``kk`` lanes over blocks of ``block`` positions, each from its
-        kernel's own predicate (``attn``: the paged decode-attention kernel
-        under the ``"attn"`` layers; ``mamba``: at every width the step is
-        compiled at); False and no reason for a kind of layer the model
-        does not have."""
+        "attn_kernels", "attn_decline_reason", "window_kernels",
+        "window_decline_reason"} for a step of ``slots`` rows of ``kk``
+        lanes over blocks of ``block`` positions (``entries`` of them a
+        row's table), each from its kernel's own predicate (``attn``: the
+        paged decode-attention kernel under the ``"attn"`` layers;
+        ``window``: its windowed form over the rings, or the paged one
+        where the window layers have no window; ``mamba``: at every width
+        the step is compiled at); False and no reason for a kind of layer
+        the model does not have."""
         from paddle_tpu.ops.pallas import decode_attention
         from paddle_tpu.ops.pallas import kda as kda_kernel
         from paddle_tpu.ops.pallas import mamba as mamba_kernel
@@ -584,7 +841,16 @@ class Served:
                    cfg.attn_heads, cfg.attn_heads * cfg.attn_head_dim,
                    cfg.attn_kv_heads * cfg.attn_head_dim, block, paged=True,
                    chunk=kk)
-               if "attn" in kinds else None}
+               if "attn" in kinds else None,
+               "window": (decode_attention.window_decline_reason(
+                   cfg.window_heads, cfg.window_heads * cfg.attn_head_dim,
+                   cfg.attn_kv_heads * cfg.attn_head_dim, block,
+                   entries or -(-(cfg.window + kk) // block), kk)
+                   if cfg.window else decode_attention.decline_reason(
+                       cfg.window_heads, cfg.window_heads * cfg.attn_head_dim,
+                       cfg.attn_kv_heads * cfg.attn_head_dim, block,
+                       paged=True, chunk=kk))
+               if "window" in kinds else None}
         return {**{k + "_kernels": k in kinds and why[k] is None
                    for k in why},
                 **{k + "_decline_reason": why[k] for k in why}}

@@ -10,10 +10,11 @@ top-k with renormalization (Switch/GShard style): no dynamic shapes, no
 scatter — everything stays MXU-friendly einsums under jit.
 
 That all-experts einsum (``moe_ffn``) serves the 2017 trunk and is the
-tests' oracle.  The SERVED expert layer is below it (``sigmoid_router``,
-``routed_experts``): tokens sorted by expert, grouped products over the
-experts this holder was told it holds, the rest of the experts' part left
-to whoever holds them (models/hybrid_lm.py; docs/serving.md).
+tests' oracle.  The SERVED expert layer is below it (``sigmoid_router`` or
+``softmax_router``, ``routed_experts``): tokens sorted by expert, grouped
+products over the experts this holder was told it holds, the rest of the
+experts' part left to whoever holds them (models/hybrid_lm.py;
+docs/serving.md).
 """
 
 import jax
@@ -117,6 +118,20 @@ def sigmoid_router(x, w, bias, top_k, scale):
                                precision=jax.lax.Precision.HIGHEST))
     _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
     chosen = jnp.take_along_axis(s, idx, axis=-1)
+    return idx.astype(jnp.int32), \
+        scale * chosen / chosen.sum(-1, keepdims=True)
+
+
+def softmax_router(x, w, top_k, scale):
+    """A softmax router with a scale, in float32, ``sigmoid_router``'s
+    interface without a bias: ``s = softmax(x W_r)`` over ALL the experts
+    (the router keeps its published width); the ``top_k`` largest are
+    chosen and weighted ``scale * s_e / sum of the chosen s``.  x ``[N,
+    D]``, w ``[D, E]`` -> (idx ``[N, top_k]`` int32, weights ``[N,
+    top_k]``).  The product runs at ``highest`` precision, as there."""
+    s = jax.nn.softmax(jnp.dot(x.astype(jnp.float32), w.astype(jnp.float32),
+                               precision=jax.lax.Precision.HIGHEST), axis=-1)
+    chosen, idx = jax.lax.top_k(s, top_k)
     return idx.astype(jnp.int32), \
         scale * chosen / chosen.sum(-1, keepdims=True)
 

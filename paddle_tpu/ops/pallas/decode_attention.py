@@ -43,6 +43,10 @@ ever exists in HBM.
   of 128 entries of 16 positions cost 1,024 grid steps a call, five in
   six of them dead at the serving contexts; tiled, the ``opt1.3b_chat``
   call went from 0.27 to 0.08 ms (docs/kernels.md has the table).
+  With a WINDOW W the same tile loop starts at the tile of ``qpos_0 - W +
+  1`` and skips the entries before it (``decode_attention_window_chunk``,
+  ``decode_attn_window_chunk`` in a trace): over a per-slot ring of W + K
+  positions a window layer costs O(W), not O(position).
   ``G = 1`` — int8 K/V in a block under an s8 tile, shapes whose panels
   are not whole lane rows — is the block-a-grid-step form, grid ``(S,
   blocks_per_row)`` with ``[1, block_size, Dkv]`` k/v specs indexing
@@ -466,9 +470,15 @@ def _paged_chunk_kernel(pos_ref, tbl_ref, *args, **kw):
 
 def _paged_tile_kernel(pos_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf,
                        vbuf, sem, first_slot, m_scr, l_scr, acc_scr, *, bs,
-                       g, kk, scale):
+                       g, kk, scale, window=None):
     """Paged body with a TILE of ``g`` table entries: one grid
     step is one ROW, and the row's live tiles are a loop inside it.
+
+    ``window`` W: lane i attends only ``(qpos_i - W, qpos_i]``.  The row's
+    tile loop then starts at the tile of ``max(0, qpos_0 - W + 1)`` and an
+    entry wholly before that position is neither copied nor addressed, so
+    a row costs O(W + K) positions whatever its context (None: every
+    position from 0, the program as it was).
 
     The pools stay in HBM (``pl.ANY``).  Tile t of a row is its table
     entries ``[t*g, (t+1)*g)``: each LIVE entry's block is copied into its
@@ -492,10 +502,18 @@ def _paged_tile_kernel(pos_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf,
     last = pos_ref[r, kk - 1]
     n_tiles = last // tile + 1
 
+    def first_entry(row):       # the row's first entry its lanes reach
+        return jnp.maximum(pos_ref[row, 0] - window + 1, 0) // bs
+
     def copies(row, t, slot, op):
         live = pos_ref[row, kk - 1] // bs + 1
+        lo = None if window is None else first_entry(row)
         for i in range(g):
-            @pl.when(t * g + i < live)
+            wanted = t * g + i < live
+            if lo is not None:
+                wanted = jnp.logical_and(wanted, t * g + i >= lo)
+
+            @pl.when(wanted)
             def _():
                 bid = tbl_ref[row, t * g + i]
                 for hbm, buf in ((k_hbm, kbuf), (v_hbm, vbuf)):
@@ -504,11 +522,14 @@ def _paged_tile_kernel(pos_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf,
                         sem.at[slot])
                     getattr(cp, op)()
 
+    def start(row):             # the tile a row's loop starts at
+        return 0 if window is None else first_entry(row) // g
+
     @pl.when(r == 0)
     def _():
         vbuf[...] = jnp.zeros_like(vbuf)
         first_slot[0] = 0
-        copies(0, 0, 0, "start")
+        copies(0, start(0), 0, "start")
 
     first = first_slot[0]
     _init_row(m_scr, l_scr, acc_scr)
@@ -518,9 +539,10 @@ def _paged_tile_kernel(pos_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf,
     for i in range(1, kk):
         lim = jnp.where(lane >= i, pos_ref[r, i], lim)
     col = jax.lax.broadcasted_iota(jnp.int32, (mp, tile), 1)
+    t0 = start(r)
 
     def body(t, carry):
-        slot = (first + t) % 2
+        slot = (first + t) % 2 if window is None else (first + t - t0) % 2
 
         @pl.when(t + 1 < n_tiles)
         def _():
@@ -529,10 +551,13 @@ def _paged_tile_kernel(pos_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf,
         @pl.when(jnp.logical_and(t + 1 == n_tiles,
                                  r + 1 < pl.num_programs(0)))
         def _():
-            copies(r + 1, 0, 1 - slot, "start")
+            nxt = r + 1
+            copies(nxt, start(nxt), 1 - slot, "start")
 
         copies(r, t, slot, "wait")
         seen = col + t * tile <= lim
+        if window is not None:
+            seen = jnp.logical_and(seen, col + t * tile > lim - window)
         for j in range(n_p):
             cols = slice(j * wp, (j + 1) * wp)
             s = jax.lax.dot_general(
@@ -551,8 +576,9 @@ def _paged_tile_kernel(pos_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf,
                 preferred_element_type=jnp.float32)            # [mp, wp]
         return carry
 
-    jax.lax.fori_loop(0, n_tiles, body, 0)
-    first_slot[0] = (first + n_tiles) % 2
+    jax.lax.fori_loop(t0, n_tiles, body, 0)
+    first_slot[0] = (first + n_tiles) % 2 if window is None \
+        else (first + n_tiles - t0) % 2
     l = jnp.maximum(l_scr[...], 1e-30)
     for j in range(n_p):
         o_ref[0, j] = (acc_scr[j] / _lanes(l[j], wp)).astype(o_ref.dtype)
@@ -732,13 +758,17 @@ def decode_attention_paged_chunk(q, k, v, qpos, tables, num_heads, *,
     return out.reshape(s, kk, d)
 
 
-@functools.partial(jax.jit, static_argnames=("g", "num_heads", "interpret"))
-def _paged_chunk_tiled(q, k, v, qpos, tables, *, g, num_heads, interpret):
+@functools.partial(jax.jit, static_argnames=("g", "num_heads", "interpret",
+                                             "window"))
+def _paged_chunk_tiled(q, k, v, qpos, tables, *, g, num_heads, interpret,
+                       window=None):
     """``decode_attention_paged_chunk`` at G > 1 (``_paged_tile_kernel``):
     grid (S,), q and the output panel-major, the pools left in HBM.
     Jitted so that the layers of a step, which call it with the same
     shapes, share ONE trace of the kernel and one Mosaic lowering (the
-    step is traced a layer at a time; XLA inlines the calls)."""
+    step is traced a layer at a time; XLA inlines the calls).  With a
+    ``window`` it is ``decode_attention_window_chunk``'s, under that
+    kernel's own name."""
     s, kk, d = q.shape
     bs, dkv = k.shape[1], k.shape[2]
     dh, hkv, group = _head_split(d, dkv, num_heads)
@@ -761,17 +791,70 @@ def _paged_chunk_tiled(q, k, v, qpos, tables, *, g, num_heads, interpret):
             pltpu.VMEM((n_p, mp, wp), jnp.float32),
         ],
     )
+    kernel = functools.partial(_paged_tile_kernel, bs=bs, g=g, kk=kk,
+                               scale=1.0 / math.sqrt(dh))
+    name, span = "decode_attn_paged_chunk", tables.shape[1] * bs
+    if window is not None:
+        kernel = functools.partial(kernel, window=window)
+        # a row's tiles reach W + K - 1 positions and two part-tiles
+        name, span = "decode_attn_window_chunk", \
+            min(span, window + kk - 1 + 2 * g * bs)
     out = pl.pallas_call(
-        functools.partial(_paged_tile_kernel, bs=bs, g=g, kk=kk,
-                          scale=1.0 / math.sqrt(dh)),
-        grid_spec=grid_spec, name="decode_attn_paged_chunk",
+        kernel, grid_spec=grid_spec, name=name,
         out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
-        cost_estimate=kernel_cost(s, tables.shape[1] * bs, d, dkv,
+        cost_estimate=kernel_cost(s, span, d, dkv,
                                   q.dtype.itemsize, tq=kk,
                                   kv_itemsize=k.dtype.itemsize),
         interpret=interpret,
     )(jnp.asarray(qpos, jnp.int32), jnp.asarray(tables, jnp.int32), qp, k, v)
     return _from_panels(out, kk, hkv, group, dh)
+
+
+def ring_tables(slots, ring_blocks, entries):
+    """The block table of a per-slot RING as the tiled kernel walks it:
+    slot r's ring is blocks ``r * ring_blocks ..`` of the ring buffer laid
+    ``[slots * ring_blocks, block, Dkv]``, and position p lives in its
+    block ``(p // block) % ring_blocks`` -> ``[slots, entries]`` int32."""
+    return (jnp.arange(slots, dtype=jnp.int32)[:, None] * ring_blocks
+            + jnp.arange(entries, dtype=jnp.int32)[None, :] % ring_blocks)
+
+
+def decode_attention_window_chunk(q, k_ring, v_ring, qpos, num_heads,
+                                  window, *, block, entries,
+                                  interpret=None):
+    """Window attention over per-slot RINGS: q [S, K, D], k_ring / v_ring
+    [S, R, Dkv] (row r's ring; position p at ``p % R``, already written
+    for the chunk), qpos [S, K] -> [S, K, D].  Lane i attends ``(qpos_i -
+    window, qpos_i]``.  The ring must hold ``window + K - 1`` positions so
+    that the chunk's own writes never overwrite a position a lane reads;
+    the kernel walks the ring in blocks of ``block`` positions through
+    ``ring_tables`` of ``entries`` entries a row (enough for the largest
+    position), so a row reads the O(window + K) positions behind it and
+    nothing else, under its own name (``decode_attn_window_chunk``).
+    Raises ValueError on shapes the tiled kernel does not cover — callers
+    use ``maybe_window_chunk``."""
+    interpret = _interpret(interpret)
+    s, kk, d = q.shape
+    ring, dkv = k_ring.shape[1], k_ring.shape[2]
+    if ring % block or ring < window + kk - 1:
+        raise ValueError(
+            f"decode_attention_window_chunk: a ring of {ring} positions "
+            f"does not hold window {window} + chunk {kk} - 1 in blocks of "
+            f"{block}")
+    split = _head_split(d, dkv, num_heads)
+    g = paged_chunk_tile(num_heads, d, dkv, block, entries, kk,
+                         interpret=interpret)
+    if split is None or g == 1 or not _chunk_ok(kk, num_heads, interpret) \
+            or _tile_problem(block, dkv, split[0], interpret):
+        raise ValueError(
+            f"decode_attention_window_chunk: unsupported shape q={q.shape} "
+            f"ring={k_ring.shape} heads={num_heads} block={block}")
+    nblk = ring // block
+    as_pool = lambda x: x.reshape(s * nblk, block, dkv)
+    return _paged_chunk_tiled(q, as_pool(k_ring), as_pool(v_ring), qpos,
+                              ring_tables(s, nblk, entries), g=g,
+                              num_heads=num_heads, interpret=interpret,
+                              window=int(window))
 
 
 # ------------------------------------------------------------ dispatch
@@ -834,6 +917,19 @@ def decline_reason(num_heads, d, dkv, blk_len, paged=False, chunk=1,
     return _tile_problem(blk, dkv, split[0], interpret, quant=quant)
 
 
+def window_decline_reason(num_heads, d, dkv, block, entries, chunk):
+    """``decline_reason`` for the window kernel over per-slot rings
+    (``decode_attention_window_chunk``): the paged kernel's predicate, and
+    its TILED form, since the lower bound lives in the tile loop (a shape
+    that falls back to the block-a-grid-step kernel has no window)."""
+    why = decline_reason(num_heads, d, dkv, block, paged=True, chunk=chunk)
+    if why is None and paged_chunk_tile(num_heads, d, dkv, block, entries,
+                                        chunk) == 1:
+        why = (f"heads {num_heads} over Dkv {dkv} at block {block} take the "
+               "block-a-grid-step kernel, which has no window bound")
+    return why
+
+
 def tile_positions(num_heads, d, dkv, blk_len, nb_row=1, paged=False,
                    chunk=1, quant=False, shards=1):
     """K/V positions ONE step of the kernel covers for shapes
@@ -879,3 +975,15 @@ def maybe_paged_chunk(q, k, v, qpos, tables, num_heads, kscale=None,
     return decode_attention_paged_chunk(q, k, v, qpos, tables, num_heads,
                                         interpret=_interpret(None),
                                         kscale=kscale, vscale=vscale)
+
+
+def maybe_window_chunk(q, k_ring, v_ring, qpos, num_heads, window, *, block,
+                       entries):
+    """Window-kernel output [S, K, D] over per-slot rings when the kernel
+    is enabled and covers these shapes; None -> the caller's XLA path."""
+    if window_decline_reason(num_heads, q.shape[2], k_ring.shape[2], block,
+                             entries, q.shape[1]) is not None:
+        return None
+    return decode_attention_window_chunk(
+        q, k_ring, v_ring, qpos, num_heads, window, block=block,
+        entries=entries, interpret=_interpret(None))

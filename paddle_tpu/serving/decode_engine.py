@@ -471,6 +471,12 @@ class DecodeEngine:
         # attention layers (the trunk's own is ``decode_kernels``)
         self.attn_kernels = False
         self.attn_decline_reason = None
+        # and its windowed form under the window layers, over their rings
+        self.window_kernels = False
+        self.window_decline_reason = None
+        # positions a window layer attends (0: the model has none)
+        self._window = getattr(model, "window", 0)
+        self._window_attended = 0
         # what a model's last step reported of itself (hybrid_lm: the
         # chosen experts), left on the device; None for the trunk
         self.step_aux = None
@@ -650,7 +656,7 @@ class DecodeEngine:
         blocks = self._paged.pool.num_blocks
         if self._model is not None:
             return self._model.init_cache(self.num_slots, blocks,
-                                          self.block_size)
+                                          self.block_size, chunk=self._kk)
         return self._transformer.init_lm_cache_paged(
             self.params, blocks, self.block_size, max_len=self.max_len,
             kv_dtype=self.kv_dtype, num_heads=self.num_heads)
@@ -1339,6 +1345,12 @@ class DecodeEngine:
         # (kept for the step's own dispatch phase: its ``attended`` stat)
         self._attended = int((n * p + n * (n + 1) // 2).sum())
         self.metrics.observe_attended_positions(self._attended)
+        if self._window:
+            # and in a window layer: min(q + 1, W) for each lane at q; and
+            # what the rows read, each position once a row, in either kind
+            self._window_attended, read = self._model.window_counts(p, n)
+            self.metrics.observe_window_positions(
+                self._window_attended, read, int((p + n).sum()))
         return victims
 
     def evict(self, slot, reason):
@@ -1437,6 +1449,9 @@ class DecodeEngine:
                    - sum(spec_armed.values()),
                    attended=self._attended)
             self._attended = 0
+            if self._window:
+                ph.set(window_attended=self._window_attended)
+                self._window_attended = 0
             self.metrics.observe_step_lanes(width, live, prefill_rows)
             # the fault point sits at the device-step boundary: a hang
             # here models a wedged device step for the watchdog to catch
@@ -1664,15 +1679,17 @@ class DecodeEngine:
                     "reference path: %s", self.name,
                     self.decode_decline_reason)
         if self._model is not None:
-            report = self._model.kernel_report(self._kk, self.block_size,
-                                               self.num_slots)
+            report = self._model.kernel_report(
+                self._kk, self.block_size, self.num_slots,
+                entries=self._paged.tables.shape[1])
             for key, value in report.items():
                 setattr(self, key, value)
             for kernel, instead in (
                     ("kda", "XLA scan (every lane rewrites the state)"),
                     ("mla", "XLA gather and [S, K, H, T] scores"),
                     ("mamba", "XLA scan (every lane rewrites every state)"),
-                    ("attn", "XLA gather and [S, K, H, T] scores")):
+                    ("attn", "XLA gather and [S, K, H, T] scores"),
+                    ("window", "XLA [S, K, H, ring] scores")):
                 if report[kernel + "_decline_reason"]:
                     logger.warning(
                         "decode[%s]: %s kernel declined -> %s: %s",
@@ -1681,6 +1698,9 @@ class DecodeEngine:
             self.metrics.set_model_kernels(self.kda_kernels,
                                            self.mla_kernels,
                                            self.mamba_kernels)
+            if self._window:
+                self.metrics.set_window(
+                    self._model.ring_bytes(self._cache), self.window_kernels)
         self.metrics.set_prefill_chunk(self.prefill_chunk)
         self.metrics.set_kv_dtype(self.kv_dtype)
         self.metrics.set_speculate_k(self.speculate_k)
@@ -1754,7 +1774,7 @@ class DecodeEngine:
         """The warm line's account of the path the compiled step took."""
         if self.decode_kernels:
             return f"fused-pallas, {self.decode_tile} positions a step"
-        model = ("kda", "mla", "mamba", "attn")
+        model = ("kda", "mla", "mamba", "attn", "window")
         fused = [k for k in model if getattr(self, k + "_kernels")]
         if fused:
             return "fused-pallas (%s)" % ", ".join(fused)

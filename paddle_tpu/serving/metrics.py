@@ -117,6 +117,13 @@ class ServingMetrics:
         # included (paged steps, counted at prepare_step): the work of an
         # attention kernel, whatever it moves
         self.attended_positions_total = 0
+        # the same in ONE window layer of a model that has them: a lane at
+        # position q attends min(q + 1, window) (0 for every other model);
+        # and what such a model's rows READ, each position once a row
+        # whatever its lanes: in a window layer, and in a full one
+        self.window_attended_positions_total = 0
+        self.window_read_positions_total = 0
+        self.read_positions_total = 0
         # lanes the steps computed (the trunk: S x K; a model: the packed
         # width the step ran at) and lanes rows fed into them, a free
         # slot's one armed lane included; counted at the hand-over
@@ -128,6 +135,11 @@ class ServingMetrics:
         self.kda_kernels = 0
         self.mla_kernels = 0
         self.mamba_kernels = 0
+        # and its window layers' kernel over their per-slot rings, whose
+        # bytes are a gauge of their own (they are among the slot-addressed
+        # leaves too)
+        self.window_kernels = 0
+        self.window_ring_bytes = 0
         self.prefill_chunk_size = 0      # gauge: engine K (0 = ladder)
         self.evictions = {r: 0 for r in EVICT_REASONS}
         # ---- speculative decoding (serving/speculative.py): draft
@@ -262,6 +274,15 @@ class ServingMetrics:
         with self._lock:
             self.attended_positions_total += int(n)
 
+    def observe_window_positions(self, attended, window_read, read):
+        """Positions the lanes of the step being prepared attend in one
+        window layer, and the positions its rows read, once a row, in a
+        window layer and in a full one."""
+        with self._lock:
+            self.window_attended_positions_total += int(attended)
+            self.window_read_positions_total += int(window_read)
+            self.read_positions_total += int(read)
+
     def observe_step_lanes(self, computed, live, prefill_rows=0):
         """The width of the step being handed over, the lanes fed and
         the rows among them that are fed a prompt chunk."""
@@ -284,6 +305,13 @@ class ServingMetrics:
         with self._lock:
             self.kda_kernels, self.mla_kernels = int(kda), int(mla)
             self.mamba_kernels = int(mamba)
+
+    def set_window(self, ring_bytes, kernels):
+        """Facts of a model with window layers: the bytes of their rings
+        and whether its step took the window kernel."""
+        with self._lock:
+            self.window_ring_bytes = int(ring_bytes)
+            self.window_kernels = int(kernels)
 
     def set_prefill_chunk(self, k):
         """Gauge: the engine's chunk size K (0 = legacy ladder mode)."""
@@ -515,11 +543,18 @@ class ServingMetrics:
                 "prefill_chunk_lanes_total":
                     self.prefill_chunk_lanes_total,
                 "attended_positions_total": self.attended_positions_total,
+                "window_attended_positions_total":
+                    self.window_attended_positions_total,
+                "window_read_positions_total":
+                    self.window_read_positions_total,
+                "read_positions_total": self.read_positions_total,
                 "step_lanes_computed_total": self.step_lanes_computed_total,
                 "step_lanes_live_total": self.step_lanes_live_total,
                 "kda_kernels": self.kda_kernels,
                 "mla_kernels": self.mla_kernels,
                 "mamba_kernels": self.mamba_kernels,
+                "window_kernels": self.window_kernels,
+                "window_ring_bytes": self.window_ring_bytes,
                 "prefill_chunk_size": self.prefill_chunk_size,
                 "speculate_k": self.speculate_k,
                 "mesh_shards": self.mesh_shards,
@@ -700,6 +735,17 @@ class ServingMetrics:
                 ("attended_positions_total", self.attended_positions_total,
                  "cached positions the seated rows' lanes attended, "
                  "their own included (paged steps)"),
+                ("window_attended_positions_total",
+                 self.window_attended_positions_total,
+                 "positions the seated rows' lanes attended in one window "
+                 "layer (min(position + 1, window) a lane)"),
+                ("window_read_positions_total",
+                 self.window_read_positions_total,
+                 "positions the seated rows read in one window layer, each "
+                 "once a row (models with window layers)"),
+                ("read_positions_total", self.read_positions_total,
+                 "positions the seated rows read in one full attention "
+                 "layer, each once a row (models with window layers)"),
                 ("step_lanes_computed_total",
                  self.step_lanes_computed_total,
                  "lanes the decode steps computed (a model's steps: the "
@@ -739,6 +785,8 @@ class ServingMetrics:
             slot_state_bytes = self.slot_state_bytes
             kda_kernels, mla_kernels = self.kda_kernels, self.mla_kernels
             mamba_kernels = self.mamba_kernels
+            window_kernels = self.window_kernels
+            ring_bytes = self.window_ring_bytes
         for metric, value, help_ in gen_counters:
             emit(metric, value, help_, mtype="counter")
         emit("prefill_chunk_size", chunk_size,
@@ -781,6 +829,12 @@ class ServingMetrics:
              "1 when a served model's step took the mla_chunk kernel")
         emit("mamba_kernels", mamba_kernels,
              "1 when a served model's step took the mamba_chunk kernel")
+        emit("window_kernels", window_kernels,
+             "1 when a served model's step took the window kernel over its "
+             "window layers' rings")
+        emit("window_ring_bytes", ring_bytes,
+             "bytes of a served model's window-layer rings, window + chunk "
+             "positions a slot (0 = no window layer)")
         emit("kv_cache_int8", int(kv_int8),
              "1 when the KV cache stores int8 + per-head scale sidecars "
              "(quantized serving; docs/serving.md)")

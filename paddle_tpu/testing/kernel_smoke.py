@@ -84,6 +84,15 @@ class Widths:
     cell_blocks_per_row: int = 16
     cell_contexts: tuple = (10, 200)
     cell_mean_context: int = 86
+    # the window kernel over per-slot rings of window + chunk positions in
+    # blocks of window_block, walked through a table of window_entries
+    window_slots: int = 4
+    window_heads: int = 6
+    window_kv_heads: int = 2
+    window_chunk: int = 8
+    window: int = 64
+    window_block: int = 16
+    window_entries: int = 32
 
 
 SMALL = Widths(heads=8, kv_heads=2, head_dim=128, slots=8, slab_len=256,
@@ -111,7 +120,12 @@ SERVING = Widths(heads=16, kv_heads=16, head_dim=128, slots=8,
                  mla_rope=64, mla_blocks_per_row=64,
                  # benchmark/configs/lm-opt-1.3b.json x chat_open.json
                  cell_heads=32, cell_head_dim=64, cell_blocks_per_row=128,
-                 cell_contexts=(100, 700), cell_mean_context=330)
+                 cell_contexts=(100, 700), cell_mean_context=330,
+                 # the laguna_repoctx cell's window layers at four of its 16
+                 # rows: 72 heads on 8 over rings of 576, table [4, 1024]
+                 window_slots=4, window_heads=72, window_kv_heads=8,
+                 window_chunk=64, window=512, window_block=32,
+                 window_entries=1024)
 
 
 class Case(NamedTuple):
@@ -708,6 +722,55 @@ def _mla_case(w):
                 facts={"pool_bytes": int(pool.size) * 4})
 
 
+_WHY_WINDOW = ("the window kernel runs on bfloat16 rings and queries as "
+               "served and returns bfloat16 (u = 2^-9) however it runs: "
+               "2*u*max|v| ~ 1e-2 on a row attending one position, as a "
+               "compiled decode case; an 8-bit-float pass is 16x off and "
+               "fails")
+
+
+def _window_case(w):
+    """The window kernel over per-slot rings (decode_attention.
+    decode_attention_window_chunk) against ``hybrid_lm``'s XLA path over
+    the same rings, bfloat16 as served: row 0 decodes deep in its context
+    (one lane), row 1 fills every lane with its window starting inside a
+    tile, so the tiles before it are skipped, row 2 starts at position 0,
+    row 3 ends inside a block with its window reaching back to 0."""
+    from paddle_tpu.models import hybrid_lm
+    from paddle_tpu.ops.pallas import decode_attention as kernel
+    s, kk, h, hkv = w.window_slots, w.window_chunk, w.window_heads, \
+        w.window_kv_heads
+    dh, win, bs, entries = w.head_dim, w.window, w.window_block, \
+        w.window_entries
+    ring = -(-(win + kk - 1) // bs) * bs
+    ks = jax.random.split(jax.random.PRNGKey(140), 3)
+    rings = [(0.5 * jax.random.normal(k, (s, ring, hkv * dh)))
+             .astype(jnp.bfloat16) for k in ks[:2]]
+    q = (0.5 * jax.random.normal(ks[2], (s, kk, h * dh))).astype(jnp.bfloat16)
+    span = entries * bs
+    pos = [span - 2, 2 * win + 3 * bs + 7, 0, bs + 1][:s] + [0] * max(0, s - 4)
+    lens = [1, kk, kk, max(1, kk // 2 + 3)][:s] + [1] * max(0, s - 4)
+    lane = np.arange(kk)[None, :]
+    qpos = jnp.asarray(np.asarray(pos)[:, None]
+                       + np.minimum(lane, np.asarray(lens)[:, None] - 1),
+                       jnp.int32)
+
+    def fn(q, k_ring, v_ring):
+        return kernel.decode_attention_window_chunk(
+            q, k_ring, v_ring, qpos, h, win, block=bs, entries=entries)
+
+    def oracle(q, k_ring, v_ring):
+        f32 = lambda x: x.astype(jnp.float32)
+        return hybrid_lm._ring_attention(
+            f32(q), f32(k_ring), f32(v_ring), qpos, hkv, dh, win) \
+            .reshape(s, kk, h * dh)
+
+    return Case(fn=fn, oracle=oracle, args=(q, *rings), err=_max_err,
+                facts={"ring_positions": ring, "window": win,
+                       "ring_bytes": 2 * int(rings[0].size) * 2},
+                tol=(_TOL_COMPILED, _WHY_WINDOW))
+
+
 def _decode(paged, chunk, quant, seed):
     return lambda w: _decode_case(w, paged=paged, chunk=chunk, quant=quant,
                                   seed=seed)
@@ -742,6 +805,7 @@ CASES = {
     "kda_chunk": _kda_case,
     "mla_chunk": _mla_case,
     "mamba_chunk": _mamba_case,
+    "decode_attention_window_chunk": _window_case,
 }
 
 
