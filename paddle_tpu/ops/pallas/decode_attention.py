@@ -42,7 +42,9 @@ ever exists in HBM.
   ``[2, G*bs, Dkv]`` scratch by hand (``_paged_tile_kernel``).  A table
   of 128 entries of 16 positions cost 1,024 grid steps a call, five in
   six of them dead at the serving contexts; tiled, the ``opt1.3b_chat``
-  call went from 0.27 to 0.08 ms (docs/kernels.md has the table).
+  call went from 0.27 to 0.08 ms (docs/kernels.md has the table).  A
+  row that feeds ONE lane (a decoding row) computes that lane's rows of
+  each panel alone, not all K lanes' (the one-lane path).
   With a WINDOW W the same tile loop starts at the tile of ``qpos_0 - W +
   1`` and skips the entries before it (``decode_attention_window_chunk``,
   ``decode_attn_window_chunk`` in a trace): over a per-slot ring of W + K
@@ -307,6 +309,17 @@ def _from_panels(o, kk, hkv, group, dh):
     return x.transpose(0, 4, 1, 2, 3, 5).reshape(s, kk, hkv * group * dh)
 
 
+def _one_lane_rows(heads, group, kk):
+    """Rows of a panel the tiled kernel's one-lane path computes: lane 0's
+    ``heads*group`` rounded up to a whole sublane tile of 8; None (no
+    one-lane path) where that is no fewer than the panel's
+    ``heads*group*K``, or where K is not a whole sublane tile (lane 0 of
+    head h is read and written at row ``h*K``; the cells' K are 8 and
+    64)."""
+    m1 = -(-heads * group // 8) * 8
+    return m1 if kk % 8 == 0 and m1 < heads * group * kk else None
+
+
 # ------------------------------------------------------------ kernel body
 
 def _accumulate(q, kb, vb, col0, blk, pos, m_scr, l_scr, acc_scr, *,
@@ -469,8 +482,8 @@ def _paged_chunk_kernel(pos_ref, tbl_ref, *args, **kw):
 
 
 def _paged_tile_kernel(pos_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf,
-                       vbuf, sem, first_slot, m_scr, l_scr, acc_scr, *, bs,
-                       g, kk, scale, window=None):
+                       vbuf, sem, first_slot, m_scr, l_scr, acc_scr, lim_scr,
+                       *q1, bs, g, kk, scale, window=None):
     """Paged body with a TILE of ``g`` table entries: one grid
     step is one ROW, and the row's live tiles are a loop inside it.
 
@@ -495,12 +508,45 @@ def _paged_tile_kernel(pos_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf,
     head of the panel, block-diagonal over the panel's heads — meet the
     tile in ONE ``[mp, wp] x [g*bs, wp]`` product, so K and V are read in
     whole lane tiles and a prefilling row's lanes share each product.
-    The online softmax is ``_accumulate``'s, on ``[mp, g*bs]`` scores."""
+    The online softmax is ``_accumulate``'s, on ``[mp, g*bs]`` scores.
+
+    The one-lane path: a row whose last lane repeats its first position
+    feeds ONE live lane (a decoding row, or a free slot; the predicate of
+    ``_chunk_kernel``'s fast path, read as data: no retrace).  Lane 0 of
+    the panel's head h is its row ``h * K``: once a row those ``hg`` rows
+    are copied into ``q1_scr`` (``[panels, m1, wp]``, ``m1`` = hg rounded
+    up to a sublane tile, ``_one_lane_rows``; none where the panel is no
+    larger), the row's tiles take those ``m1`` rows alone, in the first
+    rows of the running stats, and its finish writes lane 0's results into
+    an output of exact zeros.  Each query row's products and softmax are
+    its own, so a live lane's output is bit-for-bit the whole panel's; the
+    DMA walk is one code path for both.  Each loop over panels, heads and
+    lanes is traced once (``fori_loop`` unrolled as it lowers): a kernel's
+    trace is paid in every process's set-up."""
     r = pl.program_id(0)
     tile = g * bs
     n_p, mp, wp = acc_scr.shape
+    hg = mp // kk
+    q1_scr = q1[0] if q1 else None
     last = pos_ref[r, kk - 1]
     n_tiles = last // tile + 1
+
+    def each(n, fn):            # fn(i) for i < n, one trace, unrolled
+        def step(i, carry):
+            fn(i)
+            return carry
+        jax.lax.fori_loop(0, n, step, 0, unroll=True)
+
+    def paths(fn):
+        """``fn(rows)`` on the row's path: ``fn(m1)``, lane 0's rows in
+        ``q1_scr`` under its one position, or ``fn(mp)``, the whole panel
+        under each lane's own (``lim_scr``)."""
+        if q1_scr is None:
+            fn(mp)
+            return
+        one = last == pos_ref[r, 0]
+        pl.when(one)(lambda: fn(q1_scr.shape[1]))
+        pl.when(jnp.logical_not(one))(lambda: fn(mp))
 
     def first_entry(row):       # the row's first entry its lanes reach
         return jnp.maximum(pos_ref[row, 0] - window + 1, 0) // bs
@@ -528,17 +574,33 @@ def _paged_tile_kernel(pos_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf,
     @pl.when(r == 0)
     def _():
         vbuf[...] = jnp.zeros_like(vbuf)
+        if q1_scr is not None:
+            # the rows past hg hold no head: finite, and never written out
+            q1_scr[...] = jnp.zeros_like(q1_scr)
         first_slot[0] = 0
         copies(0, start(0), 0, "start")
 
     first = first_slot[0]
-    _init_row(m_scr, l_scr, acc_scr)
-    # row (u, gq, i) of a panel is lane i of one head: its own position
-    lane = jax.lax.broadcasted_iota(jnp.int32, (mp, tile), 0) % kk
-    lim = jnp.full((mp, tile), pos_ref[r, 0], jnp.int32)
-    for i in range(1, kk):
-        lim = jnp.where(lane >= i, pos_ref[r, i], lim)
-    col = jax.lax.broadcasted_iota(jnp.int32, (mp, tile), 1)
+
+    def init(rows):
+        m_scr[:, :rows] = jnp.full((n_p, rows, _LANES), _NEG, jnp.float32)
+        l_scr[:, :rows] = jnp.zeros((n_p, rows, _LANES), jnp.float32)
+        acc_scr[:, :rows] = jnp.zeros((n_p, rows, wp), jnp.float32)
+        if rows < mp:
+            def gather(h):      # lane 0 of head h: row h * K of each panel
+                q1_scr[:, pl.ds(h, 1)] = \
+                    q_ref[0, :, pl.ds(h * kk, 1)].astype(jnp.float32)
+
+            each(hg, gather)
+            return
+        # row (u, gq, i) of a panel is lane i of one head: its own
+        # position, once a row
+        lane = jax.lax.broadcasted_iota(jnp.int32, (mp, tile), 0) % kk
+        lim_scr[...] = jax.lax.fori_loop(
+            1, kk, lambda i, lim: jnp.where(lane >= i, pos_ref[r, i], lim),
+            jnp.full((mp, tile), pos_ref[r, 0], jnp.int32), unroll=True)
+
+    paths(init)
     t0 = start(r)
 
     def body(t, carry):
@@ -555,33 +617,66 @@ def _paged_tile_kernel(pos_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf,
             copies(nxt, start(nxt), 1 - slot, "start")
 
         copies(r, t, slot, "wait")
-        seen = col + t * tile <= lim
-        if window is not None:
-            seen = jnp.logical_and(seen, col + t * tile > lim - window)
-        for j in range(n_p):
-            cols = slice(j * wp, (j + 1) * wp)
-            s = jax.lax.dot_general(
-                q_ref[0, j].astype(jnp.float32), kbuf[slot, :, cols],
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale    # [mp, tile]
-            s = jnp.where(seen, s, _NEG)
-            m_prev, l_prev = m_scr[j], l_scr[j]                # [mp, LANES]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - _lanes(m_new, tile))
-            alpha = jnp.exp(m_prev - m_new)
-            m_scr[j] = m_new
-            l_scr[j] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc_scr[j] = acc_scr[j] * _lanes(alpha, wp) + jax.lax.dot_general(
-                p, vbuf[slot, :, cols], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)            # [mp, wp]
+
+        def attend(rows):
+            lim = pos_ref[r, 0] if rows < mp else lim_scr[...]
+            col = jax.lax.broadcasted_iota(jnp.int32, (rows, tile), 1) \
+                + t * tile
+            seen = col <= lim
+            if window is not None:
+                seen = jnp.logical_and(seen, col > lim - window)
+
+            def panel(j):
+                cols = pl.ds(pl.multiple_of(j * wp, wp), wp)
+                q = q1_scr[j] if rows < mp \
+                    else q_ref[0, j].astype(jnp.float32)
+                s = jax.lax.dot_general(
+                    q, kbuf[slot, :, cols], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale  # [rows, tile]
+                s = jnp.where(seen, s, _NEG)
+                m_prev, l_prev = m_scr[j, :rows], l_scr[j, :rows]
+                m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1,
+                                                    keepdims=True))
+                p = jnp.exp(s - _lanes(m_new, tile))
+                alpha = jnp.exp(m_prev - m_new)
+                m_scr[j, :rows] = m_new
+                l_scr[j, :rows] = l_prev * alpha + jnp.sum(p, axis=-1,
+                                                           keepdims=True)
+                acc_scr[j, :rows] = acc_scr[j, :rows] * _lanes(alpha, wp) \
+                    + jax.lax.dot_general(
+                        p, vbuf[slot, :, cols], (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)    # [rows, wp]
+
+            each(n_p, panel)
+
+        paths(attend)
         return carry
 
     jax.lax.fori_loop(t0, n_tiles, body, 0)
     first_slot[0] = (first + n_tiles) % 2 if window is None \
         else (first + n_tiles - t0) % 2
-    l = jnp.maximum(l_scr[...], 1e-30)
-    for j in range(n_p):
-        o_ref[0, j] = (acc_scr[j] / _lanes(l[j], wp)).astype(o_ref.dtype)
+
+    def finish(rows):
+        def panel(j):
+            o = acc_scr[j, :rows] / _lanes(
+                jnp.maximum(l_scr[j, :rows], 1e-30), wp)
+            if rows == mp:
+                o_ref[0, j] = o.astype(o_ref.dtype)
+            else:
+                acc_scr[j, :rows] = o
+
+        each(n_p, panel)
+        if rows < mp:
+            # lane 0's results, and the exact 0.0 of the lanes no token fed
+            o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+
+            def put(h):
+                o_ref[0, :, pl.ds(h * kk, 1)] = \
+                    acc_scr[:, pl.ds(h, 1)].astype(o_ref.dtype)
+
+            each(hg, put)
+
+    paths(finish)
 
 
 # ------------------------------------------------------------ public API
@@ -774,6 +869,7 @@ def _paged_chunk_tiled(q, k, v, qpos, tables, *, g, num_heads, interpret,
     dh, hkv, group = _head_split(d, dkv, num_heads)
     qp = _to_panels(q, hkv, group, dh)
     _s, n_p, mp, wp = qp.shape
+    m1 = _one_lane_rows(_panel_heads(hkv, dh), group, kk)
     row = pl.BlockSpec((1, n_p, mp, wp), lambda r, pos, tbl: (r, 0, 0, 0))
     pool = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -789,7 +885,8 @@ def _paged_chunk_tiled(q, k, v, qpos, tables, *, g, num_heads, interpret,
             pltpu.VMEM((n_p, mp, _LANES), jnp.float32),
             pltpu.VMEM((n_p, mp, _LANES), jnp.float32),
             pltpu.VMEM((n_p, mp, wp), jnp.float32),
-        ],
+            pltpu.VMEM((mp, g * bs), jnp.int32),
+        ] + ([] if m1 is None else [pltpu.VMEM((n_p, m1, wp), jnp.float32)]),
     )
     kernel = functools.partial(_paged_tile_kernel, bs=bs, g=g, kk=kk,
                                scale=1.0 / math.sqrt(dh))

@@ -1443,16 +1443,23 @@ class DecodeEngine:
             # speculation, as in _collect), the chunks' lanes, and the
             # positions its lanes attend (prepare_step's count, paged)
             prefill_rows = int((lens > 1).sum()) - len(spec_armed)
+            # and the seated rows fed exactly one lane: those the tiled
+            # attention kernel computes one lane of (a free slot takes
+            # that path too, and is not a row of the step)
+            seated = np.ones(self.num_slots, bool)
+            seated[self._free] = False
+            one_lane_rows = int((lens[seated] == 1).sum())
             ph.set(width=width, live=live, lanes=tokens.size,
                    rows=self.num_active, prefill_rows=prefill_rows,
                    prefill_lanes=live - self.num_slots
                    - sum(spec_armed.values()),
-                   attended=self._attended)
+                   attended=self._attended, one_lane_rows=one_lane_rows)
             self._attended = 0
             if self._window:
                 ph.set(window_attended=self._window_attended)
                 self._window_attended = 0
-            self.metrics.observe_step_lanes(width, live, prefill_rows)
+            self.metrics.observe_step_lanes(width, live, prefill_rows,
+                                            one_lane_rows)
             # the fault point sits at the device-step boundary: a hang
             # here models a wedged device step for the watchdog to catch
             faults.hit("serving.decode_step")

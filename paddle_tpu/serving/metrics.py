@@ -129,6 +129,10 @@ class ServingMetrics:
         # slot's one armed lane included; counted at the hand-over
         self.step_lanes_computed_total = 0
         self.step_lanes_live_total = 0
+        # row-steps of seated rows fed exactly one lane (decoding rows, a
+        # stalled row's one token): those the tiled attention kernel
+        # computes one lane of; counted at the hand-over
+        self.one_lane_row_steps_total = 0
         # facts, set at warm-up: did a model's step (DecodeEngine(model=))
         # take its recurrent (kda_chunk), latent (mla_chunk) and
         # selective-scan (mamba_chunk) kernels
@@ -283,13 +287,16 @@ class ServingMetrics:
             self.window_read_positions_total += int(window_read)
             self.read_positions_total += int(read)
 
-    def observe_step_lanes(self, computed, live, prefill_rows=0):
-        """The width of the step being handed over, the lanes fed and
-        the rows among them that are fed a prompt chunk."""
+    def observe_step_lanes(self, computed, live, prefill_rows=0,
+                           one_lane_rows=0):
+        """The width of the step being handed over, the lanes fed, the
+        rows among them that are fed a prompt chunk and the seated rows
+        fed exactly one lane."""
         with self._lock:
             self.step_lanes_computed_total += int(computed)
             self.step_lanes_live_total += int(live)
             self.decode_steps_with_prefill_total += int(prefill_rows > 0)
+            self.one_lane_row_steps_total += int(one_lane_rows)
 
     def observe_prefill_stalled(self, rows):
         """Rows of the step being prepared that still have prompt to
@@ -550,6 +557,7 @@ class ServingMetrics:
                 "read_positions_total": self.read_positions_total,
                 "step_lanes_computed_total": self.step_lanes_computed_total,
                 "step_lanes_live_total": self.step_lanes_live_total,
+                "one_lane_row_steps_total": self.one_lane_row_steps_total,
                 "kda_kernels": self.kda_kernels,
                 "mla_kernels": self.mla_kernels,
                 "mamba_kernels": self.mamba_kernels,
@@ -753,6 +761,9 @@ class ServingMetrics:
                 ("step_lanes_live_total", self.step_lanes_live_total,
                  "lanes rows fed into the decode steps, a free slot's "
                  "one included"),
+                ("one_lane_row_steps_total", self.one_lane_row_steps_total,
+                 "seated rows fed exactly one lane, summed over steps: the "
+                 "rows the tiled attention kernel computes one lane of"),
                 ("drafted_tokens_total", self.drafted_tokens_total,
                  "draft lanes scored by verify steps (speculative "
                  "decoding)"),

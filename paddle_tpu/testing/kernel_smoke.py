@@ -530,47 +530,123 @@ def _decode_case(w, *, paged, chunk, quant, seed, contexts=None):
                 lambda got, want: _max_err(got[live], want[live]))
 
 
-def time_paged_chunk_cell(w, calls=24, reps=20):
-    """Wall ms a call of ``decode_attention_paged_chunk`` ALONE at the
-    opt1.3b_chat cell's shape: every row at position 0 (all but a row's
-    first tile dead), at the cell's mean context, and at the table's last
-    position (none dead), decode rows (one live lane) and prefill rows
-    (every lane) apart; then the cell's own mix, contexts drawn from
-    ``cell_contexts`` with one row in eight prefilling.  ``calls`` kernels
-    chained in one program, as the step chains its layers; a time only on
-    the chip."""
-    import time
-    from paddle_tpu.ops.pallas import decode_attention as dk
-    c = _cell(w)
-    h, dh, s, kk = c.heads, c.head_dim, c.slots, c.chunk
-    bs, nb_row = c.block_size, c.blocks_per_row
-    d, t = h * dh, nb_row * bs
-    rng = np.random.RandomState(5)
-    q = jnp.asarray(rng.randn(s, kk, d) * 0.5, jnp.float32)
-    k, v = (jnp.asarray(rng.randn(s * nb_row + 1, bs, d) * 0.5, jnp.float32)
-            for _ in range(2))
-    lo, hi = w.cell_contexts
-    one, full = np.ones(s, np.int64), np.full(s, kk)
-    mix = one.copy()
-    mix[-1] = kk
-    settings = {"cell_mix": (rng.randint(lo, hi + 1, s), mix)}
-    for last in (0, w.cell_mean_context, t - 1):
-        settings[f"decode_at_{last}"] = (np.full(s, last), one)
-        last = max(last, kk - 1)
-        settings[f"prefill_at_{last}"] = (np.full(s, last), full)
+# the panels the tiled paged kernel serves, as the cells call it: (query
+# heads, K/V heads, head dim, lanes a row, block, dtype, window).  Chat's
+# two heads of 64 a block-diagonal panel, Laguna's full layers (group 6)
+# and window layers (group 9, over rings), Jamba's group 20 on one K/V head
+ONE_LANE_PANELS = {
+    "opt1.3b_chat": (32, 32, 64, 8, 16, jnp.float32, None),
+    "laguna_full": (48, 8, 128, 64, 32, jnp.bfloat16, None),
+    "laguna_window": (72, 8, 128, 64, 32, jnp.bfloat16, 512),
+    "jamba_attn": (20, 1, 128, 64, 32, jnp.bfloat16, None),
+}
 
-    @jax.jit        # positions and tables are data: one program for all
-    def chain(q, k, v, qpos, tables):
+
+def _panel_call(panel, slots, entries, key):
+    """``(q, call)``: queries and the tiled kernel's call ``call(q, qpos,
+    tables)`` of ``panel`` (a row of ``ONE_LANE_PANELS``) over ``slots``
+    rows' random K/V: private tables of ``entries`` blocks a row, or with
+    a window the rows' rings (the tables are then the kernel's own)."""
+    from paddle_tpu.ops.pallas import decode_attention as dk
+    h, hkv, dh, kk, bs, dtype, window = panel
+    keys = iter(jax.random.split(key, 3))
+    rand = lambda *shp: 0.5 * jax.random.normal(next(keys), shp, dtype)
+    q = rand(slots, kk, h * dh)
+    if window is None:
+        k, v = (rand(slots * entries + 1, bs, hkv * dh) for _ in range(2))
+        return q, lambda q, qpos, tables: dk.decode_attention_paged_chunk(
+            q, k, v, qpos, tables, h)
+    ring = -(-(window + kk - 1) // bs) * bs
+    k, v = (rand(slots, ring, hkv * dh) for _ in range(2))
+    return q, lambda q, qpos, tables: dk.decode_attention_window_chunk(
+        q, k, v, qpos, h, window, block=bs, entries=entries)
+
+
+def one_lane_vs_panel(panel, positions, entries, seed=0):
+    """The tiled kernel's output ``(one, two)`` [S, K, D] for rows at
+    ``positions`` fed ONE lane — the one-lane path — and for the same rows
+    fed a second lane at the next position, which takes the whole panel.
+    Lane 0 reads the same positions in both (a tile past it is a bit-exact
+    no-op), so ``one[:, 0]`` must equal ``two[:, 0]`` bit for bit, and
+    ``one[:, 1:]`` is exact zeros.  ``panel``: a row of
+    ``ONE_LANE_PANELS``."""
+    pos = np.asarray(positions, np.int64)
+    s, kk, bs = pos.size, panel[3], panel[4]
+    q, call = _panel_call(panel, s, entries, jax.random.PRNGKey(seed))
+    tables = jnp.asarray(build_private_tables(pos + 1, entries, bs,
+                                              s * entries + 1))
+    return tuple(np.asarray(jax.jit(call)(q, jnp.asarray(_chunk_lanes_ref(
+        pos, np.full(s, n), kk)), tables)) for n in (1, 2))
+
+
+# the laguna_repoctx cell's two attention calls (ONE_LANE_PANELS' laguna
+# rows) over 16 rows and tables of 1,024 entries, timed at these positions
+LAGUNA_CONTEXTS = (600, 2048, 8192, 16384, 30720)
+
+
+def laguna_settings(slots=16, chunk=64, span=32768,
+                    contexts=LAGUNA_CONTEXTS):
+    """``time_paged_chunk_cell``'s settings at the laguna_repoctx cell:
+    ``decode_at_<p>`` puts every row at position p feeding one lane;
+    ``cell_mix`` is a step of the cell, six rows decoding at 12-24 k, one
+    prefilling ``chunk`` lanes at 10 k, the other slots free at 0."""
+    one = np.ones(slots, np.int64)
+    settings = {f"decode_at_{p}": (np.full(slots, min(p, span - 1)), one)
+                for p in contexts}
+    last, lens = np.zeros(slots, np.int64), one.copy()
+    last[:6] = np.minimum(np.linspace(12288, 24576, 6).astype(np.int64),
+                          span - 1)
+    last[6], lens[6] = min(10240, span - 1), chunk
+    settings["cell_mix"] = (last, lens)
+    return settings
+
+
+def time_paged_chunk_cell(w, calls=24, reps=20, panel=None, slots=None,
+                          entries=None, settings=None):
+    """Wall ms a call of the tiled paged kernel ALONE, ``{setting: ms}``.
+    By default at the opt1.3b_chat cell's shape: every row at position 0
+    (all but a row's first tile dead), at the cell's mean context, and at
+    the table's last position (none dead), decode rows (one live lane) and
+    prefill rows (every lane) apart; then the cell's own mix, contexts
+    drawn from ``cell_contexts`` with one row in eight prefilling.
+    ``panel`` (a row of ``ONE_LANE_PANELS``; its window's form where it
+    has one), ``slots``, ``entries`` (table entries a row) and
+    ``settings`` (``{name: (last positions, lanes fed)}``, e.g.
+    ``laguna_settings()``) time another cell's call.  ``calls`` kernels
+    chained in one program, as the step chains its layers; positions and
+    tables are data (one compile a kernel); a time only on the chip."""
+    import time
+    if panel is None:
+        c = _cell(w)
+        panel = (c.heads, c.kv_heads, c.head_dim, c.chunk, c.block_size,
+                 jnp.float32, None)
+        slots, entries = c.slots, c.blocks_per_row
+    kk, bs = panel[3], panel[4]
+    t = entries * bs
+    if settings is None:
+        rng = np.random.RandomState(5)
+        lo, hi = w.cell_contexts
+        one, full = np.ones(slots, np.int64), np.full(slots, kk)
+        mix = one.copy()
+        mix[-1] = kk
+        settings = {"cell_mix": (rng.randint(lo, hi + 1, slots), mix)}
+        for last in (0, w.cell_mean_context, t - 1):
+            settings[f"decode_at_{last}"] = (np.full(slots, last), one)
+            last = max(last, kk - 1)
+            settings[f"prefill_at_{last}"] = (np.full(slots, last), full)
+    q, call = _panel_call(panel, slots, entries, jax.random.PRNGKey(5))
+
+    @jax.jit
+    def chain(q, qpos, tables):
         for _ in range(calls):
-            q = dk.decode_attention_paged_chunk(q, k, v, qpos, tables, h)
+            q = call(q, qpos, tables)
         return q
 
     out = {}
     for name, (last, lens) in settings.items():
-        args = (q, k, v,
-                jnp.asarray(_chunk_lanes_ref(last - lens + 1, lens, kk)),
-                jnp.asarray(build_private_tables(last, nb_row, bs,
-                                                 k.shape[0])))
+        args = (q, jnp.asarray(_chunk_lanes_ref(last - lens + 1, lens, kk)),
+                jnp.asarray(build_private_tables(last, entries, bs,
+                                                 slots * entries + 1)))
         jax.block_until_ready(chain(*args))
         t0 = time.perf_counter()
         for _ in range(reps):
@@ -735,7 +811,9 @@ def _window_case(w):
     the same rings, bfloat16 as served: row 0 decodes deep in its context
     (one lane), row 1 fills every lane with its window starting inside a
     tile, so the tiles before it are skipped, row 2 starts at position 0,
-    row 3 ends inside a block with its window reaching back to 0."""
+    row 3 ends inside a block with its window reaching back to 0.  LIVE
+    lanes are compared, as in the decode cases: a one-lane row's other
+    lanes are the zeros of the kernel's one-lane path."""
     from paddle_tpu.models import hybrid_lm
     from paddle_tpu.ops.pallas import decode_attention as kernel
     s, kk, h, hkv = w.window_slots, w.window_chunk, w.window_heads, \
@@ -754,6 +832,7 @@ def _window_case(w):
     qpos = jnp.asarray(np.asarray(pos)[:, None]
                        + np.minimum(lane, np.asarray(lens)[:, None] - 1),
                        jnp.int32)
+    live = jnp.asarray(lane < np.asarray(lens)[:, None])
 
     def fn(q, k_ring, v_ring):
         return kernel.decode_attention_window_chunk(
@@ -765,7 +844,8 @@ def _window_case(w):
             f32(q), f32(k_ring), f32(v_ring), qpos, hkv, dh, win) \
             .reshape(s, kk, h * dh)
 
-    return Case(fn=fn, oracle=oracle, args=(q, *rings), err=_max_err,
+    return Case(fn=fn, oracle=oracle, args=(q, *rings),
+                err=lambda got, want: _max_err(got[live], want[live]),
                 facts={"ring_positions": ring, "window": win,
                        "ring_bytes": 2 * int(rings[0].size) * 2},
                 tol=(_TOL_COMPILED, _WHY_WINDOW))

@@ -364,7 +364,8 @@ def test_step_stats_reach_the_host_plane_with_the_tracer_off(tmp_path):
     assert len(disp) == engine.metrics.decode_steps_total
     for st in disp:
         assert {"step", "in_flight", "width", "live", "lanes", "rows",
-                "prefill_rows", "prefill_lanes", "attended"} == set(st)
+                "prefill_rows", "prefill_lanes", "attended",
+                "one_lane_rows"} == set(st)
     # 9 to feed at K = 4: chunks of 3, 3 and 1 lanes, then decode steps
     assert [st["prefill_lanes"] for st in disp][:4] == [3, 3, 1, 0]
     assert [st["prefill_rows"] for st in disp][:4] == [1, 1, 1, 0]
@@ -373,6 +374,58 @@ def test_step_stats_reach_the_host_plane_with_the_tracer_off(tmp_path):
     # the counters an operator has without a profiler moved all the same
     assert engine.metrics.decode_steps_with_prefill_total == 3
     assert engine.metrics.prefill.count == 1
+
+
+def test_one_lane_rows_are_the_seated_rows_fed_one_lane():
+    """``one_lane_rows`` on each ``engine.step.dispatch`` phase, and the
+    ``one_lane_row_steps_total`` counter they sum to, are the seated rows
+    of that step fed exactly ONE lane (``lens == 1``): the tiled attention
+    kernel's one-lane predicate.  A free slot's armed lane takes that path
+    too and is no row of the step.  Two slots, three requests at K = 4:
+    steps with a row prefilling beside a row decoding, both decoding, one
+    slot free."""
+    from paddle_tpu.serving.decode_engine import (DecodeEngine,
+                                                  GenerationBatcher)
+    engine = DecodeEngine(_lm_params(), num_heads=2, num_slots=2,
+                          max_len=32, prefill_chunk=4, kv_layout="paged",
+                          kv_block_size=8, name="obs_one_lane")
+    want = {}
+    dispatch = engine.dispatch_step
+
+    def counted():
+        seated = [s for s in range(engine.num_slots)
+                  if s not in engine._free]
+        want[engine.steps_dispatched] = sum(
+            int(engine._len[s]) == 1 for s in seated)
+        return dispatch()
+
+    engine.dispatch_step = counted
+    trace.enable(sample=1.0, capacity=4096, process="unit")
+    gen = GenerationBatcher(engine, default_max_tokens=3)
+    try:
+        futs = [gen.submit((np.arange(1, 4 + 5 * i) + i) % 60, max_tokens=3)
+                for i in range(3)]
+        assert all(len(f.result(60)["tokens"]) == 3 for f in futs)
+    finally:
+        gen.close()
+    got = {p["step"]: p["attrs"]["one_lane_rows"]
+           for p in trace.get_tracer().phases()
+           if p["name"] == "engine.step.dispatch"}
+    assert got == want and len(got) == engine.metrics.decode_steps_total
+    disp = {p["step"]: p["attrs"] for p in trace.get_tracer().phases()
+            if p["name"] == "engine.step.dispatch"}
+    # the kinds of step the drive was built to hold
+    kinds = {(d["rows"], d["prefill_rows"], d["one_lane_rows"])
+             for d in disp.values()}
+    assert {(2, 1, 1), (2, 0, 2), (1, 0, 1)} <= kinds, kinds
+    for d in disp.values():     # no speculation: a row is one or the other
+        assert d["one_lane_rows"] == d["rows"] - d["prefill_rows"]
+    m = engine.metrics
+    assert m.one_lane_row_steps_total == sum(want.values()) > 0
+    assert m.snapshot()["one_lane_row_steps_total"] \
+        == m.one_lane_row_steps_total
+    assert (f"one_lane_row_steps_total {m.one_lane_row_steps_total}"
+            in m.render_prometheus())
 
 
 def test_debug_traces_endpoint_carries_phases():

@@ -414,6 +414,97 @@ def test_paged_chunk_tile_rule(monkeypatch):
     assert tile(32, 2048, 2048, 16, 128, 8) == 4
 
 
+# the cells' panels (kernel_smoke.ONE_LANE_PANELS) cut to interpret-mode
+# sizes, every panel layout kept: chat's block-diagonal two heads of 64,
+# group 6 and group 9 (windowed, over rings) on 128-wide heads, group 20 on
+# ONE K/V head.  (query heads, K/V heads, head dim, lanes, block, dtype,
+# window)
+TINY_PANELS = {
+    "opt1.3b_chat": (4, 4, 64, KK, BS_T, jnp.float32, None),
+    "laguna_full": (12, 2, 128, KK, BS_T, jnp.bfloat16, None),
+    "laguna_window": (18, 2, 128, KK, 8, jnp.bfloat16, 16),
+    "jamba_attn": (20, 1, 128, KK, BS_T, jnp.bfloat16, None),
+}
+
+
+@pytest.mark.parametrize("panel", sorted(TINY_PANELS))
+def test_one_lane_path_is_the_whole_panel_bit_for_bit(panel):
+    """A row that feeds one lane takes the tiled kernel's one-lane path
+    (lane 0's rows of each panel); the same row fed a second lane takes the
+    whole panel.  Lane 0 is the same bits either way, at the first
+    position, on each side of a tile seam and at the table's end, and the
+    lanes no token fed are exact zeros."""
+    from paddle_tpu.testing.kernel_smoke import one_lane_vs_panel
+    spec = TINY_PANELS[panel]
+    entries = 16 if spec[-1] else NB_ROW_T
+    span = entries * spec[4]
+    tile = 128
+    positions = [p for p in (0, 5, tile - 2, tile - 1, tile, span - 2)
+                 if p + 1 < span]
+    one, two = one_lane_vs_panel(spec, positions, entries,
+                                 seed=len(panel))
+    np.testing.assert_array_equal(one[:, 0], two[:, 0])
+    assert np.isfinite(one[:, 0].astype(np.float32)).all()
+    assert not one[:, 1:].astype(np.float32).any()
+    # the second lane is a lane of its own: the panel computed it
+    assert two[:, 1].astype(np.float32).any()
+
+
+@pytest.mark.parametrize("panel", sorted(TINY_PANELS))
+def test_one_lane_rows_beside_every_other_kind_of_row(panel):
+    """One step of every kind of row: rows decoding (one lane, the
+    one-lane path) deep in the table and at its start, a row prefilling
+    every lane, the partly live last chunk of a prompt, and a free slot
+    (its one armed lane at position 0 over the scratch block, or its own
+    ring).  Every live lane matches the XLA reference; the lanes no token
+    fed in a one-lane row are 0.0."""
+    from paddle_tpu.models import hybrid_lm
+    h, hkv, dh, kk, bs, dtype, window = TINY_PANELS[panel]
+    entries = 16 if window else NB_ROW_T
+    span = entries * bs
+    last = np.asarray([span - 3, 9, 200 % span, span // 2 + 5, 0])
+    lens = np.asarray([1, 1, kk, 3, 1])
+    free = 4
+    rng = np.random.RandomState(len(panel) + 7)
+    s = last.size
+    qpos = _chunk_lanes_ref_np(last - lens + 1, lens, kk)
+    q = jnp.asarray(rng.randn(s, kk, h * dh) * 0.5, dtype)
+    live = np.arange(kk)[None, :] < lens[:, None]
+    f32 = lambda x: np.asarray(jnp.asarray(x, jnp.float32))
+    with dk.forced_mode("always"):
+        if window is None:
+            nb = s * entries + 1
+            kp, vp = (jnp.asarray(rng.randn(nb, bs, hkv * dh) * 0.5, dtype)
+                      for _ in range(2))
+            from paddle_tpu.testing.kernel_smoke import build_private_tables
+            tables = build_private_tables(last, entries, bs, nb)
+            tables[free] = 0            # a free slot's table names block 0
+            got = dk.maybe_paged_chunk(q, kp, vp, jnp.asarray(qpos),
+                                       jnp.asarray(tables), h)
+            want = _ref_paged_chunk(f32(q), f32(kp), f32(vp), qpos, tables, h)
+        else:
+            ring = -(-(window + kk - 1) // bs) * bs
+            kr, vr = (jnp.asarray(rng.randn(s, ring, hkv * dh) * 0.5, dtype)
+                      for _ in range(2))
+            got = dk.maybe_window_chunk(q, kr, vr, jnp.asarray(qpos), h,
+                                        window, block=bs, entries=entries)
+            want = hybrid_lm._ring_attention(
+                *map(jnp.asarray, (f32(q), f32(kr), f32(vr))),
+                jnp.asarray(qpos), hkv, dh, window).reshape(s, kk, h * dh)
+    assert got is not None
+    got = f32(got)
+    np.testing.assert_allclose(got[live], np.asarray(want)[live],
+                               rtol=2e-2 if dtype == jnp.bfloat16 else 1e-5,
+                               atol=2e-2 if dtype == jnp.bfloat16 else 1e-5)
+    assert not got[lens == 1][:, 1:].any()
+    assert np.isfinite(got).all()
+
+
+def _chunk_lanes_ref_np(positions, lengths, kk):
+    from paddle_tpu.testing.kernel_smoke import _chunk_lanes_ref
+    return _chunk_lanes_ref(np.asarray(positions), np.asarray(lengths), kk)
+
+
 def test_tile_positions_names_each_kernels_step(slab_engine, paged_engine):
     """What ``DecodeEngine.warmup`` logs beside the resolved path: the
     K/V positions one step of the serving kernel covers — the slab
