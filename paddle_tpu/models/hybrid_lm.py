@@ -5,10 +5,11 @@ ops/kda.py; ``"mla"``: latent attention over the paged pool, ops/mla.py;
 ``"mamba"``: a selective scan with per-slot state, ops/mamba.py; ``"attn"``:
 softmax attention with grouped K/V over paged K and V pools,
 ``attn_chunk``; ``"window"``: the same over the last ``window`` positions,
-whose K and V live in a per-slot ring) and a per-layer FFN kind
-(``"dense"``: a gated SiLU FFN; ``"moe"``: a sigmoid- or softmax-routed
-expert layer that holds its share of the experts plus a shared expert,
-ops/moe.py).
+whose K and V live in a per-slot ring; ``"sparse"``: softmax attention
+over the positions a learned indexer selects, ``sparse_chunk``) and a
+per-layer FFN kind (``"dense"``: a gated SiLU FFN; ``"moe"``: a sigmoid- or
+softmax-routed expert layer that holds its share of the experts, plus a
+shared expert where the model has one, ops/moe.py).
 
     x += Attn_l(RMSNorm(x));  x += FFN_l(RMSNorm(x));  logits = RMSNorm(x_L) W_head
 
@@ -18,14 +19,17 @@ layer).  An MLA layer may have a low-rank query with its own norm
 (``q_rank``) and rotate its 64 query columns and the shared key part
 (``rope_theta``); softmax attention may turn q and k (whole heads or
 their leading part, plain or YaRN frequencies) and gate each head's
-output.  Four published families build a ``Config``
+output.  Five published families build a ``Config``
 (``config_from_hf``): ``kimi_linear`` (KDA and unrotated MLA, 3 to 1),
 ``pangu_ultra_moe`` (MLA in every layer, rotated, a low-rank query,
 sandwich norms: a cache of latent pools only, no slot owns state),
 ``jamba`` (Mamba with one unrotated multi-query attention layer a period,
 dense FFNs, a tied head, no positional signal of any kind) and ``laguna``
 (window and full attention 3 to 1 with their own head counts and
-rotations, per-head output gates, a softmax router).
+rotations, per-head output gates, a softmax router) and ``keye`` (sparse
+attention in every layer: per-head q/k norms, a lightning indexer whose
+keys have their own paged leaf, an exact top-k; a softmax router without
+a shared expert).
 
 ``DecodeEngine(params, model=Served(cfg))`` serves it through the one
 chunked paged step (docs/serving.md "Models that hold state"), whose
@@ -37,7 +41,8 @@ KDA or Mamba layer's recurrent state and convolution tail are
 slot-addressed, zeroed as data inside the step when a row starts at
 position 0, and left alone by lanes past a row's length; a window layer's
 ring is slot-addressed too, and needs no zeroing (a lane reads only
-positions its row wrote since position 0).
+positions its row wrote since position 0); a sparse layer's K, V and
+indexer keys (``"ik"``) are three block-addressed leaves.
 
 The residual stream, the norms, the router and the recurrence are float32;
 matrix products follow ``ops/linear.matmul``."""
@@ -51,7 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from paddle_tpu.models.transformer import _chunk_lanes
-from paddle_tpu.ops import kda, linear, mamba, mla, moe
+from paddle_tpu.ops import dsa, kda, linear, mamba, mla, moe
 from paddle_tpu.serving.kv_pool import BLOCK_LEAF, SLOT_LEAF
 
 
@@ -104,6 +109,12 @@ class Config:
     window_rope: tuple = None
     window: int = 0
     router: str = "sigmoid"     # or "softmax": over all experts, no bias
+    # "sparse" layers (q and k each RMS-normed a head before rotation):
+    # the indexer's heads, rotation and the positions a lane keeps
+    index_heads: int = 0
+    index_dim: int = 0
+    index_rope: tuple = None
+    index_topk: int = 0
 
     @property
     def latent_width(self):
@@ -137,11 +148,14 @@ def config_from_hf(c):
     the routed experts then gives those held of ``num_experts_published``,
     by rank ``rank``).  ``mamba_d_state`` and ``attn_layer_period`` are the
     third family (``_jamba_config``), ``layer_types`` with
-    ``num_attention_heads_per_layer`` the fourth (``_laguna_config``)."""
+    ``num_attention_heads_per_layer`` the fourth (``_laguna_config``),
+    ``sa_config`` the fifth (``_keye_config``)."""
     if "mamba_d_state" in c and "attn_layer_period" in c:
         return _jamba_config(c)
     if "layer_types" in c and "num_attention_heads_per_layer" in c:
         return _laguna_config(c)
+    if "sa_config" in c:
+        return _keye_config(c)
 
     def either(*keys):
         return next(c[k] for k in keys if k in c)
@@ -260,6 +274,58 @@ def _laguna_config(c):
         window=int(c.get("sliding_window") or 0), router="softmax")
 
 
+def _keye_config(c):
+    """The ``keye`` family (Keye-VL-2.0's language model, Qwen3-MoE-shaped):
+    every layer is a ``"sparse"`` attention layer (``num_attention_heads``
+    query heads on ``num_key_value_heads``, q and k RMS-normed a head, all
+    of each head turned at ``rope_theta``; a lightning indexer of
+    ``sa_config.indexer_num_heads`` heads of ``indexer_head_dim`` on one
+    key head, the leading half of its head turned, keeping ``topk``
+    positions a lane) and a softmax-routed expert layer with its chosen
+    shares renormalised and no shared expert.  ``rope_scaling.
+    mrope_section`` must cover half a head: a text token's three positions
+    are equal, and the rotation is the plain one.  ``expert_parallel`` as
+    for the other families."""
+    sa = c["sa_config"]
+    section = (c.get("rope_scaling") or {}).get("mrope_section")
+    dh = c["head_dim"]
+    if section and 2 * sum(section) != dh:
+        raise NotImplementedError(
+            f"mrope_section {section} does not cover half a head of {dh}")
+    if sa.get("indexer_num_kv_heads", 1) != 1 or c.get("mlp_only_layers") \
+            or c.get("decoder_sparse_step", 1) != 1 \
+            or c.get("use_sliding_window") \
+            or not c.get("norm_topk_prob", True):
+        raise NotImplementedError(
+            "the keye family is served with one indexer key head, an "
+            "expert layer in every layer, no window and renormalised "
+            "shares")
+    ep = c.get("expert_parallel") or {}
+    count = c["num_experts"]
+    theta = float(c["rope_theta"])
+    di = sa["indexer_head_dim"]
+    return Config(
+        vocab_size=c["vocab_size"], hidden_size=c["hidden_size"],
+        layers=(("sparse", "moe"),) * c["num_hidden_layers"],
+        rms_norm_eps=c["rms_norm_eps"],
+        kda_heads=0, kda_head_dim=0, conv_kernel=0, kda_gate_rank=0,
+        mla_heads=0, qk_nope=0, qk_rope=0, v_head_dim=0, kv_rank=0,
+        dense_width=c["intermediate_size"],
+        expert_width=c["moe_intermediate_size"],
+        router_width=ep.get("num_experts_published", count),
+        held=(ep.get("rank", 0) * count, count),
+        top_k=c["num_experts_per_tok"], routed_scale=1.0, shared_experts=0,
+        attn_heads=c["num_attention_heads"],
+        attn_kv_heads=c["num_key_value_heads"], attn_head_dim=dh,
+        tie_embeddings=bool(c["tie_word_embeddings"]),
+        attn_rope=rope_frequencies(dh, {"rope_theta": theta}),
+        router="softmax",
+        index_heads=sa["indexer_num_heads"], index_dim=di,
+        index_rope=rope_frequencies(di, {"rope_theta": theta,
+                                         "partial_rotary_factor": 0.5}),
+        index_topk=sa["topk"])
+
+
 def rope_frequencies(head_dim, spec):
     """One kind of layer's rotation from its ``rope_parameters`` entry ->
     (rotary_dim, inv_freq (rotary_dim / 2 floats), cos/sin scale).  The
@@ -321,6 +387,17 @@ def _init_attn(key, cfg, kind, dtype):
         if cfg.attn_gate:
             out["wgate"] = lin(ks[2], d, heads)
         return out
+    if kind == "sparse":
+        dh, heads, di = cfg.attn_head_dim, cfg.attn_heads, cfg.index_dim
+        return {"wqkv": lin(ks[0], d, (heads + 2 * cfg.attn_kv_heads) * dh),
+                "wo": lin(ks[1], heads * dh, d),
+                "q_norm": jnp.ones((dh,), jnp.float32),
+                "k_norm": jnp.ones((dh,), jnp.float32),
+                "wq_index": lin(ks[2], d, cfg.index_heads * di),
+                "wk_index": lin(ks[3], d, di),
+                "k_index_norm": jnp.ones((di,), jnp.float32),
+                "k_index_bias": jnp.zeros((di,), jnp.float32),
+                "w_index": lin(ks[4], d, cfg.index_heads)}
     if kind == "mamba":
         di, n, r = cfg.mamba_inner, cfg.mamba_state, cfg.mamba_dt_rank
         # Mamba's own start: A = 1..n in every column, D = 1, dt in
@@ -378,8 +455,9 @@ def _init_ffn(key, cfg, kind, dtype):
                             jnp.float32)
     if cfg.router == "sigmoid":
         out["router_bias"] = jnp.zeros((cfg.router_width,), jnp.float32)
-    out["shared"] = gated(jax.random.split(ks[4], 3),
-                          cfg.expert_width * cfg.shared_experts)
+    if cfg.shared_experts:
+        out["shared"] = gated(jax.random.split(ks[4], 3),
+                              cfg.expert_width * cfg.shared_experts)
     return out
 
 
@@ -436,7 +514,11 @@ def init_cache(cfg, slots, blocks, block, latent_dtype=jnp.float32,
     the tables; window (with ``cfg.window``): ``{"k", "v" [slots,
     ring_positions(block, chunk) / block, block, kv heads x head dim]}``,
     a RING owned by the slot (position p at ``p % ring``), for steps of
-    ``chunk`` lanes a row at most."""
+    ``chunk`` lanes a row at most; sparse: the attn pools and ``{"ik"
+    [blocks, block, pool_width(indexer head dim)]}``, the indexer's keys,
+    beside them (a 64-wide leaf would take a whole 128-lane tile of HBM
+    anyway, and a copy of it must be whole tiles: the lanes past the head
+    stay zero)."""
     h, dk = cfg.kda_heads, cfg.kda_head_dim
 
     def layer(kind):
@@ -454,10 +536,15 @@ def init_cache(cfg, slots, blocks, block, latent_dtype=jnp.float32,
                                         cfg.mamba_inner), jnp.float32),
                     "conv": jnp.zeros((slots, cfg.mamba_conv - 1,
                                        cfg.mamba_inner), jnp.float32)}
-        if kind in ("attn", "window"):
+        if kind in ("attn", "window", "sparse"):
             shape = (blocks, block, cfg.attn_kv_heads * cfg.attn_head_dim)
-            return {"k": jnp.zeros(shape, latent_dtype),
-                    "v": jnp.zeros(shape, latent_dtype)}
+            out = {"k": jnp.zeros(shape, latent_dtype),
+                   "v": jnp.zeros(shape, latent_dtype)}
+            if kind == "sparse":
+                out["ik"] = jnp.zeros(
+                    (blocks, block, mla.pool_width(cfg.index_dim)),
+                    latent_dtype)
+            return out
         return {"latent": jnp.zeros(
             (blocks, block, mla.pool_width(cfg.latent_width)), latent_dtype)}
 
@@ -468,6 +555,8 @@ _LEAF_KINDS = {"kda": {"state": SLOT_LEAF, "conv": SLOT_LEAF},
                "mamba": {"state": SLOT_LEAF, "conv": SLOT_LEAF},
                "attn": {"k": BLOCK_LEAF, "v": BLOCK_LEAF},
                "window": {"k": SLOT_LEAF, "v": SLOT_LEAF},
+               "sparse": {"k": BLOCK_LEAF, "v": BLOCK_LEAF,
+                          "ik": BLOCK_LEAF},
                "mla": {"latent": BLOCK_LEAF}}
 
 
@@ -642,15 +731,100 @@ def _ring_attention(q, k_ring, v_ring, qpos, kv_heads, head_dim, window):
     return linear.einsum("skvgr,srvd->skvgd", probs, rows(v_ring))
 
 
+def head_norm(x, gain, heads, head_dim, eps):
+    """x ``[N, heads x head_dim]`` RMS-normed a head, one ``[head_dim]``
+    gain for all heads."""
+    return kda.rms_norm(x.reshape(-1, heads, head_dim), gain, eps) \
+        .reshape(-1, heads * head_dim)
+
+
+def _layer_norm(x, gain, bias, eps):
+    x = x.astype(jnp.float32)
+    mean = jnp.mean(x, -1, keepdims=True)
+    var = jnp.mean((x - mean) ** 2, -1, keepdims=True)
+    return (x - mean) * jax.lax.rsqrt(var + eps) * gain + bias
+
+
+def sparse_chunk(p, h, k_pool, v_pool, ik_pool, qpos, tables, src, back, *,
+                 num_heads, kv_heads, head_dim, rope, index_heads, index_dim,
+                 index_rope, topk, eps):
+    """One sparse attention layer over the step's packed lanes (DeepSeek
+    Sparse Attention's lightning indexer before GQA softmax attention,
+    ops/dsa.py).  p: ``wqkv``, ``wo``, ``q_norm`` / ``k_norm`` (a head's
+    RMSNorm gains), ``wq_index`` [d, index_heads x index_dim],
+    ``wk_index`` [d, index_dim] with the LayerNorm ``k_index_norm`` /
+    ``k_index_bias``, ``w_index`` [d, index_heads]; h ``[N, d]`` the normed
+    input; the K, V and indexer-key pools ``[blocks, block, .]``; qpos,
+    tables, src, back as ``attn_chunk`` takes them -> (y ``[N, d]``, the
+    three pools, the positions each lane took as bits ``[S, K, W]`` int32,
+    ``ops/dsa.py``'s layout).
+
+    q and k are normed a head, then turned (``rope``); the indexer's query
+    ``h W_qI`` and key ``LayerNorm(h W_kI)`` turn by ``index_rope``; the
+    weights are ``h W_w / sqrt(index_heads x index_dim)``.  K, V and the
+    indexer's key are written BEFORE the reads, as ``attn_chunk`` writes
+    K and V; each lane then keeps the ``topk`` positions at or before its
+    own with the largest ``sum_j w_j ReLU(q_j . k_s)`` and attends those
+    alone, all its heads alike."""
+    s, kk = qpos.shape
+    block, dkv = k_pool.shape[1], kv_heads * head_dim
+    d_q = num_heads * head_dim
+    qkv = linear.matmul(h, p["wqkv"])
+    row, pos = src // kk, qpos.reshape(-1)[src]
+    blk = jnp.where(mla.own_places(src, back), tables[row, pos // block],
+                    k_pool.shape[0])
+    write = lambda pool, new: pool.at[blk, pos % block].set(
+        new.astype(pool.dtype), mode="drop")
+    k = rotate(head_norm(qkv[:, d_q:d_q + dkv], p["k_norm"], kv_heads,
+                         head_dim, eps), pos, kv_heads, head_dim, rope)
+    k_pool = write(k_pool, k)
+    v_pool = write(v_pool, qkv[:, d_q + dkv:])
+    q = rotate(head_norm(qkv[:, :d_q], p["q_norm"], num_heads, head_dim, eps),
+               pos, num_heads, head_dim, rope)
+    # the indexer's key and queries, widened with zeros to the key leaf's
+    # width (``init_cache``): the extra lanes add nothing to a product
+    wide = lambda x, heads: jnp.pad(
+        x.reshape(-1, heads, index_dim),
+        ((0, 0), (0, 0), (0, ik_pool.shape[2] - index_dim)))
+    ik = _layer_norm(linear.matmul(h, p["wk_index"]), p["k_index_norm"],
+                     p["k_index_bias"], eps)
+    ik_pool = write(ik_pool, wide(rotate(ik, pos, 1, index_dim, index_rope),
+                                  1)[:, 0])
+    qi = wide(rotate(linear.matmul(h, p["wq_index"]), pos, index_heads,
+                     index_dim, index_rope), index_heads)
+    w = linear.matmul(h, p["w_index"]) * (index_heads * index_dim) ** -0.5
+    from paddle_tpu.ops.pallas import dsa as dsa_kernels
+    kernel = dsa_kernels.decline_reason(
+        num_heads, d_q, dkv, block, tables.shape[1], kk, index_heads) is None
+    scores = dsa.index_scores(qi.astype(ik_pool.dtype)[back], w[back],
+                              ik_pool, qpos, tables, kernel)
+    picks, bits = dsa.select(scores, qpos, topk, kernel)
+    o = dsa.attend(q.astype(k_pool.dtype)[back], k_pool, v_pool, scores,
+                   picks, qpos, tables, num_heads, kernel)
+    o = o.reshape(s * kk, d_q)[src]
+    return linear.matmul(o, p["wo"]), k_pool, v_pool, ik_pool, bits
+
+
 def decode_chunk(params, cfg, tokens, positions, lengths, cache, tables,
                  with_routes=False, packing=None):
+    """``decode_chunk_report``'s logits and new cache, and with
+    ``with_routes`` its chosen experts."""
+    logits, cache, routes, _picks = decode_chunk_report(
+        params, cfg, tokens, positions, lengths, cache, tables, packing)
+    return (logits, cache, routes) if with_routes else (logits, cache)
+
+
+def decode_chunk_report(params, cfg, tokens, positions, lengths, cache,
+                        tables, packing=None):
     """``lm_decode_chunk_paged``'s lane semantics: tokens ``[S, K]``,
     positions ``[S]`` (lane 0's), lengths ``[S]`` in ``[1, K]``; row r
     advances ``lengths[r]`` positions.  -> (logits ``[S, V]`` at each
-    row's last fed lane, new cache), and with ``with_routes`` the chosen
-    experts ``[S, K, top_k]`` of every expert layer, in layer order (a lane
-    past its row's length repeats the row's last).  A row at position 0
-    starts from zero state; lengths and positions are data.
+    row's last fed lane, new cache, the chosen experts ``[S, K, top_k]`` of
+    every expert layer, in layer order (a lane past its row's length
+    repeats the row's last), and the positions each sparse layer's lanes
+    took, ``[S, K, W]`` bits (``sparse_chunk``; an empty list for a model
+    without sparse layers)).  A row at position 0 starts from zero state;
+    lengths and positions are data.
 
     The residual stream lives on a packed axis ``[N, D]`` of the step's
     live lanes: the embedding, the norms, every projection, the router and
@@ -673,7 +847,7 @@ def decode_chunk(params, cfg, tokens, positions, lengths, cache, tables,
     eps = cfg.rms_norm_eps
     x = params["emb"][jnp.asarray(tokens).reshape(-1)[src]] \
         .astype(jnp.float32)
-    new_cache, routes = [], []
+    new_cache, routes, picks = [], [], []
     for lp, c, (attn_kind, ffn_kind) in zip(params["layers"], cache,
                                             cfg.layers):
         h = kda.rms_norm(x, lp["norm1"], eps)
@@ -693,6 +867,15 @@ def decode_chunk(params, cfg, tokens, positions, lengths, cache, tables,
                 lp["attn"], h, c["k"], c["v"], qpos, tables, src, back,
                 **cfg.attention(attn_kind))
             new_cache.append({"k": k_pool, "v": v_pool})
+        elif attn_kind == "sparse":
+            y, k_pool, v_pool, ik_pool, chose = sparse_chunk(
+                lp["attn"], h, c["k"], c["v"], c["ik"], qpos, tables, src,
+                back, num_heads=cfg.attn_heads, kv_heads=cfg.attn_kv_heads,
+                head_dim=cfg.attn_head_dim, rope=cfg.attn_rope,
+                index_heads=cfg.index_heads, index_dim=cfg.index_dim,
+                index_rope=cfg.index_rope, topk=cfg.index_topk, eps=eps)
+            new_cache.append({"k": k_pool, "v": v_pool, "ik": ik_pool})
+            picks.append(chose)
         else:
             y, pool = mla.mla_chunk(
                 lp["attn"], h, c["latent"], qpos, tables, src, back,
@@ -712,10 +895,11 @@ def decode_chunk(params, cfg, tokens, positions, lengths, cache, tables,
                 if cfg.router == "softmax" else moe.sigmoid_router(
                     h, f["router"], f["router_bias"], cfg.top_k,
                     cfg.routed_scale)
-            sh = f["shared"]
             y = moe.routed_experts(h, idx, weights, f, cfg.held,
-                                   valid=valid) \
-                + moe.gated_ffn(h, sh["wg"], sh["wu"], sh["wd"])
+                                   valid=valid)
+            if cfg.shared_experts:
+                sh = f["shared"]
+                y = y + moe.gated_ffn(h, sh["wg"], sh["wu"], sh["wd"])
             routes.append(idx[back])
         x = x + (kda.rms_norm(y, lp["post_ffn"], eps) if cfg.post_norms
                  else y)
@@ -724,9 +908,7 @@ def decode_chunk(params, cfg, tokens, positions, lengths, cache, tables,
     # a tied head contracts the table's own columns: no transposed copy
     logits = linear.einsum("sd,vd->sv", last, params["emb"]) \
         if cfg.tie_embeddings else linear.matmul(last, params["head"])
-    if with_routes:
-        return logits, new_cache, routes
-    return logits, new_cache
+    return logits, new_cache, routes, picks
 
 
 # ------------------------------------------------------------ the engine
@@ -744,6 +926,9 @@ class Served:
         # positions a window layer attends, 0 where no layer has a ring
         self.window = cfg.window if any(
             kind == "window" for kind, _f in cfg.layers) else 0
+        # layers that select, and the positions a lane keeps in each
+        self.sparse_layers = sum(kind == "sparse" for kind, _f in cfg.layers)
+        self.sparse_topk = cfg.index_topk if self.sparse_layers else 0
 
     def init_cache(self, slots, blocks, block, chunk=1):
         """The cache for ``slots`` rows of steps of ``chunk`` lanes at
@@ -773,6 +958,24 @@ class Served:
         read = int((p + n - np.maximum(p - self.window + 1, 0)).sum())
         return attended, read
 
+    def sparse_counts(self, positions, lengths):
+        """What a step's lanes do in the sparse layers, all of them (numpy,
+        on the host; rows feed ``lengths`` lanes from ``positions``) ->
+        (positions the indexer scored: ``q + 1`` a lane at q; positions
+        selected: ``min(q + 1, topk)``; positions the rows read, the union
+        of a row's lanes' selections once a row: exact for a row that feeds
+        one lane, its bound ``min(p + n, n topk)`` for one that feeds n)."""
+        p = np.asarray(positions, np.int64)
+        n = np.asarray(lengths, np.int64)
+        lane = np.arange(int(np.max(n, initial=1)))[None, :]
+        fed = lane < n[:, None]
+        q = p[:, None] + lane
+        scored = int(np.where(fed, q + 1, 0).sum())
+        chosen = int(np.where(fed, np.minimum(q + 1, self.sparse_topk),
+                              0).sum())
+        read = int(np.minimum(p + n, n * self.sparse_topk).sum())
+        return tuple(self.sparse_layers * x for x in (scored, chosen, read))
+
     def cache_kinds(self):
         return cache_kinds(self.cfg)
 
@@ -797,29 +1000,36 @@ class Served:
                      tables, src, back):
         """-> (logits, new cache, what the step reports of itself: the
         chosen experts ``[expert layers, S, K, top_k]`` int32, which the
-        engine keeps on the device unread, ``DecodeEngine.step_aux``).
+        engine keeps on the device unread, ``DecodeEngine.step_aux``; a
+        model with sparse layers reports them and the positions each sparse
+        layer's lanes took, a tuple of ``[S, K, W]`` bits a sparse layer,
+        as a pair: a tuple, not a stack, so that the selection kernel's
+        outputs are the report and nothing copies 8 MB a layer).
         The step runs at the width of ``src``, static under ``jit``: a
         step that feeds a sixth of its lanes does not pay for all of
         them."""
-        logits, cache, routes = decode_chunk(
+        logits, cache, routes, picks = decode_chunk_report(
             params, self.cfg, tokens, positions, lengths, cache, tables,
-            with_routes=True, packing=(src, back))
+            packing=(src, back))
         aux = jnp.stack(routes) if routes else jnp.zeros((0,), jnp.int32)
-        return logits, cache, aux
+        return logits, cache, (aux, tuple(picks)) if picks else aux
 
     def kernel_report(self, kk, block, slots, entries=None):
         """{"kda_kernels", "kda_decline_reason", "mla_kernels",
         "mla_decline_reason", "mamba_kernels", "mamba_decline_reason",
         "attn_kernels", "attn_decline_reason", "window_kernels",
-        "window_decline_reason"} for a step of ``slots`` rows of ``kk``
-        lanes over blocks of ``block`` positions (``entries`` of them a
-        row's table), each from its kernel's own predicate (``attn``: the
-        paged decode-attention kernel under the ``"attn"`` layers;
-        ``window``: its windowed form over the rings, or the paged one
-        where the window layers have no window; ``mamba``: at every width
-        the step is compiled at); False and no reason for a kind of layer
-        the model does not have."""
+        "window_decline_reason", "sparse_kernels", "sparse_decline_reason"}
+        for a step of ``slots`` rows of ``kk`` lanes over blocks of
+        ``block`` positions (``entries`` of them a row's table), each from
+        its kernel's own predicate (``attn``: the paged decode-attention
+        kernel under the ``"attn"`` layers; ``window``: its windowed form
+        over the rings, or the paged one where the window layers have no
+        window; ``sparse``: the indexer, selection and attention kernels,
+        ops/pallas/dsa.py; ``mamba``: at every width the step is compiled
+        at); False and no reason for a kind of layer the model does not
+        have."""
         from paddle_tpu.ops.pallas import decode_attention
+        from paddle_tpu.ops.pallas import dsa as dsa_kernels
         from paddle_tpu.ops.pallas import kda as kda_kernel
         from paddle_tpu.ops.pallas import mamba as mamba_kernel
         from paddle_tpu.ops.pallas import mla as mla_kernel
@@ -850,7 +1060,12 @@ class Served:
                        cfg.window_heads, cfg.window_heads * cfg.attn_head_dim,
                        cfg.attn_kv_heads * cfg.attn_head_dim, block,
                        paged=True, chunk=kk))
-               if "window" in kinds else None}
+               if "window" in kinds else None,
+               "sparse": dsa_kernels.decline_reason(
+                   cfg.attn_heads, cfg.attn_heads * cfg.attn_head_dim,
+                   cfg.attn_kv_heads * cfg.attn_head_dim, block,
+                   entries or 1, kk, cfg.index_heads)
+               if "sparse" in kinds else None}
         return {**{k + "_kernels": k in kinds and why[k] is None
                    for k in why},
                 **{k + "_decline_reason": why[k] for k in why}}

@@ -474,13 +474,22 @@ class DecodeEngine:
         # and its windowed form under the window layers, over their rings
         self.window_kernels = False
         self.window_decline_reason = None
+        # and the indexer, selection and attention kernels of its sparse
+        # layers (ops/pallas/dsa.py)
+        self.sparse_kernels = False
+        self.sparse_decline_reason = None
         # positions a window layer attends (0: the model has none)
         self._window = getattr(model, "window", 0)
         self._window_attended = 0
+        # positions a sparse layer's lane keeps (0: the model has none),
+        # and those the prepared step's lanes select, all sparse layers
+        self._sparse = getattr(model, "sparse_topk", 0)
+        self._selected = 0
         # what a model's last step reported of itself (hybrid_lm: the
         # chosen experts), left on the device; None for the trunk
         self.step_aux = None
         self._step_log = None      # a list while record_steps() is on
+        self._step_keep = None
         # the picks of the step dispatched last, on the device: the next
         # step's argument (zeros before the first step and after a reset),
         # born and replaced together with the cache it belongs to
@@ -624,20 +633,24 @@ class DecodeEngine:
                 "kv_dtype with model=: the model chooses its pool's dtype "
                 "(hybrid_lm.Served(latent_dtype=...))")
 
-    def record_steps(self, on=True):
+    def record_steps(self, on=True, keep=None):
         """While on, ``step()`` keeps what each step fed and what the model
         reported of it (``recorded_steps()``): the host arrays it
-        snapshotted anyway and the unread device report.  For a check that
+        snapshotted anyway and the unread device report, or what ``keep(
+        tokens, positions, lengths, report)`` makes of them when the step
+        is read (a check that wants a few rows of a large report takes them
+        there, and the rest is freed with the step).  For a check that
         must know what the SERVER's step chose (the benchmark hands the
         routed experts of streamed tokens to its reference); off by
         default, and nothing is kept then."""
+        self._step_keep = keep
         self._step_log = [] if on else None
 
     def recorded_steps(self):
         """[(tokens [S, K], positions [S], lengths [S], report)] of the
         steps since ``record_steps()``, oldest first; with
         ``report_logits`` the report is (the model's, logits [S, V]), on
-        the device."""
+        the device (or what ``keep`` made of it)."""
         return list(self._step_log or ())
 
     def slot_state(self, slot):
@@ -1351,6 +1364,11 @@ class DecodeEngine:
             self._window_attended, read = self._model.window_counts(p, n)
             self.metrics.observe_window_positions(
                 self._window_attended, read, int((p + n).sum()))
+        if self._sparse:
+            # scored, selected and read in all the sparse layers
+            scored, self._selected, read = self._model.sparse_counts(p, n)
+            self.metrics.observe_sparse_positions(
+                scored, self._selected, read, int((p + n).sum()))
         return victims
 
     def evict(self, slot, reason):
@@ -1458,6 +1476,9 @@ class DecodeEngine:
             if self._window:
                 ph.set(window_attended=self._window_attended)
                 self._window_attended = 0
+            if self._sparse:
+                ph.set(selected=self._selected)
+                self._selected = 0
             self.metrics.observe_step_lanes(width, live, prefill_rows,
                                             one_lane_rows)
             # the fault point sits at the device-step boundary: a hang
@@ -1516,7 +1537,9 @@ class DecodeEngine:
             waited = tokens[:, 0] < 0
             if waited.any():
                 tokens[waited, 0] = np.asarray(handle.prev)[waited]
-            log.append((tokens, handle.pos, lens, handle.aux))
+            keep = self._step_keep
+            log.append((tokens, handle.pos, lens, handle.aux if keep is None
+                        else keep(tokens, handle.pos, lens, handle.aux)))
         # teacher-forced lanes this step fed beyond the per-slot token
         # (the chunked-prefill occupancy surface)
         chunk_lanes = int(lens.sum() - self.num_slots)
@@ -1696,7 +1719,8 @@ class DecodeEngine:
                     ("mla", "XLA gather and [S, K, H, T] scores"),
                     ("mamba", "XLA scan (every lane rewrites every state)"),
                     ("attn", "XLA gather and [S, K, H, T] scores"),
-                    ("window", "XLA [S, K, H, ring] scores")):
+                    ("window", "XLA [S, K, H, ring] scores"),
+                    ("sparse", "XLA top-k and [S, K, H, T] scores")):
                 if report[kernel + "_decline_reason"]:
                     logger.warning(
                         "decode[%s]: %s kernel declined -> %s: %s",
@@ -1708,6 +1732,8 @@ class DecodeEngine:
             if self._window:
                 self.metrics.set_window(
                     self._model.ring_bytes(self._cache), self.window_kernels)
+            if self._sparse:
+                self.metrics.set_sparse(self.sparse_kernels)
         self.metrics.set_prefill_chunk(self.prefill_chunk)
         self.metrics.set_kv_dtype(self.kv_dtype)
         self.metrics.set_speculate_k(self.speculate_k)

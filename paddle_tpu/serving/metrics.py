@@ -120,10 +120,19 @@ class ServingMetrics:
         # the same in ONE window layer of a model that has them: a lane at
         # position q attends min(q + 1, window) (0 for every other model);
         # and what such a model's rows READ, each position once a row
-        # whatever its lanes: in a window layer, and in a full one
+        # whatever its lanes: in a window layer, and in a full one (or, in
+        # a model with sparse layers, what the indexer reads in one)
         self.window_attended_positions_total = 0
         self.window_read_positions_total = 0
         self.read_positions_total = 0
+        # a model with sparse layers, over all of them: positions its
+        # indexer scored (q + 1 a lane at q), positions its lanes selected
+        # (min(q + 1, topk)), and positions its rows read, the union of a
+        # row's lanes' selections once a row (its bound where a row feeds
+        # more than one lane; serving/decode_engine prepare_step)
+        self.sparse_scored_positions_total = 0
+        self.sparse_selected_positions_total = 0
+        self.sparse_read_positions_total = 0
         # lanes the steps computed (the trunk: S x K; a model: the packed
         # width the step ran at) and lanes rows fed into them, a free
         # slot's one armed lane included; counted at the hand-over
@@ -144,6 +153,8 @@ class ServingMetrics:
         # leaves too)
         self.window_kernels = 0
         self.window_ring_bytes = 0
+        # and its sparse layers' indexer, selection and attention kernels
+        self.sparse_kernels = 0
         self.prefill_chunk_size = 0      # gauge: engine K (0 = ladder)
         self.evictions = {r: 0 for r in EVICT_REASONS}
         # ---- speculative decoding (serving/speculative.py): draft
@@ -287,6 +298,17 @@ class ServingMetrics:
             self.window_read_positions_total += int(window_read)
             self.read_positions_total += int(read)
 
+    def observe_sparse_positions(self, scored, selected, read, held):
+        """What the lanes of the step being prepared score, select and
+        read in the sparse layers, all of them, and the positions its rows
+        hold (``read_positions_total``: the indexer reads each once a row
+        and layer)."""
+        with self._lock:
+            self.sparse_scored_positions_total += int(scored)
+            self.sparse_selected_positions_total += int(selected)
+            self.sparse_read_positions_total += int(read)
+            self.read_positions_total += int(held)
+
     def observe_step_lanes(self, computed, live, prefill_rows=0,
                            one_lane_rows=0):
         """The width of the step being handed over, the lanes fed, the
@@ -319,6 +341,12 @@ class ServingMetrics:
         with self._lock:
             self.window_ring_bytes = int(ring_bytes)
             self.window_kernels = int(kernels)
+
+    def set_sparse(self, kernels):
+        """Fact of a model with sparse layers: whether its step took the
+        indexer, selection and sparse attention kernels."""
+        with self._lock:
+            self.sparse_kernels = int(kernels)
 
     def set_prefill_chunk(self, k):
         """Gauge: the engine's chunk size K (0 = legacy ladder mode)."""
@@ -555,6 +583,12 @@ class ServingMetrics:
                 "window_read_positions_total":
                     self.window_read_positions_total,
                 "read_positions_total": self.read_positions_total,
+                "sparse_scored_positions_total":
+                    self.sparse_scored_positions_total,
+                "sparse_selected_positions_total":
+                    self.sparse_selected_positions_total,
+                "sparse_read_positions_total":
+                    self.sparse_read_positions_total,
                 "step_lanes_computed_total": self.step_lanes_computed_total,
                 "step_lanes_live_total": self.step_lanes_live_total,
                 "one_lane_row_steps_total": self.one_lane_row_steps_total,
@@ -563,6 +597,7 @@ class ServingMetrics:
                 "mamba_kernels": self.mamba_kernels,
                 "window_kernels": self.window_kernels,
                 "window_ring_bytes": self.window_ring_bytes,
+                "sparse_kernels": self.sparse_kernels,
                 "prefill_chunk_size": self.prefill_chunk_size,
                 "speculate_k": self.speculate_k,
                 "mesh_shards": self.mesh_shards,
@@ -753,7 +788,21 @@ class ServingMetrics:
                  "once a row (models with window layers)"),
                 ("read_positions_total", self.read_positions_total,
                  "positions the seated rows read in one full attention "
-                 "layer, each once a row (models with window layers)"),
+                 "layer, each once a row (models with window or sparse "
+                 "layers)"),
+                ("sparse_scored_positions_total",
+                 self.sparse_scored_positions_total,
+                 "positions a sparse model's indexer scored, over its "
+                 "lanes and sparse layers (position + 1 a lane)"),
+                ("sparse_selected_positions_total",
+                 self.sparse_selected_positions_total,
+                 "positions a sparse model's lanes attended, over its "
+                 "sparse layers (min(position + 1, topk) a lane)"),
+                ("sparse_read_positions_total",
+                 self.sparse_read_positions_total,
+                 "positions a sparse model's rows selected, the union of "
+                 "a row's lanes once a row and sparse layer (its bound "
+                 "for a row fed more than one lane)"),
                 ("step_lanes_computed_total",
                  self.step_lanes_computed_total,
                  "lanes the decode steps computed (a model's steps: the "
@@ -798,6 +847,7 @@ class ServingMetrics:
             mamba_kernels = self.mamba_kernels
             window_kernels = self.window_kernels
             ring_bytes = self.window_ring_bytes
+            sparse_kernels = self.sparse_kernels
         for metric, value, help_ in gen_counters:
             emit(metric, value, help_, mtype="counter")
         emit("prefill_chunk_size", chunk_size,
@@ -846,6 +896,9 @@ class ServingMetrics:
         emit("window_ring_bytes", ring_bytes,
              "bytes of a served model's window-layer rings, window + chunk "
              "positions a slot (0 = no window layer)")
+        emit("sparse_kernels", sparse_kernels,
+             "1 when a served model's step took the indexer, selection and "
+             "sparse attention kernels over its sparse layers")
         emit("kv_cache_int8", int(kv_int8),
              "1 when the KV cache stores int8 + per-head scale sidecars "
              "(quantized serving; docs/serving.md)")

@@ -493,7 +493,7 @@ def test_kernel_report_names_every_kind(hf):
     model = hybrid_lm.Served(hybrid_lm.config_from_hf(hf))
     report = model.kernel_report(8, BLOCK, 4)
     assert set(report) == {
-        k + suffix for k in ("kda", "mla", "mamba", "attn", "window")
+        k + suffix for k in ("kda", "mla", "mamba", "attn", "window", "sparse")
         for suffix in ("_kernels", "_decline_reason")}
     assert report["window_decline_reason"] is None \
         and not report["window_kernels"]
