@@ -418,15 +418,17 @@ def test_sparse_counts_follow_the_lanes(hf):
 
 # ------------------------------------------------ the other families
 
+# Laguna's, Kimi's and Pangu's steps lay each expert layer's groups out on
+# whole ``moe.ROW_ALIGN`` rows; Jamba's has no expert layer
 PINNED = {
     "jamba2-3b":
         "3d5d788893aa74374f0d1fb1ec89a821c00959951682f1437b0cc912c205638b",
     "laguna-s-2.1-ep8-8l":
-        "be164ace3134b0f540cac76c06843fe83580f9cde24ec9dcc389a9f5cc56009a",
+        "5a574a7fd91f1aa3293e5c0ccf4753faa9717ac4097abfd83f420aad3cadf32b",
     "kimi-linear-48b-ep4-8l":
-        "a395317472c6dbdcec13467c356c3ea94add28a6b1d83534e83c4d3e32423ec4",
+        "8d9fa10b27c27167d069fe6c2e9c2ab6204a28ef3168039e5b418f3fa5b90d39",
     "openpangu-718b-ep16-5l":
-        "1fffadef5e69ada0e57b7599d54583438dc185efdf43ab04d9923d0a90f25356",
+        "7b724408071694faa517af2fe411a66b1dfb6b5814a8bd190885f46cfbebd609",
 }
 
 
