@@ -20,7 +20,8 @@ from paddle_tpu.layers.graph import (
     LayerOutput, register_layer, auto_name, map_rows, as_seq, value_data)
 from paddle_tpu.ops import (activations, linear, losses, math_ops, embedding as
                             emb_ops, sequence as seq_ops, crf as crf_ops,
-                            ctc as ctc_ops, sampling as sampling_ops)
+                            ctc as ctc_ops, sampling as sampling_ops,
+                            rnn as rnn_ops)
 from paddle_tpu.utils.error import ConfigError
 
 __all__ = [
@@ -864,6 +865,10 @@ class _SeqPoolImpl:
 
     def apply(self, ctx, cfg, params, x):
         from paddle_tpu.core.sequence import NestedSequenceBatch
+        if isinstance(x, rnn_ops.LstmOutput):
+            # a plain last_seq of a fused LSTM (graph.Topology hands it
+            # over): the row at lengths - 1 is the final carry
+            return x.final.h
         if isinstance(x, NestedSequenceBatch):
             # reference sequence levels over sub-sequenced input:
             # TO_SEQUENCE pools within each sub-sequence (-> sequence of

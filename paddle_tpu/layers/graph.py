@@ -209,6 +209,7 @@ class Topology:
         self.outputs = list(outputs)
         self.order = self._topo_sort(self.outputs)
         self._lstm_projections = self._find_lstm_projections()
+        self._lstm_handoffs = self._find_lstm_handoffs()
         self.data_layers = {n.name: n for n in self.order
                             if n.layer_type == "data"}
         for feed in extra_feeds:
@@ -256,6 +257,45 @@ class Topology:
                     and readers[id(fc)] == 1 and id(fc) not in outputs):
                 pairs[id(node)] = fc
         return pairs
+
+    def _find_lstm_handoffs(self):
+        """{id(lstmemory node): the fcs among its readers} for every node
+        whose readers all take its value in the fused kernel's own form
+        (``ops.rnn.LstmOutput``): each reader is the fc of a projection
+        pair (its lstmemory's kernels read the time-major stream as their
+        input) or a ``last_seq`` at its default stride and level (it reads
+        the final carry), and the node is no output, runs forward, carries
+        no state into the next batch and has no dropout or error clipping
+        of its own.  ``apply`` hands such a node over unless the call wants
+        its value or applies one of those fcs as a layer; ``ops.rnn.lstm``
+        does only where the fused kernel runs."""
+        readers = collections.defaultdict(list)
+        for node in self.order:
+            for i in node.inputs:
+                readers[id(i)].append(node)
+        outputs = {id(o) for o in self.outputs}
+        pair_fcs = {id(fc) for fc in self._lstm_projections.values()}
+
+        def takes_kernel_form(reader):
+            cfg = reader.cfg
+            return id(reader) in pair_fcs or (
+                reader.layer_type == "seq_pool"
+                and cfg.get("pooling") == "last"
+                and (cfg.get("stride") or -1) <= 0
+                and cfg.get("agg_level") is None)
+
+        handoffs = {}
+        for node in self.order:
+            cfg = node.cfg
+            if (node.layer_type == "lstmemory" and id(node) not in outputs
+                    and readers[id(node)]
+                    and not any(cfg.get(k) for k in (
+                        "reverse", "prev_batch_state", "drop_rate",
+                        "error_clipping_threshold"))
+                    and all(map(takes_kernel_form, readers[id(node)]))):
+                handoffs[id(node)] = [r for r in readers[id(node)]
+                                      if id(r) in pair_fcs]
+        return handoffs
 
     def init(self, rng):
         """Initialize all parameters: {layer_name: {param_name: array}}.
@@ -312,6 +352,11 @@ class Topology:
             and not (precomputed and fc.name in precomputed)
             and "b" not in params.get(self._param_key(fc), {})}
         unapplied = {id(fc) for fc in projections.values()}
+        handoffs = {
+            lstm: "stream" if fcs else "final"
+            for lstm, fcs in self._lstm_handoffs.items()
+            if lstm not in wanted
+            and all(id(fc) in unapplied for fc in fcs)}
         for node in self.order:
             if id(node) in unapplied:
                 continue
@@ -336,6 +381,8 @@ class Topology:
             try:
                 kwargs = {} if fc is None else {
                     "proj": params[self._param_key(fc)]["w0"]}
+                if id(node) in handoffs:
+                    kwargs["hand_over"] = handoffs[id(node)]
                 val = impl.apply(ctx, node.cfg, p, *ins, **kwargs)
                 # reference ExtraLayerAttribute(drop_rate=...) applies to any
                 # layer's output; fc/mixed/dropout handle it inside their

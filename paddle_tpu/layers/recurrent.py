@@ -84,10 +84,12 @@ class _LstmImpl:
             p["b"] = jnp.zeros((7 * d,), dtypes.param_dtype())
         return p
 
-    def apply(self, ctx, cfg, params, x, proj=None):
+    def apply(self, ctx, cfg, params, x, proj=None, hand_over=None):
         """``proj``: ``x`` is the input of the bias-free fc that feeds this
         layer and ``proj`` its weight (graph.Topology hands the pair over
-        unapplied): the projection is the LSTM's own to compute."""
+        unapplied): the projection is the LSTM's own to compute, and ``x``
+        may be a fused LSTM's ``LstmOutput``.  ``hand_over``: every reader
+        takes the fused kernel's own form (``ops.rnn.lstm(hand_over=)``)."""
         d = cfg["size"]
         b = params.get("b")
         bias = b[:4 * d] if b is not None else None
@@ -97,9 +99,14 @@ class _LstmImpl:
         init = _prev_batch_init(ctx, cfg)
         if init is not None:
             init = rnn_ops.LstmState(h=init[..., :d], c=init[..., d:])
-        out, final = rnn_ops.lstm(as_seq(x), params["w"], bias=bias,
+        if _prev_batch_carry(ctx, cfg):
+            hand_over = None
+        if not isinstance(x, rnn_ops.LstmOutput):
+            x = as_seq(x)
+        out, final = rnn_ops.lstm(x, params["w"], bias=bias,
                                   check_i=ci, check_f=cf, check_o=co,
                                   init_state=init, proj=proj,
+                                  hand_over=hand_over,
                                   reverse=cfg.get("reverse", False),
                                   act=cfg.get("act", "tanh"),
                                   gate_act=cfg.get("gate_act", "sigmoid"),

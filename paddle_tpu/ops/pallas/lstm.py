@@ -41,6 +41,12 @@ Backward is a second time-reversed kernel (BPTT): recomputes nothing,
 reads the forward-saved activations, accumulates dW_r (and dW_x, db) in
 VMEM f32 accumulators and streams the input's gradient out.  Per-batch
 peephole partials are reduced outside the kernel.
+
+A second entry (``lstm_fused(..., final_only=True)``) has the final carry
+(h, c) as its only outputs, for a reader that takes the last valid step
+alone: the residuals are the same, and its backward takes no [T, B, D]
+cotangent: the final h's enters the chain at the last step as the final
+c's does, and masked steps carry both to each row's last valid step.
 """
 
 import functools
@@ -112,11 +118,13 @@ def _fwd_kernel(x_ref, wx_ref, b_ref, wr_ref, chk_ref, mask_ref,
 
 
 def _bwd_kernel(acts_ref, cs_ref, csp_ref, hsp_ref, x_ref, wx_ref, wr_ref,
-                chk_ref, mask_ref, dh_out_ref, dcfin_ref,
+                chk_ref, mask_ref, dh_out_ref, dhfin_ref, dcfin_ref,
                 dx_ref, dwx_ref, db_ref, dwr_ref, dchk_ref,
                 dh_scr, dc_scr, dwr_scr, dchk_scr, dwx_scr, db_scr, *, d, nt):
     """x_ref/wx_ref, dwx_ref/db_ref and their scratch are None where the
-    forward was handed gate inputs: dx_ref then takes ``dgates`` itself."""
+    forward was handed gate inputs: dx_ref then takes ``dgates`` itself.
+    One of dh_out_ref (every step's h an output) and dhfin_ref (the final
+    carry's h alone) is None."""
     ib = pl.program_id(0)         # batch tile
     j = pl.program_id(1)          # reversed: actual time t = nt - 1 - j
     t = nt - 1 - j
@@ -124,9 +132,11 @@ def _bwd_kernel(acts_ref, cs_ref, csp_ref, hsp_ref, x_ref, wx_ref, wr_ref,
 
     @pl.when(j == 0)
     def _():
-        dh_scr[:] = jnp.zeros_like(dh_scr)
-        # final-cell cotangent enters the chain at the last (first-reversed)
-        # step, exactly where the scan's carry cotangent starts
+        # the final carry's cotangents enter the chain at the last (first-
+        # reversed) step, exactly where the scan's carry cotangent starts;
+        # masked steps then carry them to each row's last valid step
+        dh_scr[:] = (jnp.zeros_like(dh_scr) if dhfin_ref is None
+                     else dhfin_ref[0].astype(jnp.float32))
         dc_scr[:] = dcfin_ref[0].astype(jnp.float32)
         dchk_scr[:] = jnp.zeros_like(dchk_scr)
 
@@ -150,7 +160,9 @@ def _bwd_kernel(acts_ref, cs_ref, csp_ref, hsp_ref, x_ref, wx_ref, wr_ref,
     ci, cf, co = chk_ref[0:1], chk_ref[1:2], chk_ref[2:3]
     m = _lanes(mask_ref[0], d)
 
-    dh = dh_scr[:] + dh_out_ref[0].astype(jnp.float32)
+    dh = dh_scr[:]
+    if dh_out_ref is not None:
+        dh = dh + dh_out_ref[0].astype(jnp.float32)
     dc_merged = dc_scr[:]
     tc = jnp.tanh(c_t)
     do_ = dh * tc
@@ -184,14 +196,14 @@ def _bwd_kernel(acts_ref, cs_ref, csp_ref, hsp_ref, x_ref, wx_ref, wr_ref,
     if projected:
         # the projection's gradients as autodiff of linear.fc forms them:
         # operands in the compute dtype W_x arrives in, float32 sums, each
-        # product leaving in that dtype, for it is the cotangent of an
-        # operand cast to it (the conversion back is outside, where
-        # autodiff puts the cast's transpose)
+        # product rounded to that dtype, for it is the cotangent of an
+        # operand cast to it; dx then leaves in the input's own dtype (the
+        # cast's transpose), so the layer below reads it as it is
         cd = wx_ref.dtype
         dg = dgates.astype(cd)
-        dx_ref[0] = jax.lax.dot_general(
+        dx_ref[0] = _rounded_as(jax.lax.dot_general(
             dg, wx_ref[:], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(cd)
+            preferred_element_type=jnp.float32), cd).astype(dx_ref.dtype)
         dwx_scr[:] = dwx_scr[:] + jax.lax.dot_general(
             x_ref[0].astype(cd), dg, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -209,6 +221,24 @@ def _bwd_kernel(acts_ref, cs_ref, csp_ref, hsp_ref, x_ref, wx_ref, wr_ref,
         if projected:
             dwx_ref[:] = dwx_scr[:].astype(dwx_ref.dtype)
             db_ref[:] = db_scr[:]
+
+
+def _rounded_as(v, dtype):
+    """float32 ``v`` rounded to nearest-even at ``dtype``'s precision and
+    kept in float32: ``v.astype(dtype).astype(float32)``, which XLA may
+    drop on the chip, where ``lax.reduce_precision`` (which it keeps) has
+    no Mosaic lowering.  bfloat16 is float32's upper half: round the lower
+    16 bits away in the integers.  A NaN is kept as it is: the carry into
+    its upper half would make some payloads infinite or zero."""
+    if jnp.dtype(dtype) == jnp.float32:
+        return v
+    if jnp.dtype(dtype) != jnp.bfloat16:
+        return v.astype(dtype).astype(jnp.float32)
+    bits = jax.lax.bitcast_convert_type(v, jnp.uint32)
+    bits = bits + (jnp.uint32(0x7FFF) + ((bits >> 16) & jnp.uint32(1)))
+    rounded = jax.lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000),
+                                           jnp.float32)
+    return jnp.where(v != v, v, rounded)
 
 
 def _compiler_params(bt, d, d_in):
@@ -299,10 +329,12 @@ def _fwd(x, w_x, bias, w_r, checks, mask, interpret, bt, save_residuals):
     return hs, cfin, None, None
 
 
-def _bwd(interpret, bt, res, g_out):
+def _bwd(interpret, bt, res, dh_out, dhfin, dcfin):
+    """``dh_out`` [T, B, D]: the cotangent of every step's h, or None where
+    the final carry alone was an output: then ``dhfin`` [1, B, D], its h's
+    cotangent, enters the chain at the last step as ``dcfin`` does."""
     x, w_x, bias, w_r, checks, mask, hs, cs, acts = res
-    dh_out, dcfin = g_out
-    nt, b, d = dh_out.shape
+    nt, b, d = hs.shape
     g = 4 * d
     projected = w_x is not None
 
@@ -331,7 +363,7 @@ def _bwd(interpret, bt, res, g_out):
         out_specs = [pl.BlockSpec((1, bt, d_in), now),     # dx
                      pl.BlockSpec((d_in, g), const),       # dW_x
                      pl.BlockSpec((1, g), const)]          # db
-        out_shape = [jax.ShapeDtypeStruct((nt, b, d_in), cd),
+        out_shape = [jax.ShapeDtypeStruct((nt, b, d_in), x.dtype),
                      jax.ShapeDtypeStruct((d_in, g), cd),
                      jax.ShapeDtypeStruct((1, g), jnp.float32)]
         own_scratch = [pltpu.VMEM((d_in, g), jnp.float32),
@@ -342,14 +374,21 @@ def _bwd(interpret, bt, res, g_out):
         out_specs = [pl.BlockSpec((1, bt, g), now)]        # dgates
         out_shape = [jax.ShapeDtypeStruct((nt, b, g), hs.dtype)]
         own_scratch = []
+    streamed = dh_out is not None
+
+    def final(ib, j):
+        return (0, ib, 0)
+
     in_specs += [
         pl.BlockSpec((d, g), const),
         pl.BlockSpec((3, d), const),
         pl.BlockSpec((1, bt, _LANES), now),                # mask
-        pl.BlockSpec((1, bt, d), now),                     # dh_out
-        pl.BlockSpec((1, bt, d), lambda ib, j: (0, ib, 0)),    # dcfin
+        pl.BlockSpec((1, bt, d), now if streamed else final),  # dh_out | dhfin
+        pl.BlockSpec((1, bt, d), final),                   # dcfin
     ]
-    operands += [w_r, checks, mask, dh_out, dcfin.astype(jnp.float32)]
+    operands += [w_r, checks, mask,
+                 dh_out if streamed else dhfin.astype(jnp.float32),
+                 dcfin.astype(jnp.float32)]
     out_specs += [
         pl.BlockSpec((d, g), const),                       # dW_r
         pl.BlockSpec((bt, 3 * d), lambda ib, j: (ib, 0)),  # dchk rows
@@ -371,7 +410,8 @@ def _bwd(interpret, bt, res, g_out):
         def take(n, present=True):
             return [refs.pop(0) for _ in range(n)] if present else [None] * n
 
-        _bwd_kernel(*take(4), *take(2, projected), *take(5),        # inputs
+        _bwd_kernel(*take(4), *take(2, projected), *take(3),        # inputs
+                    *take(1, streamed), *take(1, not streamed), *take(1),
                     *take(1), *take(2, projected), *take(2),        # outputs
                     *take(4), *take(2, projected), d=d, nt=nt)      # scratch
 
@@ -388,7 +428,7 @@ def _bwd(interpret, bt, res, g_out):
     )(*operands)
     dx, dwx, db = outs[0], None, None
     if projected:
-        dx, dwx = dx.astype(x.dtype), outs[1].astype(w_x.dtype)
+        dwx = outs[1].astype(w_x.dtype)
         db = outs[2][0].astype(bias.dtype)
     dwr, dchk = outs[-2:]
     dchecks = dchk.sum(axis=0).reshape(3, d).astype(checks.dtype)
@@ -410,7 +450,28 @@ def _fused_fwd_rule(x, w_x, bias, w_r, checks, mask, interpret, bt):
     return (hs, cfin), (x_res, w_x, bias, w_r, checks, mask, hs, cs, acts)
 
 
-_fused.defvjp(_fused_fwd_rule, _bwd)
+_fused.defvjp(_fused_fwd_rule, lambda interpret, bt, res, g: _bwd(
+    interpret, bt, res, g[0], None, g[1]))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _fused_final(x, w_x, bias, w_r, checks, mask, interpret, bt):
+    """``_fused`` whose outputs are the final carry (h, c) alone: the
+    residuals are the same, and the backward takes no [T, B, D]
+    cotangent."""
+    hs, cfin, _, _ = _fwd(x, w_x, bias, w_r, checks, mask, interpret, bt,
+                          save_residuals=False)
+    return hs[-1], cfin[0]
+
+
+def _fused_final_fwd_rule(x, w_x, bias, w_r, checks, mask, interpret, bt):
+    (hs, cfin), res = _fused_fwd_rule(x, w_x, bias, w_r, checks, mask,
+                                      interpret, bt)
+    return (hs[-1], cfin[0]), res
+
+
+_fused_final.defvjp(_fused_final_fwd_rule, lambda interpret, bt, res, g: _bwd(
+    interpret, bt, res, None, g[0][None], g[1][None]))
 
 
 def vmem_bytes(bt, d):
@@ -447,13 +508,14 @@ def bwd_vmem_bytes(bt, d, d_in):
     """The projected BACKWARD's footprint: ``vmem_bytes``'s without the
     two buffers of the [bt, 4D] ``dxs`` block, with W_x resident, the dW_x
     accumulator (f32) and its output block (compute dtype), the bias's
-    sums, and per row two buffers each of x_t (f32) and dx_t (compute
-    dtype) and the compute-dtype copies of dgates and x_t."""
+    sums, and per row two buffers each of x_t and dx_t (f32, dx leaving
+    in its input's dtype) and the compute-dtype copies of dgates and
+    x_t."""
     cd = jnp.dtype(dtypes.compute_dtype()).itemsize
     resident = (4 * (12 * d * d + 6 * d + d_in * 4 * d + 2 * 8 * 4 * d)
                 + 2 * cd * d_in * 4 * d)
-    per_row = (4 * (5 * d + 2 * (12 * d + d_in + _LANES))
-               + cd * (4 * d + 3 * d_in))
+    per_row = (4 * (5 * d + 2 * (12 * d + 2 * d_in + _LANES))
+               + cd * (4 * d + d_in))
     return resident + bt * per_row
 
 
@@ -480,7 +542,7 @@ def batch_tile(b, d, d_in=None):
 
 def supported(b, d, act, gate_act, state_act, init_state, d_in=None):
     """Kernel path preconditions; callers fall back to the scan otherwise.
-    reverse is handled by the caller's time-flip (see rnn._fused_seq_apply).
+    reverse is handled by the caller's time-flip (see rnn._fused_dispatch).
     The VMEM guard keeps weights that cannot be resident off the kernel
     path (d=1280: w_r, its gradient's accumulator and output block are
     79 MB f32 — over a 16 MiB core, inside a v5e's 128 MiB); a batch too
@@ -494,14 +556,17 @@ def supported(b, d, act, gate_act, state_act, init_state, d_in=None):
 
 
 def lstm_fused(xs_tm, mask_tm, w_r, check_i, check_f, check_o,
-               proj=None, bias=None, interpret=None):
+               proj=None, bias=None, interpret=None, final_only=False):
     """Whole-sequence fused LSTM.
 
     xs_tm: [T, B, 4D] time-major pre-projected gate inputs (bias included);
     or, with ``proj`` [in, 4D], the layer's input [T, B, in] itself, which
     the forward kernel projects and adds ``bias`` [4D] (or nothing) to, and
-    whose gradients the backward kernel forms.
-    mask_tm: [T, B] float 0/1.  Returns (hs_tm [T, B, D], final (h, c)).
+    whose gradients the backward kernel forms (dx in xs_tm's dtype).
+    mask_tm: [T, B] float 0/1.  Returns (hs_tm [T, B, D], final (h, c));
+    with ``final_only``, (None, final (h, c)): the final carry is the only
+    output, so no [T, B, D] cotangent exists and the backward starts from
+    the carry's.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -519,5 +584,9 @@ def lstm_fused(xs_tm, mask_tm, w_r, check_i, check_f, check_o,
         for v in (check_i, check_f, check_o)])
     mask_r = jnp.broadcast_to(
         mask_tm.astype(jnp.float32)[:, :, None], (nt, b, _LANES))
-    hs, cfin = _fused(xs_tm, proj, bias, w_r, checks, mask_r, interpret, bt)
+    args = (xs_tm, proj, bias, w_r, checks, mask_r, interpret, bt)
+    if final_only:
+        h, c = _fused_final(*args)
+        return None, (h, c.astype(h.dtype))
+    hs, cfin = _fused(*args)
     return hs, (hs[-1], cfin[0].astype(hs.dtype))

@@ -18,7 +18,8 @@ semantics exactly.
 """
 
 import contextlib
-from typing import NamedTuple
+import functools
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +32,28 @@ from paddle_tpu.ops.linear import matmul
 class LstmState(NamedTuple):
     h: jnp.ndarray  # [B, D] hidden (output)
     c: jnp.ndarray  # [B, D] cell state
+
+
+def _batch_major(hs_tm, lengths) -> SequenceBatch:
+    """A time-major [T, B, D] output as the masked batch-major sequence
+    every reader of a recurrent layer takes by default."""
+    sb = SequenceBatch(data=hs_tm.transpose(1, 0, 2), lengths=lengths)
+    return sb.with_data(sb.data * sb.mask(hs_tm.dtype)[..., None])
+
+
+class LstmOutput(NamedTuple):
+    """A fused LSTM's output in the kernel's own form, for readers that
+    need no masked batch-major sequence (``graph.Topology`` decides which):
+    ``hs`` the time-major [T, B, D] stream as the kernel wrote it, a masked
+    step holding the carry frozen there, or None where only ``final`` was
+    asked for; ``final`` the carry after each row's last valid step, which
+    is the row at ``lengths - 1`` (0 for a row of length 0)."""
+    hs: Optional[jnp.ndarray]
+    final: LstmState
+    lengths: jnp.ndarray
+
+    def sequence(self) -> SequenceBatch:
+        return _batch_major(self.hs, self.lengths)
 
 
 def lstm_cell(x4, state: LstmState, w_r, check_i=None, check_f=None,
@@ -134,6 +157,11 @@ FUSED_DISPATCH_COUNT = 0
 #: counted at trace time like its neighbour.
 PROJECTED_DISPATCH_COUNT = 0
 
+#: the LSTM outputs handed to their readers in the fused kernel's own form
+#: (``LstmOutput``) instead of a masked batch-major sequence; counted at
+#: trace time like its neighbours.
+HANDED_OVER_COUNT = 0
+
 
 # The mesh axis a multi-device jit shards the batch over, as (mesh, axis) —
 # set by the trainer around its step's trace (``batch_sharded_over``).
@@ -170,11 +198,11 @@ def _local_batch(b):
     return b // n if b % n == 0 else 0
 
 
-def _fused_seq_apply(seq, xs, ms, reverse, kernel_fn, weights):
+def _fused_dispatch(xs, ms, reverse, kernel_fn, weights):
     """Shared fused-kernel dispatch: reverse = forward kernel over
     time-flipped arrays, flipped back (valid because sequences are
     left-aligned; masked steps freeze the carry identically either way).
-    Returns (SequenceBatch, final-state) from
+    Returns (time-major hs or None, final-state) from
     ``kernel_fn(xs_tm, ms_tm, weights)``; ``weights`` (a tuple, None
     entries allowed) is passed explicitly so that under
     ``batch_sharded_over`` it enters the shard_map as a replicated operand
@@ -193,8 +221,14 @@ def _fused_seq_apply(seq, xs, ms, reverse, kernel_fn, weights):
     hs_tm, final = kernel_fn(xs_k, ms_k, weights)
     if reverse:
         hs_tm = jnp.flip(hs_tm, 0)
-    out = hs_tm.transpose(1, 0, 2) * seq.mask(hs_tm.dtype)[..., None]
-    return SequenceBatch(data=out, lengths=seq.lengths), final
+    return hs_tm, final
+
+
+def _fused_seq_apply(seq, xs, ms, reverse, kernel_fn, weights):
+    """``_fused_dispatch`` whose output is a masked batch-major sequence:
+    returns (SequenceBatch, final-state)."""
+    hs_tm, final = _fused_dispatch(xs, ms, reverse, kernel_fn, weights)
+    return _batch_major(hs_tm, seq.lengths), final
 
 
 def _masked_scan(step, init_carry, xs_time_major, mask_time_major, reverse=False):
@@ -211,9 +245,9 @@ def _masked_scan(step, init_carry, xs_time_major, mask_time_major, reverse=False
                         reverse=reverse, unroll=SCAN_UNROLL)
 
 
-def lstm(seq: SequenceBatch, w_r, bias=None, check_i=None, check_f=None,
+def lstm(seq, w_r, bias=None, check_i=None, check_f=None,
          check_o=None, reverse=False, act="tanh", gate_act="sigmoid",
-         state_act="tanh", init_state=None, proj=None):
+         state_act="tanh", init_state=None, proj=None, hand_over=None):
     """Whole-sequence LSTM (reference LstmLayer + SequenceToBatch).
 
     seq.data: [B, T, 4D] pre-projected gate inputs (the reference's lstmemory
@@ -221,12 +255,27 @@ def lstm(seq: SequenceBatch, w_r, bias=None, check_i=None, check_f=None,
     [B, T, in] input of that bias-free projection, which the fused kernels
     then compute, and differentiate, themselves (neither the [T, B, 4D]
     gate inputs nor their gradient reaches HBM) and every other path forms
-    first.  bias: [4D].  Returns
-    (SequenceBatch of h [B, T, D], final LstmState).
+    first.  With ``proj``, ``seq`` may also be the ``LstmOutput`` of a
+    fused LSTM below, whose time-major stream the projecting kernels read
+    as it is (they ignore a masked step's input; every other path reads
+    its ``sequence()``).  bias: [4D].
+
+    ``hand_over`` ("stream" or "final", forward only): the caller's readers
+    take the fused kernel's own form, so where that kernel runs the output
+    is an ``LstmOutput`` with the time-major stream, or with the final
+    carry alone (the kernel's entry whose backward takes no [T, B, D]
+    cotangent).  Returns (SequenceBatch of h [B, T, D] or LstmOutput,
+    final LstmState).
     """
-    global PROJECTED_DISPATCH_COUNT
-    b, d = seq.data.shape[0], w_r.shape[0]
-    ms = seq.mask().transpose(1, 0)                 # [T, B]
+    global PROJECTED_DISPATCH_COUNT, HANDED_OVER_COUNT
+    stream = isinstance(seq, LstmOutput)
+    if stream:
+        t, b = seq.hs.shape[:2]
+    else:
+        b, t = seq.data.shape[:2]
+    d = w_r.shape[0]
+    ms = (jnp.arange(t, dtype=jnp.int32)[:, None]
+          < seq.lengths[None, :]).astype(jnp.float32)       # [T, B]
     fused = blocked = projected = False
     if _fused_lstm_enabled():
         # import inside the branch: a broken pallas install must not take
@@ -242,23 +291,37 @@ def lstm(seq: SequenceBatch, w_r, bias=None, check_i=None, check_f=None,
         # equivalent weight traffic; docs/kernels.md blocked-variant notes)
         blocked = not fused and pl_lstm_blk.supported(*guard)
 
-    x = seq.data
-    if not projected:
-        if proj is not None:
-            x = matmul(x, proj)
-        if bias is not None:
-            x = x + bias
-    xs = x.transpose(1, 0, 2)       # time-major [T, B, 4D], or [T, B, in]
+    if stream and projected:
+        xs = seq.hs                 # time-major [T, B, in] already
+    else:
+        if stream:
+            seq = seq.sequence()
+        x = seq.data
+        if not projected:
+            if proj is not None:
+                x = matmul(x, proj)
+            if bias is not None:
+                x = x + bias
+        xs = x.transpose(1, 0, 2)   # time-major [T, B, 4D], or [T, B, in]
 
     if fused or blocked:
         PROJECTED_DISPATCH_COUNT += projected
-        kernel = pl_lstm.lstm_fused if fused \
-            else pl_lstm_blk.lstm_fused_blocked
+        hand_over = hand_over if fused and not reverse else None
+        if fused:
+            kernel = functools.partial(pl_lstm.lstm_fused,
+                                       final_only=hand_over == "final")
+        else:
+            kernel = pl_lstm_blk.lstm_fused_blocked
         weights = (w_r, check_i, check_f, check_o)
-        sb, (fh, fc) = _fused_seq_apply(
-            seq, xs, ms, reverse, lambda x, m, w: kernel(x, m, *w),
+        hs_tm, (fh, fc) = _fused_dispatch(
+            xs, ms, reverse, lambda x, m, w: kernel(x, m, *w),
             (weights + (proj, bias)) if projected else weights)
-        return sb, LstmState(h=fh, c=fc)
+        final = LstmState(h=fh, c=fc)
+        if hand_over:
+            HANDED_OVER_COUNT += 1
+            return LstmOutput(hs=hs_tm, final=final,
+                              lengths=seq.lengths), final
+        return _batch_major(hs_tm, seq.lengths), final
 
     if init_state is None:
         init_state = LstmState(h=jnp.zeros((b, d), x.dtype),
@@ -269,8 +332,7 @@ def lstm(seq: SequenceBatch, w_r, bias=None, check_i=None, check_f=None,
                          act, gate_act, state_act)
 
     final, hs = _masked_scan(step, init_state, xs, ms, reverse=reverse)
-    out = hs.h.transpose(1, 0, 2) * seq.mask(hs.h.dtype)[..., None]
-    return SequenceBatch(data=out, lengths=seq.lengths), final
+    return _batch_major(hs.h, seq.lengths), final
 
 
 def gru(seq: SequenceBatch, w_gate, w_state, bias=None, reverse=False,
@@ -300,8 +362,7 @@ def gru(seq: SequenceBatch, w_gate, w_state, bias=None, reverse=False,
         return gru_cell(x3, h, w_gate, w_state, act, gate_act)
 
     final, hs = _masked_scan(step, init_state, xs, ms, reverse=reverse)
-    out = hs.transpose(1, 0, 2) * seq.mask(hs.dtype)[..., None]
-    return SequenceBatch(data=out, lengths=seq.lengths), final
+    return _batch_major(hs, seq.lengths), final
 
 
 def simple_rnn(seq: SequenceBatch, w_r, bias=None, reverse=False, act="tanh",
@@ -323,8 +384,7 @@ def simple_rnn(seq: SequenceBatch, w_r, bias=None, reverse=False, act="tanh",
         init_state = jnp.zeros((b, d), x.dtype)
     final, hs = _masked_scan(lambda h, xt: simple_rnn_cell(xt, h, w_r, act),
                              init_state, xs, ms, reverse=reverse)
-    out = hs.transpose(1, 0, 2) * seq.mask(hs.dtype)[..., None]
-    return SequenceBatch(data=out, lengths=seq.lengths), final
+    return _batch_major(hs, seq.lengths), final
 
 
 def recurrent_group(step_fn, inputs, boot_memories, reverse=False, rng=None):

@@ -155,3 +155,122 @@ def test_only_the_plain_pair_is_handed_over(np_rng, projections_handed,
     for g, w in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want), strict=True):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+# ------------------------------------- the LSTM's output in the kernel's form
+
+def _lstm_nodes(topo):
+    return [n for n in topo.order if n.layer_type == "lstmemory"]
+
+
+@pytest.mark.parametrize("compute", ["float32", "bfloat16"])
+@pytest.mark.parametrize("lengths", [[1, T, 3, 5, 2, T, 4, 1],
+                                     [0, T, 3, 1, 5, 2, T, 4]],
+                         ids=["ragged", "an_empty_row"])
+def test_the_cells_network_hands_its_outputs_over_bit_for_bit(
+        np_rng, monkeypatch, lengths, compute):
+    """The benchmark's network on the kernels: layer 1's time-major stream
+    is layer 2's input and layer 2's final carry is ``last_seq``'s row.
+    Loss and every gradient leaf are those of the same graph with both
+    ``lstmemory`` nodes asked for in ``extra_outputs`` (each then a masked
+    batch-major sequence, as every reader had it before), bit for bit, at
+    lengths from 1 to T and with a row of length 0, under both compute
+    dtypes (bfloat16: layer 2's ``dx`` is rounded in its kernel)."""
+    from benchmark.drivers import train as driver
+    from paddle_tpu.core import dtypes
+    monkeypatch.setattr(dtypes, "_compute_dtype", getattr(jnp, compute))
+    monkeypatch.setattr(rnn, "FUSED_LSTM", "always")
+    trainer = driver.build_trainer(CFG, seed=3)
+    topo, params = trainer.topology, trainer.parameters
+    feed = {"w": SequenceBatch(
+                data=jnp.asarray(np_rng.randint(0, V, (B, T)), jnp.int32),
+                lengths=jnp.asarray(lengths, jnp.int32)),
+            "lab": jnp.asarray(np_rng.randint(0, 2, (B,)), jnp.int32)}
+
+    def grad(extra):
+        def cost(p):
+            out = topo.apply(p, feed, mode="train", extra_outputs=extra)
+            return jnp.mean(out[0] if extra else out)
+        before = rnn.HANDED_OVER_COUNT
+        got = jax.value_and_grad(cost)(params)
+        return got, rnn.HANDED_OVER_COUNT - before
+
+    got, handed = grad(())
+    assert handed == 2
+    want, handed = grad(_lstm_nodes(topo))
+    assert handed == 0
+    flat = lambda t: jax.tree_util.tree_leaves_with_path(t)
+    for (path, g), (_, w) in zip(flat(got), flat(want), strict=True):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                      err_msg=jax.tree_util.keystr(path))
+
+
+def _one_layer(what):
+    """words -> embedding -> simple_lstm -> a pooling -> cost, as ``what``
+    asks; returns (Topology, the lstmemory node)."""
+    reset_names()
+    words = L.data_layer("w", size=V, is_seq=True)
+    label = L.data_layer("lab", size=1)
+    emb = L.embedding_layer(words, size=H)
+    if what == "bidirectional_lstm":
+        seq = networks.bidirectional_lstm(emb, H, return_seq=True)
+        pooled, lstm = L.last_seq(seq), None
+    elif what == "bidirectional_last_and_first":
+        pooled, lstm = networks.bidirectional_lstm(emb, H), None
+    elif what == "prev_batch_state":
+        lstm = L.lstmemory(L.fc_layer(emb, size=4 * H, act=None,
+                                      bias_attr=False),
+                           size=H, prev_batch_state=True)
+        pooled = L.last_seq(lstm)
+    else:
+        lstm = networks.simple_lstm(emb, H, reverse=what == "reversed")
+        pooled = {"max_pooling": lambda: L.pooling_layer(
+                      lstm, pooling_type=L.pooling.Max),
+                  "strided_last_seq": lambda: L.pooling_layer(
+                      L.last_seq(lstm, stride=2), pooling_type=L.pooling.Max),
+                  "two_readers": lambda: L.concat_layer(
+                      [L.last_seq(lstm), L.first_seq(lstm)])}.get(
+                          what, lambda: L.last_seq(lstm))()
+    probs = L.fc_layer(pooled, size=2, act="softmax")
+    return Topology(L.classification_cost(probs, label)), lstm
+
+
+@pytest.mark.parametrize("what,want", [
+    ("the_cells_network", 2), ("the_scan", 0), ("one_layer", 1),
+    ("bidirectional_lstm", 0), ("reversed", 0), ("max_pooling", 0),
+    ("strided_last_seq", 0), ("two_readers", 0), ("extra_output", 0),
+    ("prev_batch_state", 0),
+    # the forward half's one reader is a plain last_seq (the reversed
+    # half's a first_seq): the rule hands the forward half over
+    ("bidirectional_last_and_first", 1)])
+def test_only_kernel_form_readers_get_the_kernels_form(
+        np_rng, monkeypatch, what, want):
+    """``rnn.HANDED_OVER_COUNT`` over one forward pass on the kernels: 2
+    for the benchmark's network, 1 for one layer read by ``last_seq``, and
+    0 for the scan, both layers of ``bidirectional_lstm`` returning the
+    sequence, a reversed layer, any other pooling, a strided ``last_seq``,
+    a second reader, a node the call asks for, a state carried to the next
+    batch.  Each graph's cost is the same either way (the second pass
+    hands nothing over)."""
+    from benchmark.drivers import train as driver
+    lstm, kw = None, {}
+    if what in ("the_cells_network", "the_scan"):
+        topo = driver.build_trainer(CFG, seed=3).topology
+    else:
+        topo, lstm = _one_layer(
+            "one_layer" if what == "extra_output" else what)
+    if what == "extra_output":
+        kw = {"extra_outputs": [lstm]}
+    monkeypatch.setattr(rnn, "FUSED_LSTM",
+                        "0" if what == "the_scan" else "always")
+    params, feed = topo.init(jax.random.PRNGKey(1)), _feed(np_rng)
+    before = rnn.HANDED_OVER_COUNT
+    got = topo.apply(params, feed, mode="test", **kw)
+    assert rnn.HANDED_OVER_COUNT - before == want
+    monkeypatch.setattr(topo, "_lstm_handoffs", {})
+    want_cost = topo.apply(params, feed, mode="test", **kw)
+    assert rnn.HANDED_OVER_COUNT - before == want
+    np.testing.assert_allclose(
+        np.asarray(jax.tree_util.tree_leaves(got)[0]),
+        np.asarray(jax.tree_util.tree_leaves(want_cost)[0]),
+        rtol=1e-6, atol=1e-7)

@@ -99,3 +99,41 @@ def test_padding_invariant(name):
                 np.asarray(gw), np.asarray(ga), rtol=1e-4, atol=1e-6,
                 err_msg=f"{name}: param grad {jax.tree_util.keystr(path)} "
                         "depends on padding")
+
+
+def test_padding_invariant_lstm_outputs_handed_over(monkeypatch):
+    """The benchmark's two ``simple_lstm -> last_seq`` layers on the fused
+    kernels (interpret mode here), their outputs handed over in the
+    kernels' own form: layer 2 reads layer 1's UNMASKED time-major stream,
+    whose padded steps hold the frozen carry, and ``last_seq`` the final
+    carry.  Loss and gradients do not see the padding."""
+    from benchmark.drivers import train as driver
+    from paddle_tpu.ops import rnn
+    monkeypatch.setattr(rnn, "FUSED_LSTM", "always")
+    b, t, v, h = 8, 5, 40, 128
+    topo = driver.build_trainer(
+        {"vocab": v, "emb": h, "hidden": h, "lstm_layers": 2, "classes": 2,
+         "pooling": "last", "optimizer": {"kind": "Adam",
+                                          "learning_rate": 2e-3}},
+        seed=3).topology
+    params = topo.init(jax.random.PRNGKey(0))
+    r = np.random.RandomState(45)
+    feed = {"w": SequenceBatch(
+                data=jnp.asarray(r.randint(0, v, (b, t)), jnp.int32),
+                lengths=jnp.asarray([1, t, 2, 3, 0, 4, t, 1], jnp.int32)),
+            "lab": jnp.asarray(r.randint(0, 2, (b,)), jnp.int32)}
+    wide = dict(feed, w=_extend(feed["w"]))
+    before = rnn.HANDED_OVER_COUNT
+    base, g_base = jax.value_and_grad(
+        lambda p: _loss(topo, p, feed))(params)
+    assert rnn.HANDED_OVER_COUNT == before + 2
+    padded, g_wide = jax.value_and_grad(
+        lambda p: _loss(topo, p, wide))(params)
+    np.testing.assert_allclose(float(padded), float(base), rtol=1e-5)
+    for (path, ga), (_, gw) in zip(
+            jax.tree_util.tree_flatten_with_path(g_base)[0],
+            jax.tree_util.tree_flatten_with_path(g_wide)[0], strict=True):
+        np.testing.assert_allclose(
+            np.asarray(gw), np.asarray(ga), rtol=1e-4, atol=1e-6,
+            err_msg=f"param grad {jax.tree_util.keystr(path)} depends on "
+                    "padding")

@@ -6,6 +6,8 @@ Runs the kernel in interpret mode on the CPU mesh; the same code lowers to
 Mosaic on a real chip (exercised by chip_smoke.py and the
 ``lstm-h512_train`` benchmark cell)."""
 
+import collections
+
 import numpy as np
 import pytest
 import jax
@@ -433,20 +435,27 @@ def test_projected_kernel_matches_the_unprojected(
                                    rtol=2e-4, atol=2e-5, err_msg=la)
 
 
-def _pallas_outputs(jaxpr):
-    """``(name, [output shapes])`` of every pallas_call in a jaxpr."""
+def _pallas_calls(jaxpr):
+    """``(name, [operand avals], [output avals])`` of every pallas_call in
+    a jaxpr."""
     from jax.extend.core import ClosedJaxpr, Jaxpr
     found = []
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
-            found.append((eqn.params["name"],
-                          [a.shape for a in eqn.params["out_avals"]]))
+            found.append((eqn.params["name"], [v.aval for v in eqn.invars],
+                          list(eqn.params["out_avals"])))
             continue
         for p in eqn.params.values():
             for sub in p if isinstance(p, (list, tuple)) else [p]:
                 if isinstance(sub, (ClosedJaxpr, Jaxpr)):
-                    found += _pallas_outputs(getattr(sub, "jaxpr", sub))
+                    found += _pallas_calls(getattr(sub, "jaxpr", sub))
     return found
+
+
+def _pallas_outputs(jaxpr):
+    """``(name, [output shapes])`` of every pallas_call in a jaxpr."""
+    return [(name, [a.shape for a in outs])
+            for name, _, outs in _pallas_calls(jaxpr)]
 
 
 @pytest.mark.parametrize("projected", [True, False],
@@ -536,6 +545,13 @@ def test_projection_gradients_keep_the_compute_dtypes_rounding(
         return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(x, w_x, bias)
 
     got, want = grads(True), grads(False)
+    # dx leaves the kernel in x's own dtype, already rounded
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda x: jnp.sum(_lstm_of_input(x, lengths, w_x, w_r, bias, checks,
+                                         True, "always")[1].c)))(x).jaxpr
+    (_, _, bwd_outputs), = [c for c in _pallas_calls(jaxpr)
+                            if c[0] == "lstm_bwd"]
+    assert bwd_outputs[0].dtype == x.dtype == jnp.float32
     for label, g, w in zip(["dx", "dw_x", "dbias"], got, want, strict=True):
         g, w = np.asarray(g), np.asarray(w)
         assert g.dtype == np.float32, label
@@ -546,6 +562,28 @@ def test_projection_gradients_keep_the_compute_dtypes_rounding(
         np.testing.assert_allclose(g, w, rtol=0,
                                    atol=2 ** -7 * np.abs(w).max(),
                                    err_msg=label)
+
+
+def test_in_kernel_rounding_is_the_bfloat16_conversion(np_rng):
+    """The projected backward rounds dx to bfloat16 in the integers; that
+    is the conversion's round-to-nearest-even bit for bit, ties, the
+    largest finite values and infinities included, and a NaN of any
+    payload stays a NaN (the integer carry must not make it 0 or inf)."""
+    from paddle_tpu.ops.pallas.lstm import _rounded_as
+    edges = np.array([0, 0x80000000, 0x00008000, 0x00018000, 0x3F808000,
+                      0x3F818000, 0x7F7FFFFF, 0x7F7F8000, 0x7F800000,
+                      0xFF800000, 0x00000001, 0x7F800001, 0x7FC00000,
+                      0x7FFFFFFF, 0xFFFFFFFF, 0xFF800001], np.uint32)
+    bits = np.concatenate([edges, np_rng.randint(0, 2 ** 32, 20000,
+                                                 dtype=np.uint64)
+                           .astype(np.uint32)])
+    v = jnp.asarray(bits.view(np.float32))
+    got = np.asarray(_rounded_as(v, jnp.bfloat16))
+    want = np.asarray(v.astype(jnp.bfloat16).astype(jnp.float32))
+    nan = np.isnan(want)
+    np.testing.assert_array_equal(np.isnan(got), nan)
+    np.testing.assert_array_equal(got[~nan].view(np.uint32),
+                                  want[~nan].view(np.uint32))
 
 
 @pytest.mark.parametrize("why", ["scan", "init_state", "activation",
@@ -635,20 +673,22 @@ def test_projected_forward_is_planned_with_w_x_resident(
 
 
 @pytest.mark.parametrize("bt,d,d_in,mosaic_mib", [
-    (1024, 512, 512, 73.74), (1024, 512, 128, 65.74), (512, 512, 512, 45.01),
-    (1024, 128, 128, 14.41), (1024, 256, 256, 33.50), (1024, 384, 384, 53.01),
-    (1024, 640, 640, 98.40), (1024, 128, 2048, 47.78),
-    (1024, 128, 4096, 83.77), (512, 256, 4096, 74.16),
-    (256, 1024, 1024, 79.34), (8, 1536, 128, 112.72)])
+    (1024, 512, 512, 81.75), (1024, 512, 128, 78.01), (512, 512, 512, 46.51),
+    (1024, 128, 128, 14.92), (1024, 256, 256, 37.51), (1024, 384, 384, 58.44),
+    (1024, 640, 640, 114.66), (1024, 128, 2048, 52.78),
+    (1024, 128, 4096, 95.79), (512, 256, 4096, 78.01),
+    (256, 1024, 1024, 115.5), (8, 1536, 128, 112.79)])
 def test_projected_backward_is_planned_with_dw_x_resident(
         monkeypatch, bt, d, d_in, mosaic_mib):
     """``bwd_vmem_bytes`` against the smallest ``vmem_limit_bytes`` under
-    which the projected backward (bfloat16 W_x, dx and dW_x out, the
-    forward at its own limit) compiled for the v5e (bisected chip-free,
-    T=100: at T=4 XLA holds whole operands in VMEM and Mosaic counts less,
-    PR 38): the plan is over it, the limit handed over covers it, and the
-    benchmark's batch is ONE tile of both of its layers at the v5e's
-    budget."""
+    which the projected backward (bfloat16 W_x and dW_x out, dx in its
+    float32 input's dtype, the forward at its own limit) compiled for the
+    v5e inside the gradient of ``sum(hs) + sum(c_final)`` through
+    ``lstm_fused(proj=)`` (bisected chip-free to 0.05 MiB, T=100: at T=4
+    XLA holds whole operands in VMEM and Mosaic counts less; the kernel
+    with float32 dx, docs/kernels.md): the plan is over it, the limit
+    handed over covers it, and the benchmark's batch is ONE tile of both
+    of its layers at the v5e's budget."""
     from paddle_tpu.core import dtypes
     from paddle_tpu.ops.pallas import common, lstm as pl
     monkeypatch.setattr(dtypes, "_compute_dtype", jnp.bfloat16)
@@ -664,3 +704,146 @@ def test_projected_backward_is_planned_with_dw_x_resident(
     # at d=640 the plan (118.7 MiB at 1,024 rows) halves a tile that
     # Mosaic's count (98.4) would take whole: the plan errs to the safe side
     assert pl.batch_tile(1024, 640, 640) == 512
+
+
+# ------------------------------------------ the output in the kernel's form
+
+@pytest.mark.parametrize("tiles", [1, 2])
+@pytest.mark.parametrize("projected", [False, True],
+                         ids=["gate_inputs", "projected"])
+def test_final_carry_entry_matches_scan(np_rng, monkeypatch, pallas_grids,
+                                        projected, tiles):
+    """``lstm(hand_over="final")`` on the kernels is the entry whose
+    outputs are the final (h, c) alone: their values and the gradients of
+    x, W_x, w_r, the bias and the peepholes against the scan's, on ragged
+    rows with one of length 0 (its carry is 0 either way), one and two
+    batch tiles.  The backward is handed no [T, B, D] cotangent: its
+    operands of that shape are the residuals c_t, c_{t-1} and h_{t-1}
+    alone, and the final h's cotangent enters as a [1, B, D] block."""
+    b, d_in = 16, 256                # d_in neither D nor 4D
+    _force_tile(monkeypatch, b, D, b // tiles, d_in if projected else None)
+    x, lengths, w_x, w_r, bias, checks = _projected_case(np_rng, b, d_in, D)
+    lengths = lengths.at[0].set(0).at[1].set(T).at[2].set(1)
+    probe_h = jnp.asarray(np_rng.randn(b, D), jnp.float32)
+    probe_c = jnp.asarray(np_rng.randn(b, D), jnp.float32)
+
+    def loss(mode, x, w_x, w_r, bias, checks):
+        out, final = _lstm_of_input(x, lengths, w_x, w_r, bias, checks,
+                                    projected, mode, hand_over="final")
+        assert isinstance(out, rnn.LstmOutput) == (mode == "always")
+        return (jnp.sum(final.h * probe_h) + jnp.sum(final.c * probe_c),
+                (final.h, final.c))
+
+    args = (x, w_x, w_r, bias, checks)
+    grad = lambda mode: jax.value_and_grad(
+        lambda *a: loss(mode, *a), has_aux=True, argnums=(0, 1, 2, 3, 4))
+    before = rnn.HANDED_OVER_COUNT
+    got = grad("always")(*args)
+    assert rnn.HANDED_OVER_COUNT == before + 1
+    assert pallas_grids == [("lstm_fwd", (tiles, T)),
+                            ("lstm_bwd", (tiles, T))]
+    want = grad("0")(*args)
+    assert rnn.HANDED_OVER_COUNT == before + 1
+    labels = ["loss", "h", "c", "dx", "dw_x", "dw_r", "dbias",
+              "dci", "dcf", "dco"]
+    for la, g, w in zip(labels, jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want), strict=True):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=2e-4, atol=2e-5, err_msg=la)
+    assert not np.asarray(got[0][1][0])[0].any()     # the empty row's h
+
+    def bwd_operands(hand_over):
+        def loss(x):
+            out, final = _lstm_of_input(x, lengths, w_x, w_r, bias, checks,
+                                        projected, "always",
+                                        hand_over=hand_over)
+            every_h = 0.0 if hand_over else jnp.sum(out.data)
+            return every_h + jnp.sum(final.h) + jnp.sum(final.c)
+        jaxpr = jax.make_jaxpr(jax.grad(loss))(x).jaxpr
+        (_, operands, _), = [c for c in _pallas_calls(jaxpr)
+                             if c[0] == "lstm_bwd"]
+        return collections.Counter(a.shape for a in operands)
+
+    streamed, final = bwd_operands(None), bwd_operands("final")
+    assert streamed - final == {(T, b, D): 1}        # dh_out
+    assert final - streamed == {(1, b, D): 1}        # dh_final
+
+
+@pytest.mark.parametrize("why", ["forward", "reverse", "scan"])
+def test_the_stream_is_the_sequence_in_the_kernels_form(np_rng, why):
+    """``lstm(hand_over="stream")``: on the kernels, forward, an
+    ``LstmOutput`` whose ``sequence()`` is the masked batch-major output,
+    bit for bit, and whose row at ``lengths - 1`` of the stream is the
+    final h (a masked step holds the carry); reversed or on the scan the
+    output is that sequence itself, and nothing is counted."""
+    seq, w_r, checks, bias = _mk(np_rng, ragged=True)
+    kw = dict(bias=bias, check_i=checks[0], check_f=checks[1],
+              check_o=checks[2], reverse=why == "reverse")
+    prior = rnn.FUSED_LSTM
+    rnn.FUSED_LSTM = "0" if why == "scan" else "always"
+    try:
+        before = rnn.HANDED_OVER_COUNT
+        out, final = rnn.lstm(seq, w_r, hand_over="stream", **kw)
+        handed = rnn.HANDED_OVER_COUNT - before
+        want, want_final = rnn.lstm(seq, w_r, **kw)
+    finally:
+        rnn.FUSED_LSTM = prior
+    assert handed == (why == "forward")
+    assert isinstance(out, rnn.LstmOutput) == (why == "forward")
+    if why == "forward":
+        np.testing.assert_array_equal(np.asarray(out.final.h),
+                                      np.asarray(final.h))
+        last = np.asarray(out.hs)[np.maximum(np.asarray(seq.lengths) - 1, 0),
+                                  np.arange(B)]
+        np.testing.assert_array_equal(last, np.asarray(final.h))
+        out = out.sequence()
+    np.testing.assert_array_equal(np.asarray(out.data), np.asarray(want.data))
+    for g, w in zip(final, want_final, strict=True):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_handed_over_stack_under_a_data_mesh(np_rng):
+    """Two projected layers as the benchmark's network stacks them, each
+    kernel on its batch shard (``batch_sharded_over``): layer 1 hands its
+    time-major stream to layer 2's kernels, layer 2 its final carry alone.
+    The loss of the final h and every gradient equal the single-device
+    scan of the same layers."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from paddle_tpu.parallel.mesh import AXIS_DATA, MeshConfig, make_mesh
+    mesh = make_mesh(MeshConfig(data=2), devices=jax.devices()[:2])
+    b = 2 * B
+    x, lengths, w_x, w_r, bias, checks = _projected_case(np_rng, b, D, D)
+    w_x2, w_r2 = w_x[:, ::-1] * 0.9, w_r[::-1] * 1.1
+    probe = jnp.asarray(np_rng.randn(b, D), jnp.float32)
+
+    def loss(x, w_x, w_r, w_x2, w_r2):
+        layer = dict(bias=bias, check_i=checks[0], check_f=checks[1],
+                     check_o=checks[2])
+        out, _ = rnn.lstm(SequenceBatch(data=x, lengths=lengths), w_r,
+                          proj=w_x, hand_over="stream", **layer)
+        _, final = rnn.lstm(out, w_r2, proj=w_x2, hand_over="final", **layer)
+        return jnp.sum(final.h * probe)
+
+    grad = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))
+    args = (x, w_x, w_r, w_x2, w_r2)
+    prior = rnn.FUSED_LSTM
+    try:
+        rnn.FUSED_LSTM = "always"
+        before = rnn.HANDED_OVER_COUNT
+
+        def sharded(*a):
+            with rnn.batch_sharded_over(mesh, AXIS_DATA):
+                return grad(*a)
+
+        whole = NamedSharding(mesh, P())
+        got = jax.jit(sharded, in_shardings=(
+            NamedSharding(mesh, P(AXIS_DATA)),) + (whole,) * 4)(*args)
+        assert rnn.HANDED_OVER_COUNT == before + 2
+        rnn.FUSED_LSTM = "0"
+        want = jax.jit(grad)(*args)
+    finally:
+        rnn.FUSED_LSTM = prior
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want), strict=True):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-4)
